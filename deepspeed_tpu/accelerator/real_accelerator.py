@@ -36,14 +36,15 @@ def get_accelerator():
             raise ValueError(f"accelerator_name {accelerator_name} value is not supported. "
                              f"Supported list: {SUPPORTED_ACCELERATOR_LIST}")
     else:
-        # Auto-detect: prefer TPU, fall back to whatever jax default backend is.
-        try:
-            import jax
+        # Auto-detect from the backend JAX initialised. No fallback: a backend
+        # that fails to initialise raises here, and one this package does not
+        # know is an error — never a silent "cpu" that hides the device.
+        import jax
 
-            platform = jax.default_backend()
-            accelerator_name = {"tpu": "tpu", "cpu": "cpu", "gpu": "gpu"}.get(platform, "cpu")
-        except Exception:
-            accelerator_name = "cpu"
+        accelerator_name = jax.default_backend()
+        if accelerator_name not in SUPPORTED_ACCELERATOR_LIST:
+            raise RuntimeError(f"JAX default backend {accelerator_name!r} is not a supported "
+                               f"accelerator {SUPPORTED_ACCELERATOR_LIST}")
 
     if accelerator_name == "tpu":
         from .tpu_accelerator import TPU_Accelerator

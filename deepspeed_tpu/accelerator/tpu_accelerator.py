@@ -32,10 +32,9 @@ class TPU_Accelerator(DeepSpeedAccelerator):
         return False
 
     def _devices(self):
-        try:
-            return jax.devices(self._platform)
-        except RuntimeError:
-            return jax.devices()
+        # raises when the platform asked for (or detected) has no devices:
+        # counting another platform's devices would hide the missing one
+        return jax.devices(self._platform)
 
     def _local_devices(self):
         return [d for d in self._devices() if d.process_index == jax.process_index()]

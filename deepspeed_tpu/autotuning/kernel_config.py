@@ -226,12 +226,11 @@ class KernelAutotuner:
 
     @staticmethod
     def _on_tpu() -> bool:
-        try:
-            import jax
+        # decides interpret mode for every sweep: a backend that fails to
+        # initialise raises here, it never reads as "not a TPU"
+        import jax
 
-            return jax.default_backend() == "tpu"
-        except Exception:
-            return False
+        return jax.default_backend() == "tpu"
 
     def measure(self, fn: Callable[[], object]) -> float:
         """Median-of-steps wall seconds for one candidate callable (each call

@@ -368,22 +368,45 @@ def _sparse_attention(cfg: TransformerConfig, q, k, v):
     return ctx.transpose(0, 2, 1, 3)
 
 
+def _multi_device_tpu_mesh():
+    """The registered mesh when Pallas kernels will run on more than one TPU
+    device, else None (off-TPU the kernels are jnp references GSPMD
+    partitions itself; on one device there is nothing to partition)."""
+    if jax.default_backend() != "tpu":
+        return None
+    from ..parallel import groups
+
+    if not groups.is_initialized():
+        return None
+    mesh = groups.get_mesh()
+    return mesh if mesh.size > 1 else None
+
+
 def _attention(cfg: TransformerConfig, q, k, v):
     if cfg.sparse_attention is not None:
         return _sparse_attention(cfg, q, k, v)
     impl = cfg.attention_impl
     if impl == "auto":
-        try:
-            import jax
-
-            impl = "flash" if jax.default_backend() == "tpu" else "reference"
-        except Exception:
-            impl = "reference"
+        impl = "flash" if jax.default_backend() == "tpu" else "reference"
     alibi = cfg.positions == "alibi"
     if impl == "flash":
         from ..ops.pallas.flash_attention import flash_attention
 
-        return flash_attention(q, k, v, causal=True, window=cfg.sliding_window, alibi=alibi)
+        attn = partial(flash_attention, causal=True, window=cfg.sliding_window, alibi=alibi)
+        mesh = _multi_device_tpu_mesh()
+        if mesh is None:
+            return attn(q, k, v)
+        # GSPMD cannot partition a Mosaic kernel ("wrap the call in a
+        # shard_map"): on a multi-device TPU mesh the kernel runs per shard,
+        # batch over the data axes and heads over the tensor-parallel axis
+        # (plus the seq axis inside Ulysses, whose all-to-all put it there)
+        heads = (MODEL_AXIS, SEQ_AXIS) if cfg.sequence_parallel else (MODEL_AXIS, )
+        if alibi and math.prod(mesh.shape[a] for a in heads) > 1:
+            raise NotImplementedError("alibi flash attention with sharded heads: the kernel "
+                                      "derives slopes from the LOCAL head index")
+        spec = P(BATCH_AXES, None, heads, None)
+        return jax.shard_map(attn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+                             check_vma=False)(q, k, v)
     return reference_attention(q, k, v, causal=True, window=cfg.sliding_window,
                                alibi=alibi_slopes(cfg.num_heads) if alibi else None)
 

@@ -3,14 +3,16 @@
 Analog of the reference ``op_builder/builder.py`` which compiles torch
 cpp-extensions on first use. Here: g++ compiles each C++ source set to a
 shared library loaded via ctypes (no pybind11 in this image). Libraries are
-cached under ``<repo>/build/native/`` keyed by a content hash, so a source
-edit triggers recompilation — the same staleness contract as the reference's
-JIT load path.
+cached under ``<repo>/build/native/`` keyed by a hash of the sources, the
+flags and the CPU they were built on (``-march=native``), so a source edit
+triggers recompilation — the same staleness contract as the reference's JIT
+load path — and a library built on one machine is never loaded on another.
 """
 
 import ctypes
 import hashlib
 import os
+import platform
 import subprocess
 import threading
 
@@ -30,12 +32,28 @@ class NativeBuildError(RuntimeError):
     pass
 
 
+def _machine_tag():
+    """What ``-march=native`` resolves against: the architecture plus the
+    CPU's feature-flag line. A copied ``build/`` tree then misses on any CPU
+    with a different instruction set instead of loading a library that may
+    fault on it."""
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    return platform.machine() + line.split(":", 1)[1].strip()
+    except OSError:
+        pass
+    return platform.machine() + platform.processor()
+
+
 def _source_hash(paths, flags):
     h = hashlib.sha256()
     for p in paths:
         with open(p, "rb") as f:
             h.update(f.read())
     h.update(" ".join(flags).encode())
+    h.update(_machine_tag().encode())
     return h.hexdigest()[:16]
 
 

@@ -21,15 +21,13 @@ per-row log-sum-exp ``lse = m + log(l)``; the backward recomputes
 
 Fallback policy: on non-TPU backends, or for shapes the kernel does not
 support (S not a multiple of 128), we use the jnp reference implementation —
-XLA fuses it reasonably. On TPU with supported shapes a kernel failure is
-LOUD: it raises unless ``DS_TPU_ALLOW_ATTN_FALLBACK=1`` is set, so training
-can never silently drop to O(S^2) unfused attention again (the round-1 perf
-failure mode).
+XLA fuses it reasonably. On TPU with supported shapes a kernel failure
+RAISES (at trace/lowering here, or at the enclosing jit's compile), so
+training can never silently drop to O(S^2) unfused attention.
 """
 
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -38,10 +36,7 @@ _NEG_INF = -1e30
 
 
 def _use_pallas():
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _fit_block(S: int, want: int) -> int:
@@ -56,18 +51,15 @@ def _fit_block(S: int, want: int) -> int:
     return b
 
 
-# Generations where the 1024 tiling is validated (bench chip is v5e). Older /
-# unknown generations keep the proven 512 default: a VMEM exhaustion inside an
-# enclosing jit surfaces at the *caller's* compile, where the retry below
-# cannot catch it.
+# Generations where the 1024 tiling is validated (forward and backward, on a
+# v5e: tests_tpu::test_flash_bwd_large_tiles_on_chip). Older / unknown
+# generations keep the 512 default: a VMEM exhaustion surfaces at the
+# enclosing jit's compile, as an error.
 _LARGE_TILE_KINDS = ("v5 lite", "v5e", "v5p", "v6")
 
 
 def _default_tile():
-    try:
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
-        return 512
+    kind = jax.devices()[0].device_kind.lower()
     return 1024 if any(t in kind for t in _LARGE_TILE_KINDS) else 512
 
 
@@ -171,31 +163,8 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = None, block_k: 
             return _reference_fallback(q, k, v, causal, window, alibi,
                                        f"no tiling fits VMEM for S={S}, d={d}")
         bq, bk = fitted
-        try:
-            return _pallas_flash(q, k, v, causal=causal, block_q=bq, block_k=bk,
-                                 window=window, alibi=alibi)
-        except Exception as e:
-            if bq > 512 or bk > 512:
-                # large tiles can exhaust VMEM on smaller TPU generations:
-                # retry once at the proven 512 tiling before going loud.
-                # NOTE this guards only the eager FORWARD call — the
-                # custom_vjp backward compiles later under jax.grad where no
-                # retry can fire; that's why the large-tile default is gated
-                # on device generation (_default_tile) and the backward is
-                # validated on-chip (tests_tpu::test_flash_bwd_large_tiles)
-                try:
-                    return _pallas_flash(q, k, v, causal=causal, block_q=_fit_block(S, 512),
-                                         block_k=_fit_block(S, 512), window=window, alibi=alibi)
-                except Exception:
-                    pass
-            if os.environ.get("DS_TPU_ALLOW_ATTN_FALLBACK") != "1":
-                raise RuntimeError(
-                    "Pallas flash attention failed on a supported shape "
-                    f"({type(e).__name__}: {e}). Set DS_TPU_ALLOW_ATTN_FALLBACK=1 "
-                    "to permit the O(S^2) reference-attention fallback."
-                ) from e
-            return _reference_fallback(q, k, v, causal, window, alibi,
-                                       f"kernel failed ({type(e).__name__}), fallback permitted")
+        return _pallas_flash(q, k, v, causal=causal, block_q=bq, block_k=bk,
+                             window=window, alibi=alibi)
     return _reference_fallback(q, k, v, causal, window, alibi)
 
 
@@ -357,6 +326,7 @@ def _flash_fwd_impl(causal, block_q, block_k, interpret, window, alibi, q, k, v)
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3), lse[..., 0]
 
@@ -477,6 +447,7 @@ def _flash_bwd_impl(causal, block_q, block_k, interpret, window, alibi, q, k, v,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkdv",
     )(qt, kt, vt, ot, dot, lse_b)
 
     # ---- pass 2: dq — k-blocks innermost; dq block revisited across kj ----
@@ -530,6 +501,7 @@ def _flash_bwd_impl(causal, block_q, block_k, interpret, window, alibi, q, k, v,
         out_shape=jax.ShapeDtypeStruct((B, nq, S, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qt, kt, vt, ot, dot, lse_b)
 
     dq = dq_t.transpose(0, 2, 1, 3).astype(q.dtype)

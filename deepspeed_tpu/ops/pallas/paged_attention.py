@@ -56,10 +56,8 @@ def _resolve_q_tile(T: int, S: int, seq_idx=None) -> int:
     """
     from ...autotuning.kernel_config import shape_bucket, tuned_tile
 
-    # DS_TPU_PAGED_Q_TILE: operator kill switch / override. The tiled grid's
-    # Mosaic lowering surfaces failures at the OUTER jit compile on the
-    # serving path (the in-wrapper ladder can't catch them there) — =1 pins
-    # the proven per-token grid without authoring a kernel_config.json.
+    # DS_TPU_PAGED_Q_TILE: operator override — =1 pins the per-token grid
+    # without authoring a kernel_config.json.
     env = os.environ.get("DS_TPU_PAGED_Q_TILE")
     if env:
         try:
@@ -162,6 +160,11 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: i
                          "nq>=8, d%128==0) — serving through the DENSE gather fallback")
         return paged_attention_reference(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size,
                                          window=window, alibi=alibi, k_scale=k_scale, v_scale=v_scale)
+    if k_scale is not None and block_size % 128 != 0:
+        # the scale block (nkv, block_size) must be lane-aligned: the TPU
+        # lowering rejects it otherwise, with a message that names neither
+        # the option nor the remedy
+        raise ValueError(f"int8 KV on TPU needs kv_block_size % 128 == 0, got {block_size}")
     if q_tile is None:
         q_tile = _resolve_q_tile(T, S, seq_idx)
     elif q_tile > 1 and not _contiguity_ok(seq_idx, S):
@@ -178,25 +181,16 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: i
     if kv_splits is None:
         kv_splits = _resolve_kv_splits(T, S, max_blocks, q_tile=int(q_tile))
     kv_splits = max(1, min(int(kv_splits), max_blocks))
-    # failure ladder: q-tiled -> kv-split decode -> per-token -> gather
-    # oracle. A tiling/split that fails Mosaic on some generation costs ONE
-    # rung, never the fused path. The split rung only exists on the
-    # per-token (decode) grid — a tiled prefill row keeps its single chain.
-    rungs = [(int(q_tile), 1)] if q_tile > 1 else []
-    rungs += [(1, kv_splits), (1, 1)]
-    for qt, ks in dict.fromkeys(rungs):
-        try:
-            return _pallas_paged(q, k_pool, v_pool, block_tables, seq_idx.astype(jnp.int32),
-                                 pos.astype(jnp.int32), block_size=block_size, window=window,
-                                 alibi=alibi_t, k_scale=k_scale, v_scale=v_scale, q_tile=qt,
-                                 kv_splits=ks)
-        except Exception as e:  # pragma: no cover — kernel bring-up safety net
-            from ...utils.logging import warning_once
-
-            warning_once(f"pallas paged attention (q_tile={qt}, kv_splits={ks}) unavailable "
-                         f"({type(e).__name__}: {e}); trying next rung")
-    return paged_attention_reference(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size,
-                                     window=window, alibi=alibi, k_scale=k_scale, v_scale=v_scale)
+    # ONE grid, chosen above from the shape; a grid the chip refuses RAISES
+    # (here at trace/lowering, or at the enclosing jit's compile) — it is
+    # never downgraded to another grid or to the gather below, which would
+    # turn a broken kernel into a slow server instead of an error. The gather
+    # is the tests' reference, the off-TPU path and the (warned) path for the
+    # shapes the kernel does not support, nothing else.
+    return _pallas_paged(q, k_pool, v_pool, block_tables, seq_idx.astype(jnp.int32),
+                         pos.astype(jnp.int32), block_size=block_size, window=window,
+                         alibi=alibi_t, k_scale=k_scale, v_scale=v_scale, q_tile=int(q_tile),
+                         kv_splits=kv_splits)
 
 
 def paged_attention_reference(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: int,
@@ -388,7 +382,8 @@ def _pallas_paged(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: int
         ],
     )
     return pl.pallas_call(kernel, grid_spec=grid_spec, out_shape=jax.ShapeDtypeStruct((T, nq, d), q.dtype),
-                          interpret=interpret)(seq_idx, pos, block_tables, *operands)
+                          interpret=interpret, name="paged_attn_per_token")(
+                              seq_idx, pos, block_tables, *operands)
 
 
 def _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
@@ -432,15 +427,19 @@ def _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
     tile_max = jnp.max(pos_t, axis=1)                                # -1 for empty tiles
     tile_min = jnp.min(jnp.where(valid, pos_t, jnp.int32(2**30)), axis=1)
 
-    # head-major tile layout [n_tiles, nq, qt, d]: the kernel's row view
-    # (nq*qt, d) then keeps each kv-head's g*qt query rows contiguous
-    q_t = q[tile_tok.reshape(-1)].reshape(n_tiles, qt, nq, d).transpose(0, 2, 1, 3)
-
+    # head-major row layout [n_tiles, R, d], row r = h*qt + t: each kv-head's
+    # g*qt query rows are contiguous. The rows (and their positions, as an
+    # [R, 1] column) are laid out HERE, by XLA, so every block's last two
+    # dims are (8, 128)-aligned or whole and the kernel never reshapes
+    # across the sublane/lane boundary.
     R = nq * qt
+    q_t = q[tile_tok.reshape(-1)].reshape(n_tiles, qt, nq, d).transpose(0, 2, 1, 3) \
+        .reshape(n_tiles, R, d)
+    pos_rows = jnp.broadcast_to(pos_t[:, None, :], (n_tiles, nq, qt)).reshape(n_tiles, R, 1)
     grid = (n_tiles, max_blocks)
 
     def q_map(i, j, seq_ref, max_ref, min_ref, bt_ref):
-        return (i, 0, 0, 0)
+        return (i, 0, 0)
 
     def kv_map(i, j, seq_ref, max_ref, min_ref, bt_ref):
         # clamp j into the tile's live range (same Mosaic idiom as the
@@ -451,9 +450,6 @@ def _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
             lo = jnp.maximum(jnp.maximum(min_ref[i], 0) - (window - 1), 0) // block_size
             jj = jnp.maximum(jj, jnp.minimum(lo, hi))
         return (bt_ref[seq_ref[i], jj], 0, 0, 0)
-
-    def pos_map(i, j, seq_ref, max_ref, min_ref, bt_ref):
-        return (i, 0)
 
     def scale_map(i, j, seq_ref, max_ref, min_ref, bt_ref):
         return (0, kv_map(i, j, seq_ref, max_ref, min_ref, bt_ref)[0])
@@ -480,7 +476,7 @@ def _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
 
         @pl.when(in_window)
         def _compute():
-            qr = q_ref[0].astype(jnp.float32).reshape(R, d) * scale  # rows r = h*qt + t
+            qr = q_ref[0].astype(jnp.float32) * scale  # [R, d], rows r = h*qt + t
             kb = k_ref[0].astype(jnp.float32)  # [bs, nkv, d]
             vb = v_ref[0].astype(jnp.float32)
             if quant:  # dequant at the VMEM tile — HBM only streamed int8
@@ -490,8 +486,7 @@ def _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
             for n in range(nkv):
                 s_heads.append(jax.lax.dot(qr[n * g * qt:(n + 1) * g * qt], kb[:, n, :].T))
             s = jnp.concatenate(s_heads, axis=0)  # [R, bs]
-            pos_vec = pos_ref[0]                  # [qt]; -1 on invalid slots
-            my_pos = jnp.broadcast_to(pos_vec[None, :], (nq, qt)).reshape(R, 1)
+            my_pos = pos_ref[0]                   # [R, 1]; -1 on invalid slots
             kpos = j * block_size + jax.lax.broadcasted_iota(jnp.int32, (R, block_size), 1)
             if alibi is not None:
                 s = s + _slopes_rows(alibi, qt) * (kpos - my_pos).astype(jnp.float32)
@@ -512,16 +507,15 @@ def _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
 
         @pl.when(j == max_blocks - 1)
         def _finalize():
-            out = acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
-            o_ref[0] = out.reshape(nq, qt, d).astype(o_ref.dtype)
+            o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)).astype(o_ref.dtype)
 
     in_specs = [
-        pl.BlockSpec((1, nq, qt, d), q_map),
+        pl.BlockSpec((1, R, d), q_map),
         pl.BlockSpec((1, block_size, nkv, d), kv_map),
         pl.BlockSpec((1, block_size, nkv, d), kv_map),
-        pl.BlockSpec((1, qt), pos_map),
+        pl.BlockSpec((1, R, 1), q_map),
     ]
-    operands = [q_t, k4, v4, pos_t]
+    operands = [q_t, k4, v4, pos_rows]
     if quant:
         in_specs += [pl.BlockSpec((nkv, block_size), scale_map),
                      pl.BlockSpec((nkv, block_size), scale_map)]
@@ -531,7 +525,7 @@ def _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
         num_scalar_prefetch=4,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, nq, qt, d), q_map),
+        out_specs=pl.BlockSpec((1, R, d), q_map),
         scratch_shapes=[
             pltpu.VMEM((R, d), jnp.float32),
             pltpu.VMEM((R, 1), jnp.float32),
@@ -539,11 +533,11 @@ def _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
         ],
     )
     out_t = pl.pallas_call(kernel, grid_spec=grid_spec,
-                           out_shape=jax.ShapeDtypeStruct((n_tiles, nq, qt, d), q.dtype),
-                           interpret=interpret)(tile_seq, tile_max, tile_min, block_tables,
-                                                *operands)
+                           out_shape=jax.ShapeDtypeStruct((n_tiles, R, d), q.dtype),
+                           interpret=interpret, name="paged_attn_q_tiled")(
+                               tile_seq, tile_max, tile_min, block_tables, *operands)
     # scatter tiles back to token order
-    flat = out_t.transpose(0, 2, 1, 3).reshape(n_tiles * qt, nq, d)
+    flat = out_t.reshape(n_tiles, nq, qt, d).transpose(0, 2, 1, 3).reshape(n_tiles * qt, nq, d)
     return flat[tile_id * qt + slot]
 
 
@@ -688,24 +682,17 @@ def _paged_kv_split(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
     if not interpret:
         # the split axis is the parallelism the kernel exists for: declare
         # it so Mosaic may distribute independent chains across megacores
-        kwargs["compiler_params"] = _parallel_params(pltpu, ("parallel", "arbitrary",
-                                                            "arbitrary"))
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"))
     acc, m, l = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((ks_n, T, nq, d), jnp.float32),
                    jax.ShapeDtypeStruct((ks_n, T, nq, 1), jnp.float32),
                    jax.ShapeDtypeStruct((ks_n, T, nq, 1), jnp.float32)],
-        interpret=interpret, **kwargs)(seq_idx, pos, block_tables, *operands)
+        interpret=interpret, name="paged_attn_kv_split", **kwargs)(
+            seq_idx, pos, block_tables, *operands)
     # log-sum-exp merge over splits (the flash-decode combine)
     m_star = jnp.max(m, axis=0, keepdims=True)
     w = jnp.exp(m - m_star)  # dead splits: exp(-1e30 - m*) == 0
     out = jnp.sum(acc * w, axis=0) / jnp.maximum(jnp.sum(l * w, axis=0), 1e-30)
     return out.astype(q.dtype)
-
-
-def _parallel_params(pltpu, semantics):
-    """``dimension_semantics`` across jax versions (CompilerParams vs the
-    older TPUCompilerParams spelling); None when neither exists — the call
-    then simply compiles without the megacore hint."""
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(pltpu, "TPUCompilerParams", None)
-    return cls(dimension_semantics=tuple(semantics)) if cls is not None else None

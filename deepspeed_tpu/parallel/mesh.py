@@ -25,26 +25,12 @@ import jax
 from jax.sharding import Mesh, PartitionSpec, NamedSharding
 
 def shard_map_compat(fn, mesh, in_specs, out_specs, check=False, axis_names=None):
-    """``shard_map`` across jax versions: the top-level API
-    (``jax.shard_map``: ``check_vma`` / ``axis_names`` = the MANUAL axes)
-    when present, else ``jax.experimental.shard_map.shard_map``
-    (``check_rep`` / ``auto`` = the complement: axes left automatic)."""
-    if hasattr(jax, "shard_map"):
-        kw = {"check_vma": check}
-        if axis_names is not None:
-            kw["axis_names"] = frozenset(axis_names)
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    if axis_names is not None:
-        # partial-manual has no working equivalent on the old API:
-        # ``auto=<complement>`` lowers with a PartitionId instruction the SPMD
-        # partitioner rejects, and fully-manual conflicts with the body's
-        # GSPMD sharding constraints — fail fast with the real reason
-        raise NotImplementedError(
-            "partial-manual shard_map (axis_names=...) requires the jax.shard_map API; "
-            "this jax build only ships the fully-manual experimental shard_map")
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check)
+    """``jax.shard_map`` with this package's defaults: replication checking
+    off, and ``axis_names`` (the MANUAL axes of a partial-manual map: 1F1B
+    pipeline, ZeRO++ hpZ) passed only when given."""
+    kw = {"axis_names": frozenset(axis_names)} if axis_names is not None else {}
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=check, **kw)
 
 
 DATA_AXIS = "data"

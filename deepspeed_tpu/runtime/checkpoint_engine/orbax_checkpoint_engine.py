@@ -47,10 +47,10 @@ class OrbaxCheckpointEngine(CheckpointEngine):
         try:
             if arrays:
                 self._ckptr.save(os.path.join(path, "arrays"), arrays, force=True)
-                if not self._async and hasattr(self._ckptr, "wait_until_finished"):
-                    # StandardCheckpointer finalizes in a background thread since
-                    # orbax 0.11 — a synchronous save contract must block here,
-                    # else an immediate offline read sees arrays.orbax-checkpoint-tmp
+                if not self._async:
+                    # StandardCheckpointer finalizes in a background thread —
+                    # a synchronous save contract must block here, else an
+                    # immediate offline read sees arrays.orbax-checkpoint-tmp
                     self._ckptr.wait_until_finished()
             if jax.process_index() == 0:
                 os.makedirs(path, exist_ok=True)
@@ -96,9 +96,8 @@ class OrbaxCheckpointEngine(CheckpointEngine):
         else:
             try:
                 if template is not None:
-                    # partial restore, emulated against the on-disk metadata
-                    # (orbax < 0.11 has no partial_restore kwarg and rejects
-                    # any item tree that is not the exact saved structure):
+                    # partial restore against the on-disk metadata (a
+                    # PyTreeRestore item must be the exact saved structure):
                     # template∩disk restores through the template's
                     # ShapeDtypeStructs (sharded placement), disk-only
                     # subtrees restore as host numpy, template-only subtrees
@@ -107,8 +106,9 @@ class OrbaxCheckpointEngine(CheckpointEngine):
                     # checkpoint loaded into an offload engine)
                     arr_template, _ = _split_state(template)
                     with self._ocp.Checkpointer(self._ocp.PyTreeCheckpointHandler()) as ckptr:
-                        item, restore_args = self._merge_item(ckptr.metadata(arrays_path),
-                                                             arr_template)
+                        # StepMetadata -> TreeMetadata -> {key: subtree | ArrayMetadata}
+                        saved = ckptr.metadata(arrays_path).item_metadata.tree
+                        item, restore_args = self._merge_item(saved, arr_template)
                         arrays = ckptr.restore(
                             arrays_path,
                             args=self._ocp.args.PyTreeRestore(item=item, restore_args=restore_args))

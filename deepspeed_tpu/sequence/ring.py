@@ -36,14 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # older jax: experimental API; rep-checking there rejects
-    import functools as _functools
-
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    shard_map = _functools.partial(_shard_map, check_rep=False)
+from jax import shard_map
 
 from ..parallel.mesh import SEQ_AXIS, BATCH_AXES, MODEL_AXIS
 

@@ -31,12 +31,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-if __name__ == "__main__":
-    jax.config.update("jax_platforms", "cpu")
-    from jax._src import xla_bridge
-
-    xla_bridge._clear_backends()
-
 import deepspeed_tpu
 from deepspeed_tpu.models import TransformerConfig, TransformerLM
 from deepspeed_tpu.parallel import groups

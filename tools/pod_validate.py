@@ -62,11 +62,6 @@ def validate_one(name, size, mesh_axes, stage, micro, gas, seq, extra, do_compil
     import jax.numpy as jnp
     import numpy as np
 
-    jax.config.update("jax_platforms", "cpu")
-    from jax._src import xla_bridge
-
-    xla_bridge._clear_backends()
-
     import deepspeed_tpu
     from deepspeed_tpu.models.llama import llama2_config
     from deepspeed_tpu.models import TransformerLM
