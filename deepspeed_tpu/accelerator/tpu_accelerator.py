@@ -26,6 +26,7 @@ class TPU_Accelerator(DeepSpeedAccelerator):
         self._current_device_index = 0
         self._seed = 0
         self._rng_key = jax.random.PRNGKey(0)
+        self._fence = None  # (jitted trivial computation, one operand per local device)
 
     # ---- Device APIs ----
     def is_synchronized_device(self):
@@ -64,6 +65,16 @@ class TPU_Accelerator(DeepSpeedAccelerator):
         return len(self._devices())
 
     def synchronize(self, device_index=None):
+        """Wait until everything already enqueued on the local devices has
+        run. A device runs its programs in the order they were enqueued, so
+        the wait is for one trivial computation enqueued now on each of them
+        (``jax.effects_barrier()`` alone waits only for computations with
+        side effects, and returns while ordinary ones are still queued)."""
+        if self._fence is None:
+            self._fence = (jax.jit(lambda x: x + 1),
+                           [jax.device_put(np.int32(0), d) for d in self._local_devices()])
+        fn, operands = self._fence
+        jax.block_until_ready([fn(x) for x in operands])
         jax.effects_barrier()
 
     # ---- RNG APIs ----

@@ -23,8 +23,9 @@ from ...monitor.health import get_health
 from ...monitor.memory import get_memory, tree_device_bytes
 from ...monitor.metrics import get_metrics
 from ...monitor.roofline import get_roofline
-from ...monitor.trace import (get_tracer, observe_latency, pop_compile_source,
+from ...monitor.trace import (NULL_SPAN, get_tracer, pop_compile_source,
                               push_compile_source)
+from ...ops.pallas.paged_attention import kernel_choice
 from ...utils.logging import log_dist
 from .config_v2 import RaggedInferenceEngineConfig
 from .model_implementations.flat_model import ragged_forward
@@ -112,6 +113,7 @@ class InferenceEngineV2:
             max_blocks_per_seq=self._max_blocks_per_seq, block_size=bs)
 
         self._compiled: Dict[Tuple[int, int, Optional[str]], object] = {}
+        self._kernel_labels: Dict[Tuple[int, int], str] = {}  # (tokens, rows) -> span label, see _kernel_of
         # speculative-decoding lifetime totals (two int adds per verify
         # step; the gauge feeding off them only updates when metrics are on)
         self._spec_totals = {"drafted": 0, "accepted": 0}
@@ -325,8 +327,9 @@ class InferenceEngineV2:
 
     @_serving_compile_scope
     def _put(self, batch_uids, batch_tokens, do_checks, sample, block, sampling=None):
-        observing = get_tracer().enabled or get_metrics().enabled
-        t0 = time.perf_counter() if observing else 0.0
+        tr = get_tracer()
+        reg = get_metrics()
+        t0 = time.perf_counter() if reg.enabled else 0.0
         rf = get_roofline()
         t_rf = time.perf_counter() if rf.enabled else 0.0
         batch_tokens = [np.asarray(t, np.int32).reshape(-1) for t in batch_tokens]
@@ -336,86 +339,117 @@ class InferenceEngineV2:
             raise ValueError("put(): zero-length token chunk "
                              f"(uids {[u for u, t in zip(batch_uids, batch_tokens) if t.size == 0]})")
         # classify prefill vs decode from the PRE-trim sizes: a cache hit can
-        # trim a repeat prompt down to one token, but its latency is still a
-        # TTFT sample (and the hit is exactly what makes it worth recording)
+        # trim a repeat prompt down to one token, but it is still a prefill
+        # step (and the hit is exactly what makes it worth recording)
         had_prefill = any(t.size > 1 for t in batch_tokens)
-        if do_checks:
-            result = self.can_schedule(batch_uids, [t.size for t in batch_tokens])
-            if result is not SchedulingResult.Success:
-                raise SchedulingError(result)
+        # span name as a two-literal conditional so check_goodput_taxonomy
+        # can map both
+        with tr.span("serving/prefill" if had_prefill else "serving/decode_step",
+                     tid="serving") as sp:
+            with tr.span("serving/engine_batch", tid="serving"):
+                if do_checks:
+                    result = self.can_schedule(batch_uids, [t.size for t in batch_tokens])
+                    if result is not SchedulingResult.Success:
+                        raise SchedulingError(result)
 
-        self.batch.clear()
-        descs = []
-        for i, (uid, toks) in enumerate(zip(batch_uids, batch_tokens)):
-            seq = self.state_manager.get_sequence(uid)
-            if seq is None:
-                # cache-hit prefill path: a new sequence's first chunk is
-                # matched against the radix tree; the hit's blocks arrive
-                # shared (seen_tokens pre-seeded) and only the uncached
-                # suffix is actually fed/computed
-                seq, skip = self._create_with_prefix(uid, toks)
-                if skip:
-                    toks = batch_tokens[i] = toks[skip:]
-            self.state_manager.note_tokens(seq, toks)
-            self.state_manager.allocate_blocks(seq, toks.size)
-            seq.pre_forward(toks.size)
-            self.batch.insert_sequence(seq, toks)
-            descs.append(seq)
-        rb = self.batch.finalize()
+                self.batch.clear()
+                descs = []
+                for i, (uid, toks) in enumerate(zip(batch_uids, batch_tokens)):
+                    seq = self.state_manager.get_sequence(uid)
+                    if seq is None:
+                        # cache-hit prefill path: a new sequence's first chunk is
+                        # matched against the radix tree; the hit's blocks arrive
+                        # shared (seen_tokens pre-seeded) and only the uncached
+                        # suffix is actually fed/computed
+                        seq, skip = self._create_with_prefix(uid, toks)
+                        if skip:
+                            toks = batch_tokens[i] = toks[skip:]
+                    self.state_manager.note_tokens(seq, toks)
+                    self.state_manager.allocate_blocks(seq, toks.size)
+                    seq.pre_forward(toks.size)
+                    self.batch.insert_sequence(seq, toks)
+                    descs.append(seq)
+                rb = self.batch.finalize()
+            t_bucket, s_bucket = rb.token_ids.shape[0], rb.block_tables.shape[0]
 
-        from .sampling import all_greedy, pack_sampling
+            from .sampling import all_greedy, pack_sampling
 
-        kv = self.state_manager.kv_cache
-        if sampling is not None and not all_greedy(sampling):
-            if sample is None:
-                # sample=None means "give me logits" — silently returning
-                # sampled token ids instead would hand a logits consumer an
-                # int32 vector
-                raise ValueError("put(sample=None) returns logits; pass sample='greedy' "
-                                 "with a sampling list to draw tokens on device")
-            # sampled rows draw on device (greedy rows argmax via temp 0);
-            # sample='greedy' callers without sampling keep the original
-            # compiled program byte-for-byte
-            mode = "sample"
-            fn = self._get_compiled(rb.token_ids.shape[0], rb.block_tables.shape[0],
-                                    "sample")
-            samp_f, seeds = pack_sampling(sampling, batch_uids, rb.block_tables.shape[0])
-            out, pools = fn(self.params, jnp.asarray(rb.packed()), jnp.asarray(samp_f),
-                            jnp.asarray(seeds), kv.pools())
-        else:
-            mode = sample
-            fn = self._get_compiled(rb.token_ids.shape[0], rb.block_tables.shape[0], sample)
-            # ONE descriptor upload per forward (reference single pinned-buffer
-            # upload) instead of one host-to-device transfer per array
-            out, pools = fn(self.params, jnp.asarray(rb.packed()), kv.pools())
-        kv.update(*pools)
-        for seq in descs:
-            seq.post_forward()
-            self.state_manager.publish_sequence(seq)  # completed full blocks → tree
-        out = out[:rb.n_seqs]  # slice ON DEVICE: the host fetch moves
-        out = out if not block else np.asarray(out)  # n_seqs rows, not the padded bucket
+            kv = self.state_manager.kv_cache
+            with tr.span("serving/engine_dispatch", tid="serving") as sd:
+                n_programs = len(self._compiled)
+                if sampling is not None and not all_greedy(sampling):
+                    if sample is None:
+                        # sample=None means "give me logits" — silently returning
+                        # sampled token ids instead would hand a logits consumer an
+                        # int32 vector
+                        raise ValueError("put(sample=None) returns logits; pass sample='greedy' "
+                                         "with a sampling list to draw tokens on device")
+                    # sampled rows draw on device (greedy rows argmax via temp 0);
+                    # sample='greedy' callers without sampling keep the original
+                    # compiled program byte-for-byte
+                    mode = "sample"
+                    fn = self._get_compiled(t_bucket, s_bucket, "sample")
+                    samp_f, seeds = pack_sampling(sampling, batch_uids, s_bucket)
+                    out, pools = fn(self.params, jnp.asarray(rb.packed()), jnp.asarray(samp_f),
+                                    jnp.asarray(seeds), kv.pools())
+                else:
+                    mode = sample
+                    fn = self._get_compiled(t_bucket, s_bucket, sample)
+                    # ONE descriptor upload per forward (reference single pinned-buffer
+                    # upload) instead of one host-to-device transfer per array
+                    out, pools = fn(self.params, jnp.asarray(rb.packed()), kv.pools())
+                kv.update(*pools)
+                if sd is not NULL_SPAN:
+                    sd.set_args(compiled=len(self._compiled) > n_programs)
+            with tr.span("serving/engine_commit", tid="serving"):
+                for seq in descs:
+                    seq.post_forward()
+                    self.state_manager.publish_sequence(seq)  # completed full blocks → tree
+            with tr.span("serving/engine_fetch", tid="serving"):
+                out = out[:rb.n_seqs]  # slice ON DEVICE: the host fetch moves
+                out = out if not block else np.asarray(out)  # n_seqs rows, not the padded bucket
+            if sp is not NULL_SPAN:
+                # counts at the boundary, and the uids so that a request-scoped
+                # trace can attribute every engine forward to the requests
+                # composing it (capped: span args are payload, not a table)
+                sizes = [int(t.size) for t in batch_tokens]
+                sp.set_args(seqs=len(batch_uids), rows=len(batch_uids),
+                            rows_decode=sum(1 for n in sizes if n == 1), tokens=sum(sizes),
+                            bucket_tokens=int(t_bucket), bucket_rows=int(s_bucket), steps=1,
+                            kernel=self._kernel_of(t_bucket, s_bucket),
+                            uids=[int(u) for u in batch_uids[:16]], blocked=bool(block))
         if rf.enabled and block:
             # wall join through the blocking host fetch — the same window the
             # outer put() books as prefill/decode-active in the goodput ledger,
             # so the roofline and goodput accountings reconcile
-            rf.note_wall(f"put/t{rb.token_ids.shape[0]}/s{rb.block_tables.shape[0]}"
-                         f"/{mode or 'logits'}", time.perf_counter() - t_rf)
-        if observing:
-            # prefill (multi-token chunks) latency IS TTFT when block=True
-            # (admission -> first token on host, the FastGen definition);
-            # block=False measures only async dispatch, so no latency sample
-            hist = ("serving/ttft_ms" if had_prefill else "serving/decode_step_ms") if block else None
-            # uids ride the span so a request-scoped trace can attribute
-            # every engine forward to the requests composing it (capped:
-            # span args are JSONL payload, not a table); span name as a
-            # two-literal conditional so check_goodput_taxonomy can map both
-            observe_latency(t0, "serving/prefill" if had_prefill else "serving/decode_step",
-                            hist_name=hist,
-                            span_args={"seqs": len(batch_uids),
-                                       "tokens": int(sum(t.size for t in batch_tokens)),
-                                       "uids": [int(u) for u in batch_uids[:16]],
-                                       "blocked": bool(block)})
+            rf.note_wall(f"put/t{t_bucket}/s{s_bucket}/{mode or 'logits'}",
+                         time.perf_counter() - t_rf)
+        if reg.enabled and block:
+            # a STEP's latency, not a time to first token (the operator's TTFT
+            # is gateway/ttft_ms_<class>, from admission); block=False measures
+            # only the async dispatch, so no latency sample
+            dt_ms = (time.perf_counter() - t0) * 1e3
+            if had_prefill:
+                reg.histogram("serving/prefill_step_ms").observe(dt_ms)
+            else:
+                reg.histogram("serving/decode_step_ms").observe(dt_ms)
         return out
+
+    def _kernel_of(self, T: int, S: int) -> str:
+        """``<kernel>:<q_tile or kv_splits>:<rule>`` of the paged-attention
+        call inside the compiled program of ``T`` tokens and ``S`` rows,
+        looked up once per shape in the table ``paged_attention`` fills while
+        ``jit`` traces it (so only after the program's first call). Empty
+        while nothing recorded a choice for the shape (an attention module
+        that is not the paged kernel's)."""
+        label = self._kernel_labels.get((T, S))
+        if label is None:
+            choice = kernel_choice(T, S, self._max_blocks_per_seq)
+            if choice is None:
+                return ""
+            label = self._kernel_labels[(T, S)] = "%s:%d:%s" % (
+                choice["kernel"], max(choice["q_tile"], choice["kv_splits"]), choice["rule"])
+        return label
 
     # ------------------------------------------------------------------
     def decode(self, batch_uids: List[int], first_tokens, n_steps: int, block: bool = True,
@@ -468,112 +502,125 @@ class InferenceEngineV2:
     @_serving_compile_scope
     def _decode(self, batch_uids, first_tokens, n_steps, block, eos_token_ids=None,
                 sampling=None):
-        observing = get_tracer().enabled or get_metrics().enabled
-        t0 = time.perf_counter() if observing else 0.0
+        tr = get_tracer()
+        reg = get_metrics()
+        t0 = time.perf_counter() if reg.enabled else 0.0
         rf = get_roofline()
         t_rf = time.perf_counter() if rf.enabled else 0.0
         uids = list(batch_uids)
         S = len(uids)
-        if len(set(uids)) != len(uids):
-            # same corruption mode put()'s admission rejects: two rows of one
-            # uid would write divergent KV at the same positions
-            raise SchedulingError(SchedulingResult.BatchSequenceLimitExceeded)
-        if S > self.batch.max_seqs:
-            # must reject BEFORE allocate/pre_forward: a mid-loop wrapper
-            # ValueError would strand in-flight state on every sequence
-            raise SchedulingError(SchedulingResult.BatchSequenceLimitExceeded)
-        first = [np.asarray(t, np.int32).reshape(-1) for t in first_tokens]
-        assert all(t.size == 1 for t in first), "decode() takes exactly one next token per sequence"
-        seqs = []
-        for uid in uids:
-            seq = self.state_manager.get_sequence(uid)
-            if seq is None:
-                raise SchedulingError(SchedulingResult.EngineSequenceLimitExceeded)
-            if seq.seen_tokens + n_steps > self._max_context:
-                raise SchedulingError(SchedulingResult.KVCacheLimitExceeded)
-            seqs.append(seq)
-        blocks_needed = sum(s.blocks_needed(n_steps) for s in seqs)
-        if blocks_needed > self.state_manager.available_blocks:
-            raise SchedulingError(SchedulingResult.KVCacheLimitExceeded)
-        if not hasattr(self, "_decode_batch"):
-            # the scan packs exactly one token per sequence, so its wrapper
-            # uses the SAME bucket table for tokens and sequences
-            self._decode_batch = RaggedBatchWrapper(
-                max_ragged_batch_size=self.batch.max_seqs,
-                max_ragged_sequence_count=self.batch.max_seqs,
-                max_blocks_per_seq=self._max_blocks_per_seq, block_size=self.config.kv_block_size,
-                token_buckets=self.batch.seq_buckets, seq_buckets=self.batch.seq_buckets)
-        for seq, toks in zip(seqs, first):
-            self.state_manager.allocate_blocks(seq, n_steps)
-            seq.pre_forward(n_steps)
+        with tr.span("serving/decode", tid="serving") as sp:
+            with tr.span("serving/engine_batch", tid="serving"):
+                if len(set(uids)) != len(uids):
+                    # same corruption mode put()'s admission rejects: two rows of one
+                    # uid would write divergent KV at the same positions
+                    raise SchedulingError(SchedulingResult.BatchSequenceLimitExceeded)
+                if S > self.batch.max_seqs:
+                    # must reject BEFORE allocate/pre_forward: a mid-loop wrapper
+                    # ValueError would strand in-flight state on every sequence
+                    raise SchedulingError(SchedulingResult.BatchSequenceLimitExceeded)
+                first = [np.asarray(t, np.int32).reshape(-1) for t in first_tokens]
+                assert all(t.size == 1 for t in first), \
+                    "decode() takes exactly one next token per sequence"
+                seqs = []
+                for uid in uids:
+                    seq = self.state_manager.get_sequence(uid)
+                    if seq is None:
+                        raise SchedulingError(SchedulingResult.EngineSequenceLimitExceeded)
+                    if seq.seen_tokens + n_steps > self._max_context:
+                        raise SchedulingError(SchedulingResult.KVCacheLimitExceeded)
+                    seqs.append(seq)
+                blocks_needed = sum(s.blocks_needed(n_steps) for s in seqs)
+                if blocks_needed > self.state_manager.available_blocks:
+                    raise SchedulingError(SchedulingResult.KVCacheLimitExceeded)
+                if not hasattr(self, "_decode_batch"):
+                    # the scan packs exactly one token per sequence, so its wrapper
+                    # uses the SAME bucket table for tokens and sequences
+                    self._decode_batch = RaggedBatchWrapper(
+                        max_ragged_batch_size=self.batch.max_seqs,
+                        max_ragged_sequence_count=self.batch.max_seqs,
+                        max_blocks_per_seq=self._max_blocks_per_seq,
+                        block_size=self.config.kv_block_size,
+                        token_buckets=self.batch.seq_buckets, seq_buckets=self.batch.seq_buckets)
+                for seq, toks in zip(seqs, first):
+                    self.state_manager.allocate_blocks(seq, n_steps)
+                    seq.pre_forward(n_steps)
 
-        self._decode_batch.clear()
-        for seq, toks in zip(seqs, first):
-            # tables now cover the full horizon; positions advance in-scan
-            self._decode_batch.insert_sequence(seq, toks)
-        rb = self._decode_batch.finalize()
+                self._decode_batch.clear()
+                for seq, toks in zip(seqs, first):
+                    # tables now cover the full horizon; positions advance in-scan
+                    self._decode_batch.insert_sequence(seq, toks)
+                rb = self._decode_batch.finalize()
 
-        from .sampling import all_greedy, pack_sampling
+            from .sampling import all_greedy, pack_sampling
 
-        kv = self.state_manager.kv_cache
-        s_bucket = rb.token_ids.shape[0]
-        rf_sampled = sampling is not None and not all_greedy(sampling)
-        rf_bucket = f"decode/s{s_bucket}/n{n_steps}{'/sampled' if rf_sampled else ''}"
-        if rf_sampled:
-            fn = self._get_compiled_decode(s_bucket, n_steps, sampled=True)
-            samp_f, seeds = pack_sampling(sampling, uids, s_bucket)
-            toks, pools = fn(self.params, jnp.asarray(rb.packed()), jnp.asarray(samp_f),
-                             jnp.asarray(seeds), kv.pools())
-        else:
-            fn = self._get_compiled_decode(s_bucket, n_steps)
-            # start positions already ride inside packed() (each decode row
-            # is one token at its position) — no separate seq_start_len upload
-            toks, pools = fn(self.params, jnp.asarray(rb.packed()), kv.pools())
-        kv.update(*pools)
-        toks = toks[:S]  # on-device slice before any host fetch
-        pc = self.state_manager.prefix_cache
-        if block:
-            toks = np.asarray(toks)
-            if eos_token_ids is None or isinstance(eos_token_ids, (int, np.integer)):
-                eos_list = [eos_token_ids] * S
-            else:
-                eos_list = list(eos_token_ids)
-                assert len(eos_list) == S, "eos_token_ids must match batch_uids"
-            for seq, f, row, eos in zip(seqs, first, toks, eos_list):
-                start = seq.seen_tokens
-                if pc is not None:
-                    # tokens materialized this burst: the fed first token
-                    # plus every in-scan feedback token except the last
-                    # output (whose KV is not written until it is fed back)
-                    self.state_manager.note_tokens(seq, np.concatenate([f, row[:-1]]))
-                seq.post_forward()
-                if eos is not None:
-                    hit = np.nonzero(row == eos)[0]
-                    if hit.size and int(hit[0]) + 1 < n_steps:
-                        # horizon overshoot: the caller keeps row[:hit+1];
-                        # KV/history past the eos is garbage — rewind it
-                        # BEFORE publish so the tree never sees it
-                        self.state_manager.rollback_to(seq, start + 1 + int(hit[0]))
-                self.state_manager.publish_sequence(seq)
-        else:
-            if pc is not None:
-                for seq in seqs:
-                    seq.history_valid = False  # generated ids never reached host
-            for seq in seqs:
-                seq.post_forward()
-                self.state_manager.publish_sequence(seq)
+            kv = self.state_manager.kv_cache
+            s_bucket = rb.token_ids.shape[0]
+            rf_sampled = sampling is not None and not all_greedy(sampling)
+            rf_bucket = f"decode/s{s_bucket}/n{n_steps}{'/sampled' if rf_sampled else ''}"
+            with tr.span("serving/engine_dispatch", tid="serving") as sd:
+                n_programs = len(self._compiled)
+                if rf_sampled:
+                    fn = self._get_compiled_decode(s_bucket, n_steps, sampled=True)
+                    samp_f, seeds = pack_sampling(sampling, uids, s_bucket)
+                    toks, pools = fn(self.params, jnp.asarray(rb.packed()), jnp.asarray(samp_f),
+                                     jnp.asarray(seeds), kv.pools())
+                else:
+                    fn = self._get_compiled_decode(s_bucket, n_steps)
+                    # start positions already ride inside packed() (each decode row
+                    # is one token at its position) — no separate seq_start_len upload
+                    toks, pools = fn(self.params, jnp.asarray(rb.packed()), kv.pools())
+                kv.update(*pools)
+                if sd is not NULL_SPAN:
+                    sd.set_args(compiled=len(self._compiled) > n_programs)
+            with tr.span("serving/engine_fetch", tid="serving"):
+                toks = toks[:S]  # on-device slice before any host fetch
+                if block:
+                    toks = np.asarray(toks)
+            pc = self.state_manager.prefix_cache
+            with tr.span("serving/engine_commit", tid="serving"):
+                if block:
+                    if eos_token_ids is None or isinstance(eos_token_ids, (int, np.integer)):
+                        eos_list = [eos_token_ids] * S
+                    else:
+                        eos_list = list(eos_token_ids)
+                        assert len(eos_list) == S, "eos_token_ids must match batch_uids"
+                    for seq, f, row, eos in zip(seqs, first, toks, eos_list):
+                        start = seq.seen_tokens
+                        if pc is not None:
+                            # tokens materialized this burst: the fed first token
+                            # plus every in-scan feedback token except the last
+                            # output (whose KV is not written until it is fed back)
+                            self.state_manager.note_tokens(seq, np.concatenate([f, row[:-1]]))
+                        seq.post_forward()
+                        if eos is not None:
+                            hit = np.nonzero(row == eos)[0]
+                            if hit.size and int(hit[0]) + 1 < n_steps:
+                                # horizon overshoot: the caller keeps row[:hit+1];
+                                # KV/history past the eos is garbage — rewind it
+                                # BEFORE publish so the tree never sees it
+                                self.state_manager.rollback_to(seq, start + 1 + int(hit[0]))
+                        self.state_manager.publish_sequence(seq)
+                else:
+                    if pc is not None:
+                        for seq in seqs:
+                            seq.history_valid = False  # generated ids never reached host
+                    for seq in seqs:
+                        seq.post_forward()
+                        self.state_manager.publish_sequence(seq)
+            if sp is not NULL_SPAN:
+                # without the host fetch the span is dispatch only: the blocked
+                # flag discloses it
+                sp.set_args(seqs=S, rows=S, tokens=S * int(n_steps), steps=int(n_steps),
+                            bucket_rows=int(s_bucket), bucket_tokens=int(s_bucket),
+                            kernel=self._kernel_of(s_bucket, s_bucket),
+                            uids=[int(u) for u in uids[:16]], blocked=bool(block))
         if rf.enabled and block:
             rf.note_wall(rf_bucket, time.perf_counter() - t_rf)
-        if observing:
-            # as with put(): without the host fetch the wall time is dispatch
-            # only — emit the span (blocked flag disclosed), skip the samples
-            observe_latency(t0, "serving/decode",
-                            hist_name="serving/decode_ms" if block else None,
-                            gauges=({"serving/decode_tokens_per_sec":
-                                     lambda dt: S * n_steps / max(dt, 1e-9)} if block else None),
-                            span_args={"seqs": S, "steps": int(n_steps),
-                                       "uids": [int(u) for u in uids[:16]],
-                                       "blocked": bool(block)})
+        if reg.enabled and block:
+            dt = time.perf_counter() - t0
+            reg.histogram("serving/decode_ms").observe(dt * 1e3)
+            reg.gauge("serving/decode_tokens_per_sec").set(S * n_steps / max(dt, 1e-9))
         return toks
 
     def _ragged_step(self, params, packed, pools, t_bucket, s_bucket, gather_k: int = 0,
@@ -735,190 +782,205 @@ class InferenceEngineV2:
                    sampling=None):
         from .sampling import all_greedy, pack_sampling
 
-        observing = get_tracer().enabled or get_metrics().enabled
-        t0 = time.perf_counter() if observing else 0.0
+        tr = get_tracer()
+        reg = get_metrics()
+        t0 = time.perf_counter() if reg.enabled else 0.0
         rf = get_roofline()
         t_rf = time.perf_counter() if rf.enabled else 0.0
         uids = list(batch_uids)
         S = len(uids)
-        firsts = [np.asarray(t, np.int32).reshape(-1) for t in first_tokens]
-        # normalize drafts to per-sequence branch LISTS (a bare array is one
-        # linear branch — the PR 9 call surface unchanged)
-        branches: List[List[np.ndarray]] = []
-        for d in draft_tokens:
-            bl = [np.asarray(b, np.int32).reshape(-1) for b in d] \
-                if isinstance(d, (list, tuple)) else [np.asarray(d, np.int32).reshape(-1)]
-            branches.append([b for b in bl if b.size])
-        tree = any(len(bl) > 1 for bl in branches)
-        sampled = not all_greedy(sampling)
-        if tree and sampled:
-            raise ValueError("token-tree verification is greedy-only; a sampled request "
-                             "verifies one linear draft via rejection sampling")
-        if k is None:
-            k = max((b.size for bl in branches for b in bl), default=0)
-        k = int(k)
-        if k < 1:
-            raise ValueError("speculate_decode needs k >= 1 (use decode() for plain steps)")
-        assert all(t.size == 1 for t in firsts), \
-            "speculate_decode takes exactly one pending next token per sequence"
-        if any(b.size > k for bl in branches for b in bl):
-            raise ValueError(f"draft longer than k={k}")
-        W = max((len(bl) for bl in branches), default=1) if tree else 1
-        n_new = 1 + W * k  # fed chunk length: root + every (padded) branch
-        if len(set(uids)) != len(uids) or S > self.batch.max_seqs:
-            raise SchedulingError(SchedulingResult.BatchSequenceLimitExceeded)
-        if S * n_new > self.batch.max_tokens:
-            raise SchedulingError(SchedulingResult.TokenLimitExceeded)
-        seqs = []
-        for uid in uids:
-            seq = self.state_manager.get_sequence(uid)
-            if seq is None:
-                raise SchedulingError(SchedulingResult.EngineSequenceLimitExceeded)
-            if seq.seen_tokens + n_new > self._max_context:
-                raise SchedulingError(SchedulingResult.KVCacheLimitExceeded)
-            seqs.append(seq)
-        if sum(s.blocks_needed(n_new) for s in seqs) > self.state_manager.available_blocks:
-            raise SchedulingError(SchedulingResult.KVCacheLimitExceeded)
+        with tr.span("serving/spec_verify", tid="serving") as sp:
+            with tr.span("serving/engine_batch", tid="serving"):
+                firsts = [np.asarray(t, np.int32).reshape(-1) for t in first_tokens]
+                # normalize drafts to per-sequence branch LISTS (a bare array is one
+                # linear branch — the PR 9 call surface unchanged)
+                branches: List[List[np.ndarray]] = []
+                for d in draft_tokens:
+                    bl = [np.asarray(b, np.int32).reshape(-1) for b in d] \
+                        if isinstance(d, (list, tuple)) else [np.asarray(d, np.int32).reshape(-1)]
+                    branches.append([b for b in bl if b.size])
+                tree = any(len(bl) > 1 for bl in branches)
+                sampled = not all_greedy(sampling)
+                if tree and sampled:
+                    raise ValueError("token-tree verification is greedy-only; a sampled request "
+                                     "verifies one linear draft via rejection sampling")
+                if k is None:
+                    k = max((b.size for bl in branches for b in bl), default=0)
+                k = int(k)
+                if k < 1:
+                    raise ValueError("speculate_decode needs k >= 1 (use decode() for plain steps)")
+                assert all(t.size == 1 for t in firsts), \
+                    "speculate_decode takes exactly one pending next token per sequence"
+                if any(b.size > k for bl in branches for b in bl):
+                    raise ValueError(f"draft longer than k={k}")
+                W = max((len(bl) for bl in branches), default=1) if tree else 1
+                n_new = 1 + W * k  # fed chunk length: root + every (padded) branch
+                if len(set(uids)) != len(uids) or S > self.batch.max_seqs:
+                    raise SchedulingError(SchedulingResult.BatchSequenceLimitExceeded)
+                if S * n_new > self.batch.max_tokens:
+                    raise SchedulingError(SchedulingResult.TokenLimitExceeded)
+                seqs = []
+                for uid in uids:
+                    seq = self.state_manager.get_sequence(uid)
+                    if seq is None:
+                        raise SchedulingError(SchedulingResult.EngineSequenceLimitExceeded)
+                    if seq.seen_tokens + n_new > self._max_context:
+                        raise SchedulingError(SchedulingResult.KVCacheLimitExceeded)
+                    seqs.append(seq)
+                if sum(s.blocks_needed(n_new) for s in seqs) > self.state_manager.available_blocks:
+                    raise SchedulingError(SchedulingResult.KVCacheLimitExceeded)
 
-        # uniform chunks; short drafts/branch lists pad by repeating their
-        # last token (branch 0 clones for missing branches): pads ride the
-        # forward like any draft and only ever COMMIT when they equal the
-        # target's own choice, so parity is unconditional
-        chunks, padded = [], []
-        for f, bl in zip(firsts, branches):
-            if tree:
-                bl = list(bl) or [np.full(k, int(f[0]), np.int32)]
-                while len(bl) < W:
-                    bl.append(bl[0])
-                pb = [np.concatenate([b, np.full(k - b.size,
-                                                 int(b[-1]) if b.size else int(f[0]),
-                                                 np.int32)]) for b in bl]
-                padded.append(pb)
-                chunks.append(np.concatenate([f] + pb))
-            else:
-                d = bl[0] if bl else np.empty(0, np.int32)
-                pad = np.full(k - d.size, int(d[-1]) if d.size else int(f[0]), np.int32)
-                padded.append([np.concatenate([d, pad])])
-                chunks.append(np.concatenate([f, d, pad]))
-        starts = [s.seen_tokens for s in seqs]
-        self.batch.clear()
-        for seq, c in zip(seqs, chunks):
-            # note BEFORE the forward, like _put: history mirrors the fed
-            # chunk; commit_speculative/rollback_to reconcile it afterwards
-            self.state_manager.note_tokens(seq, c)
-            self.state_manager.allocate_blocks(seq, n_new)
-            seq.pre_forward(n_new)
-            self.batch.insert_sequence(seq, c)
-        rb = self.batch.finalize()
-        t_bucket, s_bucket = rb.token_ids.shape[0], rb.block_tables.shape[0]
+                # uniform chunks; short drafts/branch lists pad by repeating their
+                # last token (branch 0 clones for missing branches): pads ride the
+                # forward like any draft and only ever COMMIT when they equal the
+                # target's own choice, so parity is unconditional
+                chunks, padded = [], []
+                for f, bl in zip(firsts, branches):
+                    if tree:
+                        bl = list(bl) or [np.full(k, int(f[0]), np.int32)]
+                        while len(bl) < W:
+                            bl.append(bl[0])
+                        pb = [np.concatenate([b, np.full(k - b.size,
+                                                         int(b[-1]) if b.size else int(f[0]),
+                                                         np.int32)]) for b in bl]
+                        padded.append(pb)
+                        chunks.append(np.concatenate([f] + pb))
+                    else:
+                        d = bl[0] if bl else np.empty(0, np.int32)
+                        pad = np.full(k - d.size, int(d[-1]) if d.size else int(f[0]), np.int32)
+                        padded.append([np.concatenate([d, pad])])
+                        chunks.append(np.concatenate([f, d, pad]))
+                starts = [s.seen_tokens for s in seqs]
+                self.batch.clear()
+                for seq, c in zip(seqs, chunks):
+                    # note BEFORE the forward, like _put: history mirrors the fed
+                    # chunk; commit_speculative/rollback_to reconcile it afterwards
+                    self.state_manager.note_tokens(seq, c)
+                    self.state_manager.allocate_blocks(seq, n_new)
+                    seq.pre_forward(n_new)
+                    self.batch.insert_sequence(seq, c)
+                rb = self.batch.finalize()
+                t_bucket, s_bucket = rb.token_ids.shape[0], rb.block_tables.shape[0]
 
-        kv = self.state_manager.kv_cache
-        fn = self._get_compiled_verify(t_bucket, s_bucket, n_new - 1, tree=tree,
-                                       sampled=sampled)
-        if tree:
-            # per-token tree metadata rows [pos_ids | branch | depth]: node
-            # 0 is the shared root at depth 0; branch b's nodes carry depth
-            # 1..k and LOGICAL position start + depth (their KV slots stay
-            # flat — the mask in _ragged_step keeps siblings invisible)
-            meta = np.zeros((3, t_bucket), np.int32)
-            depth_row = np.concatenate([[0]] + [np.arange(1, k + 1)] * W).astype(np.int32)
-            branch_row = np.concatenate([[0]] + [np.full(k, b) for b in range(W)]).astype(np.int32)
-            cur = 0
-            for start in starts:
-                meta[0, cur:cur + n_new] = start + depth_row
-                meta[1, cur:cur + n_new] = branch_row
-                meta[2, cur:cur + n_new] = depth_row
-                cur += n_new
-            out, pools = fn(self.params, jnp.asarray(rb.packed()),
-                            jnp.asarray(meta.reshape(-1)), kv.pools())
-        elif sampled:
-            samp_f, seeds = pack_sampling(sampling, uids, s_bucket)
-            out, pools = fn(self.params, jnp.asarray(rb.packed()),
-                            jnp.asarray(samp_f), jnp.asarray(seeds), kv.pools())
-        else:
-            out, pools = fn(self.params, jnp.asarray(rb.packed()), kv.pools())
-        kv.update(*pools)
+            kv = self.state_manager.kv_cache
+            with tr.span("serving/engine_dispatch", tid="serving") as sd:
+                n_programs = len(self._compiled)
+                fn = self._get_compiled_verify(t_bucket, s_bucket, n_new - 1, tree=tree,
+                                               sampled=sampled)
+                if tree:
+                    # per-token tree metadata rows [pos_ids | branch | depth]: node
+                    # 0 is the shared root at depth 0; branch b's nodes carry depth
+                    # 1..k and LOGICAL position start + depth (their KV slots stay
+                    # flat — the mask in _ragged_step keeps siblings invisible)
+                    meta = np.zeros((3, t_bucket), np.int32)
+                    depth_row = np.concatenate([[0]] + [np.arange(1, k + 1)] * W).astype(np.int32)
+                    branch_row = np.concatenate([[0]] + [np.full(k, b) for b in range(W)]).astype(np.int32)
+                    cur = 0
+                    for start in starts:
+                        meta[0, cur:cur + n_new] = start + depth_row
+                        meta[1, cur:cur + n_new] = branch_row
+                        meta[2, cur:cur + n_new] = depth_row
+                        cur += n_new
+                    out, pools = fn(self.params, jnp.asarray(rb.packed()),
+                                    jnp.asarray(meta.reshape(-1)), kv.pools())
+                elif sampled:
+                    samp_f, seeds = pack_sampling(sampling, uids, s_bucket)
+                    out, pools = fn(self.params, jnp.asarray(rb.packed()),
+                                    jnp.asarray(samp_f), jnp.asarray(seeds), kv.pools())
+                else:
+                    out, pools = fn(self.params, jnp.asarray(rb.packed()), kv.pools())
+                kv.update(*pools)
+                if sd is not NULL_SPAN:
+                    sd.set_args(compiled=len(self._compiled) > n_programs)
 
-        if eos_token_ids is None or isinstance(eos_token_ids, (int, np.integer)):
-            eos_list = [eos_token_ids] * S
-        else:
-            eos_list = list(eos_token_ids)
-            assert len(eos_list) == S, "eos_token_ids must match batch_uids"
-        results = []
-        drafted = accepted = 0
-        accepts = []
-        if sampled:
-            acc_m = np.asarray(out[0][:S]).astype(bool)  # [S, k] accept bits
-            nxt_m = np.asarray(out[1][:S])               # [S, k+1] resample/bonus
-        else:
-            rows = np.asarray(out[:S])  # [S, n_new] greedy argmax per position
-        for i, (seq, c, start, bl, eos) in enumerate(zip(seqs, chunks, starts, branches,
-                                                         eos_list)):
-            src_dst = None
-            if sampled:
-                d = padded[i][0]
-                rej = np.nonzero(~acc_m[i])[0]
-                a = int(rej[0]) if rej.size else k
-                committed = list(c[1:1 + a]) + [int(nxt_m[i, a])]
-                path = c[1:1 + a]
-                real = int(bl[0].size) if bl else 0
-            elif tree:
-                row = rows[i]
-                # deepest-argmax-path walk: branch b's node at depth t+1 is
-                # accepted iff its token equals the argmax at its PARENT
-                # node (root for t=0); ties keep the first branch, so a
-                # padded branch-0 clone can never displace the original
-                a, bwin = -1, 0
-                for b in range(W):
-                    pb = padded[i][b]
-                    parents = np.concatenate(
-                        [[0], 1 + b * k + np.arange(k - 1)]).astype(np.int64)
-                    neq = np.nonzero(pb != row[parents])[0]
-                    a_b = int(neq[0]) if neq.size else k
-                    if a_b > a:
-                        a, bwin = a_b, b
-                path = padded[i][bwin][:a]
-                bonus = int(row[0] if a == 0 else row[1 + bwin * k + a - 1])
-                committed = list(path) + [bonus]
-                if bwin != 0 and a > 0:
-                    # winner's KV sits at its flat tree slots — move it to
-                    # the canonical contiguous positions before rollback
-                    src_dst = [(start + 1 + bwin * k + t, start + 1 + t)
-                               for t in range(a)]
-                real = int(bl[bwin].size) if bwin < len(bl) else 0
-                drafted += sum(int(b.size) for b in bl)
+            if eos_token_ids is None or isinstance(eos_token_ids, (int, np.integer)):
+                eos_list = [eos_token_ids] * S
             else:
-                row = rows[i]
-                neq = np.nonzero(c[1:] != row[:k])[0]
-                a = int(neq[0]) if neq.size else k
-                committed = list(row[:a + 1])
-                path = row[:a]
-                real = int(bl[0].size) if bl else 0
-            if eos is not None:
-                # an eos among the ACCEPTED tokens ends the stream there:
-                # commit through the eos only, so the post-eos accepted
-                # tail (KV + history) is rolled back with the rejects and
-                # never published (the bonus-position eos needs nothing —
-                # its KV was never materialized)
-                hit = np.nonzero(np.asarray(path)[:a] == eos)[0]
-                if hit.size:
-                    a = int(hit[0])
-                    committed = committed[:a + 1]
-                    if src_dst is not None:
-                        src_dst = src_dst[:a]
-            seq.post_forward()                       # seen = start + n_new
-            if tree:
-                self.state_manager.commit_speculative(
-                    seq, start + 1 + a,
-                    [int(c[0])] + [int(t) for t in committed[:a]], src_dst)
-            else:
-                self.state_manager.rollback_to(seq, start + 1 + a)
-            self.state_manager.publish_sequence(seq)  # accepted full blocks → tree
-            results.append(np.asarray(committed, np.int32))
-            if not tree:
-                drafted += real
-            accepted += min(a, real)  # pads excluded from the honest rate
-            accepts.append(a)
+                eos_list = list(eos_token_ids)
+                assert len(eos_list) == S, "eos_token_ids must match batch_uids"
+            results = []
+            drafted = accepted = 0
+            accepts = []
+            with tr.span("serving/engine_fetch", tid="serving"):
+                if sampled:
+                    acc_m = np.asarray(out[0][:S]).astype(bool)  # [S, k] accept bits
+                    nxt_m = np.asarray(out[1][:S])               # [S, k+1] resample/bonus
+                else:
+                    rows = np.asarray(out[:S])  # [S, n_new] greedy argmax per position
+            with tr.span("serving/engine_commit", tid="serving"):
+                for i, (seq, c, start, bl, eos) in enumerate(zip(seqs, chunks, starts, branches,
+                                                                 eos_list)):
+                    src_dst = None
+                    if sampled:
+                        d = padded[i][0]
+                        rej = np.nonzero(~acc_m[i])[0]
+                        a = int(rej[0]) if rej.size else k
+                        committed = list(c[1:1 + a]) + [int(nxt_m[i, a])]
+                        path = c[1:1 + a]
+                        real = int(bl[0].size) if bl else 0
+                    elif tree:
+                        row = rows[i]
+                        # deepest-argmax-path walk: branch b's node at depth t+1 is
+                        # accepted iff its token equals the argmax at its PARENT
+                        # node (root for t=0); ties keep the first branch, so a
+                        # padded branch-0 clone can never displace the original
+                        a, bwin = -1, 0
+                        for b in range(W):
+                            pb = padded[i][b]
+                            parents = np.concatenate(
+                                [[0], 1 + b * k + np.arange(k - 1)]).astype(np.int64)
+                            neq = np.nonzero(pb != row[parents])[0]
+                            a_b = int(neq[0]) if neq.size else k
+                            if a_b > a:
+                                a, bwin = a_b, b
+                        path = padded[i][bwin][:a]
+                        bonus = int(row[0] if a == 0 else row[1 + bwin * k + a - 1])
+                        committed = list(path) + [bonus]
+                        if bwin != 0 and a > 0:
+                            # winner's KV sits at its flat tree slots — move it to
+                            # the canonical contiguous positions before rollback
+                            src_dst = [(start + 1 + bwin * k + t, start + 1 + t)
+                                       for t in range(a)]
+                        real = int(bl[bwin].size) if bwin < len(bl) else 0
+                        drafted += sum(int(b.size) for b in bl)
+                    else:
+                        row = rows[i]
+                        neq = np.nonzero(c[1:] != row[:k])[0]
+                        a = int(neq[0]) if neq.size else k
+                        committed = list(row[:a + 1])
+                        path = row[:a]
+                        real = int(bl[0].size) if bl else 0
+                    if eos is not None:
+                        # an eos among the ACCEPTED tokens ends the stream there:
+                        # commit through the eos only, so the post-eos accepted
+                        # tail (KV + history) is rolled back with the rejects and
+                        # never published (the bonus-position eos needs nothing —
+                        # its KV was never materialized)
+                        hit = np.nonzero(np.asarray(path)[:a] == eos)[0]
+                        if hit.size:
+                            a = int(hit[0])
+                            committed = committed[:a + 1]
+                            if src_dst is not None:
+                                src_dst = src_dst[:a]
+                    seq.post_forward()                       # seen = start + n_new
+                    if tree:
+                        self.state_manager.commit_speculative(
+                            seq, start + 1 + a,
+                            [int(c[0])] + [int(t) for t in committed[:a]], src_dst)
+                    else:
+                        self.state_manager.rollback_to(seq, start + 1 + a)
+                    self.state_manager.publish_sequence(seq)  # accepted full blocks → tree
+                    results.append(np.asarray(committed, np.int32))
+                    if not tree:
+                        drafted += real
+                    accepted += min(a, real)  # pads excluded from the honest rate
+                    accepts.append(a)
+            if sp is not NULL_SPAN:
+                sp.set_args(seqs=S, rows=S, tokens=S * n_new, bucket_tokens=int(t_bucket),
+                            bucket_rows=int(s_bucket), steps=1, k=k, drafted=drafted,
+                            tree_width=W, sampled=bool(sampled), accepted=accepts[:16],
+                            kernel=self._kernel_of(t_bucket, s_bucket),
+                            uids=[int(u) for u in uids[:16]])
         self._spec_totals["drafted"] += drafted
         self._spec_totals["accepted"] += accepted
         if rf.enabled:
@@ -927,23 +989,16 @@ class InferenceEngineV2:
             rf.note_wall(f"verify/t{t_bucket}/s{s_bucket}/k{n_new - 1}"
                          f"{'/tree' if tree else ''}{'/sampled' if sampled else ''}",
                          time.perf_counter() - t_rf)
-        if observing:
-            m = get_metrics()
-            if m.enabled:
-                m.counter("serving/spec_drafted_tokens").inc(drafted)
-                m.counter("serving/spec_accepted_tokens").inc(accepted)
-                m.counter("serving/spec_rejected_tokens").inc(drafted - accepted)
-                m.gauge("serving/spec_accept_rate").set(
-                    self._spec_totals["accepted"] / max(1, self._spec_totals["drafted"]))
-            committed_n = int(sum(len(r) for r in results))
-            observe_latency(t0, "serving/spec_verify",
-                            hist_name="serving/spec_verify_ms",
-                            gauges={"serving/spec_tokens_per_sec":
-                                    lambda dt: committed_n / max(dt, 1e-9)},
-                            span_args={"seqs": S, "k": k, "drafted": drafted,
-                                       "tree_width": W, "sampled": bool(sampled),
-                                       "accepted": accepts[:16],
-                                       "uids": [int(u) for u in uids[:16]]})
+        if reg.enabled:
+            reg.counter("serving/spec_drafted_tokens").inc(drafted)
+            reg.counter("serving/spec_accepted_tokens").inc(accepted)
+            reg.counter("serving/spec_rejected_tokens").inc(drafted - accepted)
+            reg.gauge("serving/spec_accept_rate").set(
+                self._spec_totals["accepted"] / max(1, self._spec_totals["drafted"]))
+            dt = time.perf_counter() - t0
+            reg.histogram("serving/spec_verify_ms").observe(dt * 1e3)
+            reg.gauge("serving/spec_tokens_per_sec").set(
+                sum(len(r) for r in results) / max(dt, 1e-9))
         return results
 
     def _note_compile(self, bucket):

@@ -14,7 +14,8 @@ Two real implementations behind one interface:
 import numpy as np
 
 from .....models.transformer import alibi_slopes
-from .....ops.pallas.paged_attention import _pallas_paged, paged_attention, paged_attention_reference
+from .....ops.pallas.paged_attention import (_note_choice, _pallas_paged, paged_attention,
+                                             paged_attention_reference)
 from ..configs import DSSelfAttentionConfig
 from ..interfaces import DSSelfAttentionBase, DSSelfAttentionRegistry
 
@@ -74,6 +75,8 @@ class PallasPagedAttention(DSSelfAttentionBase):
             import jax.numpy as jnp
 
             al = _alibi(cfg)
+            _note_choice(q.shape[0], tables_l.shape[0], tables_l.shape[1],
+                         "paged_attn_per_token", 1, 1, "interpret")
             return _pallas_paged(q, k_flat, v_flat, tables_l, seq_idx.astype(jnp.int32),
                                  pos.astype(jnp.int32), block_size=cfg.block_size,
                                  interpret=True, window=cfg.sliding_window,

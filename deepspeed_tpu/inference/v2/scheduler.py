@@ -26,12 +26,13 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from ...monitor.trace import NULL_SPAN, get_tracer
 from .scheduling_utils import SchedulingResult
 
 
 class _Request:
     __slots__ = ("uid", "prompt", "max_new_tokens", "eos_token_id", "fed", "generated", "done",
-                 "charged_blocks", "shared_blocks", "sampling", "tenant")
+                 "charged_blocks", "shared_blocks", "sampling", "tenant", "t_submit")
 
     def __init__(self, uid, prompt, max_new_tokens, eos_token_id, sampling=None,
                  tenant=None):
@@ -46,6 +47,7 @@ class _Request:
         self.done = False
         self.charged_blocks = 0  # lifetime KV reservation charged at admission
         self.shared_blocks = 0   # blocks arriving shared from the prefix cache
+        self.t_submit = time.perf_counter()  # -> first_wait_ms of the step that first feeds it
 
     @property
     def sampled(self) -> bool:
@@ -530,15 +532,26 @@ class DynamicSplitFuseScheduler:
     def step(self) -> int:
         """Compose and run ONE engine call: all runnable decodes first, then
         prefill chunks up to the token budget. Returns tokens processed
-        (0 = nothing runnable)."""
+        (0 = nothing runnable). The whole of it is one ``serving/sched_step``
+        span; its time outside the engine's child span is the scheduler's
+        planning and token bookkeeping."""
+        with get_tracer().span("serving/sched_step", tid="serving") as sp:
+            return self._step(sp)
+
+    def _step(self, sp) -> int:
         decoding = [r for r in self._active.values() if not r.prefilling and not r.done]
         prefilling = [r for r in self._active.values() if r.prefilling]
         if decoding and not prefilling and not self._pending and len(decoding) <= self.max_seqs:
+            kind, n = "decode", 0
             if self._drafter is not None:
-                n = self._spec_burst(decoding)
-                if n:
-                    return n
-            return self._decode_burst(decoding)
+                kind, n = "spec_verify", self._spec_burst(decoding)
+            if not n:
+                kind, n = "decode", self._decode_burst(decoding)
+            if sp is not NULL_SPAN:
+                sp.set_args(kind=kind, rows=len(decoding), rows_decode=len(decoding), tokens=n,
+                            prefill_tokens=0, pending=0, active=len(self._active),
+                            budget_left=self.token_budget, first_wait_ms=[])
+            return n
 
         uids: List[int] = []
         chunks: List[np.ndarray] = []
@@ -548,6 +561,7 @@ class DynamicSplitFuseScheduler:
             uids.append(req.uid)
             chunks.append(np.asarray([req.generated[-1]], np.int32))
             budget -= 1
+        n_decode = len(uids)
 
         def add_prefill(req):
             nonlocal budget
@@ -568,6 +582,7 @@ class DynamicSplitFuseScheduler:
         # exceeds what the pool can currently promise) must not starve later
         # pending requests that do fit — scan past it instead of breaking
         i = 0
+        first_fed = len(uids)  # rows from here on are fed their first chunk
         while i < len(self._pending) and budget > 0 and len(uids) < self.max_seqs:
             req = self._pending[i]
             if self._try_admit(req, uids, [c.size for c in chunks], budget):
@@ -576,6 +591,14 @@ class DynamicSplitFuseScheduler:
             else:
                 i += 1
 
+        if sp is not NULL_SPAN:
+            now = time.perf_counter()
+            n_tokens = sum(c.size for c in chunks)
+            sp.set_args(kind="put" if uids else "none", rows=len(uids), rows_decode=n_decode,
+                        tokens=n_tokens, prefill_tokens=n_tokens - n_decode,
+                        pending=len(self._pending), active=len(self._active), budget_left=budget,
+                        first_wait_ms=[round((now - self._active[u].t_submit) * 1e3, 3)
+                                       for u in uids[first_fed:]])
         if not uids:
             return 0
         # sampling rides down only when a sampled row's OUTPUT matters this

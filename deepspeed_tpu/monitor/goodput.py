@@ -137,6 +137,19 @@ SPAN_ALLOWLIST = (
     "checkpoint/async_write",
     # legacy v1 one-shot generate path — not wired to a ledger
     "serving/generate",
+    # layer-boundary OVERLAYS on the profiler's clock (replica._run,
+    # scheduler.step, engine_v2, train_batch): they subdivide or enclose the
+    # wall time the engine spans above and the driver's own idle/draining
+    # bookings already account for — booking them too would double-count it
+    "serving/loop_pull",
+    "serving/loop_fanout",
+    "serving/loop_idle",
+    "serving/sched_step",
+    "serving/engine_batch",
+    "serving/engine_dispatch",
+    "serving/engine_fetch",
+    "serving/engine_commit",
+    "train/dispatch",
     # zero-duration instants (consume no wall clock)
     "serving/request_rejected",
     "preemption_exit",

@@ -3,9 +3,20 @@
 The observability spine the reference spreads over ``@timed_op`` wrappers,
 the flops profiler and the torch profiler hooks, unified here into one bus:
 
-  * ``get_tracer().span("fwd")`` — a context manager emitting a Chrome-trace
-    duration event (``ph:"X"``) with ``pid`` = this host process and ``tid`` =
-    a logical stream (engine / comm / compile / checkpoint / serving / data).
+  * ``get_tracer().span("fwd")`` — THE span primitive, a context manager
+    with two sinks. While a profiler session is active
+    (``jax.profiler.TraceAnnotation.is_enabled()``: the benchmark's
+    ``--trace 1``, the engine's ``_maybe_device_trace``, an operator's
+    ``jax.profiler.start_trace``) it is a ``TraceAnnotation`` named
+    ``dstpu/<name>`` whose keyword arguments become the event's stats, so
+    the program's spans lie in the same ``.xplane.pb`` as the device's
+    operations, on the profiler's clock, under the thread that ran them.
+    While the bus is enabled it is a Chrome-trace duration event
+    (``ph:"X"``) under ``<name>`` with ``pid`` = this host process and
+    ``tid`` = a logical stream (engine / comm / compile / checkpoint /
+    serving / data). The ``process_name`` metadata event carries
+    ``origin_unix_ns`` and ``origin_perf_counter``, so a JSONL file can be
+    laid over an xplane file after the fact.
   * ``complete``/``instant``/``counter`` — manual emission for call sites
     that cannot use a ``with`` block (async dispatch, listener callbacks).
   * JAX compile/recompile events are captured through
@@ -17,9 +28,15 @@ Output is JSONL: one Chrome-trace event object per line, each independently
 ``trace_viewer`` JSON-array form for chrome://tracing or Perfetto is one
 ``to_chrome_trace`` call away.
 
-Zero overhead when disabled: ``span()`` returns a shared no-op singleton
-(``NULL_SPAN``), every other emitter early-returns on one attribute check, and
-the compile listener is only installed on first enable.
+Zero overhead when disabled: with the bus off, no mirror and no profiler
+session ``span()`` returns a shared no-op singleton (``NULL_SPAN``) after one
+attribute check and one ``is_enabled()``; every other emitter early-returns
+on one attribute check, and the compile listener is only installed on first
+enable. Arguments that cost anything to compute are set with
+``if sp is not NULL_SPAN: sp.set_args(...)``, never built before the check.
+Retroactive emission (``complete``, ``observe_latency``) cannot reach the
+profiler: it stays for what is retroactive by nature (the compile listener,
+per-request stages).
 
 This module must stay import-light (no package-internal imports): it is
 pulled in by ``comm.comm`` during package bootstrap.
@@ -29,6 +46,12 @@ import json
 import os
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
+
+# the profiler-side name of every span: the benchmark's readers find the
+# program's spans in an xplane file by this prefix
+PROFILER_PREFIX = "dstpu/"
 
 # canonical logical streams -> stable Chrome-trace tid numbers
 STREAMS = ("engine", "comm", "compile", "checkpoint", "serving", "data")
@@ -53,25 +76,39 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "_name", "_tid", "_args", "_t0")
+    """One open span: ``_ann`` is its profiler annotation (None without a
+    profiler session), ``_bus`` says whether the JSONL bus / mirror gets the
+    event when it closes."""
 
-    def __init__(self, tracer, name, tid, args):
+    __slots__ = ("_tracer", "_name", "_tid", "_args", "_t0", "_ann", "_bus")
+
+    def __init__(self, tracer, name, tid, args, ann, bus):
         self._tracer = tracer
         self._name = name
         self._tid = tid
         self._args = args
+        self._ann = ann
+        self._bus = bus
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def set_args(self, **kwargs):
-        self._args.update(kwargs)
+        if self._bus:
+            self._args.update(kwargs)
+        if self._ann is not None:
+            self._ann.set_metadata(**kwargs)
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
-        self._tracer.complete(self._name, self._t0, t1 - self._t0, tid=self._tid, args=self._args)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self._bus:
+            self._tracer.complete(self._name, self._t0, t1 - self._t0, tid=self._tid, args=self._args)
         return False
 
 
@@ -119,8 +156,13 @@ class Tracer:
                     _install_compile_listener()
                     self._install_atexit()
                     self.enabled = True
+                    # the two origins are one instant on two clocks: ts 0 of
+                    # this file on the unix clock an xplane file is stamped in
+                    lag = time.perf_counter() - self._origin
                     self._emit({"name": "process_name", "ph": "M", "ts": 0, "pid": self._pid,
-                                "tid": 0, "args": {"name": "deepspeed_tpu"}})
+                                "tid": 0, "args": {"name": "deepspeed_tpu",
+                                                   "origin_unix_ns": time.time_ns() - int(lag * 1e9),
+                                                   "origin_perf_counter": self._origin}})
                     # re-announce streams first seen in mirror-only mode:
                     # their thread_name metadata went to the flight ring,
                     # never to the buffer/file — without this, a trace
@@ -161,11 +203,15 @@ class Tracer:
 
     # -- emission -------------------------------------------------------
     def span(self, name, tid="engine", **args):
-        """Context manager for a duration event. Allocation-free no-op
-        (the shared ``NULL_SPAN`` object) while disabled and unmirrored."""
-        if not self.enabled and self._mirror is None:
+        """Context manager for a duration event, to the profiler while a
+        session is active and to the bus while it is enabled (or mirrored).
+        Allocation-free no-op (the shared ``NULL_SPAN`` object) otherwise."""
+        bus = self.enabled or self._mirror is not None
+        if TraceAnnotation.is_enabled():
+            return _Span(self, name, tid, args, TraceAnnotation(PROFILER_PREFIX + name, **args), bus)
+        if not bus:
             return NULL_SPAN
-        return _Span(self, name, tid, args)
+        return _Span(self, name, tid, args, None, True)
 
     def complete(self, name, t0, duration, tid="engine", args=None):
         """Emit a ``ph:"X"`` duration event. ``t0`` is a ``time.perf_counter``
@@ -268,6 +314,22 @@ class Tracer:
         with self._lock:
             self._flush_locked()
             self._close_fh()
+
+    def reset(self):
+        """Back to the state of a fresh process: closed, disabled, and no
+        path, mirror or buffered event left. The tracer is a process
+        singleton, so whatever one user of it (a test, a benchmark run) sets
+        outlives that user unless it ends with this."""
+        with self._lock:
+            self.close()
+            self.enabled = False
+            self._path = None
+            self._mirror = None
+            self._buf = []
+            self._tids = {}
+            self._opened_paths = set()
+            self._flush_every = 256
+        return self
 
     def drain(self):
         """Return (and clear) the buffered, not-yet-written events — the

@@ -40,6 +40,25 @@ def _contiguity_ok(seq_idx, S: int) -> bool:
     return runs <= S + 1
 
 
+# Which kernel each traced shape took, and the rule that decided:
+# ``(T, S, max_blocks) -> {"kernel", "q_tile", "kv_splits", "rule"}``.
+# ``paged_attention`` resolves both while ``jit`` traces the program, from
+# static shapes, so filling this costs nothing at run time; the serving
+# engine reads it once per compiled program and puts it on the step's span.
+KERNEL_CHOICES = {}
+
+
+def kernel_choice(T: int, S: int, max_blocks: int):
+    """The recorded choice for a traced shape, or None if no program of that
+    shape has been traced in this process."""
+    return KERNEL_CHOICES.get((int(T), int(S), int(max_blocks)))
+
+
+def _note_choice(T, S, max_blocks, kernel, q_tile, kv_splits, rule):
+    KERNEL_CHOICES[(int(T), int(S), int(max_blocks))] = {
+        "kernel": kernel, "q_tile": int(q_tile), "kv_splits": int(kv_splits), "rule": rule}
+
+
 def _resolve_q_tile(T: int, S: int, seq_idx=None) -> int:
     """Resolve the q-tile through the kernel-config registry
     (``autotuning/kernel_config.py``), falling back to the shape heuristic:
@@ -54,6 +73,13 @@ def _resolve_q_tile(T: int, S: int, seq_idx=None) -> int:
     violation; traced callers (the jitted ragged step) are covered by the
     layout invariant itself.
     """
+    return _q_tile_choice(T, S, seq_idx)[0]
+
+
+def _q_tile_choice(T: int, S: int, seq_idx=None):
+    """``(q_tile, rule)``: :func:`_resolve_q_tile` with the rule that decided
+    (``env``, ``tuned``, ``heuristic:prefill_ish``, ``heuristic:decode_shaped``
+    or ``contiguity_demoted``)."""
     from ...autotuning.kernel_config import shape_bucket, tuned_tile
 
     # DS_TPU_PAGED_Q_TILE: operator override — =1 pins the per-token grid
@@ -64,22 +90,29 @@ def _resolve_q_tile(T: int, S: int, seq_idx=None) -> int:
             qt = max(1, int(env))
         except ValueError:
             qt = 1
-        return qt if _contiguity_ok(seq_idx, S) else 1
+        if qt > 1 and not _contiguity_ok(seq_idx, S):
+            return 1, "contiguity_demoted"
+        return qt, "env"
 
     prefill_ish = T >= 64 and T >= 2 * max(S, 1)
-    default = 8 if prefill_ish else 1
     # lookup order: exact (T, S) bucket, then — for prefill-ish shapes
     # ONLY — the T-only bucket the sweep records (S here is block-table
     # CAPACITY, which varies per deployment, so T generalizes over it). A
     # pure-decode shape (one token per sequence) must never inherit a
     # prefill-tuned tile from the T-only key: every tile would carry qt-1
     # masked slots for zero KV amortization.
-    fallback = int(tuned_tile("paged_attention", shape_bucket(T=T), "q_tile",
-                              default)) if prefill_ish else default
-    qt = int(tuned_tile("paged_attention", shape_bucket(T=T, S=S), "q_tile", fallback))
+    tuned = tuned_tile("paged_attention", shape_bucket(T=T, S=S), "q_tile", None)
+    if tuned is None and prefill_ish:
+        tuned = tuned_tile("paged_attention", shape_bucket(T=T), "q_tile", None)
+    if tuned is not None:
+        qt, rule = int(tuned), "tuned"
+    elif prefill_ish:
+        qt, rule = 8, "heuristic:prefill_ish"
+    else:
+        qt, rule = 1, "heuristic:decode_shaped"
     if qt > 1 and not _contiguity_ok(seq_idx, S):
-        return 1
-    return max(qt, 1)
+        return 1, "contiguity_demoted"
+    return max(qt, 1), rule
 
 
 def _resolve_kv_splits(T: int, S: int, max_blocks: int, q_tile: int = 1) -> int:
@@ -99,28 +132,39 @@ def _resolve_kv_splits(T: int, S: int, max_blocks: int, q_tile: int = 1) -> int:
     ``B``-only bucket the decode sweep records (B = block-table capacity —
     the KV length is what the split amortizes over; T is just the decode
     batch size of the moment)."""
+    return _kv_splits_choice(T, S, max_blocks, q_tile)[0]
+
+
+def _kv_splits_choice(T: int, S: int, max_blocks: int, q_tile: int = 1):
+    """``(kv_splits, rule)``: :func:`_resolve_kv_splits` with the rule that
+    decided (``heuristic:tiled``, ``heuristic:short_table``,
+    ``heuristic:multi_token``, ``env``, ``tuned`` or ``heuristic:long_table``)."""
     from ...autotuning.kernel_config import shape_bucket, tuned_tile
 
-    if q_tile > 1 or max_blocks < 8 or T > 2 * max(S, 1):
-        # tiled prefill rows keep the single chain; a short table has no KV
-        # axis worth splitting (each split must own >= a few blocks); and a
-        # batch with real multi-token chunks (T well past the seq count —
-        # e.g. a non-contiguous prefill demoted to the per-token grid) must
-        # not inherit the split's T x kv_splits partial buffers
-        return 1
+    # tiled prefill rows keep the single chain; a short table has no KV
+    # axis worth splitting (each split must own >= a few blocks); and a
+    # batch with real multi-token chunks (T well past the seq count —
+    # e.g. a non-contiguous prefill demoted to the per-token grid) must
+    # not inherit the split's T x kv_splits partial buffers
+    if q_tile > 1:
+        return 1, "heuristic:tiled"
+    if max_blocks < 8:
+        return 1, "heuristic:short_table"
+    if T > 2 * max(S, 1):
+        return 1, "heuristic:multi_token"
     env = os.environ.get("DS_TPU_PAGED_KV_SPLITS")
     if env:
         try:
             ks = max(1, int(env))
         except ValueError:
             ks = 1
-        return min(ks, max_blocks)
-    default = min(8, max(1, max_blocks // 4))
-    fallback = int(tuned_tile("paged_attention", shape_bucket(B=max_blocks), "kv_splits",
-                              default))
-    ks = int(tuned_tile("paged_attention", shape_bucket(B=max_blocks, T=T), "kv_splits",
-                        fallback))
-    return max(1, min(ks, max_blocks))
+        return min(ks, max_blocks), "env"
+    tuned = tuned_tile("paged_attention", shape_bucket(B=max_blocks, T=T), "kv_splits", None)
+    if tuned is None:
+        tuned = tuned_tile("paged_attention", shape_bucket(B=max_blocks), "kv_splits", None)
+    if tuned is not None:
+        return max(1, min(int(tuned), max_blocks)), "tuned"
+    return max(1, min(8, max_blocks // 4)), "heuristic:long_table"
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: int, window=None,
@@ -150,14 +194,18 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: i
     S = block_tables.shape[0]
     if window is not None:
         window = int(window)
+    max_blocks = block_tables.shape[1]
     if jax.default_backend() != "tpu" or nq < 8 or d % 128 != 0:
-        if jax.default_backend() == "tpu":
+        on_tpu = jax.default_backend() == "tpu"
+        if on_tpu:
             # off-TPU the oracle is the design; ON TPU a shape miss silently
             # costing a full context gather per layer per step must be loud
             from ...utils.logging import warning_once
 
             warning_once(f"pallas paged attention: unsupported shape (nq={nq}, d={d}; needs "
                          "nq>=8, d%128==0) — serving through the DENSE gather fallback")
+        _note_choice(T, S, max_blocks, "paged_attention_reference", 1, 1,
+                     "unsupported_shape" if on_tpu else "off_tpu")
         return paged_attention_reference(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size,
                                          window=window, alibi=alibi, k_scale=k_scale, v_scale=v_scale)
     if k_scale is not None and block_size % 128 != 0:
@@ -166,7 +214,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: i
         # the option nor the remedy
         raise ValueError(f"int8 KV on TPU needs kv_block_size % 128 == 0, got {block_size}")
     if q_tile is None:
-        q_tile = _resolve_q_tile(T, S, seq_idx)
+        q_tile, q_rule = _q_tile_choice(T, S, seq_idx)
     elif q_tile > 1 and not _contiguity_ok(seq_idx, S):
         # an explicit q_tile must not bypass the layout contract: a
         # non-contiguous batch would overflow the tiled grid's static tile
@@ -175,12 +223,24 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: i
 
         warning_once(f"paged attention: q_tile={q_tile} requested but seq_idx is not "
                      "sequence-contiguous — demoting to the per-token grid")
-        q_tile = 1
+        q_tile, q_rule = 1, "contiguity_demoted"
+    else:
+        q_rule = "explicit"
     alibi_t = tuple(np.asarray(alibi).tolist()) if alibi is not None else None
-    max_blocks = block_tables.shape[1]
     if kv_splits is None:
-        kv_splits = _resolve_kv_splits(T, S, max_blocks, q_tile=int(q_tile))
+        kv_splits, kv_rule = _kv_splits_choice(T, S, max_blocks, q_tile=int(q_tile))
+    else:
+        kv_rule = "explicit"
     kv_splits = max(1, min(int(kv_splits), max_blocks))
+    # the same order as _pallas_paged: a tile wins, then a split, then the
+    # per-token grid; a demotion stays the reason whatever the split says
+    if q_tile > 1:
+        _note_choice(T, S, max_blocks, "paged_attn_q_tiled", q_tile, 1, q_rule)
+    elif kv_splits > 1:
+        _note_choice(T, S, max_blocks, "paged_attn_kv_split", 1, kv_splits, kv_rule)
+    else:
+        _note_choice(T, S, max_blocks, "paged_attn_per_token", 1, 1,
+                     q_rule if q_rule == "contiguity_demoted" else kv_rule)
     # ONE grid, chosen above from the shape; a grid the chip refuses RAISES
     # (here at trace/lowering, or at the enclosing jit's compile) — it is
     # never downgraded to another grid or to the gather below, which would

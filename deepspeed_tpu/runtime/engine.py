@@ -39,7 +39,7 @@ from .zero.partition import ZeroShardingPolicy, PartitionRules, constrain
 from ..accelerator import get_accelerator
 from ..comm import comm as dist
 from ..monitor.monitor import MonitorMaster
-from ..monitor.trace import configure_tracer, get_tracer
+from ..monitor.trace import NULL_SPAN, configure_tracer, get_tracer
 from ..monitor.metrics import get_metrics, compute_mfu
 from ..monitor.health import get_health
 from ..monitor.goodput import configure_goodput, get_goodput
@@ -1316,33 +1316,35 @@ class DeepSpeedEngine:
             # books the gap since the last boundary as idle (or recovery,
             # when the resilience runner flagged a restart in flight)
             gl.step_entry()
-        wait_obs = self._tracer.enabled or self._metrics.enabled or health_on \
-            or gl is not None
+        wait_obs = self._metrics.enabled or health_on or gl is not None
         t_in = time.perf_counter() if wait_obs else 0.0
         prefetched = isinstance(batch, DeviceBatch)
-        if batch is None:
-            assert data_iter is not None
-            first = next(data_iter)
-            if isinstance(first, DeviceBatch):
-                batch, prefetched = first, True
+        # batch preparation and placement: what the step waits for before it
+        # can be dispatched
+        with self._tracer.span("input_wait", tid="data", step=self.global_steps) as sp_in:
+            if batch is None:
+                assert data_iter is not None
+                first = next(data_iter)
+                if isinstance(first, DeviceBatch):
+                    batch, prefetched = first, True
+                else:
+                    batch = self._host_prepare_batch(
+                        mbs=[first] + [next(data_iter) for _ in range(gas - 1)])
+            elif not prefetched:
+                batch = self._host_prepare_batch(batch=batch)
+            if prefetched:
+                placed = batch.data
             else:
-                batch = self._host_prepare_batch(mbs=[first] + [next(data_iter) for _ in range(gas - 1)])
-        elif not prefetched:
-            batch = self._host_prepare_batch(batch=batch)
-        if prefetched:
-            placed = batch.data
-        else:
-            with self.mesh:
-                placed = self._shard_batch(batch, leading=("mb", ))
+                with self.mesh:
+                    placed = self._shard_batch(batch, leading=("mb", ))
+            if sp_in is not NULL_SPAN:
+                sp_in.set_args(prefetched=prefetched)
         if wait_obs:
             dt_in = time.perf_counter() - t_in
             if health_on:
                 self._last_input_wait_ms = dt_in * 1e3  # straggler-vote sample
             if self._metrics.enabled:
                 self._metrics.histogram("train/input_wait_ms").observe(dt_in * 1e3)
-            if self._tracer.enabled:
-                self._tracer.complete("input_wait", t_in, dt_in, tid="data",
-                                      args={"step": self.global_steps, "prefetched": prefetched})
 
         self._maybe_device_trace()
         if prefetched:
@@ -1359,17 +1361,20 @@ class DeepSpeedEngine:
             self.random_ltd_scheduler.update_seq(self.global_steps)
         step_rng, self._rng = jax.random.split(self._rng)
         self.tput_timer.start()
-        # observe every step while tracing (profiling mode: the block that
-        # makes spans honest is intended); in sink-only mode sample at the
-        # steps_per_print boundary, where _record_metrics pays the host sync
-        # anyway — plain telemetry must not serialize the async step pipeline
-        observing = self._tracer.enabled or (
-            self._metrics.enabled and (self.global_steps + 1) % self.config.steps_per_print == 0)
+        # a traced run pipelines like an untraced one: the ``train_batch``
+        # span covers dispatch (``blocked: false``) and the device trace gives
+        # device time. Only at the steps_per_print boundary, where
+        # _record_metrics pays the host sync anyway, is the step's result
+        # waited for, for the metrics registry's step time and MFU — plain
+        # telemetry must not serialize the async step pipeline
+        sampling = self._metrics.enabled and (self.global_steps + 1) % self.config.steps_per_print == 0
+        observing = sampling or self._tracer.enabled
         t_step = time.perf_counter() if observing else 0.0
         if self.host_optimizer is not None:
             metrics = self._offload_train_batch(placed, step_rng)
         else:
-            if "train_step" not in self._compiled:
+            building = "train_step" not in self._compiled
+            if building:
                 self._last_batch_struct = jax.tree_util.tree_map(lambda x: np.ndim(x), placed)
                 if gl is not None:
                     # a fused-step (re)build after the warmup boundary is
@@ -1388,7 +1393,9 @@ class DeepSpeedEngine:
                         "train_step", self._compiled["train_step"], mesh=self.mesh)
             _rf = get_roofline()
             t_rf = time.perf_counter() if _rf.enabled else 0.0
-            with self.mesh:
+            # enqueue of the fused step (a first call traces and compiles here)
+            with self._tracer.span("train/dispatch", tid="engine", step=self.global_steps,
+                                   compiled=building), self.mesh:
                 self.state, metrics = self._compiled["train_step"](self.state, placed, step_rng)
             if _rf.enabled:
                 # dispatch-side wall at the step boundary: async steps make a
@@ -1401,7 +1408,7 @@ class DeepSpeedEngine:
         self.global_samples += self.train_batch_size()
         self.tput_timer.stop(global_step=True)
         if observing:
-            self._observe_step(t_step, placed, metrics)
+            self._observe_step(t_step, placed, metrics, block=sampling)
         if self.host_optimizer is None and self.fp16_enabled and bool(metrics["overflow"]):
             self.skipped_steps += 1  # offload path counts inside _host_apply_update
         self._record_metrics(metrics)
@@ -1626,7 +1633,7 @@ class DeepSpeedEngine:
                 self._compiled["loss"] = jax.jit(lambda p, b, r: self._loss_fn(p, b, r)[0])
             with self.mesh:
                 loss = self._compiled["loss"](self.state["params"], self._shard_batch(batch), fwd_rng)
-            self._emit_phase("fwd", t0, loss)
+            self._emit_phase("fwd", t0)
             return loss
         if "grads" not in self._compiled:
 
@@ -1637,7 +1644,7 @@ class DeepSpeedEngine:
         with self.mesh:
             batch = self._shard_batch(batch)
             grads, loss = self._compiled["grads"](self.state["params"], batch, fwd_rng, self.state["loss_scale"])
-        self._emit_phase("fwd", t0, loss)
+        self._emit_phase("fwd", t0)
         self._pending_batches.append(grads)
         return loss
 
@@ -1658,24 +1665,20 @@ class DeepSpeedEngine:
                     self._compiled["grad_add"] = jax.jit(
                         lambda a, b: jax.tree_util.tree_map(jnp.add, a, b), donate_argnums=(0, ))
                 self._grad_acc_buffer = self._compiled["grad_add"](self._grad_acc_buffer, grads)
-        self._emit_phase("bwd", t0, self._grad_acc_buffer)
+        self._emit_phase("bwd", t0)
         self.micro_steps += 1
         return loss
 
-    def _emit_phase(self, name, t0, block_on=None):
+    def _emit_phase(self, name, t0):
         """Emit one engine-phase duration event (fwd/bwd/step). No-op unless
-        the trace bus is live; blocking on ``block_on`` then is what makes
-        the span cover the device work, not just the async dispatch."""
+        the trace bus is live. It never waits for the device, so a traced run
+        pipelines like an untraced one: the event covers the phase's host
+        side (``blocked: false``), the device trace its device side."""
         if not self._tracer.enabled:
             return
-        if block_on is not None:
-            try:
-                jax.block_until_ready(block_on)
-            except Exception:
-                pass
         tid = "checkpoint" if name.startswith("checkpoint/") else "engine"
         self._tracer.complete(name, t0, time.perf_counter() - t0, tid=tid,
-                              args={"step": self.global_steps})
+                              args={"step": self.global_steps, "blocked": False})
 
     def is_gradient_accumulation_boundary(self):
         """Reference ``engine.py`` same name: true when the next step() will
@@ -1760,19 +1763,21 @@ class DeepSpeedEngine:
     def get_batch_info(self):
         return (self.train_batch_size(), self.train_micro_batch_size_per_gpu(), self.gradient_accumulation_steps())
 
-    def _observe_step(self, t0, batch, metrics):
-        """Trace span + derived throughput/MFU for one fused train step.
-        Only runs when the trace bus or metrics registry is live (observing
-        implies profiling mode, so blocking on the step result is intended —
-        it is what makes the recorded wall time honest)."""
-        jax.block_until_ready(metrics["loss"])
+    def _observe_step(self, t0, batch, metrics, block):
+        """``train_batch`` span, and with ``block`` (the steps_per_print
+        boundary of a live metrics registry) the derived throughput/MFU of one
+        fused train step: waiting for the step's result is what makes that
+        wall time honest. Without ``block`` the step stays in flight and the
+        span, which says ``blocked: false``, covers its dispatch."""
+        if block:
+            jax.block_until_ready(metrics["loss"])
         dt = max(time.perf_counter() - t0, 1e-9)
         leaves = jax.tree_util.tree_leaves(batch)
         # (gas, rows, seq, ...) leaves carry a token dim; scalar tracks don't
         seq = int(np.shape(leaves[0])[-1]) if leaves and np.ndim(leaves[0]) >= 3 else None
         tokens = self.train_batch_size() * (seq or 1)
         mfu = None
-        if seq is not None:
+        if block and seq is not None:
             from ..profiling.flops_profiler import training_flops_per_token
 
             mcfg = getattr(self.module, "config", None)
@@ -1782,7 +1787,7 @@ class DeepSpeedEngine:
                                            seq_len=seq)
             mfu = compute_mfu(fpt * tokens, dt, n_chips=self.mesh.size)
         reg = self._metrics
-        if reg.enabled:
+        if block:
             reg.counter("train/steps").inc()
             reg.counter("train/tokens").inc(tokens)
             reg.histogram("train/step_time_ms").observe(dt * 1e3)
@@ -1791,7 +1796,7 @@ class DeepSpeedEngine:
             if mfu is not None:
                 reg.gauge("train/mfu").set(mfu)
         if self._tracer.enabled:
-            args = {"step": self.global_steps, "tokens": tokens}
+            args = {"step": self.global_steps, "tokens": tokens, "blocked": bool(block)}
             if mfu is not None:
                 args["mfu"] = round(mfu, 4)
             self._tracer.complete("train_batch", t0, dt, tid="engine", args=args)

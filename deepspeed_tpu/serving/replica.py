@@ -28,6 +28,7 @@ from ..monitor.flight import get_flight_recorder
 from ..monitor.goodput import get_goodput
 from ..monitor.health import get_health
 from ..monitor.metrics import get_metrics
+from ..monitor.trace import NULL_SPAN, get_tracer
 from ..inference.v2 import DynamicSplitFuseScheduler
 from ..runtime.resilience import chaos
 
@@ -537,6 +538,7 @@ class EngineReplica:
         src = self.heartbeat_source
         gl = self._goodput
         tl = self._timeline
+        tr = get_tracer()
         stall_gap = get_goodput().stall_gap_s
         try:
             while not self._stop.is_set():
@@ -559,22 +561,33 @@ class EngineReplica:
                             # with each in-flight request's segments
                             tl.on_stall(self.name, t_fire, gap)
                 busy = False
-                self._process_cancellations()
-                if not self.paused:
-                    busy = self._pull_resumes() or busy
-                    busy = self._pull_admitted() or busy
-                    if self._scheduler.has_work:
-                        if hb.enabled:
-                            # armed exactly while work is in flight: a wedged
-                            # step (or a dead driver) goes stale and trips the
-                            # serving-family deadline
-                            hb.beat(src)
-                        busy = self._step() or busy
+                # the driver's own account of its loop, on the profiler's
+                # clock: pulling, stepping (the scheduler's and the engine's
+                # spans nest inside), fanning out, waiting for work
+                sp = tr.span("serving/loop_pull", tid="serving")
+                if sp is not NULL_SPAN and not self._pullable():
+                    sp = NULL_SPAN  # nothing to pull or cancel: no span
+                with sp:
+                    self._process_cancellations()
+                    if not self.paused:
+                        busy = self._pull_resumes() or busy
+                        busy = self._pull_admitted(sp) or busy
+                    if sp is not NULL_SPAN:
+                        sp.set_args(queue_depth=self._admission.depth(replica=self.name),
+                                    inflight=self._inflight)
+                if not self.paused and self._scheduler.has_work:
+                    if hb.enabled:
+                        # armed exactly while work is in flight: a wedged
+                        # step (or a dead driver) goes stale and trips the
+                        # serving-family deadline
+                        hb.beat(src)
+                    busy = self._step() or busy
                 if not busy:
                     if hb.enabled:
                         hb.disarm(src)
                     t_wait = time.perf_counter() if gl is not None else 0.0
-                    self._wake.wait(self.IDLE_WAIT_S)
+                    with tr.span("serving/loop_idle", tid="serving", paused=self.paused):
+                        self._wake.wait(self.IDLE_WAIT_S)
                     self._wake.clear()
                     if gl is not None:
                         gl.book("draining" if self.paused else "idle",
@@ -653,8 +666,18 @@ class EngineReplica:
                 # disconnect / explicit cancel) — finalize reads it
                 self._reqtrace.finalize(req, spec=spec)
 
-    def _pull_admitted(self) -> bool:
-        pulled = False
+    def _pullable(self) -> bool:
+        """Whether this loop iteration has anything to cancel, adopt or pull
+        (asked only while a span sink is live, to leave empty pulls out)."""
+        if self._cancelled:
+            return True
+        return not self.paused and bool(
+            self._resumes or (self._inflight < self._max_inflight
+                              and self._admission.depth(replica=self.name)))
+
+    def _pull_admitted(self, sp=NULL_SPAN) -> bool:
+        pulled = 0
+        waits = [] if sp is not NULL_SPAN else None  # admitted -> pulled, for ``loop_pull``
         while self._inflight < self._max_inflight:
             req = self._admission.pop_for(self.name)
             if req is None:
@@ -681,8 +704,12 @@ class EngineReplica:
                     time.perf_counter() - req.t_admitted, rid=req.rid)
             self._streams[req.uid] = req
             self._inflight += 1
-            pulled = True
-        return pulled
+            pulled += 1
+            if waits is not None and req.t_admitted is not None and len(waits) < 32:
+                waits.append(round((time.perf_counter() - req.t_admitted) * 1e3, 3))
+        if waits is not None:
+            sp.set_args(pulled=pulled, wait_ms=waits)
+        return pulled > 0
 
     def _pull_resumes(self) -> bool:
         """Driver-side half of a handoff adoption: submit each migrated
@@ -739,16 +766,21 @@ class EngineReplica:
             self._inflight = 0
             raise
         self.steps += 1
-        self._fanout()
+        with get_tracer().span("serving/loop_fanout", tid="serving") as sp:
+            pushed, finished = self._fanout()
+            if sp is not NULL_SPAN:
+                sp.set_args(pushed=pushed, finished=finished)
         return n > 0
 
     def _fanout(self):
         """Push newly generated tokens to each request's stream; close out
         finished requests with TTFT/TPOT accounting. Reads only each
         stream's TAIL (``new_tokens``) — snapshotting ``results`` here
-        would re-copy every active generation whole on every step."""
+        would re-copy every active generation whole on every step.
+        Returns ``(tokens pushed, requests finished)``."""
         finished = self._scheduler.finished
         reg = get_metrics()
+        n_pushed = n_finished = 0
         for uid, req in list(self._streams.items()):
             st = req.stream
             # resume_base: tokens the stream already held when a migrated
@@ -757,6 +789,7 @@ class EngineReplica:
             new = self._scheduler.new_tokens(uid, st.produced - req.resume_base)
             if new:
                 pushed = st.push(new)
+                n_pushed += pushed
                 if pushed:
                     reg.counter("gateway/tokens_streamed_total").inc(pushed)
                     if req.ttft_ms is None and st.first_token_t is not None:
@@ -782,6 +815,7 @@ class EngineReplica:
                 req.handoff_state = "fallback"
             if uid in finished:  # once: the stream entry is removed with it
                 self._inflight -= 1
+                n_finished += 1
                 del self._streams[uid]
                 self._close_out(req)
                 # the stream holds the full generation; dropping the
@@ -789,6 +823,7 @@ class EngineReplica:
                 # (and each per-step `results` snapshot) from growing with
                 # every request ever served
                 self._scheduler.discard_result(uid)
+        return n_pushed, n_finished
 
     def _close_out(self, req: GatewayRequest):
         st = req.stream

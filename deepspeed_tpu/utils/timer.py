@@ -2,10 +2,15 @@
 
 TPU-native analog of the reference ``deepspeed/utils/timer.py``
 (``SynchronizedWallClockTimer`` :class via device events, ``ThroughputTimer``,
-``NoopTimer``). On TPU there are no CUDA events; synchronization is achieved by
-blocking on the most recent JAX async dispatch (``jax.block_until_ready`` /
-``jax.effects_barrier``), which gives the same "device work up to here is done"
-semantics the reference gets from ``get_accelerator().synchronize()``.
+``NoopTimer``). On TPU there are no CUDA events; ``SynchronizedWallClockTimer``
+fences with ``get_accelerator().synchronize()`` as the reference does, which
+here waits for a trivial computation enqueued behind everything already
+queued on each local device: "device work up to here is done", so a timer
+around an un-fetched jitted call covers its run, not its enqueue.
+``ThroughputTimer`` sits in ``train_batch``'s hot path and never waits for
+the device (a wait there would serialize the asynchronous step pipeline):
+it times from dispatch to dispatch, which back-pressure makes the step time
+in steady state.
 """
 
 import time
@@ -32,12 +37,9 @@ STEP_GLOBAL_TIMER = "step"
 
 
 def _device_sync():
-    try:
-        import jax
+    from ..accelerator import get_accelerator
 
-        jax.effects_barrier()
-    except Exception:
-        pass
+    get_accelerator().synchronize()
 
 
 class CudaEventTimer:  # name kept for API familiarity; this is a host timer pair
@@ -189,7 +191,6 @@ class ThroughputTimer:
         self._init_timer()
         self.started = True
         if self.global_step_count >= self.start_step:
-            _device_sync()
             self.start_time = time.time()
 
     def stop(self, global_step=False, report_speed=True):
@@ -200,7 +201,6 @@ class ThroughputTimer:
         if global_step:
             self.global_step_count += 1
         if self.start_time > 0:
-            _device_sync()
             self.end_time = time.time()
             duration = self.end_time - self.start_time
             self.total_elapsed_time += duration

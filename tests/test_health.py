@@ -51,11 +51,7 @@ def _reset_health_plane():
     yield
     get_health().shutdown()
     h.stall_count, h.last_dump_path = 0, None
-    tr = get_tracer()
-    tr.set_mirror(None)
-    tr.configure(enabled=False)
-    tr.drain()
-    tr._path = None
+    get_tracer().reset()
     get_flight_recorder().configure(enabled=False)
     get_flight_recorder().clear()
     get_metrics().disable()

@@ -474,9 +474,7 @@ def test_overlap_gather_rides_trace_bus():
         assert gathers[0]["args"]["msg_size"] > 0
         assert gathers[0]["args"].get("traced") is True
     finally:
-        tr.configure(enabled=False)
-        tr.drain()
-        tr._path = None
+        tr.reset()
         dist.comms_logger.enabled = False
 
 

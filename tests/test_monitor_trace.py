@@ -33,11 +33,9 @@ CHROME_TRACE_FIELDS = ("name", "ph", "ts", "dur", "pid", "tid")
 def _reset_observability():
     """The tracer/registry are process-global: always leave them disabled so
     engines built by OTHER test files never pay the observing path."""
+    get_tracer().reset()  # whatever an earlier file in this process left set
     yield
-    tr = get_tracer()
-    tr.configure(enabled=False)
-    tr.drain()
-    tr._path = None
+    get_tracer().reset()
     get_metrics().disable()
     get_metrics().reset()
     dist.comms_logger.enabled = False
@@ -405,12 +403,13 @@ def test_serving_ttft_and_decode_histograms(eight_devices):
     eng = InferenceEngineV2(TransformerLM(cfg), icfg)
 
     prompt = np.arange(16, dtype=np.int32) % cfg.vocab_size
-    first = eng.put([0], [prompt], sample="greedy")  # prefill -> TTFT sample
+    first = eng.put([0], [prompt], sample="greedy")  # prefill -> one prefill-step sample
     eng.decode([0], [np.asarray([int(first[0])], np.int32)], n_steps=2)
 
     snap = get_metrics().snapshot()
-    assert snap["histograms"]["serving/ttft_ms"]["count"] == 1
-    assert snap["histograms"]["serving/ttft_ms"]["p50"] > 0
+    assert snap["histograms"]["serving/prefill_step_ms"]["count"] == 1
+    assert snap["histograms"]["serving/prefill_step_ms"]["p50"] > 0
+    assert "serving/ttft_ms" not in snap["histograms"]  # a step's latency is not a time to first token
     assert snap["histograms"]["serving/decode_ms"]["count"] == 1
     names = {e["name"] for e in get_tracer().drain()}
     assert {"serving/prefill", "serving/decode"} <= names

@@ -229,8 +229,11 @@ def test_input_wait_metric_and_span():
     batch = {"input_ids": rng.integers(0, 64, size=(8, SEQ), dtype=np.int32)}
     configure_metrics(enabled=True)
     get_metrics().reset()
+    # the tracer is a process singleton: a path, a flush_every of 1 or a
+    # mirror left by an earlier file of this process would take the events
+    # this test drains from the buffer
+    get_tracer().reset()
     configure_tracer(enabled=True)  # pathless buffer mode
-    get_tracer().drain()
     try:
         engine.train_batch(batch)
         engine.train_batch(batch)
@@ -240,7 +243,7 @@ def test_input_wait_metric_and_span():
         assert len(waits) >= 2
         assert waits[0]["args"]["prefetched"] is False
     finally:
-        configure_tracer(enabled=False)
+        get_tracer().reset()
         configure_metrics(enabled=False)
         get_metrics().reset()
         engine.destroy()
@@ -383,14 +386,14 @@ def test_v2_warmup_precompiles_decode():
 
     from deepspeed_tpu.monitor.trace import configure_tracer, get_tracer
 
+    get_tracer().reset()
     configure_tracer(enabled=True)
-    get_tracer().drain()
     try:
         res = eng.warmup([2], 4)  # 2 seqs rounds up to the wrapper's bucket (4)
     finally:
         compiles = [e for e in get_tracer().drain()
                     if e.get("name") == "jax_compile" and e.get("args", {}).get("source") == "warmup"]
-        configure_tracer(enabled=False)
+        get_tracer().reset()
     assert ("decode", 4, 4, False) in eng._compiled  # (seqs, steps, sampled)
     assert res == [{"seqs": 4, "steps": 4, "seconds": res[0]["seconds"], "cached": False}]
     assert compiles and compiles[0]["args"]["seqs"] == 4

@@ -333,8 +333,7 @@ def test_prefix_metrics_and_trace_span(tiny_model, tmp_path):
             assert any('"prefix_hit"' in line for line in f)
     finally:
         configure_metrics(enabled=False)
-        get_tracer().close()
-        configure_tracer(enabled=False)
+        get_tracer().reset()
 
 
 def test_publish_race_keeps_evictable_exact(tiny_model):

@@ -1,0 +1,342 @@
+"""The one span primitive (``monitor/trace.py``) and the spans the serving
+loop, the scheduler, the engine and ``train_batch`` open with it: under a
+profiler session they are ``dstpu/<name>`` annotations in the xplane file,
+properly nested on the thread that ran them, with their arguments; with the
+JSONL bus on they are the same spans once each under their bus names; with
+both off nothing is allocated. Read back with the benchmark's own reader
+(``benchmark/lib/program_spans.py``), the one the per-layer metrics use."""
+
+import os
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir))
+
+from benchmark.lib import program_spans  # noqa: E402
+from deepspeed_tpu.models import TransformerConfig, TransformerLM  # noqa: E402
+from deepspeed_tpu.monitor.metrics import get_metrics  # noqa: E402
+from deepspeed_tpu.monitor.trace import NULL_SPAN, get_tracer  # noqa: E402
+from deepspeed_tpu.ops.pallas import paged_attention as pa  # noqa: E402
+
+from conftest import tiny_batch  # noqa: E402
+
+ENGINE_CHILDREN = {"serving/engine_batch", "serving/engine_dispatch", "serving/engine_fetch",
+                   "serving/engine_commit"}
+ENGINE_STEPS = {"serving/prefill", "serving/decode_step", "serving/decode"}
+SCHED_ARGS = {"kind", "rows", "rows_decode", "tokens", "prefill_tokens", "pending", "active",
+              "budget_left", "first_wait_ms"}
+
+
+@pytest.fixture(autouse=True)
+def _fresh_tracer():
+    """The tracer is a process singleton and ``--dist loadfile`` puts several
+    test files in one process: start and end every test from ``reset()``."""
+    get_tracer().reset()
+    yield
+    get_tracer().reset()
+    get_metrics().disable()
+    get_metrics().reset()
+
+
+def _engine():
+    from deepspeed_tpu.inference.v2 import InferenceEngineV2, RaggedInferenceEngineConfig
+
+    cfg = TransformerConfig(vocab_size=128, hidden_size=64, num_layers=2, num_heads=4,
+                            intermediate_size=128, max_seq_len=128, dtype=jnp.float32,
+                            attention_impl="reference")
+    icfg = RaggedInferenceEngineConfig()
+    icfg.use_pallas_kernels = "always"  # the paged kernel's module: off the TPU it takes its reference
+    icfg.kv_block_size = 16
+    icfg.num_kv_blocks = 32
+    icfg.state_manager.max_tracked_sequences = 4
+    icfg.state_manager.max_ragged_sequence_count = 4
+    icfg.state_manager.max_ragged_batch_size = 32
+    icfg.state_manager.max_context = 96
+    return InferenceEngineV2(TransformerLM(cfg), icfg)
+
+
+def _serve(engine, uid_base=0):
+    """Three requests through a scheduler of its own: SplitFuse puts (a
+    40-token prompt against a 32-token budget is chunked), then decode bursts."""
+    from deepspeed_tpu.inference.v2 import DynamicSplitFuseScheduler
+
+    sched = DynamicSplitFuseScheduler(engine, token_budget=32)
+    rng = np.random.default_rng(0)
+    for i, (n_prompt, n_new) in enumerate(((40, 6), (12, 9), (5, 4))):
+        sched.submit(uid_base + i, rng.integers(0, 128, size=n_prompt, dtype=np.int32), max_new_tokens=n_new)
+    return sched.run()
+
+
+def _profiled(tmp_path, fn):
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path, ) = list(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+    return program_spans.read(str(path))
+
+
+@pytest.fixture(scope="module")
+def engine():
+    return _engine()
+
+
+@pytest.fixture(scope="module")
+def serve_trace(engine, tmp_path_factory):
+    _serve(engine, uid_base=100)  # compile every program first: the traced run is warm
+    return _profiled(tmp_path_factory.mktemp("serve_trace"), lambda: _serve(engine, uid_base=200))
+
+
+def _children(trace, parent):
+    return [s for s in trace["spans"] if s is not parent and s.line == parent.line
+            and s.start_s >= parent.start_s and s.end_s <= parent.end_s]
+
+
+def test_profiler_sees_sched_step_with_nested_children_on_one_thread(serve_trace):
+    steps = program_spans.spans_named(serve_trace, "serving/sched_step")
+    assert len(steps) >= 4
+    assert len({s.line for s in serve_trace["spans"]}) == 1, "one thread ran them all"
+    for step in steps:
+        inside = _children(serve_trace, step)
+        engine_steps = [s for s in inside if s.name in ENGINE_STEPS]
+        assert len(engine_steps) == 1, [s.name for s in inside]
+        leaves = _children(serve_trace, engine_steps[0])
+        assert {s.name for s in leaves} == ENGINE_CHILDREN
+        assert sum(s.end_s - s.start_s for s in leaves) <= engine_steps[0].end_s - engine_steps[0].start_s
+        assert engine_steps[0].end_s - engine_steps[0].start_s <= step.end_s - step.start_s
+    # no span outside a sched_step: the scheduler's step encloses the engine's
+    covered = {id(s) for step in steps for s in _children(serve_trace, step)} | {id(s) for s in steps}
+    assert all(id(s) in covered for s in serve_trace["spans"])
+
+
+def test_sched_step_arguments_are_present_and_consistent(serve_trace):
+    steps = program_spans.spans_named(serve_trace, "serving/sched_step")
+    kinds = [s.args["kind"] for s in steps]
+    assert set(kinds) == {"put", "decode"}
+    fed_first = []
+    for step in steps:
+        assert SCHED_ARGS <= set(step.args), sorted(step.args)
+        a = step.args
+        assert a["rows_decode"] <= a["rows"] and a["prefill_tokens"] <= a["tokens"]
+        (eng, ) = [s for s in _children(serve_trace, step) if s.name in ENGINE_STEPS]
+        assert eng.args["rows"] == a["rows"]
+        if a["kind"] == "put":
+            assert eng.name in ("serving/prefill", "serving/decode_step")
+            assert eng.args["tokens"] == a["tokens"] and eng.args["rows_decode"] == a["rows_decode"]
+            assert a["tokens"] == a["rows_decode"] + a["prefill_tokens"] and a["budget_left"] == 32 - a["tokens"]
+        else:
+            assert eng.name == "serving/decode" and a["tokens"] == a["rows"] * eng.args["steps"]
+        fed_first += program_spans.numbers(a["first_wait_ms"])
+    assert len(fed_first) == 3 and all(w >= 0 for w in fed_first), "one wait per request, at its first chunk"
+
+
+def test_engine_step_spans_carry_buckets_steps_and_kernel(serve_trace):
+    found = set()
+    for span in serve_trace["spans"]:
+        if span.name not in ENGINE_STEPS:
+            continue
+        found.add(span.name)
+        a = span.args
+        assert a["rows"] <= a["bucket_rows"], a
+        assert a["tokens"] <= a["bucket_tokens"] * a["steps"], a
+        assert a["steps"] == 1 or span.name == "serving/decode"
+        # off the TPU the paged kernel is the gather reference, and the span says so
+        assert a["kernel"] == "paged_attention_reference:1:off_tpu", a
+    assert {"serving/prefill", "serving/decode"} <= found
+    dispatches = program_spans.spans_named(serve_trace, "serving/engine_dispatch")
+    assert dispatches and all(d.args["compiled"] == 0 for d in dispatches), "the traced run was warm"
+
+
+def test_bus_sees_the_same_spans_once_each_under_their_bus_names(engine, serve_trace):
+    tracer = get_tracer().configure(enabled=True)  # pathless buffer
+    _serve(engine, uid_base=300)
+    events = [e for e in tracer.drain() if e["ph"] == "X"]
+    assert all(not e["name"].startswith("dstpu/") for e in events)
+    by_name = lambda spans, key: sorted(key(s) for s in spans)
+    on_bus = by_name(events, lambda e: e["name"])
+    in_profile = by_name(serve_trace["spans"], lambda s: s.name)
+    assert on_bus == in_profile, "same schedule, same spans, each once"
+    step = next(e for e in events if e["name"] == "serving/sched_step")
+    assert SCHED_ARGS <= set(step["args"])
+
+
+def test_process_name_event_carries_both_clock_origins():
+    tracer = get_tracer().configure(enabled=True)
+    (meta, ) = [e for e in tracer.drain() if e["name"] == "process_name"]
+    origin_ns, origin_pc = meta["args"]["origin_unix_ns"], meta["args"]["origin_perf_counter"]
+    # the same instant on both clocks: now, measured on each, is equally far from it
+    assert abs((time.time_ns() - origin_ns) * 1e-9 - (time.perf_counter() - origin_pc)) < 0.05
+
+
+def test_both_sinks_off_allocates_nothing_and_the_kernel_table_fills_at_trace_time_only(engine):
+    tracer = get_tracer()
+    assert not tracer.enabled and not jax.profiler.TraceAnnotation.is_enabled()
+    assert tracer.span("serving/sched_step", tid="serving") is NULL_SPAN
+    _serve(engine, uid_base=400)
+    assert tracer.drain() == []
+    # the table is written while jit traces a program, never when one runs
+    q, pool = jnp.ones((8, 4, 16)), jnp.ones((64, 4, 16))
+    tables, seq_idx, pos = jnp.zeros((2, 4), jnp.int32), jnp.zeros((8, ), jnp.int32), jnp.arange(8)
+    fn = jax.jit(lambda q: pa.paged_attention(q, pool, pool, tables, seq_idx, pos, 16))
+    pa.KERNEL_CHOICES.pop((8, 2, 4), None)
+    fn(q)
+    assert pa.kernel_choice(8, 2, 4) == {"kernel": "paged_attention_reference", "q_tile": 1, "kv_splits": 1,
+                                         "rule": "off_tpu"}
+    pa.KERNEL_CHOICES.clear()
+    fn(q)
+    assert pa.KERNEL_CHOICES == {}
+
+
+@pytest.mark.parametrize("shape,env,want", [
+    ((512, 8, 65), {}, (8, "heuristic:prefill_ish", 1, "heuristic:tiled")),
+    ((32, 32, 65), {}, (1, "heuristic:decode_shaped", 8, "heuristic:long_table")),
+    ((32, 32, 4), {}, (1, "heuristic:decode_shaped", 1, "heuristic:short_table")),
+    ((32, 32, 65), {"DS_TPU_PAGED_KV_SPLITS": "1"}, (1, "heuristic:decode_shaped", 1, "env")),
+    ((512, 8, 65), {"DS_TPU_PAGED_Q_TILE": "16"}, (16, "env", 1, "heuristic:tiled")),
+])
+def test_kernel_choice_names_the_rule_that_decided(monkeypatch, tmp_path, shape, env, want):
+    from deepspeed_tpu.autotuning.kernel_config import set_kernel_config_path
+
+    set_kernel_config_path(str(tmp_path / "none.json"))
+    for k, v in env.items():
+        monkeypatch.setenv(k, v)
+    try:
+        T, S, max_blocks = shape
+        q_tile, q_rule = pa._q_tile_choice(T, S)
+        kv_splits, kv_rule = pa._kv_splits_choice(T, S, max_blocks, q_tile=q_tile)
+        assert (q_tile, q_rule, kv_splits, kv_rule) == want
+        assert pa._resolve_q_tile(T, S) == q_tile and pa._resolve_kv_splits(T, S, max_blocks, q_tile) == kv_splits
+    finally:
+        set_kernel_config_path(None)
+
+
+@pytest.mark.parametrize("T,S,max_blocks,want", [
+    (512, 32, 65, {"kernel": "paged_attn_q_tiled", "q_tile": 8, "kv_splits": 1, "rule": "heuristic:prefill_ish"}),
+    (32, 32, 65, {"kernel": "paged_attn_kv_split", "q_tile": 1, "kv_splits": 8, "rule": "heuristic:long_table"}),
+    (32, 32, 4, {"kernel": "paged_attn_per_token", "q_tile": 1, "kv_splits": 1, "rule": "heuristic:short_table"}),
+])
+def test_on_the_tpu_branch_the_table_names_the_grid_that_runs(monkeypatch, tmp_path, T, S, max_blocks, want):
+    """The TPU branch of ``paged_attention`` with the kernel itself stubbed
+    out: the recorded kernel is the one ``_pallas_paged`` is handed."""
+    from deepspeed_tpu.autotuning.kernel_config import set_kernel_config_path
+
+    set_kernel_config_path(str(tmp_path / "none.json"))
+    handed = {}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(pa, "_pallas_paged", lambda q, *a, q_tile, kv_splits, **kw: handed.update(
+        q_tile=q_tile, kv_splits=kv_splits) or q)
+    try:
+        q, pool = jnp.ones((T, 8, 128)), jnp.ones((max_blocks * 16, 8, 128))
+        pa.paged_attention(q, pool, pool, jnp.zeros((S, max_blocks), jnp.int32), jnp.zeros((T, ), jnp.int32),
+                           jnp.zeros((T, ), jnp.int32), 16)
+        assert pa.kernel_choice(T, S, max_blocks) == want
+        assert handed == {"q_tile": want["q_tile"], "kv_splits": want["kv_splits"]}
+    finally:
+        set_kernel_config_path(None)
+        pa.KERNEL_CHOICES.pop((T, S, max_blocks), None)
+
+
+def test_contiguity_demotion_and_tuned_rule(tmp_path):
+    from deepspeed_tpu.autotuning.kernel_config import set_kernel_config_path, shape_bucket
+
+    reg = set_kernel_config_path(str(tmp_path / "kc.json"))
+    try:
+        interleaved = np.tile(np.arange(2), 32).astype(np.int32)
+        assert pa._q_tile_choice(64, 2, interleaved) == (1, "contiguity_demoted")
+        reg.record("paged_attention", shape_bucket(T=512), {"q_tile": 16})
+        assert pa._q_tile_choice(512, 8) == (16, "tuned")
+        reg.record("paged_attention", shape_bucket(B=65), {"kv_splits": 4})
+        assert pa._kv_splits_choice(32, 32, 65) == (4, "tuned")
+    finally:
+        set_kernel_config_path(None)
+
+
+def _train_engine(extra):
+    import deepspeed_tpu
+
+    model = TransformerLM(TransformerConfig(vocab_size=128, hidden_size=64, num_layers=2, num_heads=4,
+                                            max_seq_len=64, intermediate_size=128,
+                                            attention_impl="reference", dtype=jnp.float32))
+    cfg = {"train_batch_size": 16, "train_micro_batch_size_per_gpu": 2, "gradient_accumulation_steps": 1,
+           "optimizer": {"type": "Adam", "params": {"lr": 1e-3}}, "tpu": {"mesh": {"data": 8}},
+           "steps_per_print": 1000}
+    cfg.update(extra)
+    engine, _, _, _ = deepspeed_tpu.initialize(model=model, config=cfg)
+    return engine
+
+
+def test_traced_train_batch_does_not_block_and_yields_input_wait_and_dispatch(tmp_path, eight_devices):
+    engine = _train_engine({"trace": {}})  # presence-enables the bus, pathless
+    assert get_tracer().enabled
+    blocked = []
+    real = jax.block_until_ready
+    batch = tiny_batch(batch_size=16, seq=32)
+    engine.train_batch(batch)  # builds the program
+    get_tracer().drain()
+    jax.block_until_ready = lambda x: (blocked.append(1), real(x))[1]
+    try:
+        trace = _profiled(tmp_path, lambda: [engine.train_batch(batch) for _ in range(2)])
+    finally:
+        jax.block_until_ready = real
+    assert blocked == [], "a traced step stays in flight like an untraced one"
+    events = [e for e in get_tracer().drain() if e["ph"] == "X"]
+    names = [e["name"] for e in events]
+    assert names.count("input_wait") == 2 and names.count("train/dispatch") == 2 and names.count("train_batch") == 2
+    assert all(e["args"]["blocked"] is False for e in events if e["name"] == "train_batch")
+    wait = next(e for e in events if e["name"] == "input_wait")
+    assert wait["args"]["prefetched"] is False and "step" in wait["args"]
+    dispatch = program_spans.spans_named(trace, "train/dispatch")
+    assert len(dispatch) == 2 and all(d.args["compiled"] == 0 for d in dispatch)
+    assert len(program_spans.spans_named(trace, "input_wait")) == 2
+    # the driver thread of a training run is the one that dispatches
+    assert {name for name, _, _ in program_spans.driver_segments(trace)} == {"input_wait", "train/dispatch"}
+    engine.destroy()
+
+
+def test_reset_returns_the_singleton_to_a_fresh_state(tmp_path):
+    class Mirror:
+        def record_event(self, ev):
+            pass
+
+    tracer = get_tracer().configure(enabled=True, path=str(tmp_path / "t.jsonl"), flush_every=1)
+    tracer.set_mirror(Mirror())
+    with tracer.span("fwd"):
+        pass
+    tracer.reset()
+    assert not tracer.enabled and tracer._path is None and tracer._mirror is None and tracer._fh is None
+    assert tracer.span("fwd") is NULL_SPAN
+    tracer.configure(enabled=True)  # pathless again: events stay in the buffer
+    with tracer.span("bwd"):
+        pass
+    assert [e["name"] for e in tracer.drain() if e["ph"] == "X"] == ["bwd"]
+
+
+def test_synchronized_timer_covers_an_unfetched_jitted_call():
+    from deepspeed_tpu.utils.timer import SynchronizedWallClockTimer
+
+    @jax.jit
+    def heavy(x):
+        return jax.lax.fori_loop(0, 40, lambda i, a: jnp.tanh(a @ a) * 0.5 + a * 0.5, x)
+
+    x = jnp.ones((500, 500))
+    heavy(x).block_until_ready()
+    t0 = time.perf_counter()
+    y = heavy(x)
+    enqueue_s = time.perf_counter() - t0
+    y.block_until_ready()
+    run_s = time.perf_counter() - t0
+    assert enqueue_s < run_s / 5, "the call returns long before it has run"
+    timer = SynchronizedWallClockTimer.Timer("heavy")
+    timer.start()
+    y = heavy(x)  # never fetched: only the timer's fence waits for it
+    timer.stop()
+    assert timer.elapsed(reset=False) > run_s / 3, "the timer covers the run, not the enqueue"

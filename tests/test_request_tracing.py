@@ -51,11 +51,7 @@ def _reset_trace_bus():
     so this module's enables never leak into other test files (the
     test_monitor_trace/test_health contract)."""
     yield
-    tr = get_tracer()
-    tr.set_mirror(None)
-    tr.configure(enabled=False)
-    tr.drain()
-    tr._path = None
+    get_tracer().reset()
     get_flight_recorder().configure(enabled=False)
     get_flight_recorder().clear()
 

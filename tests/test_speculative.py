@@ -551,8 +551,7 @@ def test_spec_metrics_counters_gauge_and_span(tiny_model, tmp_path):
                 "spec_verify span missing from the trace bus"
     finally:
         configure_metrics(enabled=False)
-        get_tracer().close()
-        configure_tracer(enabled=False)
+        get_tracer().reset()
 
 
 # ---------------------------------------------------------------------------

@@ -50,11 +50,7 @@ def _reset_trace_bus():
     """Tracer/flight are process singletons: leave them disarmed and empty
     so this module's enables never leak into other test files."""
     yield
-    tr = get_tracer()
-    tr.set_mirror(None)
-    tr.configure(enabled=False)
-    tr.drain()
-    tr._path = None
+    get_tracer().reset()
     get_flight_recorder().configure(enabled=False)
     get_flight_recorder().clear()
 

@@ -38,6 +38,11 @@ RAW_RESPONSE_CALLS = ("send_response", "send_header", "end_headers")
 KEYWORD_EMITTERS = ("instant", "span")
 ARGSDICT_EMITTERS = ("complete",)
 
+# the replica driver's spans of its own loop: each covers every request in
+# flight (what was pulled, fanned out or waited for), so none has ONE id to
+# carry; the per-request overlays (serving/reqtrace.py) join through uids
+LOOP_SPANS = ("serving/loop_pull", "serving/loop_fanout", "serving/loop_idle")
+
 
 def _call_attr_name(node):
     if isinstance(node, ast.Call) and isinstance(node.func, ast.Attribute):
@@ -103,6 +108,9 @@ def _check_span_request_ids(path, src, tree):
         if name is None:
             continue
         why = None
+        if name == "span" and node.args and isinstance(node.args[0], ast.Constant) \
+                and node.args[0].value in LOOP_SPANS:
+            continue
         if name in KEYWORD_EMITTERS:
             if not any(kw.arg == "request_id" for kw in node.keywords):
                 why = f"'{name}' emission without a request_id= keyword"
