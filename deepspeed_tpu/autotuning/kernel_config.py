@@ -322,7 +322,9 @@ class KernelAutotuner:
         # backend: _resolve_q_tile only consults the T-only bucket for such
         # shapes, so a smaller smoke sweep would record winners no live call
         # can reach
-        T = T or (256 if on_tpu else 128)
+        # on the chip, rows of 256 tokens: long enough for the 128- and
+        # 256-token tiles among the candidates to be filled
+        T = T or (1024 if on_tpu else 128)
         n_seqs = min(n_seqs, max(1, T // 64))
         bs = block_size or (128 if on_tpu else 16)
         if not on_tpu:
@@ -337,7 +339,7 @@ class KernelAutotuner:
         per = T // n_seqs
         seq_idx = jnp.asarray(np.repeat(np.arange(n_seqs), per)[:T], jnp.int32)
         pos = jnp.asarray(np.tile(np.arange(per), n_seqs)[:T] + bs, jnp.int32)
-        cands = candidates or [{"q_tile": qt} for qt in ((1, 8, 16, 32) if on_tpu else (1, 4, 8))]
+        cands = candidates or [{"q_tile": qt} for qt in ((1, 8, 32, 64, 128, 256) if on_tpu else (1, 4, 8))]
 
         def build(c):
             return lambda: _pallas_paged(q, k_pool, v_pool, tables, seq_idx, pos,

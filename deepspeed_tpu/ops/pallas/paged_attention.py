@@ -28,16 +28,25 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def _contiguity_ok(seq_idx, S: int) -> bool:
+def _contiguity_ok(seq_idx, S: int, pos=None) -> bool:
     """True when the tiled grid's layout contract holds: same-sequence
-    tokens contiguous, at most S runs plus the trailing pad run. Traced
-    ``seq_idx`` (the jitted ragged step) is covered by the SplitFuse batch
-    layout invariant itself (``ragged_wrapper.finalize``)."""
+    tokens contiguous, at most S runs plus the trailing pad run, and (where
+    ``pos`` is given) a run's positions ascending by one (a chunk) or
+    standing still (the pad run) — a position that falls starts a new run,
+    and a tile's positions span at most ``q_tile``, which is what bounds its
+    KV blocks under a sliding window. Traced arguments (the jitted ragged
+    step) are covered by the SplitFuse batch layout invariant itself
+    (``ragged_wrapper.finalize``)."""
     if seq_idx is None or isinstance(seq_idx, jax.core.Tracer):
         return True
     s = np.asarray(seq_idx)
-    runs = 1 + int(np.count_nonzero(s[1:] != s[:-1])) if s.size else 1
-    return runs <= S + 1
+    newrun = s[1:] != s[:-1]
+    if pos is not None and not isinstance(pos, jax.core.Tracer):
+        step = np.diff(np.asarray(pos))
+        if np.any(~newrun & (step > 1)):
+            return False
+        newrun = newrun | (step < 0)
+    return 1 + int(np.count_nonzero(newrun)) <= S + 1
 
 
 # Which kernel each traced shape took, and the rule that decided:
@@ -59,6 +68,12 @@ def _note_choice(T, S, max_blocks, kernel, q_tile, kv_splits, rule):
         "kernel": kernel, "q_tile": int(q_tile), "kv_splits": int(kv_splits), "rule": rule}
 
 
+# The largest q-tile the shape heuristic gives: the MXU then sees ``g * 128``
+# query rows a kv head, and a KV block is fetched and cut into heads once per
+# 128 query tokens (256 measured 2-5% faster at twice the VMEM: PERF.md, PR 25).
+_LONG_ROW_TILE = 128
+
+
 def _resolve_q_tile(T: int, S: int, seq_idx=None) -> int:
     """Resolve the q-tile through the kernel-config registry
     (``autotuning/kernel_config.py``), falling back to the shape heuristic:
@@ -76,10 +91,30 @@ def _resolve_q_tile(T: int, S: int, seq_idx=None) -> int:
     return _q_tile_choice(T, S, seq_idx)[0]
 
 
-def _q_tile_choice(T: int, S: int, seq_idx=None):
+def _heuristic_q_tile(T: int, S: int):
+    """``(q_tile, rule)`` from the program's static shapes alone. ``T / S``
+    is the mean tokens a row of the bucket, and a step's chunks are longer
+    than its mean row because most rows beside them are one-token decode
+    rows, so the tile is the power of two at or under ``2 T / S`` (and
+    ``T``), between 8 and 128. A long-prompt step (2,048 tokens over at most
+    8 rows) takes the large tile (``long_rows``). A step with many rows for
+    its tokens (a 512-token SplitFuse ``put`` over 32 rows, a linear
+    speculative verify of k+1 tokens a row) takes a smaller one
+    (``short_rows``), because the grid has a tile per row beyond the chunks'
+    own and each costs a q and an output tile of DMA and its share of grid
+    steps. Fewer than two tokens a row (or a tiny batch) is decode and leaves
+    the tiled grid alone."""
+    per_row2 = min(T, 2 * T // max(S, 1))
+    if T < 64 or per_row2 < 4:
+        return 1, "heuristic:decode_shaped"
+    qt = max(8, min(_LONG_ROW_TILE, 1 << (per_row2.bit_length() - 1)))
+    return qt, "heuristic:long_rows" if qt == _LONG_ROW_TILE else "heuristic:short_rows"
+
+
+def _q_tile_choice(T: int, S: int, seq_idx=None, pos=None):
     """``(q_tile, rule)``: :func:`_resolve_q_tile` with the rule that decided
-    (``env``, ``tuned``, ``heuristic:prefill_ish``, ``heuristic:decode_shaped``
-    or ``contiguity_demoted``)."""
+    (``env``, ``tuned``, ``heuristic:long_rows``, ``heuristic:short_rows``,
+    ``heuristic:decode_shaped`` or ``contiguity_demoted``)."""
     from ...autotuning.kernel_config import shape_bucket, tuned_tile
 
     # DS_TPU_PAGED_Q_TILE: operator override — =1 pins the per-token grid
@@ -90,11 +125,11 @@ def _q_tile_choice(T: int, S: int, seq_idx=None):
             qt = max(1, int(env))
         except ValueError:
             qt = 1
-        if qt > 1 and not _contiguity_ok(seq_idx, S):
+        if qt > 1 and not _contiguity_ok(seq_idx, S, pos):
             return 1, "contiguity_demoted"
         return qt, "env"
 
-    prefill_ish = T >= 64 and T >= 2 * max(S, 1)
+    qt, rule = _heuristic_q_tile(T, S)
     # lookup order: exact (T, S) bucket, then — for prefill-ish shapes
     # ONLY — the T-only bucket the sweep records (S here is block-table
     # CAPACITY, which varies per deployment, so T generalizes over it). A
@@ -102,17 +137,13 @@ def _q_tile_choice(T: int, S: int, seq_idx=None):
     # prefill-tuned tile from the T-only key: every tile would carry qt-1
     # masked slots for zero KV amortization.
     tuned = tuned_tile("paged_attention", shape_bucket(T=T, S=S), "q_tile", None)
-    if tuned is None and prefill_ish:
+    if tuned is None and qt > 1:
         tuned = tuned_tile("paged_attention", shape_bucket(T=T), "q_tile", None)
     if tuned is not None:
-        qt, rule = int(tuned), "tuned"
-    elif prefill_ish:
-        qt, rule = 8, "heuristic:prefill_ish"
-    else:
-        qt, rule = 1, "heuristic:decode_shaped"
-    if qt > 1 and not _contiguity_ok(seq_idx, S):
+        qt, rule = max(int(tuned), 1), "tuned"
+    if qt > 1 and not _contiguity_ok(seq_idx, S, pos):
         return 1, "contiguity_demoted"
-    return max(qt, 1), rule
+    return qt, rule
 
 
 def _resolve_kv_splits(T: int, S: int, max_blocks: int, q_tile: int = 1) -> int:
@@ -180,9 +211,11 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: i
     slot); dequant happens at the kernel's tile read, so only int8 bytes
     stream from HBM.
     ``q_tile``: tokens per q-tile grid row (None = kernel-config registry,
-    then shape heuristic). q_tile > 1 packs contiguous same-sequence tokens
-    into one grid row so each KV block streams from HBM once per TILE
-    instead of once per token — the prefill-chunk amortization win.
+    then the shape heuristic: 128 for long rows, the power of two under
+    ``2 T / S`` for many short ones, 1 for decode). q_tile > 1 packs contiguous
+    same-sequence tokens into one grid row so each KV block streams from HBM
+    once per TILE instead of once per token, and each kv head's dot feeds the
+    MXU ``g * q_tile`` rows — the prefill-chunk amortization win.
     ``kv_splits``: flash-decode KV partitioning for the per-token (decode)
     grid — each split runs a partial online softmax over its share of the
     KV blocks on its own grid row (megacore-parallel on chip) and the
@@ -214,8 +247,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: i
         # the option nor the remedy
         raise ValueError(f"int8 KV on TPU needs kv_block_size % 128 == 0, got {block_size}")
     if q_tile is None:
-        q_tile, q_rule = _q_tile_choice(T, S, seq_idx)
-    elif q_tile > 1 and not _contiguity_ok(seq_idx, S):
+        q_tile, q_rule = _q_tile_choice(T, S, seq_idx, pos)
+    elif q_tile > 1 and not _contiguity_ok(seq_idx, S, pos):
         # an explicit q_tile must not bypass the layout contract: a
         # non-contiguous batch would overflow the tiled grid's static tile
         # bound and silently scatter tokens into the wrong tiles
@@ -305,6 +338,18 @@ def _slopes_rows(alibi, reps):
     kernel trace ("captures constants ... pass them as inputs"), which
     silently broke the per-token alibi path before this helper."""
     return jnp.concatenate([jnp.full((reps, 1), float(a), jnp.float32) for a in alibi], axis=0)
+
+
+def _slopes_tok_major(alibi_g, rows):
+    """Alibi slopes of one kv head's ``g`` query heads as a [rows, 1] column
+    for token-major rows (``row = t * g + h``), from scalar constants (see
+    :func:`_slopes_rows`)."""
+    out = jnp.full((rows, 1), float(alibi_g[0]), jnp.float32)
+    if len(alibi_g) > 1:
+        h = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) % len(alibi_g)
+        for i, a in enumerate(alibi_g[1:], start=1):
+            out = jnp.where(h == i, float(a), out)
+    return out
 
 
 @functools.partial(jax.jit, static_argnames=("block_size", "interpret", "window", "alibi",
@@ -446,12 +491,44 @@ def _pallas_paged(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: int
                               seq_idx, pos, block_tables, *operands)
 
 
+# tokens of a tile's rows that the short pass of ``_paged_q_tiled`` covers: a
+# tile holding no more than this many valid tokens (a one-token decode row
+# riding beside a prefill chunk, a short ragged tail) meets the MXU with these
+# rows only, whatever ``q_tile`` is
+_SHORT_TILE_TOKENS = 8
+_LANES = 128
+
+
+def _q_tiled_vmem_bytes(R: int, G: int, d: int, block_size: int, nkv: int, q_itemsize: int,
+                        kv_itemsize: int) -> int:
+    """VMEM working set of one ``paged_attn_q_tiled`` grid step: the q and
+    output tiles and the K and V blocks double-buffered by the pipeline (and
+    the block once more, cut by kv head), the float32 ``acc``, the
+    lane-replicated ``m``/``l`` and positions, and one kv head's float32
+    scores and probabilities."""
+    tiles = 2 * 2 * R * d * q_itemsize                        # q in, o out
+    kv = 2 * 2 * block_size * max(nkv, 16) * d * kv_itemsize  # K, V (sublane-padded)
+    kv += 2 * nkv * block_size * d * 4                        # by head, at most float32
+    state = R * d * 4 + 2 * R * _LANES * 4                    # acc, m, l
+    pos = 2 * G * _LANES * 4
+    head = 4 * G * max(block_size, _LANES) * 4                # s, p and their temporaries
+    return tiles + kv + state + pos + head
+
+
+def _lanes(x, n: int):
+    """A lane-replicated ``[rows, 128]`` column as ``[rows, n]``: itself when
+    the widths agree (no broadcast is emitted), else its first lane spread."""
+    return x if x.shape[1] == n else jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
 def _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
                    block_size: int, q_tile: int, window, alibi, interpret: bool):
-    """Q-tiled grid: ``(n_tiles, max_blocks)`` where each tile packs up to
+    """Q-tiled grid: ``(n_tiles, kv_steps)`` where each tile packs up to
     ``q_tile`` CONTIGUOUS same-sequence tokens, so every KV block streams
-    from HBM once per *tile* instead of once per token — a 256-token prefill
-    chunk at q_tile=8 reads each of its KV blocks 32x instead of 256x.
+    from HBM (and is cut into kv heads) once per *tile* instead of once per
+    token — a 2,048-token prefill chunk at q_tile=128 reads each of its KV
+    blocks 16x instead of 2,048x, and each kv head's dot feeds the MXU
+    ``g * q_tile`` query rows.
 
     Tile assembly happens in jnp-land (traced, static shapes): a segmented
     tiling over the ragged batch — tiles never span a sequence boundary, so
@@ -459,9 +536,22 @@ def _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
     upper bound ceil(T/q_tile) + S + 1 (interior splits + one ragged tail
     tile per sequence run + the trailing pad run); unused tiles carry
     ``max_pos = -1`` and every kv step skips them. Ragged tile tails ride the
-    existing per-token ``pl.when``/position masking (invalid slots get
-    pos = -1, masking every context position). int8-KV dequant, alibi and
-    sliding window are preserved bit-for-bit from the per-token grid.
+    per-row position masking (invalid slots get pos = -1, masking every
+    context position).
+
+    Inside a grid step the block is cut into kv heads once (into a small
+    scratch) and the heads are walked one at a time, so what lives at once is
+    one head's ``[g * q_tile, block]`` scores beside the resident q / output
+    tile and the ``acc``/``m``/``l`` scratch (sized by
+    :func:`_q_tiled_vmem_bytes`). Rows of a kv head are token-major
+    (``row = t * g + h``): a tile's valid rows are a prefix, and a tile with
+    at most ``_SHORT_TILE_TOKENS`` valid tokens runs only that prefix through
+    the MXU. Both dots take the operands in the precision they arrive in
+    (bf16 q and pool: bf16 operands, float32 accumulation; the scores, the
+    masking and the softmax state are float32); int8 KV dequantises at the
+    tile in float32. ``m``, ``l`` and the positions are kept replicated
+    across the 128 lanes, so that with 128-token blocks no step broadcasts a
+    column. Alibi and the sliding window mask as on the per-token grid.
     """
     T, nq, d = q.shape
     nkv = k4.shape[2]
@@ -471,10 +561,19 @@ def _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
     quant = ks2 is not None
     scale = 1.0 / math.sqrt(d)
     n_tiles = -(-T // qt) + S + 1
+    G = g * qt                     # rows of one kv head in a tile
+    R = nkv * G                    # == nq * qt
+    short = min(G, g * _SHORT_TILE_TOKENS)
+    # operands of the two dots: what q and the pool hold, unless the pool is
+    # quantised (its dequantised values are float32)
+    cdt = jnp.float32 if quant else jnp.promote_types(q.dtype, k4.dtype)
 
     # --- segmented tile descriptors (contiguity contract: see paged_attention) ---
     tok = jnp.arange(T, dtype=jnp.int32)
-    newrun = jnp.concatenate([jnp.ones((1, ), bool), seq_idx[1:] != seq_idx[:-1]])
+    # a run is one sequence's chunk: the sequence changes, or the position
+    # falls (the pad run, seq 0 at position 0, behind a chunk of row 0)
+    newrun = jnp.concatenate([jnp.ones((1, ), bool),
+                              jnp.logical_or(seq_idx[1:] != seq_idx[:-1], pos[1:] < pos[:-1])])
     run_start = jax.lax.associative_scan(jnp.maximum, jnp.where(newrun, tok, 0))
     within = tok - run_start                      # offset inside this token's run
     tile_id = jnp.cumsum((within % qt == 0).astype(jnp.int32)) - 1   # [T]
@@ -485,42 +584,53 @@ def _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
     pos_t = jnp.where(valid, pos[tile_tok], -1)                      # [n_tiles, qt]
     tile_seq = jnp.where(valid[:, 0], seq_idx[tile_tok[:, 0]], 0)    # [n_tiles]
     tile_max = jnp.max(pos_t, axis=1)                                # -1 for empty tiles
-    tile_min = jnp.min(jnp.where(valid, pos_t, jnp.int32(2**30)), axis=1)
+    tile_cnt = jnp.sum(valid, axis=1, dtype=jnp.int32)               # valid slots (a prefix)
+    # first KV block any row of the tile can see, and the kv steps a tile can
+    # need: under a sliding window the live blocks of a tile are a run of at
+    # most (window + q_tile) / block, wherever in the context it lies (a
+    # run's positions ascend by one: the layout contract), so the kv axis
+    # walks that run instead of the whole table
+    if window is not None:
+        tile_min = jnp.min(jnp.where(valid, pos_t, jnp.int32(2**30)), axis=1)
+        tile_lo = jnp.where(tile_cnt > 0, jnp.maximum(tile_min - (window - 1), 0) // block_size, 0)
+        kv_steps = min(max_blocks, (window + qt - 2) // block_size + 2)
+    else:
+        tile_lo = jnp.zeros_like(tile_cnt)
+        kv_steps = max_blocks
 
-    # head-major row layout [n_tiles, R, d], row r = h*qt + t: each kv-head's
-    # g*qt query rows are contiguous. The rows (and their positions, as an
-    # [R, 1] column) are laid out HERE, by XLA, so every block's last two
+    # kv-head-major, then token-major row layout [n_tiles, R, d], row
+    # r = n*G + t*g + h: each kv head's G query rows are contiguous and its
+    # valid rows come first. The rows (and one kv head's positions, spread
+    # over the lanes) are laid out HERE, by XLA, so every block's last two
     # dims are (8, 128)-aligned or whole and the kernel never reshapes
     # across the sublane/lane boundary.
-    R = nq * qt
-    q_t = q[tile_tok.reshape(-1)].reshape(n_tiles, qt, nq, d).transpose(0, 2, 1, 3) \
+    q_t = q[tile_tok.reshape(-1)].reshape(n_tiles, qt, nkv, g, d).transpose(0, 2, 1, 3, 4) \
         .reshape(n_tiles, R, d)
-    pos_rows = jnp.broadcast_to(pos_t[:, None, :], (n_tiles, nq, qt)).reshape(n_tiles, R, 1)
-    grid = (n_tiles, max_blocks)
+    pos_rows = jnp.broadcast_to(jnp.repeat(pos_t, g, axis=1)[:, :, None], (n_tiles, G, _LANES))
+    grid = (n_tiles, kv_steps)
 
-    def q_map(i, j, seq_ref, max_ref, min_ref, bt_ref):
+    def q_map(i, j, seq_ref, max_ref, lo_ref, cnt_ref, bt_ref):
         return (i, 0, 0)
 
-    def kv_map(i, j, seq_ref, max_ref, min_ref, bt_ref):
-        # clamp j into the tile's live range (same Mosaic idiom as the
-        # per-token grid: skipped steps re-use the resident block)
+    def kv_map(i, j, seq_ref, max_ref, lo_ref, cnt_ref, bt_ref):
+        # clamp the step into the tile's live range [lo, hi] (same Mosaic
+        # idiom as the per-token grid: skipped steps re-use the resident block)
         hi = jnp.maximum(max_ref[i], 0) // block_size
-        jj = jnp.minimum(j, hi)
-        if window is not None:
-            lo = jnp.maximum(jnp.maximum(min_ref[i], 0) - (window - 1), 0) // block_size
-            jj = jnp.maximum(jj, jnp.minimum(lo, hi))
-        return (bt_ref[seq_ref[i], jj], 0, 0, 0)
+        return (bt_ref[seq_ref[i], jnp.minimum(lo_ref[i] + j, hi)], 0, 0, 0)
 
-    def scale_map(i, j, seq_ref, max_ref, min_ref, bt_ref):
-        return (0, kv_map(i, j, seq_ref, max_ref, min_ref, bt_ref)[0])
+    def scale_map(i, j, *refs):
+        return (0, kv_map(i, j, *refs)[0])
 
-    def kernel(seq_ref, max_ref, min_ref, bt_ref, q_ref, k_ref, v_ref, pos_ref, *rest):
+    nt_dims = (((1, ), (1, )), ((), ()))  # [rows, d] x [block, d] -> [rows, block]
+
+    def kernel(seq_ref, max_ref, lo_ref, cnt_ref, bt_ref, q_ref, k_ref, v_ref, pos_ref, *rest):
         if quant:
-            ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
+            ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref, kh_ref, vh_ref = rest
         else:
-            o_ref, acc_ref, m_ref, l_ref = rest
+            o_ref, acc_ref, m_ref, l_ref, kh_ref, vh_ref = rest
         i = pl.program_id(0)
         j = pl.program_id(1)
+        jb = lo_ref[i] + j  # the table column this step covers
 
         @pl.when(j == 0)
         def _init():
@@ -528,52 +638,73 @@ def _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
             m_ref[:] = jnp.full_like(m_ref, -1e30)
             l_ref[:] = jnp.zeros_like(l_ref)
 
-        my_max = max_ref[i]
-        in_window = j * block_size <= my_max  # empty tile: my_max = -1, always skipped
-        if window is not None:
-            in_window = jnp.logical_and(
-                in_window, (j + 1) * block_size - 1 > min_ref[i] - window)
+        # blocks under the window's lower edge lie before lo; an empty tile
+        # has max = -1 and is skipped at every step
+        in_window = jb * block_size <= max_ref[i]
 
-        @pl.when(in_window)
-        def _compute():
-            qr = q_ref[0].astype(jnp.float32) * scale  # [R, d], rows r = h*qt + t
-            kb = k_ref[0].astype(jnp.float32)  # [bs, nkv, d]
-            vb = v_ref[0].astype(jnp.float32)
-            if quant:  # dequant at the VMEM tile — HBM only streamed int8
-                kb = kb * ks_ref[...].T[:, :, None]
-                vb = vb * vs_ref[...].T[:, :, None]
-            s_heads = []
-            for n in range(nkv):
-                s_heads.append(jax.lax.dot(qr[n * g * qt:(n + 1) * g * qt], kb[:, n, :].T))
-            s = jnp.concatenate(s_heads, axis=0)  # [R, bs]
-            my_pos = pos_ref[0]                   # [R, 1]; -1 on invalid slots
-            kpos = j * block_size + jax.lax.broadcasted_iota(jnp.int32, (R, block_size), 1)
-            if alibi is not None:
-                s = s + _slopes_rows(alibi, qt) * (kpos - my_pos).astype(jnp.float32)
+        def _compute(rows):
+            """One KV block against the first ``rows`` rows of every kv head."""
+            my_pos = _lanes(pos_ref[0, :rows, :], block_size)   # -1 on invalid slots
+            kpos = jb * block_size + jax.lax.broadcasted_iota(jnp.int32, (rows, block_size), 1)
             vis = kpos <= my_pos
             if window is not None:
                 vis = jnp.logical_and(vis, my_pos - kpos < window)
-            s = jnp.where(vis, s, -1e30)
-            m_prev = m_ref[:]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            ctx_heads = []
-            for n in range(nkv):
-                ctx_heads.append(jax.lax.dot(p[n * g * qt:(n + 1) * g * qt], vb[:, n, :]))
-            acc_ref[:] = acc_ref[:] * alpha + jnp.concatenate(ctx_heads, axis=0)
-            m_ref[:] = m_new
+            if alibi is not None:
+                rel = (kpos - my_pos).astype(jnp.float32)
+            kb, vb = k_ref[0], v_ref[0]            # [bs, nkv, d]
+            if quant:  # dequant at the VMEM tile — HBM only streamed int8
+                kb = kb.astype(jnp.float32) * ks_ref[...].T[:, :, None]
+                vb = vb.astype(jnp.float32) * vs_ref[...].T[:, :, None]
+            for n in range(nkv):  # cut the block into kv heads once, for the head loop to index
+                kh_ref[n] = kb[:, n, :].astype(cdt)
+                vh_ref[n] = vb[:, n, :].astype(cdt)
 
-        @pl.when(j == max_blocks - 1)
+            def head(n):
+                """One kv head's G rows: its working set is all that lives."""
+                r0 = n * G
+                if not isinstance(n, int) and G % 8 == 0:
+                    r0 = pl.multiple_of(r0, 8)
+                r = pl.ds(r0, rows)
+                s = jax.lax.dot_general(q_ref[0, r, :].astype(cdt), kh_ref[n], nt_dims,
+                                        preferred_element_type=jnp.float32) * scale
+                if alibi is not None:
+                    s = s + _slopes_tok_major(alibi[n * g:(n + 1) * g], rows) * rel
+                s = jnp.where(vis, s, -1e30)
+                m_prev = m_ref[r, :]               # [rows, 128], lanes equal
+                m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+                p = jnp.exp(s - _lanes(m_new, block_size))
+                alpha = jnp.exp(m_prev - m_new)
+                l_ref[r, :] = l_ref[r, :] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+                acc_ref[r, :] = acc_ref[r, :] * _lanes(alpha, d) + jax.lax.dot(
+                    p.astype(cdt), vh_ref[n], preferred_element_type=jnp.float32)
+                m_ref[r, :] = m_new
+
+            if alibi is None:
+                # traced once and unrolled by the lowering: the same straight
+                # line of eight heads as a Python loop gives Mosaic, at an
+                # eighth of the tracing (a rolled loop ran 1.6x slower; eight
+                # traced copies cost every program 0.3-1.0 s of set-up)
+                jax.lax.fori_loop(0, nkv, lambda n, c: (head(n), c)[1], 0, unroll=True)
+            else:  # the slopes are constants of the head
+                for n in range(nkv):
+                    head(n)
+
+        if short < G:
+            is_short = cnt_ref[i] <= _SHORT_TILE_TOKENS
+            pl.when(jnp.logical_and(in_window, is_short))(lambda: _compute(short))
+            pl.when(jnp.logical_and(in_window, jnp.logical_not(is_short)))(lambda: _compute(G))
+        else:
+            pl.when(in_window)(lambda: _compute(G))
+
+        @pl.when(j == kv_steps - 1)
         def _finalize():
-            o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)).astype(o_ref.dtype)
+            o_ref[0] = (acc_ref[:] / _lanes(jnp.maximum(l_ref[:], 1e-30), d)).astype(o_ref.dtype)
 
     in_specs = [
         pl.BlockSpec((1, R, d), q_map),
         pl.BlockSpec((1, block_size, nkv, d), kv_map),
         pl.BlockSpec((1, block_size, nkv, d), kv_map),
-        pl.BlockSpec((1, R, 1), q_map),
+        pl.BlockSpec((1, G, _LANES), q_map),
     ]
     operands = [q_t, k4, v4, pos_rows]
     if quant:
@@ -582,22 +713,32 @@ def _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
         operands += [ks2, vs2]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=5,
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, R, d), q_map),
         scratch_shapes=[
             pltpu.VMEM((R, d), jnp.float32),
-            pltpu.VMEM((R, 1), jnp.float32),
-            pltpu.VMEM((R, 1), jnp.float32),
+            pltpu.VMEM((R, _LANES), jnp.float32),
+            pltpu.VMEM((R, _LANES), jnp.float32),
+            pltpu.VMEM((nkv, block_size, d), cdt),   # the block's K, V by kv head
+            pltpu.VMEM((nkv, block_size, d), cdt),
         ],
     )
+    kwargs = {}
+    if not interpret:
+        # the default scoped limit (16 MiB on a v5e) is under the working set
+        # of a 128-token tile of 32 heads; ask for what the step needs plus
+        # half again for Mosaic's own temporaries
+        need = _q_tiled_vmem_bytes(R, G, d, block_size, nkv, q.dtype.itemsize, k4.dtype.itemsize)
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=max(32 << 20, min(need * 3 // 2, 100 << 20)))
     out_t = pl.pallas_call(kernel, grid_spec=grid_spec,
                            out_shape=jax.ShapeDtypeStruct((n_tiles, R, d), q.dtype),
-                           interpret=interpret, name="paged_attn_q_tiled")(
-                               tile_seq, tile_max, tile_min, block_tables, *operands)
+                           interpret=interpret, name="paged_attn_q_tiled", **kwargs)(
+                               tile_seq, tile_max, tile_lo, tile_cnt, block_tables, *operands)
     # scatter tiles back to token order
-    flat = out_t.reshape(n_tiles, nq, qt, d).transpose(0, 2, 1, 3).reshape(n_tiles * qt, nq, d)
+    flat = out_t.reshape(n_tiles, nkv, qt, g, d).transpose(0, 2, 1, 3, 4).reshape(n_tiles * qt, nq, d)
     return flat[tile_id * qt + slot]
 
 

@@ -15,8 +15,8 @@ from deepspeed_tpu.autotuning.kernel_config import (CONFIG_FILENAME, KernelAutot
                                                     KernelConfigRegistry, set_kernel_config_path,
                                                     shape_bucket, topology_key, tuned_tile)
 from deepspeed_tpu.models.transformer import alibi_slopes
-from deepspeed_tpu.ops.pallas.paged_attention import (_pallas_paged, _resolve_kv_splits,
-                                                      _resolve_q_tile,
+from deepspeed_tpu.ops.pallas.paged_attention import (_contiguity_ok, _pallas_paged, _q_tile_choice,
+                                                      _resolve_kv_splits, _resolve_q_tile,
                                                       paged_attention_reference)
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir))
@@ -97,6 +97,62 @@ def test_qtiled_parity_matrix(case, q_tile):
     out1 = _pallas_paged(q, kp, vp, tables, seq_idx, pos, block_size=bs, interpret=True,
                          q_tile=1, **kw)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(ref), rtol=2e-4, atol=2e-5)
+
+
+def _prefill_batch(rng, nq, d, bs, q_tile, dtype):
+    """A long-prompt SplitFuse step at the tile sizes the heuristic now picks:
+    a chunk longer than two tiles mid-context with a ragged tail (seq 0), two
+    one-token decode rows riding beside it (seqs 1, 2: the short pass), a
+    5-token chunk from position 0 (seq 3), and the trailing pad run
+    ragged_wrapper.finalize emits (seq 0, pos 0); with S = 4 the static tile
+    bound leaves empty tiles behind it."""
+    n_chunk = 2 * q_tile + 13
+    seq_idx = np.asarray([0] * n_chunk + [1, 2] + [3] * 5 + [0] * 4, np.int32)
+    pos = np.asarray(list(range(40, 40 + n_chunk)) + [20 * bs + 3, 9 * bs] + list(range(5)) + [0] * 4,
+                     np.int32)
+    q = jnp.asarray(rng.normal(size=(seq_idx.size, nq, d)), dtype)
+    return q, jnp.asarray(seq_idx), jnp.asarray(pos)
+
+
+@pytest.mark.parametrize("q_tile", [32, 128])
+@pytest.mark.parametrize("case", ["plain", "window", "alibi", "window_alibi", "int8", "int8_window",
+                                  "gqa4", "f32", "f32_window_alibi"])
+def test_qtiled_prefill_tile_parity_matrix(case, q_tile):
+    """The tiles the shape heuristic picks for prefill (32 for many rows, 128
+    for long prompts) against the gather oracle: bf16 q and pool, so both
+    dots run on bf16 operands with float32 accumulation, at the tolerances
+    the chip tests hold bf16 and int8 to; float32 inputs at the interpret
+    matrix's. The window (50) is shorter than a tile, so its lower edge
+    crosses KV blocks inside one tile."""
+    import zlib
+
+    f32 = case.startswith("f32")
+    dtype = jnp.float32 if f32 else jnp.bfloat16
+    nkv, g = (2, 4) if case == "gqa4" else (2, 2)
+    d, bs, blocks_per_seq = 32, 16, 24
+    rng, nq, kp, vp, tables, scales = _paged_setup(seed=zlib.crc32(case.encode()), nkv=nkv, g=g, d=d, bs=bs,
+                                                   n_seqs=4, blocks_per_seq=blocks_per_seq,
+                                                   int8=case.startswith("int8"))
+    if not scales:
+        kp, vp = kp.astype(dtype), vp.astype(dtype)
+    q, seq_idx, pos = _prefill_batch(rng, nq, d, bs, q_tile, dtype)
+    assert int(pos.max()) < blocks_per_seq * bs
+    kw = dict(scales)
+    if "alibi" in case:
+        kw["alibi"] = tuple(alibi_slopes(nq).tolist())
+    if "window" in case:
+        kw["window"] = 50
+    ref = paged_attention_reference(q, kp, vp, tables, seq_idx, pos, bs, **kw)
+    out = _pallas_paged(q, kp, vp, tables, seq_idx, pos, block_size=bs, interpret=True,
+                        q_tile=q_tile, **kw)
+    assert out.dtype == q.dtype and out.shape == q.shape
+    tol = dict(rtol=2e-4, atol=2e-5) if f32 else dict(rtol=2e-2, atol=2e-2) if scales else \
+        dict(rtol=5e-2, atol=5e-2)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref, np.float32), **tol)
+    if not f32:
+        # and tighter than any one element: the whole output within bf16 rounding
+        err = np.linalg.norm(np.asarray(out, np.float32) - np.asarray(ref, np.float32))
+        assert err / np.linalg.norm(np.asarray(ref, np.float32)) < 6e-3
 
 
 def test_qtiled_decode_only_with_pad_run():
@@ -256,13 +312,13 @@ def test_resolve_q_tile_contract_and_registry(tmp_path):
     seq_idx violating the same-sequence-contiguity contract demotes tiling
     to per-token (the tiled grid would otherwise overflow its tile bound)."""
     # heuristic: prefill-ish T tiles, pure-decode-ish T does not
-    assert _resolve_q_tile(256, 4) == 8
+    assert _resolve_q_tile(256, 4) == 128
     assert _resolve_q_tile(8, 8) == 1
     # contiguity guard on concrete seq_idx: alternating tokens -> demoted
     interleaved = jnp.asarray(np.arange(64) % 2, jnp.int32)
     assert _resolve_q_tile(64, 2, interleaved) == 1
     contiguous = jnp.asarray(np.repeat([0, 1], 32), jnp.int32)
-    assert _resolve_q_tile(64, 2, contiguous) == 8
+    assert _resolve_q_tile(64, 2, contiguous) == 64
     # registry override (planted for THIS topology) beats the heuristic
     reg = KernelConfigRegistry(str(tmp_path / CONFIG_FILENAME))
     reg.record("paged_attention", shape_bucket(T=256, S=4), {"q_tile": 16})
@@ -284,6 +340,51 @@ def test_resolve_q_tile_contract_and_registry(tmp_path):
         assert _resolve_q_tile(8, 8) == 16
     finally:
         del os.environ["DS_TPU_PAGED_Q_TILE"]
+
+
+@pytest.mark.parametrize("T,S,want", [
+    # mistral-7b.longprompt's prefill bucket (table 65 wide): hundreds of tokens a row
+    (2048, 8, (128, "heuristic:long_rows")),
+    (1024, 8, (128, "heuristic:long_rows")),
+    (2048, 1, (128, "heuristic:long_rows")),
+    # mistral-7b.chat's SplitFuse put: 512 tokens over 32 rows, most of them decode rows
+    (512, 32, (32, "heuristic:short_rows")),
+    (256, 32, (16, "heuristic:short_rows")),
+    (256, 8, (64, "heuristic:short_rows")),
+    (64, 1, (64, "heuristic:short_rows")),      # never a tile beyond the batch
+    (160, 32, (8, "heuristic:short_rows")),     # a linear verify of 5 tokens a row
+    (64, 32, (8, "heuristic:short_rows")),      # two tokens a row: the smallest tile
+    (63, 1, (1, "heuristic:decode_shaped")),    # a tiny batch
+    (32, 32, (1, "heuristic:decode_shaped")),
+    (64, 33, (1, "heuristic:decode_shaped")),   # fewer than two tokens a row
+])
+def test_q_tile_heuristic_follows_tokens_per_row(T, S, want):
+    """The tile follows from the static (T, S) alone, one rule name per
+    outcome; no registry, no environment."""
+    assert "DS_TPU_PAGED_Q_TILE" not in os.environ
+    assert _q_tile_choice(T, S) == want
+    assert _resolve_q_tile(T, S) == want[0]
+
+
+@pytest.mark.parametrize("q_tile", [32, 128])
+def test_qtiled_pad_run_behind_row0_under_window(q_tile):
+    """A one-row batch deep in its context, then the pad run (seq 0 again,
+    position 0): the fall in position starts a new run, so no tile mixes the
+    chunk's positions with the pad's and the window-bounded kv axis (here 7
+    and 13 steps of a 32-block table) still covers every row's blocks."""
+    rng, nq, kp, vp, tables, _ = _paged_setup(seed=3, n_seqs=2, blocks_per_seq=32)
+    d, bs, window = 32, 16, 50
+    n = q_tile + 9
+    seq_idx = jnp.asarray([0] * (n + 6), jnp.int32)
+    pos = jnp.asarray(list(range(350, 350 + n)) + [0] * 6, jnp.int32)
+    assert int(pos.max()) < 32 * bs
+    q = jnp.asarray(rng.normal(size=(n + 6, nq, d)), jnp.float32)
+    assert _contiguity_ok(seq_idx, 1, pos) and not _contiguity_ok(seq_idx, 1, pos[::-1])
+    assert not _contiguity_ok(seq_idx, 1, pos.at[3].add(7))  # a jump forward inside a run
+    ref = paged_attention_reference(q, kp, vp, tables, seq_idx, pos, bs, window=window)
+    out = _pallas_paged(q, kp, vp, tables, seq_idx, pos, block_size=bs, interpret=True,
+                        q_tile=q_tile, window=window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-5)
 
 
 def test_tuned_tile_consulted_by_every_call_site(tmp_path):

@@ -197,7 +197,9 @@ def test_both_sinks_off_allocates_nothing_and_the_kernel_table_fills_at_trace_ti
 
 
 @pytest.mark.parametrize("shape,env,want", [
-    ((512, 8, 65), {}, (8, "heuristic:prefill_ish", 1, "heuristic:tiled")),
+    ((2048, 8, 65), {}, (128, "heuristic:long_rows", 1, "heuristic:tiled")),  # mistral-7b.longprompt
+    ((512, 32, 65), {}, (32, "heuristic:short_rows", 1, "heuristic:tiled")),  # mistral-7b.chat's put
+    ((512, 8, 65), {}, (128, "heuristic:long_rows", 1, "heuristic:tiled")),
     ((32, 32, 65), {}, (1, "heuristic:decode_shaped", 8, "heuristic:long_table")),
     ((32, 32, 4), {}, (1, "heuristic:decode_shaped", 1, "heuristic:short_table")),
     ((32, 32, 65), {"DS_TPU_PAGED_KV_SPLITS": "1"}, (1, "heuristic:decode_shaped", 1, "env")),
@@ -220,7 +222,8 @@ def test_kernel_choice_names_the_rule_that_decided(monkeypatch, tmp_path, shape,
 
 
 @pytest.mark.parametrize("T,S,max_blocks,want", [
-    (512, 32, 65, {"kernel": "paged_attn_q_tiled", "q_tile": 8, "kv_splits": 1, "rule": "heuristic:prefill_ish"}),
+    (512, 32, 65, {"kernel": "paged_attn_q_tiled", "q_tile": 32, "kv_splits": 1, "rule": "heuristic:short_rows"}),
+    (2048, 8, 65, {"kernel": "paged_attn_q_tiled", "q_tile": 128, "kv_splits": 1, "rule": "heuristic:long_rows"}),
     (32, 32, 65, {"kernel": "paged_attn_kv_split", "q_tile": 1, "kv_splits": 8, "rule": "heuristic:long_table"}),
     (32, 32, 4, {"kernel": "paged_attn_per_token", "q_tile": 1, "kv_splits": 1, "rule": "heuristic:short_table"}),
 ])

@@ -347,6 +347,69 @@ def test_paged_attention_q_tiled_on_chip():
                                atol=2e-2, rtol=2e-2)
 
 
+def _paged_reference_by_run(q, k_pool, v_pool, tables, rows, bs, window):
+    """Float32 attention of ragged rows ``(context_before, new_tokens)``, one
+    sequence at a time and 256 queries at a time: the gather oracle
+    materialises a context per TOKEN, 71 GB at these shapes."""
+    T, nq, d = q.shape
+    nkv = k_pool.shape[1]
+    outs, t0 = [], 0
+    for r, (before, new) in enumerate(rows):
+        slots = (tables[r][:, None] * bs + jnp.arange(bs)[None, :]).reshape(-1)
+        k, v = k_pool[slots].astype(jnp.float32), v_pool[slots].astype(jnp.float32)
+        ctx = jnp.arange(k.shape[0])[None, :]
+        for c0 in range(0, new, 256):
+            c1 = min(new, c0 + 256)
+            qq = q[t0 + c0:t0 + c1].astype(jnp.float32).reshape(c1 - c0, nkv, nq // nkv, d) / np.sqrt(d)
+            p = jnp.arange(before + c0, before + c1)[:, None]
+            s = jnp.einsum("tngd,cnd->tngc", qq, k, precision="highest")
+            vis = (ctx <= p) & (p - ctx < window)
+            w = jax.nn.softmax(jnp.where(vis[:, None, None, :], s, -1e30), axis=-1)
+            outs.append(jnp.einsum("tngc,cnd->tngd", w, v, precision="highest").reshape(c1 - c0, nq, d))
+        t0 += new
+    return jnp.concatenate(outs, 0)
+
+
+@pytest.mark.parametrize("name,T,S,rows,want", [
+    # mistral-7b.longprompt: the last 2,048-token chunk of an 8,192-token prompt
+    ("longprompt", 2048, 8, [(6144, 2048)], (128, "heuristic:long_rows")),
+    # the same bucket as the closed loop fills it: a chunk and six decode rows
+    ("longprompt_mixed", 2048, 8,
+     [(1200, 1), (2500, 1), (3600, 1), (5000, 1), (7000, 1), (8000, 1), (2048, 2042)],
+     (128, "heuristic:long_rows")),
+    # mistral-7b.chat: a SplitFuse put of 20 one-token rows and a 490-token chunk
+    ("chat", 512, 32, [(100 + 59 * i, 1) for i in range(20)] + [(0, 490)], (32, "heuristic:short_rows")),
+])
+def test_paged_q_tiled_at_the_serving_cells_shapes(name, T, S, rows, want):
+    """``paged_attention`` as the serving engine calls it, at the shapes of
+    the benchmark's two serving cells (32/8 heads of 128, 128-token blocks,
+    tables 65 wide, window 4,096, bf16): the tile the heuristic picks, against
+    a float32 reference. A kernel that does not fit VMEM fails to compile
+    here, loudly."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    nq, nkv, d, bs, mb, window, n_blocks = 32, 8, 128, 128, 65, 4096, 619
+    rng = np.random.default_rng(25)
+    k_pool = jnp.asarray(rng.normal(size=(n_blocks * bs, nkv, d)), jnp.bfloat16)
+    v_pool = jnp.asarray(rng.normal(size=(n_blocks * bs, nkv, d)), jnp.bfloat16)
+    tables = jnp.asarray(rng.integers(0, n_blocks, size=(S, mb)), jnp.int32)
+    seq_idx = np.concatenate([np.full(new, r) for r, (_, new) in enumerate(rows)])
+    pos = np.concatenate([np.arange(before, before + new) for before, new in rows])
+    n = seq_idx.size  # the rest is the pad run ragged_wrapper.finalize emits
+    seq_idx = jnp.asarray(np.pad(seq_idx, (0, T - n)), jnp.int32)
+    pos = jnp.asarray(np.pad(pos, (0, T - n)), jnp.int32)
+    q = jnp.asarray(rng.normal(size=(T, nq, d)), jnp.bfloat16)
+
+    pa.KERNEL_CHOICES.pop((T, S, mb), None)
+    out = jax.jit(lambda q: pa.paged_attention(q, k_pool, v_pool, tables, seq_idx, pos, bs, window=window))(q)
+    choice = pa.kernel_choice(T, S, mb)
+    assert (choice["kernel"], choice["q_tile"], choice["rule"]) == ("paged_attn_q_tiled", ) + want
+    ref = np.asarray(_paged_reference_by_run(q, k_pool, v_pool, tables, rows, bs, window), np.float32)
+    got = np.asarray(out[:n], np.float32)
+    np.testing.assert_allclose(got, ref, atol=5e-2, rtol=5e-2)
+    assert np.linalg.norm(got - ref) / np.linalg.norm(ref) < 5e-3
+
+
 def test_v2_engine_serving_on_chip_bf16_and_int8():
     """Engine-level on-chip smoke of the composed ragged program (embed +
     quantized scatter + paged kernel + multi-step decode scan) — the exact
