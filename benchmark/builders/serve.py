@@ -76,6 +76,19 @@ def instrument(engine, log: list):
     engine.put, engine.decode = put_logged, decode_logged
 
 
+def system_logits(engine, check_ids, n_prompt: int, uid: int = 2**30):
+    """The engine's float32 logits at the last position of a prefill of
+    ``check_ids[:n_prompt]`` and at each further position of ``check_ids``,
+    decoded one token at a time through the cache."""
+    import numpy as np
+
+    got = [np.asarray(engine.put([uid], [check_ids[:n_prompt]], sample=None), np.float32)[0]]
+    for j in range(n_prompt, len(check_ids)):
+        got.append(np.asarray(engine.put([uid], [check_ids[j:j + 1]], sample=None), np.float32)[0])
+    engine.flush(uid)
+    return got
+
+
 def _buckets_up_to(buckets, n: int):
     """The static buckets that batches of at most ``n`` can round up to."""
     out = []
@@ -96,7 +109,7 @@ def build(cell: dict, seed: int, devices, rehearsal: bool, phases):
     from deepspeed_tpu.models import TransformerLM
     from deepspeed_tpu.serving import GatewayConfig, ServingGateway
 
-    from benchmark.lib import common, reference
+    from benchmark.lib import common, loader
     from benchmark.lib.model import model_config, seed_word
 
     mark = phases.mark
@@ -117,6 +130,7 @@ def build(cell: dict, seed: int, devices, rehearsal: bool, phases):
     rng = np.random.default_rng([int(seed), 7])
     check_ids = rng.integers(0, cfg.vocab_size, size=n_prompt + n_decode, dtype=np.int32)
     positions = list(range(n_prompt - 1, n_prompt + n_decode))
+    reference = loader.load_reference(cell)
     with common.span("reference"):
         ref = np.asarray(reference.forward_logits(reference.hyper_from_published(cf), params,
                                                   jnp.asarray(check_ids[None, :]), positions))[0]
@@ -162,12 +176,7 @@ def build(cell: dict, seed: int, devices, rehearsal: bool, phases):
     mark("warmup_slices")
     # correct, second half: the system's logits for the same sequence, the
     # prompt through one prefill and each further position through the cache
-    uid = 2**30
-    got = [np.asarray(engine.put([uid], [check_ids[:n_prompt]], sample=None), np.float32)[0]]
-    for j in range(n_decode):
-        got.append(np.asarray(engine.put([uid], [check_ids[n_prompt + j:n_prompt + j + 1]], sample=None),
-                              np.float32)[0])
-    engine.flush(uid)
+    got = system_logits(engine, check_ids, n_prompt)
     rel_l2 = [float(np.linalg.norm(g - r) / np.linalg.norm(r)) for g, r in zip(got, ref)]
     check = {"positions": positions, "rel_l2": rel_l2, "rel_l2_tol": ck["rel_l2_tol"],
              "finite": bool(all(np.isfinite(g).all() for g in got)),

@@ -46,18 +46,19 @@ def run(cell: dict, args, t_process_start: float) -> dict:
     t_begin = time.perf_counter()
     time.sleep(args.seconds)
     t_end = time.perf_counter()
-    tracer, reduced, trace_window = None, None, None
+    reduced, trace_window = None, None
     if args.trace:
         tracer = common.Tracer(cell["root"], cell["name"])
         tracer.start()
         t_trace = time.perf_counter()
         time.sleep(trace_seconds)
+        # the trace ends here, under the full load: the drain below, in which the rows empty one
+        # by one, lasts as long as the longest answer and is no part of what the cell measures
+        trace_window = (t_trace, time.perf_counter())
+        reduced = tracer.stop_and_reduce()
     stop.set()  # no new request; those in flight finish outside the window and are counted
     for t in threads:
         t.join(serving.WAIT_S)
-    if tracer is not None:
-        trace_window = (t_trace, time.perf_counter())
-        reduced = tracer.stop_and_reduce()
     system.gateway.stop()
 
     measured = [r for r in records if t_begin <= r["t_due"] <= t_end]

@@ -17,7 +17,7 @@ def run(cell: dict, args, t_process_start: float) -> dict:
 
     from deepspeed_tpu.runtime.data_pipeline.prefetch import DeviceBatch
 
-    from benchmark.lib import common, rates, reference
+    from benchmark.lib import common, loader, rates
     from benchmark.lib.peaks import peaks_for
 
     phases, devices, compiles, system = common.begin_run(cell, args, t_process_start)
@@ -42,6 +42,7 @@ def run(cell: dict, args, t_process_start: float) -> dict:
         raise ValueError(f"{k} distinct check sequences do not divide the batch of {system.sequences}")
     distinct = jax.jit(lambda key: jax.random.randint(key, (k, system.seq), 0, system.cfg.vocab_size, jnp.int32),
                        out_shardings=system.rows_sharding)(jax.random.fold_in(system.key, 10**6))
+    reference = loader.load_reference(cell)
     hyper = reference.hyper_from_published(cf)
     with common.span("reference"):
         ref_loss, ref_norm = reference.loss_and_grad_norm(hyper, engine.state["params"], distinct)

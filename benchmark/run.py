@@ -13,7 +13,7 @@ traffic mixes and metrics exist is data: ``BENCHMARK.json`` and the files
 under ``benchmark/`` (see ``benchmark/lib/loader.py``).
 
 ``--rehearsal`` (needs ``JAX_PLATFORMS=cpu``) runs a tiny cell of
-``benchmark/rehearsal.json`` on the CPU to debug the harness itself: its line
+``benchmark/rehearsal/`` on the CPU to debug the harness itself: its line
 names the CPU as the device and carries counts only, never a metric.
 """
 

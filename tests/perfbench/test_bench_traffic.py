@@ -71,7 +71,7 @@ def test_every_seed_plays_the_same_cycle_from_another_point():
     assert traffic.cycles_for({"count": 32, "cycle_seconds": 8}, 30) == 5
 
 
-@pytest.mark.parametrize("name", ["longprompt", "chat"])
+@pytest.mark.parametrize("name", ["longprompt", "chat", "decode-heavy"])
 def test_the_committed_mixes_offer_identical_totals_for_two_seeds(name):
     mix = loader._read_json(f"{loader.ROOT}/benchmark/traffic/{name}.json")
     a = traffic.make_requests(mix, 1, 32000, cycles=2, with_tokens=False)
@@ -81,3 +81,22 @@ def test_the_committed_mixes_offer_identical_totals_for_two_seeds(name):
     assert sum(r["max_new_tokens"] for r in a) == sum(r["max_new_tokens"] for r in b)
     lo, hi = mix["prompt_tokens"]["lo"], mix["prompt_tokens"]["hi"]
     assert all(lo <= r["prompt_len"] <= hi for r in a)
+
+
+def test_a_mix_that_fixes_its_start_offers_every_seed_the_same_requests_in_the_same_order():
+    """For a closed loop of long requests the point where the cycle starts decides how many requests
+    begin inside the window; such a mix gives ``start`` and the seed draws token ids alone."""
+    fixed = {**MIX, "start": 7}
+    sizes = lambda reqs: [(r["position"], r["prompt_len"], r["max_new_tokens"]) for r in reqs]
+    a = traffic.make_requests(fixed, 1, 1000, cycles=2)
+    b = traffic.make_requests(fixed, 2**31 + 11, 1000, cycles=2)
+    assert sizes(a) == sizes(b) and a[0]["position"] == 7 and a[40]["position"] == 7
+    assert any((x["prompt"] != y["prompt"]).any() for x, y in zip(a, b))  # the token ids are still the seed's
+    assert traffic.make_requests({**MIX, "start": 47}, 1, 1000, cycles=1)[0]["position"] == 7  # taken modulo the cycle
+    # the same seed draws the same token ids with and without a fixed start: the start's draw is still made
+    free = traffic.make_requests(MIX, 1, 1000, cycles=1)
+    same_point = traffic.make_requests({**MIX, "start": free[0]["position"]}, 1, 1000, cycles=1)
+    assert sizes(free) == sizes(same_point)
+    assert all((x["prompt"] == y["prompt"]).all() for x, y in zip(free, same_point))
+    mix = loader._read_json(f"{loader.ROOT}/benchmark/traffic/decode-heavy.json")
+    assert mix["start"] == 28 and mix["count"] == mix["clients"] == 32
