@@ -25,6 +25,7 @@ from ...monitor.metrics import get_metrics
 from ...monitor.roofline import get_roofline
 from ...monitor.trace import (NULL_SPAN, get_tracer, pop_compile_source,
                               push_compile_source)
+from ...moe.grouped import merge_routing_stats
 from ...ops.pallas.paged_attention import kernel_choice
 from ...utils.logging import log_dist
 from .config_v2 import RaggedInferenceEngineConfig
@@ -77,6 +78,20 @@ class InferenceEngineV2:
         from .modules.heuristics import build_modules
 
         self._modules = build_modules(mc, ic, use_pallas=self._use_pallas)
+        self._moe = self._modules.get("moe")  # None: a dense model
+        # what every serving program of this engine is jitted with. A model
+        # with experts on the TPU compiles with XLA's scoped VMEM raised to
+        # 64 MiB: with the default 16 MiB, a put program of 64 tokens x 8 rows
+        # with two or more layers HUNG the chip (PR 27, TPU v5e, jax 0.9.0:
+        # any paged-attention grid followed by moe_gmm followed by the next
+        # layer's attention; 32- and 128-token programs ran, one layer ran,
+        # either kernel beside the other's jnp form ran). XLA keeps the
+        # kernels' small operands in VMEM beside their scoped region and at
+        # that size the two collide; with the larger scoped region it has not
+        # been seen. Dense models' programs are compiled as before.
+        self._jit_options = {}
+        if self._moe is not None and self._use_pallas and jax.default_backend() == "tpu":
+            self._jit_options = {"compiler_options": {"xla_tpu_scoped_vmem_limit_kib": 65536}}
 
         if params is None:
             params = jax.jit(lambda r: model.init(r, None))(jax.random.PRNGKey(0))
@@ -390,14 +405,14 @@ class InferenceEngineV2:
                     mode = "sample"
                     fn = self._get_compiled(t_bucket, s_bucket, "sample")
                     samp_f, seeds = pack_sampling(sampling, batch_uids, s_bucket)
-                    out, pools = fn(self.params, jnp.asarray(rb.packed()), jnp.asarray(samp_f),
-                                    jnp.asarray(seeds), kv.pools())
+                    (out, *stats), pools = fn(self.params, jnp.asarray(rb.packed()),
+                                              jnp.asarray(samp_f), jnp.asarray(seeds), kv.pools())
                 else:
                     mode = sample
                     fn = self._get_compiled(t_bucket, s_bucket, sample)
                     # ONE descriptor upload per forward (reference single pinned-buffer
                     # upload) instead of one host-to-device transfer per array
-                    out, pools = fn(self.params, jnp.asarray(rb.packed()), kv.pools())
+                    (out, *stats), pools = fn(self.params, jnp.asarray(rb.packed()), kv.pools())
                 kv.update(*pools)
                 if sd is not NULL_SPAN:
                     sd.set_args(compiled=len(self._compiled) > n_programs)
@@ -408,6 +423,12 @@ class InferenceEngineV2:
             with tr.span("serving/engine_fetch", tid="serving"):
                 out = out[:rb.n_seqs]  # slice ON DEVICE: the host fetch moves
                 out = out if not block else np.asarray(out)  # n_seqs rows, not the padded bucket
+                moe_args = {}
+                if stats and block and sp is not NULL_SPAN:
+                    # two int32 the step computed beside its result; they are
+                    # ready when the result is, so this waits for nothing
+                    moe_args = self._moe_span_args(sum(int(t.size) for t in batch_tokens),
+                                                   t_bucket, 1, np.asarray(stats[0]))
             if sp is not NULL_SPAN:
                 # counts at the boundary, and the uids so that a request-scoped
                 # trace can attribute every engine forward to the requests
@@ -417,7 +438,7 @@ class InferenceEngineV2:
                             rows_decode=sum(1 for n in sizes if n == 1), tokens=sum(sizes),
                             bucket_tokens=int(t_bucket), bucket_rows=int(s_bucket), steps=1,
                             kernel=self._kernel_of(t_bucket, s_bucket),
-                            uids=[int(u) for u in batch_uids[:16]], blocked=bool(block))
+                            uids=[int(u) for u in batch_uids[:16]], blocked=bool(block), **moe_args)
         if rf.enabled and block:
             # wall join through the blocking host fetch — the same window the
             # outer put() books as prefill/decode-active in the goodput ledger,
@@ -434,6 +455,21 @@ class InferenceEngineV2:
             else:
                 reg.histogram("serving/decode_step_ms").observe(dt_ms)
         return out
+
+    def _moe_span_args(self, tokens: int, t_bucket: int, steps: int, stats) -> dict:
+        """What a step span says of the expert layers: ``moe_slots`` routed
+        slots (live tokens x top-k x layers), ``moe_rows`` the rows the
+        grouped kernel computed for them, padding included (static for the
+        bucket), ``experts_hit`` of ``experts_total`` experts with at least
+        one slot, summed over layers and steps, and ``expert_load_max``, the
+        most slots one expert of one layer held. ``stats`` is the program's
+        ``[experts_hit, expert_load_max]``."""
+        mc = self.model_config
+        return {"moe_slots": tokens * mc.moe_top_k * mc.num_layers,
+                "moe_rows": self._moe.padded_rows(t_bucket) * mc.num_layers * steps,
+                "experts_hit": int(stats[0]),
+                "experts_total": mc.moe_num_experts * mc.num_layers * steps,
+                "expert_load_max": int(stats[1])}
 
     def _kernel_of(self, T: int, S: int) -> str:
         """``<kernel>:<q_tile or kv_splits>:<rule>`` of the paged-attention
@@ -563,20 +599,24 @@ class InferenceEngineV2:
                 if rf_sampled:
                     fn = self._get_compiled_decode(s_bucket, n_steps, sampled=True)
                     samp_f, seeds = pack_sampling(sampling, uids, s_bucket)
-                    toks, pools = fn(self.params, jnp.asarray(rb.packed()), jnp.asarray(samp_f),
-                                     jnp.asarray(seeds), kv.pools())
+                    (toks, *stats), pools = fn(self.params, jnp.asarray(rb.packed()),
+                                               jnp.asarray(samp_f), jnp.asarray(seeds), kv.pools())
                 else:
                     fn = self._get_compiled_decode(s_bucket, n_steps)
                     # start positions already ride inside packed() (each decode row
                     # is one token at its position) — no separate seq_start_len upload
-                    toks, pools = fn(self.params, jnp.asarray(rb.packed()), kv.pools())
+                    (toks, *stats), pools = fn(self.params, jnp.asarray(rb.packed()), kv.pools())
                 kv.update(*pools)
                 if sd is not NULL_SPAN:
                     sd.set_args(compiled=len(self._compiled) > n_programs)
             with tr.span("serving/engine_fetch", tid="serving"):
                 toks = toks[:S]  # on-device slice before any host fetch
+                moe_args = {}
                 if block:
                     toks = np.asarray(toks)
+                    if stats and sp is not NULL_SPAN:
+                        moe_args = self._moe_span_args(S * int(n_steps), s_bucket, int(n_steps),
+                                                       np.asarray(stats[0]))
             pc = self.state_manager.prefix_cache
             with tr.span("serving/engine_commit", tid="serving"):
                 if block:
@@ -614,7 +654,7 @@ class InferenceEngineV2:
                 sp.set_args(seqs=S, rows=S, tokens=S * int(n_steps), steps=int(n_steps),
                             bucket_rows=int(s_bucket), bucket_tokens=int(s_bucket),
                             kernel=self._kernel_of(s_bucket, s_bucket),
-                            uids=[int(u) for u in uids[:16]], blocked=bool(block))
+                            uids=[int(u) for u in uids[:16]], blocked=bool(block), **moe_args)
         if rf.enabled and block:
             rf.note_wall(rf_bucket, time.perf_counter() - t_rf)
         if reg.enabled and block:
@@ -624,7 +664,7 @@ class InferenceEngineV2:
         return toks
 
     def _ragged_step(self, params, packed, pools, t_bucket, s_bucket, gather_k: int = 0,
-                     tree_meta=None):
+                     tree_meta=None, moe_stats: bool = False):
         """One ragged forward over the pool tuple (2 = bf16 pools, 4 = int8
         pools + scales). The SINGLE builder both compiled paths share —
         quant/non-quant variation lives in the tuple arity, not in four
@@ -646,7 +686,10 @@ class InferenceEngineV2:
         the shared root (depth 0), and earlier nodes of the token's OWN
         branch. The mask/ctx-position arrays built here feed
         ``ragged_forward``'s tree kwargs; with ``tree_meta`` None this is
-        byte-identical to the plain causal step."""
+        byte-identical to the plain causal step.
+
+        ``moe_stats`` (a model with experts): a third result, int32
+        ``[experts_hit, expert_load_max]`` of this forward."""
         from .ragged.ragged_wrapper import unpack_descriptors
 
         token_ids, seq_idx, pos, valid, tables, last_idx = unpack_descriptors(
@@ -680,6 +723,9 @@ class InferenceEngineV2:
             # an earlier slot but must stay invisible
             vis_tree = in_tree & (cd <= depth[:, None]) & ((cd == 0) | (cb == branch[:, None]))
             mask = (ctx_p < start[:, None]) | vis_tree
+            if getattr(self.model_config, "per_layer_attention", False):
+                raise NotImplementedError("token-tree verification builds one visibility mask for all "
+                                          "layers; this model's layers differ in window (layer_types)")
             window = getattr(self.model_config, "sliding_window", None)
             if window:
                 ctx_pid_t = jnp.where(in_tree, start[:, None] + cd, ctx_p)
@@ -701,7 +747,9 @@ class InferenceEngineV2:
         out = ragged_forward(self.model_config, self.config.kv_block_size, params,
                              token_ids, seq_idx, pos, valid, tables, last_idx,
                              pools[0], pools[1], use_pallas=self._use_pallas,
-                             modules=self._modules, **scales, **extra)
+                             modules=self._modules, moe_stats=moe_stats, **scales, **extra)
+        if moe_stats:
+            return out[0], tuple(out[1:-1]), out[-1]
         return out[0], tuple(out[1:])  # logits, new pool tuple
 
     # ------------------------------------------------------------------
@@ -1058,7 +1106,7 @@ class InferenceEngineV2:
                                                     seeds, starts)
                     return (accept.astype(jnp.int32), nxt), pools
 
-                self._compiled[key] = jax.jit(fwd, donate_argnums=(4, ))
+                self._compiled[key] = jax.jit(fwd, donate_argnums=(4, ), **self._jit_options)
             elif tree:
                 def fwd(params, packed, tree_meta, pools):
                     logits, pools = step_fn(params, packed, pools, t_bucket, s_bucket,
@@ -1066,7 +1114,7 @@ class InferenceEngineV2:
                     toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                     return toks.reshape(s_bucket, k + 1), pools
 
-                self._compiled[key] = jax.jit(fwd, donate_argnums=(3, ))
+                self._compiled[key] = jax.jit(fwd, donate_argnums=(3, ), **self._jit_options)
             else:
                 def fwd(params, packed, pools):
                     logits, pools = step_fn(params, packed, pools, t_bucket, s_bucket,
@@ -1074,7 +1122,7 @@ class InferenceEngineV2:
                     toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                     return toks.reshape(s_bucket, k + 1), pools
 
-                self._compiled[key] = jax.jit(fwd, donate_argnums=(2, ))
+                self._compiled[key] = jax.jit(fwd, donate_argnums=(2, ), **self._jit_options)
             rf = get_roofline()
             if rf.enabled:
                 # roofline cost capture: the wrapper snapshots this program's
@@ -1093,6 +1141,12 @@ class InferenceEngineV2:
 
             max_blocks = self._max_blocks_per_seq
             step_fn = self._ragged_step
+            moe = self._moe is not None
+            stats0 = (jnp.zeros(2, jnp.int32), ) if moe else ()
+
+            def merge(stats, new):
+                """The routing counts (none for a dense model) over the steps."""
+                return tuple(merge_routing_stats(a, b) for a, b in zip(stats, new))
 
             if sampled:
                 from .sampling import sample_tokens
@@ -1102,41 +1156,43 @@ class InferenceEngineV2:
                     pos_row = packed[2 * s_bucket:3 * s_bucket]
 
                     def step(carry, t):
-                        toks, pl = carry
+                        toks, pl, stats = carry
                         stepped = packed.at[0:s_bucket].set(toks) \
                                         .at[2 * s_bucket:3 * s_bucket].add(t)
-                        logits, pl = step_fn(params, stepped, pl, s_bucket, s_bucket)
+                        logits, pl, *new = step_fn(params, stepped, pl, s_bucket, s_bucket,
+                                                   moe_stats=moe)
                         # draw keyed by the NEW token's absolute position —
                         # the same stream the sampled put path would produce
                         nxt = sample_tokens(logits, samp_f[:, 0], samp_f[:, 1], seeds,
                                             pos_row + t + 1)
-                        return (nxt, pl), nxt
+                        return (nxt, pl, merge(stats, new)), nxt
 
-                    (_, pools), out = jax.lax.scan(
-                        step, (token_ids, pools), jnp.arange(n_steps, dtype=jnp.int32))
-                    return out.T, pools  # [S, n_steps]
+                    (_, pools, stats), out = jax.lax.scan(
+                        step, (token_ids, pools, stats0), jnp.arange(n_steps, dtype=jnp.int32))
+                    return (out.T, *stats), pools  # [S, n_steps]
 
-                self._compiled[key] = jax.jit(fwd, donate_argnums=(4, ))
+                self._compiled[key] = jax.jit(fwd, donate_argnums=(4, ), **self._jit_options)
             else:
                 def fwd(params, packed, pools):
                     token_ids = unpack_descriptors(packed, s_bucket, s_bucket, max_blocks)[0]
 
                     def step(carry, t):
-                        toks, pl = carry
+                        toks, pl, stats = carry
                         # feed the greedy tokens back into the packed descriptor
                         # and advance positions in-scan from the packed starts
                         # (packed layout: [T ids][T seq_idx][T pos]...)
                         stepped = packed.at[0:s_bucket].set(toks) \
                                         .at[2 * s_bucket:3 * s_bucket].add(t)
-                        logits, pl = step_fn(params, stepped, pl, s_bucket, s_bucket)
+                        logits, pl, *new = step_fn(params, stepped, pl, s_bucket, s_bucket,
+                                                   moe_stats=moe)
                         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                        return (nxt, pl), nxt
+                        return (nxt, pl, merge(stats, new)), nxt
 
-                    (_, pools), out = jax.lax.scan(
-                        step, (token_ids, pools), jnp.arange(n_steps, dtype=jnp.int32))
-                    return out.T, pools  # [S, n_steps]
+                    (_, pools, stats), out = jax.lax.scan(
+                        step, (token_ids, pools, stats0), jnp.arange(n_steps, dtype=jnp.int32))
+                    return (out.T, *stats), pools  # [S, n_steps]
 
-                self._compiled[key] = jax.jit(fwd, donate_argnums=(2, ))
+                self._compiled[key] = jax.jit(fwd, donate_argnums=(2, ), **self._jit_options)
             rf = get_roofline()
             if rf.enabled:
                 self._compiled[key] = rf.capture_executable(bucket, self._compiled[key])
@@ -1490,12 +1546,14 @@ class InferenceEngineV2:
                 raise ValueError(f"unsupported sample mode {sample!r}: None | 'greedy' | 'sample'")
             step_fn = self._ragged_step
             mb = self._max_blocks_per_seq
+            moe = self._moe is not None  # the step's result then carries the routing counts
 
             if sample == "sample":
                 from .sampling import sample_tokens
 
                 def fwd(params, packed, samp_f, seeds, pools):
-                    logits, pools = step_fn(params, packed, pools, t_bucket, s_bucket)
+                    logits, pools, *stats = step_fn(params, packed, pools, t_bucket, s_bucket,
+                                                    moe_stats=moe)
                     last = packed[4 * t_bucket + s_bucket * mb:
                                   4 * t_bucket + s_bucket * mb + s_bucket]
                     # key each draw by the sampled token's OWN position:
@@ -1503,16 +1561,17 @@ class InferenceEngineV2:
                     # independent of batch composition
                     ctr = packed[2 * t_bucket:3 * t_bucket][jnp.maximum(last, 0)] + 1
                     toks = sample_tokens(logits, samp_f[:, 0], samp_f[:, 1], seeds, ctr)
-                    return toks, pools
+                    return (toks, *stats), pools
 
-                self._compiled[key] = jax.jit(fwd, donate_argnums=(4, ))
+                self._compiled[key] = jax.jit(fwd, donate_argnums=(4, ), **self._jit_options)
             else:
                 def fwd(params, packed, pools):
-                    logits, pools = step_fn(params, packed, pools, t_bucket, s_bucket)
+                    logits, pools, *stats = step_fn(params, packed, pools, t_bucket, s_bucket,
+                                                    moe_stats=moe)
                     out = jnp.argmax(logits, axis=-1).astype(jnp.int32) if sample == "greedy" else logits
-                    return out, pools
+                    return (out, *stats), pools
 
-                self._compiled[key] = jax.jit(fwd, donate_argnums=(2, ))
+                self._compiled[key] = jax.jit(fwd, donate_argnums=(2, ), **self._jit_options)
             rf = get_roofline()
             if rf.enabled:
                 self._compiled[key] = rf.capture_executable(bucket, self._compiled[key])

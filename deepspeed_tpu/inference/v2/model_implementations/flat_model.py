@@ -30,13 +30,14 @@ import jax
 import jax.numpy as jnp
 
 from ....models.transformer import TransformerConfig, apply_rope, mlp_activation, rope_table
+from ....moe.grouped import merge_routing_stats
 
 
 def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, Any], token_ids, seq_idx, pos, valid,
                    block_tables, last_idx, k_pool, v_pool, use_pallas: bool = False,
                    unroll: bool = True, modules: Dict[str, Any] = None,
                    k_scale=None, v_scale=None, pos_ids=None, attn_mask=None,
-                   ctx_pos_ids=None):
+                   ctx_pos_ids=None, moe_stats: bool = False):
     """Returns (last-token logits [S_pad, V], k_pool, v_pool).
 
     token_ids/seq_idx/pos/valid: [T_pad]; block_tables: [S_pad, max_blocks];
@@ -65,6 +66,13 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
     FastGen's DSModule layer). None builds the auto set from ``cfg`` and
     ``use_pallas``, preserving the pre-registry call surface.
 
+    A model with experts (``cfg.moe_num_experts``) runs its MLP through the
+    ``moe`` module (padding tokens route nowhere); ``moe_stats`` appends to
+    the return int32 ``[experts_hit, expert_load_max]``: experts with at least
+    one slot summed over the layers, and the most slots on one expert in a
+    layer. A model with ``cfg.layer_types`` gives each layer its own window
+    (the ``attention`` / ``attention_full`` modules) and its own rope table.
+
     ``k_scale``/``v_scale``: int8-KV mode — [nkv, L*pool_len] fp32 absmax
     scales (lane-major over slots, the layout both the scatter and the
     Pallas kernel consume without a transpose). When given, the pools hold
@@ -81,6 +89,12 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
         modules = build_modules(cfg, ec, use_pallas=use_pallas)
     attention, linear = modules["attention"], modules["linear"]
     embedding, unembed, pre_norm = modules["embedding"], modules["unembed"], modules["norm"]
+    moe = modules.get("moe")
+    if cfg.moe_num_experts > 0 and moe is None:
+        raise ValueError("a model with experts needs the module set's 'moe' slot "
+                         "(modules/heuristics.build_modules fills it)")
+    if moe_stats and moe is None:
+        raise ValueError("moe_stats asked of a model without experts")
     if getattr(cfg, "sparse_attention", None) is not None:
         # same policy as forward_with_cache: dense paged decode would
         # silently mismatch a sparse-trained model's attention distribution
@@ -92,7 +106,11 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
 
     pid = pos if pos_ids is None else pos_ids
     x = embedding(params, token_ids, pid)  # [T, H]
-    sin, cos = rope_table(cfg, pid) if cfg.positions == "rotary" else (None, None)
+    # one rope table an attention kind (a model of one kind: the key None), in
+    # the order the kinds first appear: a set's order changes with the process's
+    # string hash seed, and with it the traced program and its compile-cache key
+    ropes = {kind: rope_table(cfg, pid, kind) for kind in dict.fromkeys(cfg.layer_types or (None, ))} \
+        if cfg.positions == "rotary" else {}
 
     # flat KV slot of each token; padding tokens dropped via OOB scatter.
     # The pools ride the layer scan as CARRY over a layers-flattened view
@@ -108,7 +126,12 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
 
     quant = k_scale is not None
 
-    def layer(x, blk, l, k_flat, v_flat, ks_flat, vs_flat):
+    def layer(x, blk, l, k_flat, v_flat, ks_flat, vs_flat, stats=None, kind=None):
+        """``kind``: the layer's attention kind, static (None in a model of
+        one kind, where ``l`` may be traced); ``stats``: the running MoE
+        counts."""
+        attend = modules["attention_full"] if kind == "full_attention" else attention
+        sin, cos = ropes.get(kind, (None, None))
         h1 = pre_norm(x, blk["ln1_scale"], blk.get("ln1_bias"))
         bias = (lambda n: blk[n]) if cfg.use_bias else (lambda n: None)
         qkvb = (lambda n: blk[n]) if cfg.qkv_bias_enabled else (lambda n: None)
@@ -141,11 +164,21 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
         scales = {"k_scale": ks_flat, "v_scale": vs_flat} if quant else {}
         if attn_mask is not None:
             scales = dict(scales, pos_ids=pid, mask=attn_mask, ctx_pos_ids=ctx_pos_ids)
-        ctx = attention(q, k_flat, v_flat, tables_l, seq_idx, pos, **scales)
+        ctx = attend(q, k_flat, v_flat, tables_l, seq_idx, pos, **scales)
 
         attn_out = linear(ctx.reshape(T, nq * d), blk["wo"], bias("bo"))
 
         def mlp(h):
+            nonlocal stats
+            if moe is not None:
+                # the experts stay in the stacked arrays and the kernel reads
+                # layer l's out of them: ``blk`` holds no expert weights
+                out = moe(h, blk["gate_wg"], experts["moe_wi"], experts.get("moe_wg"),
+                          experts["moe_wo"], valid=valid, with_stats=stats is not None, layer=l)
+                if stats is not None:
+                    out, layer_stats = out
+                    stats = merge_routing_stats(stats, layer_stats)
+                return out
             up = linear(h, blk["w_up"], bias("b_up"))
             if cfg.mlp == "swiglu":
                 act = mlp_activation(cfg, up, linear(h, blk["w_gate"], None))
@@ -155,34 +188,40 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
 
         if cfg.parallel_residual:  # GPT-J / NeoX / Falcon
             h2 = h1 if cfg.shared_ln else pre_norm(x, blk["ln2_scale"], blk.get("ln2_bias"))
-            return x + attn_out + mlp(h2), k_flat, v_flat, ks_flat, vs_flat
+            return x + attn_out + mlp(h2), k_flat, v_flat, ks_flat, vs_flat, stats
         x = x + attn_out
         h2 = pre_norm(x, blk["ln2_scale"], blk.get("ln2_bias"))
-        return x + mlp(h2), k_flat, v_flat, ks_flat, vs_flat
+        return x + mlp(h2), k_flat, v_flat, ks_flat, vs_flat, stats
 
     k_flat = k_pool.reshape(flat_len, nkv, d)
     v_flat = v_pool.reshape(flat_len, nkv, d)
     ks_flat, vs_flat = k_scale, v_scale  # already [nkv, flat_len] or None
+    stats = jnp.zeros(2, jnp.int32) if moe_stats else None
+    expert_keys = ("moe_wi", "moe_wg", "moe_wo")
+    experts = {k: v for k, v in params["blocks"].items() if k in expert_keys}
+    per_layer = {k: v for k, v in params["blocks"].items() if k not in expert_keys}
     if unroll and L <= 48:
         for l in range(L):
-            blk_l = jax.tree_util.tree_map(lambda a: a[l], params["blocks"])
-            x, k_flat, v_flat, ks_flat, vs_flat = layer(x, blk_l, l, k_flat, v_flat,
-                                                        ks_flat, vs_flat)
+            blk_l = jax.tree_util.tree_map(lambda a: a[l], per_layer)
+            x, k_flat, v_flat, ks_flat, vs_flat, stats = layer(
+                x, blk_l, l, k_flat, v_flat, ks_flat, vs_flat, stats, cfg.layer_kind(l))
     else:
-        def scan_body(carry, inp):
-            x, kf, vf, ksf, vsf = carry
-            blk, l = inp
-            return layer(x, blk, l, kf, vf, ksf, vsf), None
+        if cfg.per_layer_attention:
+            raise NotImplementedError("layer_types under lax.scan: one scan body has one window and "
+                                      "one rope; the ragged forward unrolls up to 48 layers")
 
-        (x, k_flat, v_flat, ks_flat, vs_flat), _ = jax.lax.scan(
-            scan_body, (x, k_flat, v_flat, ks_flat, vs_flat),
-            (params["blocks"], jnp.arange(L, dtype=jnp.int32)))
+        def scan_body(carry, inp):
+            blk, l = inp
+            return layer(carry[0], blk, l, *carry[1:]), None
+
+        (x, k_flat, v_flat, ks_flat, vs_flat, stats), _ = jax.lax.scan(
+            scan_body, (x, k_flat, v_flat, ks_flat, vs_flat, stats),
+            (per_layer, jnp.arange(L, dtype=jnp.int32)))
     k_pool = k_flat.reshape(L, pool_len, nkv, d)
     v_pool = v_flat.reshape(L, pool_len, nkv, d)
 
     # logits_gather semantics: final norm + unembed only each sequence's
     # last token, through the pluggable unembed module
     logits = unembed(params, x, last_idx)
-    if quant:
-        return logits, k_pool, v_pool, ks_flat, vs_flat
-    return logits, k_pool, v_pool
+    out = (logits, k_pool, v_pool, ks_flat, vs_flat) if quant else (logits, k_pool, v_pool)
+    return out + (stats, ) if moe_stats else out

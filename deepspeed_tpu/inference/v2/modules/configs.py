@@ -68,4 +68,5 @@ class DSMoEConfig(DSModuleConfig):
     n_experts: int = 1
     top_k: int = 1
     activation: str = "swiglu"
+    norm_topk_prob: bool = True  # renormalise the top-k probabilities to sum to one
     dtype: Any = jnp.bfloat16

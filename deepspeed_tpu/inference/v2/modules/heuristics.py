@@ -15,14 +15,21 @@ Auto policy:
 - linear: int8 blockwise when the engine asks for weight quantization
   (decode is weight-stream-bound), else the plain-dtype gemm;
 - embedding / unembed / norm: the single TPU implementation each (XLA fuses
-  what the reference ships as kernel variants).
+  what the reference ships as kernel variants);
+- moe (models with experts only): the grouped ragged matmul, the one serving
+  MoE path.
+
+A model whose layers differ in attention kind (``layer_types``) gets one
+attention module a kind: ``attention`` serves the window layers,
+``attention_full`` the full ones (the same implementation, built without a
+window).
 """
 
 from typing import Union
 
-from .configs import (DSEmbeddingsConfig, DSLinearConfig, DSNormConfig,
+from .configs import (DSEmbeddingsConfig, DSLinearConfig, DSMoEConfig, DSNormConfig,
                       DSSelfAttentionConfig, DSUnembedConfig)
-from .interfaces import (DSEmbeddingRegistry, DSLinearRegistry, DSPreNormRegistry,
+from .interfaces import (DSEmbeddingRegistry, DSLinearRegistry, DSMoERegistry, DSPreNormRegistry,
                          DSSelfAttentionRegistry, DSUnembedRegistry)
 from .module_registry import ConfigBundle
 from . import implementations  # noqa: F401 — populates the registries
@@ -73,12 +80,25 @@ def build_modules(model_config, engine_config, use_pallas: bool = False) -> dict
     full module set the ragged forward consumes."""
     mc = model_config
     dt = mc.dtype
-    attn = DSSelfAttentionConfig(
-        num_heads=mc.num_heads, num_kv_heads=mc.num_kv_heads, head_dim=mc.head_dim,
-        block_size=engine_config.kv_block_size, sliding_window=mc.sliding_window,
-        positions=mc.positions, dtype=dt)
+
+    def attention(window):
+        return instantiate_attention(DSSelfAttentionConfig(
+            num_heads=mc.num_heads, num_kv_heads=mc.num_kv_heads, head_dim=mc.head_dim,
+            block_size=engine_config.kv_block_size, sliding_window=window,
+            positions=mc.positions, dtype=dt), engine_config, use_pallas=use_pallas)
+
+    extra = {}
+    if getattr(mc, "layer_types", None) is not None and "full_attention" in mc.layer_types:
+        extra["attention_full"] = attention(None)
+    if getattr(mc, "moe_num_experts", 0) > 0:
+        # one implementation, so no choice is offered (``ModulesConfig`` has no such slot)
+        extra["moe"] = DSMoERegistry.instantiate_config(ConfigBundle(
+            name="grouped_gemm_moe", config=DSMoEConfig(
+                n_experts=mc.moe_num_experts, top_k=mc.moe_top_k, activation=mc.mlp,
+                norm_topk_prob=mc.moe_norm_topk_prob, dtype=dt)))
     return {
-        "attention": instantiate_attention(attn, engine_config, use_pallas=use_pallas),
+        **extra,
+        "attention": attention(mc.sliding_window),
         "linear": instantiate_linear(DSLinearConfig(dtype=dt), engine_config),
         "embedding": instantiate_embed(DSEmbeddingsConfig(
             positions=mc.positions, embed_layernorm=mc.embed_layernorm, norm=mc.norm,

@@ -2,86 +2,61 @@
 ``implementations/moe/cutlass_multi_gemm_moe.py``).
 
 The reference's CUTLASS multi-gemm gathers each expert's tokens and runs E
-variable-size gemms. On TPU, dynamic per-expert token counts are shape-hostile
-(XLA wants static shapes), so the serving MoE uses *dense dispatch*: every
-token is pushed through every expert as one batched [E]-stacked einsum and
-combined with the (renormalized) top-k gate weights. For serving expert
-counts (8-64) the batched gemm keeps the MXU saturated and avoids the
-gather/scatter latency chain; training-scale EP sharding lives in
-``moe/sharded_moe.py``'s capacity-based all-to-all instead.
+variable-size gemms. Here the routed slots are sorted by expert into
+block-aligned groups and go through the Pallas grouped matmul
+(``ops/pallas/grouped_matmul.py``, kernels named ``moe_gmm``): the work
+follows the routed slots, an expert without a slot is not read, and the row
+block follows the slot count of the bucket, so that a decode step of 32 rows
+(4 slots an expert at 64 experts top-8) runs 8-row blocks and streams each
+hit expert's weights once. It is the one serving MoE path: a dense dispatch
+(every token through every expert) reads every expert at any batch and
+multiplies E/k times as much.
 """
 
 import jax
-import jax.numpy as jnp
 
+from .....moe.grouped import grouped_moe_ffn, padded_rows, pick_block_rows, route_topk
 from ..configs import DSMoEConfig
 from ..interfaces import DSMoEBase, DSMoERegistry
 
 
 @DSMoERegistry.register_module
-class TopKGatedMoE(DSMoEBase):
-
-    @staticmethod
-    def name() -> str:
-        return "top_k_gated_moe"
-
-    @staticmethod
-    def supports_config(config: DSMoEConfig) -> bool:
-        return 1 <= config.top_k <= config.n_experts
-
-    def __call__(self, x, gate_w, expert_up, expert_gate, expert_down):
-        """x: [T, H]; gate_w: [H, E]; expert_up/expert_gate: [E, H, F]
-        (expert_gate may be None for non-glu); expert_down: [E, F, H]."""
-        cfg = self.config
-        dt = cfg.dtype
-        logits = jnp.einsum("th,he->te", x, gate_w.astype(dt)).astype(jnp.float32)
-        top_vals, top_idx = jax.lax.top_k(logits, cfg.top_k)  # [T, k]
-        weights = jax.nn.softmax(top_vals, axis=-1).astype(dt)
-        # dense dispatch: combine weight is nonzero only for the top-k experts
-        combine = jnp.zeros(logits.shape, dt).at[
-            jnp.arange(logits.shape[0])[:, None], top_idx].set(weights)  # [T, E]
-
-        up = jnp.einsum("th,ehf->etf", x, expert_up.astype(dt))
-        if expert_gate is not None:  # swiglu
-            g = jnp.einsum("th,ehf->etf", x, expert_gate.astype(dt))
-            act = jax.nn.silu(g) * up
-        else:
-            act = jax.nn.gelu(up)
-        out = jnp.einsum("etf,efh->eth", act, expert_down.astype(dt))
-        return jnp.einsum("te,eth->th", combine, out)
-
-
-@DSMoERegistry.register_module
 class GroupedGemmMoE(DSMoEBase):
-    """Grouped ragged-matmul MoE (reference cutlass_ops moe_gemm analog):
-    expert-sorted tokens through the Pallas grouped GEMM
-    (``ops/pallas/grouped_matmul.py``) — FFN work scales with the T*k routed
-    tokens instead of the dense-dispatch T*E. The large-E serving choice;
-    select via ``modules={"moe": "grouped_gemm_moe"}`` or ConfigBundle name."""
 
     @staticmethod
     def name() -> str:
         return "grouped_gemm_moe"
 
     @staticmethod
-    def supports_config(config) -> bool:
+    def supports_config(config: DSMoEConfig) -> bool:
         return 1 <= config.top_k <= config.n_experts
 
-    def __call__(self, x, gate_w, expert_up, expert_gate, expert_down):
-        """Same contract as :class:`TopKGatedMoE`."""
-        from deepspeed_tpu.moe.grouped import grouped_moe_ffn
-
+    def padded_rows(self, tokens: int) -> int:
+        """Rows the grouped kernel computes for a bucket of ``tokens``
+        tokens, padding included (static: the bound over every routing)."""
         cfg = self.config
-        dt = cfg.dtype
-        logits = jnp.einsum("th,he->te", x, gate_w.astype(dt)).astype(jnp.float32)
-        top_vals, top_idx = jax.lax.top_k(logits, cfg.top_k)
-        weights = jax.nn.softmax(top_vals, axis=-1).astype(dt)
+        slots = tokens * cfg.top_k
+        return padded_rows(slots, cfg.n_experts, pick_block_rows(slots, cfg.n_experts), False)
+
+    def __call__(self, x, gate_w, expert_up, expert_gate, expert_down, valid=None,
+                 with_stats: bool = False, layer=None):
+        """x: [T, H]; gate_w: [H, E]; expert_up/expert_gate: [E, H, F]
+        (expert_gate may be None for non-glu); expert_down: [E, F, H] — or,
+        with ``layer`` given, the model's stacked ``[L, E, ...]`` arrays, of
+        which the kernel reads that layer's experts in place (a slice handed
+        to it would be copied first). ``valid`` [T]: padding tokens of the
+        bucket route nowhere. Routing:
+        the top-k of the float32 softmax over all experts, renormalised if
+        the configuration says so; no token is dropped. ``with_stats`` adds
+        int32 ``[experts_hit, expert_load_max]``."""
+        cfg = self.config
+        top_idx, top_w = route_topk(x, gate_w, cfg.top_k, cfg.norm_topk_prob)
 
         def act(up, gate):
-            return jax.nn.silu(gate) * up if gate is not None else jax.nn.gelu(up)
+            if cfg.activation == "swiglu":
+                return jax.nn.silu(gate) * up
+            return jax.nn.relu(up) if cfg.activation == "relu" else jax.nn.gelu(up)
 
-        # routing goes in precomputed (idx, weights) form — no dense [T, E]
-        # scatter + re-top-k round trip (the O(T*E) work this path avoids)
-        return grouped_moe_ffn(x.astype(dt), None, expert_up, expert_down,
-                               top_k=cfg.top_k, wg=expert_gate, activation=act,
-                               top_idx=top_idx, top_w=weights)
+        return grouped_moe_ffn(x.astype(cfg.dtype), top_idx, top_w.astype(cfg.dtype), expert_up, expert_down,
+                               wg=expert_gate if cfg.activation == "swiglu" else None, activation=act,
+                               valid=valid, differentiable=False, with_stats=with_stats, layer=layer)

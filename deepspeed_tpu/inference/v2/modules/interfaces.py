@@ -138,16 +138,23 @@ class DSPreNormRegistry(DSModuleRegistryBase):
 
 
 class DSMoEBase(DSModuleBase):
-    """``__call__(x, gate_w, expert_up, expert_gate, expert_down)`` → [T, H]
-    token-level top-k routed expert MLP (reference ``interfaces/moe_base.py``)."""
+    """``__call__(x, gate_w, expert_up, expert_gate, expert_down, valid=None,
+    with_stats=False, layer=None)`` → [T, H] token-level top-k routed expert MLP
+    (reference ``interfaces/moe_base.py``); with ``with_stats`` also int32
+    ``[experts_hit, expert_load_max]``. ``padded_rows(tokens)``: the rows its
+    kernel computes for a bucket of that many tokens."""
 
     @staticmethod
     def config_class() -> Type[DSModuleConfig]:
         return DSMoEConfig
 
     @abstractmethod
-    def __call__(self, x, gate_w, expert_up, expert_gate, expert_down):
+    def __call__(self, x, gate_w, expert_up, expert_gate, expert_down, valid=None,
+                 with_stats: bool = False, layer=None):
         ...
+
+    def padded_rows(self, tokens: int) -> int:
+        return tokens * self.config.top_k
 
 
 class DSMoERegistry(DSModuleRegistryBase):

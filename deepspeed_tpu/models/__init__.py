@@ -9,3 +9,4 @@ from .bloom import bloom, bloom_config
 from .gptj import gptj, gptj_config
 from .gpt_neox import gpt_neox, gpt_neox_config
 from .falcon import falcon, falcon_config
+from .mellum import mellum, mellum_config

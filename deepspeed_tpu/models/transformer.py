@@ -79,6 +79,16 @@ class TransformerConfig:
     # None = full causal context. Applies to training (flash/reference),
     # the v1 KV-cache path, and the v2 paged path.
     sliding_window: Optional[int] = None
+    # per-layer attention kind ("sliding_attention" | "full_attention", one
+    # entry a layer): ``sliding_window`` then applies only where the kind
+    # says so. None = every layer is of the one kind ``sliding_window`` gives.
+    layer_types: Optional[Tuple[str, ...]] = None
+    # a rope per attention kind, as published (``rope_type`` "default" or
+    # "yarn" with its factor, original length, betas and attention factor);
+    # a kind it does not name, and None, is plain rope at ``rope_theta``
+    rope_parameters: Optional[Dict[str, dict]] = None
+    # width of a head where it is not hidden_size // num_heads
+    head_size: Optional[int] = None
     sequence_parallel: bool = False  # Ulysses/ring sharding over the seq axis
     sequence_parallel_impl: str = "ulysses"  # 'ulysses' (a2a) | 'ring' (ppermute)
     dropout: float = 0.0
@@ -94,11 +104,16 @@ class TransformerConfig:
     moe_min_capacity: int = 4
     moe_aux_loss_coef: float = 0.01
     moe_noisy_gate_policy: Optional[str] = None
-    # "einsum": the [S, E, C] one-hot dispatch/combine (EP-shardable, the
-    # GSPMD default); "grouped": the megablocks-style Pallas ragged matmul
-    # (ops/pallas/grouped_matmul.py) — work scales with routed tokens, the
-    # single-shard win at large E (reference cutlass_ops moe_gemm analog)
-    moe_impl: str = "einsum"
+    # width of one expert; None = intermediate_size
+    moe_intermediate_size: Optional[int] = None
+    # False: the capacity gates (top-1 / top-2, tokens over capacity are
+    # dropped) through the [S, E, C] one-hot einsum, which shards over the
+    # expert-parallel axis. True: softmax over all experts in float32, the
+    # top ``moe_top_k`` of it, no token ever dropped, through the grouped
+    # ragged matmul (moe/grouped.py), whose work follows the routed slots.
+    moe_dropless: bool = False
+    # dropless routing: renormalise the top-k probabilities to sum to one
+    moe_norm_topk_prob: bool = True
     # ZeRO++ qwZ (reference partition_parameters.py:1139 quantized all-gather
     # handles): when set (by the engine, from zero_quantized_weights), the
     # per-layer stage-3 weight gathers inside the scan body travel as int8
@@ -114,8 +129,15 @@ class TransformerConfig:
     overlap_gather: bool = False
 
     def __post_init__(self):
-        if self.moe_impl not in ("einsum", "grouped"):
-            raise ValueError(f"moe_impl must be 'einsum' or 'grouped', got {self.moe_impl!r}")
+        if self.moe_num_experts > 0 and self.moe_top_k > 2 and not self.moe_dropless:
+            raise ValueError(f"moe_top_k={self.moe_top_k}: the capacity gates are top-1 and top-2 only; "
+                             "set moe_dropless=True for top-k routing without dropped tokens")
+        if self.layer_types is not None:
+            self.layer_types = tuple(self.layer_types)
+            unknown = set(self.layer_types) - {"sliding_attention", "full_attention"}
+            if unknown or len(self.layer_types) != self.num_layers:
+                raise ValueError(f"layer_types needs {self.num_layers} entries of 'sliding_attention' or "
+                                 f"'full_attention', got {len(self.layer_types)} with {sorted(unknown)}")
         if self.intermediate_size is None:
             if self.mlp == "swiglu":
                 self.intermediate_size = int(8 * self.hidden_size / 3 / 128 + 1) * 128
@@ -140,7 +162,24 @@ class TransformerConfig:
 
     @property
     def head_dim(self):
-        return self.hidden_size // self.num_heads
+        return self.head_size or self.hidden_size // self.num_heads
+
+    @property
+    def expert_size(self):
+        return self.moe_intermediate_size or self.intermediate_size
+
+    def layer_kind(self, l: int) -> Optional[str]:
+        """Attention kind of layer ``l``; None where the model has one kind."""
+        return None if self.layer_types is None else self.layer_types[l]
+
+    def layer_window(self, l: int) -> Optional[int]:
+        """The window layer ``l`` attends in; None = all earlier keys."""
+        return None if self.layer_kind(l) == "full_attention" else self.sliding_window
+
+    @property
+    def per_layer_attention(self) -> bool:
+        """Window and rope are a layer's own, so no one scan body serves all."""
+        return self.layer_types is not None
 
 
 # ---------------------------------------------------------------------------
@@ -165,12 +204,12 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
         "ln2_scale": jnp.ones((L, H), jnp.float32),
     }
     if cfg.moe_num_experts > 0:
-        E = cfg.moe_num_experts
+        E, Fe = cfg.moe_num_experts, cfg.expert_size
         blocks["gate_wg"] = dense_init(k[4], (L, H, E), H)
-        blocks["moe_wi"] = dense_init(k[5], (L, E, H, F), H)
-        blocks["moe_wo"] = dense_init(k[6], (L, E, F, H), F) / math.sqrt(2 * L)
+        blocks["moe_wi"] = dense_init(k[5], (L, E, H, Fe), H)
+        blocks["moe_wo"] = dense_init(k[6], (L, E, Fe, H), Fe) / math.sqrt(2 * L)
         if cfg.mlp == "swiglu":
-            blocks["moe_wg"] = dense_init(k[10], (L, E, H, F), H)
+            blocks["moe_wg"] = dense_init(k[10], (L, E, H, Fe), H)
     else:
         blocks["w_up"] = dense_init(k[4], (L, H, F), H)
         blocks["w_down"] = dense_init(k[5], (L, F, H), F) / math.sqrt(2 * L)
@@ -255,10 +294,45 @@ def _norm(x, scale, bias, kind, eps):
     return out.astype(x.dtype)
 
 
-def rope_table(cfg: TransformerConfig, positions: jax.Array) -> Tuple[jax.Array, jax.Array]:
+def rope_inv_freq(cfg: TransformerConfig, kind: Optional[str] = None) -> Tuple[np.ndarray, float]:
+    """Inverse frequencies [d/2] and the factor on sin and cos for the rope of
+    attention kind ``kind``. Plain rope unless ``cfg.rope_parameters[kind]``
+    says ``yarn`` (Peng et al. 2023, as Hugging Face's
+    ``_compute_yarn_parameters`` computes it): dimensions that turn more than
+    ``beta_fast`` times in the original context keep their frequency, those
+    that turn less than ``beta_slow`` times are interpolated by ``factor``,
+    a linear ramp between."""
     d = cfg.rotary_dim or cfg.head_dim
-    inv_freq = 1.0 / (cfg.rope_theta**(jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    freqs = jnp.einsum("s,f->sf", positions.astype(jnp.float32), inv_freq)
+    rp = (cfg.rope_parameters or {}).get(kind) or {}
+    theta = float(rp.get("rope_theta", cfg.rope_theta))
+    extrap = 1.0 / theta**(np.arange(0, d, 2, dtype=np.float64) / d)
+    kind_of = rp.get("rope_type", "default")
+    if kind_of == "default":
+        return extrap.astype(np.float32), 1.0
+    if kind_of != "yarn":
+        raise NotImplementedError(f"rope_type {kind_of!r}: 'default' and 'yarn' are implemented")
+    factor, original = float(rp["factor"]), float(rp["original_max_position_embeddings"])
+
+    def correction_dim(rotations):
+        return d * math.log(original / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(rp.get("beta_fast", 32))), 0)
+    high = min(math.ceil(correction_dim(rp.get("beta_slow", 1))), d - 1)
+    ramp = np.clip((np.arange(d // 2, dtype=np.float64) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    inv_freq = extrap / factor * ramp + extrap * (1.0 - ramp)
+    scale = rp.get("attention_factor")
+    if scale is None:
+        scale = 0.1 * math.log(factor) + 1.0
+    return inv_freq.astype(np.float32), float(scale)
+
+
+def rope_table(cfg: TransformerConfig, positions: jax.Array,
+               kind: Optional[str] = None) -> Tuple[jax.Array, jax.Array]:
+    """sin and cos [S, d/2] at ``positions`` for attention kind ``kind``."""
+    inv_freq, scale = rope_inv_freq(cfg, kind)
+    freqs = jnp.einsum("s,f->sf", positions.astype(jnp.float32), jnp.asarray(inv_freq))
+    if scale != 1.0:
+        return jnp.sin(freqs) * scale, jnp.cos(freqs) * scale
     return jnp.sin(freqs), jnp.cos(freqs)
 
 
@@ -578,8 +652,9 @@ def _block(cfg: TransformerConfig, x, layer, sin, cos, rng=None, constrain=True)
 
 
 def _moe_mlp(cfg: TransformerConfig, layer, h, rng=None, constrain=True):
-    """MoE FFN in GSPMD form: per-row top-k gating (moe/sharded_moe.py math),
-    dispatch to [B, E, C, M] slots, flip the sharding from batch-over-data to
+    """MoE FFN. Dropless top-k (``cfg.moe_dropless``) runs the grouped
+    ragged matmul; otherwise GSPMD form: per-row top-1/top-2 capacity gating
+    (moe/sharded_moe.py math), dispatch to [B, E, C, M] slots, flip the sharding from batch-over-data to
     experts-over-data (XLA lowers the constraint boundary to the dispatch
     all-to-all of the reference's ``_AllToAll``), expert FFN, flip back,
     combine."""
@@ -588,6 +663,18 @@ def _moe_mlp(cfg: TransformerConfig, layer, h, rng=None, constrain=True):
     dt = cfg.dtype
     B, S, H = h.shape
     E = cfg.moe_num_experts
+    if cfg.moe_dropless:
+        # top-k of the softmax over all experts, nothing dropped: the kept
+        # set has no [E, C] shape, so the slots go sorted by expert through
+        # the grouped matmul; no auxiliary loss is defined for it here
+        from ..moe.grouped import grouped_moe_ffn, route_topk
+
+        x = h.reshape(B * S, H)
+        top_idx, top_w = route_topk(x, layer["gate_wg"], cfg.moe_top_k, cfg.moe_norm_topk_prob)
+        y = grouped_moe_ffn(x, top_idx, top_w.astype(dt), layer["moe_wi"], layer["moe_wo"],
+                            wg=layer.get("moe_wg") if cfg.mlp == "swiglu" else None,
+                            activation=lambda up, gate: mlp_activation(cfg, up, gate))
+        return y.reshape(B, S, H), jnp.zeros([], jnp.float32)
     gate_in = h.astype(jnp.float32)
     if cfg.moe_noisy_gate_policy == "Jitter" and rng is not None:
         rng, jit_key = jax.random.split(rng)
@@ -606,21 +693,6 @@ def _moe_mlp(cfg: TransformerConfig, layer, h, rng=None, constrain=True):
         l_aux, combine, dispatch = jax.vmap(gate_row)(logits, keys)
     else:
         l_aux, combine, dispatch = jax.vmap(lambda lg: gate_row(lg, None))(logits)
-
-    if cfg.moe_impl == "grouped":
-        # grouped ragged-matmul path: FFN work scales with routed tokens
-        # (B*S*k + alignment), not B*S*E*C. Kept set and gate weights come
-        # from the SAME capacity gating above, so numerics match the einsum
-        # path. Global sort/scatter makes this the single-shard choice; the
-        # einsum path remains the EP/GSPMD default.
-        from ..moe.grouped import grouped_moe_ffn
-
-        w_se = combine.sum(axis=3).reshape(B * S, E).astype(dt)  # [B*S, E]
-        y = grouped_moe_ffn(
-            h.reshape(B * S, H), w_se, layer["moe_wi"], layer["moe_wo"],
-            top_k=cfg.moe_top_k, wg=layer.get("moe_wg") if cfg.mlp == "swiglu" else None,
-            activation=lambda up, gate: mlp_activation(cfg, up, gate))
-        return y.reshape(B, S, H), jnp.mean(l_aux)
 
     dispatched = jnp.einsum("bsec,bsm->becm", dispatch.astype(dt), h)
     if constrain:
@@ -665,6 +737,17 @@ def _remat_policy(name: str):
     return policy
 
 
+def _refuse_mixed_layers(cfg: TransformerConfig, what: str):
+    """The whole-sequence paths scan ONE block over the stacked layers, with
+    one window and one rope table; a model whose layers differ in either is
+    served by the ragged path (inference/v2), which unrolls them."""
+    if cfg.per_layer_attention:
+        raise NotImplementedError(
+            f"{what}: layer_types gives each layer its own window and rope "
+            f"({sorted(set(cfg.layer_types))}); the scanned block has one of each. Serve this "
+            "model through InferenceEngineV2 (ragged_forward)")
+
+
 def forward_hidden(cfg: TransformerConfig, params: Dict[str, Any], input_ids: jax.Array, rng=None,
                    pld_theta=None):
     """Token ids [B, S] → (final-norm hidden [B, S, H], moe_aux_loss).
@@ -675,6 +758,7 @@ def forward_hidden(cfg: TransformerConfig, params: Dict[str, Any], input_ids: ja
     ``runtime/progressive_layer_drop.py``) — traced keep-rate scalar;
     requires ``rng``. Each layer is wrapped in ``lax.cond`` so dropped
     layers are genuinely skipped at runtime (the training-time saving)."""
+    _refuse_mixed_layers(cfg, "forward_hidden")
     dt = cfg.dtype
     B, S = input_ids.shape
     x = params["embed"]["embedding"].astype(dt)[input_ids]
@@ -886,6 +970,7 @@ def forward_with_cache(cfg: TransformerConfig, params, input_ids, cache):
         raise NotImplementedError("sparse_attention serving is not implemented: the KV-cache "
                                   "decode applies dense attention; unset sparse_attention "
                                   "for inference")
+    _refuse_mixed_layers(cfg, "forward_with_cache")
     dt = cfg.dtype
     B, T = input_ids.shape
     start = cache["length"]
@@ -993,6 +1078,8 @@ def _stage_scan_fn(cfg: TransformerConfig, with_aux: bool = False):
             f"moe_noisy_gate_policy={cfg.moe_noisy_gate_policy!r} does not compose with "
             "pipeline parallelism yet (stage executors run gating without an rng); "
             "disable the noisy gate or run without the pipe axis")
+
+    _refuse_mixed_layers(cfg, "pipeline stages")
 
     def stage_fn(blocks_local, xb, sin, cos):
         def body(carry, layer):

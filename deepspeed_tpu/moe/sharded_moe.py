@@ -218,18 +218,7 @@ class MOELayer:
     """
 
     def __init__(self, gate: TopKGate, hidden_dim: int, ffn_dim: int, num_local_experts: int,
-                 ep_axis: Optional[str] = None, ep_size: int = 1, activation: Callable = jax.nn.gelu,
-                 moe_impl: str = "einsum"):
-        if moe_impl not in ("einsum", "grouped"):
-            raise ValueError(f"moe_impl must be 'einsum' or 'grouped', got {moe_impl!r}")
-        if moe_impl == "grouped" and ep_axis is not None and ep_size > 1:
-            # the grouped path replaces dispatch+combine entirely; the EP
-            # a2a rides the capacity-slot layout, so the combination is not
-            # implemented — reject loudly rather than silently fall back
-            raise NotImplementedError(
-                "moe_impl='grouped' does not compose with expert parallelism yet "
-                "(the a2a exchanges fixed-capacity slots); use moe_impl='einsum' "
-                "for EP-sharded layers")
+                 ep_axis: Optional[str] = None, ep_size: int = 1, activation: Callable = jax.nn.gelu):
         self.gate = gate
         self.hidden_dim = hidden_dim
         self.ffn_dim = ffn_dim
@@ -237,7 +226,6 @@ class MOELayer:
         self.ep_axis = ep_axis
         self.ep_size = ep_size
         self.activation = activation
-        self.moe_impl = moe_impl
 
     def init(self, rng):
         kg, k1, k2 = jax.random.split(rng, 3)
@@ -262,18 +250,6 @@ class MOELayer:
         S, M = x.shape
         E = self.gate.num_experts
         l_aux, combine, dispatch, capacity = self.gate(params["gate"], x, rng=rng, train=train)
-
-        if self.moe_impl == "grouped":
-            # megablocks-style path (ops/pallas/grouped_matmul.py): work
-            # scales with routed tokens, not S*E*C — same kept set and gate
-            # weights as the einsum path (w_se = combine collapsed over the
-            # capacity axis), so numerics match the dispatch/combine einsums
-            from .grouped import grouped_moe_ffn
-
-            y = grouped_moe_ffn(
-                x, combine.sum(axis=2), params["experts"]["wi"], params["experts"]["wo"],
-                top_k=self.gate.k, activation=lambda up, gate: self.activation(up))
-            return y, l_aux
 
         # dispatch: [S, E, C] x [S, M] → [E, C, M]
         dispatched = jnp.einsum("sec,sm->ecm", dispatch.astype(x.dtype), x)

@@ -34,23 +34,41 @@ import jax
 import jax.numpy as jnp
 
 
+# One expert's weight tile is what a row block streams from HBM, and a row
+# block of a decode step is 8 rows: the tile has to be megabytes for the
+# stream to run near the memory's rate (a 256 x 128 tile is 64 KiB, a tenth
+# of a microsecond of HBM against a third of one per grid step).
+WEIGHT_TILE_BYTES = 6 * 2**20
+VMEM_LIMIT_BYTES = 64 * 2**20
+
+
 def _fit_block(dim: int, preferred: int) -> int:
-    """Largest power-of-two block <= preferred that divides dim (1 worst case)."""
-    b = preferred
+    """Largest block <= preferred that divides dim: a multiple of 128 where
+    dim is one (2304 -> 2304, 1152, 768, ...; 896 -> 896, 128), else a power
+    of two (1 worst case)."""
+    if dim % 128 == 0:
+        n = dim // 128
+        return 128 * max(m for m in range(1, n + 1) if n % m == 0 and 128 * m <= max(preferred, 128))
+    b = 1 << (max(preferred, 1).bit_length() - 1)
     while b > 1 and dim % b != 0:
         b //= 2
     return max(b, 1)
 
 
-def _resolve_gmm_tiles(K: int, N: int, block_k=None, block_n=None):
+def _resolve_gmm_tiles(K: int, N: int, block_k=None, block_n=None, itemsize: int = 2):
     """K/N tile resolution: explicit caller value > kernel-config registry
-    (per chip/topology/shape bucket) > the 512 default. ``block_t`` is NOT
-    tunable here — it is a dispatcher contract (block_expert's shape)."""
+    (per chip/topology/shape bucket) > the whole of K and N, K halved until
+    the weight tile fits ``WEIGHT_TILE_BYTES``. ``block_t`` is NOT tunable
+    here — it is a dispatcher contract (block_expert's shape)."""
     from ...autotuning.kernel_config import shape_bucket, tuned_tile
 
     bucket = shape_bucket(K=K, N=N)
-    bk = block_k if block_k is not None else tuned_tile("grouped_matmul", bucket, "block_k", 512)
-    bn = block_n if block_n is not None else tuned_tile("grouped_matmul", bucket, "block_n", 512)
+    bk = block_k if block_k is not None else tuned_tile("grouped_matmul", bucket, "block_k", None)
+    bn = block_n if block_n is not None else tuned_tile("grouped_matmul", bucket, "block_n", None)
+    if bn is None:
+        bn = _fit_block(N, max(WEIGHT_TILE_BYTES // (itemsize * 128), 128))
+    if bk is None:
+        bk = _fit_block(K, max(WEIGHT_TILE_BYTES // (itemsize * int(bn)), 128))
     return int(bk), int(bn)
 
 
@@ -64,7 +82,8 @@ def gmm_reference(lhs, rhs, block_expert, block_t=128):
     return out.astype(lhs.dtype)
 
 
-def gmm(lhs, rhs, block_expert, block_t=128, block_k=None, block_n=None, interpret=False):
+def gmm(lhs, rhs, block_expert, block_t=128, block_k=None, block_n=None, interpret=False,
+        num_live=None):
     """Grouped matmul ``out[i*bt:(i+1)*bt] = lhs[i*bt:(i+1)*bt] @
     rhs[block_expert[i]]``.
 
@@ -72,17 +91,26 @@ def gmm(lhs, rhs, block_expert, block_t=128, block_k=None, block_n=None, interpr
     expert weights; block_expert: [T//block_t] int32 (non-decreasing).
     Returns [T, N] in lhs.dtype; fp32 accumulation.
 
+    ``num_live``: int32 scalar, the row blocks that hold routed rows (the
+    dispatcher's static bound ``T`` is for the worst routing). Blocks from
+    there on compute nothing and come back zero; the dispatcher names the
+    last live block's expert for them, so they read no weights either.
+
     Registry tiles resolve HERE, outside the jit: resolving inside would key
     the compiled-executable cache on ``block_k=None`` and freeze the
     first-seen tiles — a later kernel-config install would be silently
     ignored for already-traced shapes.
     """
-    block_k, block_n = _resolve_gmm_tiles(lhs.shape[1], rhs.shape[2], block_k, block_n)
-    return _gmm(lhs, rhs, block_expert, block_t, block_k, block_n, interpret)
+    block_k, block_n = _resolve_gmm_tiles(lhs.shape[1], rhs.shape[2], block_k, block_n,
+                                          jnp.dtype(rhs.dtype).itemsize)
+    if num_live is None:
+        num_live = block_expert.shape[0]
+    live = jnp.asarray(num_live, jnp.int32).reshape(1)
+    return _gmm(lhs, rhs, block_expert, live, block_t, block_k, block_n, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "block_k", "block_n", "interpret"))
-def _gmm(lhs, rhs, block_expert, block_t, block_k, block_n, interpret):
+def _gmm(lhs, rhs, block_expert, live, block_t, block_k, block_n, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -99,34 +127,37 @@ def _gmm(lhs, rhs, block_expert, block_t, block_k, block_n, interpret):
     assert block_expert.shape == (nt, ), \
         f"block_expert must be [{nt}] for T={T}, block_t={bt}, got {block_expert.shape}"
 
-    def kernel(be_ref, x_ref, w_ref, o_ref, acc_ref):
-        k = pl.program_id(2)
+    def kernel(be_ref, live_ref, x_ref, w_ref, o_ref, acc_ref):
+        i, k = pl.program_id(0), pl.program_id(2)
 
         @pl.when(k == 0)
         def _init():
             acc_ref[:] = jnp.zeros_like(acc_ref)
 
-        acc_ref[:] += jax.lax.dot(x_ref[...].astype(jnp.float32),
-                                  w_ref[0].astype(jnp.float32),
-                                  preferred_element_type=jnp.float32)
+        # operands in the type they are stored in (a bf16 weight tile is not
+        # widened in VMEM: the MXU multiplies bf16 and accumulates float32)
+        @pl.when(i < live_ref[0])
+        def _dot():
+            acc_ref[:] += jax.lax.dot(x_ref[...], w_ref[0], preferred_element_type=jnp.float32)
 
         @pl.when(k == nk - 1)
         def _store():
             o_ref[:] = acc_ref[:].astype(o_ref.dtype)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(nt, nn, nk),
         in_specs=[
-            pl.BlockSpec((bt, bk), lambda i, j, k, be: (i, k)),
-            pl.BlockSpec((1, bk, bn), lambda i, j, k, be: (be[i], k, j)),
+            pl.BlockSpec((bt, bk), lambda i, j, k, be, lv: (i, k)),
+            pl.BlockSpec((1, bk, bn), lambda i, j, k, be, lv: (be[i], k, j)),
         ],
-        out_specs=pl.BlockSpec((bt, bn), lambda i, j, k, be: (i, j)),
+        out_specs=pl.BlockSpec((bt, bn), lambda i, j, k, be, lv: (i, j)),
         scratch_shapes=[pltpu.VMEM((bt, bn), jnp.float32)],
     )
     return pl.pallas_call(kernel, grid_spec=grid_spec,
                           out_shape=jax.ShapeDtypeStruct((T, N), lhs.dtype),
-                          interpret=interpret)(block_expert, lhs, rhs)
+                          compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
+                          name="moe_gmm", interpret=interpret)(block_expert, live, lhs, rhs)
 
 
 def tgmm(lhs, dy, block_expert, num_experts, block_t=128, block_k=None, block_n=None,
@@ -139,7 +170,7 @@ def tgmm(lhs, dy, block_expert, num_experts, block_t=128, block_k=None, block_n=
     otherwise an absent expert's output block would never be written.
     Registry tiles resolve outside the jit (see :func:`gmm`).
     """
-    block_k, block_n = _resolve_gmm_tiles(lhs.shape[1], dy.shape[1], block_k, block_n)
+    block_k, block_n = _resolve_gmm_tiles(lhs.shape[1], dy.shape[1], block_k, block_n, 4)
     return _tgmm(lhs, dy, block_expert, num_experts, block_t, block_k, block_n, interpret)
 
 
@@ -193,7 +224,8 @@ def _tgmm(lhs, dy, block_expert, num_experts, block_t, block_k, block_n, interpr
     )
     return pl.pallas_call(kernel, grid_spec=grid_spec,
                           out_shape=jax.ShapeDtypeStruct((num_experts, K, N), jnp.float32),
-                          interpret=interpret)(block_expert, lhs, dy)
+                          compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
+                          name="moe_tgmm", interpret=interpret)(block_expert, lhs, dy)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, ))

@@ -76,8 +76,9 @@ def test_block_align_dispatch_structure():
                         [0.0, 1.0, 0.0],
                         [0.2, 0.8, 0.0],
                         [0.0, 0.0, 0.0]], jnp.float32)  # last token dropped
-    tok, w_slot, dest, block_expert, T_pad = block_align_dispatch(w_se, top_k=2,
-                                                                 block_rows=4)
+    top_w, top_idx = jax.lax.top_k(w_se, 2)
+    tok, w_slot, dest, block_expert, T_pad, n_live, sizes = block_align_dispatch(top_idx, top_w, 3, 4)
+    assert int(n_live) == 3 and np.asarray(sizes).tolist() == [4, 3, 1]
     assert T_pad % 4 == 0 and block_expert.shape == (T_pad // 4, )
     # non-decreasing block table covering every expert at least once
     beh = np.asarray(block_expert)
@@ -89,9 +90,9 @@ def test_block_align_dispatch_structure():
     # recompute the routing the dispatcher used (top-2 over w_se) and check
     # each slot's dest row falls in a block owned by its routed expert
     wv, idx = jax.lax.top_k(w_se, 2)
-    routed = np.asarray(idx).reshape(-1)
-    order = np.argsort(routed, kind="stable")
-    for slot_expert, d in zip(routed[order], np.asarray(dest)):
+    routed = np.asarray(idx).reshape(-1)  # slots stay in token order
+    assert np.asarray(tok).tolist() == [0, 0, 1, 1, 2, 2, 3, 3]
+    for slot_expert, d in zip(routed, np.asarray(dest)):
         assert beh[d // 4] == slot_expert, (slot_expert, int(d))
 
 
@@ -124,8 +125,8 @@ def test_grouped_ffn_matches_einsum_dispatch(top_k, mlp):
     y_ref = jnp.einsum("sec,ecm->sm", combine, eo)
 
     w_se = combine.sum(axis=2)  # [S, E] per-token kept weights
-    y = grouped_moe_ffn(x, w_se, wi, wo, top_k=top_k, wg=wg, activation=act,
-                        block_rows=8, interpret=True)
+    top_w, top_idx = jax.lax.top_k(w_se, top_k)
+    y = grouped_moe_ffn(x, top_idx, top_w, wi, wo, wg=wg, activation=act, interpret=True)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), rtol=2e-4, atol=2e-4)
 
 
@@ -138,8 +139,8 @@ def test_grouped_ffn_is_differentiable():
     wo = jnp.asarray(rng.normal(size=(E, F, M)), jnp.float32)
 
     def loss(wi, wo, w_se):
-        return jnp.sum(grouped_moe_ffn(x, w_se, wi, wo, top_k=1, block_rows=8,
-                                       interpret=True) ** 2)
+        top_w, top_idx = jax.lax.top_k(w_se, 1)
+        return jnp.sum(grouped_moe_ffn(x, top_idx, top_w, wi, wo, interpret=True) ** 2)
 
     gwi, gwo, gse = jax.grad(loss, argnums=(0, 1, 2))(wi, wo, w_se)
     assert np.isfinite(np.asarray(gwi)).all() and np.abs(np.asarray(gwi)).max() > 0
@@ -147,82 +148,102 @@ def test_grouped_ffn_is_differentiable():
     assert np.isfinite(np.asarray(gse)).all()
 
 
-def test_moelayer_grouped_impl_matches_einsum():
-    """MOELayer(moe_impl='grouped') == MOELayer(moe_impl='einsum') on the
-    same params/tokens (identical gating, different dispatch mechanism)."""
-    from deepspeed_tpu.moe.sharded_moe import MOELayer, TopKGate
-
-    rng = np.random.default_rng(7)
-    S, M, F, E = 24, 16, 32, 4
-    gate = TopKGate(M, E, k=2)
-    einsum_layer = MOELayer(gate, M, F, num_local_experts=E)
-    grouped_layer = MOELayer(gate, M, F, num_local_experts=E, moe_impl="grouped")
-    params = einsum_layer.init(jax.random.PRNGKey(0))
-    x = jnp.asarray(rng.normal(size=(S, M)), jnp.float32)
-    y_e, aux_e = einsum_layer(params, x, train=False)
-    y_g, aux_g = grouped_layer(params, x, train=False)
-    np.testing.assert_allclose(np.asarray(y_g), np.asarray(y_e), rtol=2e-4, atol=2e-4)
-    np.testing.assert_allclose(float(aux_g), float(aux_e), rtol=1e-6)
-    # EP + grouped is rejected loudly, not silently wrong
-    with pytest.raises(NotImplementedError, match="expert parallelism"):
-        MOELayer(gate, M, F, num_local_experts=2, ep_axis="data", ep_size=2,
-                 moe_impl="grouped")
-    with pytest.raises(ValueError, match="moe_impl"):
-        MOELayer(gate, M, F, num_local_experts=E, moe_impl="banana")
+def _dense_expert_loop(x, top_idx, top_w, wi, wg, wo):
+    """Every token through each of its experts, one token and one expert at
+    a time: what the grouped path must equal."""
+    x, wi, wg, wo = (np.asarray(a, np.float64) for a in (x, wi, wg, wo))
+    out = np.zeros_like(x)
+    for t in range(x.shape[0]):
+        for e, w in zip(np.asarray(top_idx)[t], np.asarray(top_w, np.float64)[t]):
+            gate = x[t] @ wg[e]
+            out[t] += w * ((gate / (1.0 + np.exp(-gate)) * (x[t] @ wi[e])) @ wo[e])
+    return out
 
 
-def test_transformer_moe_impl_grouped_forward_and_grad():
-    """cfg.moe_impl='grouped' : same loss as einsum dispatch, and the fused
-    train path stays differentiable through the custom-VJP kernels."""
-    from deepspeed_tpu.models import TransformerConfig, TransformerLM
-    from deepspeed_tpu.models.transformer import forward_with_aux, init_params
+@pytest.mark.parametrize("tokens,block_rows", [(4, 8), (128, 64), (2048, 128)])
+def test_v2_grouped_gemm_moe_matches_dense_expert_loop(tokens, block_rows):
+    """The serving MoE module (the one ``build_modules`` fills the ``moe`` slot
+    with) against a dense per-expert loop at 8, 256 and 4,096 routed slots,
+    with an expert that receives no slot and one that receives most, padding
+    tokens that route nowhere, and its routing counts against numpy's."""
+    from deepspeed_tpu.inference.v2.modules import ConfigBundle, DSMoEConfig, DSMoERegistry
+    from deepspeed_tpu.moe.grouped import padded_rows, pick_block_rows, route_topk
 
-    rng = np.random.default_rng(9)
-    ids = rng.integers(0, 64, size=(2, 16)).astype(np.int32)
+    H, F, E, K = 32, 48, 8, 2
+    rng = np.random.default_rng(tokens)
+    x = jnp.asarray(rng.normal(size=(tokens, H)), jnp.float32)
+    gate_w = rng.normal(size=(H, E)).astype(np.float32)
+    # a router that sends every token to expert 0 first and none to the last:
+    # tokens are |noise| + a large first coordinate, which expert 0 reads with
+    # a large weight and the last expert with a large negative one
+    x = x.at[:, 0].set(6.0 + jnp.abs(x[:, 0]))
+    gate_w[0, 0], gate_w[0, E - 1] = 8.0, -8.0
+    gate_w = jnp.asarray(gate_w)
+    up = jnp.asarray(rng.normal(size=(E, H, F)) * 0.2, jnp.float32)
+    gt = jnp.asarray(rng.normal(size=(E, H, F)) * 0.2, jnp.float32)
+    down = jnp.asarray(rng.normal(size=(E, F, H)) * 0.2, jnp.float32)
+    valid = jnp.asarray(np.arange(tokens) < tokens - tokens // 4)  # the bucket's tail is padding
 
-    def run(impl):
-        cfg = TransformerConfig(vocab_size=64, hidden_size=32, num_layers=2, num_heads=2,
-                                intermediate_size=32, max_seq_len=32, dtype=jnp.float32,
-                                attention_impl="reference", moe_num_experts=4,
-                                moe_top_k=2, moe_impl=impl)
-        params = init_params(cfg, jax.random.PRNGKey(1))
+    moe = DSMoERegistry.instantiate_config(ConfigBundle(
+        name="grouped_gemm_moe",
+        config=DSMoEConfig(n_experts=E, top_k=K, activation="swiglu", dtype=jnp.float32)))
+    assert pick_block_rows(tokens * K, E) == block_rows
+    assert moe.padded_rows(tokens) == padded_rows(tokens * K, E, block_rows, False)
+    y, stats = moe(x, gate_w, up, gt, down, valid=valid, with_stats=True)
 
-        def loss(p):
-            logits, aux = forward_with_aux(cfg, p, jnp.asarray(ids))
-            return jnp.mean(logits ** 2) + aux
-
-        val, grads = jax.value_and_grad(loss)(params)
-        return float(val), grads
-
-    v_e, g_e = run("einsum")
-    v_g, g_g = run("grouped")
-    np.testing.assert_allclose(v_g, v_e, rtol=5e-4)
-    ge = np.asarray(g_e["blocks"]["moe_wi"])
-    gg = np.asarray(g_g["blocks"]["moe_wi"])
-    np.testing.assert_allclose(gg, ge, rtol=5e-3, atol=1e-5)
+    top_idx, top_w = route_topk(x, gate_w, K)
+    live = np.asarray(valid)
+    counts = np.bincount(np.asarray(top_idx)[live].reshape(-1), minlength=E)
+    assert counts[0] >= 0.95 * live.sum() and counts[E - 1] == 0, counts
+    ref = _dense_expert_loop(x, top_idx, top_w, up, gt, down)
+    np.testing.assert_allclose(np.asarray(y)[live], ref[live], rtol=2e-4, atol=2e-4)
+    assert not np.asarray(y)[~live].any(), "a padding token has no expert output"
+    assert np.asarray(stats).tolist() == [int((counts > 0).sum()), int(counts.max())]
 
 
-def test_v2_grouped_gemm_moe_matches_dense_dispatch_module():
-    """Registry-selected grouped_gemm_moe == top_k_gated_moe on the same
-    weights (the serving dense-dispatch oracle)."""
-    from deepspeed_tpu.inference.v2.modules import DSMoERegistry
-    from deepspeed_tpu.inference.v2.modules.configs import DSMoEConfig
-    from deepspeed_tpu.inference.v2.modules.module_registry import ConfigBundle
+def test_transformer_dropless_topk_forward_and_grad():
+    """``moe_dropless``: top-k of the softmax over all experts through the
+    grouped matmul, against the dense per-expert loop on the same routing,
+    and differentiable through the custom-VJP kernels (the weight gradient of
+    an expert no token chose is zero, not unwritten memory)."""
+    from deepspeed_tpu.models import TransformerConfig
+    from deepspeed_tpu.models.transformer import _moe_mlp, init_params
+    from deepspeed_tpu.moe.grouped import route_topk
 
-    T, H, F, E, K = 12, 16, 32, 4, 2
-    rng = np.random.default_rng(11)
-    x = jnp.asarray(rng.normal(size=(T, H)), jnp.float32)
-    gate_w = jnp.asarray(rng.normal(size=(H, E)), jnp.float32)
-    up = jnp.asarray(rng.normal(size=(E, H, F)) * 0.1, jnp.float32)
-    gt = jnp.asarray(rng.normal(size=(E, H, F)) * 0.1, jnp.float32)
-    down = jnp.asarray(rng.normal(size=(E, F, H)) * 0.1, jnp.float32)
+    cfg = TransformerConfig(vocab_size=64, hidden_size=32, num_layers=1, num_heads=2,
+                            intermediate_size=64, moe_intermediate_size=24, max_seq_len=32,
+                            dtype=jnp.float32, attention_impl="reference", moe_num_experts=8,
+                            moe_top_k=3, moe_dropless=True)
+    blocks = init_params(cfg, jax.random.PRNGKey(1))["blocks"]
+    layer = jax.tree_util.tree_map(lambda a: a[0], blocks)
+    assert layer["moe_wi"].shape == (8, 32, 24)
+    # no token ever routes to the last expert
+    layer["gate_wg"] = layer["gate_wg"].at[:, 7].set(0.0).at[0, 7].set(-8.0)
+    h = jnp.asarray(np.random.default_rng(2).normal(size=(2, 16, 32)), jnp.float32)
+    h = h.at[..., 0].set(3.0 + jnp.abs(h[..., 0]))
 
-    def build(name):
-        return DSMoERegistry.instantiate_config(ConfigBundle(
-            name=name, config=DSMoEConfig(n_experts=E, top_k=K, activation="swiglu",
-                                          dtype=jnp.float32)))
+    y, aux = _moe_mlp(cfg, layer, h)
+    top_idx, top_w = route_topk(h.reshape(32, 32), layer["gate_wg"], 3)
+    assert 7 not in np.asarray(top_idx)
+    np.testing.assert_allclose(np.asarray(top_w).sum(-1), 1.0, rtol=1e-6)
+    ref = _dense_expert_loop(h.reshape(32, 32), top_idx, top_w, layer["moe_wi"], layer["moe_wg"],
+                             layer["moe_wo"])
+    np.testing.assert_allclose(np.asarray(y).reshape(32, 32), ref, rtol=2e-4, atol=2e-4)
+    assert float(aux) == 0.0
 
-    dense = build("top_k_gated_moe")(x, gate_w, up, gt, down)
-    grouped = build("grouped_gemm_moe")(x, gate_w, up, gt, down)
-    np.testing.assert_allclose(np.asarray(grouped), np.asarray(dense),
-                               rtol=2e-4, atol=2e-4)
+    grads = jax.grad(lambda lyr: jnp.sum(_moe_mlp(cfg, lyr, h)[0] ** 2))(layer)
+    g = np.asarray(grads["moe_wi"])
+    assert np.isfinite(g).all() and np.abs(g[:7]).max() > 0 and not g[7].any()
+    assert np.isfinite(np.asarray(grads["gate_wg"])).all()
+
+
+@pytest.mark.parametrize("top_k", [3, 8])
+def test_top_k_above_two_is_dropless_or_refused(top_k):
+    """``moe_top_k`` > 2 never falls through to the top-2 capacity gate."""
+    from deepspeed_tpu.models import TransformerConfig
+
+    kwargs = dict(vocab_size=64, hidden_size=32, num_layers=1, num_heads=2, moe_num_experts=8,
+                  moe_top_k=top_k)
+    with pytest.raises(ValueError, match="moe_dropless"):
+        TransformerConfig(**kwargs)
+    assert TransformerConfig(moe_dropless=True, **kwargs).moe_top_k == top_k

@@ -157,7 +157,7 @@ def test_moe_module_matches_per_token_loop():
     down = jnp.asarray(rng.normal(size=(E, F, H)) * 0.1, jnp.float32)
 
     moe = DSMoERegistry.instantiate_config(ConfigBundle(
-        name="top_k_gated_moe",
+        name="grouped_gemm_moe",
         config=DSMoEConfig(n_experts=E, top_k=K, activation="swiglu", dtype=jnp.float32)))
     out = np.asarray(moe(x, gate_w, up, gt, down))
 
@@ -176,4 +176,4 @@ def test_moe_module_matches_per_token_loop():
 def test_moe_supports_config_gate():
     with pytest.raises(ValueError, match="not supported"):
         DSMoERegistry.instantiate_config(ConfigBundle(
-            name="top_k_gated_moe", config=DSMoEConfig(n_experts=2, top_k=3)))
+            name="grouped_gemm_moe", config=DSMoEConfig(n_experts=2, top_k=3)))

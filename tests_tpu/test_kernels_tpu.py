@@ -485,10 +485,46 @@ def test_grouped_matmul_on_chip():
     mid = jax.nn.gelu(jnp.einsum("ecm,emf->ecf", disp, wi))
     y_ref = jnp.einsum("sec,ecm->sm", combine.astype(x.dtype),
                        jnp.einsum("ecf,efm->ecm", mid, wo))
-    y = grouped_moe_ffn(x, combine.sum(axis=2).astype(x.dtype), wi, wo, top_k=2,
-                        activation=lambda up, g: jax.nn.gelu(up), block_rows=128)
+    top_w, top_idx = jax.lax.top_k(combine.sum(axis=2).astype(x.dtype), 2)
+    y = grouped_moe_ffn(x, top_idx, top_w, wi, wo, activation=lambda up, g: jax.nn.gelu(up))
     np.testing.assert_allclose(np.asarray(y, np.float32), np.asarray(y_ref, np.float32),
                                rtol=1e-1, atol=2e-1)
+
+
+@pytest.mark.parametrize("K,N,bt", [(2304, 896, 128), (896, 2304, 128), (2304, 896, 8)])
+def test_grouped_matmul_fwd_bwd_at_served_widths_on_chip(K, N, bt):
+    """``grouped_matmul`` forward and backward (gmm, gmm against the transposed
+    experts, tgmm) at the widths the serving cell runs (64 experts of 2304 x 896
+    and 896 x 2304, bf16) with the DEFAULT tiles, which at these widths are the
+    whole of K and N (``_resolve_gmm_tiles``): the tiles the training path takes
+    too. Against a float32 einsum over the row blocks and its gradient; every
+    expert owns a block (tgmm writes an expert's output when it visits it) and
+    expert 0 owns many."""
+    from deepspeed_tpu.ops.pallas.grouped_matmul import grouped_matmul
+
+    rng = np.random.default_rng(K + bt)
+    E, blocks = 64, 96
+    T = blocks * bt
+    lhs = jnp.asarray(rng.normal(size=(T, K)), jnp.bfloat16)
+    rhs = jnp.asarray(rng.normal(size=(E, K, N)) / np.sqrt(K), jnp.bfloat16)
+    dy = jnp.asarray(rng.normal(size=(T, N)), jnp.bfloat16)
+    be = jnp.asarray(np.sort(np.concatenate([np.arange(E), np.zeros(blocks - E, np.int64)])).astype(np.int32))
+
+    def by_block(lhs, rhs):  # [blocks, bt, K] x [blocks, K, N]: a block's rows times its expert
+        return jnp.einsum("bik,bkn->bin", lhs.reshape(blocks, bt, K), rhs[be],
+                          precision=jax.lax.Precision.HIGHEST).reshape(T, N)
+
+    def loss(fn):
+        return lambda lhs, rhs: jnp.sum(fn(lhs, rhs).astype(jnp.float32) * dy.astype(jnp.float32))
+
+    out, (dx, dw) = jax.jit(lambda l, r: (grouped_matmul(l, r, be, block_t=bt), jax.grad(
+        loss(lambda l, r: grouped_matmul(l, r, be, block_t=bt)), argnums=(0, 1))(l, r)))(lhs, rhs)
+    ref, (rx, rw) = jax.jit(lambda l, r: (by_block(l, r), jax.grad(loss(by_block), argnums=(0, 1))(l, r)))(
+        lhs.astype(jnp.float32), rhs.astype(jnp.float32))
+    for name, got, want in (("out", out, ref), ("dx", dx, rx), ("dw", dw, rw)):
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        rel = np.linalg.norm(got - want) / np.linalg.norm(want)
+        assert np.isfinite(got).all() and rel < 1e-2, (name, rel)  # bf16 operands and outputs: about 3e-3
 
 
 def test_int4_weight_dequant_on_chip():
@@ -551,3 +587,48 @@ def test_paged_attention_kv_split_on_chip():
                          kv_splits=8, k_scale=kT, v_scale=vT)
     np.testing.assert_allclose(np.asarray(out8, np.float32), np.asarray(ref8, np.float32),
                                atol=6e-2, rtol=6e-2)
+
+
+def test_moe_serving_programs_of_every_bucket_pair_run_on_chip():
+    """Every (token bucket, row bucket) ``put`` program and every row bucket's
+    one-step ``decode`` program of a model with experts and both attention
+    kinds, at the served widths and TWO layers (a window layer and a full
+    one): PR 27 met a program of paged attention + ``moe_gmm`` in two or more
+    layers (64 tokens x 8 rows) that never returned under XLA's default scoped
+    VMEM, and the engine now compiles such a model's programs with a larger
+    one (``engine._jit_options``). A serving cell warms only the buckets its
+    traffic reaches; this runs all of them. A hang becomes an exit: the
+    traceback of ``faulthandler`` names the bucket that did not return."""
+    import faulthandler
+
+    from deepspeed_tpu.inference.v2 import (DSStateManagerConfig, InferenceEngineV2,
+                                            RaggedInferenceEngineConfig)
+    from deepspeed_tpu.models import TransformerLM, mellum_config
+
+    cfg = mellum_config("12b-a2.5b", num_layers=2, layer_types=("sliding_attention", "full_attention"),
+                        dtype=jnp.bfloat16)
+    sm = DSStateManagerConfig(max_tracked_sequences=64, max_ragged_batch_size=2048,
+                              max_ragged_sequence_count=64, max_context=8320)
+    icfg = RaggedInferenceEngineConfig(kv_block_size=128, num_kv_blocks=200, kv_dtype=jnp.bfloat16, state_manager=sm)
+    model = TransformerLM(cfg)
+    # bf16 as served: experts stored in the type they are multiplied in are read in place from the stack
+    params = jax.jit(lambda k: jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16), model.init(k, None)))(
+        jax.random.PRNGKey(0))
+    eng = InferenceEngineV2(model, icfg, params=params)
+    assert eng._jit_options, "a model with experts compiles its serving programs with the larger scoped VMEM"
+    ran = []
+    try:
+        for tokens in eng.batch.token_buckets:
+            for rows in eng.batch.seq_buckets:
+                if rows > tokens:
+                    continue  # no batch has more rows than tokens
+                faulthandler.dump_traceback_later(150, exit=True)
+                print(f"put tokens={tokens} rows={rows}", flush=True)
+                ran += eng.warmup([rows], [], token_buckets=[tokens], declare_warmed=False)
+        for rows in eng.batch.seq_buckets:
+            faulthandler.dump_traceback_later(150, exit=True)
+            print(f"decode rows={rows}", flush=True)
+            ran += eng.warmup([rows], [1], declare_warmed=False)
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+    assert len(ran) >= len(eng.batch.seq_buckets) and not any(r["cached"] for r in ran), ran
