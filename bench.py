@@ -414,7 +414,7 @@ def bench_kernels(on_tpu: bool) -> dict:
         print(f"# WARNING: kernels.paged_attention bench failed "
               f"({type(e).__name__}: {str(e)[:160]})", flush=True)
 
-    # --- paged attention decode: flash-decode KV-split on vs off ---
+    # --- paged attention decode: the decode kernel (kv_splits > 1) against the per-token grid ---
     try:
         from deepspeed_tpu.ops.pallas.paged_attention import (_pallas_paged,
                                                               _resolve_kv_splits)

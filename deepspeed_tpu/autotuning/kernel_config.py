@@ -381,13 +381,13 @@ class KernelAutotuner:
 
     def tune_paged_decode(self, n_seqs=None, max_blocks=None, block_size=None, nq=8, d=128,
                           candidates=None):
-        """Sweep the flash-decode ``kv_splits`` factor on a DECODE-shaped
-        batch (one token per sequence, long block table): the split is the
-        only knob that parallelizes a single long-context decode row across
-        the KV axis, and its winner depends on chip generation (megacore
-        count, DMA depth) and context length. Records under the B-only
-        bucket (B = block-table capacity) — the key ``_resolve_kv_splits``
-        falls back to for any decode batch size."""
+        """Sweep ``kv_splits`` on a DECODE-shaped batch (one token per
+        sequence, long block table): 1 is the per-token grid, anything above
+        the decode kernel (its grid is the work list of live blocks, so the
+        candidates above 1 are one kernel: the sweep decides between two
+        grids). Records under the B-only bucket (B = block-table capacity) —
+        the key ``_resolve_kv_splits`` falls back to for any decode batch
+        size."""
         from ..ops.pallas.paged_attention import _pallas_paged
 
         on_tpu = self._on_tpu()

@@ -9,6 +9,7 @@ donated through, so steady-state decode reuses a single compiled program and
 the only host→device traffic is the packed batch descriptor arrays.
 """
 
+import collections
 import functools
 import time
 from typing import Dict, Iterable, List, Optional, Tuple
@@ -26,7 +27,7 @@ from ...monitor.roofline import get_roofline
 from ...monitor.trace import (NULL_SPAN, get_tracer, pop_compile_source,
                               push_compile_source)
 from ...moe.grouped import merge_routing_stats
-from ...ops.pallas.paged_attention import kernel_choice
+from ...ops.pallas.paged_attention import decode_kv_counts, kernel_choice
 from ...utils.logging import log_dist
 from .config_v2 import RaggedInferenceEngineConfig
 from .model_implementations.flat_model import ragged_forward
@@ -129,6 +130,9 @@ class InferenceEngineV2:
 
         self._compiled: Dict[Tuple[int, int, Optional[str]], object] = {}
         self._kernel_labels: Dict[Tuple[int, int], str] = {}  # (tokens, rows) -> span label, see _kernel_of
+        # (window or None, layers that attend in it), for _kv_span_args
+        self._kv_windows = list(collections.Counter(
+            mc.layer_window(l) for l in range(mc.num_layers)).items())
         # speculative-decoding lifetime totals (two int adds per verify
         # step; the gauge feeding off them only updates when metrics are on)
         self._spec_totals = {"drafted": 0, "accepted": 0}
@@ -386,6 +390,7 @@ class InferenceEngineV2:
                     descs.append(seq)
                 rb = self.batch.finalize()
             t_bucket, s_bucket = rb.token_ids.shape[0], rb.block_tables.shape[0]
+            pos0 = [seq.seen_tokens for seq in descs] if sp is not NULL_SPAN and not had_prefill else None
 
             from .sampling import all_greedy, pack_sampling
 
@@ -438,7 +443,8 @@ class InferenceEngineV2:
                             rows_decode=sum(1 for n in sizes if n == 1), tokens=sum(sizes),
                             bucket_tokens=int(t_bucket), bucket_rows=int(s_bucket), steps=1,
                             kernel=self._kernel_of(t_bucket, s_bucket),
-                            uids=[int(u) for u in batch_uids[:16]], blocked=bool(block), **moe_args)
+                            uids=[int(u) for u in batch_uids[:16]], blocked=bool(block), **moe_args,
+                            **(self._kv_span_args(t_bucket, s_bucket, [pos0]) if pos0 else {}))
         if rf.enabled and block:
             # wall join through the blocking host fetch — the same window the
             # outer put() books as prefill/decode-active in the goodput ledger,
@@ -470,6 +476,20 @@ class InferenceEngineV2:
                 "experts_hit": int(stats[0]),
                 "experts_total": mc.moe_num_experts * mc.num_layers * steps,
                 "expert_load_max": int(stats[1])}
+
+    def _kv_span_args(self, T: int, S: int, pos) -> dict:
+        """What a decode span says of the attention kernel's grid: ``kv_live``
+        live (row, KV block) pairs and ``kv_steps`` block slots the grid runs
+        for them, both summed over layers and steps
+        (``paged_attention.decode_kv_counts``; ``pos``: the fed tokens'
+        positions, a list of rows a step). Nothing for a shape no program has
+        traced yet or one the tiled prefill grid took."""
+        choice = kernel_choice(T, S, self._max_blocks_per_seq)
+        if choice is None or choice["kernel"] == "paged_attn_q_tiled":
+            return {}
+        steps, live = decode_kv_counts(choice, pos, self._kv_windows, self.config.kv_block_size,
+                                       self._max_blocks_per_seq, T)
+        return {"kv_steps": steps, "kv_live": live}
 
     def _kernel_of(self, T: int, S: int) -> str:
         """``<kernel>:<q_tile or kv_splits>:<rule>`` of the paged-attention
@@ -578,6 +598,7 @@ class InferenceEngineV2:
                         max_blocks_per_seq=self._max_blocks_per_seq,
                         block_size=self.config.kv_block_size,
                         token_buckets=self.batch.seq_buckets, seq_buckets=self.batch.seq_buckets)
+                pos0 = np.asarray([seq.seen_tokens for seq in seqs]) if sp is not NULL_SPAN else None
                 for seq, toks in zip(seqs, first):
                     self.state_manager.allocate_blocks(seq, n_steps)
                     seq.pre_forward(n_steps)
@@ -654,7 +675,9 @@ class InferenceEngineV2:
                 sp.set_args(seqs=S, rows=S, tokens=S * int(n_steps), steps=int(n_steps),
                             bucket_rows=int(s_bucket), bucket_tokens=int(s_bucket),
                             kernel=self._kernel_of(s_bucket, s_bucket),
-                            uids=[int(u) for u in uids[:16]], blocked=bool(block), **moe_args)
+                            uids=[int(u) for u in uids[:16]], blocked=bool(block), **moe_args,
+                            **self._kv_span_args(s_bucket, s_bucket,
+                                                 pos0[None, :] + np.arange(int(n_steps))[:, None]))
         if rf.enabled and block:
             rf.note_wall(rf_bucket, time.perf_counter() - t_rf)
         if reg.enabled and block:

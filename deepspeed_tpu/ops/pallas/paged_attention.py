@@ -50,7 +50,9 @@ def _contiguity_ok(seq_idx, S: int, pos=None) -> bool:
 
 
 # Which kernel each traced shape took, and the rule that decided:
-# ``(T, S, max_blocks) -> {"kernel", "q_tile", "kv_splits", "rule"}``.
+# ``(T, S, max_blocks) -> {"kernel", "q_tile", "kv_splits", "rule",
+# "blocks_per_step"}`` (the last: KV blocks a grid step of the decode kernel
+# takes, 1 for the other grids).
 # ``paged_attention`` resolves both while ``jit`` traces the program, from
 # static shapes, so filling this costs nothing at run time; the serving
 # engine reads it once per compiled program and puts it on the step's span.
@@ -63,9 +65,10 @@ def kernel_choice(T: int, S: int, max_blocks: int):
     return KERNEL_CHOICES.get((int(T), int(S), int(max_blocks)))
 
 
-def _note_choice(T, S, max_blocks, kernel, q_tile, kv_splits, rule):
+def _note_choice(T, S, max_blocks, kernel, q_tile, kv_splits, rule, blocks_per_step: int = 1):
     KERNEL_CHOICES[(int(T), int(S), int(max_blocks))] = {
-        "kernel": kernel, "q_tile": int(q_tile), "kv_splits": int(kv_splits), "rule": rule}
+        "kernel": kernel, "q_tile": int(q_tile), "kv_splits": int(kv_splits), "rule": rule,
+        "blocks_per_step": int(blocks_per_step)}
 
 
 # The largest q-tile the shape heuristic gives: the MXU then sees ``g * 128``
@@ -147,22 +150,22 @@ def _q_tile_choice(T: int, S: int, seq_idx=None, pos=None):
 
 
 def _resolve_kv_splits(T: int, S: int, max_blocks: int, q_tile: int = 1) -> int:
-    """Resolve the flash-decode KV-split factor through the kernel-config
-    registry, falling back to the shape heuristic. The split applies ONLY to
-    the per-token grid (``q_tile == 1`` — decode-shaped rows): a prefill tile
-    already amortizes its KV stream across the tile's tokens, while a decode
-    row walks its whole context serially — partitioning the KV blocks across
-    a second grid axis lets the online-softmax chains of a long context run
-    independently (megacore-parallel on chip) at the cost of one
-    log-sum-exp merge over ``kv_splits`` partials.
+    """Which grid a decode-shaped batch takes: 1 keeps the per-token grid
+    (``rows x table columns``), anything above takes the decode kernel
+    ``paged_attn_kv_split``. That kernel's grid is the work list of the
+    batch's live (row, block) pairs and has no split count any more (a row is
+    one softmax chain; a v5e has one TensorCore, so partial chains bought
+    nothing but their merge), so a value above 1 says "the decode kernel" and
+    nothing else: the name and the numbers are kept for the registry entries,
+    the sweep (``KernelAutotuner.tune_paged_decode``: 1 against the rest) and
+    the callers that exist.
 
-    ``DS_TPU_PAGED_KV_SPLITS``: operator kill switch / override — ``1`` pins
-    the proven single-chain grid (the same escape hatch as
-    ``DS_TPU_PAGED_Q_TILE``), any higher value forces that split factor.
-    Lookup order mirrors ``q_tile``: exact ``(B, T)`` bucket, then the
-    ``B``-only bucket the decode sweep records (B = block-table capacity —
-    the KV length is what the split amortizes over; T is just the decode
-    batch size of the moment)."""
+    Resolution: a tiled prefill (``q_tile > 1``), a table under 8 columns and
+    a batch with real multi-token chunks stay off the decode kernel whatever
+    else is set; then ``DS_TPU_PAGED_KV_SPLITS`` (``1`` pins the per-token
+    grid), then the kernel-config registry (exact ``(B, T)`` bucket, then the
+    ``B``-only bucket the decode sweep records; B = block-table capacity),
+    then the heuristic, which takes the decode kernel."""
     return _kv_splits_choice(T, S, max_blocks, q_tile)[0]
 
 
@@ -172,11 +175,11 @@ def _kv_splits_choice(T: int, S: int, max_blocks: int, q_tile: int = 1):
     ``heuristic:multi_token``, ``env``, ``tuned`` or ``heuristic:long_table``)."""
     from ...autotuning.kernel_config import shape_bucket, tuned_tile
 
-    # tiled prefill rows keep the single chain; a short table has no KV
-    # axis worth splitting (each split must own >= a few blocks); and a
-    # batch with real multi-token chunks (T well past the seq count —
-    # e.g. a non-contiguous prefill demoted to the per-token grid) must
-    # not inherit the split's T x kv_splits partial buffers
+    # tiled prefill rows have their own grid; a short table keeps the
+    # per-token grid (its dead columns are few); and a batch with real
+    # multi-token chunks (T well past the seq count — e.g. a non-contiguous
+    # prefill demoted to the per-token grid) is not a decode batch: the work
+    # list gives every token a row of its own
     if q_tile > 1:
         return 1, "heuristic:tiled"
     if max_blocks < 8:
@@ -216,11 +219,11 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: i
     same-sequence tokens into one grid row so each KV block streams from HBM
     once per TILE instead of once per token, and each kv head's dot feeds the
     MXU ``g * q_tile`` rows — the prefill-chunk amortization win.
-    ``kv_splits``: flash-decode KV partitioning for the per-token (decode)
-    grid — each split runs a partial online softmax over its share of the
-    KV blocks on its own grid row (megacore-parallel on chip) and the
-    partials merge with the standard log-sum-exp combine. None = registry,
-    then heuristic; ignored whenever the q-tiled grid is taken.
+    ``kv_splits``: above 1, a decode-shaped batch takes the decode kernel
+    (``paged_attn_kv_split``: one grid step per live (row, KV block) pair,
+    from a scalar-prefetched work list) instead of the per-token grid; the
+    number itself shapes nothing (:func:`_resolve_kv_splits`). None =
+    registry, then heuristic; ignored whenever the q-tiled grid is taken.
     Returns [T, nq, d]."""
     T, nq, d = q.shape
     nkv = k_pool.shape[1]
@@ -265,12 +268,13 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: i
     else:
         kv_rule = "explicit"
     kv_splits = max(1, min(int(kv_splits), max_blocks))
-    # the same order as _pallas_paged: a tile wins, then a split, then the
-    # per-token grid; a demotion stays the reason whatever the split says
+    # the same order as _pallas_paged: a tile wins, then the decode kernel,
+    # then the per-token grid; a demotion stays the reason whatever else says
     if q_tile > 1:
         _note_choice(T, S, max_blocks, "paged_attn_q_tiled", q_tile, 1, q_rule)
     elif kv_splits > 1:
-        _note_choice(T, S, max_blocks, "paged_attn_kv_split", 1, kv_splits, kv_rule)
+        _note_choice(T, S, max_blocks, "paged_attn_kv_split", 1, kv_splits, kv_rule,
+                     _decode_blocks_per_step(block_size * nkv, d, k_pool.dtype.itemsize))
     else:
         _note_choice(T, S, max_blocks, "paged_attn_per_token", 1, 1,
                      q_rule if q_rule == "contiguity_demoted" else kv_rule)
@@ -366,14 +370,15 @@ def _pallas_paged(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: int
     S, max_blocks = block_tables.shape
     # view the pool as whole blocks; drop any trailing scratch remainder
     n_pool_blocks = k_pool.shape[0] // block_size
-    k4 = k_pool[:n_pool_blocks * block_size].reshape(n_pool_blocks, block_size, nkv, d)
-    v4 = v_pool[:n_pool_blocks * block_size].reshape(n_pool_blocks, block_size, nkv, d)
+    n_live = n_pool_blocks * block_size
+    k4 = k_pool[:n_live].reshape(n_pool_blocks, block_size, nkv, d)
+    v4 = v_pool[:n_live].reshape(n_pool_blocks, block_size, nkv, d)
     quant = k_scale is not None
     if quant:
         # scales stay [nkv, cols]: sublane = nkv, lane = block_size — the
         # layout the scatter side maintains natively, no per-call transpose
-        ks2 = k_scale[:, :n_pool_blocks * block_size]
-        vs2 = v_scale[:, :n_pool_blocks * block_size]
+        ks2 = k_scale[:, :n_live]
+        vs2 = v_scale[:, :n_live]
     scale = 1.0 / math.sqrt(d)
 
     if q_tile and q_tile > 1:
@@ -382,10 +387,14 @@ def _pallas_paged(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: int
                               block_size=block_size, q_tile=int(q_tile), window=window,
                               alibi=alibi, interpret=interpret)
     if kv_splits and kv_splits > 1:
-        return _paged_kv_split(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos,
-                               ks2 if quant else None, vs2 if quant else None,
-                               block_size=block_size, kv_splits=int(kv_splits),
-                               window=window, alibi=alibi, interpret=interpret)
+        # a block as one [block * nkv, d] matrix, row t * nkv + n: the pool's
+        # own bytes; the int8 scales [nkv, cols] are laid out to match
+        as_rows = lambda pool: pool[:n_live].reshape(n_pool_blocks, block_size * nkv, d)
+        by_col = lambda sc: jnp.transpose(sc).reshape(n_pool_blocks, 1, block_size * nkv)
+        return _paged_kv_split(pl, pltpu, q, as_rows(k_pool), as_rows(v_pool), block_tables, seq_idx,
+                               pos, by_col(ks2) if quant else None, by_col(vs2) if quant else None,
+                               block_size=block_size, window=window, alibi=alibi,
+                               interpret=interpret)
 
     grid = (T, max_blocks)
 
@@ -742,158 +751,233 @@ def _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
     return flat[tile_id * qt + slot]
 
 
-def _paged_kv_split(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
-                    block_size: int, kv_splits: int, window, alibi, interpret: bool):
-    """Flash-decode KV-split grid: ``(kv_splits, T, blocks_per_split)``.
+# measured on a v5e (PERF.md section 6, PR 28): 512 KiB blocks (8 kv heads of
+# 128, bf16) 0.84 us a block at one a step, 0.70 at two or four; 256 KiB
+# blocks (4 kv heads) 0.60, 0.42 and 0.35 at one, two and four
+_DECODE_STEP_BYTES = 1 << 20
 
-    A decode row's online softmax is a serial chain over its whole context
-    — on a long context that chain is the decode latency floor. Partition
-    the KV blocks: split ``s`` owns block range
-    ``[s * blocks_per_split, (s+1) * blocks_per_split)`` and computes an
-    independent partial (un-normalized accumulator + its running max ``m``
-    and mass ``l``); the partials merge afterwards with the standard
-    log-sum-exp combine
 
-        m* = max_s m_s;  out = Σ_s e^{m_s - m*} acc_s / Σ_s e^{m_s - m*} l_s
+def _decode_blocks_per_step(rows: int, d: int, itemsize: int) -> int:
+    """KV blocks one grid step of the decode kernel takes (1, 2 or 4), from
+    the bytes of one block's K and V (``rows = block_size * nkv`` rows of
+    ``d``): a grid step costs about a third of a microsecond whatever it
+    fetches, so a step should stream at least ``_DECODE_STEP_BYTES``."""
+    return max(1, min(4, _DECODE_STEP_BYTES // (2 * rows * d * itemsize)))
 
-    which is exactly the two-pass algebra of the online softmax, so the
-    result is bit-comparable (within f32 association) to the single chain.
-    The split axis leads the grid and is declared ``parallel`` — on chip the
-    independent chains distribute across megacores; the per-token grid can
-    never parallelize one token's context. Splits wholly beyond a token's
-    live range (or wholly below its sliding window) contribute
-    ``m = -inf, l = 0`` and vanish in the merge. int8 dequant-at-tile,
-    alibi and window masking are inherited unchanged from the per-token
-    grid."""
+
+def decode_kv_counts(choice, pos, windows, block_size: int, max_blocks: int, bucket_rows: int):
+    """``(kv_steps, kv_live)`` of a decode bucket's attention calls, on the
+    host, from the rows' positions: ``kv_live`` the live (row, block) pairs
+    (:func:`_decode_work_list`'s count, real rows only) and ``kv_steps`` the
+    block slots the chosen kernel's grid runs for them, pad rows included:
+    the decode kernel's items times the blocks an item takes, every table
+    column of every bucket row for the other grids and the gather. ``choice``
+    is the shape's :func:`kernel_choice`; ``pos`` the positions of the fed
+    tokens, ``[..., rows]`` (one leading entry a step); ``windows`` pairs of
+    (window or None, layers that attend in it)."""
+    pos = np.asarray(pos, np.int64)
+    calls = pos.size // max(pos.shape[-1], 1)
+    hi = np.minimum(pos // block_size, max_blocks - 1)
+    per = choice["blocks_per_step"] if choice["kernel"] == "paged_attn_kv_split" else 0
+    steps = live = 0
+    for window, layers in windows:
+        lo = 0 if window is None else np.minimum(np.maximum(pos - (window - 1), 0) // block_size, hi)
+        n = hi - lo + 1
+        live += layers * int(n.sum())
+        if per:
+            steps += layers * per * (int((-(-n // per)).sum()) + calls * (bucket_rows - pos.shape[-1]))
+        else:
+            steps += layers * calls * bucket_rows * max_blocks
+    return steps, live
+
+
+def _decode_work_list(block_tables, seq_idx, pos, block_size: int, window, per_step: int = 1):
+    """The live (row, block) pairs of a decode batch, row after row and
+    ``per_step`` consecutive table columns an item, as the int32 arrays the
+    decode kernel prefetches, and how many items there are.
+
+    Row ``t`` at position ``p`` sees table columns ``lo..hi`` with ``hi = p //
+    block_size`` and ``lo`` the column of ``p - window + 1`` (0 without a
+    window), so it has ``hi - lo + 1 >= 1`` live pairs, in
+    ``ceil((hi - lo + 1) / per_step)`` items: no item lies past a row's
+    position or wholly under its window. Returns ``(w_row, w_col, w_blk,
+    total)``: item ``i < total`` is row ``w_row[i]`` against table columns
+    ``w_col[i] + b``, pool blocks ``w_blk[b * bound + i]``, for ``b <
+    per_step``; a row's items are consecutive and ascend by column. A slot
+    past the row's last column (only a row's last item has any) names the
+    block that slot held in the item before, so nothing is fetched for it,
+    and the kernel's position mask hides it. ``bound``, the arrays' length,
+    is the most items the SHAPES allow, ``T x ceil(min(max_blocks, blocks a
+    window can span) / per_step)``: prefix-shared blocks count once per row
+    that reads them, so the pool's size bounds nothing. ``w_row`` has one
+    more entry and reads ``T`` from ``total`` on, so ``w_row[i + 1] !=
+    w_row[i]`` marks the last item of every row."""
+    T = pos.shape[0]
+    max_blocks = block_tables.shape[1]
+    hi = jnp.clip(pos // block_size, 0, max_blocks - 1)
+    if window is None:
+        lo = jnp.zeros_like(hi)
+        cols = max_blocks
+    else:
+        lo = jnp.minimum(jnp.maximum(pos - (window - 1), 0) // block_size, hi)
+        cols = min(max_blocks, (window + block_size - 2) // block_size + 1)
+    n = (hi - lo + per_step) // per_step
+    bound = T * (-(-cols // per_step))
+    total = jnp.sum(n)
+    start = jnp.cumsum(n) - n
+    i = jnp.arange(bound + 1, dtype=jnp.int32)
+    w_row = jnp.repeat(jnp.arange(T, dtype=jnp.int32), n, total_repeat_length=bound + 1)
+    w_col = lo[w_row] + (i - start[w_row]) * per_step
+    blks = []
+    for b in range(per_step):
+        live = w_col + b <= hi[w_row]
+        blk = block_tables[seq_idx[w_row], jnp.minimum(w_col + b, hi[w_row])]
+        if b:  # a dead slot repeats the block this slot last held
+            blk = blk[jax.lax.cummax(jnp.where(live, i, 0))]
+        blks.append(blk[:bound])
+    return (jnp.where(i < total, w_row, T), w_col[:bound], jnp.concatenate(blks),
+            total.astype(jnp.int32))
+
+
+def _paged_kv_split(pl, pltpu, q, k2, v2, block_tables, seq_idx, pos, ks2, vs2,
+                    block_size: int, window, alibi, interpret: bool):
+    """The decode kernel: grid steps for the LIVE (row, KV block) pairs only.
+
+    A decode batch is one query token a row against contexts of very
+    different length under one block table as wide as the longest context
+    the engine admits. The grid is therefore not ``rows x table columns`` but
+    the work list of :func:`_decode_work_list`, scalar-prefetched: step ``i``
+    is row ``w_row[i]`` against ``B`` consecutive table columns from
+    ``w_col[i]`` (:func:`_decode_blocks_per_step`: as many blocks as make a
+    step's stream worth its fixed cost), the grid's length is the number of
+    items (a DYNAMIC bound: no step runs for a pair that is not live, and the
+    static bound is only the arrays' length), and the pipeline fetches the
+    next item's blocks while this one is computed, across rows as within
+    them. A batch whose contexts fit two columns runs a step a row, a row
+    past its sliding window starts at the window's first block, and one long
+    row among short ones costs its own blocks and nothing else. Each row is
+    ONE online-softmax chain, normalised at its last step: nothing is merged
+    afterwards (the kernel keeps the name its time is read by).
+
+    The pool ``[pool_len, nkv, d]`` is read as ``[blocks, block * nkv, d]``:
+    the same bytes on the chip (a token's ``nkv`` heads are the rows of one
+    tile either way, so XLA makes no copy), and a block is then ONE matrix
+    whose row ``t * nkv + n`` is token ``t``'s key for kv head ``n``. A step
+    multiplies all ``nq`` query rows with all of it, masks the columns of the
+    other kv heads like keys out of sight (one compare against a constant
+    table of each column's token, or of no token for another head's), and
+    multiplies the probabilities ``[nq, block * nkv]`` with the value block
+    the same way: the zeros of the other heads' columns drop out of the sum,
+    so the output needs no cut by head either. Nothing is gathered by
+    sublane, sliced under a tile or transposed; the MXU sees two dots a block
+    whatever ``nkv`` and ``g`` are.
+    Operands go to the MXU as they are stored (bf16 q and pool: bf16
+    operands, float32 accumulation); scores, mask, ``m``, ``l`` and ``acc``
+    are float32, ``m``/``l`` replicated across the lanes. int8 KV is cast
+    exactly and its per-token scales (``ks2``/``vs2``: ``[blocks, 1, block *
+    nkv]``, laid out by the caller) multiply the scores and the probabilities
+    in float32."""
     T, nq, d = q.shape
-    nkv = k4.shape[2]
+    M = k2.shape[1]                # rows of a block: block_size * nkv
+    nkv = M // block_size
     g = nq // nkv
-    S, max_blocks = block_tables.shape
-    ks_n = int(kv_splits)
-    per = -(-max_blocks // ks_n)
     quant = ks2 is not None
     scale = 1.0 / math.sqrt(d)
-    grid = (ks_n, T, per)
+    # operands of the two dots: what q and the pool hold, unless the pool is
+    # quantised (int8 is exact in float32, and the scales are float32)
+    cdt = jnp.float32 if quant else jnp.promote_types(q.dtype, k2.dtype)
+    B = _decode_blocks_per_step(M, d, k2.dtype.itemsize)
+    w_row, w_col, w_blk, total = _decode_work_list(block_tables, seq_idx, pos, block_size, window, B)
+    bound = w_col.shape[0]
 
-    def q_map(s, t, j, seq_ref, pos_ref, bt_ref):
-        return (t, 0, 0)
+    def q_map(i, row_ref, col_ref, blk_ref, pos_ref):
+        return (row_ref[i], 0, 0)
 
-    def o_map(s, t, j, seq_ref, pos_ref, bt_ref):
-        return (s, t, 0, 0)
+    def kv_map(b):
+        return lambda i, row_ref, col_ref, blk_ref, pos_ref: (blk_ref[b * bound + i], 0, 0)
 
-    def kv_map(s, t, j, seq_ref, pos_ref, bt_ref):
-        # clamp into the token's live range (the Mosaic skip-refetch idiom
-        # of the per-token grid): dead steps re-present a resident block
-        hi = pos_ref[t] // block_size
-        jj = jnp.minimum(s * per + j, hi)
-        if window is not None:
-            lo = jnp.maximum(pos_ref[t] - (window - 1), 0) // block_size
-            jj = jnp.maximum(jj, jnp.minimum(lo, hi))
-        return (bt_ref[seq_ref[t], jj], 0, 0, 0)
+    nt_dims = (((1, ), (1, )), ((), ()))  # [nq, d] x [M, d] -> [nq, M]
 
-    def scale_map(s, t, j, seq_ref, pos_ref, bt_ref):
-        return (0, kv_map(s, t, j, seq_ref, pos_ref, bt_ref)[0])
-
-    def kernel(seq_ref, pos_ref, bt_ref, q_ref, k_ref, v_ref, *rest):
+    def kernel(row_ref, col_ref, blk_ref, pos_ref, q_ref, tok_ref, *rest):
+        k_refs, v_refs, rest = rest[:B], rest[B:2 * B], rest[2 * B:]
         if quant:
-            ks_ref, vs_ref, o_ref, m_o_ref, l_o_ref, acc_ref, m_ref, l_ref = rest
-        else:
-            o_ref, m_o_ref, l_o_ref, acc_ref, m_ref, l_ref = rest
-        s_id = pl.program_id(0)
-        t = pl.program_id(1)
-        j = pl.program_id(2)
-        jb = s_id * per + j  # absolute block index this step covers
-        my_pos = pos_ref[t]
+            ks_refs, vs_refs, rest = rest[:B], rest[B:2 * B], rest[2 * B:]
+        o_ref, acc_ref, m_ref, l_ref = rest
+        i = pl.program_id(0)
+        row = row_ref[i]
+        my_pos = pos_ref[row]
 
-        @pl.when(j == 0)
+        @pl.when(jnp.logical_or(i == 0, row_ref[jnp.maximum(i - 1, 0)] != row))
         def _init():
             acc_ref[:] = jnp.zeros_like(acc_ref)
             m_ref[:] = jnp.full_like(m_ref, -1e30)
             l_ref[:] = jnp.zeros_like(l_ref)
 
-        in_window = jnp.logical_and(jb * block_size <= my_pos, jb < max_blocks)
-        if window is not None:
-            in_window = jnp.logical_and(
-                in_window, (jb + 1) * block_size - 1 > my_pos - window)
-
-        @pl.when(in_window)
-        def _compute():
-            qb = q_ref[0].astype(jnp.float32) * scale  # [nq, d]
-            kb = k_ref[0].astype(jnp.float32)  # [bs, nkv, d]
-            vb = v_ref[0].astype(jnp.float32)
-            if quant:  # dequant at the VMEM tile — HBM only streamed int8
-                kb = kb * ks_ref[...].T[:, :, None]
-                vb = vb * vs_ref[...].T[:, :, None]
-            s_heads = []
-            for n in range(nkv):
-                s_heads.append(jax.lax.dot(qb[n * g:(n + 1) * g], kb[:, n, :].T))
-            sc = jnp.concatenate(s_heads, axis=0)  # [nq, bs]
-            kpos = jb * block_size + jax.lax.broadcasted_iota(jnp.int32, (nq, block_size), 1)
-            if alibi is not None:
-                sc = sc + _slopes_rows(alibi, 1) * (kpos - my_pos).astype(jnp.float32)
-            vis = kpos <= my_pos
+        qb = q_ref[0].astype(cdt)
+        # tok_ref[h, c]: the token of column c inside its block where c is a
+        # key of query head h's kv head, else a number no position reaches. A
+        # key is seen at or before the row's position, inside the window; a
+        # slot past the row's last column lies wholly after the position
+        tok = tok_ref[...]
+        scs = []
+        for b in range(B):
+            sc = jax.lax.dot_general(qb, k_refs[b][0].astype(cdt), nt_dims,
+                                     preferred_element_type=jnp.float32)     # [nq, M]
+            if quant:
+                sc = sc * ks_refs[b][0]
+            sc = sc * scale
+            rel = my_pos - (col_ref[i] + b) * block_size
+            vis = tok <= rel
             if window is not None:
-                vis = jnp.logical_and(vis, my_pos - kpos < window)
-            sc = jnp.where(vis, sc, -1e30)
-            m_prev = m_ref[:]
-            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
-            p = jnp.exp(sc - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            ctx_heads = []
-            for n in range(nkv):
-                ctx_heads.append(jax.lax.dot(p[n * g:(n + 1) * g], vb[:, n, :]))
-            acc_ref[:] = acc_ref[:] * alpha + jnp.concatenate(ctx_heads, axis=0)
-            m_ref[:] = m_new
+                vis = jnp.logical_and(vis, tok > rel - window)
+            if alibi is not None:
+                sc = sc + _slopes_rows(alibi, 1) * (tok - rel).astype(jnp.float32)
+            scs.append(jnp.where(vis, sc, -1e30))
+        m_prev = m_ref[:]                                                    # [nq, 128], lanes equal
+        m_new = m_prev
+        for sc in scs:
+            m_new = jnp.maximum(m_new, jnp.max(sc, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_ref[:] * alpha
+        acc = acc_ref[:] * _lanes(alpha, d)
+        for b, sc in enumerate(scs):
+            p = jnp.exp(sc - _lanes(m_new, M))
+            l_new = l_new + jnp.sum(p, axis=-1, keepdims=True)
+            if quant:
+                p = p * vs_refs[b][0]
+            acc = acc + jax.lax.dot(p.astype(cdt), v_refs[b][0].astype(cdt),
+                                    preferred_element_type=jnp.float32)
+        l_ref[:] = l_new
+        m_ref[:] = m_new
+        acc_ref[:] = acc
 
-        @pl.when(j == per - 1)
+        @pl.when(row_ref[i + 1] != row)
         def _finalize():
-            # un-normalized partial + its softmax stats: the merge below
-            # owns the division, so the kernel never divides by a dead
-            # split's zero mass
-            o_ref[0, 0] = acc_ref[:]
-            m_o_ref[0, 0] = m_ref[:]
-            l_o_ref[0, 0] = l_ref[:]
+            o_ref[0] = (acc_ref[:] / _lanes(jnp.maximum(l_ref[:], 1e-30), d)).astype(o_ref.dtype)
 
-    in_specs = [
-        pl.BlockSpec((1, nq, d), q_map),
-        pl.BlockSpec((1, block_size, nkv, d), kv_map),
-        pl.BlockSpec((1, block_size, nkv, d), kv_map),
-    ]
-    operands = [q, k4, v4]
+    # the one table of the masks, from the shapes alone (a constant of the
+    # program, fetched once: its block index never changes)
+    col = np.arange(M)
+    tok_of = np.where(col[None, :] % nkv == np.arange(nq)[:, None] // g, col[None, :] // nkv, 2**30)
+    in_specs = [pl.BlockSpec((1, nq, d), q_map), pl.BlockSpec((nq, M), lambda i, *refs: (0, 0))]
+    in_specs += [pl.BlockSpec((1, M, d), kv_map(b)) for b in range(B)] * 2
+    operands = [q, jnp.asarray(tok_of, jnp.int32)] + [k2] * B + [v2] * B
     if quant:
-        in_specs += [pl.BlockSpec((nkv, block_size), scale_map),
-                     pl.BlockSpec((nkv, block_size), scale_map)]
-        operands += [ks2, vs2]
+        in_specs += [pl.BlockSpec((1, 1, M), kv_map(b)) for b in range(B)] * 2
+        operands += [ks2] * B + [vs2] * B
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=grid,
+        num_scalar_prefetch=4,
+        grid=(total, ),
         in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, 1, nq, d), o_map),
-                   pl.BlockSpec((1, 1, nq, 1), o_map),
-                   pl.BlockSpec((1, 1, nq, 1), o_map)],
+        out_specs=pl.BlockSpec((1, nq, d), q_map),
         scratch_shapes=[
             pltpu.VMEM((nq, d), jnp.float32),
-            pltpu.VMEM((nq, 1), jnp.float32),
-            pltpu.VMEM((nq, 1), jnp.float32),
+            pltpu.VMEM((nq, _LANES), jnp.float32),
+            pltpu.VMEM((nq, _LANES), jnp.float32),
         ],
     )
-    kwargs = {}
-    if not interpret:
-        # the split axis is the parallelism the kernel exists for: declare
-        # it so Mosaic may distribute independent chains across megacores
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"))
-    acc, m, l = pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((ks_n, T, nq, d), jnp.float32),
-                   jax.ShapeDtypeStruct((ks_n, T, nq, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((ks_n, T, nq, 1), jnp.float32)],
-        interpret=interpret, name="paged_attn_kv_split", **kwargs)(
-            seq_idx, pos, block_tables, *operands)
-    # log-sum-exp merge over splits (the flash-decode combine)
-    m_star = jnp.max(m, axis=0, keepdims=True)
-    w = jnp.exp(m - m_star)  # dead splits: exp(-1e30 - m*) == 0
-    out = jnp.sum(acc * w, axis=0) / jnp.maximum(jnp.sum(l * w, axis=0), 1e-30)
-    return out.astype(q.dtype)
+    return pl.pallas_call(kernel, grid_spec=grid_spec,
+                          out_shape=jax.ShapeDtypeStruct((T, nq, d), q.dtype),
+                          interpret=interpret, name="paged_attn_kv_split")(
+                              w_row, w_col, w_blk, pos, *operands)

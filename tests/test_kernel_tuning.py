@@ -15,8 +15,10 @@ from deepspeed_tpu.autotuning.kernel_config import (CONFIG_FILENAME, KernelAutot
                                                     KernelConfigRegistry, set_kernel_config_path,
                                                     shape_bucket, topology_key, tuned_tile)
 from deepspeed_tpu.models.transformer import alibi_slopes
-from deepspeed_tpu.ops.pallas.paged_attention import (_contiguity_ok, _pallas_paged, _q_tile_choice,
-                                                      _resolve_kv_splits, _resolve_q_tile,
+from deepspeed_tpu.ops.pallas import paged_attention as pa_mod
+from deepspeed_tpu.ops.pallas.paged_attention import (_contiguity_ok, _decode_work_list, _pallas_paged,
+                                                      _q_tile_choice, _resolve_kv_splits,
+                                                      _resolve_q_tile, decode_kv_counts,
                                                       paged_attention_reference)
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir))
@@ -35,6 +37,12 @@ def _fresh_registry():
 # q-tiled paged attention: interpret-mode parity matrix vs the gather oracle
 # ---------------------------------------------------------------------------
 
+def _int8_pool(xf):
+    """``xf`` [pool, nkv, d] as an int8 pool and its [nkv, pool] absmax/127 scales."""
+    sc = (np.abs(xf).max(axis=2) / 127.0).T
+    return jnp.asarray(np.round(xf / sc.T[:, :, None]).clip(-127, 127), jnp.int8), jnp.asarray(sc, jnp.float32)
+
+
 def _paged_setup(seed=0, nkv=2, g=2, d=32, bs=16, n_seqs=3, blocks_per_seq=4, int8=False):
     rng = np.random.default_rng(seed)
     nq = nkv * g
@@ -43,11 +51,8 @@ def _paged_setup(seed=0, nkv=2, g=2, d=32, bs=16, n_seqs=3, blocks_per_seq=4, in
     vf = rng.normal(size=(pool, nkv, d))
     tables = jnp.arange(n_seqs * blocks_per_seq, dtype=jnp.int32).reshape(n_seqs, blocks_per_seq)
     if int8:
-        ks = (np.abs(kf).max(axis=2) / 127.0).T  # [nkv, pool]
-        vs = (np.abs(vf).max(axis=2) / 127.0).T
-        kp = jnp.asarray(np.round(kf / ks.T[:, :, None]).clip(-127, 127), jnp.int8)
-        vp = jnp.asarray(np.round(vf / vs.T[:, :, None]).clip(-127, 127), jnp.int8)
-        scales = dict(k_scale=jnp.asarray(ks, jnp.float32), v_scale=jnp.asarray(vs, jnp.float32))
+        (kp, ks), (vp, vs) = _int8_pool(kf), _int8_pool(vf)
+        scales = dict(k_scale=ks, v_scale=vs)
     else:
         kp, vp = jnp.asarray(kf, jnp.float32), jnp.asarray(vf, jnp.float32)
         scales = {}
@@ -219,6 +224,127 @@ def test_kv_split_parity_matrix(case, kv_splits):
     out1 = _pallas_paged(q, kp, vp, tables, seq_idx, pos, block_size=bs, interpret=True,
                          q_tile=1, kv_splits=1, **kw)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(ref), rtol=2e-4, atol=2e-5)
+
+
+# the decode kernel at what it keys on in the serving cells, scaled down in
+# d and block only: (case, nkv, g, window, int8, alibi, contexts in tokens of
+# each row). Blocks are 16 tokens, the table 65 columns wide, as the cells'.
+_DECODE_CASES = {
+    # mistral-7b's heads: rows of 1 to 16 live blocks in a 65-column table
+    "g4_nkv8_rows_of_1_to_16_blocks": (8, 4, None, False, False, [16 * b - (b % 3) for b in range(1, 17)]),
+    # mellum's heads and window layers: rows under the window beside rows past it
+    "g8_nkv4_rows_under_and_past_the_window": (4, 8, 128, False, False,
+                                               [5, 40, 127, 128, 129, 200, 255, 256, 257, 505]),
+    "g8_nkv4_full_layer": (4, 8, None, False, False, [5, 40, 127, 128, 129, 200, 255, 256, 257, 505]),
+    # longprompt's decode rows: one long row among short ones
+    "one_long_row_and_many_short": (8, 4, 512, False, False, [1030, 3, 17, 33, 2, 16, 31, 9]),
+    "int8_g4": (8, 4, None, True, False, [16 * b + 5 for b in range(1, 9)]),
+    "int8_window_g8": (4, 8, 128, True, False, [5, 130, 255, 256, 300]),
+    "alibi_g4": (8, 4, None, False, True, [1, 16, 17, 100, 250]),
+    "alibi_window_g8": (4, 8, 128, False, True, [1, 16, 17, 129, 300]),
+}
+
+
+def _decode_case(name, pad_rows=3):
+    """Pools, a 65-column table whose live columns hold distinct random
+    blocks, one decode token a row and ``pad_rows`` pad rows (sequence 0 at
+    position 0, as ``ragged_wrapper.finalize`` emits them)."""
+    import zlib
+
+    nkv, g, window, int8, alibi, ctx = _DECODE_CASES[name]
+    d, bs, mb = 32, 16, 65
+    rng = np.random.default_rng(zlib.crc32(name.encode()))
+    S = len(ctx)
+    need = [-(-c // bs) for c in ctx]
+    n_blocks = sum(need) + 3
+    tables = np.zeros((S, mb), np.int32)
+    free = rng.permutation(n_blocks)
+    for r, n in enumerate(need):
+        tables[r, :n], free = free[:n], free[n:]
+    kf = rng.normal(size=(n_blocks * bs, nkv, d))
+    vf = rng.normal(size=(n_blocks * bs, nkv, d))
+    kw = {}
+    if int8:
+        (kp, ks), (vp, vs) = _int8_pool(kf), _int8_pool(vf)
+        kw = dict(k_scale=ks, v_scale=vs)
+    else:
+        kp, vp = jnp.asarray(kf, jnp.float32), jnp.asarray(vf, jnp.float32)
+    if alibi:
+        kw["alibi"] = tuple(alibi_slopes(nkv * g).tolist())
+    if window is not None:
+        kw["window"] = window
+    seq_idx = jnp.asarray(list(range(S)) + [0] * pad_rows, jnp.int32)
+    pos = jnp.asarray([c - 1 for c in ctx] + [0] * pad_rows, jnp.int32)
+    q = jnp.asarray(rng.normal(size=(S + pad_rows, nkv * g, d)), jnp.float32)
+    return q, kp, vp, jnp.asarray(tables), seq_idx, pos, bs, kw
+
+
+@pytest.fixture
+def blocks_per_step(request, monkeypatch):
+    """The decode kernel with ``request.param`` KV blocks a grid step, whatever
+    the rule would give the cases' small blocks (the jitted kernel is traced
+    anew)."""
+    monkeypatch.setattr(pa_mod, "_decode_blocks_per_step", lambda rows, d, itemsize: request.param)
+    _pallas_paged.clear_cache()
+    yield request.param
+    _pallas_paged.clear_cache()
+
+
+@pytest.mark.parametrize("blocks_per_step", [1, 2, 4], indirect=True)
+@pytest.mark.parametrize("case", sorted(_DECODE_CASES))
+def test_decode_kernel_parity_at_what_the_cells_key_on(case, blocks_per_step):
+    """``paged_attn_kv_split`` against the gather oracle at the head shapes of
+    both decode-heavy cells (g 4 x 8 kv heads, g 8 x 4), a 65-column table
+    with rows of very different length, rows on both sides of a window, pad
+    rows at position 0, int8 KV and alibi, at one, two and four blocks a grid
+    step (odd tails and rows shorter than a step included)."""
+    q, kp, vp, tables, seq_idx, pos, bs, kw = _decode_case(case)
+    ref = paged_attention_reference(q, kp, vp, tables, seq_idx, pos, bs, **kw)
+    out = _pallas_paged(q, kp, vp, tables, seq_idx, pos, block_size=bs, interpret=True,
+                        q_tile=1, kv_splits=2, **kw)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("per_step", [1, 2, 4])
+@pytest.mark.parametrize("window", [None, 40, 128])
+def test_decode_work_list_holds_exactly_the_live_pairs(window, per_step):
+    """The grid of the decode kernel IS the work list: for a ragged batch
+    with a window its items, spread over their slots, are exactly the (row,
+    column) pairs with a visible key, each once, with the table's block;
+    a slot past a row's end repeats the block it last held (no fetch); the
+    host's ``decode_kv_counts`` counts the same pairs and the same slots;
+    and the arrays are as long as the shapes' bound."""
+    bs, mb = 16, 65
+    rng = np.random.default_rng(3)
+    pos = np.asarray([0, 15, 16, 39, 40, 41, 127, 128, 200, 1039, 0, 0], np.int32)
+    T = pos.size
+    seq_idx = np.asarray(list(range(T - 2)) + [0, 0], np.int32)   # two pad rows
+    tables = rng.integers(0, 500, size=(T - 2, mb)).astype(np.int32)
+    w_row, w_col, w_blk, total = (np.asarray(a) for a in _decode_work_list(
+        jnp.asarray(tables), jnp.asarray(seq_idx), jnp.asarray(pos), bs, window, per_step))
+    cols = mb if window is None else min(mb, (window + bs - 2) // bs + 1)
+    bound = T * -(-cols // per_step)
+    assert w_col.shape == (bound, ) and w_blk.shape == (per_step * bound, ) and w_row.shape == (bound + 1, )
+    want = {(t, c) for t in range(T) for c in range(mb)
+            if c * bs <= pos[t] and (window is None or (c + 1) * bs - 1 > pos[t] - window)}
+    got = []
+    for i in range(int(total)):
+        for b in range(per_step):
+            t, c = int(w_row[i]), int(w_col[i]) + b
+            if c <= pos[t] // bs:
+                got.append((t, c))
+                assert w_blk[b * bound + i] == tables[seq_idx[t], c]
+            elif i:  # item 0 has no item before it: its dead slots cost a fetch
+                assert w_blk[b * bound + i] == w_blk[b * bound + i - 1]
+    assert len(got) == len(set(got)) and set(got) == want
+    assert list(w_row[:int(total)]) == sorted(w_row[:int(total)]) and (w_row[int(total):] == T).all()
+    choice = {"kernel": "paged_attn_kv_split", "blocks_per_step": per_step}
+    real = T - 2
+    steps, live = decode_kv_counts(choice, pos[:real], [(window, 1)], bs, mb, T)
+    assert live == len({p for p in want if p[0] < real}) and steps == int(total) * per_step
+    # the grids that walk the whole table run every column of every bucket row
+    assert decode_kv_counts({"kernel": "paged_attn_per_token", "blocks_per_step": 1}, pos[:real],
+                            [(window, 3)], bs, mb, T) == (3 * T * mb, 3 * live)
 
 
 def test_kv_split_non_dividing_factor_and_single_block():

@@ -43,12 +43,12 @@ def _fresh_tracer():
     get_metrics().reset()
 
 
-def _engine():
+def _engine(window=None):
     from deepspeed_tpu.inference.v2 import InferenceEngineV2, RaggedInferenceEngineConfig
 
     cfg = TransformerConfig(vocab_size=128, hidden_size=64, num_layers=2, num_heads=4,
                             intermediate_size=128, max_seq_len=128, dtype=jnp.float32,
-                            attention_impl="reference")
+                            attention_impl="reference", sliding_window=window)
     icfg = RaggedInferenceEngineConfig()
     icfg.use_pallas_kernels = "always"  # the paged kernel's module: off the TPU it takes its reference
     icfg.kv_block_size = 16
@@ -156,6 +156,48 @@ def test_engine_step_spans_carry_buckets_steps_and_kernel(serve_trace):
     assert dispatches and all(d.args["compiled"] == 0 for d in dispatches), "the traced run was warm"
 
 
+def _live_pairs(contexts, steps, block, window):
+    """(row, KV block) pairs with a key in sight, by brute force: row ``r`` at
+    step ``j`` sits at position ``contexts[r] + j`` and sees block ``b`` when
+    one of its tokens is at or before that position and inside the window."""
+    pairs = 0
+    for c in contexts:
+        for p in range(c, c + steps):
+            pairs += sum(1 for b in range(p // block + 1)
+                         if window is None or (b + 1) * block - 1 > p - window)
+    return pairs
+
+
+@pytest.mark.parametrize("window", [None, 24])
+def test_decode_spans_count_the_kernels_grid_and_the_live_pairs(window):
+    """``serving/decode`` and ``serving/decode_step`` carry ``kv_steps`` and
+    ``kv_live``: ``kv_live`` is the live (row, block) pairs of the rows'
+    contexts over the steps and layers, window applied, and ``kv_steps`` what
+    the kernel that ran walks for the bucket (off the TPU the gather: every
+    table column of every bucket row)."""
+    engine = _engine(window)
+    rng = np.random.default_rng(1)
+    contexts = [30, 9, 17]
+    uids = [7, 8, 9]
+    for uid, n in zip(uids, contexts):
+        engine.put([uid], [rng.integers(0, 128, size=n, dtype=np.int32)])
+    one = [np.asarray([3], np.int32)] * 3
+    engine.put(uids, one), engine.decode(uids, one, 5)        # trace both programs first
+    contexts = [c + 6 for c in contexts]
+    tracer = get_tracer().configure(enabled=True)
+    engine.put(uids, one)
+    engine.decode(uids, one, 5)
+    events = {e["name"]: e.get("args", {}) for e in tracer.drain() if e["ph"] == "X"}
+    step, burst = events["serving/decode_step"], events["serving/decode"]
+    layers, max_blocks = 2, 96 // 16
+    assert step["kv_live"] == layers * _live_pairs(contexts, 1, 16, window)
+    assert burst["kv_live"] == layers * _live_pairs([c + 1 for c in contexts], 5, 16, window)
+    assert step["kernel"].startswith("paged_attention_reference")
+    assert step["kv_steps"] == layers * step["bucket_tokens"] * max_blocks
+    assert burst["kv_steps"] == layers * 5 * burst["bucket_rows"] * max_blocks
+    assert 0 < burst["kv_live"] <= burst["kv_steps"]
+
+
 def test_bus_sees_the_same_spans_once_each_under_their_bus_names(engine, serve_trace):
     tracer = get_tracer().configure(enabled=True)  # pathless buffer
     _serve(engine, uid_base=300)
@@ -190,7 +232,7 @@ def test_both_sinks_off_allocates_nothing_and_the_kernel_table_fills_at_trace_ti
     pa.KERNEL_CHOICES.pop((8, 2, 4), None)
     fn(q)
     assert pa.kernel_choice(8, 2, 4) == {"kernel": "paged_attention_reference", "q_tile": 1, "kv_splits": 1,
-                                         "rule": "off_tpu"}
+                                         "rule": "off_tpu", "blocks_per_step": 1}
     pa.KERNEL_CHOICES.clear()
     fn(q)
     assert pa.KERNEL_CHOICES == {}
@@ -222,10 +264,15 @@ def test_kernel_choice_names_the_rule_that_decided(monkeypatch, tmp_path, shape,
 
 
 @pytest.mark.parametrize("T,S,max_blocks,want", [
-    (512, 32, 65, {"kernel": "paged_attn_q_tiled", "q_tile": 32, "kv_splits": 1, "rule": "heuristic:short_rows"}),
-    (2048, 8, 65, {"kernel": "paged_attn_q_tiled", "q_tile": 128, "kv_splits": 1, "rule": "heuristic:long_rows"}),
-    (32, 32, 65, {"kernel": "paged_attn_kv_split", "q_tile": 1, "kv_splits": 8, "rule": "heuristic:long_table"}),
-    (32, 32, 4, {"kernel": "paged_attn_per_token", "q_tile": 1, "kv_splits": 1, "rule": "heuristic:short_table"}),
+    (512, 32, 65, {"kernel": "paged_attn_q_tiled", "q_tile": 32, "kv_splits": 1, "rule": "heuristic:short_rows",
+                   "blocks_per_step": 1}),
+    (2048, 8, 65, {"kernel": "paged_attn_q_tiled", "q_tile": 128, "kv_splits": 1, "rule": "heuristic:long_rows",
+                   "blocks_per_step": 1}),
+    # the decode kernel: 16-token blocks of 8 kv heads of 128 in float32 are 128 KiB, so four a grid step
+    (32, 32, 65, {"kernel": "paged_attn_kv_split", "q_tile": 1, "kv_splits": 8, "rule": "heuristic:long_table",
+                  "blocks_per_step": 4}),
+    (32, 32, 4, {"kernel": "paged_attn_per_token", "q_tile": 1, "kv_splits": 1, "rule": "heuristic:short_table",
+                 "blocks_per_step": 1}),
 ])
 def test_on_the_tpu_branch_the_table_names_the_grid_that_runs(monkeypatch, tmp_path, T, S, max_blocks, want):
     """The TPU branch of ``paged_attention`` with the kernel itself stubbed

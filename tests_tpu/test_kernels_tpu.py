@@ -546,47 +546,61 @@ def test_int4_weight_dequant_on_chip():
     np.testing.assert_allclose(np.asarray(y, np.float32), y_ref, rtol=5e-2, atol=5e-1)
 
 
-def test_paged_attention_kv_split_on_chip():
-    """Flash-decode KV-split kernel on real TPU: a decode-shaped
-    long-context batch through the split grid —
-    partial softmax per split, log-sum-exp merge, megacore-parallel split
-    axis — vs the gather reference, bf16 and int8-KV. Mosaic-compiled: the
-    interpret-mode parity matrix in tests/test_kernel_tuning.py cannot see
-    lowering bugs, and the split grid's CompilerParams(dimension_semantics)
-    path only exists here."""
-    rng = np.random.default_rng(19)
-    nq, nkv, d, bs, mb = 16, 16, 128, 128, 16
-    n_seqs = 4
-    pool_len = n_seqs * mb * bs
-    q = jnp.asarray(rng.normal(size=(n_seqs, nq, d)), jnp.bfloat16)
-    tables = jnp.asarray(rng.permutation(n_seqs * mb).reshape(n_seqs, mb), jnp.int32)
-    seq_idx = jnp.arange(n_seqs, dtype=jnp.int32)
-    # one fully-live long-context row plus mid-context rows
-    pos = jnp.asarray([mb * bs - 1, bs + 3, 5 * bs + 17, 2], jnp.int32)
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("name,nq,nkv,window,mb,n_blocks,contexts", [
+    # mistral-7b.decode-heavy: 32 rows of 262-2,020 tokens, 8 kv heads (two 512 KiB blocks a grid step)
+    ("mistral-7b", 32, 8, 4096, 65, 619, [262 + 57 * i for i in range(31)]),
+    # mellum2-12b-a2.5b.decode-heavy: 4 kv heads (four blocks a step), its window layers and its full ones
+    ("mellum2.window", 32, 4, 1024, 65, 619, [262 + 57 * i for i in range(31)]),
+    ("mellum2.full", 32, 4, None, 65, 619, [262 + 57 * i for i in range(31)]),
+    # one kv head a query head, a table it fills: one 1 MiB block a step, one long row beside short ones
+    ("mha16", 16, 16, None, 16, 64, [2048, 131, 657, 3]),
+])
+def test_paged_attention_kv_split_on_chip(name, nq, nkv, window, mb, n_blocks, contexts, kv):
+    """The decode kernel on the chip, through ``paged_attention`` as the
+    serving engine calls it, at the shapes of the two decode-heavy cells
+    (heads of 128, 128-token blocks, tables 65 wide, one token a row, a pad
+    row at position 0), bf16 and int8 KV, against the gather reference. The
+    interpret-mode matrix in tests/test_kernel_tuning.py cannot see Mosaic:
+    the dynamic grid bound, the scalar-prefetched work list and the pool read
+    as ``[blocks, block * nkv, d]`` only exist here."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
 
-    kf = rng.normal(size=(pool_len, nkv, d)).astype(np.float32)
-    vf = rng.normal(size=(pool_len, nkv, d)).astype(np.float32)
-    k_pool = jnp.asarray(kf, jnp.bfloat16)
-    v_pool = jnp.asarray(vf, jnp.bfloat16)
-    ref = paged_attention_reference(q, k_pool, v_pool, tables, seq_idx, pos, bs)
-    for ks in (4, 8):
-        out = _pallas_paged(q, k_pool, v_pool, tables, seq_idx, pos, block_size=bs,
-                            q_tile=1, kv_splits=ks)
-        np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref, np.float32),
-                                   atol=5e-2, rtol=5e-2, err_msg=f"kv_splits={ks}")
+    d, bs = 128, 128
+    rng = np.random.default_rng(28)
+    S = len(contexts)
+    T = S + 1  # the pad run ragged_wrapper.finalize emits
+    tables = np.zeros((S, mb), np.int32)
+    for r, c in enumerate(contexts):
+        tables[r, :-(-c // bs)] = rng.choice(n_blocks, size=-(-c // bs), replace=False)
+    tables = jnp.asarray(tables)
+    seq_idx = jnp.asarray(list(range(S)) + [0], jnp.int32)
+    pos = jnp.asarray([c - 1 for c in contexts] + [0], jnp.int32)
+    q = jnp.asarray(rng.normal(size=(T, nq, d)), jnp.bfloat16)
+    kf = rng.normal(size=(n_blocks * bs, nkv, d)).astype(np.float32)
+    vf = rng.normal(size=(n_blocks * bs, nkv, d)).astype(np.float32)
+    if kv == "int8":
+        ksc = np.maximum(np.abs(kf).max(-1) / 127.0, 1e-8)
+        vsc = np.maximum(np.abs(vf).max(-1) / 127.0, 1e-8)
+        k_pool = jnp.asarray(np.round(kf / ksc[..., None]), jnp.int8)
+        v_pool = jnp.asarray(np.round(vf / vsc[..., None]), jnp.int8)
+        kw = dict(k_scale=jnp.asarray(ksc.T), v_scale=jnp.asarray(vsc.T))
+    else:
+        k_pool, v_pool, kw = jnp.asarray(kf, jnp.bfloat16), jnp.asarray(vf, jnp.bfloat16), {}
+    del kf, vf
 
-    # int8-KV through the split grid (dequant at the tile read per split)
-    ksc = np.maximum(np.abs(kf).max(-1) / 127.0, 1e-8)
-    vsc = np.maximum(np.abs(vf).max(-1) / 127.0, 1e-8)
-    k8 = jnp.asarray(np.round(kf / ksc[..., None]), jnp.int8)
-    v8 = jnp.asarray(np.round(vf / vsc[..., None]), jnp.int8)
-    kT, vT = jnp.asarray(ksc.T), jnp.asarray(vsc.T)
-    ref8 = paged_attention_reference(q, k8, v8, tables, seq_idx, pos, bs,
-                                     k_scale=kT, v_scale=vT)
-    out8 = _pallas_paged(q, k8, v8, tables, seq_idx, pos, block_size=bs, q_tile=1,
-                         kv_splits=8, k_scale=kT, v_scale=vT)
-    np.testing.assert_allclose(np.asarray(out8, np.float32), np.asarray(ref8, np.float32),
-                               atol=6e-2, rtol=6e-2)
+    pa.KERNEL_CHOICES.pop((T, S, mb), None)
+    out = jax.jit(lambda q, k_pool, v_pool, kw: pa.paged_attention(  # the pools as arguments, not constants
+        q, k_pool, v_pool, tables, seq_idx, pos, bs, window=window, **kw))(q, k_pool, v_pool, kw)
+    choice = pa.kernel_choice(T, S, mb)
+    # a grid step streams 1 MiB of K and V where the blocks are smaller than that
+    assert (choice["kernel"], choice["blocks_per_step"]) == (
+        "paged_attn_kv_split", max(1, min(4, (1 << 20) // (2 * bs * nkv * d * k_pool.dtype.itemsize))))
+    ref = np.asarray(paged_attention_reference(q, k_pool, v_pool, tables, seq_idx, pos, bs,
+                                               window=window, **kw), np.float32)
+    got = np.asarray(out, np.float32)
+    np.testing.assert_allclose(got, ref, atol=5e-2, rtol=5e-2)
+    assert np.linalg.norm(got - ref) / np.linalg.norm(ref) < 6e-3
 
 
 def test_moe_serving_programs_of_every_bucket_pair_run_on_chip():
