@@ -354,178 +354,6 @@ def bench_serving(on_tpu: bool):
     return out
 
 
-def bench_kernels(on_tpu: bool) -> dict:
-    """Raw-speed microbench A/Bs (PR 10): q-tiled vs per-token paged
-    attention tok/s, explicit-overlap vs implicit ZeRO-3 step time, tuned vs
-    default flash tiles. Each sub-block is independently guarded — a failure
-    costs that key only, never the headline. Off-TPU the Pallas arms run in
-    interpret mode on tiny shapes (disclosed), so the numbers exercise the
-    plumbing, not the chip."""
-    import time as _t
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from deepspeed_tpu.autotuning.kernel_config import KernelAutotuner
-
-    out = {}
-    if not on_tpu:
-        out["note"] = "cpu: pallas arms run interpreted on tiny shapes"
-
-    # same warmup/median methodology as the tile sweep, so the A/B block and
-    # the autotuner can never quietly measure differently
-    timeit = KernelAutotuner(output_dir=".", steps=3, warmup=1).measure
-
-    # --- paged attention: q-tiled vs per-token ---
-    try:
-        from deepspeed_tpu.ops.pallas.paged_attention import _pallas_paged, _resolve_q_tile
-
-        rng = np.random.default_rng(0)
-        if on_tpu:
-            nq, nkv, d, bs, chunk, n_seqs = 16, 16, 128, 128, 128, 2
-        else:
-            nq, nkv, d, bs, chunk, n_seqs = 4, 4, 32, 16, 16, 2
-        T = chunk * n_seqs
-        NB = n_seqs * (-(-(chunk + bs) // bs))
-        dt = jnp.bfloat16 if on_tpu else jnp.float32
-        k_pool = jnp.asarray(rng.normal(size=(NB * bs, nkv, d)), dt)
-        v_pool = jnp.asarray(rng.normal(size=(NB * bs, nkv, d)), dt)
-        tables = jnp.arange(NB, dtype=jnp.int32).reshape(n_seqs, -1)
-        q = jnp.asarray(rng.normal(size=(T, nq, d)), dt)
-        seq_idx = jnp.asarray(np.repeat(np.arange(n_seqs), chunk), jnp.int32)
-        pos = jnp.asarray(np.tile(np.arange(chunk), n_seqs) + bs // 2, jnp.int32)
-        qt = _resolve_q_tile(T, n_seqs)
-        if qt <= 1:
-            qt = 8
-
-        def paged(q_tile):
-            return lambda: _pallas_paged(q, k_pool, v_pool, tables, seq_idx, pos,
-                                         block_size=bs, q_tile=q_tile, interpret=not on_tpu)
-
-        t1 = timeit(paged(1))
-        tq = timeit(paged(qt))
-        out["paged_attention"] = {
-            "q_tile": qt, "prefill_tokens": T,
-            "per_token_tok_s": round(T / t1, 1),
-            "q_tiled_tok_s": round(T / tq, 1),
-            "speedup": round(t1 / tq, 3),
-        }
-    except Exception as e:
-        print(f"# WARNING: kernels.paged_attention bench failed "
-              f"({type(e).__name__}: {str(e)[:160]})", flush=True)
-
-    # --- paged attention decode: the decode kernel (kv_splits > 1) against the per-token grid ---
-    try:
-        from deepspeed_tpu.ops.pallas.paged_attention import (_pallas_paged,
-                                                              _resolve_kv_splits)
-
-        # the SHARED decode-shaped case (one token per sequence at the end
-        # of a fully-live long context — the shape where the per-token
-        # grid's single softmax chain is the latency floor): the bench
-        # measures exactly the shape tune_paged_decode records
-        n_seqs = 4
-        q, k_pool, v_pool, tables, seq_idx, pos, bs, mb = \
-            KernelAutotuner.paged_decode_case(on_tpu, n_seqs=n_seqs)
-        ks = _resolve_kv_splits(n_seqs, n_seqs, mb)
-        if ks <= 1:
-            ks = 8
-
-        def decode(kv_splits):
-            return lambda: _pallas_paged(q, k_pool, v_pool, tables, seq_idx, pos,
-                                         block_size=bs, q_tile=1, kv_splits=kv_splits,
-                                         interpret=not on_tpu)
-
-        t1 = timeit(decode(1))
-        ts = timeit(decode(ks))
-        out["paged_decode_split"] = {
-            "kv_splits": ks, "context_tokens": mb * bs, "decode_rows": n_seqs,
-            "split_off_tok_s": round(n_seqs / t1, 1),
-            "split_on_tok_s": round(n_seqs / ts, 1),
-            "speedup": round(t1 / ts, 3),
-        }
-    except Exception as e:
-        print(f"# WARNING: kernels.paged_decode_split bench failed "
-              f"({type(e).__name__}: {str(e)[:160]})", flush=True)
-
-    # --- ZeRO-3 overlap_comm: explicit vs implicit step time ---
-    try:
-        import deepspeed_tpu
-        from deepspeed_tpu.models import TransformerConfig, TransformerLM
-        from deepspeed_tpu.parallel import groups
-
-        if on_tpu:
-            mcfg = TransformerConfig(vocab_size=8192, hidden_size=1024, num_layers=8,
-                                     num_heads=8, intermediate_size=2816, max_seq_len=512,
-                                     dtype=jnp.bfloat16, attention_impl="flash")
-            micro, seq, steps = 2, 512, 4
-        else:
-            mcfg = TransformerConfig(vocab_size=256, hidden_size=64, num_layers=4, num_heads=4,
-                                     intermediate_size=128, max_seq_len=64, dtype=jnp.float32,
-                                     attention_impl="reference")
-            micro, seq, steps = 2, 64, 3
-        step_ms = {}
-        for overlap in (False, True):
-            groups.reset()
-            n = len(jax.devices())
-            cfgd = {
-                "train_batch_size": micro * n,
-                "train_micro_batch_size_per_gpu": micro,
-                "gradient_accumulation_steps": 1,
-                "optimizer": {"type": "AdamW", "params": {"lr": 1e-4}},
-                "zero_optimization": {"stage": 3, "overlap_comm": overlap},
-                "bf16": {"enabled": bool(on_tpu)},
-                "steps_per_print": 10**9,
-                "tpu": {"mesh": {"data": n}},
-            }
-            eng, _, _, _ = deepspeed_tpu.initialize(model=TransformerLM(mcfg), config=cfgd)
-            rng = np.random.default_rng(0)
-            batch = {"input_ids": rng.integers(0, mcfg.vocab_size, size=(micro * n, seq),
-                                               dtype=np.int32)}
-            eng.train_batch(batch)  # compile
-            float(np.asarray(eng.state["step"]))
-            t0 = _t.perf_counter()
-            for _ in range(steps):
-                eng.train_batch(batch)
-            float(np.asarray(eng.state["step"]))
-            step_ms["overlap_on" if overlap else "overlap_off"] = round(
-                (_t.perf_counter() - t0) / steps * 1e3, 3)
-            _free_engine(eng, "state")
-        out["zero3_overlap"] = {
-            "step_ms_off": step_ms["overlap_off"], "step_ms_on": step_ms["overlap_on"],
-            "speedup": round(step_ms["overlap_off"] / max(step_ms["overlap_on"], 1e-9), 3),
-        }
-    except Exception as e:
-        print(f"# WARNING: kernels.zero3_overlap bench failed "
-              f"({type(e).__name__}: {str(e)[:160]})", flush=True)
-
-    # --- flash attention: tuned vs default tiles (only meaningful on-chip) ---
-    if on_tpu:
-        try:
-            from deepspeed_tpu.ops.pallas.flash_attention import (_default_tile, _pallas_flash,
-                                                                  _resolve_tiles)
-
-            S, nq, d = 2048, 16, 128
-            k1, k2, k3 = jax.random.split(jax.random.PRNGKey(0), 3)
-            qf = jax.random.normal(k1, (1, S, nq, d), jnp.bfloat16)
-            kf = jax.random.normal(k2, (1, S, nq, d), jnp.bfloat16)
-            vf = jax.random.normal(k3, (1, S, nq, d), jnp.bfloat16)
-            dflt = _default_tile()
-            bq, bk = _resolve_tiles(S, d)
-            td = timeit(lambda: _pallas_flash(qf, kf, vf, causal=True, block_q=dflt,
-                                              block_k=dflt))
-            tt = timeit(lambda: _pallas_flash(qf, kf, vf, causal=True, block_q=bq, block_k=bk))
-            out["flash_tiles"] = {
-                "default": [dflt, dflt], "tuned": [bq, bk],
-                "default_ms": round(td * 1e3, 3), "tuned_ms": round(tt * 1e3, 3),
-                "speedup": round(td / tt, 3),
-                "untuned": (bq, bk) == (dflt, dflt),  # no kernel_config.json for this topo
-            }
-        except Exception as e:
-            print(f"# WARNING: kernels.flash_tiles bench failed "
-                  f"({type(e).__name__}: {str(e)[:160]})", flush=True)
-    return out
-
-
 def trace_demo(seq=128, micro=2):
     """Drive the eager 3-call engine API and one eager collective under the
     live tracer: the fwd/bwd/step phase spans only exist as separate host
@@ -1178,23 +1006,6 @@ def run_bench():
             print(f"# WARNING: timeline bench phase failed "
                   f"({type(e).__name__}: {str(e)[:200]})", flush=True)
 
-    # --kernels: raw-speed microbench A/Bs (q-tiled paged attention, explicit
-    # ZeRO-3 overlap, tuned-vs-default flash tiles). Outside the headline
-    # timed window; DS_TPU_BENCH_KERNELS=0 skips, failure never costs the
-    # headline (each sub-block is guarded inside bench_kernels).
-    kernels_line = None
-    if os.environ.get("DS_TPU_BENCH_KERNELS", "1") != "0":
-        try:
-            kernels_line = bench_kernels(on_tpu)
-            if kernels_line.get("paged_attention"):
-                pa = kernels_line["paged_attention"]
-                print(f"# kernels: paged q_tile={pa['q_tile']} speedup={pa['speedup']}x; "
-                      f"overlap={kernels_line.get('zero3_overlap', {}).get('speedup')}x",
-                      flush=True)
-        except Exception as e:
-            print(f"# WARNING: kernels bench phase failed "
-                  f"({type(e).__name__}: {str(e)[:200]})", flush=True)
-
     if trace_path:
         # eager 3-call path demo: genuine fwd/bwd/step spans plus an eager
         # device collective (comm/all_reduce span with real bytes + bandwidth)
@@ -1243,8 +1054,6 @@ def run_bench():
         # cross-round tooling compares `value` ONLY within one backend+chip
         **backend_stamp(on_tpu),
     }
-    if kernels_line is not None:
-        line["kernels"] = kernels_line
     # DS_TPU_BENCH_BASELINE=<prior BENCH_rXX.json or raw line>: attach the
     # round-over-round ratio — or the refusal — computed by the same rules
     baseline_path = os.environ.get("DS_TPU_BENCH_BASELINE")

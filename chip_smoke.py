@@ -211,7 +211,7 @@ def server_phase(smoke: Smoke):
     check_step("server prefill step", (s["prompt"], seq_bucket, "greedy"), n_put,
                "paged_attn_q_tiled")
     check_step("server decode step", ("decode", seq_bucket, horizons[0], False), n_decode,
-               "paged_attn_per_token")
+               "paged_attn_kv_split")
 
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, s["vocab"], size=s["prompt"], dtype=np.int32) for _ in range(4)]
@@ -355,7 +355,6 @@ def main(argv=None) -> int:
 
     import jax
 
-    from deepspeed_tpu.autotuning.kernel_config import default_config_path
     from deepspeed_tpu.monitor.metrics import peak_flops_per_chip, peak_hbm_bw_per_chip
     from deepspeed_tpu.utils.compile_cache import enable_compile_cache
     from deepspeed_tpu.utils.logging import logger
@@ -380,8 +379,6 @@ def main(argv=None) -> int:
         else "checkout default"
     entries = len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0
     smoke.say(f"compile cache: {cache_dir} ({placed_by}, {entries} entries at start)")
-    tiles = default_config_path()  # tile winners, keyed "<device_kind>|n<devices>"
-    smoke.say(f"kernel config: {tiles} ({'PRESENT: tuned tiles may apply' if os.path.exists(tiles) else 'absent: heuristic tiles'})")
     peak_flops, peak_bw = peak_flops_per_chip(dev.device_kind), peak_hbm_bw_per_chip(dev.device_kind)
     if smoke.rehearsal:
         smoke.say(f"peaks for {dev.device_kind!r}: {peak_flops}, {peak_bw} (none off the chip)")

@@ -1,5 +1,3 @@
 from .autotuner import Autotuner, TuningResult
-from .kernel_config import (KernelAutotuner, KernelConfigRegistry, get_kernel_registry,
-                            set_kernel_config_path, shape_bucket, topology_key, tuned_tile)
 from .scheduler import Experiment, ResourceManager
 from .tuner import BaseTuner, GridSearchTuner, ModelBasedTuner, RandomTuner

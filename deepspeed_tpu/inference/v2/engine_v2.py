@@ -492,7 +492,8 @@ class InferenceEngineV2:
         return {"kv_steps": steps, "kv_live": live}
 
     def _kernel_of(self, T: int, S: int) -> str:
-        """``<kernel>:<q_tile or kv_splits>:<rule>`` of the paged-attention
+        """``<kernel>:<n>:<rule>`` (``n``: the tiled kernel's ``q_tile``, or the
+        KV blocks a grid step of the decode kernel takes) of the paged-attention
         call inside the compiled program of ``T`` tokens and ``S`` rows,
         looked up once per shape in the table ``paged_attention`` fills while
         ``jit`` traces it (so only after the program's first call). Empty
@@ -504,7 +505,7 @@ class InferenceEngineV2:
             if choice is None:
                 return ""
             label = self._kernel_labels[(T, S)] = "%s:%d:%s" % (
-                choice["kernel"], max(choice["q_tile"], choice["kv_splits"]), choice["rule"])
+                choice["kernel"], max(choice["q_tile"], choice["blocks_per_step"]), choice["rule"])
         return label
 
     # ------------------------------------------------------------------

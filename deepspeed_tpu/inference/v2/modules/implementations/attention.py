@@ -6,7 +6,7 @@ Two real implementations behind one interface:
 - ``dense_blocked_attention``: the gather-based jnp oracle — runs anywhere,
   the numerics reference.
 - ``paged_pallas_attention``: the Pallas LUT-prefetch paged kernel — the TPU
-  serving path; ``implementation_config={'interpret': True}`` runs the same
+  serving path; ``implementation_config={'interpret': True}`` runs the decode
   kernel through the Pallas interpreter so CPU CI can cover the kernel's
   program (not its Mosaic lowering).
 """
@@ -75,8 +75,14 @@ class PallasPagedAttention(DSSelfAttentionBase):
             import jax.numpy as jnp
 
             al = _alibi(cfg)
+            # the interpreter evaluates the decode kernel's body on the CPU: no
+            # device kernel of that name runs, and the span says so. Its
+            # ``kv_steps`` count every table column (``decode_kv_counts``
+            # knows only the device kernel's work list): tests/perfbench
+            # holds the CPU twins to that (PERF.md section 7, PR 29)
             _note_choice(q.shape[0], tables_l.shape[0], tables_l.shape[1],
-                         "paged_attn_per_token", 1, 1, "interpret")
+                         {"kernel": "paged_attn_interpreted", "q_tile": 1, "blocks_per_step": 1,
+                          "rule": "interpret"})
             return _pallas_paged(q, k_flat, v_flat, tables_l, seq_idx.astype(jnp.int32),
                                  pos.astype(jnp.int32), block_size=cfg.block_size,
                                  interpret=True, window=cfg.sliding_window,

@@ -6,7 +6,7 @@ the chip is running *as fast as the hardware allows*. This module joins what
 XLA says a compiled executable must do (``compiled.cost_analysis()`` FLOPs
 and bytes accessed — the exact mechanism ``profiling/flops_profiler.py``
 uses point-wise) with what we measure it doing (the engine step boundary,
-the serving forward wrappers, ``KernelAutotuner.measure``), per shape
+the serving forward wrappers), per shape
 bucket — the same bucket labels the PR 14 recompile sentinel tracks — and
 renders a per-bucket verdict:
 

@@ -63,19 +63,11 @@ def _default_tile():
     return 1024 if any(t in kind for t in _LARGE_TILE_KINDS) else 512
 
 
-def _resolve_tiles(S: int, d: int, block_q=None, block_k=None):
-    """Tile resolution order: explicit caller value > kernel-config registry
-    (``autotuning/kernel_config.py``, per chip generation/topology/shape
-    bucket) > the generation heuristic ``_default_tile``. The VMEM fit and
-    divisor snap downstream still apply to tuned values — the registry can
-    propose tiles, never break the kernel's preconditions."""
-    from ...autotuning.kernel_config import shape_bucket, tuned_tile
-
+def _resolve_tiles(block_q=None, block_k=None):
+    """The caller's explicit tile, else the generation's ``_default_tile``.
+    The VMEM fit and divisor snap downstream apply to both."""
     dflt = _default_tile()
-    bucket = shape_bucket(S=S, d=d)
-    bq = block_q if block_q is not None else tuned_tile("flash_attention", bucket, "block_q", dflt)
-    bk = block_k if block_k is not None else tuned_tile("flash_attention", bucket, "block_k", dflt)
-    return int(bq), int(bk)
+    return int(dflt if block_q is None else block_q), int(dflt if block_k is None else block_k)
 
 
 def _shapes_supported(q):
@@ -148,7 +140,7 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = None, block_k: 
         # others use the interleaved table — LOUD jnp path
         return _reference_fallback(q, k, v, causal, window, alibi,
                                    f"alibi with non-power-of-2 head count {q.shape[2]}")
-    block_q, block_k = _resolve_tiles(q.shape[1], q.shape[3], block_q, block_k)
+    block_q, block_k = _resolve_tiles(block_q, block_k)
     if _use_pallas() and not _shapes_supported(q):
         return _reference_fallback(q, k, v, causal, window, alibi,
                                    f"unsupported shape {q.shape} (S must be a multiple of 128, "

@@ -56,17 +56,13 @@ def _fit_block(dim: int, preferred: int) -> int:
 
 
 def _resolve_gmm_tiles(K: int, N: int, block_k=None, block_n=None, itemsize: int = 2):
-    """K/N tile resolution: explicit caller value > kernel-config registry
-    (per chip/topology/shape bucket) > the whole of K and N, K halved until
-    the weight tile fits ``WEIGHT_TILE_BYTES``. ``block_t`` is NOT tunable
-    here — it is a dispatcher contract (block_expert's shape)."""
-    from ...autotuning.kernel_config import shape_bucket, tuned_tile
-
-    bucket = shape_bucket(K=K, N=N)
-    bk = block_k if block_k is not None else tuned_tile("grouped_matmul", bucket, "block_k", None)
-    bn = block_n if block_n is not None else tuned_tile("grouped_matmul", bucket, "block_n", None)
+    """K/N tiles: the caller's explicit value, else the whole of K and N, K
+    halved until the weight tile fits ``WEIGHT_TILE_BYTES``. ``block_t`` is
+    NOT chosen here — it is a dispatcher contract (block_expert's shape)."""
+    bn = block_n
     if bn is None:
         bn = _fit_block(N, max(WEIGHT_TILE_BYTES // (itemsize * 128), 128))
+    bk = block_k
     if bk is None:
         bk = _fit_block(K, max(WEIGHT_TILE_BYTES // (itemsize * int(bn)), 128))
     return int(bk), int(bn)
@@ -74,8 +70,7 @@ def _resolve_gmm_tiles(K: int, N: int, block_k=None, block_n=None, itemsize: int
 
 def gmm_reference(lhs, rhs, block_expert, block_t=128):
     """jnp gather oracle for :func:`gmm` — the numerics reference the kernel
-    is tested against (and the always-available fallback contract the
-    ``tools/check_kernel_configs.py`` gate demands of every tuned kernel)."""
+    is tested against."""
     expert_per_row = jnp.repeat(block_expert, block_t)
     out = jnp.einsum("tk,tkn->tn", lhs.astype(jnp.float32),
                      rhs[expert_per_row].astype(jnp.float32))

@@ -2,12 +2,13 @@
 kernel.
 
 Analog of the reference ``v2/kernels/ragged_ops/blocked_flash`` (CUDA flash
-attention adapted to paged KV block tables, SURVEY.md §2.3). TPU design: a
-Pallas kernel on a ``(tokens, kv_blocks)`` grid using
-``PrefetchScalarGridSpec`` so the K/V BlockSpec index maps read the *block
-table* (scalar-prefetched) — the DMA engine then streams exactly the KV
-blocks each token's sequence owns, straight from HBM, while the online
-softmax accumulates in VMEM scratch across the inner grid dimension.
+attention adapted to paged KV block tables, SURVEY.md §2.3). TPU design: two
+Pallas kernels on ``PrefetchScalarGridSpec`` grids whose K/V BlockSpec index
+maps read the *block table* (scalar-prefetched) — the DMA engine then
+streams exactly the KV blocks a sequence owns, straight from HBM, while the
+online softmax accumulates in VMEM scratch: ``paged_attn_q_tiled`` for
+batches with multi-token chunks, ``paged_attn_kv_split`` for everything
+else; :func:`choose_kernel` picks between them from the static shapes.
 
 Token-level formulation: query token ``t`` belongs to ``seq_idx[t]`` at
 absolute position ``pos[t]`` and attends all cached positions ``<= pos[t]``.
@@ -21,7 +22,6 @@ reference, tests/unit/inference/v2/kernels).
 
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -50,12 +50,10 @@ def _contiguity_ok(seq_idx, S: int, pos=None) -> bool:
 
 
 # Which kernel each traced shape took, and the rule that decided:
-# ``(T, S, max_blocks) -> {"kernel", "q_tile", "kv_splits", "rule",
-# "blocks_per_step"}`` (the last: KV blocks a grid step of the decode kernel
-# takes, 1 for the other grids).
-# ``paged_attention`` resolves both while ``jit`` traces the program, from
-# static shapes, so filling this costs nothing at run time; the serving
-# engine reads it once per compiled program and puts it on the step's span.
+# ``(T, S, max_blocks) -> choose_kernel's record``. ``paged_attention``
+# chooses while ``jit`` traces the program, from static shapes, so filling
+# this costs nothing at run time; the serving engine reads it once per
+# compiled program and puts it on the step's span.
 KERNEL_CHOICES = {}
 
 
@@ -65,144 +63,73 @@ def kernel_choice(T: int, S: int, max_blocks: int):
     return KERNEL_CHOICES.get((int(T), int(S), int(max_blocks)))
 
 
-def _note_choice(T, S, max_blocks, kernel, q_tile, kv_splits, rule, blocks_per_step: int = 1):
-    KERNEL_CHOICES[(int(T), int(S), int(max_blocks))] = {
-        "kernel": kernel, "q_tile": int(q_tile), "kv_splits": int(kv_splits), "rule": rule,
-        "blocks_per_step": int(blocks_per_step)}
+def _note_choice(T, S, max_blocks, choice):
+    KERNEL_CHOICES[(int(T), int(S), int(max_blocks))] = choice
 
 
-# The largest q-tile the shape heuristic gives: the MXU then sees ``g * 128``
-# query rows a kv head, and a KV block is fetched and cut into heads once per
-# 128 query tokens (256 measured 2-5% faster at twice the VMEM: PERF.md, PR 25).
+# The largest q-tile: the MXU then sees ``g * 128`` query rows a kv head, and
+# a KV block is fetched and cut into heads once per 128 query tokens (256
+# measured 2-5% faster at twice the VMEM: PERF.md, PR 25).
 _LONG_ROW_TILE = 128
 
 
-def _resolve_q_tile(T: int, S: int, seq_idx=None) -> int:
-    """Resolve the q-tile through the kernel-config registry
-    (``autotuning/kernel_config.py``), falling back to the shape heuristic:
-    tile only batches with real multi-token chunks (T well beyond the seq
-    count — pure-decode batches have one token per sequence, where tiling
-    pays q-DMA for masked rows and buys no KV-stream amortization).
+def choose_kernel(T: int, S: int, max_blocks: int, nq: int, block_rows: int, d: int, itemsize: int,
+                  seq_idx=None, pos=None) -> dict:
+    """Which kernel serves a batch of ``T`` tokens over ``S`` table rows of
+    ``max_blocks`` columns, and with which tile: ``{"kernel", "q_tile",
+    "blocks_per_step", "rule"}``. It follows from the program's static shapes
+    (and the backend) alone; no argument, file or environment variable
+    changes it. ``block_rows`` is a KV block's rows, ``block_size * nkv``, and
+    ``itemsize`` the pool's; ``seq_idx``/``pos`` only let a caller with
+    CONCRETE arrays have the tiled grid's layout contract checked. In order:
 
-    The tiled grid requires same-sequence tokens to be CONTIGUOUS in the
-    batch (the SplitFuse/ragged layout invariant — ``ragged_wrapper.finalize``
-    packs per-sequence chunks back to back). When ``seq_idx`` is concrete the
-    contract is verified here and tiling is demoted to per-token on
-    violation; traced callers (the jitted ragged step) are covered by the
-    layout invariant itself.
-    """
-    return _q_tile_choice(T, S, seq_idx)[0]
-
-
-def _heuristic_q_tile(T: int, S: int):
-    """``(q_tile, rule)`` from the program's static shapes alone. ``T / S``
-    is the mean tokens a row of the bucket, and a step's chunks are longer
-    than its mean row because most rows beside them are one-token decode
-    rows, so the tile is the power of two at or under ``2 T / S`` (and
-    ``T``), between 8 and 128. A long-prompt step (2,048 tokens over at most
-    8 rows) takes the large tile (``long_rows``). A step with many rows for
-    its tokens (a 512-token SplitFuse ``put`` over 32 rows, a linear
-    speculative verify of k+1 tokens a row) takes a smaller one
-    (``short_rows``), because the grid has a tile per row beyond the chunks'
-    own and each costs a q and an output tile of DMA and its share of grid
-    steps. Fewer than two tokens a row (or a tiny batch) is decode and leaves
-    the tiled grid alone."""
+    - off the TPU (``off_tpu``), or heads the kernels do not tile (``nq < 8``,
+      ``d % 128``: ``unsupported_shape``): the gather reference;
+    - ``T >= 64`` with at least two tokens a row: ``paged_attn_q_tiled``.
+      ``T / S`` is the mean tokens a row of the bucket, and a step's chunks
+      are longer than its mean row because most rows beside them are
+      one-token decode rows, so the tile is the power of two at or under
+      ``2 T / S`` (and ``T``), between 8 and 128. A long-prompt step (2,048
+      tokens over at most 8 rows) takes the large tile
+      (``heuristic:long_rows``). A step with many rows for its tokens (a
+      512-token SplitFuse ``put`` over 32 rows, a linear speculative verify of
+      k+1 tokens a row) takes a smaller one (``heuristic:short_rows``),
+      because the grid has a tile per row beyond the chunks' own and each
+      costs a q and an output tile of DMA and its share of grid steps;
+    - such a batch whose concrete ``seq_idx`` breaks the layout contract
+      (:func:`_contiguity_ok`: the tiled grid would overflow its static tile
+      bound and scatter tokens into the wrong tiles): ``contiguity_demoted``
+      to the decode kernel;
+    - everything else: ``paged_attn_kv_split``, whose work list takes
+      ``seq_idx`` and ``pos`` a TOKEN, at :func:`_decode_blocks_per_step` KV
+      blocks a grid step. The rule says which kind of batch it was: a table
+      under 8 columns (``heuristic:short_table``), more than two tokens a
+      row in a batch under 64 tokens (``heuristic:multi_token``: a chunk's
+      tail beside decode rows), else decode (``heuristic:long_table``)."""
+    choice = {"kernel": "paged_attention_reference", "q_tile": 1, "blocks_per_step": 1}
+    if jax.default_backend() != "tpu":
+        return {**choice, "rule": "off_tpu"}
+    if nq < 8 or d % 128 != 0:
+        return {**choice, "rule": "unsupported_shape"}
     per_row2 = min(T, 2 * T // max(S, 1))
-    if T < 64 or per_row2 < 4:
-        return 1, "heuristic:decode_shaped"
-    qt = max(8, min(_LONG_ROW_TILE, 1 << (per_row2.bit_length() - 1)))
-    return qt, "heuristic:long_rows" if qt == _LONG_ROW_TILE else "heuristic:short_rows"
-
-
-def _q_tile_choice(T: int, S: int, seq_idx=None, pos=None):
-    """``(q_tile, rule)``: :func:`_resolve_q_tile` with the rule that decided
-    (``env``, ``tuned``, ``heuristic:long_rows``, ``heuristic:short_rows``,
-    ``heuristic:decode_shaped`` or ``contiguity_demoted``)."""
-    from ...autotuning.kernel_config import shape_bucket, tuned_tile
-
-    # DS_TPU_PAGED_Q_TILE: operator override — =1 pins the per-token grid
-    # without authoring a kernel_config.json.
-    env = os.environ.get("DS_TPU_PAGED_Q_TILE")
-    if env:
-        try:
-            qt = max(1, int(env))
-        except ValueError:
-            qt = 1
-        if qt > 1 and not _contiguity_ok(seq_idx, S, pos):
-            return 1, "contiguity_demoted"
-        return qt, "env"
-
-    qt, rule = _heuristic_q_tile(T, S)
-    # lookup order: exact (T, S) bucket, then — for prefill-ish shapes
-    # ONLY — the T-only bucket the sweep records (S here is block-table
-    # CAPACITY, which varies per deployment, so T generalizes over it). A
-    # pure-decode shape (one token per sequence) must never inherit a
-    # prefill-tuned tile from the T-only key: every tile would carry qt-1
-    # masked slots for zero KV amortization.
-    tuned = tuned_tile("paged_attention", shape_bucket(T=T, S=S), "q_tile", None)
-    if tuned is None and qt > 1:
-        tuned = tuned_tile("paged_attention", shape_bucket(T=T), "q_tile", None)
-    if tuned is not None:
-        qt, rule = max(int(tuned), 1), "tuned"
-    if qt > 1 and not _contiguity_ok(seq_idx, S, pos):
-        return 1, "contiguity_demoted"
-    return qt, rule
-
-
-def _resolve_kv_splits(T: int, S: int, max_blocks: int, q_tile: int = 1) -> int:
-    """Which grid a decode-shaped batch takes: 1 keeps the per-token grid
-    (``rows x table columns``), anything above takes the decode kernel
-    ``paged_attn_kv_split``. That kernel's grid is the work list of the
-    batch's live (row, block) pairs and has no split count any more (a row is
-    one softmax chain; a v5e has one TensorCore, so partial chains bought
-    nothing but their merge), so a value above 1 says "the decode kernel" and
-    nothing else: the name and the numbers are kept for the registry entries,
-    the sweep (``KernelAutotuner.tune_paged_decode``: 1 against the rest) and
-    the callers that exist.
-
-    Resolution: a tiled prefill (``q_tile > 1``), a table under 8 columns and
-    a batch with real multi-token chunks stay off the decode kernel whatever
-    else is set; then ``DS_TPU_PAGED_KV_SPLITS`` (``1`` pins the per-token
-    grid), then the kernel-config registry (exact ``(B, T)`` bucket, then the
-    ``B``-only bucket the decode sweep records; B = block-table capacity),
-    then the heuristic, which takes the decode kernel."""
-    return _kv_splits_choice(T, S, max_blocks, q_tile)[0]
-
-
-def _kv_splits_choice(T: int, S: int, max_blocks: int, q_tile: int = 1):
-    """``(kv_splits, rule)``: :func:`_resolve_kv_splits` with the rule that
-    decided (``heuristic:tiled``, ``heuristic:short_table``,
-    ``heuristic:multi_token``, ``env``, ``tuned`` or ``heuristic:long_table``)."""
-    from ...autotuning.kernel_config import shape_bucket, tuned_tile
-
-    # tiled prefill rows have their own grid; a short table keeps the
-    # per-token grid (its dead columns are few); and a batch with real
-    # multi-token chunks (T well past the seq count — e.g. a non-contiguous
-    # prefill demoted to the per-token grid) is not a decode batch: the work
-    # list gives every token a row of its own
-    if q_tile > 1:
-        return 1, "heuristic:tiled"
-    if max_blocks < 8:
-        return 1, "heuristic:short_table"
-    if T > 2 * max(S, 1):
-        return 1, "heuristic:multi_token"
-    env = os.environ.get("DS_TPU_PAGED_KV_SPLITS")
-    if env:
-        try:
-            ks = max(1, int(env))
-        except ValueError:
-            ks = 1
-        return min(ks, max_blocks), "env"
-    tuned = tuned_tile("paged_attention", shape_bucket(B=max_blocks, T=T), "kv_splits", None)
-    if tuned is None:
-        tuned = tuned_tile("paged_attention", shape_bucket(B=max_blocks), "kv_splits", None)
-    if tuned is not None:
-        return max(1, min(int(tuned), max_blocks)), "tuned"
-    return max(1, min(8, max_blocks // 4)), "heuristic:long_table"
+    if T >= 64 and per_row2 >= 4:
+        if _contiguity_ok(seq_idx, S, pos):
+            qt = max(8, min(_LONG_ROW_TILE, 1 << (per_row2.bit_length() - 1)))
+            return {"kernel": "paged_attn_q_tiled", "q_tile": qt, "blocks_per_step": 1,
+                    "rule": "heuristic:long_rows" if qt == _LONG_ROW_TILE else "heuristic:short_rows"}
+        rule = "contiguity_demoted"
+    elif max_blocks < 8:
+        rule = "heuristic:short_table"
+    elif T > 2 * max(S, 1):
+        rule = "heuristic:multi_token"
+    else:
+        rule = "heuristic:long_table"
+    return {"kernel": "paged_attn_kv_split", "q_tile": 1, "rule": rule,
+            "blocks_per_step": _decode_blocks_per_step(block_rows, d, itemsize)}
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: int, window=None,
-                    alibi=None, k_scale=None, v_scale=None, q_tile=None, kv_splits=None):
+                    alibi=None, k_scale=None, v_scale=None):
     """q: [T, nq, d]; k_pool/v_pool: [pool_len, nkv, d] (one layer,
     pool_len = num_blocks*block_size, may include one trailing scratch slot);
     block_tables: [S, max_blocks]; seq_idx/pos: [T].
@@ -213,35 +140,23 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: i
     scales [nkv, pool_len] hold one fp32 absmax/127 factor per (kv-head,
     slot); dequant happens at the kernel's tile read, so only int8 bytes
     stream from HBM.
-    ``q_tile``: tokens per q-tile grid row (None = kernel-config registry,
-    then the shape heuristic: 128 for long rows, the power of two under
-    ``2 T / S`` for many short ones, 1 for decode). q_tile > 1 packs contiguous
-    same-sequence tokens into one grid row so each KV block streams from HBM
-    once per TILE instead of once per token, and each kv head's dot feeds the
-    MXU ``g * q_tile`` rows — the prefill-chunk amortization win.
-    ``kv_splits``: above 1, a decode-shaped batch takes the decode kernel
-    (``paged_attn_kv_split``: one grid step per live (row, KV block) pair,
-    from a scalar-prefetched work list) instead of the per-token grid; the
-    number itself shapes nothing (:func:`_resolve_kv_splits`). None =
-    registry, then heuristic; ignored whenever the q-tiled grid is taken.
+    The kernel and its tile are :func:`choose_kernel`'s, from the shapes.
     Returns [T, nq, d]."""
     T, nq, d = q.shape
     nkv = k_pool.shape[1]
-    S = block_tables.shape[0]
+    S, max_blocks = block_tables.shape
     if window is not None:
         window = int(window)
-    max_blocks = block_tables.shape[1]
-    if jax.default_backend() != "tpu" or nq < 8 or d % 128 != 0:
-        on_tpu = jax.default_backend() == "tpu"
-        if on_tpu:
+    choice = choose_kernel(T, S, max_blocks, nq, block_size * nkv, d, k_pool.dtype.itemsize, seq_idx, pos)
+    _note_choice(T, S, max_blocks, choice)
+    if choice["kernel"] == "paged_attention_reference":
+        if choice["rule"] == "unsupported_shape":
             # off-TPU the oracle is the design; ON TPU a shape miss silently
             # costing a full context gather per layer per step must be loud
             from ...utils.logging import warning_once
 
             warning_once(f"pallas paged attention: unsupported shape (nq={nq}, d={d}; needs "
                          "nq>=8, d%128==0) — serving through the DENSE gather fallback")
-        _note_choice(T, S, max_blocks, "paged_attention_reference", 1, 1,
-                     "unsupported_shape" if on_tpu else "off_tpu")
         return paged_attention_reference(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size,
                                          window=window, alibi=alibi, k_scale=k_scale, v_scale=v_scale)
     if k_scale is not None and block_size % 128 != 0:
@@ -249,45 +164,16 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: i
         # lowering rejects it otherwise, with a message that names neither
         # the option nor the remedy
         raise ValueError(f"int8 KV on TPU needs kv_block_size % 128 == 0, got {block_size}")
-    if q_tile is None:
-        q_tile, q_rule = _q_tile_choice(T, S, seq_idx, pos)
-    elif q_tile > 1 and not _contiguity_ok(seq_idx, S, pos):
-        # an explicit q_tile must not bypass the layout contract: a
-        # non-contiguous batch would overflow the tiled grid's static tile
-        # bound and silently scatter tokens into the wrong tiles
-        from ...utils.logging import warning_once
-
-        warning_once(f"paged attention: q_tile={q_tile} requested but seq_idx is not "
-                     "sequence-contiguous — demoting to the per-token grid")
-        q_tile, q_rule = 1, "contiguity_demoted"
-    else:
-        q_rule = "explicit"
     alibi_t = tuple(np.asarray(alibi).tolist()) if alibi is not None else None
-    if kv_splits is None:
-        kv_splits, kv_rule = _kv_splits_choice(T, S, max_blocks, q_tile=int(q_tile))
-    else:
-        kv_rule = "explicit"
-    kv_splits = max(1, min(int(kv_splits), max_blocks))
-    # the same order as _pallas_paged: a tile wins, then the decode kernel,
-    # then the per-token grid; a demotion stays the reason whatever else says
-    if q_tile > 1:
-        _note_choice(T, S, max_blocks, "paged_attn_q_tiled", q_tile, 1, q_rule)
-    elif kv_splits > 1:
-        _note_choice(T, S, max_blocks, "paged_attn_kv_split", 1, kv_splits, kv_rule,
-                     _decode_blocks_per_step(block_size * nkv, d, k_pool.dtype.itemsize))
-    else:
-        _note_choice(T, S, max_blocks, "paged_attn_per_token", 1, 1,
-                     q_rule if q_rule == "contiguity_demoted" else kv_rule)
     # ONE grid, chosen above from the shape; a grid the chip refuses RAISES
     # (here at trace/lowering, or at the enclosing jit's compile) — it is
-    # never downgraded to another grid or to the gather below, which would
-    # turn a broken kernel into a slow server instead of an error. The gather
-    # is the tests' reference, the off-TPU path and the (warned) path for the
-    # shapes the kernel does not support, nothing else.
+    # never downgraded to another grid or to the gather, which would turn a
+    # broken kernel into a slow server instead of an error. The gather is the
+    # tests' reference, the off-TPU path and the (warned) path for the shapes
+    # the kernels do not support, nothing else.
     return _pallas_paged(q, k_pool, v_pool, block_tables, seq_idx.astype(jnp.int32),
                          pos.astype(jnp.int32), block_size=block_size, window=window,
-                         alibi=alibi_t, k_scale=k_scale, v_scale=v_scale, q_tile=int(q_tile),
-                         kv_splits=kv_splits)
+                         alibi=alibi_t, k_scale=k_scale, v_scale=v_scale, q_tile=choice["q_tile"])
 
 
 def paged_attention_reference(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: int,
@@ -340,7 +226,7 @@ def _slopes_rows(alibi, reps):
     Python floats: each ``jnp.full`` embeds a SCALAR constant, which Pallas
     accepts — a closure-captured ``jnp.asarray(tuple)`` array is rejected at
     kernel trace ("captures constants ... pass them as inputs"), which
-    silently broke the per-token alibi path before this helper."""
+    silently broke an alibi path before this helper."""
     return jnp.concatenate([jnp.full((reps, 1), float(a), jnp.float32) for a in alibi], axis=0)
 
 
@@ -356,148 +242,39 @@ def _slopes_tok_major(alibi_g, rows):
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("block_size", "interpret", "window", "alibi",
-                                             "q_tile", "kv_splits"))
+@functools.partial(jax.jit, static_argnames=("block_size", "interpret", "window", "alibi", "q_tile"))
 def _pallas_paged(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: int, interpret: bool = False,
-                  window=None, alibi=None, k_scale=None, v_scale=None, q_tile: int = 1,
-                  kv_splits: int = 1):
+                  window=None, alibi=None, k_scale=None, v_scale=None, q_tile: int = 1):
+    """The kernel the caller names, with no choice of its own: ``q_tile``
+    above 1 runs ``paged_attn_q_tiled`` at that tile, 1 (one query token a
+    grid row) the decode kernel ``paged_attn_kv_split``. ``paged_attention``
+    passes :func:`choose_kernel`'s tile; the interpret-mode parity tests
+    name each body themselves."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    T, nq, d = q.shape
     nkv = k_pool.shape[1]
-    g = nq // nkv
-    S, max_blocks = block_tables.shape
+    d = q.shape[2]
     # view the pool as whole blocks; drop any trailing scratch remainder
     n_pool_blocks = k_pool.shape[0] // block_size
     n_live = n_pool_blocks * block_size
-    k4 = k_pool[:n_live].reshape(n_pool_blocks, block_size, nkv, d)
-    v4 = v_pool[:n_live].reshape(n_pool_blocks, block_size, nkv, d)
     quant = k_scale is not None
-    if quant:
-        # scales stay [nkv, cols]: sublane = nkv, lane = block_size — the
-        # layout the scatter side maintains natively, no per-call transpose
-        ks2 = k_scale[:, :n_live]
-        vs2 = v_scale[:, :n_live]
-    scale = 1.0 / math.sqrt(d)
+    # scales stay [nkv, cols]: sublane = nkv, lane = block_size — the layout
+    # the scatter side maintains natively, no per-call transpose
+    ks2, vs2 = (k_scale[:, :n_live], v_scale[:, :n_live]) if quant else (None, None)
 
-    if q_tile and q_tile > 1:
-        return _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos,
-                              ks2 if quant else None, vs2 if quant else None,
-                              block_size=block_size, q_tile=int(q_tile), window=window,
+    if q_tile > 1:
+        as_blocks = lambda pool: pool[:n_live].reshape(n_pool_blocks, block_size, nkv, d)
+        return _paged_q_tiled(pl, pltpu, q, as_blocks(k_pool), as_blocks(v_pool), block_tables, seq_idx, pos,
+                              ks2, vs2, block_size=block_size, q_tile=q_tile, window=window,
                               alibi=alibi, interpret=interpret)
-    if kv_splits and kv_splits > 1:
-        # a block as one [block * nkv, d] matrix, row t * nkv + n: the pool's
-        # own bytes; the int8 scales [nkv, cols] are laid out to match
-        as_rows = lambda pool: pool[:n_live].reshape(n_pool_blocks, block_size * nkv, d)
-        by_col = lambda sc: jnp.transpose(sc).reshape(n_pool_blocks, 1, block_size * nkv)
-        return _paged_kv_split(pl, pltpu, q, as_rows(k_pool), as_rows(v_pool), block_tables, seq_idx,
-                               pos, by_col(ks2) if quant else None, by_col(vs2) if quant else None,
-                               block_size=block_size, window=window, alibi=alibi,
-                               interpret=interpret)
-
-    grid = (T, max_blocks)
-
-    def q_map(t, j, seq_ref, pos_ref, bt_ref):
-        return (t, 0, 0)
-
-    def kv_map(t, j, seq_ref, pos_ref, bt_ref):
-        # clamp j into the token's live range: the index map runs (and its
-        # DMA issues) even for grid steps the kernel's pl.when skips, so
-        # out-of-range columns are remapped to an in-range block — Mosaic
-        # sees a repeated index and skips the refetch instead of streaming
-        # blocks the online softmax never reads
-        hi = pos_ref[t] // block_size
-        jj = jnp.minimum(j, hi)
-        if window is not None:
-            lo = jnp.maximum(pos_ref[t] - (window - 1), 0) // block_size
-            jj = jnp.maximum(jj, jnp.minimum(lo, hi))
-        return (bt_ref[seq_ref[t], jj], 0, 0, 0)
-
-    def kernel(seq_ref, pos_ref, bt_ref, q_ref, k_ref, v_ref, *rest):
-        if quant:
-            ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
-        else:
-            o_ref, acc_ref, m_ref, l_ref = rest
-        t = pl.program_id(0)
-        j = pl.program_id(1)
-        my_pos = pos_ref[t]
-
-        @pl.when(j == 0)
-        def _init():
-            acc_ref[:] = jnp.zeros_like(acc_ref)
-            m_ref[:] = jnp.full_like(m_ref, -1e30)
-            l_ref[:] = jnp.zeros_like(l_ref)
-
-        in_window = (j * block_size <= my_pos) if window is None else jnp.logical_and(
-            j * block_size <= my_pos, (j + 1) * block_size - 1 > my_pos - window)
-
-        @pl.when(in_window)
-        def _compute():
-            qb = q_ref[0].astype(jnp.float32) * scale  # [nq, d]
-            kb = k_ref[0].astype(jnp.float32)  # [bs, nkv, d]
-            vb = v_ref[0].astype(jnp.float32)
-            if quant:  # dequant at the VMEM tile — HBM only streamed int8
-                kb = kb * ks_ref[...].T[:, :, None]  # [bs, nkv, 1]
-                vb = vb * vs_ref[...].T[:, :, None]
-            # per-kv-head 2-D MXU dots (Mosaic has no mismatched-batch dots);
-            # nkv is small and static so the loop unrolls at trace time
-            s_heads = []
-            for n in range(nkv):
-                s_heads.append(jax.lax.dot(qb[n * g:(n + 1) * g], kb[:, n, :].T))  # [g, bs]
-            s = jnp.concatenate(s_heads, axis=0)  # [nq, bs]
-            kpos = j * block_size + jax.lax.broadcasted_iota(jnp.int32, (nq, block_size), 1)
-            if alibi is not None:
-                s = s + _slopes_rows(alibi, 1) * (kpos - my_pos).astype(jnp.float32)
-            vis = kpos <= my_pos
-            if window is not None:
-                vis = jnp.logical_and(vis, my_pos - kpos < window)
-            s = jnp.where(vis, s, -1e30)
-            m_prev = m_ref[:]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)  # [nq, bs]
-            alpha = jnp.exp(m_prev - m_new)
-            l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            ctx_heads = []
-            for n in range(nkv):
-                ctx_heads.append(jax.lax.dot(p[n * g:(n + 1) * g], vb[:, n, :]))  # [g, d]
-            ctx = jnp.concatenate(ctx_heads, axis=0)  # [nq, d]
-            acc_ref[:] = acc_ref[:] * alpha + ctx
-            m_ref[:] = m_new
-
-        @pl.when(j == max_blocks - 1)
-        def _finalize():
-            o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)).astype(o_ref.dtype)
-
-    def scale_map(t, j, seq_ref, pos_ref, bt_ref):
-        blk = kv_map(t, j, seq_ref, pos_ref, bt_ref)[0]
-        return (0, blk)
-
-    in_specs = [
-        pl.BlockSpec((1, nq, d), q_map),
-        pl.BlockSpec((1, block_size, nkv, d), kv_map),
-        pl.BlockSpec((1, block_size, nkv, d), kv_map),
-    ]
-    operands = [q, k4, v4]
-    if quant:
-        in_specs += [pl.BlockSpec((nkv, block_size), scale_map),
-                     pl.BlockSpec((nkv, block_size), scale_map)]
-        operands += [ks2, vs2]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, nq, d), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((nq, d), jnp.float32),
-            pltpu.VMEM((nq, 1), jnp.float32),
-            pltpu.VMEM((nq, 1), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(kernel, grid_spec=grid_spec, out_shape=jax.ShapeDtypeStruct((T, nq, d), q.dtype),
-                          interpret=interpret, name="paged_attn_per_token")(
-                              seq_idx, pos, block_tables, *operands)
+    # a block as one [block * nkv, d] matrix, row t * nkv + n: the pool's own
+    # bytes; the int8 scales [nkv, cols] are laid out to match
+    as_rows = lambda pool: pool[:n_live].reshape(n_pool_blocks, block_size * nkv, d)
+    by_col = lambda sc: jnp.transpose(sc).reshape(n_pool_blocks, 1, block_size * nkv)
+    return _paged_kv_split(pl, pltpu, q, as_rows(k_pool), as_rows(v_pool), block_tables, seq_idx,
+                           pos, by_col(ks2) if quant else None, by_col(vs2) if quant else None,
+                           block_size=block_size, window=window, alibi=alibi, interpret=interpret)
 
 
 # tokens of a tile's rows that the short pass of ``_paged_q_tiled`` covers: a
@@ -560,7 +337,7 @@ def _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
     masking and the softmax state are float32); int8 KV dequantises at the
     tile in float32. ``m``, ``l`` and the positions are kept replicated
     across the 128 lanes, so that with 128-token blocks no step broadcasts a
-    column. Alibi and the sliding window mask as on the per-token grid.
+    column. Alibi and the sliding window mask as in the decode kernel.
     """
     T, nq, d = q.shape
     nkv = k4.shape[2]
@@ -622,8 +399,9 @@ def _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
         return (i, 0, 0)
 
     def kv_map(i, j, seq_ref, max_ref, lo_ref, cnt_ref, bt_ref):
-        # clamp the step into the tile's live range [lo, hi] (same Mosaic
-        # idiom as the per-token grid: skipped steps re-use the resident block)
+        # clamp the step into the tile's live range [lo, hi]: the index map
+        # runs (and its DMA issues) even for steps the kernel's pl.when
+        # skips, and Mosaic skips the refetch of a repeated block index
         hi = jnp.maximum(max_ref[i], 0) // block_size
         return (bt_ref[seq_ref[i], jnp.minimum(lo_ref[i] + j, hi)], 0, 0, 0)
 
@@ -771,10 +549,10 @@ def decode_kv_counts(choice, pos, windows, block_size: int, max_blocks: int, buc
     (:func:`_decode_work_list`'s count, real rows only) and ``kv_steps`` the
     block slots the chosen kernel's grid runs for them, pad rows included:
     the decode kernel's items times the blocks an item takes, every table
-    column of every bucket row for the other grids and the gather. ``choice``
-    is the shape's :func:`kernel_choice`; ``pos`` the positions of the fed
-    tokens, ``[..., rows]`` (one leading entry a step); ``windows`` pairs of
-    (window or None, layers that attend in it)."""
+    column of every bucket row for the gather (the tiled grid is not asked).
+    ``choice`` is the shape's :func:`kernel_choice`; ``pos`` the positions of
+    the fed tokens, ``[..., rows]`` (one leading entry a step); ``windows``
+    pairs of (window or None, layers that attend in it)."""
     pos = np.asarray(pos, np.int64)
     calls = pos.size // max(pos.shape[-1], 1)
     hi = np.minimum(pos // block_size, max_blocks - 1)

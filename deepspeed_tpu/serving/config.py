@@ -192,7 +192,7 @@ class ControlConfig:
     enabled: bool = False
     # decision-loop tick period
     interval_s: float = 0.25
-    # armed policies: 'admission' | 'scaling' | 'retune' | 'speculation'
+    # armed policies: 'admission' | 'scaling' | 'speculation'
     policies: Tuple = ("admission", "scaling", "speculation")
     # trailing sensor window the rates/deltas are computed over
     window_s: float = 5.0
@@ -234,16 +234,7 @@ class ControlConfig:
     queue_depth_undrain: int = 1
     # never drain below this many un-draining live replicas
     min_active_replicas: int = 1
-    # -- (c) retune policy: sentinel buckets nominate autotuner sweeps -----
-    # unexpected steady-state compiles a bucket needs before nomination
-    retune_min_bucket_count: int = 3
-    # sweeps launched per controller lifetime (each sweep is minutes of
-    # device time — the budget is deliberately small)
-    retune_max_sweeps: int = 2
-    # autotuner artifact root (the registry JSON the sweeps persist into,
-    # unless a process-global registry is already configured)
-    retune_artifact_dir: str = "/tmp/dstpu_control_retune"
-    # -- (d) speculation policy: accept-rate band retunes K ----------------
+    # -- (c) speculation policy: accept-rate band moves K ------------------
     spec_accept_high: float = 0.8
     spec_accept_low: float = 0.4
     spec_k_min: int = 1
@@ -254,7 +245,7 @@ class ControlConfig:
     spec_min_window_drafted: int = 16
 
 
-KNOWN_POLICIES = ("admission", "scaling", "retune", "speculation")
+KNOWN_POLICIES = ("admission", "scaling", "speculation")
 
 
 @dataclass

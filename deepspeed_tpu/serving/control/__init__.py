@@ -1,14 +1,12 @@
 """Self-driving serving: the feedback control plane.
 
 The sensor planes PRs 11-18 built (goodput ledger, reqtrace stage
-histograms, admission gauges, speculative accept stats, recompile
-sentinel) become ACTUATION inputs here: a single controller thread reads
-them, windowed, and drives four narrow public setters —
+histograms, admission gauges, speculative accept stats) become ACTUATION
+inputs here: a single controller thread reads them, windowed, and drives
+three narrow public setters —
 
   * SLO-aware admission depth overrides (``AdmissionController``);
   * replica drain/undrain/restart (``EngineReplica``);
-  * background kernel re-tuning sweeps (``KernelAutotuner`` persisted
-    through the ``KernelConfigRegistry``);
   * per-replica speculative K / tree-width (``set_spec_params``).
 
 Layering: ``decisions.py`` (the decision log every actuation goes
@@ -20,9 +18,9 @@ block = none of these objects exist (the zero-overhead-off contract).
 
 from .controller import ServingController
 from .decisions import DecisionLog
-from .policies import (AdmissionPolicy, RetunePolicy, ScalingPolicy,
-                       SpeculationPolicy, build_policies)
+from .policies import (AdmissionPolicy, ScalingPolicy, SpeculationPolicy,
+                       build_policies)
 
 __all__ = ["ServingController", "DecisionLog", "AdmissionPolicy",
-           "ScalingPolicy", "RetunePolicy", "SpeculationPolicy",
+           "ScalingPolicy", "SpeculationPolicy",
            "build_policies"]

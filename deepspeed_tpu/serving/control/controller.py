@@ -3,7 +3,7 @@
 One :class:`ServingController` per gateway when ``serving.gateway.control``
 is present. A single daemon thread ticks every ``interval_s``: it takes a
 raw sensor sample (counters, admission state, replica state, goodput
-ledgers, sentinel buckets — READ-ONLY, through the public surfaces the
+ledgers — READ-ONLY, through the public surfaces the
 earlier PRs built), diffs it against the trailing ``window_s`` of samples,
 hands the windowed snapshot to each armed policy, and applies the
 proposals through the ``_apply_*`` helpers — the ONLY sanctioned actuator
@@ -63,8 +63,6 @@ class ServingController:
         # bursty arrival dips so a momentary busy spike can't reset a
         # drain proposal's sustain counter
         self._idle_ewma: Optional[float] = None
-        # injected by tests / built lazily on the first retune actuation
-        self._tuner = None
         self._registered_gauges = None
         self._registered_state = None
         self._registered_dump = None
@@ -212,15 +210,9 @@ class ServingController:
                                        else alpha * idle_frac_raw
                                        + (1.0 - alpha) * self._idle_ewma)
                     idle_frac = self._idle_ewma
-        buckets = {}
-        gp = get_goodput()
-        for src in gp.sentinel.report().values():
-            for bucket, count in (src.get("by_bucket") or {}).items():
-                buckets[bucket] = buckets.get(bucket, 0) + int(count)
         snap = {"now": now, "window_s": now - base["t"], "classes": classes,
                 "replicas": replicas, "depth_total": adm.depth(),
-                "idle_frac": idle_frac, "idle_frac_raw": idle_frac_raw,
-                "compile_buckets": buckets}
+                "idle_frac": idle_frac, "idle_frac_raw": idle_frac_raw}
         self._last_snap = snap
         return snap
 
@@ -304,27 +296,6 @@ class ServingController:
                             inflight_rids=self._inflight_rids())
         return True
 
-    def _apply_retune(self, policy, prop) -> bool:
-        args = prop["args"]
-        tuner = self._get_tuner()
-        best, error = None, None
-        try:
-            if args["sweep"] == "paged":
-                best = tuner.tune_paged(T=args["T"])
-            else:
-                best = tuner.tune_paged_decode()
-            tuner.registry.save()
-        except Exception as e:  # noqa: BLE001 — a failed sweep never kills the loop
-            error = f"{type(e).__name__}: {str(e)[:200]}"
-        applied = error is None and best is not None
-        self.decisions.emit(policy=policy.name, action=prop["action"],
-                            applied=applied, reason=prop["reason"],
-                            sensors=prop["sensors"],
-                            result={"bucket": args["bucket"], "best": best,
-                                    "error": error},
-                            inflight_rids=self._inflight_rids())
-        return applied
-
     def _apply_spec(self, policy, prop) -> bool:
         args = prop["args"]
         rep = next((r for r in self.gateway.replicas
@@ -341,14 +312,6 @@ class ServingController:
                             sensors=prop["sensors"], result=result,
                             inflight_rids=self._inflight_rids())
         return applied
-
-    def _get_tuner(self):
-        if self._tuner is None:
-            from ...autotuning.kernel_config import (KernelAutotuner,
-                                                     get_kernel_registry)
-            self._tuner = KernelAutotuner(self.config.retune_artifact_dir,
-                                          registry=get_kernel_registry())
-        return self._tuner
 
     # -- export surfaces -----------------------------------------------------
     def gauge_rows(self):

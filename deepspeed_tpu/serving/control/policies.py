@@ -5,7 +5,7 @@ Each policy is a small stateful object with one method —
 windowed sensor snapshot (see ``ServingController._sense``). A proposal
 is a plain dict::
 
-    {"kind": "admission" | "scale" | "retune" | "spec",
+    {"kind": "admission" | "scale" | "spec",
      "action": <short verb string>,
      "reason": <why, one line>,
      "sensors": <the readings that justified it>,
@@ -23,11 +23,10 @@ strictly above relax threshold) and requires its condition to hold for
 and the act/undo thresholds never chase each other.
 """
 
-import re
 from typing import Dict, List
 
-__all__ = ["AdmissionPolicy", "ScalingPolicy", "RetunePolicy",
-           "SpeculationPolicy", "build_policies"]
+__all__ = ["AdmissionPolicy", "ScalingPolicy", "SpeculationPolicy",
+           "build_policies"]
 
 
 class _Sustain:
@@ -209,61 +208,8 @@ class ScalingPolicy:
         return out
 
 
-class RetunePolicy:
-    """(c) Online kernel re-tuning: the recompile sentinel's hot
-    steady-state compile buckets nominate background ``KernelAutotuner``
-    sweeps, persisted through the ``KernelConfigRegistry``. Each bucket is
-    nominated AT MOST ONCE per controller lifetime and the total sweep
-    budget is bounded — a sweep is minutes of device time, so the policy
-    is a nomination filter, not a loop."""
-
-    name = "retune"
-
-    _PUT = re.compile(r"^put/t(\d+)")
-    _DECODE = re.compile(r"^decode/")
-
-    def __init__(self, config):
-        self.config = config
-        self._nominated = set()
-        self._launched = 0
-
-    def propose(self, snap) -> List[dict]:
-        cfg = self.config
-        out = []
-        for bucket, count in sorted(snap.get("compile_buckets", {}).items(),
-                                    key=lambda kv: (-kv[1], kv[0])):
-            if self._launched >= cfg.retune_max_sweeps:
-                break
-            if bucket in self._nominated or count < cfg.retune_min_bucket_count:
-                continue
-            sensors = {"bucket": bucket, "unexpected_compiles": count}
-            m = self._PUT.match(bucket)
-            if m:
-                self._nominated.add(bucket)
-                self._launched += 1
-                out.append({"kind": "retune", "action": "tune_paged",
-                            "reason": f"hot untuned bucket {bucket} "
-                                      f"({count} steady-state compiles)",
-                            "sensors": sensors,
-                            "args": {"bucket": bucket, "sweep": "paged",
-                                     "T": int(m.group(1))}})
-            elif self._DECODE.match(bucket):
-                self._nominated.add(bucket)
-                self._launched += 1
-                out.append({"kind": "retune", "action": "tune_paged_decode",
-                            "reason": f"hot untuned bucket {bucket} "
-                                      f"({count} steady-state compiles)",
-                            "sensors": sensors,
-                            "args": {"bucket": bucket, "sweep": "paged_decode"}})
-            else:
-                # verify/... and unknown shapes have no sweep mapping yet;
-                # mark them handled so they don't re-propose every tick
-                self._nominated.add(bucket)
-        return out
-
-
 class SpeculationPolicy:
-    """(d) Per-replica speculative adaptation: the windowed draft accept
+    """(c) Per-replica speculative adaptation: the windowed draft accept
     rate tunes K within ``[spec_k_min, spec_k_max]`` (and optionally tree
     width up to ``spec_tree_width_max``). High acceptance = the drafter is
     under-asked, raise K; low acceptance = verify tokens are being burned,
@@ -319,7 +265,7 @@ class SpeculationPolicy:
 
 
 _BUILDERS = {"admission": AdmissionPolicy, "scaling": ScalingPolicy,
-             "retune": RetunePolicy, "speculation": SpeculationPolicy}
+             "speculation": SpeculationPolicy}
 
 
 def build_policies(config) -> List[object]:

@@ -28,7 +28,6 @@ from deepspeed_tpu.serving import (ControlConfig, GatewayConfig, ServingGateway,
                                    SLOClassConfig)
 from deepspeed_tpu.serving.admission import AdmissionController
 from deepspeed_tpu.serving.control.policies import (AdmissionPolicy,
-                                                    RetunePolicy,
                                                     ScalingPolicy,
                                                     SpeculationPolicy)
 from deepspeed_tpu.serving.disagg import DisaggCoordinator
@@ -398,8 +397,7 @@ def _ctl_cfg(**kw):
     base = dict(enabled=True, sustain_ticks=1, min_window_completions=2,
                 slo_miss_tighten=0.5, slo_miss_relax=0.1, min_queue_depth=1,
                 queue_depth_undrain=1, idle_frac_drain=0.9,
-                min_active_replicas=1, retune_min_bucket_count=3,
-                retune_max_sweeps=2, spec_accept_high=0.8, spec_accept_low=0.4,
+                min_active_replicas=1, spec_accept_high=0.8, spec_accept_low=0.4,
                 spec_k_min=1, spec_k_max=8, spec_min_window_drafted=8)
     base.update(kw)
     return ControlConfig(**base)
@@ -430,16 +428,15 @@ def test_scaling_policy_restart_beats_undrain_and_floors_drain():
                         "idle_frac": 0.99}) == []
 
 
-def test_retune_policy_nominates_once_within_budget():
-    pol = RetunePolicy(_ctl_cfg(retune_max_sweeps=2))
-    snap = {"compile_buckets": {"verify/t1/s8/k4": 9,     # unmapped: skipped
-                                "put/t64/s8/greedy": 5,
-                                "decode/s8/n1": 4,
-                                "put/t32/s8/greedy": 2}}  # under min count
-    out = pol.propose(snap)
-    assert [(p["action"], p["args"].get("T")) for p in out] == \
-        [("tune_paged", 64), ("tune_paged_decode", None)]
-    assert pol.propose(snap) == []  # nominated at most once, budget spent
+@pytest.mark.parametrize("body", [{"retune_min_bucket_count": 3}, {"retune_max_sweeps": 2},
+                                  {"retune_artifact_dir": "/tmp/x"}, {"policies": ["retune"]}])
+def test_retune_fields_are_refused(body):
+    """The retune policy and its three fields are gone: a configuration that
+    names them fails as any unknown key or policy fails, it is not ignored."""
+    from deepspeed_tpu.serving.config import GatewayConfig
+
+    with pytest.raises(ValueError, match="unknown (keys|policies)"):
+        GatewayConfig.from_dict({"control": body})
 
 
 def test_speculation_policy_adapts_k_on_accept_band():
@@ -454,36 +451,6 @@ def test_speculation_policy_adapts_k_on_accept_band():
     assert out[0]["action"] == "lower_k" and out[0]["args"]["k"] == 2
     rep["spec"] = {"d_drafted": 2, "d_accepted": 2, "k": 3, "tree_width": 1}
     assert pol.propose({"replicas": [rep]}) == []  # window too small to judge
-
-
-# ---------------------------------------------------------------------------
-# retune actuation: fake tuner injected, registry persisted, decision logged
-# ---------------------------------------------------------------------------
-def test_apply_retune_persists_through_registry(direct_engine):
-    g = _armed_gateway(direct_engine)
-    ctl = g.controller
-
-    class _FakeRegistry:
-        saves = 0
-
-        def save(self):
-            _FakeRegistry.saves += 1
-
-    class _FakeTuner:
-        registry = _FakeRegistry()
-
-        def tune_paged(self, T):
-            return {"T": T, "q_tile": 128}
-
-    ctl._tuner = _FakeTuner()
-    pol = RetunePolicy(_ctl_cfg())
-    prop = {"kind": "retune", "action": "tune_paged",
-            "reason": "hot untuned bucket", "sensors": {"bucket": "put/t64"},
-            "args": {"bucket": "put/t64", "sweep": "paged", "T": 64}}
-    assert ctl._apply_retune(pol, prop)
-    assert _FakeRegistry.saves == 1  # sweep result persisted, not transient
-    rec = ctl.decisions.recent()[-1]
-    assert rec["applied"] and rec["result"]["best"] == {"T": 64, "q_tile": 128}
 
 
 # ---------------------------------------------------------------------------
@@ -504,15 +471,12 @@ def test_control_actuator_gate_catches_drift(tmp_path):
     (pkg / "serving" / "handlers.py").write_text(
         "def route(replica):\n    replica.drain()\n")
     (pkg / "serving" / "control" / "controller.py").write_text(
-        # rule 3: an _apply_* helper that actuates without emitting
-        "def _apply_scale(prop):\n    prop.rep.restart()\n"
-        # rule 2: a sensor path launching device work
-        "def sense(tuner):\n    tuner.tune_paged(T=64)\n")
+        # rule 2: an _apply_* helper that actuates without emitting
+        "def _apply_scale(prop):\n    prop.rep.restart()\n")
     whys = sorted(why for _rel, _ln, _snip, why in find_violations(str(pkg)))
-    assert len(whys) == 3
+    assert len(whys) == 2
     assert any("rule 1" in w for w in whys)
     assert any("rule 2" in w for w in whys)
-    assert any("rule 3" in w for w in whys)
 
 
 # ---------------------------------------------------------------------------

@@ -231,7 +231,7 @@ def test_both_sinks_off_allocates_nothing_and_the_kernel_table_fills_at_trace_ti
     fn = jax.jit(lambda q: pa.paged_attention(q, pool, pool, tables, seq_idx, pos, 16))
     pa.KERNEL_CHOICES.pop((8, 2, 4), None)
     fn(q)
-    assert pa.kernel_choice(8, 2, 4) == {"kernel": "paged_attention_reference", "q_tile": 1, "kv_splits": 1,
+    assert pa.kernel_choice(8, 2, 4) == {"kernel": "paged_attention_reference", "q_tile": 1,
                                          "rule": "off_tpu", "blocks_per_step": 1}
     pa.KERNEL_CHOICES.clear()
     fn(q)
@@ -239,75 +239,55 @@ def test_both_sinks_off_allocates_nothing_and_the_kernel_table_fills_at_trace_ti
 
 
 @pytest.mark.parametrize("shape,env,want", [
-    ((2048, 8, 65), {}, (128, "heuristic:long_rows", 1, "heuristic:tiled")),  # mistral-7b.longprompt
-    ((512, 32, 65), {}, (32, "heuristic:short_rows", 1, "heuristic:tiled")),  # mistral-7b.chat's put
-    ((512, 8, 65), {}, (128, "heuristic:long_rows", 1, "heuristic:tiled")),
-    ((32, 32, 65), {}, (1, "heuristic:decode_shaped", 8, "heuristic:long_table")),
-    ((32, 32, 4), {}, (1, "heuristic:decode_shaped", 1, "heuristic:short_table")),
-    ((32, 32, 65), {"DS_TPU_PAGED_KV_SPLITS": "1"}, (1, "heuristic:decode_shaped", 1, "env")),
-    ((512, 8, 65), {"DS_TPU_PAGED_Q_TILE": "16"}, (16, "env", 1, "heuristic:tiled")),
+    ((2048, 8, 65), {}, ("paged_attn_q_tiled", 128, "heuristic:long_rows")),  # mistral-7b.longprompt
+    ((512, 32, 65), {}, ("paged_attn_q_tiled", 32, "heuristic:short_rows")),  # mistral-7b.chat's put
+    ((512, 8, 65), {}, ("paged_attn_q_tiled", 128, "heuristic:long_rows")),
+    ((32, 32, 65), {}, ("paged_attn_kv_split", 1, "heuristic:long_table")),
+    ((32, 32, 4), {}, ("paged_attn_kv_split", 1, "heuristic:short_table")),
+    # the variables that once overrode the choice are read by nothing
+    ((32, 32, 65), {"DS_TPU_PAGED_KV_SPLITS": "1"}, ("paged_attn_kv_split", 1, "heuristic:long_table")),
+    ((512, 8, 65), {"DS_TPU_PAGED_Q_TILE": "16"}, ("paged_attn_q_tiled", 128, "heuristic:long_rows")),
 ])
-def test_kernel_choice_names_the_rule_that_decided(monkeypatch, tmp_path, shape, env, want):
-    from deepspeed_tpu.autotuning.kernel_config import set_kernel_config_path
-
-    set_kernel_config_path(str(tmp_path / "none.json"))
+def test_kernel_choice_names_the_rule_that_decided(monkeypatch, shape, env, want):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     for k, v in env.items():
         monkeypatch.setenv(k, v)
-    try:
-        T, S, max_blocks = shape
-        q_tile, q_rule = pa._q_tile_choice(T, S)
-        kv_splits, kv_rule = pa._kv_splits_choice(T, S, max_blocks, q_tile=q_tile)
-        assert (q_tile, q_rule, kv_splits, kv_rule) == want
-        assert pa._resolve_q_tile(T, S) == q_tile and pa._resolve_kv_splits(T, S, max_blocks, q_tile) == kv_splits
-    finally:
-        set_kernel_config_path(None)
+    choice = pa.choose_kernel(*shape, nq=32, block_rows=128 * 8, d=128, itemsize=2)
+    assert (choice["kernel"], choice["q_tile"], choice["rule"]) == want
 
 
 @pytest.mark.parametrize("T,S,max_blocks,want", [
-    (512, 32, 65, {"kernel": "paged_attn_q_tiled", "q_tile": 32, "kv_splits": 1, "rule": "heuristic:short_rows",
+    (512, 32, 65, {"kernel": "paged_attn_q_tiled", "q_tile": 32, "rule": "heuristic:short_rows",
                    "blocks_per_step": 1}),
-    (2048, 8, 65, {"kernel": "paged_attn_q_tiled", "q_tile": 128, "kv_splits": 1, "rule": "heuristic:long_rows",
+    (2048, 8, 65, {"kernel": "paged_attn_q_tiled", "q_tile": 128, "rule": "heuristic:long_rows",
                    "blocks_per_step": 1}),
     # the decode kernel: 16-token blocks of 8 kv heads of 128 in float32 are 128 KiB, so four a grid step
-    (32, 32, 65, {"kernel": "paged_attn_kv_split", "q_tile": 1, "kv_splits": 8, "rule": "heuristic:long_table",
+    (32, 32, 65, {"kernel": "paged_attn_kv_split", "q_tile": 1, "rule": "heuristic:long_table",
                   "blocks_per_step": 4}),
-    (32, 32, 4, {"kernel": "paged_attn_per_token", "q_tile": 1, "kv_splits": 1, "rule": "heuristic:short_table",
-                 "blocks_per_step": 1}),
+    (32, 32, 4, {"kernel": "paged_attn_kv_split", "q_tile": 1, "rule": "heuristic:short_table",
+                 "blocks_per_step": 4}),
 ])
-def test_on_the_tpu_branch_the_table_names_the_grid_that_runs(monkeypatch, tmp_path, T, S, max_blocks, want):
+def test_on_the_tpu_branch_the_table_names_the_grid_that_runs(monkeypatch, T, S, max_blocks, want):
     """The TPU branch of ``paged_attention`` with the kernel itself stubbed
     out: the recorded kernel is the one ``_pallas_paged`` is handed."""
-    from deepspeed_tpu.autotuning.kernel_config import set_kernel_config_path
-
-    set_kernel_config_path(str(tmp_path / "none.json"))
     handed = {}
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(pa, "_pallas_paged", lambda q, *a, q_tile, kv_splits, **kw: handed.update(
-        q_tile=q_tile, kv_splits=kv_splits) or q)
+    monkeypatch.setattr(pa, "_pallas_paged", lambda q, *a, q_tile, **kw: handed.update(q_tile=q_tile) or q)
     try:
         q, pool = jnp.ones((T, 8, 128)), jnp.ones((max_blocks * 16, 8, 128))
         pa.paged_attention(q, pool, pool, jnp.zeros((S, max_blocks), jnp.int32), jnp.zeros((T, ), jnp.int32),
                            jnp.zeros((T, ), jnp.int32), 16)
         assert pa.kernel_choice(T, S, max_blocks) == want
-        assert handed == {"q_tile": want["q_tile"], "kv_splits": want["kv_splits"]}
+        assert handed == {"q_tile": want["q_tile"]}
     finally:
-        set_kernel_config_path(None)
         pa.KERNEL_CHOICES.pop((T, S, max_blocks), None)
 
 
-def test_contiguity_demotion_and_tuned_rule(tmp_path):
-    from deepspeed_tpu.autotuning.kernel_config import set_kernel_config_path, shape_bucket
-
-    reg = set_kernel_config_path(str(tmp_path / "kc.json"))
-    try:
-        interleaved = np.tile(np.arange(2), 32).astype(np.int32)
-        assert pa._q_tile_choice(64, 2, interleaved) == (1, "contiguity_demoted")
-        reg.record("paged_attention", shape_bucket(T=512), {"q_tile": 16})
-        assert pa._q_tile_choice(512, 8) == (16, "tuned")
-        reg.record("paged_attention", shape_bucket(B=65), {"kv_splits": 4})
-        assert pa._kv_splits_choice(32, 32, 65) == (4, "tuned")
-    finally:
-        set_kernel_config_path(None)
+def test_contiguity_demotion(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    interleaved = np.tile(np.arange(2), 32).astype(np.int32)
+    choice = pa.choose_kernel(64, 2, 65, 32, 128 * 8, 128, 2, seq_idx=interleaved)
+    assert (choice["kernel"], choice["rule"]) == ("paged_attn_kv_split", "contiguity_demoted")
 
 
 def _train_engine(extra):

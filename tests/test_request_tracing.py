@@ -185,6 +185,11 @@ def test_x_request_id_on_every_response_path():
         g.drain(False)
         # absent id: one is GENERATED (never a missing header)
         g.replicas[0].resume()
+        # the resumed replica pulls the two queued requests on its own thread:
+        # posting before it has would be shed again at depth 2 (seen under load)
+        deadline = time.monotonic() + 30
+        while g.admission.depth() >= 2 and time.monotonic() < deadline:
+            time.sleep(0.01)
         st, _, rid = _post(port, {"prompt": [5, 6, 7], "max_new_tokens": 2,
                                   "stream": False})
         assert st == 200 and rid and len(rid) == 16

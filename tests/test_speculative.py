@@ -534,10 +534,16 @@ def test_spec_metrics_counters_gauge_and_span(tiny_model, tmp_path):
     configure_tracer(enabled=True, path=trace_file)
     try:
         spec = SpeculativeConfig(mode="ngram", k=3, min_match=1)
-        eng = _engine(model, params, cache_on=True, spec=spec)
-        motif = np.arange(6, dtype=np.int32) + 40
-        prompt = np.concatenate([motif, motif, motif])
-        _run_sched(eng, [(1, prompt)], max_new=14)
+        # the prompt holds every token of the vocabulary once, so whatever the
+        # model emits first has an earlier occurrence for the n-gram drafter
+        # to continue from: the first decode round IS a verify step, whatever
+        # the weights (a repetitive prompt alone is not enough: the stream
+        # leaves the motif at its first token and the plain decode burst
+        # takes the rounds in which it would have repeated)
+        eng = _engine(model, params, cache_on=True, spec=spec, max_context=160)
+        prompt = np.arange(model.config.vocab_size, dtype=np.int32)
+        _, sched = _run_sched(eng, [(1, prompt)], max_new=14)
+        assert sched.spec_stats["rounds"] > 0
         snap = get_metrics().snapshot()
         c = snap["counters"]
         assert c["serving/spec_drafted_tokens"] > 0

@@ -546,36 +546,68 @@ def test_int4_weight_dequant_on_chip():
     np.testing.assert_allclose(np.asarray(y, np.float32), y_ref, rtol=5e-2, atol=5e-1)
 
 
+def _decode_rows(contexts):
+    """One decode token a row at the end of its context, then the pad run
+    ragged_wrapper.finalize emits (row 0, position 0)."""
+    return [(r, c - 1) for r, c in enumerate(contexts)] + [(0, 0)]
+
+
+def _chunks(*runs):
+    """Tokens of (row, first position, count) runs, back to back."""
+    return [(r, p + i) for r, p, n in runs for i in range(n)]
+
+
+_CELL_CONTEXTS = [262 + 57 * i for i in range(31)]
+# the inputs the deleted per-token grid served (PR 29), by the rule that now hands them to the decode kernel
+_SHORT_TABLE = _decode_rows([1 + (37 * i) % 512 for i in range(31)])                      # (a) 32 rows, 4 columns
+_VERIFY = _chunks(*[(r, 100 + 251 * r, 5) for r in range(8)])                             # (b) 8 rows x 5 tokens
+_INTERLEAVED = [(i % 4, 100 + 500 * (i % 4) + i // 4) for i in range(96)]                 # (c) not contiguous
+# (d) the cells' own 32-token x 8-row put: a 20-token chunk at ~3,000, 4 decode rows, 8 pad tokens
+_PUT_32x8 = _chunks((0, 2980, 20)) + [(1, 301), (2, 555), (3, 790), (4, 999)] + [(0, 0)] * 8
+
+
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
-@pytest.mark.parametrize("name,nq,nkv,window,mb,n_blocks,contexts", [
+@pytest.mark.parametrize("name,nq,nkv,window,S,mb,n_blocks,tokens,rule", [
     # mistral-7b.decode-heavy: 32 rows of 262-2,020 tokens, 8 kv heads (two 512 KiB blocks a grid step)
-    ("mistral-7b", 32, 8, 4096, 65, 619, [262 + 57 * i for i in range(31)]),
+    ("mistral-7b", 32, 8, 4096, 31, 65, 619, _decode_rows(_CELL_CONTEXTS), "heuristic:long_table"),
     # mellum2-12b-a2.5b.decode-heavy: 4 kv heads (four blocks a step), its window layers and its full ones
-    ("mellum2.window", 32, 4, 1024, 65, 619, [262 + 57 * i for i in range(31)]),
-    ("mellum2.full", 32, 4, None, 65, 619, [262 + 57 * i for i in range(31)]),
+    ("mellum2.window", 32, 4, 1024, 31, 65, 619, _decode_rows(_CELL_CONTEXTS), "heuristic:long_table"),
+    ("mellum2.full", 32, 4, None, 31, 65, 619, _decode_rows(_CELL_CONTEXTS), "heuristic:long_table"),
     # one kv head a query head, a table it fills: one 1 MiB block a step, one long row beside short ones
-    ("mha16", 16, 16, None, 16, 64, [2048, 131, 657, 3]),
+    ("mha16", 16, 16, None, 4, 16, 64, _decode_rows([2048, 131, 657, 3]), "heuristic:long_table"),
+    ("short_table", 32, 8, None, 31, 4, 619, _SHORT_TABLE, "heuristic:short_table"),
+    ("short_table.window", 32, 8, 256, 31, 4, 619, _SHORT_TABLE, "heuristic:short_table"),
+    ("verify_8x5", 32, 8, None, 8, 17, 619, _VERIFY, "heuristic:multi_token"),
+    ("verify_8x5.window", 32, 8, 1024, 8, 17, 619, _VERIFY, "heuristic:multi_token"),
+    ("noncontiguous_96", 32, 8, None, 4, 17, 619, _INTERLEAVED, "contiguity_demoted"),
+    ("noncontiguous_96.window", 32, 8, 1024, 4, 17, 619, _INTERLEAVED, "contiguity_demoted"),
+    ("put_32x8.mistral", 32, 8, 4096, 8, 65, 619, _PUT_32x8, "heuristic:multi_token"),
+    ("put_32x8.mistral.full", 32, 8, None, 8, 65, 619, _PUT_32x8, "heuristic:multi_token"),
+    ("put_32x8.mellum.window", 32, 4, 1024, 8, 65, 619, _PUT_32x8, "heuristic:multi_token"),
+    ("put_32x8.mellum.full", 32, 4, None, 8, 65, 619, _PUT_32x8, "heuristic:multi_token"),
 ])
-def test_paged_attention_kv_split_on_chip(name, nq, nkv, window, mb, n_blocks, contexts, kv):
+def test_paged_attention_kv_split_on_chip(name, nq, nkv, window, S, mb, n_blocks, tokens, rule, kv):
     """The decode kernel on the chip, through ``paged_attention`` as the
     serving engine calls it, at the shapes of the two decode-heavy cells
     (heads of 128, 128-token blocks, tables 65 wide, one token a row, a pad
-    row at position 0), bf16 and int8 KV, against the gather reference. The
-    interpret-mode matrix in tests/test_kernel_tuning.py cannot see Mosaic:
-    the dynamic grid bound, the scalar-prefetched work list and the pool read
-    as ``[blocks, block * nkv, d]`` only exist here."""
+    row at position 0) and at every kind of batch the selector hands it
+    (a short table, several tokens a row, a batch that is not contiguous, the
+    cells' 32-token x 8-row ``put``), bf16 and int8 KV, against the gather
+    reference. The interpret-mode matrix in tests/test_kernel_tuning.py
+    cannot see Mosaic: the dynamic grid bound, the scalar-prefetched work
+    list and the pool read as ``[blocks, block * nkv, d]`` only exist here."""
     from deepspeed_tpu.ops.pallas import paged_attention as pa
 
     d, bs = 128, 128
     rng = np.random.default_rng(28)
-    S = len(contexts)
-    T = S + 1  # the pad run ragged_wrapper.finalize emits
+    T = len(tokens)
+    seq_idx = jnp.asarray([r for r, _ in tokens], jnp.int32)
+    pos = jnp.asarray([p for _, p in tokens], jnp.int32)
     tables = np.zeros((S, mb), np.int32)
-    for r, c in enumerate(contexts):
-        tables[r, :-(-c // bs)] = rng.choice(n_blocks, size=-(-c // bs), replace=False)
+    for r in range(S):
+        n = max((p for row, p in tokens if row == r), default=0) // bs + 1
+        tables[r, :n] = rng.choice(n_blocks, size=n, replace=False)
     tables = jnp.asarray(tables)
-    seq_idx = jnp.asarray(list(range(S)) + [0], jnp.int32)
-    pos = jnp.asarray([c - 1 for c in contexts] + [0], jnp.int32)
     q = jnp.asarray(rng.normal(size=(T, nq, d)), jnp.bfloat16)
     kf = rng.normal(size=(n_blocks * bs, nkv, d)).astype(np.float32)
     vf = rng.normal(size=(n_blocks * bs, nkv, d)).astype(np.float32)
@@ -592,10 +624,10 @@ def test_paged_attention_kv_split_on_chip(name, nq, nkv, window, mb, n_blocks, c
     pa.KERNEL_CHOICES.pop((T, S, mb), None)
     out = jax.jit(lambda q, k_pool, v_pool, kw: pa.paged_attention(  # the pools as arguments, not constants
         q, k_pool, v_pool, tables, seq_idx, pos, bs, window=window, **kw))(q, k_pool, v_pool, kw)
-    choice = pa.kernel_choice(T, S, mb)
     # a grid step streams 1 MiB of K and V where the blocks are smaller than that
-    assert (choice["kernel"], choice["blocks_per_step"]) == (
-        "paged_attn_kv_split", max(1, min(4, (1 << 20) // (2 * bs * nkv * d * k_pool.dtype.itemsize))))
+    assert pa.kernel_choice(T, S, mb) == {
+        "kernel": "paged_attn_kv_split", "q_tile": 1, "rule": rule,
+        "blocks_per_step": max(1, min(4, (1 << 20) // (2 * bs * nkv * d * k_pool.dtype.itemsize)))}
     ref = np.asarray(paged_attention_reference(q, k_pool, v_pool, tables, seq_idx, pos, bs,
                                                window=window, **kw), np.float32)
     got = np.asarray(out, np.float32)

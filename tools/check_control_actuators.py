@@ -5,7 +5,7 @@ controller's decision-applying helpers, and every decision site logs.
 The self-driving serving loop (``deepspeed_tpu/serving/control/``) is only
 auditable if actuations cannot bypass it: a stray ``replica.drain()`` in a
 request handler, or an admission override applied from a bench script
-inside the package, would mutate the fleet with no decision record. Three
+inside the package, would mutate the fleet with no decision record. Two
 rules keep the loop closed:
 
   1. Anywhere in ``deepspeed_tpu/``, a call to a GATED actuator method
@@ -17,13 +17,7 @@ rules keep the loop closed:
      a function of that name (the defining module and its internal
      plumbing — e.g. ``replica.py``'s goodput-ledger ``resume`` calls).
 
-  2. Inside ``serving/control/``, a call to a ``KernelAutotuner`` sweep
-     entry point (``tune_paged`` / ``tune_paged_decode`` / ``tune_flash``
-     / ``tune_grouped`` / ``tune_all`` / ``sweep``) must sit inside an
-     ``_apply_*`` helper — a policy or sensor path must never launch
-     device work.
-
-  3. Every ``_apply_*`` function in ``serving/control/`` must contain at
+  2. Every ``_apply_*`` function in ``serving/control/`` must contain at
      least one ``.emit(`` call — an actuation without a decision record
      is structurally impossible.
 
@@ -49,11 +43,6 @@ DEFAULT_PKG_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
 GATED_ACTUATORS = frozenset({
     "pause", "resume", "drain", "undrain", "restart",
     "set_depth_override", "clear_depth_override", "set_spec_params",
-})
-
-TUNER_CALLS = frozenset({
-    "tune_paged", "tune_paged_decode", "tune_flash", "tune_grouped",
-    "tune_all", "sweep",
 })
 
 
@@ -97,14 +86,14 @@ def find_violations(pkg_dir: str):
                 if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
                     func_stack = func_stack + [node.name]
                     if in_control and node.name.startswith("_apply_"):
-                        # rule 3: the helper must emit a decision record
+                        # rule 2: the helper must emit a decision record
                         emits = [c for c in ast.walk(node)
                                  if isinstance(c, ast.Call)
                                  and isinstance(c.func, ast.Attribute)
                                  and c.func.attr == "emit"]
                         if not emits:
                             flag(node, f"decision helper {node.name} never "
-                                       "emits a decision record (rule 3)")
+                                       "emits a decision record (rule 2)")
                 elif isinstance(node, ast.Call) \
                         and isinstance(node.func, ast.Attribute):
                     name = node.func.attr
@@ -114,9 +103,6 @@ def find_violations(pkg_dir: str):
                         if not sanctioned:
                             flag(node, f"actuator .{name}() outside a "
                                        "serving/control/ _apply_* helper (rule 1)")
-                    if in_control and name in TUNER_CALLS and not in_apply:
-                        flag(node, f"autotuner .{name}() outside an _apply_* "
-                                   "helper (rule 2)")
                 for child in ast.iter_child_nodes(node):
                     walk(child, func_stack)
 
