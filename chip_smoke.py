@@ -16,10 +16,10 @@ random weights from a seed):
            steps on one repeated batch.
 
 Both phases also prove the Pallas kernels are IN the compiled programs (flash
-forward + both backward passes in the train step, the paged kernel in the
-prefill and decode steps). The server runs first and is freed, so both fit
-one 16 GB chip. Any failed check or exception is a non-zero exit; nothing is
-downgraded to a warning.
+forward + both backward passes in the train step, each ONCE: the remat policy
+saves the kernel's output; the paged kernel in the prefill and decode steps).
+The server runs first and is freed, so both fit one 16 GB chip. Any failed
+check or exception is a non-zero exit; nothing is downgraded to a warning.
 
     python chip_smoke.py                                   # on the chip
     JAX_PLATFORMS=cpu python chip_smoke.py --rehearsal     # control flow only
@@ -100,12 +100,13 @@ class Smoke:
         text = lowered.as_text()
         if self.rehearsal:
             self.say(f"{what}: lowered; kernel presence is a chip-only check")
-            return
+            return None
         compiled_text = lowered.compile().as_text()
         calls = {n: text.count(f'"{n}"') for n in names}
         self.check(all(calls.values()) and CUSTOM_CALL in compiled_text,
                    f"{what}: Pallas calls lowered {calls}, {compiled_text.count(CUSTOM_CALL)} x "
                    f"{CUSTOM_CALL} in the compiled program")
+        return calls
 
 
 def memory_lines(smoke: Smoke, label: str):
@@ -339,7 +340,12 @@ def trainer_phase(smoke: Smoke):
                     f"training state is spread over all {len(in_use)} devices "
                     f"(min {min(in_use)}, max {max(in_use)} bytes in use, limit {limit})")
 
-    smoke.check_kernels("train step", engine.aot_lower_train_step(s["seq"]), FLASH_KERNELS)
+    calls = smoke.check_kernels("train step", engine.aot_lower_train_step(s["seq"]), FLASH_KERNELS)
+    if calls is not None:
+        # the policy saves attn_out, which the kernel gives its output and its
+        # log-sum-exp: a second flash_fwd means the remat recomputes it again
+        smoke.check(len(set(calls.values())) == 1,
+                    f"train step: one flash_fwd for each backward pair under {cfg.remat_policy}: {calls}")
     engine.destroy()
 
 

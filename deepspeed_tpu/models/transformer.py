@@ -32,6 +32,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from ..ops.pallas.flash_attention import name_attn_out
 from ..parallel.mesh import BATCH_AXES, DATA_AXIS, MODEL_AXIS, PIPE_AXIS, SEQ_AXIS
 from ..runtime.zero.partition import PartitionRules
 
@@ -457,8 +458,14 @@ def _multi_device_tpu_mesh():
 
 
 def _attention(cfg: TransformerConfig, q, k, v):
+    """Local attention, [B, S, n, d] in and out. Every branch returns its
+    output under the remat name ``attn_out``, given ONCE and in the shape
+    ``name_attn_out`` picks for the scan's stack: the flash kernel names its
+    own pair (``out`` and the log-sum-exp its backward starts from) inside
+    its forward rule, the other branches are named here. A second name on the
+    caller's reshape of the kernel's output would make the scan stack it twice."""
     if cfg.sparse_attention is not None:
-        return _sparse_attention(cfg, q, k, v)
+        return name_attn_out(_sparse_attention(cfg, q, k, v))
     impl = cfg.attention_impl
     if impl == "auto":
         impl = "flash" if jax.default_backend() == "tpu" else "reference"
@@ -481,8 +488,8 @@ def _attention(cfg: TransformerConfig, q, k, v):
         spec = P(BATCH_AXES, None, heads, None)
         return jax.shard_map(attn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
                              check_vma=False)(q, k, v)
-    return reference_attention(q, k, v, causal=True, window=cfg.sliding_window,
-                               alibi=alibi_slopes(cfg.num_heads) if alibi else None)
+    return name_attn_out(reference_attention(q, k, v, causal=True, window=cfg.sliding_window,
+                                             alibi=alibi_slopes(cfg.num_heads) if alibi else None))
 
 
 def _qwz_target_specs(cfg: TransformerConfig, layer):
@@ -581,7 +588,7 @@ def _attn_branch(cfg: TransformerConfig, layer, h, sin, cos):
             # degrade to plain attention when no mesh registry is live (same
             # graceful behavior as ulysses' sharding constraints outside a mesh)
             if groups.is_initialized() and mesh_axis_size(groups.get_mesh(), SEQ_AXIS) > 1:
-                ctx = ring_attention_gspmd(q, k, v, groups.get_mesh(), causal=True)
+                ctx = name_attn_out(ring_attention_gspmd(q, k, v, groups.get_mesh(), causal=True))
             else:
                 ctx = _attention(cfg, q, k, v)
         else:
@@ -595,13 +602,13 @@ def _attn_branch(cfg: TransformerConfig, layer, h, sin, cos):
             ctx = ulysses_attention_gspmd(partial(_attention, cfg), q, k, v)
     else:
         ctx = _attention(cfg, q, k, v)
+    # ctx arrives named ``attn_out`` by the path that computed it (see
+    # _attention). Under remat_policy="save_only_these_names(attn_out)" the
+    # block saves its input, that output and, for the flash kernel, its
+    # [B, nq, S] float32 log-sum-exp, so no attention forward runs in the
+    # backward; the norm, the q/k/v projections, rope (and Ulysses'
+    # all-to-alls), this reshape and the whole MLP branch are recomputed.
     ctx = ctx.reshape(B, S, nq * d)
-    # named for remat_policy="save_only_these_names(attn_out)": saving the
-    # attention context keeps the flash kernel out of the backward recompute
-    # while everything else (cheap elementwise + refusable matmuls) remats
-    from jax.ad_checkpoint import checkpoint_name
-
-    ctx = checkpoint_name(ctx, "attn_out")
     attn_out = jnp.einsum("bsd,dh->bsh", ctx, layer["wo"].astype(dt))
     if cfg.use_bias:
         attn_out = attn_out + layer["bo"].astype(dt)

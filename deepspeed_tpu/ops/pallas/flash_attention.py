@@ -9,15 +9,25 @@ numerically validated against ``models.transformer.reference_attention``
 (mirroring the reference's tests/unit/ops kernel-vs-torch strategy) — in both
 forward and ``jax.grad``.
 
-Backward follows the flash-attention recurrences: the forward saves the
-per-row log-sum-exp ``lse = m + log(l)``; the backward recomputes
-``p = exp(s - lse)`` blockwise, with the two-pass split:
+Backward follows the flash-attention recurrences: the forward saves
+``q, k, v``, its output and the per-row log-sum-exp ``lse = m + log(l)``
+([B, nq, S] float32, not the kernel's 128-lane broadcast); the backward
+recomputes ``p = exp(s - lse)`` blockwise, with the two-pass split:
 
   * dk/dv pass — grid over k-blocks, inner loop over q-blocks:
       dv += p^T dO;   ds = p * (dO v^T - delta);   dk += ds^T q * scale
   * dq pass — grid over q-blocks, inner loop over k-blocks:
       dq += ds k * scale
   where ``delta = rowsum(dO * O)``.
+
+Under ``jax.checkpoint`` the forward rule gives ``out`` and ``lse`` the name
+``attn_out``: a policy that saves that name (``save_only_these_names(attn_out)``,
+what the training configurations state) keeps the pair across the remat
+boundary, so the backward runs ``flash_bwd_dkdv`` and ``flash_bwd_dq`` from
+them and recomputes only ``q, k, v`` (norm, projections, rope) — ``flash_fwd``
+runs once a layer. Under any other policy the name is inert and the
+rematerialised forward runs the kernel a second time; with no checkpoint at
+all the residuals are simply kept.
 
 Fallback policy: on non-TPU backends, or for shapes the kernel does not
 support (S not a multiple of 128), we use the jnp reference implementation —
@@ -31,6 +41,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 _NEG_INF = -1e30
 
@@ -109,6 +120,19 @@ def _fit_tiles_vmem(S: int, d: int, bq: int, bk: int):
         bq, bk = bq2, bk2
 
 
+def name_attn_out(out):
+    """Give an attention output [B, S, n, d] the remat name ``attn_out``. A
+    value is saved in the shape it was named in, and the scan stacks it in
+    (8, 128) tiles: heads that fill the 128 lanes are named as they are (XLA
+    then keeps the stack in the kernels' layout and the backward reads it in
+    place), narrower ones in the [B, S, n * d] shape, or half of every tile
+    is padding (heads of 64 at 410M: 402 MB where [.., 1024] takes 201)."""
+    B, S, n, d = out.shape
+    if d % 128 == 0:
+        return checkpoint_name(out, "attn_out")
+    return checkpoint_name(out.reshape(B, S, n * d), "attn_out").reshape(B, S, n, d)
+
+
 def _reference_fallback(q, k, v, causal, window, alibi, reason=None):
     """The single O(S^2) jnp fallback path; ``reason`` warns once."""
     from ...models.transformer import alibi_slopes, reference_attention
@@ -117,8 +141,9 @@ def _reference_fallback(q, k, v, causal, window, alibi, reason=None):
         from ...utils.logging import warning_once
 
         warning_once(f"flash attention: {reason} — using O(S^2) reference attention")
-    return reference_attention(q, k, v, causal=causal, window=window,
-                               alibi=alibi_slopes(q.shape[2]) if alibi else None)
+    out = reference_attention(q, k, v, causal=causal, window=window,
+                              alibi=alibi_slopes(q.shape[2]) if alibi else None)
+    return name_attn_out(out)  # as the kernel names its own output
 
 
 def flash_attention(q, k, v, causal: bool = True, block_q: int = None, block_k: int = None,
@@ -176,6 +201,11 @@ def _flash_core(causal, block_q, block_k, interpret, window, alibi, q, k, v):
 
 def _flash_core_fwd(causal, block_q, block_k, interpret, window, alibi, q, k, v):
     out, lse = _flash_fwd_impl(causal, block_q, block_k, interpret, window, alibi, q, k, v)
+    # the kernel's two outputs ARE the attention output: under
+    # save_only_these_names(attn_out) they cross the remat boundary and the
+    # backward starts from them; under any other policy the name is inert
+    out = name_attn_out(out)
+    lse = checkpoint_name(lse, "attn_out")
     return out, (q, k, v, out, lse)
 
 

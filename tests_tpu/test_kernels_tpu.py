@@ -678,3 +678,39 @@ def test_moe_serving_programs_of_every_bucket_pair_run_on_chip():
     finally:
         faulthandler.cancel_dump_traceback_later()
     assert len(ran) >= len(eng.batch.seq_buckets) and not any(r["cached"] for r in ran), ran
+
+
+def _flash_calls_in_compiled_train_step(head_dim, abstract=None):
+    """Compile value_and_grad of the model's loss for a 2-layer scanned,
+    rematerialised block and count each flash kernel's custom calls in the
+    text XLA compiled. ``abstract`` maps a ShapeDtypeStruct to one placed on a
+    described device (the sandbox's compile-only rehearsal); on the chip the
+    shapes compile for the attached device."""
+    from deepspeed_tpu.models import TransformerLM, gpt_neox_config
+
+    cfg = gpt_neox_config("pythia-1b", hidden_size=16 * head_dim, num_heads=16, num_kv_heads=16,
+                          intermediate_size=4 * 16 * head_dim, num_layers=2, vocab_size=50304,
+                          max_seq_len=2048, dtype=jnp.bfloat16, attention_impl="flash", remat=True,
+                          remat_policy="save_only_these_names(attn_out)")
+    model = TransformerLM(cfg)
+    params = jax.eval_shape(lambda k: model.init(k, None), jax.random.PRNGKey(0))
+    ids = jax.ShapeDtypeStruct((2, 2048), jnp.int32)
+    if abstract is not None:
+        params, ids = jax.tree_util.tree_map(abstract, (params, ids))
+    step = jax.jit(jax.value_and_grad(lambda p, i: model.loss(p, {"input_ids": i})))
+    text = step.lower(params, ids).compile().as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    return {k: sum(k in line for line in calls) for k in ("flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq")}, len(calls)
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_remat_train_step_holds_one_flash_fwd_per_layer_body(head_dim):
+    """Under ``save_only_these_names(attn_out)`` the kernel's output and its
+    log-sum-exp cross the remat boundary, so the compiled step of the scanned
+    block holds ONE ``flash_fwd`` (the forward scan's) beside the two backward
+    kernels: three Mosaic calls where PR 21 counted four. The name is given
+    inside a ``custom_vjp`` forward rule under ``jit``; only the compiled
+    program says whether the policy saw it."""
+    calls, n = _flash_calls_in_compiled_train_step(head_dim)
+    print(f"heads of {head_dim}: {calls}, {n} x tpu_custom_call")
+    assert calls == {"flash_fwd": 1, "flash_bwd_dkdv": 1, "flash_bwd_dq": 1} and n == 3, (calls, n)
