@@ -463,19 +463,27 @@ class InferenceEngineV2:
         return out
 
     def _moe_span_args(self, tokens: int, t_bucket: int, steps: int, stats) -> dict:
-        """What a step span says of the expert layers: ``moe_slots`` routed
-        slots (live tokens x top-k x layers), ``moe_rows`` the rows the
-        grouped kernel computed for them, padding included (static for the
-        bucket), ``experts_hit`` of ``experts_total`` experts with at least
-        one slot, summed over layers and steps, and ``expert_load_max``, the
-        most slots one expert of one layer held. ``stats`` is the program's
-        ``[experts_hit, expert_load_max]``."""
+        """What a step span says of the expert layers. ``moe_slots_routed``:
+        live tokens x top-k x EXPERT layers (a leading dense layer routes
+        nothing); ``moe_slots``: those of them that landed on experts held
+        here and took a row (all, for a model that holds every expert; a
+        count of the program's otherwise: how many land here is data);
+        ``moe_rows``: the rows the grouped kernel's grid covers for the
+        bucket, padding included (static); ``experts_hit`` of
+        ``experts_total`` held experts with at least one slot, summed over
+        expert layers and steps; ``expert_load_max``, the most slots one
+        expert of one layer held; ``experts_held`` of ``experts_published``,
+        the experts here of those the router scores. ``stats`` is the
+        program's ``[experts_hit, expert_load_max, slots]``."""
         mc = self.model_config
-        return {"moe_slots": tokens * mc.moe_top_k * mc.num_layers,
-                "moe_rows": self._moe.padded_rows(t_bucket) * mc.num_layers * steps,
+        layers = mc.num_expert_layers
+        return {"moe_slots": int(stats[2]),
+                "moe_slots_routed": tokens * mc.moe_top_k * layers,
+                "moe_rows": self._moe.padded_rows(t_bucket) * layers * steps,
                 "experts_hit": int(stats[0]),
-                "experts_total": mc.moe_num_experts * mc.num_layers * steps,
-                "expert_load_max": int(stats[1])}
+                "experts_total": mc.experts_held * layers * steps,
+                "expert_load_max": int(stats[1]),
+                "experts_held": mc.experts_held, "experts_published": mc.moe_num_experts}
 
     def _kv_span_args(self, T: int, S: int, pos) -> dict:
         """What a decode span says of the attention kernel's grid: ``kv_live``
@@ -713,7 +721,7 @@ class InferenceEngineV2:
         byte-identical to the plain causal step.
 
         ``moe_stats`` (a model with experts): a third result, int32
-        ``[experts_hit, expert_load_max]`` of this forward."""
+        ``[experts_hit, expert_load_max, slots]`` of this forward."""
         from .ragged.ragged_wrapper import unpack_descriptors
 
         token_ids, seq_idx, pos, valid, tables, last_idx = unpack_descriptors(
@@ -1166,7 +1174,7 @@ class InferenceEngineV2:
             max_blocks = self._max_blocks_per_seq
             step_fn = self._ragged_step
             moe = self._moe is not None
-            stats0 = (jnp.zeros(2, jnp.int32), ) if moe else ()
+            stats0 = (jnp.zeros(3, jnp.int32), ) if moe else ()
 
             def merge(stats, new):
                 """The routing counts (none for a dense model) over the steps."""

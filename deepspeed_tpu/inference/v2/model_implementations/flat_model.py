@@ -68,10 +68,17 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
 
     A model with experts (``cfg.moe_num_experts``) runs its MLP through the
     ``moe`` module (padding tokens route nowhere); ``moe_stats`` appends to
-    the return int32 ``[experts_hit, expert_load_max]``: experts with at least
-    one slot summed over the layers, and the most slots on one expert in a
-    layer. A model with ``cfg.layer_types`` gives each layer its own window
-    (the ``attention`` / ``attention_full`` modules) and its own rope table.
+    the return int32 ``[experts_hit, expert_load_max, slots]``: experts with at
+    least one slot summed over the layers, the most slots on one expert in a
+    layer, and the slots that took a row (all routed ones, unless the model
+    holds a share of its experts). A model with ``cfg.layer_types`` gives each
+    layer its own window (the ``attention`` / ``attention_full`` modules) and
+    its own rope table, or none (``cfg.rope_layer_types``). The leading
+    ``cfg.moe_num_dense_layers`` layers of a model with experts run the dense
+    MLP, the others the routed experts held here plus the shared expert
+    (plain matmuls), whose arrays are stacked over the expert layers alone. A
+    q/k norm, the attention gate and the norms after each branch are the
+    configuration's (``qk_norm``, ``attention_gate``, ``post_norms``).
 
     ``k_scale``/``v_scale``: int8-KV mode — [nkv, L*pool_len] fp32 absmax
     scales (lane-major over slots, the layout both the scatter and the
@@ -109,7 +116,8 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
     # one rope table an attention kind (a model of one kind: the key None), in
     # the order the kinds first appear: a set's order changes with the process's
     # string hash seed, and with it the traced program and its compile-cache key
-    ropes = {kind: rope_table(cfg, pid, kind) for kind in dict.fromkeys(cfg.layer_types or (None, ))} \
+    ropes = {kind: rope_table(cfg, pid, kind) for kind in dict.fromkeys(cfg.layer_types or (None, ))
+             if cfg.rope_layer_types is None or kind in cfg.rope_layer_types} \
         if cfg.positions == "rotary" else {}
 
     # flat KV slot of each token; padding tokens dropped via OOB scatter.
@@ -131,14 +139,17 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
         one kind, where ``l`` may be traced); ``stats``: the running MoE
         counts."""
         attend = modules["attention_full"] if kind == "full_attention" else attention
-        sin, cos = ropes.get(kind, (None, None))
         h1 = pre_norm(x, blk["ln1_scale"], blk.get("ln1_bias"))
         bias = (lambda n: blk[n]) if cfg.use_bias else (lambda n: None)
         qkvb = (lambda n: blk[n]) if cfg.qkv_bias_enabled else (lambda n: None)
         q = linear(h1, blk["wq"], qkvb("bq")).reshape(T, nq, d)
         k = linear(h1, blk["wk"], qkvb("bk")).reshape(T, nkv, d)
         v = linear(h1, blk["wv"], qkvb("bv")).reshape(T, nkv, d)
-        if cfg.positions == "rotary":
+        if cfg.qk_norm:  # over each head's d, one gain vector for all heads
+            q = pre_norm(q, blk["q_norm_scale"])
+            k = pre_norm(k, blk["k_norm_scale"])
+        if kind in ropes:  # a layer kind without rope carries no position at all
+            sin, cos = ropes[kind]
             q = apply_rope(q[None], sin, cos)[0]
             k = apply_rope(k[None], sin, cos)[0]
 
@@ -166,49 +177,77 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
             scales = dict(scales, pos_ids=pid, mask=attn_mask, ctx_pos_ids=ctx_pos_ids)
         ctx = attend(q, k_flat, v_flat, tables_l, seq_idx, pos, **scales)
 
-        attn_out = linear(ctx.reshape(T, nq * d), blk["wo"], bias("bo"))
+        ctx = ctx.reshape(T, nq * d)
+        if cfg.attention_gate:
+            gate = linear(h1, blk["w_attn_gate"], None)
+            ctx = (ctx.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(ctx.dtype)
+        attn_out = linear(ctx, blk["wo"], bias("bo"))
+
+        def dense_mlp(h, w_up, w_gate, w_down, b_up=None, b_down=None):
+            up = linear(h, w_up, b_up)
+            act = mlp_activation(cfg, up, linear(h, w_gate, None)) if cfg.mlp == "swiglu" \
+                else mlp_activation(cfg, up)
+            return linear(act, w_down, b_down)
 
         def mlp(h):
             nonlocal stats
-            if moe is not None:
-                # the experts stay in the stacked arrays and the kernel reads
-                # layer l's out of them: ``blk`` holds no expert weights
-                out = moe(h, blk["gate_wg"], experts["moe_wi"], experts.get("moe_wg"),
-                          experts["moe_wo"], valid=valid, with_stats=stats is not None, layer=l)
-                if stats is not None:
-                    out, layer_stats = out
-                    stats = merge_routing_stats(stats, layer_stats)
-                return out
-            up = linear(h, blk["w_up"], bias("b_up"))
-            if cfg.mlp == "swiglu":
-                act = mlp_activation(cfg, up, linear(h, blk["w_gate"], None))
-            else:
-                act = mlp_activation(cfg, up)
-            return linear(act, blk["w_down"], bias("b_down"))
+            if "gate_wg" not in blk:  # a dense model, or a leading dense layer of a model with experts
+                return dense_mlp(h, blk["w_up"], blk.get("w_gate"), blk["w_down"], bias("b_up"), bias("b_down"))
+            # the experts stay in the stacked arrays and the kernel reads this
+            # layer's out of them: ``blk`` holds no routed expert's weights
+            out = moe(h, blk["gate_wg"], experts["moe_wi"], experts.get("moe_wg"),
+                      experts["moe_wo"], valid=valid, with_stats=stats is not None,
+                      layer=l - first_expert_layer, gate_bias=blk.get("gate_bias"))
+            if stats is not None:
+                out, layer_stats = out
+                stats = merge_routing_stats(stats, layer_stats)
+            if "shared_wi" in blk:  # every token's, whole on every chip of the group
+                out = out + dense_mlp(h, blk["shared_wi"], blk.get("shared_wg"), blk["shared_wo"])
+            return out
+
+        def post(y, name):  # the sandwich norm on a branch's output
+            return pre_norm(y, blk[name]) if cfg.post_norms else y
 
         if cfg.parallel_residual:  # GPT-J / NeoX / Falcon
             h2 = h1 if cfg.shared_ln else pre_norm(x, blk["ln2_scale"], blk.get("ln2_bias"))
             return x + attn_out + mlp(h2), k_flat, v_flat, ks_flat, vs_flat, stats
-        x = x + attn_out
+        x = x + post(attn_out, "ln1_post_scale")
         h2 = pre_norm(x, blk["ln2_scale"], blk.get("ln2_bias"))
-        return x + mlp(h2), k_flat, v_flat, ks_flat, vs_flat, stats
+        return x + post(mlp(h2), "ln2_post_scale"), k_flat, v_flat, ks_flat, vs_flat, stats
 
     k_flat = k_pool.reshape(flat_len, nkv, d)
     v_flat = v_pool.reshape(flat_len, nkv, d)
     ks_flat, vs_flat = k_scale, v_scale  # already [nkv, flat_len] or None
-    stats = jnp.zeros(2, jnp.int32) if moe_stats else None
+    stats = jnp.zeros(3, jnp.int32) if moe_stats else None
+    # what each stacked array is stacked over: the routed experts (read in
+    # place by the kernel) and the rest of an expert layer over the expert
+    # layers, the dense MLP over the dense layers, everything else over all
     expert_keys = ("moe_wi", "moe_wg", "moe_wo")
+    expert_layer_keys = ("gate_wg", "gate_bias", "shared_wi", "shared_wg", "shared_wo")
+    dense_layer_keys = ("w_up", "w_gate", "w_down", "b_up", "b_down")
+    first_expert_layer = cfg.moe_num_dense_layers if moe is not None else 0
+    mixed_mlp = first_expert_layer > 0
     experts = {k: v for k, v in params["blocks"].items() if k in expert_keys}
     per_layer = {k: v for k, v in params["blocks"].items() if k not in expert_keys}
+
+    def index_of(name, l):
+        """Layer ``l``'s index into the stacked array ``name``; None: it has none there."""
+        if mixed_mlp and name in expert_layer_keys:
+            return l - first_expert_layer if l >= first_expert_layer else None
+        if mixed_mlp and name in dense_layer_keys:
+            return l if l < first_expert_layer else None
+        return l
+
     if unroll and L <= 48:
         for l in range(L):
-            blk_l = jax.tree_util.tree_map(lambda a: a[l], per_layer)
+            blk_l = {name: jax.tree_util.tree_map(lambda a: a[i], stacked)
+                     for name, stacked in sorted(per_layer.items()) if (i := index_of(name, l)) is not None}
             x, k_flat, v_flat, ks_flat, vs_flat, stats = layer(
                 x, blk_l, l, k_flat, v_flat, ks_flat, vs_flat, stats, cfg.layer_kind(l))
     else:
-        if cfg.per_layer_attention:
-            raise NotImplementedError("layer_types under lax.scan: one scan body has one window and "
-                                      "one rope; the ragged forward unrolls up to 48 layers")
+        if cfg.per_layer_attention or mixed_mlp:
+            raise NotImplementedError("layer_types or leading dense layers under lax.scan: one scan body has "
+                                      "one window, one rope and one MLP kind; the ragged forward unrolls up to 48 layers")
 
         def scan_body(carry, inp):
             blk, l = inp

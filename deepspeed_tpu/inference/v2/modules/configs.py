@@ -40,6 +40,7 @@ class DSEmbeddingsConfig(DSModuleConfig):
     embed_layernorm: bool = False
     norm: str = "layernorm"
     norm_eps: float = 1e-5
+    scale: float = 1.0  # factor on the token embedding (muP: sqrt(hidden_size))
     dtype: Any = jnp.bfloat16
 
 
@@ -69,4 +70,14 @@ class DSMoEConfig(DSModuleConfig):
     top_k: int = 1
     activation: str = "swiglu"
     norm_topk_prob: bool = True  # renormalise the top-k probabilities to sum to one
+    score_func: str = "softmax"  # 'softmax' over all experts | 'sigmoid', each expert's own
+    route_scale: float = 1.0  # factor on the kept weights
+    # expert parallelism's share: ``n_held`` of the ``n_experts`` the router
+    # scores live here, from ``first_expert`` on; None = all of them
+    n_held: Optional[int] = None
+    first_expert: int = 0
     dtype: Any = jnp.bfloat16
+
+    @property
+    def held(self) -> int:
+        return self.n_experts if self.n_held is None else self.n_held

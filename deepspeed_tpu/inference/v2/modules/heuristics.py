@@ -17,7 +17,7 @@ Auto policy:
 - embedding / unembed / norm: the single TPU implementation each (XLA fuses
   what the reference ships as kernel variants);
 - moe (models with experts only): the grouped ragged matmul, the one serving
-  MoE path.
+  MoE path; it is told the router's rule and which experts are held here.
 
 A model whose layers differ in attention kind (``layer_types``) gets one
 attention module a kind: ``attention`` serves the window layers,
@@ -95,14 +95,16 @@ def build_modules(model_config, engine_config, use_pallas: bool = False) -> dict
         extra["moe"] = DSMoERegistry.instantiate_config(ConfigBundle(
             name="grouped_gemm_moe", config=DSMoEConfig(
                 n_experts=mc.moe_num_experts, top_k=mc.moe_top_k, activation=mc.mlp,
-                norm_topk_prob=mc.moe_norm_topk_prob, dtype=dt)))
+                norm_topk_prob=mc.moe_norm_topk_prob, score_func=mc.moe_score_func,
+                route_scale=mc.moe_route_scale, n_held=mc.moe_experts_held,
+                first_expert=mc.moe_first_expert, dtype=dt)))
     return {
         **extra,
         "attention": attention(mc.sliding_window),
         "linear": instantiate_linear(DSLinearConfig(dtype=dt), engine_config),
         "embedding": instantiate_embed(DSEmbeddingsConfig(
             positions=mc.positions, embed_layernorm=mc.embed_layernorm, norm=mc.norm,
-            norm_eps=mc.norm_eps, dtype=dt), engine_config),
+            norm_eps=mc.norm_eps, scale=getattr(mc, "embed_scale", 1.0), dtype=dt), engine_config),
         "unembed": instantiate_unembed(DSUnembedConfig(
             tie_embeddings=mc.tie_embeddings, norm=mc.norm, norm_eps=mc.norm_eps,
             dtype=dt), engine_config),

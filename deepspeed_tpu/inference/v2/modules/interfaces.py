@@ -139,10 +139,13 @@ class DSPreNormRegistry(DSModuleRegistryBase):
 
 class DSMoEBase(DSModuleBase):
     """``__call__(x, gate_w, expert_up, expert_gate, expert_down, valid=None,
-    with_stats=False, layer=None)`` → [T, H] token-level top-k routed expert MLP
-    (reference ``interfaces/moe_base.py``); with ``with_stats`` also int32
-    ``[experts_hit, expert_load_max]``. ``padded_rows(tokens)``: the rows its
-    kernel computes for a bucket of that many tokens."""
+    with_stats=False, layer=None, gate_bias=None)`` → [T, H] token-level top-k
+    routed expert MLP (reference ``interfaces/moe_base.py``), the part of it
+    that the experts held here give; with ``with_stats`` also int32
+    ``[experts_hit, expert_load_max, slots]`` over them. ``padded_rows(tokens)``:
+    the rows its kernel's grid covers for a bucket of that many tokens. How
+    many slots land on the experts held here is data, not a function of the
+    bucket: the program counts them (``slots``)."""
 
     @staticmethod
     def config_class() -> Type[DSModuleConfig]:
@@ -150,7 +153,7 @@ class DSMoEBase(DSModuleBase):
 
     @abstractmethod
     def __call__(self, x, gate_w, expert_up, expert_gate, expert_down, valid=None,
-                 with_stats: bool = False, layer=None):
+                 with_stats: bool = False, layer=None, gate_bias=None):
         ...
 
     def padded_rows(self, tokens: int) -> int:

@@ -10,3 +10,4 @@ from .gptj import gptj, gptj_config
 from .gpt_neox import gpt_neox, gpt_neox_config
 from .falcon import falcon, falcon_config
 from .mellum import mellum, mellum_config
+from .trinity import trinity, trinity_config

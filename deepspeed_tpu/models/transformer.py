@@ -115,6 +115,38 @@ class TransformerConfig:
     moe_dropless: bool = False
     # dropless routing: renormalise the top-k probabilities to sum to one
     moe_norm_topk_prob: bool = True
+    # The fields below state what a family adds to the dropless expert layer
+    # and to the block; their defaults leave every other family as it is.
+    # The first ``moe_num_dense_layers`` layers of a model with experts carry
+    # the dense MLP of width ``intermediate_size``, the rest the experts
+    moe_num_dense_layers: int = 0
+    # shared experts of width ``expert_size`` each, run on every token beside
+    # the routed ones (one SwiGLU of their summed width)
+    moe_num_shared_experts: int = 0
+    # the router's score over all ``moe_num_experts``: 'softmax', or 'sigmoid'
+    # (each expert's own; with ``moe_route_bias`` the top-k is taken of score +
+    # a per-expert float32 bias no gradient trains, and the weights are the
+    # scores alone); the kept weights are multiplied by ``moe_route_scale``
+    moe_score_func: str = "softmax"
+    moe_route_bias: bool = False
+    moe_route_scale: float = 1.0
+    # expert parallelism's share: of the ``moe_num_experts`` routed experts the
+    # router scores, ``moe_experts_held`` live here, from ``moe_first_expert``
+    # on; an assignment to another expert is computed by the chip that holds
+    # it and takes no row here. None = all of them
+    moe_experts_held: Optional[int] = None
+    moe_first_expert: int = 0
+    # RMSNorm over each head's ``head_dim`` on q and on k (one gain vector
+    # shared by the heads), before rope
+    qk_norm: bool = False
+    # attention's output times sigmoid(h W_gate), elementwise, before W_o
+    attention_gate: bool = False
+    # sandwich norm: a second norm on each branch's output before it is added
+    post_norms: bool = False
+    # the attention kinds whose layers rotate q and k; None = every layer
+    rope_layer_types: Optional[Tuple[str, ...]] = None
+    # factor on the token embedding (muP: sqrt(hidden_size))
+    embed_scale: float = 1.0
     # ZeRO++ qwZ (reference partition_parameters.py:1139 quantized all-gather
     # handles): when set (by the engine, from zero_quantized_weights), the
     # per-layer stage-3 weight gathers inside the scan body travel as int8
@@ -133,6 +165,16 @@ class TransformerConfig:
         if self.moe_num_experts > 0 and self.moe_top_k > 2 and not self.moe_dropless:
             raise ValueError(f"moe_top_k={self.moe_top_k}: the capacity gates are top-1 and top-2 only; "
                              "set moe_dropless=True for top-k routing without dropped tokens")
+        if self.moe_score_func not in ("softmax", "sigmoid"):
+            raise ValueError(f"moe_score_func {self.moe_score_func!r}: 'softmax' or 'sigmoid'")
+        if self.moe_num_experts > 0:
+            first, held = self.moe_first_expert, self.experts_held
+            if not (1 <= held and 0 <= first and first + held <= self.moe_num_experts):
+                raise ValueError(f"experts [{first}, {first + held}) held of {self.moe_num_experts}")
+            if not 0 <= self.moe_num_dense_layers < self.num_layers:
+                raise ValueError(f"moe_num_dense_layers={self.moe_num_dense_layers} of {self.num_layers} layers")
+        if self.rope_layer_types is not None:
+            self.rope_layer_types = tuple(self.rope_layer_types)
         if self.layer_types is not None:
             self.layer_types = tuple(self.layer_types)
             unknown = set(self.layer_types) - {"sliding_attention", "full_attention"}
@@ -154,7 +196,7 @@ class TransformerConfig:
                 raise NotImplementedError(
                     "sparse_attention requires num_kv_heads == num_heads (MHA) — reject at "
                     "config time rather than deep inside the first jitted forward")
-        assert self.hidden_size % self.num_heads == 0
+        assert self.head_size is not None or self.hidden_size % self.num_heads == 0
         assert self.num_heads % self.num_kv_heads == 0
 
     @property
@@ -182,6 +224,36 @@ class TransformerConfig:
         """Window and rope are a layer's own, so no one scan body serves all."""
         return self.layer_types is not None
 
+    @property
+    def num_expert_layers(self) -> int:
+        return self.num_layers - self.moe_num_dense_layers if self.moe_num_experts > 0 else 0
+
+    @property
+    def experts_held(self) -> int:
+        """Routed experts whose weights live here (all, unless a share is stated)."""
+        return self.moe_num_experts if self.moe_experts_held is None else self.moe_experts_held
+
+    @property
+    def unscannable(self) -> Tuple[str, ...]:
+        """What keeps ONE scanned block from serving every layer, by name."""
+        why = []
+        if self.layer_types is not None:
+            why.append(f"layer_types gives each layer its own window and rope ({sorted(set(self.layer_types))})")
+        if self.moe_num_experts > 0 and self.moe_num_dense_layers > 0:
+            why.append(f"{self.moe_num_dense_layers} leading dense layer(s) before the expert layers: two MLP kinds")
+        if self.experts_held != self.moe_num_experts:
+            why.append(f"a share of the experts ({self.experts_held} of {self.moe_num_experts}) without its exchange")
+        for flag, what in ((self.moe_num_shared_experts > 0, "a shared expert"),
+                           (self.moe_score_func != "softmax" or self.moe_route_bias or self.moe_route_scale != 1.0,
+                            "sigmoid / biased / scaled routing"),
+                           (self.qk_norm, "a q/k norm"), (self.attention_gate, "gated attention"),
+                           (self.post_norms, "norms after attention and MLP"),
+                           (self.rope_layer_types is not None, "rope in some layer kinds only"),
+                           (self.embed_scale != 1.0, "a scaled embedding")):
+            if flag:
+                why.append(what)
+        return tuple(why)
+
 
 # ---------------------------------------------------------------------------
 # Param init
@@ -192,30 +264,59 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
     L, H, F = cfg.num_layers, cfg.hidden_size, cfg.intermediate_size
     nq, nkv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     k = jax.random.split(rng, 12)
+    extra = partial(jax.random.fold_in, k[11])  # keys of what PR 31 added: the first eleven draw what they drew
+    Ld = cfg.moe_num_dense_layers if cfg.moe_num_experts > 0 else L  # layers with the dense MLP
+    Le = L - Ld  # layers with experts: their arrays are stacked over these alone
 
     def dense_init(key, shape, fan_in):
         return (jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in))
 
+    def gain(key, shape):
+        """A norm's gain: one, unless the family's extra norms are on, whose
+        gains are drawn about one so that leaving a norm out shows."""
+        if not (cfg.post_norms or cfg.qk_norm):
+            return jnp.ones(shape, jnp.float32)
+        return 1.0 + 0.1 * jax.random.normal(key, shape, jnp.float32)
+
     blocks = {
-        "ln1_scale": jnp.ones((L, H), jnp.float32),
+        "ln1_scale": gain(extra(12), (L, H)),
         "wq": dense_init(k[0], (L, H, nq * d), H),
         "wk": dense_init(k[1], (L, H, nkv * d), H),
         "wv": dense_init(k[2], (L, H, nkv * d), H),
         "wo": dense_init(k[3], (L, nq * d, H), nq * d) / math.sqrt(2 * L),
-        "ln2_scale": jnp.ones((L, H), jnp.float32),
+        "ln2_scale": gain(extra(13), (L, H)),
     }
-    if cfg.moe_num_experts > 0:
-        E, Fe = cfg.moe_num_experts, cfg.expert_size
-        blocks["gate_wg"] = dense_init(k[4], (L, H, E), H)
-        blocks["moe_wi"] = dense_init(k[5], (L, E, H, Fe), H)
-        blocks["moe_wo"] = dense_init(k[6], (L, E, Fe, H), Fe) / math.sqrt(2 * L)
+    if cfg.attention_gate:
+        blocks["w_attn_gate"] = dense_init(extra(11), (L, H, nq * d), H)
+    if cfg.qk_norm:
+        blocks["q_norm_scale"] = gain(extra(14), (L, d))
+        blocks["k_norm_scale"] = gain(extra(15), (L, d))
+    if cfg.post_norms:
+        blocks["ln1_post_scale"] = gain(extra(16), (L, H))
+        blocks["ln2_post_scale"] = gain(extra(17), (L, H))
+    if Le > 0:
+        # the router scores all E experts; the weights of those held here
+        # alone are allocated, and none for a dense layer
+        E, Eh, Fe = cfg.moe_num_experts, cfg.experts_held, cfg.expert_size
+        blocks["gate_wg"] = dense_init(k[4], (Le, H, E), H)
+        blocks["moe_wi"] = dense_init(k[5], (Le, Eh, H, Fe), H)
+        blocks["moe_wo"] = dense_init(k[6], (Le, Eh, Fe, H), Fe) / math.sqrt(2 * L)
         if cfg.mlp == "swiglu":
-            blocks["moe_wg"] = dense_init(k[10], (L, E, H, Fe), H)
-    else:
-        blocks["w_up"] = dense_init(k[4], (L, H, F), H)
-        blocks["w_down"] = dense_init(k[5], (L, F, H), F) / math.sqrt(2 * L)
+            blocks["moe_wg"] = dense_init(k[10], (Le, Eh, H, Fe), H)
+        if cfg.moe_route_bias:
+            # of the order of the gap between the k-th and the next score
+            blocks["gate_bias"] = 0.01 * jax.random.normal(extra(18), (Le, E), jnp.float32)
+        if cfg.moe_num_shared_experts > 0:
+            Fs = cfg.moe_num_shared_experts * Fe
+            blocks["shared_wi"] = dense_init(extra(19), (Le, H, Fs), H)
+            blocks["shared_wo"] = dense_init(extra(20), (Le, Fs, H), Fs) / math.sqrt(2 * L)
+            if cfg.mlp == "swiglu":
+                blocks["shared_wg"] = dense_init(extra(21), (Le, H, Fs), H)
+    if Ld > 0:
+        blocks["w_up"] = dense_init(k[4] if Le == 0 else extra(23), (Ld, H, F), H)
+        blocks["w_down"] = dense_init(k[5] if Le == 0 else extra(24), (Ld, F, H), F) / math.sqrt(2 * L)
         if cfg.mlp == "swiglu":
-            blocks["w_gate"] = dense_init(k[6], (L, H, F), H)
+            blocks["w_gate"] = dense_init(k[6] if Le == 0 else extra(25), (Ld, H, F), H)
     if cfg.parallel_residual and cfg.shared_ln:
         del blocks["ln2_scale"]  # single pre-norm feeds both branches
     if cfg.norm == "layernorm":
@@ -234,7 +335,7 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
     params = {
         "embed": {"embedding": jax.random.normal(k[7], (cfg.vocab_size, H), jnp.float32) * 0.02},
         "blocks": blocks,
-        "final_norm": {"scale": jnp.ones((H, ), jnp.float32)},
+        "final_norm": {"scale": gain(extra(22), (H, ))},
     }
     if cfg.norm == "layernorm":
         params["final_norm"]["bias"] = jnp.zeros((H, ), jnp.float32)
@@ -746,13 +847,14 @@ def _remat_policy(name: str):
 
 def _refuse_mixed_layers(cfg: TransformerConfig, what: str):
     """The whole-sequence paths scan ONE block over the stacked layers, with
-    one window and one rope table; a model whose layers differ in either is
-    served by the ragged path (inference/v2), which unrolls them."""
-    if cfg.per_layer_attention:
+    one window, one rope table and one MLP kind, and know the block the
+    training families share; a model whose layers differ, or whose block has
+    what ``cfg.unscannable`` names, is served by the ragged path
+    (inference/v2), which unrolls them."""
+    if cfg.unscannable:
         raise NotImplementedError(
-            f"{what}: layer_types gives each layer its own window and rope "
-            f"({sorted(set(cfg.layer_types))}); the scanned block has one of each. Serve this "
-            "model through InferenceEngineV2 (ragged_forward)")
+            f"{what}: the scanned block has one window, one rope and one MLP kind, and this model has "
+            f"{'; '.join(cfg.unscannable)}. Serve this model through InferenceEngineV2 (ragged_forward)")
 
 
 def forward_hidden(cfg: TransformerConfig, params: Dict[str, Any], input_ids: jax.Array, rng=None,

@@ -50,24 +50,52 @@ def pick_block_rows(slots: int, num_experts: int) -> int:
     return min(128, max(8, 1 << (mean2 - 1).bit_length()))
 
 
-def route_topk(x, gate_w, top_k: int, renormalise: bool = True):
-    """Dropless routing: softmax over ALL experts in float32 (the router's
-    matmul too, at the highest precision: the top-k set is a discontinuous
-    function of these logits), the ``top_k`` largest, renormalised to sum to
-    one if asked. x: [S, H]; gate_w: [H, E]. Returns (idx [S, k] int32,
-    weights [S, k] float32)."""
+def route_topk(x, gate_w, top_k: int, renormalise: bool = True, score_func: str = "softmax",
+               bias=None, scale: float = 1.0):
+    """Dropless routing: every expert's score in float32 (the router's matmul
+    too, at the highest precision: the top-k set is a discontinuous function
+    of these logits), the ``top_k`` largest, renormalised to sum to one if
+    asked. ``score_func`` 'softmax': over ALL experts; 'sigmoid': each
+    expert's own. ``bias`` [E] (float32, trained by no gradient): the chosen
+    set is the top-k of score + bias, and the weights are the SCORES at the
+    chosen experts, without it. ``scale``: a factor on the kept weights.
+    x: [S, H]; gate_w: [H, E]. Returns (idx [S, k] int32, weights [S, k]
+    float32)."""
     logits = jnp.einsum("sh,he->se", x.astype(jnp.float32), gate_w.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
-    top_p, top_idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    scores = jax.nn.sigmoid(logits) if score_func == "sigmoid" else jax.nn.softmax(logits, axis=-1)
+    if bias is None:
+        top_p, top_idx = jax.lax.top_k(scores, top_k)
+    else:
+        _, top_idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        top_p = jnp.take_along_axis(scores, top_idx, axis=-1)
     if renormalise:
-        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        total = jnp.sum(top_p, axis=-1, keepdims=True)
+        # sigmoid scores can all be tiny where softmax's largest k cannot
+        top_p = top_p / (total + 1e-20 if score_func == "sigmoid" else total)
+    if scale != 1.0:
+        top_p = top_p * scale
     return top_idx.astype(jnp.int32), top_p
 
 
+def hold_experts(top_idx, top_w, first: int, held: int):
+    """Expert parallelism's share of a routing over all experts: the experts
+    ``[first, first + held)`` live here. A chosen index becomes its local
+    one; an assignment to an absent expert becomes index ``held`` (an expert
+    that does not exist: :func:`block_align_dispatch` gives it no row and no
+    block) with weight zero, so its term is left out of the token's sum (the
+    chip that holds that expert computes it). Nothing stands in for the
+    exchange that would carry it there."""
+    local = top_idx - first
+    here = (local >= 0) & (local < held)
+    return jnp.where(here, local, held).astype(jnp.int32), jnp.where(here, top_w, jnp.zeros_like(top_w))
+
+
 def merge_routing_stats(a, b):
-    """``[experts_hit, expert_load_max]`` of two calls together (layers of a
-    forward, steps of a decode): the hits add, the largest load stays."""
-    return jnp.stack([a[0] + b[0], jnp.maximum(a[1], b[1])])
+    """``[experts_hit, expert_load_max, slots]`` of two calls together
+    (layers of a forward, steps of a decode): the hits and the slots that took
+    a row add, the largest load stays."""
+    return jnp.stack([a[0] + b[0], jnp.maximum(a[1], b[1]), a[2] + b[2]])
 
 
 def padded_rows(slots: int, num_experts: int, block_rows: int, cover_all_experts: bool) -> int:
@@ -97,7 +125,7 @@ def block_align_dispatch(top_idx, top_w, num_experts: int, block_rows: int, vali
     (flat_tok [S*k], flat_w [S*k], dest [S*k] (``T_pad`` = no row)), then
     block_expert [T_pad//block_rows], T_pad, n_live_blocks, sizes [E]."""
     (S, top_k), E = top_idx.shape, num_experts
-    flat_e = top_idx.reshape(-1).astype(jnp.int32)
+    flat_e = top_idx.reshape(-1).astype(jnp.int32)  # index E: no such expert, so no row (``hold_experts``)
     flat_w = top_w.reshape(-1)
     flat_tok = jnp.arange(S * top_k, dtype=jnp.int32) // top_k
     if valid is not None:
@@ -130,7 +158,7 @@ def block_align_dispatch(top_idx, top_w, num_experts: int, block_rows: int, vali
 def grouped_moe_ffn(x, top_idx, top_w, wi, wo, wg=None,
                     activation: Optional[Callable] = None, interpret: Optional[bool] = None,
                     valid=None, differentiable: bool = True,
-                    with_stats: bool = False, layer=None):
+                    with_stats: bool = False, layer=None, expected_slots: Optional[int] = None):
     """x: [S, M] tokens; ``top_idx``/``top_w`` [S, k]: each token's experts
     and their weights (a zero weight = a dropped assignment); wi:
     [E, M, F]; wg: optional swiglu gate weights [E, M, F]; wo: [E, F, M].
@@ -143,14 +171,18 @@ def grouped_moe_ffn(x, top_idx, top_w, wi, wo, wg=None,
     sliced ``wi[layer]`` handed to a Pallas call would first be COPIED, a
     layer's experts written and read once more in every step.
 
-    The row block is :func:`pick_block_rows` of the slot count; ``interpret``
+    The row block is :func:`pick_block_rows` of the slot count, or of
+    ``expected_slots`` where the experts here are a share of those routed over
+    (:func:`hold_experts`: most slots then take no row, the buffer is still
+    sized for all of them); ``interpret``
     defaults to whether the backend is not a TPU. ``valid`` [S]: padding
     tokens route nowhere. ``differentiable=False`` (serving): experts without
     a slot take no row block, so a step reads only the experts it hit.
 
     Returns y [S, M] = sum over kept assignments of w * FFN_e(x) — the same
     quantity the einsum combine computes; with ``with_stats`` also int32
-    ``[experts_hit, expert_load_max]`` of this call's routing.
+    ``[experts_hit, expert_load_max, slots]`` of this call's routing: experts
+    with a slot, the most slots on one, and the slots that took a row.
     """
     from ..ops.pallas.grouped_matmul import gmm, grouped_matmul
 
@@ -161,7 +193,7 @@ def grouped_moe_ffn(x, top_idx, top_w, wi, wo, wg=None,
         # layer's experts alone, not the stack
         wi, wo, wg = (w if w is None else w[layer] for w in (wi, wo, wg))
         layer = None
-    block_rows = pick_block_rows(top_idx.size, E)
+    block_rows = pick_block_rows(top_idx.size if expected_slots is None else expected_slots, E)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if activation is None:
@@ -189,5 +221,5 @@ def grouped_moe_ffn(x, top_idx, top_w, wi, wo, wg=None,
     y_slots = y_sorted[jnp.minimum(dest, T_pad - 1)] * w_slot[:, None].astype(x.dtype)
     y = jnp.sum(y_slots.reshape(S, -1, M), axis=1)  # a token's k slots are adjacent
     if with_stats:
-        return y, jnp.stack([jnp.sum(sizes > 0), jnp.max(sizes)]).astype(jnp.int32)
+        return y, jnp.stack([jnp.sum(sizes > 0), jnp.max(sizes), jnp.sum(sizes)]).astype(jnp.int32)
     return y

@@ -89,7 +89,12 @@ def gmm(lhs, rhs, block_expert, block_t=128, block_k=None, block_n=None, interpr
     ``num_live``: int32 scalar, the row blocks that hold routed rows (the
     dispatcher's static bound ``T`` is for the worst routing). Blocks from
     there on compute nothing and come back zero; the dispatcher names the
-    last live block's expert for them, so they read no weights either.
+    last live block's expert for them and the weight tile's K index stays at
+    the last one that block fetched, so they read no weights either, however
+    many K tiles a matrix is cut into (a 3072 x 3072 matrix is three 6 MiB
+    tiles: walking them again for every dead block streamed a whole expert
+    a block, three times the live bytes where an eighth of the slots land
+    here: PERF.md section 6, PR 31).
 
     Registry tiles resolve HERE, outside the jit: resolving inside would key
     the compiled-executable cache on ``block_k=None`` and freeze the
@@ -144,7 +149,9 @@ def _gmm(lhs, rhs, block_expert, live, block_t, block_k, block_n, interpret):
         grid=(nt, nn, nk),
         in_specs=[
             pl.BlockSpec((bt, bk), lambda i, j, k, be, lv: (i, k)),
-            pl.BlockSpec((1, bk, bn), lambda i, j, k, be, lv: (be[i], k, j)),
+            # a dead row block keeps the tile the last live one fetched last: no DMA
+            pl.BlockSpec((1, bk, bn), lambda i, j, k, be, lv: (
+                be[i], jnp.where(i < lv[0], k, nk - 1), jnp.where(i < lv[0], j, nn - 1))),
         ],
         out_specs=pl.BlockSpec((bt, bn), lambda i, j, k, be, lv: (i, j)),
         scratch_shapes=[pltpu.VMEM((bt, bn), jnp.float32)],

@@ -198,7 +198,7 @@ def test_v2_grouped_gemm_moe_matches_dense_expert_loop(tokens, block_rows):
     ref = _dense_expert_loop(x, top_idx, top_w, up, gt, down)
     np.testing.assert_allclose(np.asarray(y)[live], ref[live], rtol=2e-4, atol=2e-4)
     assert not np.asarray(y)[~live].any(), "a padding token has no expert output"
-    assert np.asarray(stats).tolist() == [int((counts > 0).sum()), int(counts.max())]
+    assert np.asarray(stats).tolist() == [int((counts > 0).sum()), int(counts.max()), int(counts.sum())]
 
 
 def test_transformer_dropless_topk_forward_and_grad():

@@ -363,13 +363,15 @@ def _paged_reference_by_run(q, k_pool, v_pool, tables, rows, bs, window):
             qq = q[t0 + c0:t0 + c1].astype(jnp.float32).reshape(c1 - c0, nkv, nq // nkv, d) / np.sqrt(d)
             p = jnp.arange(before + c0, before + c1)[:, None]
             s = jnp.einsum("tngd,cnd->tngc", qq, k, precision="highest")
-            vis = (ctx <= p) & (p - ctx < window)
+            vis = (ctx <= p) if window is None else (ctx <= p) & (p - ctx < window)
             w = jax.nn.softmax(jnp.where(vis[:, None, None, :], s, -1e30), axis=-1)
             outs.append(jnp.einsum("tngc,cnd->tngd", w, v, precision="highest").reshape(c1 - c0, nq, d))
         t0 += new
     return jnp.concatenate(outs, 0)
 
 
+@pytest.mark.parametrize("nq,window", [(32, 4096), (48, 4096), (48, None)],
+                         ids=["group4.window", "group6.window", "group6.full"])
 @pytest.mark.parametrize("name,T,S,rows,want", [
     # mistral-7b.longprompt: the last 2,048-token chunk of an 8,192-token prompt
     ("longprompt", 2048, 8, [(6144, 2048)], (128, "heuristic:long_rows")),
@@ -379,16 +381,21 @@ def _paged_reference_by_run(q, k_pool, v_pool, tables, rows, bs, window):
      (128, "heuristic:long_rows")),
     # mistral-7b.chat: a SplitFuse put of 20 one-token rows and a 490-token chunk
     ("chat", 512, 32, [(100 + 59 * i, 1) for i in range(20)] + [(0, 490)], (32, "heuristic:short_rows")),
+    # trinity-large-preview.decode-heavy-64: a 40-token chunk past the window beside 6 decode rows (64 tokens x 8 rows),
+    # and a 512-token put of 60 decode rows and a 440-token chunk (512 x 64)
+    ("put_64x8", 64, 8, [(300 + 700 * i, 1) for i in range(6)] + [(4200, 40)], (16, "heuristic:short_rows")),
+    ("put_512x64", 512, 64, [(262 + 29 * i, 1) for i in range(60)] + [(100, 440)], (16, "heuristic:short_rows")),
 ])
-def test_paged_q_tiled_at_the_serving_cells_shapes(name, T, S, rows, want):
+def test_paged_q_tiled_at_the_serving_cells_shapes(name, T, S, rows, want, nq, window):
     """``paged_attention`` as the serving engine calls it, at the shapes of
-    the benchmark's two serving cells (32/8 heads of 128, 128-token blocks,
-    tables 65 wide, window 4,096, bf16): the tile the heuristic picks, against
+    the benchmark's serving cells (32/8 heads of 128, and 48/8, a GQA group of
+    6 that is no multiple of the 8 sublanes: PR 31; 128-token blocks, tables 65
+    wide, window 4,096 or none, bf16): the tile the heuristic picks, against
     a float32 reference. A kernel that does not fit VMEM fails to compile
     here, loudly."""
     from deepspeed_tpu.ops.pallas import paged_attention as pa
 
-    nq, nkv, d, bs, mb, window, n_blocks = 32, 8, 128, 128, 65, 4096, 619
+    nkv, d, bs, mb, n_blocks = 8, 128, 128, 65, 619
     rng = np.random.default_rng(25)
     k_pool = jnp.asarray(rng.normal(size=(n_blocks * bs, nkv, d)), jnp.bfloat16)
     v_pool = jnp.asarray(rng.normal(size=(n_blocks * bs, nkv, d)), jnp.bfloat16)
@@ -558,6 +565,7 @@ def _chunks(*runs):
 
 
 _CELL_CONTEXTS = [262 + 57 * i for i in range(31)]
+_CELL_CONTEXTS_64 = [262 + 29 * i for i in range(60)] + [4097, 4500, 5000]
 # the inputs the deleted per-token grid served (PR 29), by the rule that now hands them to the decode kernel
 _SHORT_TABLE = _decode_rows([1 + (37 * i) % 512 for i in range(31)])                      # (a) 32 rows, 4 columns
 _VERIFY = _chunks(*[(r, 100 + 251 * r, 5) for r in range(8)])                             # (b) 8 rows x 5 tokens
@@ -574,6 +582,10 @@ _PUT_32x8 = _chunks((0, 2980, 20)) + [(1, 301), (2, 555), (3, 790), (4, 999)] + 
     ("mellum2.window", 32, 4, 1024, 31, 65, 619, _decode_rows(_CELL_CONTEXTS), "heuristic:long_table"),
     ("mellum2.full", 32, 4, None, 31, 65, 619, _decode_rows(_CELL_CONTEXTS), "heuristic:long_table"),
     # one kv head a query head, a table it fills: one 1 MiB block a step, one long row beside short ones
+    # trinity-large-preview.decode-heavy-64: 48/8 heads (a group of 6), 64 rows, contexts on both sides of the window
+    ("trinity.window", 48, 8, 4096, 63, 65, 619, _decode_rows(_CELL_CONTEXTS_64), "heuristic:long_table"),
+    ("trinity.full", 48, 8, None, 63, 65, 619, _decode_rows(_CELL_CONTEXTS_64), "heuristic:long_table"),
+    ("trinity.put_32x8.window", 48, 8, 4096, 8, 65, 619, _PUT_32x8, "heuristic:multi_token"),
     ("mha16", 16, 16, None, 4, 16, 64, _decode_rows([2048, 131, 657, 3]), "heuristic:long_table"),
     ("short_table", 32, 8, None, 31, 4, 619, _SHORT_TABLE, "heuristic:short_table"),
     ("short_table.window", 32, 8, 256, 31, 4, 619, _SHORT_TABLE, "heuristic:short_table"),
