@@ -37,6 +37,7 @@ import gc
 import http.client
 import json
 import os
+import re
 import sys
 import threading
 import time
@@ -93,10 +94,11 @@ class Smoke:
             dtype=jnp.float32 if self.rehearsal else jnp.bfloat16,
             attention_impl="flash", **extra)
 
-    def check_kernels(self, what: str, lowered, names):
-        """The named Pallas kernels are in the program XLA compiled. Off the
-        chip the program is only lowered: a CPU program holds no Mosaic call
-        to find, so compiling it again would prove nothing."""
+    def check_kernels(self, what: str, lowered, names, narrow=()):
+        """The named Pallas kernels are in the program XLA compiled, and no
+        array in it has one of the minor dimensions ``narrow``. Off the chip
+        the program is only lowered: a CPU program holds no Mosaic call to
+        find, so compiling it again would prove nothing."""
         text = lowered.as_text()
         if self.rehearsal:
             self.say(f"{what}: lowered; kernel presence is a chip-only check")
@@ -106,6 +108,11 @@ class Smoke:
         self.check(all(calls.values()) and CUSTOM_CALL in compiled_text,
                    f"{what}: Pallas calls lowered {calls}, {compiled_text.count(CUSTOM_CALL)} x "
                    f"{CUSTOM_CALL} in the compiled program")
+        if narrow:
+            widths = "|".join(str(n) for n in narrow)
+            found = sorted(set(re.findall(rf"\b(?:f32|bf16)\[(?:\d+,)+(?:{widths})\]", compiled_text)))
+            self.check(not found, f"{what}: no array with a minor dimension of {' or '.join(map(str, narrow))} "
+                                  f"in the compiled program: {found}")
         return calls
 
 
@@ -299,7 +306,9 @@ def trainer_phase(smoke: Smoke):
     gas = s["global_batch"] // (s["micro"] * n)
     smoke.check(gas >= 1 and gas * s["micro"] * n == s["global_batch"],
                 f"global batch {s['global_batch']} = micro {s['micro']} x gas {gas} x {n} devices")
+    # a quarter of each head rotates, as in the Pythias the benchmark trains
     cfg = smoke.model_config(remat=True, remat_policy="save_only_these_names(attn_out)")
+    cfg = dataclasses.replace(cfg, rotary_dim=cfg.head_dim // 4)
     config = {
         "train_batch_size": s["global_batch"],
         "train_micro_batch_size_per_gpu": s["micro"],
@@ -340,7 +349,10 @@ def trainer_phase(smoke: Smoke):
                     f"training state is spread over all {len(in_use)} devices "
                     f"(min {min(in_use)}, max {max(in_use)} bytes in use, limit {limit})")
 
-    calls = smoke.check_kernels("train step", engine.aot_lower_train_step(s["seq"]), FLASH_KERNELS)
+    # partial rotary at the full width of the head: neither a half of the
+    # rotated lanes nor the pass-through lanes is an array of its own
+    calls = smoke.check_kernels("train step", engine.aot_lower_train_step(s["seq"]), FLASH_KERNELS,
+                                narrow=(cfg.rotary_dim // 2, cfg.head_dim - cfg.rotary_dim))
     if calls is not None:
         # the policy saves attn_out, which the kernel gives its output and its
         # log-sum-exp: a second flash_fwd means the remat recomputes it again

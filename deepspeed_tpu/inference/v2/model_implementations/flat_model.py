@@ -150,8 +150,8 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
             k = pre_norm(k, blk["k_norm_scale"])
         if kind in ropes:  # a layer kind without rope carries no position at all
             sin, cos = ropes[kind]
-            q = apply_rope(q[None], sin, cos)[0]
-            k = apply_rope(k[None], sin, cos)[0]
+            q = apply_rope(q[None], sin, cos, cfg.rotary_dim)[0]
+            k = apply_rope(k[None], sin, cos, cfg.rotary_dim)[0]
 
         # append this batch's KV to the paged pool (linear_blocked_kv_rotary);
         # in-place scatter on the scan carry at layer l's offset

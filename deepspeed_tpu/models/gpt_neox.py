@@ -4,6 +4,14 @@ Analog of the reference ``module_inject/containers/gptneox.py``: parallel
 residual with TWO pre-norms, partial rotary (rotary_pct, NeoX-half style),
 GELU, biases, untied embeddings, fused per-head query_key_value in HF
 checkpoints (split by the converter).
+
+``rotary_dim`` r is ``rotary_pct`` of the head's d (a quarter in the Pythias:
+16 of 64 at 410M, 32 of 128 at 1.4B). With r < d ``rope_table`` gives both
+tables at the full width of the head, ``[S, d]``: the signed sine ``[-sin,
+sin, 0 ... 0]`` and the cosine ``[cos, cos, 1 ... 1]`` over r/2, r/2 and d - r
+lanes, and ``apply_rope`` rotates every head as a whole (``x * cos +
+partner(x) * sin``, the lanes from r on taken from ``x``): no part of a head
+is sliced off or concatenated back, in the forward or in the backward.
 """
 
 from .transformer import TransformerConfig, TransformerLM

@@ -430,28 +430,99 @@ def rope_inv_freq(cfg: TransformerConfig, kind: Optional[str] = None) -> Tuple[n
 
 def rope_table(cfg: TransformerConfig, positions: jax.Array,
                kind: Optional[str] = None) -> Tuple[jax.Array, jax.Array]:
-    """sin and cos [S, d/2] at ``positions`` for attention kind ``kind``."""
+    """The two tables ``apply_rope`` takes, at ``positions`` for attention kind
+    ``kind``. With ``r = cfg.rotary_dim`` and ``d = cfg.head_dim``:
+
+    - ``r == d``: sin and cos, ``[S, d/2]`` each.
+    - ``r < d`` (partial rotary): both at the FULL width of a head, ``[S, d]``,
+      so that nothing narrower than a head exists where they are applied or
+      where they are made: the signed sine ``[-sin, sin, 0 ... 0]`` and the
+      cosine ``[cos, cos, 1 ... 1]`` (``r/2`` lanes, ``r/2`` lanes, ``d - r``
+      lanes), from the frequencies laid out twice with zeros behind them
+      (sin 0 = 0, cos 0 = 1). Made once a step and outside the layer scan."""
     inv_freq, scale = rope_inv_freq(cfg, kind)
-    freqs = jnp.einsum("s,f->sf", positions.astype(jnp.float32), jnp.asarray(inv_freq))
-    if scale != 1.0:
-        return jnp.sin(freqs) * scale, jnp.cos(freqs) * scale
-    return jnp.sin(freqs), jnp.cos(freqs)
+    half, rest = len(inv_freq), cfg.head_dim - 2 * len(inv_freq)
+    if rest == 0:
+        freqs = jnp.einsum("s,f->sf", positions.astype(jnp.float32), jnp.asarray(inv_freq))
+        if scale != 1.0:
+            return jnp.sin(freqs) * scale, jnp.cos(freqs) * scale
+        return jnp.sin(freqs), jnp.cos(freqs)
+    lanes = np.concatenate([inv_freq, inv_freq, np.zeros(rest, np.float32)])
+    sign = np.concatenate([np.full(half, -scale), np.full(half, scale), np.zeros(rest)]).astype(np.float32)
+    gain = np.concatenate([np.full(2 * half, scale), np.ones(rest)]).astype(np.float32)
+    freqs = jnp.einsum("s,f->sf", positions.astype(jnp.float32), jnp.asarray(lanes))
+    return jnp.sin(freqs) * sign, jnp.cos(freqs) * gain
 
 
-def apply_rope(x, sin, cos):
-    """x: [B, S, n, d]; sin/cos: [S, r/2] with r <= d (partial rotary, GPT-J
-    ``rotary_dim`` / NeoX ``rotary_pct``): the first r dims rotate in half
-    style, the rest pass through."""
-    r = 2 * sin.shape[-1]
+def _rope_partner(x, r):
+    """``x`` [.., d] with the two halves of its first ``r`` lanes swapped and
+    zeros beyond, in float32: ``x @ P`` for the 0/1 matrix of that permutation.
+    A product with 0 or 1 summed in float32 is exact in any dtype (``HIGHEST``:
+    a float32 ``x`` is not cut to bfloat16 first; a bfloat16 one is one pass
+    either way), and it runs on the MXU, so no lane of ``x`` is moved by a
+    slice, a concatenation or a gather of the head dim."""
+    d, h = x.shape[-1], r // 2
+    perm = np.zeros((d, d), np.float32)
+    perm[np.arange(h) + h, np.arange(h)] = 1.0
+    perm[np.arange(h), np.arange(h) + h] = 1.0
+    return jnp.einsum("...d,de->...e", x, jnp.asarray(perm, x.dtype), precision=lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32)
+
+
+def _rotate_full_width(x, sin, cos, r):
+    y = x.astype(jnp.float32) * cos[None, :, None, :] + _rope_partner(x, r) * sin[None, :, None, :]
+    # lanes at and beyond r are x itself: x * 1 + 0 * 0 would do for finite
+    # values and turn an infinity into a NaN
+    lane = lax.broadcasted_iota(jnp.int32, (x.shape[-1], ), 0)
+    return jnp.where(lane < r, y.astype(x.dtype), x)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, ))
+def _rope_full_width(x, sin, cos, r):
+    return _rotate_full_width(x, sin, cos, r)
+
+
+def _rope_full_width_fwd(x, sin, cos, r):
+    return _rotate_full_width(x, sin, cos, r), (sin, cos)
+
+
+def _rope_full_width_bwd(r, tables, g):
+    # the transpose of a rotation is the rotation back: the same expression on
+    # the cotangent with the sine negated, so the backward too permutes what
+    # arrives in x's dtype and never a float32 product. The tables are
+    # functions of the positions alone: no cotangent (None is a zero).
+    sin, cos = tables
+    return _rotate_full_width(g, -sin, cos, r), None, None
+
+
+_rope_full_width.defvjp(_rope_full_width_fwd, _rope_full_width_bwd)
+
+
+def apply_rope(x, sin, cos, rotary_dim=None):
+    """x: [B, S, n, d]; sin/cos: ``rope_table``'s pair for ``r = rotary_dim``
+    (None: ``d``). The first r dims rotate in half style (GPT-J ``rotary_dim``
+    / NeoX ``rotary_pct``), the rest pass through. ``r < d`` alone decides the
+    form:
+
+    - ``r == d``: tables ``[S, d/2]``, the two halves of a head rotated and
+      concatenated.
+    - ``r < d``: tables ``[S, d]`` (signed sine, cosine padded with ones) and
+      ``y = x * cos + partner(x) * sin`` at the full width of the head, the
+      pass-through lanes selected from ``x`` by a lane iota. No array narrower
+      than ``d`` exists in the forward or in the backward (which is the same
+      rotation turned back). Float32 arithmetic, cast to ``x.dtype`` at the
+      end, as the other form. An infinity anywhere in a head makes that head's
+      ROTATED lanes NaN (the partner is a matrix product); a pass-through lane
+      keeps its value whatever it is."""
     d = x.shape[-1]
-    xr = x[..., :r] if r < d else x
-    x1, x2 = jnp.split(xr.astype(jnp.float32), 2, axis=-1)
+    r = d if rotary_dim is None else rotary_dim
+    if r < d:
+        assert sin.shape[-1] == d, f"partial rotary ({r} of {d}) takes rope_table's full-width tables, got {sin.shape}"
+        return _rope_full_width(x, sin, cos, r)
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     sinb = sin[None, :, None, :]
     cosb = cos[None, :, None, :]
-    rot = jnp.concatenate([x1 * cosb - x2 * sinb, x2 * cosb + x1 * sinb], axis=-1).astype(x.dtype)
-    if r < d:
-        return jnp.concatenate([rot, x[..., r:]], axis=-1)
-    return rot
+    return jnp.concatenate([x1 * cosb - x2 * sinb, x2 * cosb + x1 * sinb], axis=-1).astype(x.dtype)
 
 
 def alibi_slopes(n_heads: int) -> np.ndarray:
@@ -667,8 +738,8 @@ def _attn_branch(cfg: TransformerConfig, layer, h, sin, cos):
     k = k.reshape(B, S, nkv, d)
     v = v.reshape(B, S, nkv, d)
     if cfg.positions == "rotary":
-        q = apply_rope(q, sin, cos)
-        k = apply_rope(k, sin, cos)
+        q = apply_rope(q, sin, cos, cfg.rotary_dim)
+        k = apply_rope(k, sin, cos, cfg.rotary_dim)
 
     if cfg.sequence_parallel:
         if cfg.sequence_parallel_impl == "ring":
@@ -1107,8 +1178,8 @@ def forward_with_cache(cfg: TransformerConfig, params, input_ids, cache):
         k = k.reshape(B, T, nkv, d)
         v = v.reshape(B, T, nkv, d)
         if cfg.positions == "rotary":
-            q = apply_rope(q, sin, cos)
-            k = apply_rope(k, sin, cos)
+            q = apply_rope(q, sin, cos, cfg.rotary_dim)
+            k = apply_rope(k, sin, cos, cfg.rotary_dim)
         ck = jax.lax.dynamic_update_slice_in_dim(ck, k.astype(ck.dtype), start, axis=1)
         cv = jax.lax.dynamic_update_slice_in_dim(cv, v.astype(cv.dtype), start, axis=1)
         ctx = _cached_attention(cfg, q, ck, cv, start, start + T)

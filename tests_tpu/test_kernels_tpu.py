@@ -692,27 +692,55 @@ def test_moe_serving_programs_of_every_bucket_pair_run_on_chip():
     assert len(ran) >= len(eng.batch.seq_buckets) and not any(r["cached"] for r in ran), ran
 
 
-def _flash_calls_in_compiled_train_step(head_dim, abstract=None):
-    """Compile value_and_grad of the model's loss for a 2-layer scanned,
-    rematerialised block and count each flash kernel's custom calls in the
-    text XLA compiled. ``abstract`` maps a ShapeDtypeStruct to one placed on a
-    described device (the sandbox's compile-only rehearsal); on the chip the
-    shapes compile for the attached device."""
+def _compiled_train_step_text(head_dim, abstract=None, rotary_dim=64):
+    """The text XLA compiled for value_and_grad of the model's loss with a
+    2-layer scanned, rematerialised block at a Pythia's shapes. ``abstract``
+    maps a ShapeDtypeStruct to one placed on a described device (the sandbox's
+    compile-only rehearsal); on the chip the shapes compile for the attached
+    device."""
     from deepspeed_tpu.models import TransformerLM, gpt_neox_config
 
     cfg = gpt_neox_config("pythia-1b", hidden_size=16 * head_dim, num_heads=16, num_kv_heads=16,
                           intermediate_size=4 * 16 * head_dim, num_layers=2, vocab_size=50304,
                           max_seq_len=2048, dtype=jnp.bfloat16, attention_impl="flash", remat=True,
-                          remat_policy="save_only_these_names(attn_out)")
+                          remat_policy="save_only_these_names(attn_out)", rotary_dim=rotary_dim)
     model = TransformerLM(cfg)
     params = jax.eval_shape(lambda k: model.init(k, None), jax.random.PRNGKey(0))
     ids = jax.ShapeDtypeStruct((2, 2048), jnp.int32)
     if abstract is not None:
         params, ids = jax.tree_util.tree_map(abstract, (params, ids))
     step = jax.jit(jax.value_and_grad(lambda p, i: model.loss(p, {"input_ids": i})))
-    text = step.lower(params, ids).compile().as_text()
+    return step.lower(params, ids).compile().as_text()
+
+
+def _flash_calls_in_compiled_train_step(head_dim, abstract=None):
+    """Each flash kernel's custom calls in that text, and all custom calls."""
+    text = _compiled_train_step_text(head_dim, abstract)
     calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
     return {k: sum(k in line for line in calls) for k in ("flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq")}, len(calls)
+
+
+def _arrays_narrower_than_a_head(text, head_dim, rotary_dim):
+    """Shapes in a compiled program whose minor dimension is a half of the
+    rotated lanes or the pass-through lanes of partial rotary."""
+    import re
+
+    narrow = "|".join(str(n) for n in (rotary_dim // 2, head_dim - rotary_dim))
+    return sorted(set(re.findall(rf"\b(?:f32|bf16)\[(?:\d+,)+(?:{narrow})\]", text)))
+
+
+@pytest.mark.parametrize("head_dim,rotary_dim", [(64, 16), (128, 32)])
+def test_train_step_of_partial_rotary_holds_nothing_narrower_than_a_head(head_dim, rotary_dim):
+    """Pythia-410m rotates 16 of 64 head dims, Pythia-1.4b 32 of 128. Until
+    PR 32 ``apply_rope`` sliced them off, split them into halves of 8 (16) and
+    concatenated twice, and the compiled step held float32 ``[2, 2048, 16, 8]``
+    arrays in 128-lane tiles, a sixth of the 410M step. At the full width of
+    the head no array has a minor dimension of ``r/2`` or ``d - r``: the
+    tables are made ``[S, d]`` and the partner is a matmul."""
+    text = _compiled_train_step_text(head_dim, rotary_dim=rotary_dim)
+    found = _arrays_narrower_than_a_head(text, head_dim, rotary_dim)
+    print(f"heads of {head_dim}, {rotary_dim} rotated: {found}")
+    assert "tpu_custom_call" in text and not found, found
 
 
 @pytest.mark.parametrize("head_dim", [64, 128])
