@@ -1,7 +1,7 @@
 """FastGen-equivalent inference v2 (reference ``deepspeed/inference/v2``):
 ragged continuous batching over a paged KV cache."""
 
-from .config_v2 import (CacheTelemetryConfig, DSStateManagerConfig, HostTierConfig,
+from .config_v2 import (CacheTelemetryConfig, DiffusionConfig, DSStateManagerConfig, HostTierConfig,
                         ModulesConfig, PrefixCacheConfig, RaggedInferenceEngineConfig,
                         SpeculativeConfig)
 from .engine_v2 import InferenceEngineV2

@@ -148,6 +148,31 @@ class SpeculativeConfig:
 
 
 @dataclass
+class DiffusionConfig:
+    """``ragged.diffusion`` block: how a model with ``diffusion_block_size``
+    unmasks a block (the block length itself is the model's: it is part of
+    its attention mask). Greedy: a position's token is its argmax and its
+    confidence the float32 softmax probability of that token.
+
+    ``remasking`` ``low_confidence_static``: denoise forward ``i`` of a block
+    unmasks the ``B // denoising_steps`` (one more in the first ``B %
+    denoising_steps`` forwards) masked positions of highest confidence;
+    ``low_confidence_dynamic``: every masked position whose confidence is
+    over ``confidence_threshold``, and at least the most confident one. The
+    last of the ``denoising_steps`` forwards unmasks whatever is left."""
+
+    denoising_steps: int = 4
+    remasking: str = "low_confidence_static"
+    confidence_threshold: float = 0.9
+
+    def __post_init__(self):
+        if self.remasking not in ("low_confidence_static", "low_confidence_dynamic"):
+            raise ValueError(f"remasking {self.remasking!r}: 'low_confidence_static' or 'low_confidence_dynamic'")
+        if self.denoising_steps < 1:
+            raise ValueError(f"denoising_steps must be positive, got {self.denoising_steps}")
+
+
+@dataclass
 class ModulesConfig:
     """Per-op implementation selection (reference ``modules/heuristics.py``
     config surface). Each slot is ``"auto"`` (heuristic pick), a registered
@@ -178,6 +203,8 @@ class RaggedInferenceEngineConfig:
     # speculative decoding (n-gram self-drafting or a draft model, batched
     # K-token verification with refcount-aware rollback)
     speculative: SpeculativeConfig = field(default_factory=SpeculativeConfig)
+    # unmasking schedule of a block-diffusion model; read by no other model
+    diffusion: DiffusionConfig = field(default_factory=DiffusionConfig)
     use_pallas_kernels: str = "auto"  # 'auto' | 'never' | 'always'
     # weight-only int8 (per-output-channel scales): halves the decode weight
     # stream, which is the bandwidth-bound term at serving batch sizes

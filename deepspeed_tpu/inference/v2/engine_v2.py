@@ -103,6 +103,17 @@ class InferenceEngineV2:
         self.params = params
 
         bs = ic.kv_block_size
+        # a block-diffusion model: ``decode`` advances whole blocks of this many
+        # tokens by masked diffusion (0: a causal model, one token a row a step)
+        self._block = int(getattr(mc, "diffusion_block_size", 0) or 0)
+        if self._block:
+            if bs % self._block:
+                raise ValueError(f"kv_block_size {bs} must hold whole diffusion blocks of {self._block}: the "
+                                 "prefix cache hashes KV blocks, and one may not end inside a block")
+            if getattr(ic.speculative, "enabled", False):
+                raise NotImplementedError(
+                    f"speculative decoding of a model with diffusion_block_size={self._block}: a draft is "
+                    "verified one causal token at a time, and this model has no causal next token")
         max_context = ic.state_manager.max_context
         model_max = getattr(mc, "max_seq_len", None)
         if model_max is not None and max_context > model_max:
@@ -123,6 +134,8 @@ class InferenceEngineV2:
             max_tracked_sequences=ic.state_manager.max_tracked_sequences,
             num_blocks=self.num_kv_blocks, block_size=bs, dtype=ic.kv_dtype,
             prefix_cache_config=ic.prefix_cache)
+        if self._block and self.state_manager.prefix_cache is not None:
+            self.state_manager.prefix_cache.token_quantum = self._block
         self.batch = RaggedBatchWrapper(
             max_ragged_batch_size=ic.state_manager.max_ragged_batch_size,
             max_ragged_sequence_count=ic.state_manager.max_ragged_sequence_count,
@@ -361,6 +374,10 @@ class InferenceEngineV2:
         # trim a repeat prompt down to one token, but it is still a prefill
         # step (and the hit is exactly what makes it worth recording)
         had_prefill = any(t.size > 1 for t in batch_tokens)
+        if self._block > 1 and any(t.size % self._block for t in batch_tokens):
+            raise ValueError(f"put(): chunks of {[int(t.size) for t in batch_tokens]} tokens; a model with "
+                             f"diffusion_block_size={self._block} is fed whole blocks (decode's first_tokens "
+                             "take a prompt's last partial block)")
         # span name as a two-literal conditional so check_goodput_taxonomy
         # can map both
         with tr.span("serving/prefill" if had_prefill else "serving/decode_step",
@@ -462,8 +479,10 @@ class InferenceEngineV2:
                 reg.histogram("serving/decode_step_ms").observe(dt_ms)
         return out
 
-    def _moe_span_args(self, tokens: int, t_bucket: int, steps: int, stats) -> dict:
-        """What a step span says of the expert layers. ``moe_slots_routed``:
+    def _moe_span_args(self, tokens: int, t_bucket: int, forwards: int, stats, kv_only_forwards: int = 0) -> dict:
+        """What a step span says of the expert layers over a call of
+        ``forwards`` forwards of ``tokens`` live tokens each, ``kv_only_forwards``
+        of which stop before the last layer's experts. ``moe_slots_routed``:
         live tokens x top-k x EXPERT layers (a leading dense layer routes
         nothing); ``moe_slots``: those of them that landed on experts held
         here and took a row (all, for a model that holds every expert; a
@@ -476,12 +495,12 @@ class InferenceEngineV2:
         the experts here of those the router scores. ``stats`` is the
         program's ``[experts_hit, expert_load_max, slots]``."""
         mc = self.model_config
-        layers = mc.num_expert_layers
+        layer_forwards = mc.num_expert_layers * forwards - kv_only_forwards
         return {"moe_slots": int(stats[2]),
-                "moe_slots_routed": tokens * mc.moe_top_k * layers,
-                "moe_rows": self._moe.padded_rows(t_bucket) * layers * steps,
+                "moe_slots_routed": tokens * mc.moe_top_k * layer_forwards,
+                "moe_rows": self._moe.padded_rows(t_bucket) * layer_forwards,
                 "experts_hit": int(stats[0]),
-                "experts_total": mc.experts_held * layers * steps,
+                "experts_total": mc.experts_held * layer_forwards,
                 "expert_load_max": int(stats[1]),
                 "experts_held": mc.experts_held, "experts_published": mc.moe_num_experts}
 
@@ -518,7 +537,7 @@ class InferenceEngineV2:
 
     # ------------------------------------------------------------------
     def decode(self, batch_uids: List[int], first_tokens, n_steps: int, block: bool = True,
-               eos_token_ids=None, sampling=None) -> np.ndarray:
+               eos_token_ids=None, sampling=None, max_new_tokens=None, probe=()) -> np.ndarray:
         """Run ``n_steps`` greedy decode steps ON DEVICE in one compiled
         program (a ``lax.scan`` feeding each step's argmax back as the next
         token), for sequences already tracked by the engine.
@@ -541,13 +560,33 @@ class InferenceEngineV2:
         rows). The sampled scan draws each fed-back token from the
         tempered/top-p distribution on device, keyed by (seed, position);
         all-greedy lists keep the original argmax scan program.
+
+        A model with ``diffusion_block_size`` ``B`` has no causal next token:
+        its rows advance by WHOLE BLOCKS of masked diffusion (``diffusion.py``),
+        ``n_steps`` a multiple of ``B``. A row has no last token to feed:
+        ``first_tokens`` is None, or one entry a row with the tokens already
+        KNOWN of the row's next block (a prompt's last partial block, fewer
+        than ``B`` of them, on the row's first call; empty or None after it),
+        which stand fixed beside the masks and whose K/V this call writes.
+        Returns ``[len(batch_uids), n_steps]``, the final ids of the blocks, a
+        row's known tokens first. ``max_new_tokens`` (one entry a row) says
+        how many NEW tokens each row keeps: a row that stops inside a block
+        (there, or at its eos) is rewound to its last whole kept block before
+        anything is published. ``probe`` (row indices): also return, for those
+        rows, every denoise forward's ids and logits (``(tokens, probe)``).
         """
         batch_uids = list(batch_uids)
+        if self._block:
+            decode = functools.partial(self._decode_blocks, max_new_tokens=max_new_tokens,
+                                       probe=tuple(int(r) for r in probe))
+        elif max_new_tokens is not None or probe:
+            raise ValueError("decode(max_new_tokens=, probe=) are a block-diffusion model's arguments")
+        else:
+            decode = self._decode
         hb = self._health
         gl = self.goodput_ledger
         if gl is None and not hb.enabled:
-            return self._decode(batch_uids, first_tokens, n_steps, block, eos_token_ids,
-                                sampling)
+            return decode(batch_uids, first_tokens, n_steps, block, eos_token_ids, sampling)
         if gl is not None:
             self._gp_last_uids = batch_uids
             t_gp = time.perf_counter()
@@ -556,8 +595,7 @@ class InferenceEngineV2:
             get_flight_recorder().record("serving", "decode", seqs=len(batch_uids),
                                          steps=int(n_steps))
         try:
-            return self._decode(batch_uids, first_tokens, n_steps, block, eos_token_ids,
-                                sampling)
+            return decode(batch_uids, first_tokens, n_steps, block, eos_token_ids, sampling)
         finally:
             if hb.enabled:
                 hb.end("serving")
@@ -645,8 +683,7 @@ class InferenceEngineV2:
                 if block:
                     toks = np.asarray(toks)
                     if stats and sp is not NULL_SPAN:
-                        moe_args = self._moe_span_args(S * int(n_steps), s_bucket, int(n_steps),
-                                                       np.asarray(stats[0]))
+                        moe_args = self._moe_span_args(S, s_bucket, int(n_steps), np.asarray(stats[0]))
             pc = self.state_manager.prefix_cache
             with tr.span("serving/engine_commit", tid="serving"):
                 if block:
@@ -695,8 +732,149 @@ class InferenceEngineV2:
             reg.gauge("serving/decode_tokens_per_sec").set(S * n_steps / max(dt, 1e-9))
         return toks
 
+    @_serving_compile_scope
+    def _decode_blocks(self, batch_uids, first_tokens, n_steps, block, eos_token_ids=None, sampling=None,
+                       max_new_tokens=None, probe=()):
+        """``decode`` of a block-diffusion model: ``n_steps // B`` blocks a row
+        in one compiled program (``diffusion.build_block_program``). Under the
+        span's name and arguments of the causal burst, ``steps`` counting the
+        FORWARDS of the call (denoise and commit), with the counts a block at a
+        time beside them (PERF.md section 3)."""
+        from .sampling import all_greedy
+
+        tr = get_tracer()
+        reg = get_metrics()
+        uids, S, B = list(batch_uids), len(batch_uids), self._block
+        n_steps = int(n_steps)
+        t_call = time.perf_counter()
+        with tr.span("serving/decode", tid="serving") as sp:
+            with tr.span("serving/engine_batch", tid="serving"):
+                if n_steps <= 0 or n_steps % B:
+                    raise ValueError(f"decode(n_steps={n_steps}): a model with diffusion_block_size={B} "
+                                     "advances by whole blocks")
+                if not all_greedy(sampling):
+                    raise NotImplementedError(
+                        f"temperature sampling of a model with diffusion_block_size={B}: a block's positions are "
+                        "unmasked by the confidence of their argmax, and no draw is carried through that choice")
+                if not block:
+                    raise NotImplementedError("decode(block=False) of a block-diffusion model: a row's stop is "
+                                              "settled from the fetched tokens before anything is published")
+                if len(set(uids)) != len(uids) or S > self.batch.max_seqs:
+                    raise SchedulingError(SchedulingResult.BatchSequenceLimitExceeded)
+                first = [np.zeros(0, np.int32) if t is None else np.asarray(t, np.int32).reshape(-1)
+                         for t in (first_tokens if first_tokens is not None else [None] * S)]
+                if len(first) != S or any(t.size >= B for t in first):
+                    raise ValueError(f"decode(first_tokens=): {[int(t.size) for t in first]} tokens for {S} rows; a "
+                                     f"row's next block of {B} opens with fewer than {B} known tokens")
+                opened = [int(t.size) for t in first]
+                seqs = []
+                for uid in uids:
+                    seq = self.state_manager.get_sequence(uid)
+                    if seq is None:
+                        raise SchedulingError(SchedulingResult.EngineSequenceLimitExceeded)
+                    if seq.seen_tokens % B:
+                        raise ValueError(f"uid {uid}: committed length {seq.seen_tokens} ends inside a block of {B}; "
+                                         "prompt chunks end on block boundaries (first_tokens take the rest)")
+                    if seq.seen_tokens + n_steps > self._max_context:
+                        raise SchedulingError(SchedulingResult.KVCacheLimitExceeded)
+                    seqs.append(seq)
+                if sum(s.blocks_needed(n_steps) for s in seqs) > self.state_manager.available_blocks:
+                    raise SchedulingError(SchedulingResult.KVCacheLimitExceeded)
+                if not hasattr(self, "_block_batch"):
+                    # B tokens a row, so the token buckets are the row buckets' multiples
+                    self._block_batch = RaggedBatchWrapper(
+                        max_ragged_batch_size=self.batch.max_seqs * B,
+                        max_ragged_sequence_count=self.batch.max_seqs,
+                        max_blocks_per_seq=self._max_blocks_per_seq, block_size=self.config.kv_block_size,
+                        token_buckets=[s * B for s in self.batch.seq_buckets], seq_buckets=self.batch.seq_buckets)
+                mask_id = self.model_config.mask_token_id
+                self._block_batch.clear()
+                for seq, known in zip(seqs, first):
+                    self.state_manager.allocate_blocks(seq, n_steps)
+                    seq.pre_forward(n_steps)  # the slots written; committed when the call returns
+                    self._block_batch.insert_sequence(
+                        seq, np.concatenate([known, np.full(B - known.size, mask_id, np.int32)]))
+                rb = self._block_batch.finalize()
+
+            kv = self.state_manager.kv_cache
+            s_bucket, n_blocks = rb.block_tables.shape[0], n_steps // B
+            with tr.span("serving/engine_dispatch", tid="serving") as sd:
+                n_programs = len(self._compiled)
+                fn = self._get_compiled_blocks(s_bucket, n_blocks, probe)
+                (toks, forwards, masked_fed, *rest), pools = fn(self.params, jnp.asarray(rb.packed()), kv.pools())
+                kv.update(*pools)
+                if sd is not NULL_SPAN:
+                    sd.set_args(compiled=len(self._compiled) > n_programs)
+            with tr.span("serving/engine_fetch", tid="serving"):
+                # the whole bucket comes to the host (a few KB of int32) and is cut there: an eager
+                # slice on the device would be one more tiny program a (bucket, rows) pair to warm
+                toks = np.asarray(toks)[:S]
+                stats, probed = (rest[:1], rest[1:]) if self._moe is not None else ((), rest)
+            if eos_token_ids is None or isinstance(eos_token_ids, (int, np.integer)):
+                eos_token_ids = [eos_token_ids] * S
+            assert len(eos_token_ids) == S, "eos_token_ids must match batch_uids"
+            kept = []
+            with tr.span("serving/engine_commit", tid="serving"):
+                for seq, row, n_open, eos, want in zip(seqs, toks, opened, eos_token_ids,
+                                                       max_new_tokens or [None] * S):
+                    start = seq.seen_tokens
+                    self.state_manager.note_tokens(seq, row)
+                    seq.post_forward()
+                    keep = n_steps - n_open if want is None else min(n_steps - n_open, int(want))
+                    if eos is not None:
+                        hit = np.nonzero(row[n_open:n_open + keep] == eos)[0]
+                        if hit.size:
+                            keep = int(hit[0]) + 1
+                    kept.append(keep)
+                    if n_open + keep < n_steps:
+                        # a stop inside the call: the caller keeps row[n_open:n_open + keep]. A block's K/V
+                        # saw the whole block, so only the whole kept blocks stay committed
+                        self.state_manager.rollback_to(seq, start + (n_open + keep) // B * B)
+                    self.state_manager.publish_sequence(seq)
+            if sp is not NULL_SPAN:
+                forwards = np.asarray(forwards)
+                n_denoise = int(forwards.sum())
+                n_fwd = n_denoise + n_blocks
+                moe_args = {}
+                if stats:
+                    moe_args = self._moe_span_args(S * B, s_bucket * B, n_fwd, np.asarray(stats[0]),
+                                                   kv_only_forwards=n_blocks)
+                new = n_steps * S - sum(opened)
+                sp.set_args(seqs=S, rows=S, tokens=S * n_steps, steps=n_fwd, bucket_rows=int(s_bucket),
+                            bucket_tokens=int(s_bucket * B), kernel=self._kernel_of(s_bucket * B, s_bucket),
+                            uids=[int(u) for u in uids[:16]], blocked=True, blocks=n_blocks, block_size=B,
+                            denoise_forwards=n_denoise, commit_forwards=n_blocks, tokens_committed=sum(kept),
+                            tokens_fed=S * B * n_fwd, masked_fed=int(masked_fed), tokens_dropped=new - sum(kept),
+                            open_tokens=sum(opened),
+                            block_ms=round((time.perf_counter() - t_call) * 1e3 / n_blocks, 3), **moe_args)
+        if reg.enabled:
+            dt = time.perf_counter() - t_call
+            reg.histogram("serving/decode_ms").observe(dt * 1e3)
+            reg.gauge("serving/decode_tokens_per_sec").set(sum(kept) / max(dt, 1e-9))
+        if probe:
+            ids, logits = (np.asarray(a) for a in probed)  # [blocks, steps, rows, B] and [..., V]
+            return toks, {"rows": list(probe), "ids": ids, "forwards": np.asarray(forwards), "logits": logits}
+        return toks
+
+    def _get_compiled_blocks(self, s_bucket: int, n_blocks: int, probe_rows: tuple = ()):
+        key = ("diffuse", s_bucket, n_blocks, probe_rows)
+        if key not in self._compiled:
+            from .diffusion import build_block_program
+
+            self._note_compile(f"diffuse/s{s_bucket}/b{n_blocks}{'/probe' if probe_rows else ''}")
+            mc, dc = self.model_config, self.config.diffusion
+            fwd = build_block_program(
+                self._ragged_step, block_size=self._block, mask_id=mc.mask_token_id,
+                denoising_steps=dc.denoising_steps, remasking=dc.remasking, threshold=dc.confidence_threshold,
+                s_bucket=s_bucket, n_blocks=n_blocks, moe=self._moe is not None, vocab=mc.vocab_size,
+                probe_rows=probe_rows)
+            self._compiled[key] = jax.jit(fwd, donate_argnums=(2, ), **self._jit_options)
+            log_dist(f"compiled block-diffusion decode bucket seqs={s_bucket} blocks={n_blocks} "
+                     f"probe_rows={probe_rows}", ranks=[0])
+        return self._compiled[key]
+
     def _ragged_step(self, params, packed, pools, t_bucket, s_bucket, gather_k: int = 0,
-                     tree_meta=None, moe_stats: bool = False):
+                     tree_meta=None, moe_stats: bool = False, kv_only: bool = False):
         """One ragged forward over the pool tuple (2 = bf16 pools, 4 = int8
         pools + scales). The SINGLE builder both compiled paths share —
         quant/non-quant variation lives in the tuple arity, not in four
@@ -721,7 +899,10 @@ class InferenceEngineV2:
         byte-identical to the plain causal step.
 
         ``moe_stats`` (a model with experts): a third result, int32
-        ``[experts_hit, expert_load_max, slots]`` of this forward."""
+        ``[experts_hit, expert_load_max, slots]`` of this forward.
+
+        ``kv_only``: the commit of a diffusion block (``ragged_forward``): the
+        K/V of every layer and nothing else, logits None."""
         from .ragged.ragged_wrapper import unpack_descriptors
 
         token_ids, seq_idx, pos, valid, tables, last_idx = unpack_descriptors(
@@ -779,7 +960,7 @@ class InferenceEngineV2:
         out = ragged_forward(self.model_config, self.config.kv_block_size, params,
                              token_ids, seq_idx, pos, valid, tables, last_idx,
                              pools[0], pools[1], use_pallas=self._use_pallas,
-                             modules=self._modules, moe_stats=moe_stats, **scales, **extra)
+                             modules=self._modules, moe_stats=moe_stats, kv_only=kv_only, **scales, **extra)
         if moe_stats:
             return out[0], tuple(out[1:-1]), out[-1]
         return out[0], tuple(out[1:])  # logits, new pool tuple
@@ -836,6 +1017,11 @@ class InferenceEngineV2:
         rejected drafts release block-table tail refs via the PR 3
         refcount machinery."""
         batch_uids = list(batch_uids)
+        if self._block:
+            raise NotImplementedError(
+                f"speculate_decode (speculative decoding, token-tree verification) of a model with "
+                f"diffusion_block_size={self._block}: drafts are verified against a causal next token, which a "
+                "block generated by masked diffusion does not have")
         hb = self._health
         gl = self.goodput_ledger
         if gl is None and not hb.enabled:
@@ -1278,14 +1464,16 @@ class InferenceEngineV2:
         for s_bucket in s_buckets:
             for n_steps in decode_steps:
                 n_steps = int(n_steps)
-                key = ("decode", s_bucket, n_steps, False)
+                B = self._block or 1  # a block-diffusion model: n_steps // B blocks of B tokens a row
+                key = ("diffuse", s_bucket, n_steps // B, ()) if self._block else ("decode", s_bucket, n_steps, False)
                 if key in self._compiled:
                     results.append({"seqs": s_bucket, "steps": n_steps, "seconds": 0.0, "cached": True})
                     continue
-                fn = self._get_compiled_decode(s_bucket, n_steps)
+                fn = self._get_compiled_blocks(s_bucket, n_steps // B) if self._block \
+                    else self._get_compiled_decode(s_bucket, n_steps)
                 # packed layout [T ids][T idx][T pos][T valid][S*max_blocks][S last]
-                # with T == S on the decode path
-                packed = jnp.zeros(s_bucket * (5 + max_blocks), jnp.int32)
+                # with T == S on the decode path (S * B for a block-diffusion model)
+                packed = jnp.zeros(s_bucket * (4 * B + 1 + max_blocks), jnp.int32)
                 t0 = time.perf_counter()
                 toks, pools = fn(self.params, packed, kv.pools())
                 jax.block_until_ready(toks)

@@ -37,7 +37,7 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
                    block_tables, last_idx, k_pool, v_pool, use_pallas: bool = False,
                    unroll: bool = True, modules: Dict[str, Any] = None,
                    k_scale=None, v_scale=None, pos_ids=None, attn_mask=None,
-                   ctx_pos_ids=None, moe_stats: bool = False):
+                   ctx_pos_ids=None, moe_stats: bool = False, kv_only: bool = False):
     """Returns (last-token logits [S_pad, V], k_pool, v_pool).
 
     token_ids/seq_idx/pos/valid: [T_pad]; block_tables: [S_pad, max_blocks];
@@ -79,6 +79,17 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
     (plain matmuls), whose arrays are stacked over the expert layers alone. A
     q/k norm, the attention gate and the norms after each branch are the
     configuration's (``qk_norm``, ``attention_gate``, ``post_norms``).
+
+    A model with ``cfg.diffusion_block_size`` ``B`` attends under the
+    block-causal mask, key ``j`` visible to query ``i`` iff ``j // B <= i //
+    B``: every attention path (both paged kernels, the interpreter, the gather)
+    takes a token's position only to mask, so each is given the LAST position
+    of the token's block, ``pos | (B - 1)``, here and nowhere else, while rope
+    and the KV slot keep ``pos``. ``B`` divides the KV block, so the block
+    columns a row walks are those of ``pos``. ``kv_only``: the forward that
+    commits a block: K/V of every layer are written and nothing else is
+    wanted, so the last layer stops at its scatter, there is no head, and the
+    logits returned are None.
 
     ``k_scale``/``v_scale``: int8-KV mode — [nkv, L*pool_len] fp32 absmax
     scales (lane-major over slots, the layout both the scatter and the
@@ -133,11 +144,16 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
     slot = block_tables[seq_idx, pos // block_size] * block_size + pos % block_size
 
     quant = k_scale is not None
+    # what the attention paths mask by (see the docstring)
+    vis_pos = pos | (cfg.diffusion_block_size - 1) if cfg.diffusion_block_size > 1 else pos
+    if cfg.diffusion_block_size > 1 and (attn_mask is not None or block_size % cfg.diffusion_block_size):
+        raise NotImplementedError(f"blocks of {cfg.diffusion_block_size} under a block-causal mask: no token-tree "
+                                  f"mask beside it, and a KV block ({block_size}) holds whole blocks")
 
-    def layer(x, blk, l, k_flat, v_flat, ks_flat, vs_flat, stats=None, kind=None):
+    def layer(x, blk, l, k_flat, v_flat, ks_flat, vs_flat, stats=None, kind=None, kv_alone=False):
         """``kind``: the layer's attention kind, static (None in a model of
         one kind, where ``l`` may be traced); ``stats``: the running MoE
-        counts."""
+        counts; ``kv_alone``: write this layer's K/V and stop."""
         attend = modules["attention_full"] if kind == "full_attention" else attention
         h1 = pre_norm(x, blk["ln1_scale"], blk.get("ln1_bias"))
         bias = (lambda n: blk[n]) if cfg.use_bias else (lambda n: None)
@@ -167,6 +183,8 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
             vs_flat = vs_flat.at[heads, slot_l[:, None]].set(vs, mode="drop")
         k_flat = k_flat.at[slot_l].set(k.astype(k_flat.dtype), mode="drop")
         v_flat = v_flat.at[slot_l].set(v.astype(v_flat.dtype), mode="drop")
+        if kv_alone:
+            return x, k_flat, v_flat, ks_flat, vs_flat, stats
 
         tables_l = block_tables + l * NB  # layer l's blocks in the flat pool
         # scales/tree kwargs only passed when active, so full-precision
@@ -175,7 +193,7 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
         scales = {"k_scale": ks_flat, "v_scale": vs_flat} if quant else {}
         if attn_mask is not None:
             scales = dict(scales, pos_ids=pid, mask=attn_mask, ctx_pos_ids=ctx_pos_ids)
-        ctx = attend(q, k_flat, v_flat, tables_l, seq_idx, pos, **scales)
+        ctx = attend(q, k_flat, v_flat, tables_l, seq_idx, vis_pos, **scales)
 
         ctx = ctx.reshape(T, nq * d)
         if cfg.attention_gate:
@@ -243,8 +261,12 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
             blk_l = {name: jax.tree_util.tree_map(lambda a: a[i], stacked)
                      for name, stacked in sorted(per_layer.items()) if (i := index_of(name, l)) is not None}
             x, k_flat, v_flat, ks_flat, vs_flat, stats = layer(
-                x, blk_l, l, k_flat, v_flat, ks_flat, vs_flat, stats, cfg.layer_kind(l))
+                x, blk_l, l, k_flat, v_flat, ks_flat, vs_flat, stats, cfg.layer_kind(l),
+                kv_alone=kv_only and l == L - 1)
     else:
+        if kv_only:
+            raise NotImplementedError("kv_only under lax.scan: one scan body cannot stop its last layer at the "
+                                      "scatter; the ragged forward unrolls up to 48 layers")
         if cfg.per_layer_attention or mixed_mlp:
             raise NotImplementedError("layer_types or leading dense layers under lax.scan: one scan body has "
                                       "one window, one rope and one MLP kind; the ragged forward unrolls up to 48 layers")
@@ -261,6 +283,6 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
 
     # logits_gather semantics: final norm + unembed only each sequence's
     # last token, through the pluggable unembed module
-    logits = unembed(params, x, last_idx)
+    logits = None if kv_only else unembed(params, x, last_idx)
     out = (logits, k_pool, v_pool, ks_flat, vs_flat) if quant else (logits, k_pool, v_pool)
     return out + (stats, ) if moe_stats else out

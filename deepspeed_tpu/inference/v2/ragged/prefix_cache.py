@@ -109,6 +109,11 @@ class PrefixKVCache:
         self.block_size = kv_cache.block_size
         self.min_hit_blocks = int(min_hit_blocks)
         self.eviction = eviction
+        # tokens whose K/V stand or fall together: 1 under a causal mask; the
+        # block length of a block-diffusion model (the engine sets it), where
+        # a position's K/V depends on its whole block, so a partial-tail hit
+        # may end only where a block does
+        self.token_quantum = 1
         # block-lifecycle + MRC observability (``cache_telemetry.py``); None
         # keeps every hook below at a single attribute check
         self._telemetry = telemetry
@@ -255,6 +260,7 @@ class PrefixKVCache:
                 key = np.asarray(child.chunk[:cap], dtype=np.int64)
                 neq = np.nonzero(rest[:key.size] != key)[0]
                 t = int(neq[0]) if neq.size else int(key.size)
+                t -= t % self.token_quantum
                 if t > best_t:
                     best, best_t = child, t
             # a COW copy costs a block + a device copy: with no shared run in

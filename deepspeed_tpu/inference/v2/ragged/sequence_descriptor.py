@@ -36,6 +36,11 @@ class DSSequenceDescriptor:
     # request plane knows a tenant; rides into published radix-tree nodes
     # so hits and eviction pressure are attributable. None = untenanted.
     tenant: str = None
+    # a block-diffusion model (``diffusion_block_size`` B): ``seen_tokens`` is
+    # the COMMITTED length, a multiple of B, whose K/V is final. The slots of
+    # the block after it are written by every denoise forward of a ``decode``
+    # call and count as ``in_flight_tokens`` until that call's commit, so no
+    # rollback, flush or prefix hash ever reads a slot that is not final.
 
     @property
     def cur_allocated_blocks(self) -> int:

@@ -217,3 +217,48 @@ def spec_verify_draws(logits, chunk, temps, top_ps, seeds, starts):
     nxt = jnp.where(sampled_rows, jnp.concatenate(
         [res_tok, bonus_tok], axis=1), greedy_row)
     return accept, nxt
+
+
+# ---------------------------------------------------------------------------
+# masked diffusion over blocks (a model with ``diffusion_block_size``): what a
+# denoise forward yields for each position of a block, and which masked
+# positions it unmasks. Pure jnp, called inside the engine's compiled block
+# program and by the tests.
+# ---------------------------------------------------------------------------
+
+def diffusion_candidates(logits):
+    """For each row of ``logits [N, V]``: its argmax token (int32) and that
+    token's float32 softmax probability, ``1 / sum(exp(l - max l))``."""
+    import jax.numpy as jnp
+
+    lg = logits.astype(jnp.float32)
+    top = jnp.max(lg, axis=-1, keepdims=True)
+    conf = 1.0 / jnp.sum(jnp.exp(lg - top), axis=-1)
+    return jnp.argmax(lg, axis=-1).astype(jnp.int32), conf
+
+
+def diffusion_quota(block_size: int, denoising_steps: int):
+    """Positions each of the ``denoising_steps`` forwards of the static rule
+    unmasks: ``B // steps`` each, one more in the first ``B % steps``."""
+    return [block_size // denoising_steps + (i < block_size % denoising_steps)
+            for i in range(denoising_steps)]
+
+
+def diffusion_unmask(conf, masked, remasking: str, quota, threshold: float, last):
+    """Which masked positions a denoise forward unmasks. ``conf``/``masked``
+    ``[S, B]``; ``quota`` (static rule) the positions this forward may take;
+    ``last``: the block's final denoise forward, which takes every masked
+    position. Among equal confidences the earlier position goes first."""
+    import jax.numpy as jnp
+
+    score = jnp.where(masked, conf, -1.0)
+    B = score.shape[-1]
+    j = jnp.arange(B)
+    ahead = (score[..., None, :] > score[..., :, None]) | \
+        ((score[..., None, :] == score[..., :, None]) & (j[None, :] < j[:, None]))
+    rank = jnp.sum(ahead, axis=-1)  # positions ahead of each one, masked ones first
+    if remasking == "low_confidence_static":
+        choose = rank < quota
+    else:
+        choose = (conf > threshold) | (rank == 0)
+    return masked & (choose | last)

@@ -16,7 +16,15 @@ Design points beyond the happy path:
   multi-step on-device ``decode`` (one host round-trip per horizon instead
   of per token — the steady-state fast path);
 - nothing is dropped silently: un-runnable work raises with the stalled
-  uids named, and partial generations stay readable via ``results``.
+  uids named, and partial generations stay readable via ``results``;
+- a model that generates by masked diffusion over blocks (``diffusion_block_size``
+  ``B``) emits no token from its prefill and none a step: its prompt's whole
+  blocks are prefilled in chunks that end on block boundaries, the rest of
+  the prompt opens the first generated block (the ``first_tokens`` of the
+  row's first burst), and its rows advance ``B`` tokens at a time in bursts
+  whose horizon is a power of two of BLOCKS (``_block_burst``); they ride in
+  no ``put`` beside prefill chunks, so while prompts wait the chunks go first
+  and the rows stand.
 """
 
 import copy
@@ -32,12 +40,15 @@ from .scheduling_utils import SchedulingResult
 
 class _Request:
     __slots__ = ("uid", "prompt", "max_new_tokens", "eos_token_id", "fed", "generated", "done",
-                 "charged_blocks", "shared_blocks", "sampling", "tenant", "t_submit")
+                 "charged_blocks", "shared_blocks", "sampling", "tenant", "t_submit", "prefill_len")
 
     def __init__(self, uid, prompt, max_new_tokens, eos_token_id, sampling=None,
-                 tenant=None):
+                 tenant=None, block: int = 0):
         self.uid = uid
         self.prompt = np.asarray(prompt, np.int32).reshape(-1)
+        # prompt tokens that go through prefill: all of them, or the whole blocks
+        # of a block-diffusion model (the rest opens the first generated block)
+        self.prefill_len = self.prompt.size // block * block if block else self.prompt.size
         self.max_new_tokens = int(max_new_tokens)
         self.eos_token_id = eos_token_id
         self.sampling = sampling  # SamplingParams | None (= greedy)
@@ -55,7 +66,7 @@ class _Request:
 
     @property
     def prefilling(self) -> bool:
-        return self.fed < self.prompt.size
+        return self.fed < self.prefill_len
 
     @property
     def total_tokens(self) -> int:
@@ -83,6 +94,8 @@ class DynamicSplitFuseScheduler:
             raise ValueError(f"token_budget must be positive, got {token_budget}")
         self.token_budget = min(int(token_budget), sm.max_ragged_batch_size)
         self.max_seqs = sm.max_ragged_sequence_count
+        # block length of a model generated by masked diffusion over blocks; 0: causal
+        self._block = int(getattr(engine.model_config, "diffusion_block_size", 0) or 0)
         self._pending: List[_Request] = []   # not yet tracked by the engine
         self._active: Dict[int, _Request] = {}
         self._results: Dict[int, List[int]] = {}
@@ -108,6 +121,10 @@ class DynamicSplitFuseScheduler:
         if self._drafter is not None and self._spec is None:
             from .config_v2 import SpeculativeConfig
             self._spec = SpeculativeConfig(mode="ngram")  # injected drafter, default k
+        if self._block and self._drafter is not None:
+            raise NotImplementedError(
+                f"speculative decoding of a model with diffusion_block_size={self._block}: a draft is verified "
+                "one causal token at a time, and this model has no causal next token")
         self.spec_stats = {"rounds": 0, "drafted": 0, "accepted": 0, "rejected": 0,
                            "backoffs": 0}
         self._spec_by_uid: Dict[int, Dict[str, int]] = {}
@@ -123,9 +140,11 @@ class DynamicSplitFuseScheduler:
         self._spec_ctx: Dict[int, np.ndarray] = {}
         # optional per-step observer, `fn(uids, chunk_sizes, t0, dur, kind)`
         # after EVERY engine forward this scheduler composes — `kind` is
-        # "put" (mixed decode+prefill chunks), "decode" (the multi-step
-        # burst, chunk_sizes = the horizon per row) or "spec_verify" (the
-        # speculative verify forward, chunk_sizes = the verify-chunk rows).
+        # "put" (mixed decode+prefill chunks, chunk_sizes = the tokens fed a
+        # row), "decode" (a burst, causal or of diffusion blocks) or
+        # "spec_verify" (the speculative verify forward); for both bursts
+        # chunk_sizes = the tokens each row EMITTED, the tail past a stop
+        # left out, so that a rate over them is a rate of tokens delivered.
         # The serving replica attaches one to attribute forward wall time
         # to the requests whose chunks composed it (per-chunk prefill spans
         # + per-tenant compute-second apportionment). None (the default)
@@ -139,7 +158,11 @@ class DynamicSplitFuseScheduler:
         if sampling is not None:
             sampling.validate()  # raises ValueError on out-of-range knobs
         req = _Request(uid, prompt, max_new_tokens, eos_token_id, sampling=sampling,
-                       tenant=tenant)
+                       tenant=tenant, block=self._block)
+        if self._block and req.sampled:
+            raise NotImplementedError(
+                f"uid {uid}: temperature sampling of a model with diffusion_block_size={self._block}: a block's "
+                "positions are unmasked by the confidence of their argmax, and no draw is carried through it")
         if req.prompt.size == 0:
             raise ValueError(f"uid {uid}: empty prompt")
         if req.max_new_tokens <= 0:
@@ -271,6 +294,10 @@ class DynamicSplitFuseScheduler:
             # blocks keyed by post-eos garbage tokens — blocks that then
             # never return to the free list until LRU pressure evicts them.
             known = req.fed + max(0, len(req.generated) - 1)
+            if self._block:
+                # committed blocks alone: the K/V of a block saw the whole block, so one
+                # that the request keeps a part of is not kept at all
+                known = (req.prompt.size + len(req.generated)) // self._block * self._block
             if seq.seen_tokens > known:
                 # final=True: the flush below is this sequence's last act, so
                 # the COW guard (which could need a block from a dry pool)
@@ -309,9 +336,9 @@ class DynamicSplitFuseScheduler:
             return False  # acquisition would raise, not refuse
         n_cached, shared, tree_only, match = self.engine.probe_prefix(req.prompt)
         need = self._blocks_for(req.total_tokens) - shared
-        first = min(budget, req.prompt.size - n_cached)
-        if first <= 0:
-            return False
+        first = self._chunk(budget, req.prefill_len - n_cached)
+        if first <= 0 and (not self._block or req.prefill_len > n_cached):
+            return False  # (a prompt shorter than a diffusion block has nothing to prefill)
         # supply side: the hit's tree-only shared blocks stop being evictable
         # the moment acquisition pins them — counting them as reclaimable
         # WHILE ALSO subtracting them from demand (`need`) would credit the
@@ -332,6 +359,12 @@ class DynamicSplitFuseScheduler:
         self._active[req.uid] = req
         return True
 
+    def _chunk(self, budget: int, remaining: int) -> int:
+        """Prompt tokens the next chunk of a row takes of ``remaining``: what
+        the budget leaves, cut to whole blocks for a block-diffusion model."""
+        take = min(budget, remaining)
+        return take // self._block * self._block if self._block else take
+
     def _owned_blocks(self) -> int:
         """Blocks active sequences allocated THEMSELVES (shared radix-tree
         blocks excluded: they were never charged against the reservation)."""
@@ -350,7 +383,9 @@ class DynamicSplitFuseScheduler:
         (one host round-trip per horizon instead of per token). The horizon
         quantizes DOWN to a power of two: the engine compiles one program
         per exact n_steps, so free-running horizons would pay a fresh XLA
-        compile for every distinct remaining-token count."""
+        compile for every distinct remaining-token count. A row keeps its
+        horizon of tokens unless it stops inside the burst; the observer is
+        told what each row kept."""
         horizon = min(min(r.max_new_tokens - len(r.generated) for r in decoding),
                       self.DECODE_HORIZON)
         horizon = 1 << (horizon.bit_length() - 1)  # 1,2,4,...,32: <=6 programs per bucket
@@ -362,21 +397,50 @@ class DynamicSplitFuseScheduler:
         # sampling rides down only when some row actually samples — an
         # all-greedy burst keeps the original argmax scan program
         samp = [r.sampling for r in decoding] if any(r.sampled for r in decoding) else None
-        if self.step_observer is None:
-            toks = np.asarray(self.engine.decode(uids, first, horizon, eos_token_ids=eos,
-                                                 sampling=samp))  # [S, horizon]
-        else:
-            t0 = time.perf_counter()
-            toks = np.asarray(self.engine.decode(uids, first, horizon, eos_token_ids=eos,
-                                                 sampling=samp))  # [S, horizon]
-            self.step_observer(uids, [horizon] * len(uids), t0,
-                               time.perf_counter() - t0, "decode")
-        for req, row in zip(decoding, toks):
-            for tok in row.tolist():
+        t0 = time.perf_counter()
+        toks = np.asarray(self.engine.decode(uids, first, horizon, eos_token_ids=eos,
+                                             sampling=samp))  # [S, horizon]
+        self._emit(decoding, toks, t0, time.perf_counter() - t0)
+        return len(decoding) * horizon
+
+    def _emit(self, rows: List[_Request], new_tokens, t0: float, dur: float) -> int:
+        """Append a burst's tokens row by row, dropping a row's tail past its
+        stop (eos, or max_new_tokens inside the burst), and tell the observer
+        what each row kept. Returns the tokens kept."""
+        uids, emitted = [r.uid for r in rows], []
+        for req, new in zip(rows, new_tokens):
+            before = len(req.generated)
+            for tok in np.asarray(new).tolist():
                 self._append_token(req, int(tok))
                 if req.done:
-                    break  # eos/max_new inside the burst: drop the tail
-        return len(decoding) * horizon
+                    break
+            emitted.append(len(req.generated) - before)
+        if self.step_observer is not None:
+            self.step_observer(uids, emitted, t0, dur, "decode")
+        return sum(emitted)
+
+    def _block_burst(self, decoding: List[_Request]) -> int:
+        """A burst of a block-diffusion model: every row advances the same
+        number of whole blocks in ONE engine call, a power of two of them
+        (one program a (row bucket, blocks) pair) and no more than the row
+        nearest its end still needs, the open block of a first burst
+        included: the K/V the burst writes then never passes a row's lifetime
+        reservation. A block's tokens arrive together; a row whose
+        ``max_new_tokens`` or eos falls inside a block drops that block's
+        tail, here and in the engine's committed length."""
+        B = self._block
+        # a row's first burst opens with the rest of its prompt (fewer than a block of tokens)
+        known = [r.prompt[r.prefill_len:] if not r.generated else r.prompt[:0] for r in decoding]
+        opened = [k.size for k in known]
+        left = [r.max_new_tokens - len(r.generated) for r in decoding]
+        blocks = min(min(-(-(n + o) // B) for n, o in zip(left, opened)), max(1, self.DECODE_HORIZON // B))
+        blocks = 1 << (blocks.bit_length() - 1)
+        uids = [r.uid for r in decoding]
+        t0 = time.perf_counter()
+        toks = np.asarray(self.engine.decode(uids, known, blocks * B, max_new_tokens=left,
+                                             eos_token_ids=[r.eos_token_id for r in decoding]))
+        # a row's open tokens are its prompt's: they lead the first block and are not new
+        return self._emit(decoding, [row[o:] for row, o in zip(toks, opened)], t0, time.perf_counter() - t0)
 
     def _spec_context(self, req: _Request) -> np.ndarray:
         """The request's committed stream (prompt + generated) as one int32
@@ -538,26 +602,37 @@ class DynamicSplitFuseScheduler:
         with get_tracer().span("serving/sched_step", tid="serving") as sp:
             return self._step(sp)
 
-    def _step(self, sp) -> int:
-        decoding = [r for r in self._active.values() if not r.prefilling and not r.done]
-        prefilling = [r for r in self._active.values() if r.prefilling]
-        if decoding and not prefilling and not self._pending and len(decoding) <= self.max_seqs:
+    def _burst(self, sp, decoding: List[_Request]) -> int:
+        """Every decoding row in one engine call of several steps (blocks, for
+        a block-diffusion model; a speculative round where a drafter is wired
+        in), with no prefill chunk beside them."""
+        if self._block:
+            kind, n = "diffuse", self._block_burst(decoding)
+        else:
             kind, n = "decode", 0
             if self._drafter is not None:
                 kind, n = "spec_verify", self._spec_burst(decoding)
             if not n:
                 kind, n = "decode", self._decode_burst(decoding)
-            if sp is not NULL_SPAN:
-                sp.set_args(kind=kind, rows=len(decoding), rows_decode=len(decoding), tokens=n,
-                            prefill_tokens=0, pending=0, active=len(self._active),
-                            budget_left=self.token_budget, first_wait_ms=[])
-            return n
+        if sp is not NULL_SPAN:
+            sp.set_args(kind=kind, rows=len(decoding), rows_decode=len(decoding), tokens=n,
+                        prefill_tokens=0, pending=len(self._pending), active=len(self._active),
+                        budget_left=self.token_budget, first_wait_ms=[])
+        return n
+
+    def _step(self, sp) -> int:
+        decoding = [r for r in self._active.values() if not r.prefilling and not r.done]
+        prefilling = [r for r in self._active.values() if r.prefilling]
+        if decoding and not prefilling and not self._pending and len(decoding) <= self.max_seqs:
+            return self._burst(sp, decoding)
 
         uids: List[int] = []
         chunks: List[np.ndarray] = []
         budget = self.token_budget
 
-        for req in decoding[:min(budget, self.max_seqs)]:
+        # a causal row rides as one token beside the chunks; a diffusion row has no
+        # token to feed: the chunks go alone and the rows' burst waits for them
+        for req in () if self._block else decoding[:min(budget, self.max_seqs)]:
             uids.append(req.uid)
             chunks.append(np.asarray([req.generated[-1]], np.int32))
             budget -= 1
@@ -565,9 +640,9 @@ class DynamicSplitFuseScheduler:
 
         def add_prefill(req):
             nonlocal budget
-            if budget <= 0 or len(uids) >= self.max_seqs:
+            take = self._chunk(budget, req.prefill_len - req.fed)
+            if take <= 0 or len(uids) >= self.max_seqs:
                 return False
-            take = min(budget, req.prompt.size - req.fed)
             uids.append(req.uid)
             chunks.append(req.prompt[req.fed:req.fed + take])
             req.fed += take
@@ -591,6 +666,12 @@ class DynamicSplitFuseScheduler:
             else:
                 i += 1
 
+        if not uids and self._block:
+            # no chunk could be composed (a prompt shorter than a block was admitted with
+            # nothing to prefill, or what is pending does not fit yet): the rows go on
+            decoding = [r for r in self._active.values() if not r.prefilling and not r.done][:self.max_seqs]
+            if decoding:
+                return self._burst(sp, decoding)
         if sp is not NULL_SPAN:
             now = time.perf_counter()
             n_tokens = sum(c.size for c in chunks)
@@ -617,8 +698,8 @@ class DynamicSplitFuseScheduler:
         n = sum(c.size for c in chunks)
         for uid, tok in zip(uids, np.asarray(toks).reshape(-1)):
             req = self._active[uid]
-            if req.prefilling:
-                continue  # mid-prompt chunk: the "next token" is still prompt
+            if req.prefilling or self._block:
+                continue  # mid-prompt chunk: the "next token" is still prompt (diffusion: a prefill emits none)
             self._append_token(req, int(tok))
         return n
 

@@ -11,3 +11,4 @@ from .gpt_neox import gpt_neox, gpt_neox_config
 from .falcon import falcon, falcon_config
 from .mellum import mellum, mellum_config
 from .trinity import trinity, trinity_config
+from .sdar import sdar, sdar_config

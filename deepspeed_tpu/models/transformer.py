@@ -147,6 +147,13 @@ class TransformerConfig:
     rope_layer_types: Optional[Tuple[str, ...]] = None
     # factor on the token embedding (muP: sqrt(hidden_size))
     embed_scale: float = 1.0
+    # generation by masked diffusion over blocks: query ``i`` sees key ``j``
+    # iff ``j // B <= i // B`` on absolute positions (causal between blocks of
+    # ``B`` tokens, full inside one), and a block is generated by unmasking
+    # ``mask_token_id`` slots over several forwards (InferenceEngineV2's
+    # ``decode``). 0 = a causal model, every other family as it was
+    diffusion_block_size: int = 0
+    mask_token_id: Optional[int] = None
     # ZeRO++ qwZ (reference partition_parameters.py:1139 quantized all-gather
     # handles): when set (by the engine, from zero_quantized_weights), the
     # per-layer stage-3 weight gathers inside the scan body travel as int8
@@ -173,6 +180,16 @@ class TransformerConfig:
                 raise ValueError(f"experts [{first}, {first + held}) held of {self.moe_num_experts}")
             if not 0 <= self.moe_num_dense_layers < self.num_layers:
                 raise ValueError(f"moe_num_dense_layers={self.moe_num_dense_layers} of {self.num_layers} layers")
+        if self.diffusion_block_size:
+            B = self.diffusion_block_size
+            if B < 1 or B & (B - 1):
+                raise ValueError(f"diffusion_block_size={B}: a power of two (a KV block holds whole blocks)")
+            if self.mask_token_id is None or not 0 <= self.mask_token_id < self.vocab_size:
+                raise ValueError(f"diffusion_block_size={B} needs a mask_token_id inside the vocabulary, "
+                                 f"got {self.mask_token_id}")
+            if self.sliding_window is not None or self.positions == "alibi":
+                raise NotImplementedError("a block-causal mask with a sliding window or alibi: the paged "
+                                          "kernels take ONE position a token, which here is its block's last")
         if self.rope_layer_types is not None:
             self.rope_layer_types = tuple(self.rope_layer_types)
         if self.layer_types is not None:
@@ -249,7 +266,10 @@ class TransformerConfig:
                            (self.qk_norm, "a q/k norm"), (self.attention_gate, "gated attention"),
                            (self.post_norms, "norms after attention and MLP"),
                            (self.rope_layer_types is not None, "rope in some layer kinds only"),
-                           (self.embed_scale != 1.0, "a scaled embedding")):
+                           (self.embed_scale != 1.0, "a scaled embedding"),
+                           (self.diffusion_block_size > 0,
+                            f"a block-causal mask (blocks of {self.diffusion_block_size}, generated by masked "
+                            "diffusion)")):
             if flag:
                 why.append(what)
         return tuple(why)

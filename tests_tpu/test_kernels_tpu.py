@@ -647,6 +647,56 @@ def test_paged_attention_kv_split_on_chip(name, nq, nkv, window, S, mb, n_blocks
     assert np.linalg.norm(got - ref) / np.linalg.norm(ref) < 6e-3
 
 
+@pytest.mark.parametrize("name,T,S,rows,want", [
+    # sdar-30b-a3b-chat.block-diffusion-64: a denoise forward of 64 rows x 4 tokens, contexts 256-2,048, no multiple of 128
+    ("forward_64x4", 256, 64, [(260 + 28 * i, 4) for i in range(64)], ("paged_attn_q_tiled", 8, "heuristic:short_rows")),
+    # the drain: 8 rows x 4 tokens, under 64 tokens: the decode kernel by its work list, 4 KV blocks a step
+    ("forward_8x4", 32, 8, [(260 + 252 * i, 4) for i in range(7)], ("paged_attn_kv_split", 4, "heuristic:multi_token")),
+    # a 512-token prefill chunk whose last block lies past a KV block's edge, beside a chunk of a second prompt
+    ("chunk_512", 512, 8, [(508, 452), (0, 60)], ("paged_attn_q_tiled", 128, "heuristic:long_rows")),
+])
+def test_paged_kernels_under_the_block_causal_bound_on_chip(name, T, S, rows, want):
+    """Both paged kernels as ``ragged_forward`` calls them for a block-diffusion
+    model (SDAR's 32/4 heads of 128, blocks of 4 under 128-token KV blocks,
+    tables 65 wide, bf16): given each token's BLOCK's last position to mask by,
+    against a float32 reference that applies ``j // 4 <= i // 4`` to the true
+    positions."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    nq, nkv, d, bs, mb, n_blocks, B = 32, 4, 128, 128, 65, 619, 4
+    rng = np.random.default_rng(33)
+    k_pool = jnp.asarray(rng.normal(size=(n_blocks * bs, nkv, d)), jnp.bfloat16)
+    v_pool = jnp.asarray(rng.normal(size=(n_blocks * bs, nkv, d)), jnp.bfloat16)
+    tables = jnp.asarray(rng.integers(0, n_blocks, size=(S, mb)), jnp.int32)
+    seq_idx = np.concatenate([np.full(new, r) for r, (_, new) in enumerate(rows)])
+    pos = np.concatenate([np.arange(before, before + new) for before, new in rows])
+    n = seq_idx.size  # the rest is the pad run ragged_wrapper.finalize emits
+    seq_idx = jnp.asarray(np.pad(seq_idx, (0, T - n)), jnp.int32)
+    pos = jnp.asarray(np.pad(pos, (0, T - n)), jnp.int32)
+    q = jnp.asarray(rng.normal(size=(T, nq, d)), jnp.bfloat16)
+
+    pa.KERNEL_CHOICES.pop((T, S, mb), None)
+    out = jax.jit(lambda q, vis: pa.paged_attention(q, k_pool, v_pool, tables, seq_idx, vis, bs))(q, pos | (B - 1))
+    choice = pa.kernel_choice(T, S, mb)
+    assert (choice["kernel"], max(choice["q_tile"], choice["blocks_per_step"]), choice["rule"]) == want
+    outs, t0 = [], 0
+    for r, (before, new) in enumerate(rows):
+        slots = (tables[r][:, None] * bs + jnp.arange(bs)[None, :]).reshape(-1)
+        k, v = k_pool[slots].astype(jnp.float32), v_pool[slots].astype(jnp.float32)
+        qq = q[t0:t0 + new].astype(jnp.float32).reshape(new, nkv, nq // nkv, d) / np.sqrt(d)
+        vis = jnp.arange(k.shape[0])[None, :] // B <= jnp.arange(before, before + new)[:, None] // B
+        s = jnp.einsum("tngd,cnd->tngc", qq, k, precision="highest")
+        w = jax.nn.softmax(jnp.where(vis[:, None, None, :], s, -1e30), axis=-1)
+        outs.append(jnp.einsum("tngc,cnd->tngd", w, v, precision="highest").reshape(new, nq, d))
+        t0 += new
+    ref, got = np.asarray(jnp.concatenate(outs, 0), np.float32), np.asarray(out[:n], np.float32)
+    np.testing.assert_allclose(got, ref, atol=5e-2, rtol=5e-2)
+    assert np.linalg.norm(got - ref) / np.linalg.norm(ref) < 6e-3
+    # the bound is the one thing that differs from the causal kernel: a causal mask reads otherwise
+    causal = np.asarray(jax.jit(lambda q: pa.paged_attention(q, k_pool, v_pool, tables, seq_idx, pos, bs))(q)[:n], np.float32)
+    assert np.linalg.norm(causal - ref) / np.linalg.norm(ref) > 0.02
+
+
 def test_moe_serving_programs_of_every_bucket_pair_run_on_chip():
     """Every (token bucket, row bucket) ``put`` program and every row bucket's
     one-step ``decode`` program of a model with experts and both attention
