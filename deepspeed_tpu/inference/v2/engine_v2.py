@@ -27,7 +27,7 @@ from ...monitor.roofline import get_roofline
 from ...monitor.trace import (NULL_SPAN, get_tracer, pop_compile_source,
                               push_compile_source)
 from ...moe.grouped import merge_routing_stats
-from ...ops.pallas.paged_attention import decode_kv_counts, kernel_choice
+from ...ops.pallas.paged_attention import decode_kv_counts, kernel_choice, tiled_kv_counts
 from ...utils.logging import log_dist
 from .config_v2 import RaggedInferenceEngineConfig
 from .model_implementations.flat_model import ragged_forward
@@ -143,7 +143,7 @@ class InferenceEngineV2:
 
         self._compiled: Dict[Tuple[int, int, Optional[str]], object] = {}
         self._kernel_labels: Dict[Tuple[int, int], str] = {}  # (tokens, rows) -> span label, see _kernel_of
-        # (window or None, layers that attend in it), for _kv_span_args
+        # (window or None, layers that attend in it), for _kv_span_args and _tiled_kv_span_args
         self._kv_windows = list(collections.Counter(
             mc.layer_window(l) for l in range(mc.num_layers)).items())
         # speculative-decoding lifetime totals (two int adds per verify
@@ -461,7 +461,8 @@ class InferenceEngineV2:
                             bucket_tokens=int(t_bucket), bucket_rows=int(s_bucket), steps=1,
                             kernel=self._kernel_of(t_bucket, s_bucket),
                             uids=[int(u) for u in batch_uids[:16]], blocked=bool(block), **moe_args,
-                            **(self._kv_span_args(t_bucket, s_bucket, [pos0]) if pos0 else {}))
+                            **(self._kv_span_args(t_bucket, s_bucket, [pos0]) if pos0 else {}),
+                            **self._tiled_kv_span_args(t_bucket, s_bucket, rb))
         if rf.enabled and block:
             # wall join through the blocking host fetch — the same window the
             # outer put() books as prefill/decode-active in the goodput ledger,
@@ -510,13 +511,37 @@ class InferenceEngineV2:
         for them, both summed over layers and steps
         (``paged_attention.decode_kv_counts``; ``pos``: the fed tokens'
         positions, a list of rows a step). Nothing for a shape no program has
-        traced yet or one the tiled prefill grid took."""
+        traced yet or one the tiled grid took, which says the same of itself
+        under names of its own (:meth:`_tiled_kv_span_args`)."""
         choice = kernel_choice(T, S, self._max_blocks_per_seq)
         if choice is None or choice["kernel"] == "paged_attn_q_tiled":
             return {}
         steps, live = decode_kv_counts(choice, pos, self._kv_windows, self.config.kv_block_size,
                                        self._max_blocks_per_seq, T)
         return {"kv_steps": steps, "kv_live": live}
+
+    def _tiled_kv_span_args(self, T: int, S: int, rb, forwards=((0, 1, 0), )) -> dict:
+        """What a step span says of ``paged_attn_q_tiled``'s grid, for a shape
+        that kernel took (nothing otherwise): ``tile_kv_live``, the live
+        (tile, KV block) pairs, which are the grid steps it ran, and
+        ``tile_kv_bound``, the tiles x table columns the shapes allow, both
+        summed over layers and forwards (``paged_attention.tiled_kv_counts``
+        on the batch's own arrays, as masked by: a block-diffusion model's
+        ``pos | (B - 1)``). ``forwards``: ``(offset, n, kv_only)``, ``n``
+        forwards at the batch's positions plus ``offset``, ``kv_only`` of
+        which stop before the last layer's attention."""
+        choice = kernel_choice(T, S, self._max_blocks_per_seq)
+        if choice is None or choice["kernel"] != "paged_attn_q_tiled":
+            return {}
+        last = self.model_config.layer_window(self.model_config.num_layers - 1)
+        bound = live = 0
+        for offset, n, kv_only in forwards:
+            # attention calls a window: its layers in every forward, less the last layer's in a commit
+            calls = [(w, layers * n - (kv_only if w == last else 0)) for w, layers in self._kv_windows]
+            b, l = tiled_kv_counts(choice["q_tile"], rb.token_seq_idx, (rb.token_pos + offset) | max(self._block - 1, 0),
+                                   calls, self.config.kv_block_size, self._max_blocks_per_seq, S)
+            bound, live = bound + b, live + l
+        return {"tile_kv_live": live, "tile_kv_bound": bound}
 
     def _kernel_of(self, T: int, S: int) -> str:
         """``<kernel>:<n>:<rule>`` (``n``: the tiled kernel's ``q_tile``, or the
@@ -846,7 +871,9 @@ class InferenceEngineV2:
                             denoise_forwards=n_denoise, commit_forwards=n_blocks, tokens_committed=sum(kept),
                             tokens_fed=S * B * n_fwd, masked_fed=int(masked_fed), tokens_dropped=new - sum(kept),
                             open_tokens=sum(opened),
-                            block_ms=round((time.perf_counter() - t_call) * 1e3 / n_blocks, 3), **moe_args)
+                            block_ms=round((time.perf_counter() - t_call) * 1e3 / n_blocks, 3), **moe_args,
+                            **self._tiled_kv_span_args(s_bucket * B, s_bucket, rb,
+                                                       [(b * B, int(n) + 1, 1) for b, n in enumerate(forwards)]))
         if reg.enabled:
             dt = time.perf_counter() - t_call
             reg.histogram("serving/decode_ms").observe(dt * 1e3)

@@ -94,8 +94,10 @@ def choose_kernel(T: int, S: int, max_blocks: int, nq: int, block_rows: int, d: 
       (``heuristic:long_rows``). A step with many rows for its tokens (a
       512-token SplitFuse ``put`` over 32 rows, a linear speculative verify of
       k+1 tokens a row) takes a smaller one (``heuristic:short_rows``),
-      because the grid has a tile per row beyond the chunks' own and each
-      costs a q and an output tile of DMA and its share of grid steps;
+      because a row beyond the chunks' own is a tile of its own and each
+      costs a q and an output tile of DMA and a grid step a live KV block
+      (the grid runs the live (tile, block) pairs alone:
+      :func:`_tiled_work_list`);
     - such a batch whose concrete ``seq_idx`` breaks the layout contract
       (:func:`_contiguity_ok`: the tiled grid would overflow its static tile
       bound and scatter tokens into the wrong tiles): ``contiguity_demoted``
@@ -307,23 +309,150 @@ def _lanes(x, n: int):
     return x if x.shape[1] == n else jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
 
+# what the scalar-prefetched work list of ``paged_attn_q_tiled`` may take of a
+# core's scalar memory. A v5e has 1 MiB and its compiler refuses the program
+# that passes it with whatever else it keeps there (compiled for a described
+# v5e, PR 34: a list of 1.00 MB passes, one of 1.21 MB is "Used 1.16M of 1.00M
+# smem"); half is the list's, eight times what the widest serving shape takes
+_TILED_SMEM_BYTES = 512 << 10
+
+
+def _tiled_smem_bytes(n_tiles: int, cols: int, S: int, max_blocks: int) -> int:
+    """Scalar memory ``paged_attn_q_tiled`` prefetches for a shape: the two
+    arrays of the work list, a tile's table row and token count, and the
+    block table, whose rows are padded to whole 128-lane words."""
+    return 4 * (2 * (n_tiles * cols + 1) + 2 * n_tiles + S * -(-max_blocks // _LANES) * _LANES)
+
+
+def _tile_runs(seq_idx, pos, q_tile: int, xp=jnp):
+    """The tile and the slot inside it of every token of a ragged batch: a
+    run is one sequence's chunk (the sequence changes, or the position falls:
+    the pad run, seq 0 at position 0, behind a chunk of row 0), and a run is
+    cut into tiles of ``q_tile`` tokens from its first token on, so no tile
+    spans two runs. ``xp`` is ``jnp`` for the traced program and ``numpy``
+    for the host's count of the same grid (:func:`tiled_kv_counts`)."""
+    T = pos.shape[0]
+    tok = xp.arange(T, dtype=xp.int32)
+    newrun = xp.concatenate([xp.ones((1, ), bool),
+                             xp.logical_or(seq_idx[1:] != seq_idx[:-1], pos[1:] < pos[:-1])])
+    marks = xp.where(newrun, tok, 0)
+    run_start = jax.lax.associative_scan(jnp.maximum, marks) if xp is jnp else np.maximum.accumulate(marks)
+    within = tok - run_start                      # offset inside this token's run
+    tile_id = xp.cumsum((within % q_tile == 0).astype(xp.int32)) - 1
+    return tile_id, within % q_tile
+
+
+def _tile_columns(tile_min, tile_max, tile_cnt, block_size: int, max_blocks: int, window, q_tile: int, xp=jnp):
+    """``(lo, n, cols)``: the first table column a tile's rows can see, how
+    many LIVE columns it has from there (0 for an empty tile), and the most
+    the shapes allow a tile. The last live column is that of the tile's
+    largest position; under a sliding window the first is that of its
+    smallest position's window edge, and since a run's positions ascend by
+    at most one (the layout contract) a tile spans at most ``(window +
+    q_tile - 2) // block_size + 2`` columns wherever in the context it lies
+    (the clip keeps a traced batch that breaks the contract inside the
+    arrays the bound sizes)."""
+    hi = xp.clip(tile_max // block_size, 0, max_blocks - 1)
+    if window is None:
+        lo, cols = xp.zeros_like(hi), max_blocks
+    else:
+        cols = min(max_blocks, (window + q_tile - 2) // block_size + 2)
+        lo = xp.clip(xp.maximum(tile_min - (window - 1), 0) // block_size, hi - cols + 1, hi)
+    return lo, xp.where(tile_cnt > 0, hi - lo + 1, 0), cols
+
+
+def _tiled_work_list(block_tables, seq_idx, pos, block_size: int, window, q_tile: int):
+    """The tiles of a ragged batch and the LIVE (tile, KV block) pairs of
+    their grid, as the int32 arrays ``paged_attn_q_tiled`` prefetches.
+
+    Tiles (:func:`_tile_runs`): ``n_tiles`` is the static bound ``ceil(T /
+    q_tile) + S + 1`` (interior splits, one ragged tail tile a sequence run,
+    the trailing pad run); a tile the batch does not fill is EMPTY. Returns
+    ``(tile_id, slot, tile_tok, valid, tile_seq, tile_cnt, w_tile, w_col,
+    total)``: token ``t`` sits in slot ``slot[t]`` of tile ``tile_id[t]``;
+    ``tile_tok``/``valid`` ``[n_tiles, q_tile]`` are each slot's token and
+    whether it holds one, ``tile_seq`` a tile's table row and ``tile_cnt``
+    its valid slots (a prefix).
+
+    The work list (:func:`_tile_columns`): tile ``i`` with tokens sees table
+    columns ``lo_i .. hi_i`` and has ``hi_i - lo_i + 1 >= 1`` items, an empty
+    tile none. Item ``k < total`` is tile ``w_tile[k]`` against table column
+    ``w_col[k]``, whose pool block is ``block_tables[tile_seq[w_tile[k]],
+    w_col[k]]``; items go tile after tile and ascend by column, so a tile's
+    items are consecutive. No item lies past a tile's last position or wholly
+    under its window. The arrays' length is one more than the most items the
+    SHAPES allow, ``n_tiles x min(max_blocks, columns a window can span)``
+    (prefix-shared blocks count once per tile that reads them, so the pool's
+    size bounds nothing); ``w_tile`` reads ``n_tiles`` from ``total`` on, so
+    ``w_tile[k + 1] != w_tile[k]`` marks the last item of every tile.
+
+    Both arrays are what an item inherits from its tile, spread over the
+    tile's items by a scatter of ``n_tiles`` steps and a running sum: a
+    gather an item (the first form of this list: four of 6,306 elements)
+    cost a call of 64 rows 0.24 ms of XLA time beside a kernel of 1.6 ms (my
+    chip run, PR 34)."""
+    T = pos.shape[0]
+    S, max_blocks = block_tables.shape
+    qt = int(q_tile)
+    n_tiles = -(-T // qt) + S + 1
+    tile_id, slot = _tile_runs(seq_idx, pos, qt)
+    tile_tok = jnp.zeros((n_tiles, qt), jnp.int32).at[tile_id, slot].set(jnp.arange(T, dtype=jnp.int32))
+    valid = jnp.zeros((n_tiles, qt), bool).at[tile_id, slot].set(True)
+    tile_pos = pos[tile_tok]
+    tile_seq = jnp.where(valid[:, 0], seq_idx[tile_tok[:, 0]], 0)
+    tile_cnt = jnp.sum(valid, axis=1, dtype=jnp.int32)
+    lo, n, cols = _tile_columns(jnp.min(jnp.where(valid, tile_pos, jnp.int32(2**30)), axis=1),
+                                jnp.max(jnp.where(valid, tile_pos, -1), axis=1), tile_cnt,
+                                block_size, max_blocks, window, qt)
+    bound = n_tiles * cols
+    start = jnp.cumsum(n) - n                     # the items before a tile's own
+    total = start[-1] + n[-1]
+    # a tile's number, and its first column less its first item: each steps at the tile's first item
+    # (an empty tile's step falls on the next tile's, and the sum passes over it)
+    of_tile = jnp.stack([jnp.arange(n_tiles, dtype=jnp.int32), lo - start])
+    steps = jnp.diff(of_tile, axis=1, prepend=0)
+    spread = jnp.cumsum(jnp.zeros((2, bound + 1), jnp.int32).at[:, start].add(steps), axis=1)
+    k = jnp.arange(bound + 1, dtype=jnp.int32)
+    return (tile_id, slot, tile_tok, valid, tile_seq, tile_cnt, jnp.where(k < total, spread[0], n_tiles),
+            jnp.clip(k + spread[1], 0, max_blocks - 1), total.astype(jnp.int32))
+
+
 def _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
                    block_size: int, q_tile: int, window, alibi, interpret: bool):
-    """Q-tiled grid: ``(n_tiles, kv_steps)`` where each tile packs up to
-    ``q_tile`` CONTIGUOUS same-sequence tokens, so every KV block streams
-    from HBM (and is cut into kv heads) once per *tile* instead of once per
-    token — a 2,048-token prefill chunk at q_tile=128 reads each of its KV
-    blocks 16x instead of 2,048x, and each kv head's dot feeds the MXU
-    ``g * q_tile`` query rows.
+    """Q-tiled kernel: grid steps for the LIVE (tile, KV block) pairs only.
+
+    Each tile packs up to ``q_tile`` CONTIGUOUS same-sequence tokens, so
+    every KV block streams from HBM (and is cut into kv heads) once per
+    *tile* instead of once per token — a 2,048-token prefill chunk at
+    q_tile=128 reads each of its KV blocks 16x instead of 2,048x, and each kv
+    head's dot feeds the MXU ``g * q_tile`` query rows.
 
     Tile assembly happens in jnp-land (traced, static shapes): a segmented
     tiling over the ragged batch — tiles never span a sequence boundary, so
-    one block-table row serves the whole grid row. ``n_tiles`` is the static
-    upper bound ceil(T/q_tile) + S + 1 (interior splits + one ragged tail
-    tile per sequence run + the trailing pad run); unused tiles carry
-    ``max_pos = -1`` and every kv step skips them. Ragged tile tails ride the
-    per-row position masking (invalid slots get pos = -1, masking every
-    context position).
+    one block-table row serves a whole tile — and beside it the work list of
+    :func:`_tiled_work_list`, scalar-prefetched. The grid is not ``tiles x
+    table columns`` but that list: step ``i`` is tile ``w_tile[i]`` against
+    table column ``w_col[i]``, the grid's length is the number of items (a
+    DYNAMIC bound: no step runs for a column past a tile's last position,
+    under its window, or of a tile no token fills; the static bound is only
+    the arrays' length), and the pipeline fetches the next item's block while
+    this one is computed, across tiles as within them. A tile's items are
+    consecutive and ascend by column, so each tile is ONE online-softmax
+    chain, begun where ``w_tile`` changes and normalised at its last item. A
+    batch of many short rows under a table as wide as the longest context
+    the engine admits (64 rows of 4 tokens at contexts of 2-17 blocks under
+    65 columns) costs its own blocks and nothing else. Ragged tile tails ride
+    the per-row position masking (invalid slots get pos = -1, masking every
+    context position). An EMPTY tile has no item, so its output tile is never
+    written: nothing reads it, because the scatter back to token order takes
+    only slots that tokens fill (the pad run's included: it is a real tile
+    with one live column).
+
+    The work list is two int32 arrays of ``n_tiles x columns + 1`` entries in
+    scalar memory beside the block table, which the K/V index maps read as the
+    rectangle's did (97 tiles x 65 columns and 64 table rows: 50 + 34 KB);
+    a shape whose list would pass ``_TILED_SMEM_BYTES`` raises here, at trace
+    time.
 
     Inside a grid step the block is cut into kv heads once (into a small
     scratch) and the heads are walked one at a time, so what lives at once is
@@ -342,11 +471,9 @@ def _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
     T, nq, d = q.shape
     nkv = k4.shape[2]
     g = nq // nkv
-    S, max_blocks = block_tables.shape
     qt = int(q_tile)
     quant = ks2 is not None
     scale = 1.0 / math.sqrt(d)
-    n_tiles = -(-T // qt) + S + 1
     G = g * qt                     # rows of one kv head in a tile
     R = nkv * G                    # == nq * qt
     short = min(G, g * _SHORT_TILE_TOKENS)
@@ -354,35 +481,16 @@ def _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
     # quantised (its dequantised values are float32)
     cdt = jnp.float32 if quant else jnp.promote_types(q.dtype, k4.dtype)
 
-    # --- segmented tile descriptors (contiguity contract: see paged_attention) ---
-    tok = jnp.arange(T, dtype=jnp.int32)
-    # a run is one sequence's chunk: the sequence changes, or the position
-    # falls (the pad run, seq 0 at position 0, behind a chunk of row 0)
-    newrun = jnp.concatenate([jnp.ones((1, ), bool),
-                              jnp.logical_or(seq_idx[1:] != seq_idx[:-1], pos[1:] < pos[:-1])])
-    run_start = jax.lax.associative_scan(jnp.maximum, jnp.where(newrun, tok, 0))
-    within = tok - run_start                      # offset inside this token's run
-    tile_id = jnp.cumsum((within % qt == 0).astype(jnp.int32)) - 1   # [T]
-    slot = within % qt
-
-    tile_tok = jnp.zeros((n_tiles, qt), jnp.int32).at[tile_id, slot].set(tok)
-    valid = jnp.zeros((n_tiles, qt), bool).at[tile_id, slot].set(True)
+    # --- segmented tiles and their live KV blocks (contiguity contract: see paged_attention) ---
+    tile_id, slot, tile_tok, valid, tile_seq, tile_cnt, w_tile, w_col, total = _tiled_work_list(
+        block_tables, seq_idx, pos, block_size, window, qt)
+    n_tiles = tile_cnt.shape[0]
+    cols = (w_tile.shape[0] - 1) // n_tiles
+    smem = _tiled_smem_bytes(n_tiles, cols, *block_tables.shape)
+    if smem > _TILED_SMEM_BYTES:
+        raise ValueError(f"paged_attn_q_tiled: the work list of {n_tiles} tiles x {cols} table columns takes {smem} "
+                         f"bytes of scalar memory, over {_TILED_SMEM_BYTES}")
     pos_t = jnp.where(valid, pos[tile_tok], -1)                      # [n_tiles, qt]
-    tile_seq = jnp.where(valid[:, 0], seq_idx[tile_tok[:, 0]], 0)    # [n_tiles]
-    tile_max = jnp.max(pos_t, axis=1)                                # -1 for empty tiles
-    tile_cnt = jnp.sum(valid, axis=1, dtype=jnp.int32)               # valid slots (a prefix)
-    # first KV block any row of the tile can see, and the kv steps a tile can
-    # need: under a sliding window the live blocks of a tile are a run of at
-    # most (window + q_tile) / block, wherever in the context it lies (a
-    # run's positions ascend by one: the layout contract), so the kv axis
-    # walks that run instead of the whole table
-    if window is not None:
-        tile_min = jnp.min(jnp.where(valid, pos_t, jnp.int32(2**30)), axis=1)
-        tile_lo = jnp.where(tile_cnt > 0, jnp.maximum(tile_min - (window - 1), 0) // block_size, 0)
-        kv_steps = min(max_blocks, (window + qt - 2) // block_size + 2)
-    else:
-        tile_lo = jnp.zeros_like(tile_cnt)
-        kv_steps = max_blocks
 
     # kv-head-major, then token-major row layout [n_tiles, R, d], row
     # r = n*G + t*g + h: each kv head's G query rows are contiguous and its
@@ -393,41 +501,32 @@ def _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
     q_t = q[tile_tok.reshape(-1)].reshape(n_tiles, qt, nkv, g, d).transpose(0, 2, 1, 3, 4) \
         .reshape(n_tiles, R, d)
     pos_rows = jnp.broadcast_to(jnp.repeat(pos_t, g, axis=1)[:, :, None], (n_tiles, G, _LANES))
-    grid = (n_tiles, kv_steps)
 
-    def q_map(i, j, seq_ref, max_ref, lo_ref, cnt_ref, bt_ref):
-        return (i, 0, 0)
+    def q_map(i, tile_ref, col_ref, seq_ref, cnt_ref, bt_ref):
+        return (tile_ref[i], 0, 0)
 
-    def kv_map(i, j, seq_ref, max_ref, lo_ref, cnt_ref, bt_ref):
-        # clamp the step into the tile's live range [lo, hi]: the index map
-        # runs (and its DMA issues) even for steps the kernel's pl.when
-        # skips, and Mosaic skips the refetch of a repeated block index
-        hi = jnp.maximum(max_ref[i], 0) // block_size
-        return (bt_ref[seq_ref[i], jnp.minimum(lo_ref[i] + j, hi)], 0, 0, 0)
+    def kv_map(i, tile_ref, col_ref, seq_ref, cnt_ref, bt_ref):
+        return (bt_ref[seq_ref[tile_ref[i]], col_ref[i]], 0, 0, 0)
 
-    def scale_map(i, j, *refs):
-        return (0, kv_map(i, j, *refs)[0])
+    def scale_map(i, *refs):
+        return (0, kv_map(i, *refs)[0])
 
     nt_dims = (((1, ), (1, )), ((), ()))  # [rows, d] x [block, d] -> [rows, block]
 
-    def kernel(seq_ref, max_ref, lo_ref, cnt_ref, bt_ref, q_ref, k_ref, v_ref, pos_ref, *rest):
+    def kernel(tile_ref, col_ref, seq_ref, cnt_ref, bt_ref, q_ref, k_ref, v_ref, pos_ref, *rest):
         if quant:
             ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref, kh_ref, vh_ref = rest
         else:
             o_ref, acc_ref, m_ref, l_ref, kh_ref, vh_ref = rest
         i = pl.program_id(0)
-        j = pl.program_id(1)
-        jb = lo_ref[i] + j  # the table column this step covers
+        tile = tile_ref[i]
+        jb = col_ref[i]  # the table column this step covers
 
-        @pl.when(j == 0)
+        @pl.when(jnp.logical_or(i == 0, tile_ref[jnp.maximum(i - 1, 0)] != tile))
         def _init():
             acc_ref[:] = jnp.zeros_like(acc_ref)
             m_ref[:] = jnp.full_like(m_ref, -1e30)
             l_ref[:] = jnp.zeros_like(l_ref)
-
-        # blocks under the window's lower edge lie before lo; an empty tile
-        # has max = -1 and is skipped at every step
-        in_window = jb * block_size <= max_ref[i]
 
         def _compute(rows):
             """One KV block against the first ``rows`` rows of every kv head."""
@@ -477,13 +576,13 @@ def _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
                     head(n)
 
         if short < G:
-            is_short = cnt_ref[i] <= _SHORT_TILE_TOKENS
-            pl.when(jnp.logical_and(in_window, is_short))(lambda: _compute(short))
-            pl.when(jnp.logical_and(in_window, jnp.logical_not(is_short)))(lambda: _compute(G))
+            is_short = cnt_ref[tile] <= _SHORT_TILE_TOKENS
+            pl.when(is_short)(lambda: _compute(short))
+            pl.when(jnp.logical_not(is_short))(lambda: _compute(G))
         else:
-            pl.when(in_window)(lambda: _compute(G))
+            _compute(G)
 
-        @pl.when(j == kv_steps - 1)
+        @pl.when(tile_ref[i + 1] != tile)
         def _finalize():
             o_ref[0] = (acc_ref[:] / _lanes(jnp.maximum(l_ref[:], 1e-30), d)).astype(o_ref.dtype)
 
@@ -501,7 +600,7 @@ def _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
-        grid=grid,
+        grid=(total, ),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, R, d), q_map),
         scratch_shapes=[
@@ -523,8 +622,8 @@ def _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
     out_t = pl.pallas_call(kernel, grid_spec=grid_spec,
                            out_shape=jax.ShapeDtypeStruct((n_tiles, R, d), q.dtype),
                            interpret=interpret, name="paged_attn_q_tiled", **kwargs)(
-                               tile_seq, tile_max, tile_lo, tile_cnt, block_tables, *operands)
-    # scatter tiles back to token order
+                               w_tile, w_col, tile_seq, tile_cnt, block_tables, *operands)
+    # scatter tiles back to token order: only slots that tokens fill are read
     flat = out_t.reshape(n_tiles, nkv, qt, g, d).transpose(0, 2, 1, 3, 4).reshape(n_tiles * qt, nq, d)
     return flat[tile_id * qt + slot]
 
@@ -549,7 +648,8 @@ def decode_kv_counts(choice, pos, windows, block_size: int, max_blocks: int, buc
     (:func:`_decode_work_list`'s count, real rows only) and ``kv_steps`` the
     block slots the chosen kernel's grid runs for them, pad rows included:
     the decode kernel's items times the blocks an item takes, every table
-    column of every bucket row for the gather (the tiled grid is not asked).
+    column of every bucket row for the gather (a shape the tiled grid took
+    is :func:`tiled_kv_counts`'s, under names of its own).
     ``choice`` is the shape's :func:`kernel_choice`; ``pos`` the positions of
     the fed tokens, ``[..., rows]`` (one leading entry a step); ``windows``
     pairs of (window or None, layers that attend in it)."""
@@ -567,6 +667,33 @@ def decode_kv_counts(choice, pos, windows, block_size: int, max_blocks: int, buc
         else:
             steps += layers * calls * bucket_rows * max_blocks
     return steps, live
+
+
+def tiled_kv_counts(q_tile: int, seq_idx, pos, windows, block_size: int, max_blocks: int, bucket_rows: int):
+    """``(tile_kv_bound, tile_kv_live)`` of one forward's ``paged_attn_q_tiled``
+    calls, on the host: ``tile_kv_live`` the items of :func:`_tiled_work_list`
+    (the grid steps the kernel ran: the same run, tile and column rules,
+    applied in numpy) and ``tile_kv_bound`` the rectangle the shapes allow,
+    ``n_tiles x min(max_blocks, columns a window can span)``, which the grid
+    walked until PR 34. ``seq_idx``/``pos``: the batch's tokens as the kernel
+    is given them, the pad run included (``pos`` what it MASKS by: a
+    block-diffusion model's ``pos | (B - 1)``); ``windows`` as for
+    :func:`decode_kv_counts`, over whose layers both counts are summed."""
+    seq_idx, pos = np.asarray(seq_idx, np.int32), np.asarray(pos, np.int32)
+    qt = int(q_tile)
+    n_tiles = -(-pos.size // qt) + bucket_rows + 1
+    tile_id, _ = _tile_runs(seq_idx, pos, qt, xp=np)
+    tile_min = np.full(n_tiles, 2**30, np.int32)
+    tile_max = np.full(n_tiles, -1, np.int32)
+    np.minimum.at(tile_min, tile_id, pos)
+    np.maximum.at(tile_max, tile_id, pos)
+    tile_cnt = np.bincount(tile_id, minlength=n_tiles)
+    bound = live = 0
+    for window, layers in windows:
+        _, n, cols = _tile_columns(tile_min, tile_max, tile_cnt, block_size, max_blocks, window, qt, xp=np)
+        live += layers * int(n.sum())
+        bound += layers * n_tiles * cols
+    return bound, live
 
 
 def _decode_work_list(block_tables, seq_idx, pos, block_size: int, window, per_step: int = 1):

@@ -14,8 +14,8 @@ import pytest
 from deepspeed_tpu.models.transformer import alibi_slopes
 from deepspeed_tpu.ops.pallas import paged_attention as pa_mod
 from deepspeed_tpu.ops.pallas.paged_attention import (_contiguity_ok, _decode_work_list, _pallas_paged,
-                                                      choose_kernel, decode_kv_counts,
-                                                      paged_attention_reference)
+                                                      _tiled_work_list, choose_kernel, decode_kv_counts,
+                                                      paged_attention_reference, tiled_kv_counts)
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir))
 
@@ -58,24 +58,59 @@ def _mixed_batch(rng, nq, d, bs):
     return q, jnp.asarray(seq_idx), jnp.asarray(pos)
 
 
+def _rows_of_4_batch(rng, nq, d, bs, n_rows, T, dtype=jnp.float32):
+    """The block-diffusion cell's forward in small: ``n_rows`` rows of one
+    4-token block each, at contexts of 1-5 KV blocks of 16 under a table
+    several times wider, every token masked by its block's LAST position
+    (``pos | 3``, as ``ragged_forward`` hands it to the kernels), then the pad
+    run up to ``T`` tokens. Most of the static tile bound stays empty."""
+    seq_idx = np.repeat(np.arange(n_rows), 4)
+    pos = np.concatenate([np.arange(4) + 4 * (2 + 3 * (r % 6)) for r in range(n_rows)]) | 3
+    assert pos.max() // bs <= 4 and seq_idx.size < T
+    seq_idx, pos = (np.pad(a, (0, T - a.size)).astype(np.int32) for a in (seq_idx, pos))
+    q = jnp.asarray(rng.normal(size=(T, nq, d)), dtype)
+    return q, jnp.asarray(seq_idx), jnp.asarray(pos)
+
+
+def _unwritten_tiles_reach_no_token(tables, seq_idx, pos, bs, window, q_tile):
+    """An empty tile has no item, so the kernel never writes its output tile;
+    the scatter back reads ``tile_id * q_tile + slot`` of every token, the pad
+    run's included: those are exactly the tiles the grid wrote. Returns how
+    many tiles stayed unwritten."""
+    tile_id, slot, tile_tok, valid, _, tile_cnt, w_tile, _, total = (
+        np.asarray(a) for a in _tiled_work_list(tables, seq_idx, pos, bs, window, q_tile))
+    written = set(w_tile[:int(total)].tolist())
+    assert written == set(np.flatnonzero(tile_cnt).tolist()) == set(tile_id.tolist())
+    assert valid[tile_id, slot].all() and (tile_tok[tile_id, slot] == np.arange(tile_id.size)).all()
+    return tile_cnt.size - len(written)
+
+
 @pytest.mark.parametrize("q_tile", [4, 8])
 @pytest.mark.parametrize("case", ["plain", "int8", "alibi", "window", "window_alibi",
-                                  "int8_window", "gqa"])
+                                  "int8_window", "gqa", "rows_of_4", "rows_of_4_window"])
 def test_qtiled_parity_matrix(case, q_tile):
     """The q-tiled grid must match the gather oracle bit-for-tolerance on
     every kernel feature — ragged tile tails, int8 dequant-at-tile-read,
     alibi, sliding window, GQA — on a mixed prefill+decode batch, and so
-    must the decode kernel, which serves the same batch a token a row."""
+    must the decode kernel, which serves the same batch a token a row.
+    ``rows_of_4``: the block-diffusion cell's shape in small, six rows of 4
+    tokens under the block bound and a 16-column table of which no row fills
+    more than 5, where most tiles of the static bound hold nothing and are
+    never written."""
     import zlib
 
     nkv, g = (2, 4) if case == "gqa" else (2, 2)
     int8 = case.startswith("int8")
+    rows4 = case.startswith("rows_of_4")
     # crc32, not hash(): PYTHONHASHSEED salting would make a tolerance-edge
     # failure unreproducible across runs
-    rng, nq, kp, vp, tables, scales = _paged_setup(seed=zlib.crc32(case.encode()), nkv=nkv,
-                                                   g=g, int8=int8)
+    rng, nq, kp, vp, tables, scales = _paged_setup(seed=zlib.crc32(case.encode()), nkv=nkv, g=g, int8=int8,
+                                                   **(dict(n_seqs=6, blocks_per_seq=16) if rows4 else {}))
     d, bs = 32, 16
-    q, seq_idx, pos = _mixed_batch(rng, nq, d, bs)
+    q, seq_idx, pos = _rows_of_4_batch(rng, nq, d, bs, 6, 32) if rows4 else _mixed_batch(rng, nq, d, bs)
+    if rows4:  # 8 or 6 run tiles and the pad run's of a bound of 32 / q_tile + 7
+        assert _unwritten_tiles_reach_no_token(tables, seq_idx, pos, bs, 17 if "window" in case else None,
+                                               q_tile) == {4: 7, 8: 4}[q_tile]
     kw = dict(scales)
     if "alibi" in case:
         kw["alibi"] = tuple(alibi_slopes(nq).tolist())
@@ -107,26 +142,35 @@ def _prefill_batch(rng, nq, d, bs, q_tile, dtype):
 
 @pytest.mark.parametrize("q_tile", [32, 128])
 @pytest.mark.parametrize("case", ["plain", "window", "alibi", "window_alibi", "int8", "int8_window",
-                                  "gqa4", "f32", "f32_window_alibi"])
+                                  "gqa4", "f32", "f32_window_alibi", "rows_of_4", "rows_of_4_window"])
 def test_qtiled_prefill_tile_parity_matrix(case, q_tile):
     """The tiles the shape heuristic picks for prefill (32 for many rows, 128
     for long prompts) against the gather oracle: bf16 q and pool, so both
     dots run on bf16 operands with float32 accumulation, at the tolerances
     the chip tests hold bf16 and int8 to; float32 inputs at the interpret
     matrix's. The window (50) is shorter than a tile, so its lower edge
-    crosses KV blocks inside one tile."""
+    crosses KV blocks inside one tile. ``rows_of_4``: the block-diffusion
+    cell's forward (:func:`_rows_of_4_batch`, 12 rows under a 24-column
+    table), every tile a short one."""
     import zlib
 
     f32 = case.startswith("f32")
+    rows4 = case.startswith("rows_of_4")
     dtype = jnp.float32 if f32 else jnp.bfloat16
     nkv, g = (2, 4) if case == "gqa4" else (2, 2)
     d, bs, blocks_per_seq = 32, 16, 24
     rng, nq, kp, vp, tables, scales = _paged_setup(seed=zlib.crc32(case.encode()), nkv=nkv, g=g, d=d, bs=bs,
-                                                   n_seqs=4, blocks_per_seq=blocks_per_seq,
+                                                   n_seqs=12 if rows4 else 4, blocks_per_seq=blocks_per_seq,
                                                    int8=case.startswith("int8"))
     if not scales:
         kp, vp = kp.astype(dtype), vp.astype(dtype)
-    q, seq_idx, pos = _prefill_batch(rng, nq, d, bs, q_tile, dtype)
+    if rows4:
+        q, seq_idx, pos = _rows_of_4_batch(rng, nq, d, bs, 12, 64, dtype)
+        # 12 run tiles and the pad run's, of ceil(64 / q_tile) + 13
+        assert _unwritten_tiles_reach_no_token(tables, seq_idx, pos, bs, 50 if "window" in case else None,
+                                               q_tile) == -(-64 // q_tile)
+    else:
+        q, seq_idx, pos = _prefill_batch(rng, nq, d, bs, q_tile, dtype)
     assert int(pos.max()) < blocks_per_seq * bs
     kw = dict(scales)
     if "alibi" in case:
@@ -338,6 +382,85 @@ def test_decode_work_list_holds_exactly_the_live_pairs(window, per_step):
     # the gather walks the whole table: every column of every bucket row
     assert decode_kv_counts({"kernel": "paged_attention_reference", "blocks_per_step": 1}, pos[:real],
                             [(window, 3)], bs, mb, T) == (3 * T * mb, 3 * live)
+
+
+def _tiled_batch(bs, bound4):
+    """A chunk deep in row 0's context, five decode rows, a short chunk of a
+    second prompt from position 0, and the pad run: 8 table rows, 96 tokens.
+    ``bound4``: under a block-diffusion model's bound, chunks on 4-token
+    boundaries and every token masked by its block's last position."""
+    runs = [(0, 200, 44), (1, 7 * bs, 1), (2, 1039, 1), (3, 15, 1), (4, 16, 1), (5, 12 * bs - 1, 1), (6, 0, 20)]
+    if bound4:
+        runs = [(r, p // 4 * 4, -(-n // 4) * 4) for r, p, n in runs]
+    seq_idx = np.concatenate([np.full(n, r) for r, _, n in runs])
+    pos = np.concatenate([np.arange(p, p + n) for _, p, n in runs])
+    pad = 96 - seq_idx.size
+    assert pad > 0
+    seq_idx, pos = (np.pad(a, (0, pad)).astype(np.int32) for a in (seq_idx, pos))
+    return seq_idx, (pos | 3 if bound4 else pos)
+
+
+@pytest.mark.parametrize("bound4", [False, True], ids=["causal", "block_bound"])
+@pytest.mark.parametrize("window", [None, 40, 300])
+@pytest.mark.parametrize("q_tile", [8, 32, 128])
+def test_tiled_work_list_holds_exactly_the_live_pairs(q_tile, window, bound4):
+    """The grid of the tiled kernel IS the work list: against a plain
+    enumeration of the tiles (a run cut every ``q_tile`` tokens) and of the
+    table columns in which some token of a tile has a key in sight, the items
+    are exactly those (tile, column) pairs, each once, tile after tile and
+    ascending by column, under the tile's table row; an empty tile has no item,
+    no item lies past a tile's last position or under its window; the arrays
+    are as long as the shapes' bound and one more; and the host's
+    ``tiled_kv_counts`` counts the same items and the same rectangle."""
+    bs, mb, S = 16, 65, 8
+    seq_idx, pos = _tiled_batch(bs, bound4)
+    T = pos.size
+    tables = np.random.default_rng(4).integers(0, 500, size=(S, mb)).astype(np.int32)
+    tile_id, slot, tile_tok, valid, tile_seq, tile_cnt, w_tile, w_col, total = (
+        np.asarray(a) for a in _tiled_work_list(jnp.asarray(tables), jnp.asarray(seq_idx), jnp.asarray(pos), bs,
+                                                window, q_tile))
+    total = int(total)
+    # the plain enumeration: runs, then tiles, then each tile's columns
+    tiles, run = [], []
+    for t in range(T):
+        if t and (seq_idx[t] != seq_idx[t - 1] or pos[t] < pos[t - 1]):
+            tiles += [run[i:i + q_tile] for i in range(0, len(run), q_tile)]
+            run = []
+        run.append(t)
+    tiles += [run[i:i + q_tile] for i in range(0, len(run), q_tile)]
+    want = [(i, c) for i, toks in enumerate(tiles) for c in range(mb)
+            if any(c * bs <= pos[t] and (window is None or (c + 1) * bs - 1 > pos[t] - window) for t in toks)]
+    n_tiles = -(-T // q_tile) + S + 1
+    cols = mb if window is None else min(mb, (window + q_tile - 2) // bs + 2)
+    assert len(tiles) < n_tiles and tile_cnt.shape == (n_tiles, )
+    assert tile_cnt.tolist() == [len(toks) for toks in tiles] + [0] * (n_tiles - len(tiles))
+    assert all(tile_tok[i, :len(toks)].tolist() == toks and valid[i].sum() == len(toks) for i, toks in enumerate(tiles))
+    assert w_tile.shape == w_col.shape == (n_tiles * cols + 1, ) and (w_col < mb).all()
+    assert list(zip(w_tile[:total].tolist(), w_col[:total].tolist())) == want and total == len(want)
+    assert (w_tile[total:] == n_tiles).all()
+    assert tile_seq.tolist() == [seq_idx[toks[0]] for toks in tiles] + [0] * (n_tiles - len(tiles))
+    for i, toks in enumerate(tiles):
+        mine = [c for j, c in want if j == i]
+        assert mine and mine[-1] == pos[toks].max() // bs and len(mine) <= cols
+        assert window is None or mine[0] == max(pos[toks].min() - window + 1, 0) // bs
+    assert tiled_kv_counts(q_tile, seq_idx, pos, [(window, 1)], bs, mb, S) == (n_tiles * cols, total)
+    assert tiled_kv_counts(q_tile, seq_idx, pos, [(window, 3), (None, 2)], bs, mb, S) == (
+        3 * n_tiles * cols + 2 * n_tiles * mb,
+        3 * total + 2 * tiled_kv_counts(q_tile, seq_idx, pos, [(None, 1)], bs, mb, S)[1])
+
+
+def test_a_work_list_past_the_scalar_memory_raises_at_trace_time():
+    """The list's two arrays live in scalar memory beside the block table: a shape whose bound
+    would not fit is refused while the program is traced, by name, and there
+    is no other grid to fall back to."""
+    sd = jax.ShapeDtypeStruct
+    args = lambda mb: (sd((256, 4, 32), jnp.float32), sd((64, 2, 32), jnp.float32), sd((64, 2, 32), jnp.float32),
+                       sd((64, mb), jnp.int32), sd((256, ), jnp.int32), sd((256, ), jnp.int32))
+    fn = lambda *a: _pallas_paged(*a, block_size=16, interpret=True, q_tile=8)
+    assert pa_mod._tiled_smem_bytes(97, 65, 64, 65) == 83992          # the claimed cell's forward
+    assert jax.eval_shape(fn, *args(65)).shape == (256, 4, 32)
+    with pytest.raises(ValueError, match="97 tiles x 700 table columns takes 740592 bytes of scalar memory"):
+        jax.eval_shape(fn, *args(700))
 
 
 def test_decode_kernel_full_table_and_single_block_rows():
@@ -560,8 +683,9 @@ def test_no_file_or_environment_can_change_the_choice(tmp_path, monkeypatch, on_
 def test_qtiled_pad_run_behind_row0_under_window(q_tile):
     """A one-row batch deep in its context, then the pad run (seq 0 again,
     position 0): the fall in position starts a new run, so no tile mixes the
-    chunk's positions with the pad's and the window-bounded kv axis (here 7
-    and 13 steps of a 32-block table) still covers every row's blocks."""
+    chunk's positions with the pad's and the columns the work list allows a
+    tile under the window (here 7 and 13 of a 32-block table) still cover
+    every row's blocks: each tile's items are its own 4 to 12 blocks."""
     rng, nq, kp, vp, tables, _ = _paged_setup(seed=3, n_seqs=2, blocks_per_seq=32)
     d, bs, window = 32, 16, 50
     n = q_tile + 9
@@ -571,6 +695,11 @@ def test_qtiled_pad_run_behind_row0_under_window(q_tile):
     q = jnp.asarray(rng.normal(size=(n + 6, nq, d)), jnp.float32)
     assert _contiguity_ok(seq_idx, 1, pos) and not _contiguity_ok(seq_idx, 1, pos[::-1])
     assert not _contiguity_ok(seq_idx, 1, pos.at[3].add(7))  # a jump forward inside a run
+    *_, tile_cnt, w_tile, _, total = (np.asarray(a) for a in _tiled_work_list(tables, seq_idx, pos, bs, window, q_tile))
+    cols = {32: 7, 128: 13}[q_tile]
+    assert w_tile.size == tile_cnt.size * cols + 1
+    per_tile = np.bincount(w_tile[:int(total)])
+    assert per_tile.tolist() == {32: [6, 5, 1], 128: [12, 5, 1]}[q_tile] and per_tile.max() <= cols
     ref = paged_attention_reference(q, kp, vp, tables, seq_idx, pos, bs, window=window)
     out = _pallas_paged(q, kp, vp, tables, seq_idx, pos, block_size=bs, interpret=True,
                         q_tile=q_tile, window=window)

@@ -198,6 +198,44 @@ def test_decode_spans_count_the_kernels_grid_and_the_live_pairs(window):
     assert 0 < burst["kv_live"] <= burst["kv_steps"]
 
 
+@pytest.mark.parametrize("window", [None, 24])
+def test_spans_of_a_shape_the_tiled_kernel_took_count_its_work_list(window, monkeypatch):
+    """``serving/prefill`` of a shape ``paged_attn_q_tiled`` took carries
+    ``tile_kv_live``, the work list's ``total`` on the same batch, and
+    ``tile_kv_bound``, the tiles x columns rectangle of the shapes, both times
+    the layers; not ``kv_live``/``kv_steps``, which stay the decode grid's;
+    a shape another kernel took carries neither of the two new counts. (Off
+    the TPU no shape takes the tiled kernel: the choice is planted.)"""
+    engine = _engine(window)
+    rng = np.random.default_rng(2)
+    tokens = lambda n: rng.integers(0, 128, size=n, dtype=np.int32)
+    engine.put([7], [tokens(30)]), engine.put([8], [tokens(9)])
+    one = [np.asarray([3], np.int32)]
+    engine.put([7, 8, 9], one * 2 + [tokens(20)])              # trace the programs first
+    engine.put([7, 8], one * 2)
+    max_blocks, layers = 96 // 16, 2
+    monkeypatch.setitem(pa.KERNEL_CHOICES, (32, 4, max_blocks),
+                        {"kernel": "paged_attn_q_tiled", "q_tile": 8, "blocks_per_step": 1, "rule": "planted"})
+    batches = []
+    finalize = engine.batch.finalize
+    monkeypatch.setattr(engine.batch, "finalize", lambda: batches.append(finalize()) or batches[-1])
+    tracer = get_tracer().configure(enabled=True)
+    engine.put([7, 8, 9], one * 2 + [tokens(20)])              # 22 tokens: the bucket of 32 x 4, two decode rows
+    engine.put([7, 8], one * 2)                                # 8 x 4: the decode grid's
+    events = {e["name"]: e.get("args", {}) for e in tracer.drain() if e["ph"] == "X"}
+    mixed, step = events["serving/prefill"], events["serving/decode_step"]
+    assert mixed["kernel"] == "paged_attn_q_tiled:8:planted" and (mixed["bucket_tokens"], mixed["bucket_rows"]) == (32, 4)
+    rb = batches[0]
+    *_, total = pa._tiled_work_list(jnp.asarray(rb.block_tables), jnp.asarray(rb.token_seq_idx),
+                                    jnp.asarray(rb.token_pos), 16, window, 8)
+    n_tiles = 32 // 8 + 4 + 1
+    cols = max_blocks if window is None else min(max_blocks, (window + 8 - 2) // 16 + 2)
+    assert mixed["tile_kv_live"] == layers * int(total) and mixed["tile_kv_bound"] == layers * n_tiles * cols
+    assert 0 < mixed["tile_kv_live"] < mixed["tile_kv_bound"]
+    assert not {"kv_live", "kv_steps"} & set(mixed)
+    assert {"kv_live", "kv_steps"} <= set(step) and not {"tile_kv_live", "tile_kv_bound"} & set(step)
+
+
 def test_bus_sees_the_same_spans_once_each_under_their_bus_names(engine, serve_trace):
     tracer = get_tracer().configure(enabled=True)  # pathless buffer
     _serve(engine, uid_base=300)

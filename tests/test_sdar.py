@@ -213,6 +213,47 @@ def test_the_engine_generates_what_the_reference_generates(weights, strategy, st
         assert len(forwards) < 2 + 2 * 4, "over so low a threshold some forward unmasks several positions"
 
 
+def test_a_block_calls_span_counts_the_tiled_grid_forward_by_forward(weights, monkeypatch):
+    """``serving/decode`` of a shape ``paged_attn_q_tiled`` took (planted: off
+    the TPU none does) carries ``tile_kv_live`` and ``tile_kv_bound`` summed
+    over the call's forwards and layers: a block's denoise forwards run every
+    layer's attention and its commit all but the last layer's, each at the
+    block's own positions under the bound ``pos | 3``, the pad run's too; not
+    ``kv_live``/``kv_steps``."""
+    from deepspeed_tpu.monitor.trace import get_tracer
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    model, params = weights
+    engine = _engine(model, params)
+    uids, prompts = [3, 4, 5], [_prompt(40, 1), _prompt(12, 2), _prompt(23, 3)]
+    known = [_prefill(engine, uid, p, (p.size // B * B, )) for uid, p in zip(uids, prompts)]
+    engine.decode(uids, known, 8)                                    # trace the program first
+    layers, max_blocks, S = model.config.num_layers, 192 // 16, 8
+    monkeypatch.setitem(pa.KERNEL_CHOICES, (S * B, S, max_blocks),
+                        {"kernel": "paged_attn_q_tiled", "q_tile": 8, "blocks_per_step": 1, "rule": "planted"})
+    batches, finalize = [], engine._block_batch.finalize
+    monkeypatch.setattr(engine._block_batch, "finalize", lambda: batches.append(finalize()) or batches[-1])
+    get_tracer().reset()
+    tracer = get_tracer().configure(enabled=True)
+    try:
+        engine.decode(uids, None, 8)
+        (span, ) = [e["args"] for e in tracer.drain() if e["ph"] == "X" and e["name"] == "serving/decode"]
+    finally:
+        get_tracer().reset()
+    assert span["kernel"] == "paged_attn_q_tiled:8:planted" and span["blocks"] == 2
+    assert (span["denoise_forwards"], span["commit_forwards"]) == (8, 2)
+    (rb, ) = batches                                                 # the call's descriptor: its first block
+    tables, seq_idx = jnp.asarray(rb.block_tables), jnp.asarray(rb.token_seq_idx)
+    live = 0
+    for b in range(2):
+        *_, total = pa._tiled_work_list(tables, seq_idx, jnp.asarray((rb.token_pos + b * B) | (B - 1)), 16, None, 8)
+        live += (4 * layers + (layers - 1)) * int(total)
+    n_tiles = S * B // 8 + S + 1
+    assert span["tile_kv_live"] == live > 0
+    assert span["tile_kv_bound"] == 2 * (4 * layers + layers - 1) * n_tiles * max_blocks
+    assert not {"kv_live", "kv_steps"} & set(span)
+
+
 def _scheduler(model, params, **kw):
     engine = _engine(model, params, **kw)
     return engine, DynamicSplitFuseScheduler(engine, token_budget=32)

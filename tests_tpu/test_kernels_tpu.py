@@ -3,6 +3,8 @@ windowed, alibi), paged attention, blockwise quant, fused Adam. The CPU
 suite runs these in interpret mode; Mosaic compilation differences only
 show up here."""
 
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -370,8 +372,55 @@ def _paged_reference_by_run(q, k_pool, v_pool, tables, rows, bs, window):
     return jnp.concatenate(outs, 0)
 
 
-@pytest.mark.parametrize("nq,window", [(32, 4096), (48, 4096), (48, None)],
-                         ids=["group4.window", "group6.window", "group6.full"])
+def _us_a_call(fn, *args, calls=50):
+    """Microseconds a call of a compiled ``fn``, the jnp code around the
+    kernel included (tile assembly, work list, scatter back): ``calls``
+    dispatches behind a warm one, one wait at the end."""
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(calls):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / calls * 1e6
+
+
+# microseconds a call of the PARENT's rectangle grid (commit 6c807fc, this file's tests run in its tree on the same
+# chip in the same call: my chip run, PR 34), to read beside what the tests print; nothing is asserted of either.
+# A host clock over 50 dispatches: under about 400 us it reads the dispatch, not the device (PERF.md section 6, PR 34,
+# has the device's own time, kernel and XLA code apart, from a profiler trace of the same shapes)
+_PARENT_US = {
+    ("serving_cells_shapes", "longprompt-32/8-4096"): 2039,
+    ("serving_cells_shapes", "longprompt-48/8-4096"): 3026,
+    ("serving_cells_shapes", "longprompt-48/8-None"): 4747,
+    ("serving_cells_shapes", "longprompt-32/4-1024"): 994,
+    ("serving_cells_shapes", "longprompt_mixed-32/8-4096"): 1776,
+    ("serving_cells_shapes", "longprompt_mixed-48/8-4096"): 2645,
+    ("serving_cells_shapes", "longprompt_mixed-48/8-None"): 2873,
+    ("serving_cells_shapes", "longprompt_mixed-32/4-1024"): 1064,
+    ("serving_cells_shapes", "chat-32/8-4096"): 595,
+    ("serving_cells_shapes", "chat-48/8-4096"): 857,
+    ("serving_cells_shapes", "chat-48/8-None"): 1086,
+    ("serving_cells_shapes", "chat-32/4-1024"): 579,
+    ("serving_cells_shapes", "put_64x8-32/8-4096"): 362,
+    ("serving_cells_shapes", "put_64x8-48/8-4096"): 415,
+    ("serving_cells_shapes", "put_64x8-48/8-None"): 479,
+    ("serving_cells_shapes", "put_64x8-32/4-1024"): 381,
+    ("serving_cells_shapes", "put_512x64-32/8-4096"): 1512,
+    ("serving_cells_shapes", "put_512x64-48/8-4096"): 1829,
+    ("serving_cells_shapes", "put_512x64-48/8-None"): 2298,
+    ("serving_cells_shapes", "put_512x64-32/4-1024"): 1686,
+    ("block_causal_bound", "forward_64x4"): 2640,
+    ("block_causal_bound", "forward_8x4"): 229,
+    ("block_causal_bound", "chunk_512"): 449,
+}
+
+
+def _say_us(test, case, us):
+    print(f"\n{test}[{case}]: {us:.0f} us a call (parent's rectangle: {_PARENT_US.get((test, case), 'not measured')})")
+
+
+@pytest.mark.parametrize("nq,nkv,window", [(32, 8, 4096), (48, 8, 4096), (48, 8, None), (32, 4, 1024)],
+                         ids=["group4.window", "group6.window", "group6.full", "group8.window1024"])
 @pytest.mark.parametrize("name,T,S,rows,want", [
     # mistral-7b.longprompt: the last 2,048-token chunk of an 8,192-token prompt
     ("longprompt", 2048, 8, [(6144, 2048)], (128, "heuristic:long_rows")),
@@ -386,16 +435,17 @@ def _paged_reference_by_run(q, k_pool, v_pool, tables, rows, bs, window):
     ("put_64x8", 64, 8, [(300 + 700 * i, 1) for i in range(6)] + [(4200, 40)], (16, "heuristic:short_rows")),
     ("put_512x64", 512, 64, [(262 + 29 * i, 1) for i in range(60)] + [(100, 440)], (16, "heuristic:short_rows")),
 ])
-def test_paged_q_tiled_at_the_serving_cells_shapes(name, T, S, rows, want, nq, window):
+def test_paged_q_tiled_at_the_serving_cells_shapes(name, T, S, rows, want, nq, nkv, window):
     """``paged_attention`` as the serving engine calls it, at the shapes of
     the benchmark's serving cells (32/8 heads of 128, and 48/8, a GQA group of
-    6 that is no multiple of the 8 sublanes: PR 31; 128-token blocks, tables 65
-    wide, window 4,096 or none, bf16): the tile the heuristic picks, against
-    a float32 reference. A kernel that does not fit VMEM fails to compile
-    here, loudly."""
+    6 that is no multiple of the 8 sublanes: PR 31; 32/4 under Mellum's window
+    of 1,024: PR 34; 128-token blocks, tables 65 wide, window 4,096 or none,
+    bf16): the tile the heuristic picks, on the grid of live (tile, KV block)
+    pairs, against a float32 reference. A kernel that does not fit VMEM fails
+    to compile here, loudly. Prints the microseconds a call."""
     from deepspeed_tpu.ops.pallas import paged_attention as pa
 
-    nkv, d, bs, mb, n_blocks = 8, 128, 128, 65, 619
+    d, bs, mb, n_blocks = 128, 128, 65, 619
     rng = np.random.default_rng(25)
     k_pool = jnp.asarray(rng.normal(size=(n_blocks * bs, nkv, d)), jnp.bfloat16)
     v_pool = jnp.asarray(rng.normal(size=(n_blocks * bs, nkv, d)), jnp.bfloat16)
@@ -408,9 +458,11 @@ def test_paged_q_tiled_at_the_serving_cells_shapes(name, T, S, rows, want, nq, w
     q = jnp.asarray(rng.normal(size=(T, nq, d)), jnp.bfloat16)
 
     pa.KERNEL_CHOICES.pop((T, S, mb), None)
-    out = jax.jit(lambda q: pa.paged_attention(q, k_pool, v_pool, tables, seq_idx, pos, bs, window=window))(q)
+    fn = jax.jit(lambda q: pa.paged_attention(q, k_pool, v_pool, tables, seq_idx, pos, bs, window=window))
+    out = fn(q)
     choice = pa.kernel_choice(T, S, mb)
     assert (choice["kernel"], choice["q_tile"], choice["rule"]) == ("paged_attn_q_tiled", ) + want
+    _say_us("serving_cells_shapes", f"{name}-{nq}/{nkv}-{window}", _us_a_call(fn, q))
     ref = np.asarray(_paged_reference_by_run(q, k_pool, v_pool, tables, rows, bs, window), np.float32)
     got = np.asarray(out[:n], np.float32)
     np.testing.assert_allclose(got, ref, atol=5e-2, rtol=5e-2)
@@ -660,7 +712,9 @@ def test_paged_kernels_under_the_block_causal_bound_on_chip(name, T, S, rows, wa
     model (SDAR's 32/4 heads of 128, blocks of 4 under 128-token KV blocks,
     tables 65 wide, bf16): given each token's BLOCK's last position to mask by,
     against a float32 reference that applies ``j // 4 <= i // 4`` to the true
-    positions."""
+    positions. ``forward_64x4`` is the claimed cell's forward: 64 run tiles of
+    4-17 live blocks under 97 tiles x 65 columns. Prints the microseconds a
+    call."""
     from deepspeed_tpu.ops.pallas import paged_attention as pa
 
     nq, nkv, d, bs, mb, n_blocks, B = 32, 4, 128, 128, 65, 619, 4
@@ -676,7 +730,9 @@ def test_paged_kernels_under_the_block_causal_bound_on_chip(name, T, S, rows, wa
     q = jnp.asarray(rng.normal(size=(T, nq, d)), jnp.bfloat16)
 
     pa.KERNEL_CHOICES.pop((T, S, mb), None)
-    out = jax.jit(lambda q, vis: pa.paged_attention(q, k_pool, v_pool, tables, seq_idx, vis, bs))(q, pos | (B - 1))
+    fn = jax.jit(lambda q, vis: pa.paged_attention(q, k_pool, v_pool, tables, seq_idx, vis, bs))
+    out = fn(q, pos | (B - 1))
+    _say_us("block_causal_bound", name, _us_a_call(fn, q, pos | (B - 1)))
     choice = pa.kernel_choice(T, S, mb)
     assert (choice["kernel"], max(choice["q_tile"], choice["blocks_per_step"]), choice["rule"]) == want
     outs, t0 = [], 0
