@@ -58,6 +58,32 @@ def _serving_compile_scope(method):
     return wrapped
 
 
+def _observe(sp, args, held=None):
+    """What a step function does only because its span ``sp`` is live, under a
+    child span of its own, ``serving/engine_observe``; nothing, and no span,
+    for ``NULL_SPAN``. ``args()`` gives span arguments. A step function calls
+    this twice. First between ``engine_dispatch`` and ``engine_fetch``, with
+    what needs no output of the device, while the device runs the program: the
+    arguments come back to be ``held``. Then last in the step span, with what
+    the fetch brought: ``held`` and these are set on ``sp`` in ONE ``set_args``."""
+    if sp is NULL_SPAN:
+        return None
+    with get_tracer().span("serving/engine_observe", tid="serving"):
+        if held is None:
+            return args()
+        sp.set_args(**held, **args())
+
+
+def _fetch(sp, out, watched):
+    """``(out, [])`` with ``out`` on the host; for a live step span ``sp``,
+    ``(out, [array, ...])`` with the ``watched`` arrays of the same program
+    beside it, brought in the same transfer: one wait for the device."""
+    if sp is NULL_SPAN or not watched:
+        return np.asarray(out), []
+    out, *seen = jax.device_get((out, *watched))
+    return out, seen
+
+
 class InferenceEngineV2:
 
     def __init__(self, model, config: Optional[RaggedInferenceEngineConfig] = None, params=None):
@@ -407,7 +433,6 @@ class InferenceEngineV2:
                     descs.append(seq)
                 rb = self.batch.finalize()
             t_bucket, s_bucket = rb.token_ids.shape[0], rb.block_tables.shape[0]
-            pos0 = [seq.seen_tokens for seq in descs] if sp is not NULL_SPAN and not had_prefill else None
 
             from .sampling import all_greedy, pack_sampling
 
@@ -438,31 +463,29 @@ class InferenceEngineV2:
                 kv.update(*pools)
                 if sd is not NULL_SPAN:
                     sd.set_args(compiled=len(self._compiled) > n_programs)
+            # counts at the boundary, and the uids so that a request-scoped
+            # trace can attribute every engine forward to the requests
+            # composing it (capped: span args are payload, not a table)
+            held = _observe(sp, lambda: dict(
+                rows=len(batch_uids), rows_decode=sum(1 for t in batch_tokens if t.size == 1),
+                tokens=sum(int(t.size) for t in batch_tokens),
+                bucket_tokens=int(t_bucket), bucket_rows=int(s_bucket), steps=1,
+                kernel=self._kernel_of(t_bucket, s_bucket), uids=[int(u) for u in batch_uids[:16]],
+                blocked=bool(block),
+                **({} if had_prefill else
+                   self._kv_span_args(t_bucket, s_bucket, [[seq.seen_tokens for seq in descs]])),
+                **self._tiled_kv_span_args(t_bucket, s_bucket, rb)))
             with tr.span("serving/engine_commit", tid="serving"):
                 for seq in descs:
                     seq.post_forward()
                     self.state_manager.publish_sequence(seq)  # completed full blocks → tree
+            seen = []
             with tr.span("serving/engine_fetch", tid="serving"):
-                out = out[:rb.n_seqs]  # slice ON DEVICE: the host fetch moves
-                out = out if not block else np.asarray(out)  # n_seqs rows, not the padded bucket
-                moe_args = {}
-                if stats and block and sp is not NULL_SPAN:
-                    # two int32 the step computed beside its result; they are
-                    # ready when the result is, so this waits for nothing
-                    moe_args = self._moe_span_args(sum(int(t.size) for t in batch_tokens),
-                                                   t_bucket, 1, np.asarray(stats[0]))
-            if sp is not NULL_SPAN:
-                # counts at the boundary, and the uids so that a request-scoped
-                # trace can attribute every engine forward to the requests
-                # composing it (capped: span args are payload, not a table)
-                sizes = [int(t.size) for t in batch_tokens]
-                sp.set_args(seqs=len(batch_uids), rows=len(batch_uids),
-                            rows_decode=sum(1 for n in sizes if n == 1), tokens=sum(sizes),
-                            bucket_tokens=int(t_bucket), bucket_rows=int(s_bucket), steps=1,
-                            kernel=self._kernel_of(t_bucket, s_bucket),
-                            uids=[int(u) for u in batch_uids[:16]], blocked=bool(block), **moe_args,
-                            **(self._kv_span_args(t_bucket, s_bucket, [pos0]) if pos0 else {}),
-                            **self._tiled_kv_span_args(t_bucket, s_bucket, rb))
+                out = out[:rb.n_seqs]  # slice ON DEVICE: the host fetch moves n_seqs rows, not the padded bucket
+                if block:
+                    # beside them, for a live span, the int32 the step counted of its experts
+                    out, seen = _fetch(sp, out, stats[:1])
+            _observe(sp, lambda: self._moe_span_args(held["tokens"], t_bucket, 1, seen[0]) if seen else {}, held)
         if rf.enabled and block:
             # wall join through the blocking host fetch — the same window the
             # outer put() books as prefill/decode-active in the goodput ledger,
@@ -670,7 +693,6 @@ class InferenceEngineV2:
                         max_blocks_per_seq=self._max_blocks_per_seq,
                         block_size=self.config.kv_block_size,
                         token_buckets=self.batch.seq_buckets, seq_buckets=self.batch.seq_buckets)
-                pos0 = np.asarray([seq.seen_tokens for seq in seqs]) if sp is not NULL_SPAN else None
                 for seq, toks in zip(seqs, first):
                     self.state_manager.allocate_blocks(seq, n_steps)
                     seq.pre_forward(n_steps)
@@ -702,13 +724,19 @@ class InferenceEngineV2:
                 kv.update(*pools)
                 if sd is not NULL_SPAN:
                     sd.set_args(compiled=len(self._compiled) > n_programs)
+            # without the host fetch the span is dispatch only: the blocked
+            # flag discloses it
+            held = _observe(sp, lambda: dict(
+                rows=S, tokens=S * int(n_steps), steps=int(n_steps), bucket_rows=int(s_bucket),
+                bucket_tokens=int(s_bucket), kernel=self._kernel_of(s_bucket, s_bucket),
+                uids=[int(u) for u in uids[:16]], blocked=bool(block),
+                **self._kv_span_args(s_bucket, s_bucket, np.asarray([seq.seen_tokens for seq in seqs])[None, :]
+                                     + np.arange(int(n_steps))[:, None])))
+            seen = []
             with tr.span("serving/engine_fetch", tid="serving"):
                 toks = toks[:S]  # on-device slice before any host fetch
-                moe_args = {}
                 if block:
-                    toks = np.asarray(toks)
-                    if stats and sp is not NULL_SPAN:
-                        moe_args = self._moe_span_args(S, s_bucket, int(n_steps), np.asarray(stats[0]))
+                    toks, seen = _fetch(sp, toks, stats[:1])
             pc = self.state_manager.prefix_cache
             with tr.span("serving/engine_commit", tid="serving"):
                 if block:
@@ -740,15 +768,7 @@ class InferenceEngineV2:
                     for seq in seqs:
                         seq.post_forward()
                         self.state_manager.publish_sequence(seq)
-            if sp is not NULL_SPAN:
-                # without the host fetch the span is dispatch only: the blocked
-                # flag discloses it
-                sp.set_args(seqs=S, rows=S, tokens=S * int(n_steps), steps=int(n_steps),
-                            bucket_rows=int(s_bucket), bucket_tokens=int(s_bucket),
-                            kernel=self._kernel_of(s_bucket, s_bucket),
-                            uids=[int(u) for u in uids[:16]], blocked=bool(block), **moe_args,
-                            **self._kv_span_args(s_bucket, s_bucket,
-                                                 pos0[None, :] + np.arange(int(n_steps))[:, None]))
+            _observe(sp, lambda: self._moe_span_args(S, s_bucket, int(n_steps), seen[0]) if seen else {}, held)
         if rf.enabled and block:
             rf.note_wall(rf_bucket, time.perf_counter() - t_rf)
         if reg.enabled and block:
@@ -826,15 +846,20 @@ class InferenceEngineV2:
             with tr.span("serving/engine_dispatch", tid="serving") as sd:
                 n_programs = len(self._compiled)
                 fn = self._get_compiled_blocks(s_bucket, n_blocks, probe)
-                (toks, forwards, masked_fed, *rest), pools = fn(self.params, jnp.asarray(rb.packed()), kv.pools())
+                (toks, *counts), pools = fn(self.params, jnp.asarray(rb.packed()), kv.pools())
                 kv.update(*pools)
                 if sd is not NULL_SPAN:
                     sd.set_args(compiled=len(self._compiled) > n_programs)
+            held = _observe(sp, lambda: dict(
+                rows=S, tokens=S * n_steps, bucket_rows=int(s_bucket), bucket_tokens=int(s_bucket * B),
+                kernel=self._kernel_of(s_bucket * B, s_bucket), uids=[int(u) for u in uids[:16]], blocked=True,
+                blocks=n_blocks, block_size=B, commit_forwards=n_blocks, open_tokens=sum(opened)))
             with tr.span("serving/engine_fetch", tid="serving"):
                 # the whole bucket comes to the host (a few KB of int32) and is cut there: an eager
-                # slice on the device would be one more tiny program a (bucket, rows) pair to warm
-                toks = np.asarray(toks)[:S]
-                stats, probed = (rest[:1], rest[1:]) if self._moe is not None else ((), rest)
+                # slice on the device would be one more tiny program a (bucket, rows) pair to warm.
+                # Beside it, for a live span: forwards a block, masked slots fed, the experts' counts
+                toks, seen = _fetch(sp, toks, counts[:len(counts) - 2 * bool(probe)])
+            toks = toks[:S]
             if eos_token_ids is None or isinstance(eos_token_ids, (int, np.integer)):
                 eos_token_ids = [eos_token_ids] * S
             assert len(eos_token_ids) == S, "eos_token_ids must match batch_uids"
@@ -856,31 +881,31 @@ class InferenceEngineV2:
                         # saw the whole block, so only the whole kept blocks stay committed
                         self.state_manager.rollback_to(seq, start + (n_open + keep) // B * B)
                     self.state_manager.publish_sequence(seq)
-            if sp is not NULL_SPAN:
-                forwards = np.asarray(forwards)
+
+            def counted():
+                t_done = time.perf_counter()  # the call's time a block, before what observing it takes
+                forwards, masked_fed, *stats = seen
                 n_denoise = int(forwards.sum())
                 n_fwd = n_denoise + n_blocks
                 moe_args = {}
                 if stats:
-                    moe_args = self._moe_span_args(S * B, s_bucket * B, n_fwd, np.asarray(stats[0]),
-                                                   kv_only_forwards=n_blocks)
-                new = n_steps * S - sum(opened)
-                sp.set_args(seqs=S, rows=S, tokens=S * n_steps, steps=n_fwd, bucket_rows=int(s_bucket),
-                            bucket_tokens=int(s_bucket * B), kernel=self._kernel_of(s_bucket * B, s_bucket),
-                            uids=[int(u) for u in uids[:16]], blocked=True, blocks=n_blocks, block_size=B,
-                            denoise_forwards=n_denoise, commit_forwards=n_blocks, tokens_committed=sum(kept),
-                            tokens_fed=S * B * n_fwd, masked_fed=int(masked_fed), tokens_dropped=new - sum(kept),
-                            open_tokens=sum(opened),
-                            block_ms=round((time.perf_counter() - t_call) * 1e3 / n_blocks, 3), **moe_args,
+                    moe_args = self._moe_span_args(S * B, s_bucket * B, n_fwd, stats[0], kv_only_forwards=n_blocks)
+                return dict(steps=n_fwd, denoise_forwards=n_denoise, tokens_committed=sum(kept),
+                            tokens_fed=S * B * n_fwd, masked_fed=int(masked_fed),
+                            tokens_dropped=n_steps * S - held["open_tokens"] - sum(kept),
+                            block_ms=round((t_done - t_call) * 1e3 / n_blocks, 3), **moe_args,
                             **self._tiled_kv_span_args(s_bucket * B, s_bucket, rb,
                                                        [(b * B, int(n) + 1, 1) for b, n in enumerate(forwards)]))
+
+            _observe(sp, counted, held)
         if reg.enabled:
             dt = time.perf_counter() - t_call
             reg.histogram("serving/decode_ms").observe(dt * 1e3)
             reg.gauge("serving/decode_tokens_per_sec").set(sum(kept) / max(dt, 1e-9))
         if probe:
-            ids, logits = (np.asarray(a) for a in probed)  # [blocks, steps, rows, B] and [..., V]
-            return toks, {"rows": list(probe), "ids": ids, "forwards": np.asarray(forwards), "logits": logits}
+            # forwards [blocks]; ids [blocks, steps, rows, B] and logits [..., V], the program's last two results
+            forwards, ids, logits = (np.asarray(a) for a in (counts[0], *counts[-2:]))
+            return toks, {"rows": list(probe), "ids": ids, "forwards": forwards, "logits": logits}
         return toks
 
     def _get_compiled_blocks(self, s_bucket: int, n_blocks: int, probe_rows: tuple = ()):
@@ -1186,6 +1211,10 @@ class InferenceEngineV2:
                 if sd is not NULL_SPAN:
                     sd.set_args(compiled=len(self._compiled) > n_programs)
 
+            held = _observe(sp, lambda: dict(
+                rows=S, tokens=S * n_new, bucket_tokens=int(t_bucket), bucket_rows=int(s_bucket), steps=1, k=k,
+                tree_width=W, sampled=bool(sampled), kernel=self._kernel_of(t_bucket, s_bucket),
+                uids=[int(u) for u in uids[:16]]))
             if eos_token_ids is None or isinstance(eos_token_ids, (int, np.integer)):
                 eos_list = [eos_token_ids] * S
             else:
@@ -1268,12 +1297,7 @@ class InferenceEngineV2:
                         drafted += real
                     accepted += min(a, real)  # pads excluded from the honest rate
                     accepts.append(a)
-            if sp is not NULL_SPAN:
-                sp.set_args(seqs=S, rows=S, tokens=S * n_new, bucket_tokens=int(t_bucket),
-                            bucket_rows=int(s_bucket), steps=1, k=k, drafted=drafted,
-                            tree_width=W, sampled=bool(sampled), accepted=accepts[:16],
-                            kernel=self._kernel_of(t_bucket, s_bucket),
-                            uids=[int(u) for u in uids[:16]])
+            _observe(sp, lambda: dict(drafted=drafted, accepted=accepts[:16]), held)
         self._spec_totals["drafted"] += drafted
         self._spec_totals["accepted"] += accepted
         if rf.enabled:

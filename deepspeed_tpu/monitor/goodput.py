@@ -149,6 +149,7 @@ SPAN_ALLOWLIST = (
     "serving/engine_dispatch",
     "serving/engine_fetch",
     "serving/engine_commit",
+    "serving/engine_observe",
     "train/dispatch",
     # zero-duration instants (consume no wall clock)
     "serving/request_rejected",

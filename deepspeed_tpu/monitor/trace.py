@@ -38,6 +38,17 @@ Retroactive emission (``complete``, ``observe_latency``) cannot reach the
 profiler: it stays for what is retroactive by nature (the compile listener,
 per-request stages).
 
+Every live span closes with two arguments of its own, in both sinks:
+``wall_us``, its time on the host's clock, and ``offcpu_us``, the part of it
+in which its thread was not running (wall less ``time.thread_time_ns()``). In
+a span that blocks on purpose that is the wait; in one that only computes it
+is time the interpreter lock or the machine's scheduler gave to someone else.
+Where the machine keeps a thread's clock continuously that is never below 0.
+Where the clock ticks (gVisor adds 10 ms to the thread that is running when
+its tick comes), a span shorter than a tick reads its wall, or its wall less
+a whole tick, and only a sum over many spans says anything: so no span's
+reading is cut off at 0, which would take the ticks out of the sum.
+
 This module must stay import-light (no package-internal imports): it is
 pulled in by ``comm.comm`` during package bootstrap.
 """
@@ -80,7 +91,7 @@ class _Span:
     profiler session), ``_bus`` says whether the JSONL bus / mirror gets the
     event when it closes."""
 
-    __slots__ = ("_tracer", "_name", "_tid", "_args", "_t0", "_ann", "_bus")
+    __slots__ = ("_tracer", "_name", "_tid", "_args", "_t0", "_cpu0", "_ann", "_bus")
 
     def __init__(self, tracer, name, tid, args, ann, bus):
         self._tracer = tracer
@@ -94,6 +105,7 @@ class _Span:
         if self._ann is not None:
             self._ann.__enter__()
         self._t0 = time.perf_counter()
+        self._cpu0 = time.thread_time_ns()
         return self
 
     def set_args(self, **kwargs):
@@ -104,7 +116,10 @@ class _Span:
         return self
 
     def __exit__(self, *exc):
+        cpu_us = (time.thread_time_ns() - self._cpu0) * 1e-3  # read inside the wall interval: never more than it
         t1 = time.perf_counter()
+        wall_us = (t1 - self._t0) * 1e6
+        self.set_args(wall_us=round(wall_us, 3), offcpu_us=round(wall_us - cpu_us, 3))
         if self._ann is not None:
             self._ann.__exit__(*exc)
         if self._bus:

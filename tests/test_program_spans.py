@@ -3,8 +3,12 @@ loop, the scheduler, the engine and ``train_batch`` open with it: under a
 profiler session they are ``dstpu/<name>`` annotations in the xplane file,
 properly nested on the thread that ran them, with their arguments; with the
 JSONL bus on they are the same spans once each under their bus names; with
-both off nothing is allocated. Read back with the benchmark's own reader
-(``benchmark/lib/program_spans.py``), the one the per-layer metrics use."""
+both off nothing is allocated. Every live span says its wall time and how
+long its thread did not run; what an engine step does only because its span
+is live runs under ``serving/engine_observe``, and nothing of the device's
+comes to the host outside ``serving/engine_fetch``. Read back with the
+benchmark's own reader (``benchmark/lib/program_spans.py``), the one the
+per-layer metrics use."""
 
 import os
 import sys
@@ -19,6 +23,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir))
 
 from benchmark.lib import program_spans  # noqa: E402
 from deepspeed_tpu.models import TransformerConfig, TransformerLM  # noqa: E402
+from deepspeed_tpu.monitor import trace as trace_mod  # noqa: E402
 from deepspeed_tpu.monitor.metrics import get_metrics  # noqa: E402
 from deepspeed_tpu.monitor.trace import NULL_SPAN, get_tracer  # noqa: E402
 from deepspeed_tpu.ops.pallas import paged_attention as pa  # noqa: E402
@@ -26,7 +31,7 @@ from deepspeed_tpu.ops.pallas import paged_attention as pa  # noqa: E402
 from conftest import tiny_batch  # noqa: E402
 
 ENGINE_CHILDREN = {"serving/engine_batch", "serving/engine_dispatch", "serving/engine_fetch",
-                   "serving/engine_commit"}
+                   "serving/engine_commit", "serving/engine_observe"}
 ENGINE_STEPS = {"serving/prefill", "serving/decode_step", "serving/decode"}
 SCHED_ARGS = {"kind", "rows", "rows_decode", "tokens", "prefill_tokens", "pending", "active",
               "budget_left", "first_wait_ms"}
@@ -236,6 +241,276 @@ def test_spans_of_a_shape_the_tiled_kernel_took_count_its_work_list(window, monk
     assert {"kv_live", "kv_steps"} <= set(step) and not {"tile_kv_live", "tile_kv_bound"} & set(step)
 
 
+PLANTED = {"kernel": "paged_attn_q_tiled", "q_tile": 8, "blocks_per_step": 1, "rule": "planted"}
+
+
+def _small_engine(model, params, rows, context):
+    from deepspeed_tpu.inference.v2 import DSStateManagerConfig, InferenceEngineV2, RaggedInferenceEngineConfig
+
+    sm = DSStateManagerConfig(max_tracked_sequences=rows, max_ragged_batch_size=64, max_ragged_sequence_count=rows,
+                              max_context=context)
+    icfg = RaggedInferenceEngineConfig(kv_block_size=16, num_kv_blocks=48, kv_dtype=jnp.float32, state_manager=sm)
+    icfg.use_pallas_kernels = "always"  # as in _engine
+    return InferenceEngineV2(model, icfg, params=params)
+
+
+def _step_case(name, plant):
+    """``(step span's name, prepare)``: ``prepare(uid)`` prefills a fixed
+    batch's rows under uids from ``uid`` (the rows of the round before it
+    flushed) and returns ONE call of one step function on them. Run a round to
+    build the program and another to read its spans: but for ``uids``, nothing
+    a span says depends on which uids or which KV blocks the rows were given.
+    Off the TPU no shape takes the tiled kernel: ``plant(key, choice)`` puts
+    its choice into ``paged_attention.KERNEL_CHOICES``.
+
+    ``dense_*``: this file's engine, rows of 30 and 9 tokens, the tiled
+    kernel's choice planted for the mixed put's shape (32 tokens x 4 rows).
+    ``experts_*``: the tiny Mellum (8 experts top-2, three window layers of 16
+    and a full one), rows of 40 and 12. ``experts_decode_blocks``: the tiny
+    SDAR (blocks of 4, 8 experts top-2), rows of 40, 12 and 23 prompt tokens,
+    the last three of the third left to open its first block, the tiled
+    kernel's choice planted for the block program's shape."""
+    from deepspeed_tpu.models import mellum_config, sdar
+
+    family, _, step = name.partition("_")
+    planted = None
+    if family == "dense":
+        engine, vocab, lengths, planted = _engine(), 128, (30, 9), (32, 4, 96 // 16)
+    elif step == "decode_blocks":
+        model = sdar("tiny", dtype=jnp.float32)
+        engine = _small_engine(model, jax.jit(lambda k: model.init(k, None))(jax.random.PRNGKey(5)), 8, 192)
+        vocab, lengths, planted = 500, (40, 12, 23), (8 * 4, 8, 192 // 16)
+    else:
+        cfg = mellum_config("tiny", dtype=jnp.float32)
+        engine = _small_engine(TransformerLM(cfg), TransformerLM(cfg).init(jax.random.PRNGKey(3)), 4, 128)
+        vocab, lengths = cfg.vocab_size, (40, 12)
+    held = []
+
+    def prepare(uid):
+        while held:
+            engine.flush(held.pop())
+        rng = np.random.default_rng(2)
+        uids, rest = [uid + i for i in range(len(lengths))], []
+        for u, n in zip(uids, lengths):
+            prompt = rng.integers(0, vocab, size=n, dtype=np.int32)
+            whole = n // 4 * 4 if step == "decode_blocks" else n
+            engine.put([u], [prompt[:whole]], sample="greedy")
+            rest.append(prompt[whole:])
+        held.extend(uids + [uid + len(lengths)])
+        if planted:
+            plant(planted, PLANTED)  # again every round: the table is rewritten when jit traces the program
+        one = [np.asarray([3], np.int32)] * len(uids)
+        if step == "put_with_a_prefill_chunk":
+            chunk = rng.integers(0, vocab, size=20, dtype=np.int32)
+            return lambda: engine.put(held, one + [chunk], sample="greedy")
+        held.pop()
+        if step == "put_of_decode_rows":
+            return lambda: engine.put(uids, one, sample="greedy")
+        if step == "decode":
+            return lambda: engine.decode(uids, one, 5)
+        return lambda: engine.decode(uids, rest, 8)
+
+    return {"put_with_a_prefill_chunk": "serving/prefill", "put_of_decode_rows": "serving/decode_step"}.get(
+        step, "serving/decode"), prepare
+
+
+# what the step span of each case said at commit 3963d3a (the parent of the PR that moved the observation under
+# a span of its own), but ``seqs``, which repeated ``rows``, and ``block_ms``, a time; uids from 50
+STEP_SPAN_ARGS = {
+    "dense_put_with_a_prefill_chunk": {
+        "blocked": True, "bucket_rows": 4, "bucket_tokens": 32, "kernel": "paged_attn_q_tiled:8:planted",
+        "rows": 3, "rows_decode": 2, "steps": 1, "tile_kv_bound": 108, "tile_kv_live": 18, "tokens": 22,
+        "uids": [50, 51, 52]},
+    "dense_put_of_decode_rows": {
+        "blocked": True, "bucket_rows": 4, "bucket_tokens": 8, "kernel": "paged_attention_reference:1:off_tpu",
+        "kv_live": 6, "kv_steps": 96, "rows": 2, "rows_decode": 2, "steps": 1, "tokens": 2, "uids": [50, 51]},
+    "dense_decode": {
+        "blocked": True, "bucket_rows": 4, "bucket_tokens": 4, "kernel": "paged_attention_reference:1:off_tpu",
+        "kv_live": 36, "kv_steps": 240, "rows": 2, "steps": 5, "tokens": 10, "uids": [50, 51]},
+    "experts_put_with_a_prefill_chunk": {
+        "blocked": True, "bucket_rows": 4, "bucket_tokens": 32, "expert_load_max": 19, "experts_held": 8,
+        "experts_hit": 29, "experts_published": 8, "experts_total": 32,
+        "kernel": "paged_attention_reference:1:off_tpu", "moe_rows": 704, "moe_slots": 176,
+        "moe_slots_routed": 176, "rows": 3, "rows_decode": 2, "steps": 1, "tokens": 22, "uids": [50, 51, 52]},
+    "experts_decode": {
+        "blocked": True, "bucket_rows": 4, "bucket_tokens": 4, "expert_load_max": 2, "experts_held": 8,
+        "experts_hit": 68, "experts_published": 8, "experts_total": 160,
+        "kernel": "paged_attention_reference:1:off_tpu", "kv_live": 69, "kv_steps": 640, "moe_rows": 1280,
+        "moe_slots": 80, "moe_slots_routed": 80, "rows": 2, "steps": 5, "tokens": 10, "uids": [50, 51]},
+    "experts_decode_blocks": {
+        "block_size": 4, "blocked": True, "blocks": 2, "bucket_rows": 8, "bucket_tokens": 32, "commit_forwards": 2,
+        "denoise_forwards": 8, "expert_load_max": 9, "experts_held": 8, "experts_hit": 193, "experts_published": 8,
+        "experts_total": 224, "kernel": "paged_attn_q_tiled:8:planted", "masked_fed": 51, "moe_rows": 4928,
+        "moe_slots": 672, "moe_slots_routed": 672, "open_tokens": 3, "rows": 3, "steps": 10, "tile_kv_bound": 4368,
+        "tile_kv_live": 266, "tokens": 24, "tokens_committed": 21, "tokens_dropped": 0, "tokens_fed": 120,
+        "uids": [50, 51, 52]},
+}
+
+
+class _HostReads:
+    """Every way ``engine_v2`` has brought a device array to the host, watched:
+    ``np.asarray`` / ``np.array`` of one, ``jax.device_get``, and ``int()`` of
+    one. ``at`` holds a ``perf_counter`` stamp a read (a ``device_get`` of a
+    tuple is one)."""
+
+    def __init__(self, monkeypatch):
+        from jax._src.array import ArrayImpl
+
+        from deepspeed_tpu.inference.v2 import engine_v2
+
+        self.at = []
+        reads, real_get, value = self, jax.device_get, ArrayImpl._value
+
+        class Numpy:
+
+            def __getattr__(self, name):
+                return getattr(np, name)
+
+            def asarray(self, a, *args, **kw):
+                if isinstance(a, jax.Array):
+                    reads.at.append(time.perf_counter())
+                return np.asarray(a, *args, **kw)
+
+            array = asarray
+
+        def device_get(x):
+            reads.at.append(time.perf_counter())
+            reads.getting = True  # it reads each leaf's _value: the one read just counted
+            try:
+                return real_get(x)
+            finally:
+                reads.getting = False
+
+        def _value(a):
+            if not reads.getting:
+                reads.at.append(time.perf_counter())
+            return value.fget(a)
+
+        self.getting = False
+        monkeypatch.setattr(engine_v2, "np", Numpy())
+        monkeypatch.setattr(jax, "device_get", device_get)
+        monkeypatch.setattr(ArrayImpl, "_value", property(_value))
+
+
+def _inside(events, parent):
+    """The bus events of ``parent``'s thread that lie inside it."""
+    t0, t1 = parent["ts"], parent["ts"] + parent["dur"]
+    return [e for e in events if e is not parent and e["tid"] == parent["tid"] and e["ts"] >= t0
+            and e["ts"] + e["dur"] <= t1 + 1e-3]
+
+
+@pytest.mark.parametrize("case", ["dense_put_with_a_prefill_chunk", "dense_put_of_decode_rows", "dense_decode",
+                                  "experts_put_with_a_prefill_chunk", "experts_decode", "experts_decode_blocks"])
+def test_a_step_observes_itself_under_a_span_of_its_own_and_off_the_fetch(case, tmp_path, monkeypatch):
+    """One call of each step function on a fixed batch, both sinks on: the
+    step span says what it said on the parent commit; what is counted for it
+    runs under ``serving/engine_observe`` children, on the driver thread,
+    before the fetch and last in the step; and the device's results come to
+    the host in ONE read, inside ``serving/engine_fetch``, the counts a live
+    span watches with the tokens. With both sinks off the same call reads
+    once and opens nothing."""
+    name, prepare = _step_case(case, lambda key, choice: monkeypatch.setitem(pa.KERNEL_CHOICES, key, choice))
+    prepare(10)()                                                       # builds the program
+    call = prepare(50)
+    reads = _HostReads(monkeypatch)
+    built = []
+    init = trace_mod._Span.__init__
+    monkeypatch.setattr(trace_mod._Span, "__init__", lambda self, *a: built.append(a[1]) or init(self, *a))
+    tracer = get_tracer().configure(enabled=True)
+    tracer.drain()
+    trace = _profiled(tmp_path, call)
+    events = [e for e in tracer.drain() if e["ph"] == "X"]
+    (step, ) = [e for e in events if e["name"] == name]
+    (in_profile, ) = program_spans.spans_named(trace, name)
+    want = dict(STEP_SPAN_ARGS[case], wall_us=step["args"]["wall_us"], offcpu_us=step["args"]["offcpu_us"])
+    want.update({k: step["args"][k] for k in ("block_ms", ) if k in step["args"]})
+    assert step["args"] == want
+    assert {k: in_profile.args[k] for k in STEP_SPAN_ARGS[case]} == STEP_SPAN_ARGS[case]
+    # the children, in order, in both sinks: observed while the device runs, and last
+    order = [e["name"].rpartition("/")[2] for e in sorted(_inside(events, step), key=lambda e: e["ts"])]
+    commit_first = ["engine_commit", "engine_fetch"] if name != "serving/decode" else ["engine_fetch", "engine_commit"]
+    assert order == ["engine_batch", "engine_dispatch", "engine_observe"] + commit_first + ["engine_observe"]
+    kids = sorted(_children(trace, in_profile), key=lambda s: s.start_s)
+    assert [s.name.rpartition("/")[2] for s in kids] == order and {s.line for s in kids} == {in_profile.line}
+    # one read of the device's results, inside engine_fetch
+    origin = tracer._origin
+    (fetch, ) = [e for e in events if e["name"] == "serving/engine_fetch"]
+    assert len(reads.at) == 1, "the counts a live span watches come with the tokens"
+    assert fetch["ts"] <= (reads.at[0] - origin) * 1e6 <= fetch["ts"] + fetch["dur"]
+    # both sinks off: the same call reads once, builds no span, reads no thread clock
+    get_tracer().reset()
+    call = prepare(90)
+    del reads.at[:], built[:]
+    clock = []
+    monkeypatch.setattr(time, "thread_time_ns", lambda: clock.append(1) or 0)
+    call()
+    assert len(reads.at) == 1 and built == [] and clock == []
+
+
+def test_a_verify_step_observes_itself_too(engine):
+    """``_speculate`` watches no count of the device's: its two observations
+    say the sizes while the program runs and, last, what was accepted."""
+    rng = np.random.default_rng(7)
+    uids = [600, 601]
+    engine.put(uids, [rng.integers(0, 128, size=n, dtype=np.int32) for n in (20, 7)], sample="greedy")
+    first = [np.asarray([3], np.int32)] * 2
+    drafts = [np.asarray([5, 6, 7], np.int32), np.asarray([9], np.int32)]
+    tracer = get_tracer().configure(enabled=True)
+    tracer.drain()
+    out = engine.speculate_decode(uids, first, drafts, 3)
+    events = [e for e in tracer.drain() if e["ph"] == "X"]
+    for uid in uids:
+        engine.flush(uid)
+    (step, ) = [e for e in events if e["name"] == "serving/spec_verify"]
+    order = [e["name"].rpartition("/")[2] for e in sorted(_inside(events, step), key=lambda e: e["ts"])]
+    assert order == ["engine_batch", "engine_dispatch", "engine_observe", "engine_fetch", "engine_commit",
+                     "engine_observe"]
+    said = {k: v for k, v in step["args"].items() if k not in ("wall_us", "offcpu_us", "kernel")}
+    assert said == {"rows": 2, "tokens": 8, "bucket_tokens": 8, "bucket_rows": 4, "steps": 1, "k": 3, "tree_width": 1,
+                    "sampled": False, "uids": uids, "drafted": 4, "accepted": [len(o) - 1 for o in out]}
+
+
+@pytest.mark.parametrize("sinks", ["bus", "profiler", "both"])
+def test_every_live_span_says_its_wall_time_and_how_long_its_thread_did_not_run(sinks, tmp_path):
+    """``wall_us >= offcpu_us >= 0`` on every span; a span that sleeps reads
+    nearly all of its wall time as off the CPU, one that spins nearly none
+    (the least of 25 spins of 2 ms, each short enough to fit one turn on a
+    core: the workers beside this one take it between turns)."""
+    tracer = get_tracer()
+    if sinks != "profiler":
+        tracer.configure(enabled=True)
+
+    def work():
+        with tracer.span("outer", tid="serving"):
+            with tracer.span("sleeps", tid="serving"):
+                time.sleep(0.02)
+            for _ in range(25):
+                with tracer.span("spins", tid="serving"):
+                    t_end = time.perf_counter() + 0.002
+                    while time.perf_counter() < t_end:
+                        pass
+
+    if sinks == "bus":
+        work()
+    else:
+        trace = _profiled(tmp_path, work)
+    found = []
+    if sinks != "profiler":
+        found.append({(e["name"], i): e["args"] for i, e in enumerate(tracer.drain()) if e["ph"] == "X"})
+    if sinks != "bus":
+        found.append({(s.name, i): s.args for i, s in enumerate(trace["spans"])})
+    for spans in found:
+        assert len(spans) == 27
+        for args in spans.values():
+            assert args["wall_us"] >= args["offcpu_us"] >= 0, args
+        (sleeps, ) = [a for (n, _), a in spans.items() if n == "sleeps"]
+        assert 20e3 <= sleeps["wall_us"] < 200e3 and sleeps["offcpu_us"] > 0.8 * sleeps["wall_us"], sleeps
+        spins = min((a for (n, _), a in spans.items() if n == "spins"), key=lambda a: a["offcpu_us"])
+        assert spins["wall_us"] >= 2e3 and spins["offcpu_us"] < 0.2 * spins["wall_us"], spins
+        (outer, ) = [a for (n, _), a in spans.items() if n == "outer"]
+        assert outer["offcpu_us"] >= sleeps["offcpu_us"] - 1.0
+
+
 def test_bus_sees_the_same_spans_once_each_under_their_bus_names(engine, serve_trace):
     tracer = get_tracer().configure(enabled=True)  # pathless buffer
     _serve(engine, uid_base=300)
@@ -257,12 +532,15 @@ def test_process_name_event_carries_both_clock_origins():
     assert abs((time.time_ns() - origin_ns) * 1e-9 - (time.perf_counter() - origin_pc)) < 0.05
 
 
-def test_both_sinks_off_allocates_nothing_and_the_kernel_table_fills_at_trace_time_only(engine):
+def test_both_sinks_off_allocates_nothing_and_the_kernel_table_fills_at_trace_time_only(engine, monkeypatch):
     tracer = get_tracer()
     assert not tracer.enabled and not jax.profiler.TraceAnnotation.is_enabled()
     assert tracer.span("serving/sched_step", tid="serving") is NULL_SPAN
+    built, clock = [], []
+    monkeypatch.setattr(trace_mod._Span, "__init__", lambda self, *a: built.append(a[1]))
+    monkeypatch.setattr(time, "thread_time_ns", lambda: clock.append(1) or 0)
     _serve(engine, uid_base=400)
-    assert tracer.drain() == []
+    assert tracer.drain() == [] and built == [] and clock == [], "no span object, no read of the thread's clock"
     # the table is written while jit traces a program, never when one runs
     q, pool = jnp.ones((8, 4, 16)), jnp.ones((64, 4, 16))
     tables, seq_idx, pos = jnp.zeros((2, 4), jnp.int32), jnp.zeros((8, ), jnp.int32), jnp.arange(8)
