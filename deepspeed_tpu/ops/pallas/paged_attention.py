@@ -68,7 +68,7 @@ def _note_choice(T, S, max_blocks, choice):
 
 
 # The largest q-tile: the MXU then sees ``g * 128`` query rows a kv head, and
-# a KV block is fetched and cut into heads once per 128 query tokens (256
+# a KV block is fetched and read out by kv head once per 128 query tokens (256
 # measured 2-5% faster at twice the VMEM: PERF.md, PR 25).
 _LONG_ROW_TILE = 128
 
@@ -265,14 +265,14 @@ def _pallas_paged(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: int
     # the scatter side maintains natively, no per-call transpose
     ks2, vs2 = (k_scale[:, :n_live], v_scale[:, :n_live]) if quant else (None, None)
 
+    # a block as one [block * nkv, d] matrix, row t * nkv + n: the pool's own
+    # bytes, the one operand both kernels take (no relayout between them)
+    as_rows = lambda pool: pool[:n_live].reshape(n_pool_blocks, block_size * nkv, d)
     if q_tile > 1:
-        as_blocks = lambda pool: pool[:n_live].reshape(n_pool_blocks, block_size, nkv, d)
-        return _paged_q_tiled(pl, pltpu, q, as_blocks(k_pool), as_blocks(v_pool), block_tables, seq_idx, pos,
+        return _paged_q_tiled(pl, pltpu, q, as_rows(k_pool), as_rows(v_pool), block_tables, seq_idx, pos,
                               ks2, vs2, block_size=block_size, q_tile=q_tile, window=window,
                               alibi=alibi, interpret=interpret)
-    # a block as one [block * nkv, d] matrix, row t * nkv + n: the pool's own
-    # bytes; the int8 scales [nkv, cols] are laid out to match
-    as_rows = lambda pool: pool[:n_live].reshape(n_pool_blocks, block_size * nkv, d)
+    # the decode kernel takes the int8 scales [nkv, cols] laid out to match the rows
     by_col = lambda sc: jnp.transpose(sc).reshape(n_pool_blocks, 1, block_size * nkv)
     return _paged_kv_split(pl, pltpu, q, as_rows(k_pool), as_rows(v_pool), block_tables, seq_idx,
                            pos, by_col(ks2) if quant else None, by_col(vs2) if quant else None,
@@ -290,17 +290,75 @@ _LANES = 128
 def _q_tiled_vmem_bytes(R: int, G: int, d: int, block_size: int, nkv: int, q_itemsize: int,
                         kv_itemsize: int) -> int:
     """VMEM working set of one ``paged_attn_q_tiled`` grid step: the q and
-    output tiles and the K and V blocks double-buffered by the pipeline (and
-    the block once more, cut by kv head), the float32 ``acc``, the
-    lane-replicated ``m``/``l`` and positions, and one kv head's float32
-    scores and probabilities."""
+    output tiles and the K and V blocks ``[block_size * nkv, d]``
+    double-buffered by the pipeline (and the block once more, by kv head, with
+    the words of one load and their unpacked heads in float32), the float32
+    ``acc``, the lane-replicated ``m``/``l`` and positions, and one kv head's
+    float32 scores and probabilities."""
     tiles = 2 * 2 * R * d * q_itemsize                        # q in, o out
-    kv = 2 * 2 * block_size * max(nkv, 16) * d * kv_itemsize  # K, V (sublane-padded)
+    kv = 2 * 2 * block_size * nkv * d * kv_itemsize           # K, V: the pool's rows, no padding
     kv += 2 * nkv * block_size * d * 4                        # by head, at most float32
+    kv += 2 * (1 + 4 // kv_itemsize) * block_size * d * 4     # one load's words and its heads
     state = R * d * 4 + 2 * R * _LANES * 4                    # acc, m, l
     pos = 2 * G * _LANES * 4
     head = 4 * G * max(block_size, _LANES) * 4                # s, p and their temporaries
     return tiles + kv + state + pos + head
+
+
+def _head_load_path(dtype, nkv: int) -> str:
+    """How ``paged_attn_q_tiled`` reads one kv head out of a KV block
+    ``[block * nkv, d]`` (row ``t * nkv + n`` is head ``n`` of token ``t``),
+    from the pool's STATIC dtype and head count alone: ``whole`` (one head:
+    the block is the head), ``rows`` (32-bit elements: every ``nkv``-th row
+    is a strided load), ``words`` (a bf16 or int8 pool, ``p = 4 // itemsize``
+    rows to a 32-bit sublane word, ``nkv % p == 0``: every ``nkv / p``-th
+    word holds heads ``n .. n + p - 1`` of one token, one strided word load
+    per ``p`` heads: every serving configuration), or ``cut`` (a word would
+    mix two tokens, bf16 with an odd head count or int8 with two, or the
+    halves of a word are not the dtype's values: the value-level cut)."""
+    dtype = jnp.dtype(dtype)
+    if nkv == 1:
+        return "whole"
+    if dtype.itemsize == 4:
+        return "rows"
+    packs = dtype in (jnp.bfloat16, jnp.int8) and nkv % (4 // dtype.itemsize) == 0
+    return "words" if packs else "cut"
+
+
+def _block_heads(pl, pltpu, ref, nkv: int, block_size: int):
+    """Yields the ``nkv`` heads of the KV block ``ref`` ``[1, block_size *
+    nkv, d]`` in order, each ``[block_size, d]`` with the pool's own values:
+    in the pool's dtype, but int8 widened to int32 (the caller dequantises in
+    float32 anyway). The ``words`` path is one strided load of the block
+    bitcast to ``uint32`` per ``4 // itemsize`` heads and a shift or a mask a
+    head: a bf16 is the high half of its float32, an int8 the arithmetic shift
+    of its byte moved to the top. A strided load of 32-bit rows is an ordinary
+    load; the per-head ``kb[:, n, :]`` it replaces gathered sublanes out of
+    every packed tile of the block, 2.0 of a live step's 2.755 us at 4 kv
+    heads (PERF.md section 6, PR 34 and PR 37)."""
+    dt = ref.dtype
+    path = _head_load_path(dt, nkv)
+    if path == "whole":
+        yield ref[0]
+    elif path == "rows":
+        for n in range(nkv):
+            yield ref[0, pl.ds(n, block_size, stride=nkv), :]
+    elif path == "cut":
+        block = ref[0].reshape(block_size, nkv, ref.shape[2])
+        for n in range(nkv):
+            yield block[:, n, :]
+    else:
+        p = 4 // dt.itemsize
+        words = ref.bitcast(jnp.uint32)
+        for n in range(0, nkv, p):
+            w = words[0, pl.ds(n // p, block_size, stride=nkv // p), :]
+            if dt == jnp.bfloat16:
+                yield pltpu.bitcast(w << 16, jnp.float32).astype(dt)
+                yield pltpu.bitcast(w & jnp.uint32(0xFFFF0000), jnp.float32).astype(dt)
+            else:
+                wi = pltpu.bitcast(w, jnp.int32)
+                for i in range(p):
+                    yield (wi << (24 - 8 * i)) >> 24
 
 
 def _lanes(x, n: int):
@@ -417,12 +475,12 @@ def _tiled_work_list(block_tables, seq_idx, pos, block_size: int, window, q_tile
             jnp.clip(k + spread[1], 0, max_blocks - 1), total.astype(jnp.int32))
 
 
-def _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
+def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
                    block_size: int, q_tile: int, window, alibi, interpret: bool):
     """Q-tiled kernel: grid steps for the LIVE (tile, KV block) pairs only.
 
     Each tile packs up to ``q_tile`` CONTIGUOUS same-sequence tokens, so
-    every KV block streams from HBM (and is cut into kv heads) once per
+    every KV block streams from HBM (and is read out by kv head) once per
     *tile* instead of once per token — a 2,048-token prefill chunk at
     q_tile=128 reads each of its KV blocks 16x instead of 2,048x, and each kv
     head's dot feeds the MXU ``g * q_tile`` query rows.
@@ -454,22 +512,27 @@ def _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
     a shape whose list would pass ``_TILED_SMEM_BYTES`` raises here, at trace
     time.
 
-    Inside a grid step the block is cut into kv heads once (into a small
-    scratch) and the heads are walked one at a time, so what lives at once is
-    one head's ``[g * q_tile, block]`` scores beside the resident q / output
-    tile and the ``acc``/``m``/``l`` scratch (sized by
-    :func:`_q_tiled_vmem_bytes`). Rows of a kv head are token-major
-    (``row = t * g + h``): a tile's valid rows are a prefix, and a tile with
-    at most ``_SHORT_TILE_TOKENS`` valid tokens runs only that prefix through
-    the MXU. Both dots take the operands in the precision they arrive in
-    (bf16 q and pool: bf16 operands, float32 accumulation; the scores, the
-    masking and the softmax state are float32); int8 KV dequantises at the
-    tile in float32. ``m``, ``l`` and the positions are kept replicated
-    across the 128 lanes, so that with 128-token blocks no step broadcasts a
-    column. Alibi and the sliding window mask as in the decode kernel.
+    The pool arrives as the decode kernel takes it, ``k3``/``v3`` ``[blocks,
+    block_size * nkv, d]``, the pool's own bytes (row ``t * nkv + n`` is head
+    ``n`` of token ``t``). Inside a grid step the block's heads are read out
+    of it once (:func:`_block_heads`: strided 32-bit word loads that deliver
+    two bf16 or four int8 heads each, by the pool's static dtype and head
+    count, :func:`_head_load_path`) into a small scratch by kv head, and the
+    heads are walked one at a time, so what lives at once is one head's
+    ``[g * q_tile, block]`` scores beside the resident q / output tile and
+    the ``acc``/``m``/``l`` scratch (sized by :func:`_q_tiled_vmem_bytes`).
+    Rows of a kv head are token-major (``row = t * g + h``): a tile's valid
+    rows are a prefix, and a tile with at most ``_SHORT_TILE_TOKENS`` valid
+    tokens runs only that prefix through the MXU. Both dots take the operands
+    in the precision they arrive in (bf16 q and pool: bf16 operands, float32
+    accumulation; the scores, the masking and the softmax state are float32);
+    int8 KV dequantises at the tile in float32, head by head, with the head's
+    row of scales. ``m``, ``l`` and the positions are kept replicated across
+    the 128 lanes, so that with 128-token blocks no step broadcasts a column.
+    Alibi and the sliding window mask as in the decode kernel.
     """
     T, nq, d = q.shape
-    nkv = k4.shape[2]
+    nkv = k3.shape[1] // block_size
     g = nq // nkv
     qt = int(q_tile)
     quant = ks2 is not None
@@ -479,7 +542,7 @@ def _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
     short = min(G, g * _SHORT_TILE_TOKENS)
     # operands of the two dots: what q and the pool hold, unless the pool is
     # quantised (its dequantised values are float32)
-    cdt = jnp.float32 if quant else jnp.promote_types(q.dtype, k4.dtype)
+    cdt = jnp.float32 if quant else jnp.promote_types(q.dtype, k3.dtype)
 
     # --- segmented tiles and their live KV blocks (contiguity contract: see paged_attention) ---
     tile_id, slot, tile_tok, valid, tile_seq, tile_cnt, w_tile, w_col, total = _tiled_work_list(
@@ -506,7 +569,7 @@ def _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
         return (tile_ref[i], 0, 0)
 
     def kv_map(i, tile_ref, col_ref, seq_ref, cnt_ref, bt_ref):
-        return (bt_ref[seq_ref[tile_ref[i]], col_ref[i]], 0, 0, 0)
+        return (bt_ref[seq_ref[tile_ref[i]], col_ref[i]], 0, 0)
 
     def scale_map(i, *refs):
         return (0, kv_map(i, *refs)[0])
@@ -537,13 +600,15 @@ def _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
                 vis = jnp.logical_and(vis, my_pos - kpos < window)
             if alibi is not None:
                 rel = (kpos - my_pos).astype(jnp.float32)
-            kb, vb = k_ref[0], v_ref[0]            # [bs, nkv, d]
             if quant:  # dequant at the VMEM tile — HBM only streamed int8
-                kb = kb.astype(jnp.float32) * ks_ref[...].T[:, :, None]
-                vb = vb.astype(jnp.float32) * vs_ref[...].T[:, :, None]
-            for n in range(nkv):  # cut the block into kv heads once, for the head loop to index
-                kh_ref[n] = kb[:, n, :].astype(cdt)
-                vh_ref[n] = vb[:, n, :].astype(cdt)
+                ks_t, vs_t = ks_ref[...].T, vs_ref[...].T   # [bs, nkv]
+            heads = zip(*(_block_heads(pl, pltpu, ref, nkv, block_size) for ref in (k_ref, v_ref)))
+            for n, (kh, vh) in enumerate(heads):  # the block's heads, once, for the head loop to index
+                if quant:
+                    kh = kh.astype(jnp.float32) * ks_t[:, n:n + 1]
+                    vh = vh.astype(jnp.float32) * vs_t[:, n:n + 1]
+                kh_ref[n] = kh.astype(cdt)
+                vh_ref[n] = vh.astype(cdt)
 
             def head(n):
                 """One kv head's G rows: its working set is all that lives."""
@@ -588,11 +653,11 @@ def _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
 
     in_specs = [
         pl.BlockSpec((1, R, d), q_map),
-        pl.BlockSpec((1, block_size, nkv, d), kv_map),
-        pl.BlockSpec((1, block_size, nkv, d), kv_map),
+        pl.BlockSpec((1, block_size * nkv, d), kv_map),
+        pl.BlockSpec((1, block_size * nkv, d), kv_map),
         pl.BlockSpec((1, G, _LANES), q_map),
     ]
-    operands = [q_t, k4, v4, pos_rows]
+    operands = [q_t, k3, v3, pos_rows]
     if quant:
         in_specs += [pl.BlockSpec((nkv, block_size), scale_map),
                      pl.BlockSpec((nkv, block_size), scale_map)]
@@ -616,7 +681,7 @@ def _paged_q_tiled(pl, pltpu, q, k4, v4, block_tables, seq_idx, pos, ks2, vs2,
         # the default scoped limit (16 MiB on a v5e) is under the working set
         # of a 128-token tile of 32 heads; ask for what the step needs plus
         # half again for Mosaic's own temporaries
-        need = _q_tiled_vmem_bytes(R, G, d, block_size, nkv, q.dtype.itemsize, k4.dtype.itemsize)
+        need = _q_tiled_vmem_bytes(R, G, d, block_size, nkv, q.dtype.itemsize, k3.dtype.itemsize)
         kwargs["compiler_params"] = pltpu.CompilerParams(
             vmem_limit_bytes=max(32 << 20, min(need * 3 // 2, 100 << 20)))
     out_t = pl.pallas_call(kernel, grid_spec=grid_spec,

@@ -13,9 +13,10 @@ import pytest
 
 from deepspeed_tpu.models.transformer import alibi_slopes
 from deepspeed_tpu.ops.pallas import paged_attention as pa_mod
-from deepspeed_tpu.ops.pallas.paged_attention import (_contiguity_ok, _decode_work_list, _pallas_paged,
-                                                      _tiled_work_list, choose_kernel, decode_kv_counts,
-                                                      paged_attention_reference, tiled_kv_counts)
+from deepspeed_tpu.ops.pallas.paged_attention import (_block_heads, _contiguity_ok, _decode_work_list,
+                                                      _head_load_path, _pallas_paged, _tiled_work_list,
+                                                      choose_kernel, decode_kv_counts, paged_attention_reference,
+                                                      tiled_kv_counts)
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir))
 
@@ -87,7 +88,8 @@ def _unwritten_tiles_reach_no_token(tables, seq_idx, pos, bs, window, q_tile):
 
 @pytest.mark.parametrize("q_tile", [4, 8])
 @pytest.mark.parametrize("case", ["plain", "int8", "alibi", "window", "window_alibi",
-                                  "int8_window", "gqa", "rows_of_4", "rows_of_4_window"])
+                                  "int8_window", "gqa", "rows_of_4", "rows_of_4_window",
+                                  "bf16_nkv4", "bf16_nkv8_window", "int8_nkv4"])
 def test_qtiled_parity_matrix(case, q_tile):
     """The q-tiled grid must match the gather oracle bit-for-tolerance on
     every kernel feature — ragged tile tails, int8 dequant-at-tile-read,
@@ -96,17 +98,25 @@ def test_qtiled_parity_matrix(case, q_tile):
     ``rows_of_4``: the block-diffusion cell's shape in small, six rows of 4
     tokens under the block bound and a 16-column table of which no row fills
     more than 5, where most tiles of the static bound hold nothing and are
-    never written."""
+    never written. The float32 and ``nkv=2`` int8 pools of the other cases
+    reach the tiled kernel's heads by row loads and by the value-level cut;
+    ``*_nkv4`` / ``*_nkv8`` (heads of 128, a bf16 or int8 pool under float32
+    queries, so the tight tolerance holds) by the strided word loads every
+    serving configuration takes."""
     import zlib
 
-    nkv, g = (2, 4) if case == "gqa" else (2, 2)
+    nkv = int(case.split("nkv")[1][0]) if "nkv" in case else 2
+    g = 4 if case == "gqa" else 2
     int8 = case.startswith("int8")
     rows4 = case.startswith("rows_of_4")
+    d, bs = (128, 16) if "nkv" in case else (32, 16)
     # crc32, not hash(): PYTHONHASHSEED salting would make a tolerance-edge
     # failure unreproducible across runs
-    rng, nq, kp, vp, tables, scales = _paged_setup(seed=zlib.crc32(case.encode()), nkv=nkv, g=g, int8=int8,
+    rng, nq, kp, vp, tables, scales = _paged_setup(seed=zlib.crc32(case.encode()), nkv=nkv, g=g, d=d, int8=int8,
                                                    **(dict(n_seqs=6, blocks_per_seq=16) if rows4 else {}))
-    d, bs = 32, 16
+    if case.startswith("bf16"):
+        kp, vp = kp.astype(jnp.bfloat16), vp.astype(jnp.bfloat16)
+    assert _head_load_path(kp.dtype, nkv) == ("words" if "nkv" in case else "cut" if int8 else "rows")
     q, seq_idx, pos = _rows_of_4_batch(rng, nq, d, bs, 6, 32) if rows4 else _mixed_batch(rng, nq, d, bs)
     if rows4:  # 8 or 6 run tiles and the pad run's of a bound of 32 / q_tile + 7
         assert _unwritten_tiles_reach_no_token(tables, seq_idx, pos, bs, 17 if "window" in case else None,
@@ -142,7 +152,8 @@ def _prefill_batch(rng, nq, d, bs, q_tile, dtype):
 
 @pytest.mark.parametrize("q_tile", [32, 128])
 @pytest.mark.parametrize("case", ["plain", "window", "alibi", "window_alibi", "int8", "int8_window",
-                                  "gqa4", "f32", "f32_window_alibi", "rows_of_4", "rows_of_4_window"])
+                                  "gqa4", "f32", "f32_window_alibi", "rows_of_4", "rows_of_4_window",
+                                  "nkv4", "nkv8_window", "int8_nkv4", "rows_of_4_nkv4"])
 def test_qtiled_prefill_tile_parity_matrix(case, q_tile):
     """The tiles the shape heuristic picks for prefill (32 for many rows, 128
     for long prompts) against the gather oracle: bf16 q and pool, so both
@@ -151,19 +162,24 @@ def test_qtiled_prefill_tile_parity_matrix(case, q_tile):
     matrix's. The window (50) is shorter than a tile, so its lower edge
     crosses KV blocks inside one tile. ``rows_of_4``: the block-diffusion
     cell's forward (:func:`_rows_of_4_batch`, 12 rows under a 24-column
-    table), every tile a short one."""
+    table), every tile a short one. ``*nkv4`` / ``nkv8*``: 4 and 8 kv heads
+    of 128, the serving configurations' head counts, so that the strided word
+    loads deliver whole heads two (bf16) or four (int8) to a word; the
+    ``nkv=2`` bf16 cases take word loads too, at a stride of one word."""
     import zlib
 
     f32 = case.startswith("f32")
     rows4 = case.startswith("rows_of_4")
     dtype = jnp.float32 if f32 else jnp.bfloat16
-    nkv, g = (2, 4) if case == "gqa4" else (2, 2)
-    d, bs, blocks_per_seq = 32, 16, 24
+    nkv = int(case.split("nkv")[1][0]) if "nkv" in case else 2
+    g = 4 if case == "gqa4" else 2
+    d, bs, blocks_per_seq = 128 if "nkv" in case else 32, 16, 24
     rng, nq, kp, vp, tables, scales = _paged_setup(seed=zlib.crc32(case.encode()), nkv=nkv, g=g, d=d, bs=bs,
                                                    n_seqs=12 if rows4 else 4, blocks_per_seq=blocks_per_seq,
                                                    int8=case.startswith("int8"))
     if not scales:
         kp, vp = kp.astype(dtype), vp.astype(dtype)
+    assert _head_load_path(kp.dtype, nkv) == ("rows" if f32 else "cut" if scales and nkv == 2 else "words")
     if rows4:
         q, seq_idx, pos = _rows_of_4_batch(rng, nq, d, bs, 12, 64, dtype)
         # 12 run tiles and the pad run's, of ceil(64 / q_tile) + 13
@@ -188,6 +204,48 @@ def test_qtiled_prefill_tile_parity_matrix(case, q_tile):
         # and tighter than any one element: the whole output within bf16 rounding
         err = np.linalg.norm(np.asarray(out, np.float32) - np.asarray(ref, np.float32))
         assert err / np.linalg.norm(np.asarray(ref, np.float32)) < 6e-3
+
+
+@pytest.mark.parametrize("dtype,nkv,path", [
+    # every serving configuration: a bf16 pool of 4 or 8 kv heads (an int8 one of 4 or 8) packs whole heads into a word
+    ("bfloat16", 2, "words"), ("bfloat16", 4, "words"), ("bfloat16", 8, "words"),
+    ("int8", 4, "words"), ("int8", 8, "words"),
+    ("float32", 2, "rows"), ("float32", 3, "rows"),
+    # the static fall-backs: one head is the block; a word would mix two tokens; a float16 is no half of a float32
+    ("bfloat16", 1, "whole"), ("bfloat16", 3, "cut"), ("int8", 2, "cut"), ("float16", 4, "cut"),
+])
+def test_block_heads_are_the_pools_own_bits(dtype, nkv, path):
+    """What the tiled kernel's loader delivers for head ``n`` of a KV block
+    ``[block x nkv, d]`` is ``pool_block[:, n, :]`` BIT FOR BIT, whichever
+    path the pool's static dtype and head count choose (each case names its
+    own): the unpacked halves of a 32-bit word are the pool's values, so the
+    dots' operands are what the value-level cut gave them."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bs, d, n_blocks = 16, 128, 3
+    dt = jnp.dtype(dtype)
+    assert _head_load_path(dt, nkv) == path
+    rng = np.random.default_rng(nkv)
+    if dt == jnp.int8:
+        pool = jnp.asarray(rng.integers(-128, 128, size=(n_blocks, bs, nkv, d)), jnp.int8)
+    else:  # normal draws over seven decades, a signed zero, the largest and the smallest normal magnitudes
+        x = rng.normal(size=(n_blocks, bs, nkv, d)) * np.exp(rng.uniform(-8, 8, size=(n_blocks, bs, nkv, 1)))
+        x[0, 0, :, :4] = [0.0, -0.0, float(jnp.finfo(dt).max), float(jnp.finfo(dt).tiny)]
+        pool = jnp.asarray(x, dt)
+
+    def kernel(k_ref, o_ref):
+        for n, head in enumerate(_block_heads(pl, pltpu, k_ref, nkv, bs)):
+            o_ref[0, n] = head.astype(dt)   # int8 comes widened to int32, value for value
+
+    out = pl.pallas_call(kernel, grid=(n_blocks, ), interpret=True,
+                         in_specs=[pl.BlockSpec((1, bs * nkv, d), lambda i: (i, 0, 0))],
+                         out_specs=pl.BlockSpec((1, nkv, bs, d), lambda i: (i, 0, 0, 0)),
+                         out_shape=jax.ShapeDtypeStruct((n_blocks, nkv, bs, d), dt))(
+                             pool.reshape(n_blocks, bs * nkv, d))
+    bits = lambda a: np.asarray(a).view({1: np.uint8, 2: np.uint16, 4: np.uint32}[dt.itemsize])
+    for n in range(nkv):
+        np.testing.assert_array_equal(bits(out[:, n]), bits(pool[:, :, n, :]))
 
 
 def test_qtiled_decode_only_with_pad_run():

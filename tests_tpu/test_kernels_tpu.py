@@ -458,11 +458,13 @@ def test_paged_q_tiled_at_the_serving_cells_shapes(name, T, S, rows, want, nq, n
     q = jnp.asarray(rng.normal(size=(T, nq, d)), jnp.bfloat16)
 
     pa.KERNEL_CHOICES.pop((T, S, mb), None)
-    fn = jax.jit(lambda q: pa.paged_attention(q, k_pool, v_pool, tables, seq_idx, pos, bs, window=window))
-    out = fn(q)
+    # the batch as ARGUMENTS, as the engine's jitted step has it: the work list is then built on the device
+    fn = jax.jit(lambda q, tables, seq_idx, pos: pa.paged_attention(q, k_pool, v_pool, tables, seq_idx, pos, bs,
+                                                                    window=window))
+    out = fn(q, tables, seq_idx, pos)
     choice = pa.kernel_choice(T, S, mb)
     assert (choice["kernel"], choice["q_tile"], choice["rule"]) == ("paged_attn_q_tiled", ) + want
-    _say_us("serving_cells_shapes", f"{name}-{nq}/{nkv}-{window}", _us_a_call(fn, q))
+    _say_us("serving_cells_shapes", f"{name}-{nq}/{nkv}-{window}", _us_a_call(fn, q, tables, seq_idx, pos))
     ref = np.asarray(_paged_reference_by_run(q, k_pool, v_pool, tables, rows, bs, window), np.float32)
     got = np.asarray(out[:n], np.float32)
     np.testing.assert_allclose(got, ref, atol=5e-2, rtol=5e-2)
@@ -749,8 +751,51 @@ def test_paged_kernels_under_the_block_causal_bound_on_chip(name, T, S, rows, wa
     np.testing.assert_allclose(got, ref, atol=5e-2, rtol=5e-2)
     assert np.linalg.norm(got - ref) / np.linalg.norm(ref) < 6e-3
     # the bound is the one thing that differs from the causal kernel: a causal mask reads otherwise
-    causal = np.asarray(jax.jit(lambda q: pa.paged_attention(q, k_pool, v_pool, tables, seq_idx, pos, bs))(q)[:n], np.float32)
+    causal = np.asarray(fn(q, pos)[:n], np.float32)
     assert np.linalg.norm(causal - ref) / np.linalg.norm(ref) > 0.02
+
+
+def test_both_paged_kernels_take_the_pool_as_the_same_rows_on_chip():
+    """One compiled program of SDAR's shapes (32/4 heads of 128, 128-token
+    blocks, a 65-column table) that calls both kernels on one pool, a denoise
+    forward of 64 rows x 4 tokens through ``paged_attn_q_tiled`` and the
+    drain's 8 x 4 through ``paged_attn_kv_split``: every pool-sized operand of
+    either custom call is the rank-3 ``[blocks, block x nkv, d]`` view and a
+    ``bitcast`` of the program's own parameter, so no relayout copy of the
+    pool stands between the kernels (one would cost about a millisecond a
+    layer call, and no parity test would see it)."""
+    import re
+
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    nq, nkv, d, bs, mb, n_blocks = 32, 4, 128, 128, 65, 619
+    rng = np.random.default_rng(37)
+    pools = [jnp.asarray(rng.normal(size=(n_blocks * bs, nkv, d)), jnp.bfloat16) for _ in range(2)]
+    batches = []
+    for T, S, rows in [(256, 64, [(260 + 28 * i, 4) for i in range(64)]),
+                       (32, 8, [(260 + 252 * i, 4) for i in range(7)])]:
+        seq_idx = np.concatenate([np.full(new, r) for r, (_, new) in enumerate(rows)])
+        pos = np.concatenate([np.arange(before, before + new) for before, new in rows]) | 3
+        batches.append((jnp.asarray(rng.normal(size=(T, nq, d)), jnp.bfloat16),
+                        jnp.asarray(rng.integers(0, n_blocks, size=(S, mb)), jnp.int32),
+                        jnp.asarray(np.pad(seq_idx, (0, T - seq_idx.size)), jnp.int32),
+                        jnp.asarray(np.pad(pos, (0, T - pos.size)), jnp.int32)))
+
+    def both(k_pool, v_pool, batches):
+        return [pa.paged_attention(q, k_pool, v_pool, tables, seq_idx, pos, bs) for q, tables, seq_idx, pos in batches]
+
+    text = jax.jit(both).lower(*pools, batches).compile().as_text()
+    rows_view = f"bf16[{n_blocks},{bs * nkv},{d}]"
+    defined = dict(re.findall(r"^\s*(%[\w.-]+) = (\S+ [\w-]+\([^)]*\))", text, re.M))
+    for kernel in ("paged_attn_q_tiled", "paged_attn_kv_split"):
+        call, = re.findall(rf"%{kernel}[\w.]* = .*? custom-call\(([^)]*)\)", text)
+        operands = re.findall(r"%[\w.-]+", call)
+        pool_operands = [defined[o] for o in operands if o in defined and str(n_blocks) in defined[o].split("{")[0]]
+        assert len(pool_operands) >= 2, (kernel, operands)
+        for definition in pool_operands:
+            assert definition.startswith(rows_view), (kernel, definition)
+            assert re.search(r" bitcast\(%[kv]_pool", definition), (kernel, definition)
+    assert not re.search(rf"bf16\[{n_blocks},[\d,]*\]\S* (copy|transpose|fusion)\(", text)
 
 
 def test_moe_serving_programs_of_every_bucket_pair_run_on_chip():
