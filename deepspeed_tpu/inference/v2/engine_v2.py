@@ -140,6 +140,13 @@ class InferenceEngineV2:
                 raise NotImplementedError(
                     f"speculative decoding of a model with diffusion_block_size={self._block}: a draft is "
                     "verified one causal token at a time, and this model has no causal next token")
+        # what one token caches in one layer: per-head K and V, or one latent entry
+        self._kv_entry = tuple(getattr(mc, "kv_entry", ((mc.num_kv_heads, mc.head_dim), ) * 2))
+        self._latent = bool(getattr(mc, "latent_attention", False))
+        if self._latent and getattr(ic.speculative, "enabled", False):
+            raise NotImplementedError(
+                "speculative decoding of a model with latent attention: a verify step of k + 1 tokens a row and the "
+                "token-tree mask have not been shown equal to the reference over a latent pool")
         max_context = ic.state_manager.max_context
         model_max = getattr(mc, "max_seq_len", None)
         if model_max is not None and max_context > model_max:
@@ -159,7 +166,7 @@ class InferenceEngineV2:
             mc.num_layers, mc.num_kv_heads, mc.head_dim,
             max_tracked_sequences=ic.state_manager.max_tracked_sequences,
             num_blocks=self.num_kv_blocks, block_size=bs, dtype=ic.kv_dtype,
-            prefix_cache_config=ic.prefix_cache)
+            prefix_cache_config=ic.prefix_cache, kv_entry=self._kv_entry)
         if self._block and self.state_manager.prefix_cache is not None:
             self.state_manager.prefix_cache.token_quantum = self._block
         self.batch = RaggedBatchWrapper(
@@ -270,7 +277,7 @@ class InferenceEngineV2:
 
         bs = ic.kv_block_size
         dt_bytes = _np.dtype(ic.kv_dtype).itemsize  # accepts "int8" and jnp dtypes alike
-        per_block = 2 * mc.num_layers * mc.num_kv_heads * mc.head_dim * bs * dt_bytes
+        per_block = mc.num_layers * sum(h * w for h, w in self._kv_entry) * bs * dt_bytes
         if dt_bytes == 1:  # int8 KV: absmax scales ride along, fp32 per (token, head)
             per_block += 2 * mc.num_layers * mc.num_kv_heads * bs * 4
         min_blocks = -(-max_context // bs) + 1
@@ -472,6 +479,7 @@ class InferenceEngineV2:
                 bucket_tokens=int(t_bucket), bucket_rows=int(s_bucket), steps=1,
                 kernel=self._kernel_of(t_bucket, s_bucket), uids=[int(u) for u in batch_uids[:16]],
                 blocked=bool(block),
+                **self._attn_span_args([seq.seen_tokens for seq in descs], [t.size for t in batch_tokens]),
                 **({} if had_prefill else
                    self._kv_span_args(t_bucket, s_bucket, [[seq.seen_tokens for seq in descs]])),
                 **self._tiled_kv_span_args(t_bucket, s_bucket, rb)))
@@ -527,6 +535,30 @@ class InferenceEngineV2:
                 "experts_total": mc.experts_held * layer_forwards,
                 "expert_load_max": int(stats[1]),
                 "experts_held": mc.experts_held, "experts_published": mc.moe_num_experts}
+
+    def _attn_span_args(self, seen, new) -> dict:
+        """What a step span says of the attention work whatever kernel and
+        form ran it, from the rows' lengths alone: ``attn_pairs``, the visible
+        (query token, context token) pairs of a call that feeds row ``r`` the
+        ``new[r]`` tokens after its ``seen[r]`` (a chunk, or the steps of a
+        decode horizon), and ``attn_ctx_tokens``, the context tokens those
+        queries see (what a step must read at least once a row), both summed
+        over layers with each layer's window applied; ``kv_entry_bytes``, the
+        bytes one token caches in one layer (``2 x nkv x d x itemsize``, or the
+        latent entry's), so that a reader need not know the family."""
+        seen, new = np.asarray(seen, np.int64), np.asarray(new, np.int64)
+        pairs = ctx = 0
+        for window, layers in self._kv_windows:
+            if window is None:
+                row_pairs, row_ctx = new * seen + new * (new + 1) // 2, seen + new
+            else:  # the first ``a`` tokens still see everything before them, the others ``window`` keys
+                a = np.clip(window - seen, 0, new)
+                row_pairs = a * seen + a * (a + 1) // 2 + (new - a) * window
+                row_ctx = seen + new - np.maximum(seen + 1 - window, 0)
+            pairs, ctx = pairs + layers * int(row_pairs.sum()), ctx + layers * int(row_ctx.sum())
+        kv = self.state_manager.kv_cache
+        return {"attn_pairs": pairs, "attn_ctx_tokens": ctx, "kv_entry_bytes": kv.block_bytes() // (
+            kv.block_size * kv.num_layers)}
 
     def _kv_span_args(self, T: int, S: int, pos) -> dict:
         """What a decode span says of the attention kernel's grid: ``kv_live``
@@ -730,6 +762,7 @@ class InferenceEngineV2:
                 rows=S, tokens=S * int(n_steps), steps=int(n_steps), bucket_rows=int(s_bucket),
                 bucket_tokens=int(s_bucket), kernel=self._kernel_of(s_bucket, s_bucket),
                 uids=[int(u) for u in uids[:16]], blocked=bool(block),
+                **self._attn_span_args([seq.seen_tokens for seq in seqs], [int(n_steps)] * S),
                 **self._kv_span_args(s_bucket, s_bucket, np.asarray([seq.seen_tokens for seq in seqs])[None, :]
                                      + np.arange(int(n_steps))[:, None])))
             seen = []
@@ -928,7 +961,7 @@ class InferenceEngineV2:
     def _ragged_step(self, params, packed, pools, t_bucket, s_bucket, gather_k: int = 0,
                      tree_meta=None, moe_stats: bool = False, kv_only: bool = False):
         """One ragged forward over the pool tuple (2 = bf16 pools, 4 = int8
-        pools + scales). The SINGLE builder both compiled paths share —
+        pools + scales, 1 = a latent pool). The SINGLE builder both compiled paths share —
         quant/non-quant variation lives in the tuple arity, not in four
         hand-copied closures.
 
@@ -1011,7 +1044,7 @@ class InferenceEngineV2:
         scales = {"k_scale": pools[2], "v_scale": pools[3]} if len(pools) == 4 else {}
         out = ragged_forward(self.model_config, self.config.kv_block_size, params,
                              token_ids, seq_idx, pos, valid, tables, last_idx,
-                             pools[0], pools[1], use_pallas=self._use_pallas,
+                             pools[0], pools[1] if len(pools) > 1 else None, use_pallas=self._use_pallas,
                              modules=self._modules, moe_stats=moe_stats, kv_only=kv_only, **scales, **extra)
         if moe_stats:
             return out[0], tuple(out[1:-1]), out[-1]
@@ -1074,6 +1107,10 @@ class InferenceEngineV2:
                 f"speculate_decode (speculative decoding, token-tree verification) of a model with "
                 f"diffusion_block_size={self._block}: drafts are verified against a causal next token, which a "
                 "block generated by masked diffusion does not have")
+        if self._latent:
+            raise NotImplementedError(
+                "speculate_decode (speculative decoding, token-tree verification) of a model with latent attention: "
+                "the verify step and the tree's mask have not been shown equal to the reference over a latent pool")
         hb = self._health
         gl = self.goodput_ledger
         if gl is None and not hb.enabled:
@@ -1755,10 +1792,8 @@ class InferenceEngineV2:
         n_full = min(n // bs, len(seq.kv_blocks))
         chunks, payloads = [], []
         for i in range(n_full):
-            k, v, ks, vs = sm.kv_cache.read_block(seq.kv_blocks[i])
-            payloads.append((np.asarray(k), np.asarray(v),
-                             None if ks is None else np.asarray(ks),
-                             None if vs is None else np.asarray(vs)))
+            payloads.append(tuple(None if a is None else np.asarray(a)
+                                  for a in sm.kv_cache.read_block(seq.kv_blocks[i])))
             chunks.append(tuple(int(t) for t in tokens[i * bs:(i + 1) * bs]))
         return chunks, payloads
 

@@ -12,7 +12,10 @@ arrays:
     many sequences (Dynamic SplitFuse composition);
   - KV append is a scatter into the flat pool at
     ``block_table[seq, pos // bs] * bs + pos % bs`` (invalid/padding tokens
-    scatter out-of-bounds with mode='drop');
+    scatter out-of-bounds with mode='drop'); what is appended is the model's
+    entry (``TransformerConfig.kv_entry``): per-head K and V into two pools
+    ``[L, NB*bs, nkv, d]``, or ONE latent entry a token into one pool
+    ``[L, NB*bs, 1, width]`` (latent attention);
   - attention gathers each sequence's context from the pool by block table
     and masks ``ctx_pos <= token_pos`` — numerics-reference path; the Pallas
     paged kernel (``ops/pallas/paged_attention.py``) replaces the gather on
@@ -26,10 +29,12 @@ so a trained checkpoint serves directly.
 
 from typing import Any, Dict
 
+import math
+
 import jax
 import jax.numpy as jnp
 
-from ....models.transformer import TransformerConfig, apply_rope, mlp_activation, rope_table
+from ....models.transformer import TransformerConfig, apply_rope, mlp_activation, rope_inv_freq, rope_table
 from ....moe.grouped import merge_routing_stats
 
 
@@ -41,7 +46,21 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
     """Returns (last-token logits [S_pad, V], k_pool, v_pool).
 
     token_ids/seq_idx/pos/valid: [T_pad]; block_tables: [S_pad, max_blocks];
-    last_idx: [S_pad]; k_pool/v_pool: [L, NB*bs, nkv, d] (donated).
+    last_idx: [S_pad]; k_pool/v_pool: [L, NB*bs, nkv, d] (donated), the
+    cache's pools as the model's ``kv_entry`` shapes them.
+
+    Latent attention (``cfg.latent_attention``; ``v_pool`` None, and the
+    return has the one pool): ``k_pool`` ``[L, NB*bs, 1, W]`` holds a token's
+    entry ``[rmsnorm(ckv) | rope(kr) | 0]`` (``kv_lora_rank`` lanes, then
+    ``qk_rope_head_dim``, zeros up to ``W``: whole 128-lane tiles), scattered
+    at the token's slot exactly as K is. Attention runs in the ABSORBED form:
+    with ``W_kvb = [W_K_h | W_V_h]`` a head's query is ``[q_nope_h W_K_h^T |
+    rope(q_rope_h) | 0]`` against the entries, scaled by ``1 /
+    sqrt(qk_nope_head_dim + qk_rope_head_dim)``; the attention call returns
+    ``sum p ckv`` a head and ``W_V_h`` is applied to it. Both are batched
+    matmuls over heads around the attention call on the stored
+    ``wkv_b_k`` / ``wkv_b_v``; no per-head K or V exists anywhere, for a
+    chunk's own tokens and for its history alike.
 
     ``pos_ids``/``attn_mask``/``ctx_pos_ids``: token-tree verification
     (``engine_v2.speculate_decode`` with branched drafts). ``pos`` stays the
@@ -121,6 +140,10 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
     T = token_ids.shape[0]
     nq, nkv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     pool_len = k_pool.shape[1]
+    latent = cfg.latent_attention
+    if latent != (v_pool is None) or (latent and (k_scale is not None or attn_mask is not None)):
+        raise ValueError("latent attention takes the one latent pool (v_pool None), without int8 scales or a "
+                         "token-tree mask; every other model takes k_pool and v_pool")
 
     pid = pos if pos_ids is None else pos_ids
     x = embedding(params, token_ids, pid)  # [T, H]
@@ -129,7 +152,10 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
     # string hash seed, and with it the traced program and its compile-cache key
     ropes = {kind: rope_table(cfg, pid, kind) for kind in dict.fromkeys(cfg.layer_types or (None, ))
              if cfg.rope_layer_types is None or kind in cfg.rope_layer_types} \
-        if cfg.positions == "rotary" else {}
+        if cfg.positions == "rotary" and not latent else {}
+    if latent:  # the rotated part of a head alone: tables [T, qk_rope_head_dim / 2], halves rotated
+        angles = pid.astype(jnp.float32)[:, None] * jnp.asarray(rope_inv_freq(cfg)[0])[None, :]
+        latent_rope = jnp.sin(angles), jnp.cos(angles)
 
     # flat KV slot of each token; padding tokens dropped via OOB scatter.
     # The pools ride the layer scan as CARRY over a layers-flattened view
@@ -157,45 +183,68 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
         attend = modules["attention_full"] if kind == "full_attention" else attention
         h1 = pre_norm(x, blk["ln1_scale"], blk.get("ln1_bias"))
         bias = (lambda n: blk[n]) if cfg.use_bias else (lambda n: None)
-        qkvb = (lambda n: blk[n]) if cfg.qkv_bias_enabled else (lambda n: None)
-        q = linear(h1, blk["wq"], qkvb("bq")).reshape(T, nq, d)
-        k = linear(h1, blk["wk"], qkvb("bk")).reshape(T, nkv, d)
-        v = linear(h1, blk["wv"], qkvb("bv")).reshape(T, nkv, d)
-        if cfg.qk_norm:  # over each head's d, one gain vector for all heads
-            q = pre_norm(q, blk["q_norm_scale"])
-            k = pre_norm(k, blk["k_norm_scale"])
-        if kind in ropes:  # a layer kind without rope carries no position at all
-            sin, cos = ropes[kind]
-            q = apply_rope(q[None], sin, cos, cfg.rotary_dim)[0]
-            k = apply_rope(k[None], sin, cos, cfg.rotary_dim)[0]
-
-        # append this batch's KV to the paged pool (linear_blocked_kv_rotary);
-        # in-place scatter on the scan carry at layer l's offset
-        slot_l = jnp.where(valid, l * pool_len + slot, flat_len)
-        if quant:
-            # symmetric int8 per (token, kv-head): absmax/127 over head_dim
-            ks = jnp.maximum(jnp.max(jnp.abs(k.astype(jnp.float32)), axis=-1) / 127.0, 1e-8)
-            vs = jnp.maximum(jnp.max(jnp.abs(v.astype(jnp.float32)), axis=-1) / 127.0, 1e-8)
-            k = jnp.round(k.astype(jnp.float32) / ks[..., None])
-            v = jnp.round(v.astype(jnp.float32) / vs[..., None])
-            heads = jnp.arange(nkv, dtype=jnp.int32)[None, :]
-            ks_flat = ks_flat.at[heads, slot_l[:, None]].set(ks, mode="drop")
-            vs_flat = vs_flat.at[heads, slot_l[:, None]].set(vs, mode="drop")
-        k_flat = k_flat.at[slot_l].set(k.astype(k_flat.dtype), mode="drop")
-        v_flat = v_flat.at[slot_l].set(v.astype(v_flat.dtype), mode="drop")
-        if kv_alone:
-            return x, k_flat, v_flat, ks_flat, vs_flat, stats
-
+        slot_l = jnp.where(valid, l * pool_len + slot, flat_len)  # this layer's slots in the flat pool
         tables_l = block_tables + l * NB  # layer l's blocks in the flat pool
-        # scales/tree kwargs only passed when active, so full-precision
-        # causal third-party attention implementations keep the original
-        # 6-arg call signature
-        scales = {"k_scale": ks_flat, "v_scale": vs_flat} if quant else {}
-        if attn_mask is not None:
-            scales = dict(scales, pos_ids=pid, mask=attn_mask, ctx_pos_ids=ctx_pos_ids)
-        ctx = attend(q, k_flat, v_flat, tables_l, seq_idx, vis_pos, **scales)
+        if latent:
+            c, nope, dv = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.v_head_dim
+            W = k_flat.shape[-1]
+            sin, cos = latent_rope
+            cq = pre_norm(linear(h1, blk["wq_a"], None), blk["q_a_norm_scale"])
+            qh = linear(cq, blk["wq_b"], None).reshape(T, nq, d)
+            kv = linear(h1, blk["wkv_a"], None)
+            ckv = pre_norm(kv[:, :c], blk["kv_a_norm_scale"])
+            kr = apply_rope(kv[None, :, None, c:], sin, cos)[0]          # [T, 1, rope]: ONE key part for all heads
+            entry = jnp.concatenate([ckv[:, None, :], kr], axis=-1)
+            entry = jnp.pad(entry, ((0, 0), (0, 0), (0, W - entry.shape[-1])))
+            k_flat = k_flat.at[slot_l].set(entry.astype(k_flat.dtype), mode="drop")
+            if kv_alone:
+                return x, k_flat, v_flat, ks_flat, vs_flat, stats
+            # W_K_h^T folded into the query: [q_nope_h W_K_h^T | rope(q_rope_h) | 0] against the entries
+            q_lat = jnp.einsum("thn,hcn->thc", qh[..., :nope], blk["wkv_b_k"],
+                               preferred_element_type=jnp.float32).astype(qh.dtype)
+            q_abs = jnp.concatenate([q_lat, apply_rope(qh[None, ..., nope:], sin, cos)[0]], axis=-1)
+            q_abs = jnp.pad(q_abs, ((0, 0), (0, 0), (0, W - q_abs.shape[-1])))
+            lat = attend(q_abs, k_flat, None, tables_l, seq_idx, vis_pos, value_dim=c,
+                         softmax_scale=1.0 / math.sqrt(d))              # [T, nq, c]: sum p ckv a head
+            # ... and W_V_h into the output
+            ctx = jnp.einsum("thc,hcv->thv", lat, blk["wkv_b_v"],
+                             preferred_element_type=jnp.float32).astype(lat.dtype).reshape(T, nq * dv)
+        else:
+            qkvb = (lambda n: blk[n]) if cfg.qkv_bias_enabled else (lambda n: None)
+            q = linear(h1, blk["wq"], qkvb("bq")).reshape(T, nq, d)
+            k = linear(h1, blk["wk"], qkvb("bk")).reshape(T, nkv, d)
+            v = linear(h1, blk["wv"], qkvb("bv")).reshape(T, nkv, d)
+            if cfg.qk_norm:  # over each head's d, one gain vector for all heads
+                q = pre_norm(q, blk["q_norm_scale"])
+                k = pre_norm(k, blk["k_norm_scale"])
+            if kind in ropes:  # a layer kind without rope carries no position at all
+                sin, cos = ropes[kind]
+                q = apply_rope(q[None], sin, cos, cfg.rotary_dim)[0]
+                k = apply_rope(k[None], sin, cos, cfg.rotary_dim)[0]
 
-        ctx = ctx.reshape(T, nq * d)
+            # append this batch's KV to the paged pool (linear_blocked_kv_rotary);
+            # in-place scatter on the scan carry at layer l's offset
+            if quant:
+                # symmetric int8 per (token, kv-head): absmax/127 over head_dim
+                ks = jnp.maximum(jnp.max(jnp.abs(k.astype(jnp.float32)), axis=-1) / 127.0, 1e-8)
+                vs = jnp.maximum(jnp.max(jnp.abs(v.astype(jnp.float32)), axis=-1) / 127.0, 1e-8)
+                k = jnp.round(k.astype(jnp.float32) / ks[..., None])
+                v = jnp.round(v.astype(jnp.float32) / vs[..., None])
+                heads = jnp.arange(nkv, dtype=jnp.int32)[None, :]
+                ks_flat = ks_flat.at[heads, slot_l[:, None]].set(ks, mode="drop")
+                vs_flat = vs_flat.at[heads, slot_l[:, None]].set(vs, mode="drop")
+            k_flat = k_flat.at[slot_l].set(k.astype(k_flat.dtype), mode="drop")
+            v_flat = v_flat.at[slot_l].set(v.astype(v_flat.dtype), mode="drop")
+            if kv_alone:
+                return x, k_flat, v_flat, ks_flat, vs_flat, stats
+
+            # scales/tree kwargs only passed when active, so full-precision
+            # causal third-party attention implementations keep the original
+            # 6-arg call signature
+            scales = {"k_scale": ks_flat, "v_scale": vs_flat} if quant else {}
+            if attn_mask is not None:
+                scales = dict(scales, pos_ids=pid, mask=attn_mask, ctx_pos_ids=ctx_pos_ids)
+            ctx = attend(q, k_flat, v_flat, tables_l, seq_idx, vis_pos, **scales).reshape(T, nq * d)
         if cfg.attention_gate:
             gate = linear(h1, blk["w_attn_gate"], None)
             ctx = (ctx.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(ctx.dtype)
@@ -233,8 +282,9 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
         h2 = pre_norm(x, blk["ln2_scale"], blk.get("ln2_bias"))
         return x + post(mlp(h2), "ln2_post_scale"), k_flat, v_flat, ks_flat, vs_flat, stats
 
-    k_flat = k_pool.reshape(flat_len, nkv, d)
-    v_flat = v_pool.reshape(flat_len, nkv, d)
+    # (a latent pool: one entry a token, [flat_len, 1, W], and no second pool)
+    k_flat = k_pool.reshape((flat_len, ) + k_pool.shape[2:])
+    v_flat = None if latent else v_pool.reshape(flat_len, nkv, d)
     ks_flat, vs_flat = k_scale, v_scale  # already [nkv, flat_len] or None
     stats = jnp.zeros(3, jnp.int32) if moe_stats else None
     # what each stacked array is stacked over: the routed experts (read in
@@ -278,11 +328,10 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
         (x, k_flat, v_flat, ks_flat, vs_flat, stats), _ = jax.lax.scan(
             scan_body, (x, k_flat, v_flat, ks_flat, vs_flat, stats),
             (per_layer, jnp.arange(L, dtype=jnp.int32)))
-    k_pool = k_flat.reshape(L, pool_len, nkv, d)
-    v_pool = v_flat.reshape(L, pool_len, nkv, d)
+    pools = (k_flat.reshape(k_pool.shape), ) if latent else (k_flat.reshape(k_pool.shape), v_flat.reshape(v_pool.shape))
 
     # logits_gather semantics: final norm + unembed only each sequence's
     # last token, through the pluggable unembed module
     logits = None if kv_only else unembed(params, x, last_idx)
-    out = (logits, k_pool, v_pool, ks_flat, vs_flat) if quant else (logits, k_pool, v_pool)
+    out = (logits, ) + pools + ((ks_flat, vs_flat) if quant else ())
     return out + (stats, ) if moe_stats else out

@@ -96,6 +96,34 @@ def _t_rotary_half(cfg, w):
     return (_interleaved_to_half_perm(w.T, cfg.num_heads, cfg.head_dim, cfg.rotary_dim), )
 
 
+def _tail_to_half(cols, rope):
+    """The LAST ``rope`` entries of ``cols`` from interleaved pairs (2i, 2i+1)
+    to halves (i, i + rope/2)."""
+    tail = cols[-rope:]
+    return np.concatenate([cols[:-rope], tail[0::2], tail[1::2]])
+
+
+def _t_rope_tail_half(cfg, w):
+    """Latent attention's rotated columns, which close each query head
+    (``q_b_proj`` ``[nq * (nope + rope), q_rank]``) and the latent projection
+    (``kv_a_proj_with_mqa`` ``[latent + rope, H]``): transposed, and where the
+    checkpoint pairs interleaved lanes, permuted to our half-style rope. The
+    same permutation on both is score-preserving."""
+    rope, wt = cfg.qk_rope_head_dim, w.T
+    group = cfg.head_dim if wt.shape[1] == cfg.num_heads * cfg.head_dim else wt.shape[1]
+    idx = np.concatenate([_tail_to_half(np.arange(g, g + group), rope) for g in range(0, wt.shape[1], group)])
+    return (wt[:, idx], )
+
+
+def _kvb_by_head(cfg, w):
+    """``kv_b_proj`` ``[nq * (nope + v), latent]`` as the two parts the
+    absorbed form multiplies by, a head at a time: keys ``[nq, latent, nope]``
+    and values ``[nq, latent, v]`` (``W_kvb`` is kept no third time)."""
+    nope, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
+    by_head = w.reshape(cfg.num_heads, nope + dv, cfg.kv_lora_rank).transpose(0, 2, 1)
+    return by_head[..., :nope], by_head[..., nope:]
+
+
 def _zeros_qkv(cfg):
     return (np.zeros(cfg.num_heads * cfg.head_dim, np.float32), )
 
@@ -113,6 +141,8 @@ TRANSFORMS: Dict[str, Callable] = {
     "qkv_bias_interleaved": _qkv_bias_interleaved,
     "qkv_gqa_rows": _qkv_gqa_rows,
     "t_rotary_half": _t_rotary_half,
+    "t_rope_tail_half": _t_rope_tail_half,
+    "kvb_by_head": _kvb_by_head,
     "zeros_qkv": _zeros_qkv,
     "zeros_hidden": _zeros_hidden,
 }
@@ -399,6 +429,27 @@ def _falcon() -> tuple:
         S("final_norm.scale", "transformer.ln_f.weight"),
         S("final_norm.bias", "transformer.ln_f.bias"),
         S("lm_head.kernel", "lm_head.weight", "t", when="untied"),
+    )
+
+
+def latent_attention_rows(prefix: str = "model.layers.{i}.self_attn.") -> tuple:
+    """The rows of a latent-attention block (DeepSeek-V3 / ``glm4_moe_lite``
+    names) for a family's table: the two low-rank projections with their
+    norms, ``W_kvb`` cut by head, the output projection. The rotated columns
+    are taken as INTERLEAVED in the checkpoint (``t_rope_tail_half``); a
+    checkpoint that pairs halves takes ``t`` on those two rows. No family
+    with experts has a table yet (the expert arrays are stacked over two
+    axes, which ``convert_with_spec`` does not do), so these rows stand
+    alone."""
+    a = prefix
+    return (
+        S("blocks.wq_a", a + "q_a_proj.weight", "t", per_layer=True),
+        S("blocks.q_a_norm_scale", a + "q_a_layernorm.weight", per_layer=True),
+        S("blocks.wq_b", a + "q_b_proj.weight", "t_rope_tail_half", per_layer=True),
+        S("blocks.wkv_a", a + "kv_a_proj_with_mqa.weight", "t_rope_tail_half", per_layer=True),
+        S("blocks.kv_a_norm_scale", a + "kv_a_layernorm.weight", per_layer=True),
+        S(("blocks.wkv_b_k", "blocks.wkv_b_v"), a + "kv_b_proj.weight", "kvb_by_head", per_layer=True),
+        S("blocks.wo", a + "o_proj.weight", "t", per_layer=True),
     )
 
 

@@ -36,12 +36,12 @@ class DenseBlockedAttention(DSSelfAttentionBase):
         return config.num_heads % max(config.num_kv_heads, 1) == 0
 
     def __call__(self, q, k_flat, v_flat, tables_l, seq_idx, pos, k_scale=None, v_scale=None,
-                 pos_ids=None, mask=None, ctx_pos_ids=None):
+                 pos_ids=None, mask=None, ctx_pos_ids=None, **latent):
         cfg = self.config
         return paged_attention_reference(q, k_flat, v_flat, tables_l, seq_idx, pos,
                                          cfg.block_size, window=cfg.sliding_window,
                                          alibi=_alibi(cfg), k_scale=k_scale, v_scale=v_scale,
-                                         pos_ids=pos_ids, mask=mask, ctx_pos_ids=ctx_pos_ids)
+                                         pos_ids=pos_ids, mask=mask, ctx_pos_ids=ctx_pos_ids, **latent)
 
 
 @DSSelfAttentionRegistry.register_module
@@ -58,7 +58,9 @@ class PallasPagedAttention(DSSelfAttentionBase):
                 and config.head_dim % 2 == 0)
 
     def __call__(self, q, k_flat, v_flat, tables_l, seq_idx, pos, k_scale=None, v_scale=None,
-                 pos_ids=None, mask=None, ctx_pos_ids=None):
+                 pos_ids=None, mask=None, ctx_pos_ids=None, **latent):
+        """``latent``: ``value_dim`` and ``softmax_scale`` of a latent pool
+        (``v_flat`` None), handed on as they come."""
         cfg = self.config
         if mask is not None:
             # token-tree verification: the Pallas grids know only the causal
@@ -70,7 +72,7 @@ class PallasPagedAttention(DSSelfAttentionBase):
                                              cfg.block_size, window=cfg.sliding_window,
                                              alibi=_alibi(cfg), k_scale=k_scale,
                                              v_scale=v_scale, pos_ids=pos_ids, mask=mask,
-                                             ctx_pos_ids=ctx_pos_ids)
+                                             ctx_pos_ids=ctx_pos_ids, **latent)
         if self.implementation_config.get("interpret", False):
             import jax.numpy as jnp
 
@@ -87,8 +89,8 @@ class PallasPagedAttention(DSSelfAttentionBase):
                                  pos.astype(jnp.int32), block_size=cfg.block_size,
                                  interpret=True, window=cfg.sliding_window,
                                  alibi=tuple(np.asarray(al).tolist()) if al is not None else None,
-                                 k_scale=k_scale, v_scale=v_scale)
+                                 k_scale=k_scale, v_scale=v_scale, **latent)
         # paged_attention itself falls back (loudly) off-TPU / tiny heads
         return paged_attention(q, k_flat, v_flat, tables_l, seq_idx, pos,
                                cfg.block_size, window=cfg.sliding_window, alibi=_alibi(cfg),
-                               k_scale=k_scale, v_scale=v_scale)
+                               k_scale=k_scale, v_scale=v_scale, **latent)

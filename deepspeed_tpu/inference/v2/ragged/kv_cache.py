@@ -2,15 +2,26 @@
 
 Analog of the reference ``inference/v2/ragged/kv_cache.py:40``
 (``BlockedKVCache``: device block pool fronted by a ``BlockedAllocator``).
-TPU-native layout: one stacked pool per cache group,
+TPU-native layout: the pool is built from what the MODEL says one token's
+entry in one layer is (``TransformerConfig.kv_entry``: ``(heads, width)`` of
+each part), one stacked array a part,
 
-    k_pool / v_pool : [num_layers, num_blocks * block_size, num_kv_heads, head_dim]
+    [num_layers, num_blocks * block_size, heads, width]
 
-i.e. the block dimension is flattened so a token's slot is the flat index
+* per-head K and V (every family but one): two parts ``(num_kv_heads,
+  head_dim)``, ``k_pool`` and ``v_pool``;
+* a latent entry (latent attention): ONE part ``(1, width)``, ``k_pool``
+  alone (``v_pool`` is None): the normed latent and the shared rotated key
+  part of a token, whose first lanes are also its value, stored once.
+
+The block dimension is flattened so a token's slot is the flat index
 ``block_id * block_size + offset`` — scatter (append) and gather (attention)
 are then single-index operations that XLA lowers to efficient dynamic-slice /
-dynamic-update-slice, and the Pallas paged-attention kernel indexes the same
+dynamic-update-slice, and the Pallas paged-attention kernels index the same
 flat pool. The pool shards over the ``model`` axis on the kv-head dim (TP).
+Every method below walks the parts that exist (:meth:`BlockedKVCache.pools`),
+so what rides them (copy-on-write, the host tier, rollback, export and
+import) carries a latent block as it carries a K/V block.
 """
 
 from typing import Optional, Tuple
@@ -33,20 +44,30 @@ class BlockedKVCache:
     add 1/(2·head_dim) back)."""
 
     def __init__(self, num_layers: int, num_kv_heads: int, head_dim: int, num_blocks: int, block_size: int = 64,
-                 dtype=jnp.bfloat16, sharding=None):
+                 dtype=jnp.bfloat16, sharding=None, entry=None):
+        """``entry``: the model's ``kv_entry``; None = per-head K and V of
+        ``num_kv_heads`` x ``head_dim``. ``num_kv_heads`` / ``head_dim`` are
+        then the first part's heads and width."""
+        if entry is None:
+            entry = ((num_kv_heads, head_dim), ) * 2
+        self.entry = tuple((int(h), int(w)) for h, w in entry)
         self.num_layers = num_layers
-        self.num_kv_heads = num_kv_heads
-        self.head_dim = head_dim
+        self.num_kv_heads, self.head_dim = self.entry[0]
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         if dtype in ("int8", jnp.int8, np.int8):
             dtype = jnp.int8
         self.dtype = dtype
         self.quantized = dtype == jnp.int8
+        if len(self.entry) not in (1, 2):
+            raise ValueError(f"a KV entry of one part (a latent) or two (K and V), got {self.entry}")
+        if self.quantized and len(self.entry) != 2:
+            raise NotImplementedError("an int8 KV cache beside a latent entry: the int8 layout keeps one scale a "
+                                      "(token, kv head) of K and of V, and a latent entry has neither")
         self._allocator = BlockedAllocator(num_blocks)
-        shape = (num_layers, self.num_blocks * self.block_size, num_kv_heads, head_dim)
-        self.k_pool = jnp.zeros(shape, dtype)
-        self.v_pool = jnp.zeros(shape, dtype)
+        shapes = [(num_layers, self.num_blocks * self.block_size, h, w) for h, w in self.entry]
+        self.k_pool = jnp.zeros(shapes[0], dtype)
+        self.v_pool = jnp.zeros(shapes[1], dtype) if len(shapes) == 2 else None
         self.k_scale = self.v_scale = None
         if self.quantized:
             # [nkv, L * NB * bs] — kv-heads on sublanes, slots on lanes: the
@@ -56,8 +77,8 @@ class BlockedKVCache:
             self.k_scale = jnp.zeros((num_kv_heads, flat), jnp.float32)
             self.v_scale = jnp.zeros((num_kv_heads, flat), jnp.float32)
         if sharding is not None:
-            self.k_pool = jax.device_put(self.k_pool, sharding)
-            self.v_pool = jax.device_put(self.v_pool, sharding)
+            for name in self._parts():
+                setattr(self, name, jax.device_put(getattr(self, name), sharding))
             if self.quantized:
                 # scales shard with the kv-head dim (pool dim 2 → scale dim 0)
                 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -66,6 +87,15 @@ class BlockedKVCache:
                     sc = NamedSharding(sharding.mesh, P(sharding.spec[2], None))
                     self.k_scale = jax.device_put(self.k_scale, sc)
                     self.v_scale = jax.device_put(self.v_scale, sc)
+
+    def _parts(self):
+        """Names of the value pools this cache has, in ``pools()`` order."""
+        return ("k_pool", "v_pool")[:len(self.entry)]
+
+    def _map_parts(self, fn) -> None:
+        """Replace every value pool ``p`` by ``fn(p)``."""
+        for name in self._parts():
+            setattr(self, name, fn(getattr(self, name)))
 
     @property
     def free_blocks(self) -> int:
@@ -118,8 +148,7 @@ class BlockedKVCache:
         dispatch cost is noise next to the prefill it saves."""
         bs = self.block_size
         s, d = int(src) * bs, int(dst) * bs
-        self.k_pool = self.k_pool.at[:, d:d + bs].set(self.k_pool[:, s:s + bs])
-        self.v_pool = self.v_pool.at[:, d:d + bs].set(self.v_pool[:, s:s + bs])
+        self._map_parts(lambda pool: pool.at[:, d:d + bs].set(pool[:, s:s + bs]))
         if self.quantized:
             # scale layout [nkv, L * NB * bs]: per-layer strided slots — copy
             # through a [nkv, L, NB*bs] view so each layer's span moves
@@ -134,7 +163,8 @@ class BlockedKVCache:
     def read_block(self, block: int):
         """Value-snapshot of one block's KV for D2H demotion:
         ``(k, v, k_scale, v_scale)`` device arrays (scales None on the
-        non-quantized layout), each a NEW functional slice of the pools.
+        non-quantized layout, ``v`` None for a latent entry, whose one part is
+        ``k``), each a NEW functional slice of the pools.
         The snapshot is safe to materialize from another thread AFTER the
         physical block is freed and even after the pool buffers themselves
         are donated to a later forward — jax slicing captures the pool
@@ -143,7 +173,7 @@ class BlockedKVCache:
         bs = self.block_size
         s = int(block) * bs
         k = self.k_pool[:, s:s + bs]
-        v = self.v_pool[:, s:s + bs]
+        v = None if self.v_pool is None else self.v_pool[:, s:s + bs]
         ks = vs = None
         if self.quantized:
             nkv, span = self.num_kv_heads, self.num_blocks * bs
@@ -151,15 +181,16 @@ class BlockedKVCache:
             vs = self.v_scale.reshape(nkv, self.num_layers, span)[:, :, s:s + bs]
         return k, v, ks, vs
 
-    def write_block(self, block: int, k, v, k_scale=None, v_scale=None) -> None:
+    def write_block(self, block: int, k, v=None, k_scale=None, v_scale=None) -> None:
         """H2D promotion: install host-resident KV into one block's slots
         (the inverse of :meth:`read_block`, same shapes). MUST run on the
         driver thread between forwards — it replaces the pool arrays, and
         racing a forward's donation would read an invalidated buffer."""
         bs = self.block_size
         d = int(block) * bs
-        self.k_pool = self.k_pool.at[:, d:d + bs].set(jnp.asarray(k, self.k_pool.dtype))
-        self.v_pool = self.v_pool.at[:, d:d + bs].set(jnp.asarray(v, self.v_pool.dtype))
+        for name, part in zip(self._parts(), (k, v)):
+            pool = getattr(self, name)
+            setattr(self, name, pool.at[:, d:d + bs].set(jnp.asarray(part, pool.dtype)))
         if self.quantized and k_scale is not None:
             nkv, span = self.num_kv_heads, self.num_blocks * bs
             for name, blk in (("k_scale", k_scale), ("v_scale", v_scale)):
@@ -180,8 +211,7 @@ class BlockedKVCache:
         dst = jnp.asarray(dst_slots, jnp.int32).reshape(-1)
         if src.size == 0:
             return
-        self.k_pool = self.k_pool.at[:, dst].set(self.k_pool[:, src])
-        self.v_pool = self.v_pool.at[:, dst].set(self.v_pool[:, src])
+        self._map_parts(lambda pool: pool.at[:, dst].set(pool[:, src]))
         if self.quantized:
             nkv = self.num_kv_heads
             span = self.num_blocks * self.block_size
@@ -192,25 +222,28 @@ class BlockedKVCache:
 
     def pools(self):
         """The donated pool tuple the compiled forwards thread through:
-        (k, v) full-precision, (k, v, k_scale, v_scale) quantized."""
+        (k, v) full-precision, (k, v, k_scale, v_scale) quantized, (latent, )
+        for a latent entry."""
+        pools = tuple(getattr(self, name) for name in self._parts())
         if self.quantized:
-            return (self.k_pool, self.v_pool, self.k_scale, self.v_scale)
-        return (self.k_pool, self.v_pool)
+            return pools + (self.k_scale, self.v_scale)
+        return pools
 
-    def update(self, k_pool, v_pool, k_scale=None, v_scale=None) -> None:
-        """Install the pools returned by the jitted forward (donated in/out)."""
+    def update(self, k_pool, v_pool=None, k_scale=None, v_scale=None) -> None:
+        """Install the pools returned by the jitted forward (donated in/out),
+        in ``pools()`` order."""
         self.k_pool, self.v_pool = k_pool, v_pool
         if k_scale is not None:
             self.k_scale, self.v_scale = k_scale, v_scale
 
     def memory_bytes(self) -> int:
-        n = 2 * self.k_pool.size * self.k_pool.dtype.itemsize
+        n = sum(p.size * p.dtype.itemsize for p in self.pools()[:len(self.entry)])
         if self.quantized:
             n += 2 * self.k_scale.size * 4
         return n
 
     def block_bytes(self) -> int:
-        """Device bytes one block occupies across all layers (K + V, scales
-        included on the int8 layout) — the unit of the prefix cache's
+        """Device bytes one block occupies across all layers (every part of
+        the entry, scales included on the int8 layout) — the unit of the prefix cache's
         ``cow_bytes`` accounting and the MRC's capacity math."""
         return self.memory_bytes() // self.num_blocks

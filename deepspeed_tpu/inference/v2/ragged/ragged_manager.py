@@ -27,11 +27,13 @@ class DSStateManager:
 
     def __init__(self, num_layers: int, num_kv_heads: int, head_dim: int, *, max_tracked_sequences: int = 128,
                  num_blocks: int = 256, block_size: int = 64, dtype=jnp.bfloat16, kv_sharding=None,
-                 prefix_cache_config=None):
+                 prefix_cache_config=None, kv_entry=None):
+        """``kv_entry``: the model's ``TransformerConfig.kv_entry`` (None =
+        per-head K and V of ``num_kv_heads`` x ``head_dim``)."""
         self.max_tracked_sequences = max_tracked_sequences
         self.block_size = block_size
         self.kv_cache = BlockedKVCache(num_layers, num_kv_heads, head_dim, num_blocks, block_size, dtype=dtype,
-                                       sharding=kv_sharding)
+                                       sharding=kv_sharding, entry=kv_entry)
         self.prefix_cache: Optional[PrefixKVCache] = None
         # host/disk capacity tier under the radix tree (tiered_store.py);
         # None whenever ragged.prefix_cache.host_tier is absent/disabled —

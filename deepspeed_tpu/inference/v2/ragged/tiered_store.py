@@ -60,7 +60,8 @@ class HostBlockPool:
     """Host mirror of one :class:`BlockedKVCache`'s block layout.
 
     Same axes as the device pools — ``k/v: [L, HB*bs, nkv, hd]`` in the
-    device dtype (int8 included) and, on the quantized layout, fp32 scale
+    device dtype (int8 included; ``v_pool`` None where the device cache has a
+    latent entry, whose one part is ``k``) and, on the quantized layout, fp32 scale
     side pools ``[nkv, L*HB*bs]`` — so a block moves between tiers as one
     contiguous span per pool, no transpose, no re-quantization. All
     mutation goes through the ``host_*`` methods below; like the device
@@ -76,11 +77,11 @@ class HostBlockPool:
         self.quantized = kv_cache.quantized
         if self.num_blocks < 1:
             raise ValueError(f"host pool needs >= 1 block, got {num_blocks}")
-        shape = (self.num_layers, self.num_blocks * self.block_size,
-                 kv_cache.num_kv_heads, kv_cache.head_dim)
         dtype = np.dtype(kv_cache.k_pool.dtype)  # ml_dtypes covers bf16
-        self.k_pool = np.zeros(shape, dtype)
-        self.v_pool = np.zeros(shape, dtype)
+        # one host pool a part of the device cache's entry (K and V, or the one latent part)
+        shapes = [(self.num_layers, self.num_blocks * self.block_size, h, w) for h, w in kv_cache.entry]
+        self.k_pool = np.zeros(shapes[0], dtype)
+        self.v_pool = np.zeros(shapes[1], dtype) if len(shapes) == 2 else None
         self.k_scale = self.v_scale = None
         if self.quantized:
             flat = self.num_layers * self.num_blocks * self.block_size
@@ -122,7 +123,8 @@ class HostBlockPool:
         bs = self.block_size
         d = int(block) * bs
         self.k_pool[:, d:d + bs] = k
-        self.v_pool[:, d:d + bs] = v
+        if self.v_pool is not None:
+            self.v_pool[:, d:d + bs] = v
         if self.quantized and k_scale is not None:
             ks, vs = self._scales()
             ks[:, :, d:d + bs] = k_scale
@@ -134,14 +136,14 @@ class HostBlockPool:
         bs = self.block_size
         s = int(block) * bs
         k = self.k_pool[:, s:s + bs]
-        v = self.v_pool[:, s:s + bs]
+        v = None if self.v_pool is None else self.v_pool[:, s:s + bs]
         if not self.quantized:
             return k, v, None, None
         ks, vs = self._scales()
         return k, v, ks[:, :, s:s + bs], vs[:, :, s:s + bs]
 
     def memory_bytes(self) -> int:
-        n = 2 * self.k_pool.size * self.k_pool.dtype.itemsize
+        n = sum(p.nbytes for p in (self.k_pool, self.v_pool) if p is not None)
         if self.quantized:
             n += 2 * self.k_scale.size * 4
         return n
@@ -406,9 +408,7 @@ class TieredBlockStore:
                 k, v, ks, vs = snapshot
                 # np.asarray IS the D2H copy — of the functional snapshot,
                 # not the live (long since reused) pool slots
-                self.pool.host_write(hb, np.asarray(k), np.asarray(v),
-                                     None if ks is None else np.asarray(ks),
-                                     None if vs is None else np.asarray(vs))
+                self.pool.host_write(hb, *(None if a is None else np.asarray(a) for a in (k, v, ks, vs)))
                 self._finalize_demote(node, hb, t0)
             except Exception:
                 with self._mu:
@@ -557,8 +557,8 @@ class TieredBlockStore:
             # KV goes to disk as raw bytes (uint8 view) — np.savez has no
             # portable story for ml_dtypes bf16, and the pool dtype is known
             # at read time anyway
-            arrs = {"k": np.ascontiguousarray(k).view(np.uint8),
-                    "v": np.ascontiguousarray(v).view(np.uint8)}
+            arrs = {name: np.ascontiguousarray(a).view(np.uint8) for name, a in (("k", k), ("v", v))
+                    if a is not None}  # a latent entry has the one part
             if ks is not None:
                 arrs["ks"], arrs["vs"] = ks, vs
             np.savez(buf, **arrs)
@@ -603,7 +603,7 @@ class TieredBlockStore:
             with np.load(io.BytesIO(raw)) as z:
                 dtype = np.dtype(self.pool.k_pool.dtype)
                 k = np.ascontiguousarray(z["k"]).view(dtype)
-                v = np.ascontiguousarray(z["v"]).view(dtype)
+                v = np.ascontiguousarray(z["v"]).view(dtype) if "v" in z.files else None
                 ks = z["ks"].copy() if "ks" in z.files else None
                 vs = z["vs"].copy() if "vs" in z.files else None
                 return k, v, ks, vs

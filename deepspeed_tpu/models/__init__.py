@@ -12,3 +12,4 @@ from .falcon import falcon, falcon_config
 from .mellum import mellum, mellum_config
 from .trinity import trinity, trinity_config
 from .sdar import sdar, sdar_config
+from .glm import glm, glm_config

@@ -154,6 +154,20 @@ class TransformerConfig:
     # ``decode``). 0 = a causal model, every other family as it was
     diffusion_block_size: int = 0
     mask_token_id: Optional[int] = None
+    # latent attention (MLA): q and k/v each go through a low-rank projection
+    # with an RMSNorm inside, a head's score has ``qk_nope_head_dim`` dims
+    # without position and ``qk_rope_head_dim`` rotated ones (``head_size`` is
+    # their sum, ``rotary_dim`` the second), the rotated KEY part is one vector
+    # all heads share, and what a token caches in a layer is the normed latent
+    # and that key part, ``kv_lora_rank + qk_rope_head_dim`` values, from which
+    # ``W_kvb`` gives every head's keys and its ``v_head_dim`` values. Served by
+    # the ragged path in the absorbed form. 0 = per-head K and V, every other
+    # family as it was
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # ZeRO++ qwZ (reference partition_parameters.py:1139 quantized all-gather
     # handles): when set (by the engine, from zero_quantized_weights), the
     # per-layer stage-3 weight gathers inside the scan body travel as int8
@@ -192,6 +206,23 @@ class TransformerConfig:
                                           "kernels take ONE position a token, which here is its block's last")
         if self.rope_layer_types is not None:
             self.rope_layer_types = tuple(self.rope_layer_types)
+        if self.latent_attention:
+            sizes = (self.q_lora_rank, self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim)
+            if min(sizes) <= 0 or self.qk_rope_head_dim % 2:
+                raise ValueError(f"kv_lora_rank={self.kv_lora_rank} needs q_lora_rank, qk_nope_head_dim, an even "
+                                 f"qk_rope_head_dim and v_head_dim, got {sizes}")
+            if self.head_size is None:
+                self.head_size = self.qk_nope_head_dim + self.qk_rope_head_dim
+            if self.rotary_dim is None:
+                self.rotary_dim = self.qk_rope_head_dim
+            if (self.head_size, self.rotary_dim) != (self.qk_nope_head_dim + self.qk_rope_head_dim,
+                                                     self.qk_rope_head_dim):
+                raise ValueError("latent attention: head_size is qk_nope_head_dim + qk_rope_head_dim and "
+                                 "rotary_dim is qk_rope_head_dim")
+            if self.sliding_window is not None or self.layer_types is not None or self.positions != "rotary" \
+                    or self.qk_norm or self.attention_gate or self.use_bias or self.diffusion_block_size:
+                raise NotImplementedError("latent attention beside a window, layer_types, alibi or learned "
+                                          "positions, a q/k norm, a gate, biases or block diffusion")
         if self.layer_types is not None:
             self.layer_types = tuple(self.layer_types)
             unknown = set(self.layer_types) - {"sliding_attention", "full_attention"}
@@ -227,6 +258,22 @@ class TransformerConfig:
     @property
     def expert_size(self):
         return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def latent_attention(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def kv_entry(self) -> Tuple[Tuple[int, int], ...]:
+        """What ONE token caches in ONE layer, as ``(heads, width)`` of each
+        pool of the paged cache: per-head K and V, or the one latent entry
+        (normed latent, then the shared rotated key part), padded to whole
+        128-lane tiles: the chip's tiled memory pads a row of 576 to 640 values
+        whatever its shape says, and the kernels read rows of whole tiles. The
+        value is the entry's first ``kv_lora_rank`` lanes, stored once."""
+        if self.latent_attention:
+            return ((1, -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128), )
+        return ((self.num_kv_heads, self.head_dim), ) * 2
 
     def layer_kind(self, l: int) -> Optional[str]:
         """Attention kind of layer ``l``; None where the model has one kind."""
@@ -267,6 +314,9 @@ class TransformerConfig:
                            (self.post_norms, "norms after attention and MLP"),
                            (self.rope_layer_types is not None, "rope in some layer kinds only"),
                            (self.embed_scale != 1.0, "a scaled embedding"),
+                           (self.latent_attention,
+                            f"latent attention (a cached latent of {self.kv_lora_rank} + {self.qk_rope_head_dim}, "
+                            "attended in the absorbed form)"),
                            (self.diffusion_block_size > 0,
                             f"a block-causal mask (blocks of {self.diffusion_block_size}, generated by masked "
                             "diffusion)")):
@@ -294,7 +344,7 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
     def gain(key, shape):
         """A norm's gain: one, unless the family's extra norms are on, whose
         gains are drawn about one so that leaving a norm out shows."""
-        if not (cfg.post_norms or cfg.qk_norm):
+        if not (cfg.post_norms or cfg.qk_norm or cfg.latent_attention):
             return jnp.ones(shape, jnp.float32)
         return 1.0 + 0.1 * jax.random.normal(key, shape, jnp.float32)
 
@@ -306,6 +356,21 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
         "wo": dense_init(k[3], (L, nq * d, H), nq * d) / math.sqrt(2 * L),
         "ln2_scale": gain(extra(13), (L, H)),
     }
+    if cfg.latent_attention:
+        # the two low-rank projections with their norms, and ``W_kvb`` as the
+        # two parts the absorbed form multiplies by, a head at a time: keys
+        # ``[nq, latent, nope]`` and values ``[nq, latent, v]`` (the published
+        # ``[latent, nq * (nope + v)]`` cut by head; no third copy is kept)
+        qr, c, nope, rope, dv = (cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                                 cfg.v_head_dim)
+        for name in ("wq", "wk", "wv"):
+            del blocks[name]
+        blocks.update(
+            wq_a=dense_init(extra(26), (L, H, qr), H), q_a_norm_scale=gain(extra(27), (L, qr)),
+            wq_b=dense_init(extra(28), (L, qr, nq * d), qr),
+            wkv_a=dense_init(extra(29), (L, H, c + rope), H), kv_a_norm_scale=gain(extra(30), (L, c)),
+            wkv_b_k=dense_init(extra(31), (L, nq, c, nope), c), wkv_b_v=dense_init(extra(32), (L, nq, c, dv), c),
+            wo=dense_init(k[3], (L, nq * dv, H), nq * dv) / math.sqrt(2 * L))
     if cfg.attention_gate:
         blocks["w_attn_gate"] = dense_init(extra(11), (L, H, nq * d), H)
     if cfg.qk_norm:

@@ -74,14 +74,16 @@ _LONG_ROW_TILE = 128
 
 
 def choose_kernel(T: int, S: int, max_blocks: int, nq: int, block_rows: int, d: int, itemsize: int,
-                  seq_idx=None, pos=None) -> dict:
+                  seq_idx=None, pos=None, parts: int = 2) -> dict:
     """Which kernel serves a batch of ``T`` tokens over ``S`` table rows of
     ``max_blocks`` columns, and with which tile: ``{"kernel", "q_tile",
     "blocks_per_step", "rule"}``. It follows from the program's static shapes
     (and the backend) alone; no argument, file or environment variable
-    changes it. ``block_rows`` is a KV block's rows, ``block_size * nkv``, and
-    ``itemsize`` the pool's; ``seq_idx``/``pos`` only let a caller with
-    CONCRETE arrays have the tiled grid's layout contract checked. In order:
+    changes it. ``block_rows`` is a KV block's rows, ``block_size * nkv``,
+    ``itemsize`` the pool's and ``parts`` the pools a grid step fetches a block
+    of (2: K and V; 1: a latent entry, read once for score and value);
+    ``seq_idx``/``pos`` only let a caller with CONCRETE arrays have the tiled
+    grid's layout contract checked. In order:
 
     - off the TPU (``off_tpu``), or heads the kernels do not tile (``nq < 8``,
       ``d % 128``: ``unsupported_shape``): the gather reference;
@@ -127,11 +129,11 @@ def choose_kernel(T: int, S: int, max_blocks: int, nq: int, block_rows: int, d: 
     else:
         rule = "heuristic:long_table"
     return {"kernel": "paged_attn_kv_split", "q_tile": 1, "rule": rule,
-            "blocks_per_step": _decode_blocks_per_step(block_rows, d, itemsize)}
+            "blocks_per_step": _decode_blocks_per_step(block_rows, d, itemsize, parts)}
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: int, window=None,
-                    alibi=None, k_scale=None, v_scale=None):
+                    alibi=None, k_scale=None, v_scale=None, value_dim=None, softmax_scale=None):
     """q: [T, nq, d]; k_pool/v_pool: [pool_len, nkv, d] (one layer,
     pool_len = num_blocks*block_size, may include one trailing scratch slot);
     block_tables: [S, max_blocks]; seq_idx/pos: [T].
@@ -142,14 +144,24 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: i
     scales [nkv, pool_len] hold one fp32 absmax/127 factor per (kv-head,
     slot); dequant happens at the kernel's tile read, so only int8 bytes
     stream from HBM.
+    ``v_pool`` None: a LATENT pool (latent attention in the absorbed form).
+    ``k_pool`` ``[pool_len, 1, d]`` holds one entry a token that every query
+    head attends (a group of ``nq``), the score is over all ``d`` lanes of it
+    and the value is its first ``value_dim`` lanes: the block is fetched ONCE a
+    grid step for both. ``softmax_scale`` replaces ``1 / sqrt(d)`` (a latent
+    entry's width is not the head's).
     The kernel and its tile are :func:`choose_kernel`'s, from the shapes.
-    Returns [T, nq, d]."""
+    Returns [T, nq, d] (``[T, nq, value_dim]`` over a latent pool)."""
     T, nq, d = q.shape
     nkv = k_pool.shape[1]
     S, max_blocks = block_tables.shape
     if window is not None:
         window = int(window)
-    choice = choose_kernel(T, S, max_blocks, nq, block_size * nkv, d, k_pool.dtype.itemsize, seq_idx, pos)
+    latent = v_pool is None
+    if latent and (nkv != 1 or k_scale is not None or alibi is not None or not value_dim):
+        raise ValueError("a latent pool is [pool_len, 1, d] with value_dim lanes of value, no int8 scales, no alibi")
+    choice = choose_kernel(T, S, max_blocks, nq, block_size * nkv, d, k_pool.dtype.itemsize, seq_idx, pos,
+                           parts=1 if latent else 2)
     _note_choice(T, S, max_blocks, choice)
     if choice["kernel"] == "paged_attention_reference":
         if choice["rule"] == "unsupported_shape":
@@ -160,7 +172,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: i
             warning_once(f"pallas paged attention: unsupported shape (nq={nq}, d={d}; needs "
                          "nq>=8, d%128==0) — serving through the DENSE gather fallback")
         return paged_attention_reference(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size,
-                                         window=window, alibi=alibi, k_scale=k_scale, v_scale=v_scale)
+                                         window=window, alibi=alibi, k_scale=k_scale, v_scale=v_scale,
+                                         value_dim=value_dim, softmax_scale=softmax_scale)
     if k_scale is not None and block_size % 128 != 0:
         # the scale block (nkv, block_size) must be lane-aligned: the TPU
         # lowering rejects it otherwise, with a message that names neither
@@ -175,12 +188,13 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: i
     # the kernels do not support, nothing else.
     return _pallas_paged(q, k_pool, v_pool, block_tables, seq_idx.astype(jnp.int32),
                          pos.astype(jnp.int32), block_size=block_size, window=window,
-                         alibi=alibi_t, k_scale=k_scale, v_scale=v_scale, q_tile=choice["q_tile"])
+                         alibi=alibi_t, k_scale=k_scale, v_scale=v_scale, q_tile=choice["q_tile"],
+                         value_dim=value_dim, softmax_scale=softmax_scale)
 
 
 def paged_attention_reference(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: int,
                               window=None, alibi=None, k_scale=None, v_scale=None,
-                              pos_ids=None, mask=None, ctx_pos_ids=None):
+                              pos_ids=None, mask=None, ctx_pos_ids=None, value_dim=None, softmax_scale=None):
     """Gather-based oracle: materializes each sequence's context. ``alibi``:
     per-head slopes [nq] (Bloom). ``k_scale``/``v_scale``: int8-KV
     dequantization factors [nkv, pool_len] (see ``paged_attention``).
@@ -190,7 +204,8 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables, seq_idx, pos, blo
     mask (the caller owns window semantics inside it); ``ctx_pos_ids``:
     [S, C] logical position of every context slot (tree nodes sit at flat
     slots but depth-based logical positions — alibi distances must use the
-    logical ones)."""
+    logical ones). ``v_pool`` None, ``value_dim``, ``softmax_scale``: a latent
+    pool, as in ``paged_attention``."""
     T, nq, d = q.shape
     nkv = k_pool.shape[1]
     g = nq // nkv
@@ -199,11 +214,11 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables, seq_idx, pos, blo
     ctx_slots = (block_tables[:, :, None] * block_size +
                  jnp.arange(block_size, dtype=jnp.int32)[None, None, :]).reshape(S, C)
     ctxk = k_pool[ctx_slots].astype(jnp.float32)  # [S, C, nkv, d]
-    ctxv = v_pool[ctx_slots].astype(jnp.float32)
+    ctxv = ctxk[..., :value_dim] if v_pool is None else v_pool[ctx_slots].astype(jnp.float32)
     if k_scale is not None:
         ctxk = ctxk * jnp.transpose(k_scale)[ctx_slots][..., None]  # [S, C, nkv, 1]
         ctxv = ctxv * jnp.transpose(v_scale)[ctx_slots][..., None]
-    qr = (q.astype(jnp.float32) / math.sqrt(d)).reshape(T, nkv, g, d)
+    qr = (q.astype(jnp.float32) * (softmax_scale or 1.0 / math.sqrt(d))).reshape(T, nkv, g, d)
     s = jnp.einsum("tngd,tcnd->tngc", qr, ctxk[seq_idx])
     pid = pos if pos_ids is None else pos_ids
     if alibi is not None:
@@ -220,7 +235,7 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables, seq_idx, pos, blo
     s = jnp.where(causal[:, None, None, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("tngc,tcnd->tngd", p, ctxv[seq_idx])
-    return out.reshape(T, nq, d).astype(q.dtype)
+    return out.reshape(T, nq, ctxv.shape[-1]).astype(q.dtype)
 
 
 def _slopes_rows(alibi, reps):
@@ -244,14 +259,17 @@ def _slopes_tok_major(alibi_g, rows):
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("block_size", "interpret", "window", "alibi", "q_tile"))
+@functools.partial(jax.jit, static_argnames=("block_size", "interpret", "window", "alibi", "q_tile", "value_dim",
+                                             "softmax_scale"))
 def _pallas_paged(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: int, interpret: bool = False,
-                  window=None, alibi=None, k_scale=None, v_scale=None, q_tile: int = 1):
+                  window=None, alibi=None, k_scale=None, v_scale=None, q_tile: int = 1, value_dim=None,
+                  softmax_scale=None):
     """The kernel the caller names, with no choice of its own: ``q_tile``
     above 1 runs ``paged_attn_q_tiled`` at that tile, 1 (one query token a
     grid row) the decode kernel ``paged_attn_kv_split``. ``paged_attention``
     passes :func:`choose_kernel`'s tile; the interpret-mode parity tests
-    name each body themselves."""
+    name each body themselves. ``v_pool`` None: a latent pool
+    (``paged_attention``), given to either kernel as its K operand alone."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -267,16 +285,17 @@ def _pallas_paged(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: int
 
     # a block as one [block * nkv, d] matrix, row t * nkv + n: the pool's own
     # bytes, the one operand both kernels take (no relayout between them)
-    as_rows = lambda pool: pool[:n_live].reshape(n_pool_blocks, block_size * nkv, d)
+    as_rows = lambda pool: None if pool is None else pool[:n_live].reshape(n_pool_blocks, block_size * nkv, d)
     if q_tile > 1:
         return _paged_q_tiled(pl, pltpu, q, as_rows(k_pool), as_rows(v_pool), block_tables, seq_idx, pos,
                               ks2, vs2, block_size=block_size, q_tile=q_tile, window=window,
-                              alibi=alibi, interpret=interpret)
+                              alibi=alibi, interpret=interpret, value_dim=value_dim, softmax_scale=softmax_scale)
     # the decode kernel takes the int8 scales [nkv, cols] laid out to match the rows
     by_col = lambda sc: jnp.transpose(sc).reshape(n_pool_blocks, 1, block_size * nkv)
     return _paged_kv_split(pl, pltpu, q, as_rows(k_pool), as_rows(v_pool), block_tables, seq_idx,
                            pos, by_col(ks2) if quant else None, by_col(vs2) if quant else None,
-                           block_size=block_size, window=window, alibi=alibi, interpret=interpret)
+                           block_size=block_size, window=window, alibi=alibi, interpret=interpret,
+                           value_dim=value_dim, softmax_scale=softmax_scale)
 
 
 # tokens of a tile's rows that the short pass of ``_paged_q_tiled`` covers: a
@@ -285,6 +304,17 @@ def _pallas_paged(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: int
 # rows only, whatever ``q_tile`` is
 _SHORT_TILE_TOKENS = 8
 _LANES = 128
+
+
+# rows of a latent tile that one pass of the head loop takes: every row of the
+# tile attends the same entry, so the loop walks the tile's rows in chunks
+_LATENT_ROW_CHUNK = 512
+
+
+def _latent_row_chunk(G: int) -> int:
+    """The largest divisor of a latent tile's ``G`` rows that is a multiple
+    of 8 and at most ``_LATENT_ROW_CHUNK`` (``G`` itself when it is small)."""
+    return max((c for c in range(8, min(G, _LATENT_ROW_CHUNK) + 1, 8) if G % c == 0), default=G)
 
 
 def _q_tiled_vmem_bytes(R: int, G: int, d: int, block_size: int, nkv: int, q_itemsize: int,
@@ -476,7 +506,8 @@ def _tiled_work_list(block_tables, seq_idx, pos, block_size: int, window, q_tile
 
 
 def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
-                   block_size: int, q_tile: int, window, alibi, interpret: bool):
+                   block_size: int, q_tile: int, window, alibi, interpret: bool, value_dim=None,
+                   softmax_scale=None):
     """Q-tiled kernel: grid steps for the LIVE (tile, KV block) pairs only.
 
     Each tile packs up to ``q_tile`` CONTIGUOUS same-sequence tokens, so
@@ -530,13 +561,24 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
     row of scales. ``m``, ``l`` and the positions are kept replicated across
     the 128 lanes, so that with 128-token blocks no step broadcasts a column.
     Alibi and the sliding window mask as in the decode kernel.
+
+    A LATENT pool (``v3`` None; ``paged_attention``): one entry a token,
+    ``k3`` ``[blocks, block_size, d]``, which all ``nq`` query heads attend, so
+    a tile is ONE group of ``nq * q_tile`` token-major rows. The block is
+    fetched once a grid step and read in place: the scores take all ``d``
+    lanes of it, the value product its first ``value_dim``, and the output is
+    ``value_dim`` wide. Nothing is copied by head; the head loop walks the
+    tile's rows in chunks of :func:`_latent_row_chunk` instead (each row has
+    its own position, so a chunk need not hold whole tokens).
     """
     T, nq, d = q.shape
     nkv = k3.shape[1] // block_size
     g = nq // nkv
     qt = int(q_tile)
     quant = ks2 is not None
-    scale = 1.0 / math.sqrt(d)
+    latent = v3 is None
+    dv = int(value_dim) if latent else d   # width of a value, of acc and of the output
+    scale = softmax_scale or 1.0 / math.sqrt(d)
     G = g * qt                     # rows of one kv head in a tile
     R = nkv * G                    # == nq * qt
     short = min(G, g * _SHORT_TILE_TOKENS)
@@ -576,11 +618,13 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
 
     nt_dims = (((1, ), (1, )), ((), ()))  # [rows, d] x [block, d] -> [rows, block]
 
-    def kernel(tile_ref, col_ref, seq_ref, cnt_ref, bt_ref, q_ref, k_ref, v_ref, pos_ref, *rest):
-        if quant:
-            ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref, kh_ref, vh_ref = rest
+    def kernel(tile_ref, col_ref, seq_ref, cnt_ref, bt_ref, q_ref, k_ref, *rest):
+        if latent:
+            pos_ref, o_ref, acc_ref, m_ref, l_ref = rest
+        elif quant:
+            v_ref, pos_ref, ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref, kh_ref, vh_ref = rest
         else:
-            o_ref, acc_ref, m_ref, l_ref, kh_ref, vh_ref = rest
+            v_ref, pos_ref, o_ref, acc_ref, m_ref, l_ref, kh_ref, vh_ref = rest
         i = pl.program_id(0)
         tile = tile_ref[i]
         jb = col_ref[i]  # the table column this step covers
@@ -591,8 +635,46 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
             m_ref[:] = jnp.full_like(m_ref, -1e30)
             l_ref[:] = jnp.zeros_like(l_ref)
 
+        def _update(r, s, values):
+            """Rows ``r`` of the tile's online softmax take the masked scores
+            ``s`` of this block and its ``values`` ``[block, dv]``."""
+            m_prev = m_ref[r, :]               # [rows, 128], lanes equal
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - _lanes(m_new, block_size))
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[r, :] = l_ref[r, :] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[r, :] = acc_ref[r, :] * _lanes(alpha, dv) + jax.lax.dot(
+                p.astype(cdt), values, preferred_element_type=jnp.float32)
+            m_ref[r, :] = m_new
+
+        def _latent(rows):
+            """The block's entries against the tile's first ``rows`` rows, a
+            chunk of rows at a time; the block is read where it lies."""
+            step = _latent_row_chunk(rows)
+
+            def chunk(n):
+                r0 = n * step
+                if not isinstance(r0, int):
+                    r0 = pl.multiple_of(r0, 8)
+                r = pl.ds(r0, step)
+                my_pos = _lanes(pos_ref[0, r, :], block_size)   # -1 on invalid slots
+                kpos = jb * block_size + jax.lax.broadcasted_iota(jnp.int32, (step, block_size), 1)
+                vis = kpos <= my_pos
+                if window is not None:
+                    vis = jnp.logical_and(vis, my_pos - kpos < window)
+                s = jax.lax.dot_general(q_ref[0, r, :].astype(cdt), k_ref[0].astype(cdt), nt_dims,
+                                        preferred_element_type=jnp.float32) * scale
+                _update(r, jnp.where(vis, s, -1e30), k_ref[0, :, :dv].astype(cdt))
+
+            if rows == step:
+                chunk(0)
+            else:
+                jax.lax.fori_loop(0, rows // step, lambda n, c: (chunk(n), c)[1], 0)
+
         def _compute(rows):
             """One KV block against the first ``rows`` rows of every kv head."""
+            if latent:
+                return _latent(rows)
             my_pos = _lanes(pos_ref[0, :rows, :], block_size)   # -1 on invalid slots
             kpos = jb * block_size + jax.lax.broadcasted_iota(jnp.int32, (rows, block_size), 1)
             vis = kpos <= my_pos
@@ -620,15 +702,7 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
                                         preferred_element_type=jnp.float32) * scale
                 if alibi is not None:
                     s = s + _slopes_tok_major(alibi[n * g:(n + 1) * g], rows) * rel
-                s = jnp.where(vis, s, -1e30)
-                m_prev = m_ref[r, :]               # [rows, 128], lanes equal
-                m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-                p = jnp.exp(s - _lanes(m_new, block_size))
-                alpha = jnp.exp(m_prev - m_new)
-                l_ref[r, :] = l_ref[r, :] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-                acc_ref[r, :] = acc_ref[r, :] * _lanes(alpha, d) + jax.lax.dot(
-                    p.astype(cdt), vh_ref[n], preferred_element_type=jnp.float32)
-                m_ref[r, :] = m_new
+                _update(r, jnp.where(vis, s, -1e30), vh_ref[n])
 
             if alibi is None:
                 # traced once and unrolled by the lowering: the same straight
@@ -649,15 +723,12 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
 
         @pl.when(tile_ref[i + 1] != tile)
         def _finalize():
-            o_ref[0] = (acc_ref[:] / _lanes(jnp.maximum(l_ref[:], 1e-30), d)).astype(o_ref.dtype)
+            o_ref[0] = (acc_ref[:] / _lanes(jnp.maximum(l_ref[:], 1e-30), dv)).astype(o_ref.dtype)
 
-    in_specs = [
-        pl.BlockSpec((1, R, d), q_map),
-        pl.BlockSpec((1, block_size * nkv, d), kv_map),
-        pl.BlockSpec((1, block_size * nkv, d), kv_map),
-        pl.BlockSpec((1, G, _LANES), q_map),
-    ]
-    operands = [q_t, k3, v3, pos_rows]
+    kv_spec = pl.BlockSpec((1, block_size * nkv, d), kv_map)
+    in_specs = [pl.BlockSpec((1, R, d), q_map)] + [kv_spec] * (1 if latent else 2) + [
+        pl.BlockSpec((1, G, _LANES), q_map)]
+    operands = [q_t, k3] + ([] if latent else [v3]) + [pos_rows]
     if quant:
         in_specs += [pl.BlockSpec((nkv, block_size), scale_map),
                      pl.BlockSpec((nkv, block_size), scale_map)]
@@ -667,14 +738,15 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
         num_scalar_prefetch=5,
         grid=(total, ),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, R, d), q_map),
+        out_specs=pl.BlockSpec((1, R, dv), q_map),
         scratch_shapes=[
-            pltpu.VMEM((R, d), jnp.float32),
+            pltpu.VMEM((R, dv), jnp.float32),
             pltpu.VMEM((R, _LANES), jnp.float32),
             pltpu.VMEM((R, _LANES), jnp.float32),
+        ] + ([] if latent else [
             pltpu.VMEM((nkv, block_size, d), cdt),   # the block's K, V by kv head
             pltpu.VMEM((nkv, block_size, d), cdt),
-        ],
+        ]),
     )
     kwargs = {}
     if not interpret:
@@ -685,11 +757,11 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
         kwargs["compiler_params"] = pltpu.CompilerParams(
             vmem_limit_bytes=max(32 << 20, min(need * 3 // 2, 100 << 20)))
     out_t = pl.pallas_call(kernel, grid_spec=grid_spec,
-                           out_shape=jax.ShapeDtypeStruct((n_tiles, R, d), q.dtype),
+                           out_shape=jax.ShapeDtypeStruct((n_tiles, R, dv), q.dtype),
                            interpret=interpret, name="paged_attn_q_tiled", **kwargs)(
                                w_tile, w_col, tile_seq, tile_cnt, block_tables, *operands)
     # scatter tiles back to token order: only slots that tokens fill are read
-    flat = out_t.reshape(n_tiles, nkv, qt, g, d).transpose(0, 2, 1, 3, 4).reshape(n_tiles * qt, nq, d)
+    flat = out_t.reshape(n_tiles, nkv, qt, g, dv).transpose(0, 2, 1, 3, 4).reshape(n_tiles * qt, nq, dv)
     return flat[tile_id * qt + slot]
 
 
@@ -699,12 +771,13 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
 _DECODE_STEP_BYTES = 1 << 20
 
 
-def _decode_blocks_per_step(rows: int, d: int, itemsize: int) -> int:
+def _decode_blocks_per_step(rows: int, d: int, itemsize: int, parts: int = 2) -> int:
     """KV blocks one grid step of the decode kernel takes (1, 2 or 4), from
     the bytes of one block's K and V (``rows = block_size * nkv`` rows of
-    ``d``): a grid step costs about a third of a microsecond whatever it
-    fetches, so a step should stream at least ``_DECODE_STEP_BYTES``."""
-    return max(1, min(4, _DECODE_STEP_BYTES // (2 * rows * d * itemsize)))
+    ``d``; ``parts`` 1: a latent block, fetched once): a grid step costs about
+    a third of a microsecond whatever it fetches, so a step should stream at
+    least ``_DECODE_STEP_BYTES``."""
+    return max(1, min(4, _DECODE_STEP_BYTES // (parts * rows * d * itemsize)))
 
 
 def decode_kv_counts(choice, pos, windows, block_size: int, max_blocks: int, bucket_rows: int):
@@ -810,7 +883,7 @@ def _decode_work_list(block_tables, seq_idx, pos, block_size: int, window, per_s
 
 
 def _paged_kv_split(pl, pltpu, q, k2, v2, block_tables, seq_idx, pos, ks2, vs2,
-                    block_size: int, window, alibi, interpret: bool):
+                    block_size: int, window, alibi, interpret: bool, value_dim=None, softmax_scale=None):
     """The decode kernel: grid steps for the LIVE (row, KV block) pairs only.
 
     A decode batch is one query token a row against contexts of very
@@ -846,17 +919,30 @@ def _paged_kv_split(pl, pltpu, q, k2, v2, block_tables, seq_idx, pos, ks2, vs2,
     are float32, ``m``/``l`` replicated across the lanes. int8 KV is cast
     exactly and its per-token scales (``ks2``/``vs2``: ``[blocks, 1, block *
     nkv]``, laid out by the caller) multiply the scores and the probabilities
-    in float32."""
+    in float32.
+
+    A LATENT pool (``v2`` None; ``paged_attention``): a block is the one
+    matrix ``[block_size, d]`` of its tokens' entries, fetched once a step; the
+    scores take all of it, the value product its first ``value_dim`` lanes,
+    and ``acc`` and the output are ``value_dim`` wide. The query heads are
+    padded to whole 16-row tiles of the MXU's left operand."""
     T, nq, d = q.shape
+    latent = v2 is None
+    if latent and nq % 16:
+        out = _paged_kv_split(pl, pltpu, jnp.pad(q, ((0, 0), (0, -nq % 16), (0, 0))), k2, v2, block_tables,
+                              seq_idx, pos, ks2, vs2, block_size, window, alibi, interpret, value_dim,
+                              softmax_scale)
+        return out[:, :nq]
     M = k2.shape[1]                # rows of a block: block_size * nkv
     nkv = M // block_size
     g = nq // nkv
     quant = ks2 is not None
-    scale = 1.0 / math.sqrt(d)
+    dv = int(value_dim) if latent else d   # width of a value, of acc and of the output
+    scale = softmax_scale or 1.0 / math.sqrt(d)
     # operands of the two dots: what q and the pool hold, unless the pool is
     # quantised (int8 is exact in float32, and the scales are float32)
     cdt = jnp.float32 if quant else jnp.promote_types(q.dtype, k2.dtype)
-    B = _decode_blocks_per_step(M, d, k2.dtype.itemsize)
+    B = _decode_blocks_per_step(M, d, k2.dtype.itemsize, 1 if latent else 2)
     w_row, w_col, w_blk, total = _decode_work_list(block_tables, seq_idx, pos, block_size, window, B)
     bound = w_col.shape[0]
 
@@ -869,7 +955,12 @@ def _paged_kv_split(pl, pltpu, q, k2, v2, block_tables, seq_idx, pos, ks2, vs2,
     nt_dims = (((1, ), (1, )), ((), ()))  # [nq, d] x [M, d] -> [nq, M]
 
     def kernel(row_ref, col_ref, blk_ref, pos_ref, q_ref, tok_ref, *rest):
-        k_refs, v_refs, rest = rest[:B], rest[B:2 * B], rest[2 * B:]
+        if latent:  # the value is the entry's first lanes: the same block, read where it lies
+            k_refs, rest = rest[:B], rest[B:]
+            value = lambda b: k_refs[b][0, :, :dv]
+        else:
+            k_refs, v_refs, rest = rest[:B], rest[B:2 * B], rest[2 * B:]
+            value = lambda b: v_refs[b][0]
         if quant:
             ks_refs, vs_refs, rest = rest[:B], rest[B:2 * B], rest[2 * B:]
         o_ref, acc_ref, m_ref, l_ref = rest
@@ -909,13 +1000,13 @@ def _paged_kv_split(pl, pltpu, q, k2, v2, block_tables, seq_idx, pos, ks2, vs2,
             m_new = jnp.maximum(m_new, jnp.max(sc, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_ref[:] * alpha
-        acc = acc_ref[:] * _lanes(alpha, d)
+        acc = acc_ref[:] * _lanes(alpha, dv)
         for b, sc in enumerate(scs):
             p = jnp.exp(sc - _lanes(m_new, M))
             l_new = l_new + jnp.sum(p, axis=-1, keepdims=True)
             if quant:
                 p = p * vs_refs[b][0]
-            acc = acc + jax.lax.dot(p.astype(cdt), v_refs[b][0].astype(cdt),
+            acc = acc + jax.lax.dot(p.astype(cdt), value(b).astype(cdt),
                                     preferred_element_type=jnp.float32)
         l_ref[:] = l_new
         m_ref[:] = m_new
@@ -923,15 +1014,15 @@ def _paged_kv_split(pl, pltpu, q, k2, v2, block_tables, seq_idx, pos, ks2, vs2,
 
         @pl.when(row_ref[i + 1] != row)
         def _finalize():
-            o_ref[0] = (acc_ref[:] / _lanes(jnp.maximum(l_ref[:], 1e-30), d)).astype(o_ref.dtype)
+            o_ref[0] = (acc_ref[:] / _lanes(jnp.maximum(l_ref[:], 1e-30), dv)).astype(o_ref.dtype)
 
     # the one table of the masks, from the shapes alone (a constant of the
     # program, fetched once: its block index never changes)
     col = np.arange(M)
     tok_of = np.where(col[None, :] % nkv == np.arange(nq)[:, None] // g, col[None, :] // nkv, 2**30)
     in_specs = [pl.BlockSpec((1, nq, d), q_map), pl.BlockSpec((nq, M), lambda i, *refs: (0, 0))]
-    in_specs += [pl.BlockSpec((1, M, d), kv_map(b)) for b in range(B)] * 2
-    operands = [q, jnp.asarray(tok_of, jnp.int32)] + [k2] * B + [v2] * B
+    in_specs += [pl.BlockSpec((1, M, d), kv_map(b)) for b in range(B)] * (1 if latent else 2)
+    operands = [q, jnp.asarray(tok_of, jnp.int32)] + [k2] * B + ([] if latent else [v2] * B)
     if quant:
         in_specs += [pl.BlockSpec((1, 1, M), kv_map(b)) for b in range(B)] * 2
         operands += [ks2] * B + [vs2] * B
@@ -940,14 +1031,14 @@ def _paged_kv_split(pl, pltpu, q, k2, v2, block_tables, seq_idx, pos, ks2, vs2,
         num_scalar_prefetch=4,
         grid=(total, ),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, nq, d), q_map),
+        out_specs=pl.BlockSpec((1, nq, dv), q_map),
         scratch_shapes=[
-            pltpu.VMEM((nq, d), jnp.float32),
+            pltpu.VMEM((nq, dv), jnp.float32),
             pltpu.VMEM((nq, _LANES), jnp.float32),
             pltpu.VMEM((nq, _LANES), jnp.float32),
         ],
     )
     return pl.pallas_call(kernel, grid_spec=grid_spec,
-                          out_shape=jax.ShapeDtypeStruct((T, nq, d), q.dtype),
+                          out_shape=jax.ShapeDtypeStruct((T, nq, dv), q.dtype),
                           interpret=interpret, name="paged_attn_kv_split")(
                               w_row, w_col, w_blk, pos, *operands)

@@ -380,7 +380,7 @@ def blocks_per_step(request, monkeypatch):
     """The decode kernel with ``request.param`` KV blocks a grid step, whatever
     the rule would give the cases' small blocks (the jitted kernel is traced
     anew)."""
-    monkeypatch.setattr(pa_mod, "_decode_blocks_per_step", lambda rows, d, itemsize: request.param)
+    monkeypatch.setattr(pa_mod, "_decode_blocks_per_step", lambda rows, d, itemsize, parts=2: request.param)
     _pallas_paged.clear_cache()
     yield request.param
     _pallas_paged.clear_cache()

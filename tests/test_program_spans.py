@@ -315,28 +315,34 @@ def _step_case(name, plant):
 
 
 # what the step span of each case said at commit 3963d3a (the parent of the PR that moved the observation under
-# a span of its own), but ``seqs``, which repeated ``rows``, and ``block_ms``, a time; uids from 50
+# a span of its own), but ``seqs``, which repeated ``rows``, and ``block_ms``, a time; uids from 50; and, since PR 38,
+# what a causal step says of its attention work by hand: ``attn_pairs``, ``attn_ctx_tokens``, ``kv_entry_bytes``
 STEP_SPAN_ARGS = {
     "dense_put_with_a_prefill_chunk": {
         "blocked": True, "bucket_rows": 4, "bucket_tokens": 32, "kernel": "paged_attn_q_tiled:8:planted",
         "rows": 3, "rows_decode": 2, "steps": 1, "tile_kv_bound": 108, "tile_kv_live": 18, "tokens": 22,
-        "uids": [50, 51, 52]},
+        "uids": [50, 51, 52], "attn_pairs": 2 * (31 + 10 + 210), "attn_ctx_tokens": 2 * (31 + 10 + 20), "kv_entry_bytes": 256},
     "dense_put_of_decode_rows": {
         "blocked": True, "bucket_rows": 4, "bucket_tokens": 8, "kernel": "paged_attention_reference:1:off_tpu",
-        "kv_live": 6, "kv_steps": 96, "rows": 2, "rows_decode": 2, "steps": 1, "tokens": 2, "uids": [50, 51]},
+        "kv_live": 6, "kv_steps": 96, "rows": 2, "rows_decode": 2, "steps": 1, "tokens": 2, "uids": [50, 51],
+        "attn_pairs": 2 * (31 + 10), "attn_ctx_tokens": 2 * (31 + 10), "kv_entry_bytes": 256},
     "dense_decode": {
         "blocked": True, "bucket_rows": 4, "bucket_tokens": 4, "kernel": "paged_attention_reference:1:off_tpu",
-        "kv_live": 36, "kv_steps": 240, "rows": 2, "steps": 5, "tokens": 10, "uids": [50, 51]},
+        "kv_live": 36, "kv_steps": 240, "rows": 2, "steps": 5, "tokens": 10, "uids": [50, 51],
+        "attn_pairs": 2 * (165 + 60), "attn_ctx_tokens": 2 * (35 + 14), "kv_entry_bytes": 256},
     "experts_put_with_a_prefill_chunk": {
         "blocked": True, "bucket_rows": 4, "bucket_tokens": 32, "expert_load_max": 19, "experts_held": 8,
         "experts_hit": 29, "experts_published": 8, "experts_total": 32,
         "kernel": "paged_attention_reference:1:off_tpu", "moe_rows": 704, "moe_slots": 176,
-        "moe_slots_routed": 176, "rows": 3, "rows_decode": 2, "steps": 1, "tokens": 22, "uids": [50, 51, 52]},
+        "moe_slots_routed": 176, "rows": 3, "rows_decode": 2, "steps": 1, "tokens": 22, "uids": [50, 51, 52],
+        # three window layers of 16 (16 + 13 + 200 pairs over 16 + 13 + 20 tokens) and a full one
+        "attn_pairs": 3 * 229 + 264, "attn_ctx_tokens": 3 * 49 + 74, "kv_entry_bytes": 512},
     "experts_decode": {
         "blocked": True, "bucket_rows": 4, "bucket_tokens": 4, "expert_load_max": 2, "experts_held": 8,
         "experts_hit": 68, "experts_published": 8, "experts_total": 160,
         "kernel": "paged_attention_reference:1:off_tpu", "kv_live": 69, "kv_steps": 640, "moe_rows": 1280,
-        "moe_slots": 80, "moe_slots_routed": 80, "rows": 2, "steps": 5, "tokens": 10, "uids": [50, 51]},
+        "moe_slots": 80, "moe_slots_routed": 80, "rows": 2, "steps": 5, "tokens": 10, "uids": [50, 51],
+        "attn_pairs": 3 * (80 + 74) + 215 + 75, "attn_ctx_tokens": 3 * (20 + 17) + 45 + 17, "kv_entry_bytes": 512},
     "experts_decode_blocks": {
         "block_size": 4, "blocked": True, "blocks": 2, "bucket_rows": 8, "bucket_tokens": 32, "commit_forwards": 2,
         "denoise_forwards": 8, "expert_load_max": 9, "experts_held": 8, "experts_hit": 193, "experts_published": 8,

@@ -905,3 +905,68 @@ def test_remat_train_step_holds_one_flash_fwd_per_layer_body(head_dim):
     calls, n = _flash_calls_in_compiled_train_step(head_dim)
     print(f"heads of {head_dim}: {calls}, {n} x tpu_custom_call")
     assert calls == {"flash_fwd": 1, "flash_bwd_dkdv": 1, "flash_bwd_dq": 1} and n == 3, (calls, n)
+
+
+def _latent_reference_by_run(q, pool, tables, rows, bs, dv, scale):
+    """Float32 attention of ragged rows ``(context_before, new_tokens)`` over a
+    latent pool ``[slots, 1, W]``: every head against the same entries, the
+    value their first ``dv`` lanes; one sequence and 256 queries at a time."""
+    outs, t0 = [], 0
+    for r, (before, new) in enumerate(rows):
+        n_ctx = -(-(before + new) // bs)
+        slots = (tables[r][:n_ctx, None] * bs + jnp.arange(bs)[None, :]).reshape(-1)
+        k = pool[slots, 0].astype(jnp.float32)
+        ctx = jnp.arange(k.shape[0])[None, :]
+        for c0 in range(0, new, 256):
+            c1 = min(new, c0 + 256)
+            p = jnp.arange(before + c0, before + c1)[:, None]
+            s = jnp.einsum("thd,cd->thc", q[t0 + c0:t0 + c1].astype(jnp.float32) * scale, k, precision="highest")
+            w = jax.nn.softmax(jnp.where((ctx <= p)[:, None, :], s, -1e30), axis=-1)
+            outs.append(jnp.einsum("thc,cd->thd", w, k[:, :dv], precision="highest"))
+        t0 += new
+    return jnp.concatenate(outs, 0)
+
+
+@pytest.mark.parametrize("name,T,S,rows,kernel", [
+    # glm-4.7-flash.longdoc: a 2,048-token chunk at 30k of history
+    ("chunk_at_30k", 2048, 8, [(30000, 2048)], "paged_attn_q_tiled"),
+    # the same bucket as the closed loop fills it: a chunk behind seven riding decode rows
+    ("chunk_mixed", 2048, 8, [(17000 + 2000 * i, 1) for i in range(7)] + [(12288, 2041)], "paged_attn_q_tiled"),
+    # 8 decode rows at 17k-31k
+    ("decode_8_rows", 8, 8, [(17109 + 2038 * i, 1) for i in range(8)], "paged_attn_kv_split"),
+])
+def test_paged_kernels_over_a_latent_pool_at_the_cells_shapes_on_chip(name, T, S, rows, kernel):
+    """Both paged kernels on a LATENT pool at ``glm-4.7-flash.longdoc``'s
+    shapes (20 heads against one 640-wide entry a token, the value its first
+    512 lanes, 128-token blocks, tables 257 wide, bf16) through
+    ``paged_attention`` as the engine calls it, against a float32 reference.
+    Prints the microseconds a call."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    W, dv, nq, bs, mb, n_blocks = 640, 512, 20, 128, 257, 2100
+    rng = np.random.default_rng(38)
+    pool = jnp.asarray(rng.normal(size=(n_blocks * bs, 1, W)), jnp.bfloat16)
+    tables = jnp.asarray(np.stack([rng.permutation(n_blocks)[:mb] for _ in range(S)]), jnp.int32)
+    seq_idx = np.concatenate([np.full(new, r) for r, (_, new) in enumerate(rows)])
+    pos = np.concatenate([np.arange(before, before + new) for before, new in rows])
+    n = seq_idx.size
+    seq_idx = jnp.asarray(np.pad(seq_idx, (0, T - n)), jnp.int32)
+    pos = jnp.asarray(np.pad(pos, (0, T - n)), jnp.int32)
+    q = jnp.asarray(rng.normal(size=(T, nq, W)) / 4, jnp.bfloat16)
+    scale = 1.0 / 16.0
+
+    pa.KERNEL_CHOICES.pop((T, S, mb), None)
+    fn = jax.jit(lambda q, tables, seq_idx, pos: pa.paged_attention(q, pool, None, tables, seq_idx, pos, bs,
+                                                                    value_dim=dv, softmax_scale=scale))
+    out = fn(q, tables, seq_idx, pos)
+    assert out.shape == (T, nq, dv)
+    choice = pa.kernel_choice(T, S, mb)
+    assert choice["kernel"] == kernel, choice
+    us = _us_a_call(fn, q, tables, seq_idx, pos, calls=10)
+    pairs = sum(new * before + new * (new + 1) // 2 for before, new in rows)
+    print(f"\nlatent_pool[{name}]: {us:.0f} us a call, {choice}; absorbed {pairs * nq * 2 * (W + dv) / us / 1e6:.1f} "
+          f"TFLOP/s, entries read {sum(b + n_ for b, n_ in rows) * W * 2 / us / 1e3:.1f} GB/s")
+    ref = np.asarray(_latent_reference_by_run(q, pool, tables, rows, bs, dv, scale), np.float32)
+    got = np.asarray(out[:n], np.float32)
+    np.testing.assert_allclose(got, ref, atol=5e-2, rtol=5e-2)
+    assert np.linalg.norm(got - ref) / np.linalg.norm(ref) < 5e-3
