@@ -30,7 +30,8 @@ from ...moe.grouped import merge_routing_stats
 from ...ops.pallas.paged_attention import decode_kv_counts, kernel_choice, tiled_kv_counts
 from ...utils.logging import log_dist
 from .config_v2 import RaggedInferenceEngineConfig
-from .model_implementations.flat_model import ragged_forward
+from .model_implementations.flat_model import (expanded_batch, expanded_plan, expanded_slots, expanded_workspace_bytes,
+                                                ragged_forward)
 from .ragged.ragged_manager import DSStateManager
 from .ragged.ragged_wrapper import RaggedBatchWrapper, next_bucket
 from .scheduling_utils import SchedulingError, SchedulingResult
@@ -271,8 +272,11 @@ class InferenceEngineV2:
         (resolves the round-2 'auto sizing TODO against HBM stats'):
         blocks = kv_memory_fraction x free / bytes_per_block, clamped to at
         least one max-context sequence and to the tracked-sequence budget.
-        Without memory stats (CPU) the demand is capped at a conservative
-        host budget instead of allocating the full tracked-sequence demand."""
+        Free is what the params AND the programs' own workspace leave
+        (``flat_model.expanded_workspace_bytes``: a model with latent attention
+        alone has one). Without memory stats (CPU) the demand is capped at a
+        conservative host budget instead of allocating the full
+        tracked-sequence demand."""
         import numpy as _np
 
         bs = ic.kv_block_size
@@ -288,7 +292,10 @@ class InferenceEngineV2:
             if stats and "bytes_limit" in stats:
                 param_bytes = sum(int(_np.prod(x.shape)) * x.dtype.itemsize
                                   for x in jax.tree_util.tree_leaves(self.params))
-                used = max(stats.get("bytes_in_use", 0), param_bytes)
+                # ... and what the largest program keeps beside the pool while it runs: the per-head K and V of
+                # latent attention's long rows (zero for every other model), which the pool must not be given
+                used = max(stats.get("bytes_in_use", 0), param_bytes) + expanded_workspace_bytes(
+                    mc, ic.state_manager.max_ragged_batch_size, -(-max_context // bs), bs, dt_bytes)
                 free = max(0, int(stats["bytes_limit"]) - used)
         except Exception:
             free = None
@@ -479,7 +486,7 @@ class InferenceEngineV2:
                 bucket_tokens=int(t_bucket), bucket_rows=int(s_bucket), steps=1,
                 kernel=self._kernel_of(t_bucket, s_bucket), uids=[int(u) for u in batch_uids[:16]],
                 blocked=bool(block),
-                **self._attn_span_args([seq.seen_tokens for seq in descs], [t.size for t in batch_tokens]),
+                **self._attn_span_args([seq.seen_tokens for seq in descs], [t.size for t in batch_tokens], t_bucket),
                 **({} if had_prefill else
                    self._kv_span_args(t_bucket, s_bucket, [[seq.seen_tokens for seq in descs]])),
                 **self._tiled_kv_span_args(t_bucket, s_bucket, rb)))
@@ -536,7 +543,7 @@ class InferenceEngineV2:
                 "expert_load_max": int(stats[1]),
                 "experts_held": mc.experts_held, "experts_published": mc.moe_num_experts}
 
-    def _attn_span_args(self, seen, new) -> dict:
+    def _attn_span_args(self, seen, new, t_bucket: int = 0) -> dict:
         """What a step span says of the attention work whatever kernel and
         form ran it, from the rows' lengths alone: ``attn_pairs``, the visible
         (query token, context token) pairs of a call that feeds row ``r`` the
@@ -545,7 +552,11 @@ class InferenceEngineV2:
         queries see (what a step must read at least once a row), both summed
         over layers with each layer's window applied; ``kv_entry_bytes``, the
         bytes one token caches in one layer (``2 x nkv x d x itemsize``, or the
-        latent entry's), so that a reader need not know the family."""
+        latent entry's), so that a reader need not know the family. A model
+        with latent attention says ``attn_expanded_pairs`` too: those of
+        ``attn_pairs`` that the ``put`` program of ``t_bucket`` tokens attended
+        in the expanded form (``flat_model.expanded_slots`` on the same
+        lengths; 0 for a decode horizon, which has no such program)."""
         seen, new = np.asarray(seen, np.int64), np.asarray(new, np.int64)
         pairs = ctx = 0
         for window, layers in self._kv_windows:
@@ -557,8 +568,20 @@ class InferenceEngineV2:
                 row_ctx = seen + new - np.maximum(seen + 1 - window, 0)
             pairs, ctx = pairs + layers * int(row_pairs.sum()), ctx + layers * int(row_ctx.sum())
         kv = self.state_manager.kv_cache
-        return {"attn_pairs": pairs, "attn_ctx_tokens": ctx, "kv_entry_bytes": kv.block_bytes() // (
+        args = {"attn_pairs": pairs, "attn_ctx_tokens": ctx, "kv_entry_bytes": kv.block_bytes() // (
             kv.block_size * kv.num_layers)}
+        if self._latent:  # no window beside a latent cache: ``row_pairs`` is the one kind's
+            expanded = expanded_slots(new, seen + new, *self._expanded_plan(t_bucket), kv.block_size, xp=np) >= 0
+            args["attn_expanded_pairs"] = self.model_config.num_layers * int((row_pairs * expanded).sum())
+        return args
+
+    def _expanded_plan(self, t_bucket: int):
+        """``flat_model.expanded_plan`` of this engine's ``put`` program of
+        ``t_bucket`` tokens: ``(0, 0)`` for a model without latent attention."""
+        if not self._latent:
+            return 0, 0
+        return expanded_plan(self.model_config, int(t_bucket), self._max_blocks_per_seq, self.config.kv_block_size,
+                             np.dtype(self.config.kv_dtype).itemsize)
 
     def _kv_span_args(self, T: int, S: int, pos) -> dict:
         """What a decode span says of the attention kernel's grid: ``kv_live``
@@ -589,12 +612,23 @@ class InferenceEngineV2:
         if choice is None or choice["kernel"] != "paged_attn_q_tiled":
             return {}
         last = self.model_config.layer_window(self.model_config.num_layers - 1)
+        bs = self.config.kv_block_size
+        rows, cols = self._expanded_plan(T)
         bound = live = 0
         for offset, n, kv_only in forwards:
             # attention calls a window: its layers in every forward, less the last layer's in a commit
             calls = [(w, layers * n - (kv_only if w == last else 0)) for w, layers in self._kv_windows]
-            b, l = tiled_kv_counts(choice["q_tile"], rb.token_seq_idx, (rb.token_pos + offset) | max(self._block - 1, 0),
-                                   calls, self.config.kv_block_size, self._max_blocks_per_seq, S)
+            pos = (rb.token_pos + offset) | max(self._block - 1, 0)
+            if rows:
+                # latent attention's two calls: the absorbed one without the long rows' tiles, and the
+                # expanded one over the workspace's own table
+                slots = expanded_slots(rb.seq_total_len - rb.seq_start_len, rb.seq_total_len, rows, cols, bs, xp=np)
+                slot_of_tok = np.where(rb.token_valid, slots[rb.token_seq_idx], -1)
+                x_seq, x_pos = expanded_batch(slot_of_tok, pos, rows, xp=np)
+                x_choice = kernel_choice(T, 2 * rows + 1, cols)
+                b, l = tiled_kv_counts(x_choice["q_tile"], x_seq, x_pos, calls, bs, cols, 2 * rows + 1)
+                bound, live, pos = bound + b, live + l, np.where(slot_of_tok >= 0, -1, pos)
+            b, l = tiled_kv_counts(choice["q_tile"], rb.token_seq_idx, pos, calls, bs, self._max_blocks_per_seq, S)
             bound, live = bound + b, live + l
         return {"tile_kv_live": live, "tile_kv_bound": bound}
 
@@ -603,16 +637,21 @@ class InferenceEngineV2:
         KV blocks a grid step of the decode kernel takes) of the paged-attention
         call inside the compiled program of ``T`` tokens and ``S`` rows,
         looked up once per shape in the table ``paged_attention`` fills while
-        ``jit`` traces it (so only after the program's first call). Empty
+        ``jit`` traces it (so only after the program's first call); a program
+        with latent attention's expanded call names both calls, the absorbed
+        one first, joined by ``+``. Empty
         while nothing recorded a choice for the shape (an attention module
         that is not the paged kernel's)."""
         label = self._kernel_labels.get((T, S))
         if label is None:
-            choice = kernel_choice(T, S, self._max_blocks_per_seq)
-            if choice is None:
+            choices = [kernel_choice(T, S, self._max_blocks_per_seq)]
+            rows, cols = self._expanded_plan(T)
+            if rows:  # latent attention's second call, over the workspace of per-head K and V
+                choices.append(kernel_choice(T, 2 * rows + 1, cols))
+            if None in choices:
                 return ""
-            label = self._kernel_labels[(T, S)] = "%s:%d:%s" % (
-                choice["kernel"], max(choice["q_tile"], choice["blocks_per_step"]), choice["rule"])
+            label = self._kernel_labels[(T, S)] = "+".join("%s:%d:%s" % (
+                c["kernel"], max(c["q_tile"], c["blocks_per_step"]), c["rule"]) for c in choices)
         return label
 
     # ------------------------------------------------------------------

@@ -33,9 +33,128 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ....models.transformer import TransformerConfig, apply_rope, mlp_activation, rope_inv_freq, rope_table
 from ....moe.grouped import merge_routing_stats
+
+# Latent attention, the form a row a step (``ragged_forward``). Expanded, a
+# (query, key) pair a head costs ``2 (d + dv)`` operations where absorbed costs
+# ``2 (W + c)``, and making a context token's K and V costs ``2 c (nope + dv)``
+# a head once a layer a step: by count a row gains when it is fed more than
+# ``c (nope + dv) / (W + c - d - dv)`` tokens a step (358 at GLM-4.7-Flash's
+# widths). The chip's own crossing point lies above that, at 670-710 tokens
+# (a v5e, one layer call at 12,288 and at 30,000 tokens of history: absorbed
+# 4.4 and 9.6 us a fed token; expanded 1.95 and 4.4 us a token after 1.74 and
+# 3.49 ms of expansion: PERF.md section 6, PR 40), because the expansion's
+# matmuls run at half the MXU's peak and the absorbed call at 72%; the
+# constant is the next whole number of 128-token KV blocks above it.
+_EXPAND_MIN_TOKENS = 768
+# long rows a program holds: a step's token budget is the tail of one prompt
+# and the head of the next, and whatever is past that stays absorbed
+_EXPAND_ROWS = 2
+# KV blocks one pass of the expansion's loop makes K and V of
+_EXPAND_SEGMENT_BLOCKS = 16
+# what the workspace of a program may take: an eighth of a v5e's memory. Two
+# rows of 257 blocks of 128 tokens at 20 heads of 256 + 256 in bf16 are
+# 1.35e9 bytes; a wider table is cut to what fits and a row whose context is
+# past the cut stays absorbed
+_EXPAND_WORKSPACE_BYTES = 2 << 30
+
+
+def _workspace_column_bytes(cfg: TransformerConfig, block_size: int, itemsize: int) -> int:
+    """Bytes of one KV block of every head's K and V in the workspace."""
+    return 2 * cfg.num_heads * block_size * cfg.head_dim * itemsize
+
+
+def expanded_plan(cfg: TransformerConfig, T: int, max_blocks: int, block_size: int, itemsize: int = 2):
+    """``(rows, cols)`` of the workspace a program of ``T`` tokens over tables
+    of ``max_blocks`` columns keeps for latent attention in the expanded form:
+    how many of a step's rows it can hold and how many KV blocks of context
+    each; ``(0, 0)`` for a program that attends absorbed alone (no latent
+    cache, no room for one row of ``_EXPAND_MIN_TOKENS`` tokens in ``T``, or
+    values wider than a head's scores, which the per-head kernel does not
+    take). From static shapes alone."""
+    if not cfg.latent_attention or cfg.v_head_dim > cfg.head_dim:
+        return 0, 0
+    rows = min(_EXPAND_ROWS, T // _EXPAND_MIN_TOKENS)
+    if rows == 0:
+        return 0, 0
+    cols = min(max_blocks, _EXPAND_WORKSPACE_BYTES // (rows * _workspace_column_bytes(cfg, block_size, itemsize)))
+    return (rows, cols) if cols > 0 else (0, 0)
+
+
+def expanded_workspace_bytes(cfg: TransformerConfig, T: int, max_blocks: int, block_size: int, itemsize: int = 2) -> int:
+    """Bytes of per-head K and V that the program of :func:`expanded_plan`
+    keeps beside the pool (one layer's at a time)."""
+    rows, cols = expanded_plan(cfg, T, max_blocks, block_size, itemsize)
+    return rows * cols * _workspace_column_bytes(cfg, block_size, itemsize)
+
+
+def expanded_slots(new, total, rows: int, cols: int, block_size: int, xp=jnp):
+    """The workspace slot of every table row of a step, or -1 for a row that
+    is attended absorbed: the first ``rows`` rows, in table order, that are fed
+    at least ``_EXPAND_MIN_TOKENS`` tokens (``new``) and whose context after
+    the step (``total``) fits ``cols`` KV blocks. ``xp`` is ``jnp`` inside the
+    program and ``numpy`` for the engine's count of the same step."""
+    long_row = (new >= _EXPAND_MIN_TOKENS) & (total <= cols * block_size)
+    rank = xp.cumsum(long_row.astype(xp.int32)) - 1
+    return xp.where(long_row & (rank < rows), rank, -1).astype(xp.int32)
+
+
+def expanded_batch(slot_of_tok, pos, rows: int, xp=jnp):
+    """``(seq_idx, pos)`` of a step's tokens as the expanded call attends
+    them: a token of a workspace slot at that slot's table row and its own
+    position, every other token at position -1, where it sees no key and its
+    tile costs no grid step, on one of the ``rows + 1`` further table rows
+    that stand for no context (its run's number among the runs of such
+    tokens: between, before and after ``rows`` long rows there are at most
+    ``rows + 1``, so no two runs share a row and the tiled grid's bound of a
+    ragged tile a table row holds)."""
+    live = slot_of_tok >= 0
+    run = xp.cumsum(xp.concatenate([live[:1], live[1:] & ~live[:-1]]).astype(xp.int32))   # long rows begun so far
+    return xp.where(live, slot_of_tok, rows + run).astype(xp.int32), xp.where(live, pos, -1).astype(xp.int32)
+
+
+def _expand_latents(cfg: TransformerConfig, block_size: int, entries, row_blocks, n_blocks, w_k, w_v, ws, slot: int):
+    """Per-head K and V of one row's cached context, into workspace slot
+    ``slot``. ``entries``: the pool as blocks ``[blocks, block_size, W]``;
+    ``row_blocks`` ``[cols]``: the row's table columns (this layer's block
+    ids); ``n_blocks`` (traced): the blocks the row's context holds; ``w_k``
+    ``[nq, c, nope]`` / ``w_v`` ``[nq, c, dv]``: ``W_kvb`` by head; ``ws``: the
+    workspace ``(K, V)``, each ``[nq, rows * cols, block_size, d]``: pools by
+    head (``paged_attention``), slot ``i``'s table column ``j`` the block ``i *
+    cols + j`` of every head.
+    ``K_h = [ckv W_K_h | kr]``, ``V_h = ckv W_V_h`` (zeros up to ``d``),
+    float32 sums rounded to the pool's type once. Segments of
+    ``_EXPAND_SEGMENT_BLOCKS`` whole blocks under a loop bounded by the row's
+    LENGTH, not the table's width; the last segment of a table that is not a
+    whole number of segments starts early and makes a few blocks twice."""
+    c, rope, d = cfg.kv_lora_rank, cfg.qk_rope_head_dim, cfg.head_dim
+    nq, cols = w_k.shape[0], row_blocks.shape[0]
+    seg = min(_EXPAND_SEGMENT_BLOCKS, cols)
+    n = seg * block_size
+    dt = ws[0].dtype
+    # a matmul a head, and the workspace head-outermost, so that what a pass writes is the product as it
+    # comes, ``[tokens, d]``, to one place: any other order of the workspace the compiler re-lays to this one
+    # for the loop and copies all of it both ways around it (compiled for a described v5e, PR 40). The key's shared
+    # rope part rides the same matmul through an identity under a head's last ``rope`` columns (exact: one
+    # bf16 times one, summed with zeros in float32); joined on afterwards it is a cut at no whole lane tile
+    through = jnp.pad(jnp.eye(rope, dtype=w_k.dtype), ((0, 0), (d - rope, 0)))
+    w_k = jnp.concatenate([jnp.pad(w_k, ((0, 0), (0, 0), (0, rope))), jnp.broadcast_to(through, (nq, rope, d))], axis=1)
+    w_v = jnp.pad(w_v, ((0, 0), (0, 0), (0, d - w_v.shape[-1])))
+
+    def segment(j, ws):
+        start = jnp.minimum(j * seg, cols - seg)
+        e = entries[jax.lax.dynamic_slice(row_blocks, (start, ), (seg, ))].reshape(n, -1)
+        for h in range(nq):
+            made = (jnp.dot(e[:, :c + rope], w_k[h], preferred_element_type=jnp.float32),
+                    jnp.dot(e[:, :c], w_v[h], preferred_element_type=jnp.float32))
+            ws = tuple(jax.lax.dynamic_update_slice(part, m.astype(dt).reshape(1, seg, block_size, d),
+                                                    (h, slot * cols + start, 0, 0)) for part, m in zip(ws, made))
+        return ws
+
+    return jax.lax.fori_loop(0, -(-n_blocks // seg), segment, ws)
 
 
 def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, Any], token_ids, seq_idx, pos, valid,
@@ -53,14 +172,26 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
     return has the one pool): ``k_pool`` ``[L, NB*bs, 1, W]`` holds a token's
     entry ``[rmsnorm(ckv) | rope(kr) | 0]`` (``kv_lora_rank`` lanes, then
     ``qk_rope_head_dim``, zeros up to ``W``: whole 128-lane tiles), scattered
-    at the token's slot exactly as K is. Attention runs in the ABSORBED form:
-    with ``W_kvb = [W_K_h | W_V_h]`` a head's query is ``[q_nope_h W_K_h^T |
+    at the token's slot exactly as K is. The FORM of the attention follows how
+    many tokens a row is fed this step, so a step is two attention calls whose
+    outputs are merged by row. ABSORBED (a decode row, a short chunk): with
+    ``W_kvb = [W_K_h | W_V_h]`` a head's query is ``[q_nope_h W_K_h^T |
     rope(q_rope_h) | 0]`` against the entries, scaled by ``1 /
     sqrt(qk_nope_head_dim + qk_rope_head_dim)``; the attention call returns
-    ``sum p ckv`` a head and ``W_V_h`` is applied to it. Both are batched
-    matmuls over heads around the attention call on the stored
-    ``wkv_b_k`` / ``wkv_b_v``; no per-head K or V exists anywhere, for a
-    chunk's own tokens and for its history alike.
+    ``sum p ckv`` a head and ``W_V_h`` is applied to it, both batched matmuls
+    over heads on the stored ``wkv_b_k`` / ``wkv_b_v``, and no per-head K or V
+    exists. EXPANDED (up to ``_EXPAND_ROWS`` rows fed at least
+    ``_EXPAND_MIN_TOKENS`` tokens: :func:`expanded_plan`,
+    :func:`expanded_slots`): per-head ``K_h = [ckv W_K_h | kr]`` and ``V_h =
+    ckv W_V_h`` of the row's whole context, the chunk's own tokens included,
+    are made from the pool after the scatter into a workspace
+    (:func:`_expand_latents`) that the same attention module reads as pools
+    by head (:func:`expanded_batch`), at ``2 (d + dv)`` operations a pair a head
+    instead of ``2 (W + c)``. Each call's work list drops the other's rows (a
+    token at position -1 sees no key and its tile costs no grid step), so
+    every pair is attended once, in one form; which rows are long is data, how
+    many a program holds is static, and a program under ``_EXPAND_MIN_TOKENS``
+    tokens is the absorbed call alone.
 
     ``pos_ids``/``attn_mask``/``ctx_pos_ids``: token-tree verification
     (``engine_v2.speculate_decode`` with branched drafts). ``pos`` stays the
@@ -176,10 +307,32 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
         raise NotImplementedError(f"blocks of {cfg.diffusion_block_size} under a block-causal mask: no token-tree "
                                   f"mask beside it, and a KV block ({block_size}) holds whole blocks")
 
-    def layer(x, blk, l, k_flat, v_flat, ks_flat, vs_flat, stats=None, kind=None, kv_alone=False):
+    # latent attention's long rows (see the docstring): which they are, their
+    # tokens as the expanded call takes them, and the workspace, once a program
+    x_rows, x_cols = expanded_plan(cfg, T, block_tables.shape[1], block_size, k_pool.dtype.itemsize)
+    if x_rows:
+        S = block_tables.shape[0]
+        fed = jnp.zeros(S, jnp.int32).at[seq_idx].add(valid.astype(jnp.int32))
+        length = jnp.zeros(S, jnp.int32).at[seq_idx].max(jnp.where(valid, pos + 1, 0))
+        slot_of_row = expanded_slots(fed, length, x_rows, x_cols, block_size)
+        slot_of_tok = jnp.where(valid, slot_of_row[seq_idx], -1)
+        x_seq, x_pos = expanded_batch(slot_of_tok, vis_pos, x_rows)
+        # a slot's table row and the KV blocks its context holds (none: no row took the slot)
+        x_row = [jnp.argmax(slot_of_row == i) for i in range(x_rows)]
+        x_blocks = [jnp.where(jnp.any(slot_of_row == i), -(-length[r] // block_size), 0) for i, r in enumerate(x_row)]
+        # the workspace's own table, a constant: column ``j`` of slot ``i`` lies where it was made (and the
+        # rows of the tokens of no slot name block 0: nothing is read through them)
+        x_tables = jnp.pad(jnp.arange(x_rows * x_cols, dtype=jnp.int32).reshape(x_rows, x_cols), ((0, x_rows + 1), (0, 0)))
+        absorbed_pos = jnp.where(slot_of_tok >= 0, -1, vis_pos)   # the absorbed call drops the long rows' tiles
+        workspace = (jnp.zeros((nq, x_rows * x_cols, block_size, d), k_pool.dtype), ) * 2
+    else:
+        absorbed_pos, workspace = vis_pos, None
+
+    def layer(x, blk, l, k_flat, v_flat, ks_flat, vs_flat, stats=None, ws=None, kind=None, kv_alone=False):
         """``kind``: the layer's attention kind, static (None in a model of
         one kind, where ``l`` may be traced); ``stats``: the running MoE
-        counts; ``kv_alone``: write this layer's K/V and stop."""
+        counts; ``ws``: latent attention's workspace of per-head K and V;
+        ``kv_alone``: write this layer's K/V and stop."""
         attend = modules["attention_full"] if kind == "full_attention" else attention
         h1 = pre_norm(x, blk["ln1_scale"], blk.get("ln1_bias"))
         bias = (lambda n: blk[n]) if cfg.use_bias else (lambda n: None)
@@ -198,17 +351,29 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
             entry = jnp.pad(entry, ((0, 0), (0, 0), (0, W - entry.shape[-1])))
             k_flat = k_flat.at[slot_l].set(entry.astype(k_flat.dtype), mode="drop")
             if kv_alone:
-                return x, k_flat, v_flat, ks_flat, vs_flat, stats
+                return x, k_flat, v_flat, ks_flat, vs_flat, stats, ws
+            q_rope = apply_rope(qh[None, ..., nope:], sin, cos)[0]
             # W_K_h^T folded into the query: [q_nope_h W_K_h^T | rope(q_rope_h) | 0] against the entries
             q_lat = jnp.einsum("thn,hcn->thc", qh[..., :nope], blk["wkv_b_k"],
                                preferred_element_type=jnp.float32).astype(qh.dtype)
-            q_abs = jnp.concatenate([q_lat, apply_rope(qh[None, ..., nope:], sin, cos)[0]], axis=-1)
+            q_abs = jnp.concatenate([q_lat, q_rope], axis=-1)
             q_abs = jnp.pad(q_abs, ((0, 0), (0, 0), (0, W - q_abs.shape[-1])))
-            lat = attend(q_abs, k_flat, None, tables_l, seq_idx, vis_pos, value_dim=c,
+            lat = attend(q_abs, k_flat, None, tables_l, seq_idx, absorbed_pos, value_dim=c,
                          softmax_scale=1.0 / math.sqrt(d))              # [T, nq, c]: sum p ckv a head
             # ... and W_V_h into the output
             ctx = jnp.einsum("thc,hcv->thv", lat, blk["wkv_b_v"],
-                             preferred_element_type=jnp.float32).astype(lat.dtype).reshape(T, nq * dv)
+                             preferred_element_type=jnp.float32).astype(lat.dtype)
+            if x_rows:
+                # the long rows: K and V by head from the pool as it stands after the scatter, then the
+                # per-head call over them, [q_nope_h | rope(q_rope_h)] a head
+                entries = k_flat[:flat_len - flat_len % block_size].reshape(-1, block_size, W)
+                for i in range(x_rows):
+                    ws = _expand_latents(cfg, block_size, entries, tables_l[x_row[i], :x_cols], x_blocks[i],
+                                         blk["wkv_b_k"], blk["wkv_b_v"], ws, i)
+                by_head = attend(jnp.concatenate([qh[..., :nope], q_rope], axis=-1), ws[0], ws[1], x_tables, x_seq, x_pos,
+                                 softmax_scale=1.0 / math.sqrt(d))
+                ctx = jnp.where((slot_of_tok >= 0)[:, None, None], by_head[..., :dv], ctx)
+            ctx = ctx.reshape(T, nq * dv)
         else:
             qkvb = (lambda n: blk[n]) if cfg.qkv_bias_enabled else (lambda n: None)
             q = linear(h1, blk["wq"], qkvb("bq")).reshape(T, nq, d)
@@ -236,7 +401,7 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
             k_flat = k_flat.at[slot_l].set(k.astype(k_flat.dtype), mode="drop")
             v_flat = v_flat.at[slot_l].set(v.astype(v_flat.dtype), mode="drop")
             if kv_alone:
-                return x, k_flat, v_flat, ks_flat, vs_flat, stats
+                return x, k_flat, v_flat, ks_flat, vs_flat, stats, ws
 
             # scales/tree kwargs only passed when active, so full-precision
             # causal third-party attention implementations keep the original
@@ -277,10 +442,10 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
 
         if cfg.parallel_residual:  # GPT-J / NeoX / Falcon
             h2 = h1 if cfg.shared_ln else pre_norm(x, blk["ln2_scale"], blk.get("ln2_bias"))
-            return x + attn_out + mlp(h2), k_flat, v_flat, ks_flat, vs_flat, stats
+            return x + attn_out + mlp(h2), k_flat, v_flat, ks_flat, vs_flat, stats, ws
         x = x + post(attn_out, "ln1_post_scale")
         h2 = pre_norm(x, blk["ln2_scale"], blk.get("ln2_bias"))
-        return x + post(mlp(h2), "ln2_post_scale"), k_flat, v_flat, ks_flat, vs_flat, stats
+        return x + post(mlp(h2), "ln2_post_scale"), k_flat, v_flat, ks_flat, vs_flat, stats, ws
 
     # (a latent pool: one entry a token, [flat_len, 1, W], and no second pool)
     k_flat = k_pool.reshape((flat_len, ) + k_pool.shape[2:])
@@ -310,8 +475,8 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
         for l in range(L):
             blk_l = {name: jax.tree_util.tree_map(lambda a: a[i], stacked)
                      for name, stacked in sorted(per_layer.items()) if (i := index_of(name, l)) is not None}
-            x, k_flat, v_flat, ks_flat, vs_flat, stats = layer(
-                x, blk_l, l, k_flat, v_flat, ks_flat, vs_flat, stats, cfg.layer_kind(l),
+            x, k_flat, v_flat, ks_flat, vs_flat, stats, workspace = layer(
+                x, blk_l, l, k_flat, v_flat, ks_flat, vs_flat, stats, workspace, cfg.layer_kind(l),
                 kv_alone=kv_only and l == L - 1)
     else:
         if kv_only:
@@ -325,8 +490,8 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
             blk, l = inp
             return layer(carry[0], blk, l, *carry[1:]), None
 
-        (x, k_flat, v_flat, ks_flat, vs_flat, stats), _ = jax.lax.scan(
-            scan_body, (x, k_flat, v_flat, ks_flat, vs_flat, stats),
+        (x, k_flat, v_flat, ks_flat, vs_flat, stats, workspace), _ = jax.lax.scan(
+            scan_body, (x, k_flat, v_flat, ks_flat, vs_flat, stats, workspace),
             (per_layer, jnp.arange(L, dtype=jnp.int32)))
     pools = (k_flat.reshape(k_pool.shape), ) if latent else (k_flat.reshape(k_pool.shape), v_flat.reshape(v_pool.shape))
 

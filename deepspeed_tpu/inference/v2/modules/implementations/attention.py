@@ -81,13 +81,15 @@ class PallasPagedAttention(DSSelfAttentionBase):
             # device kernel of that name runs, and the span says so. Its
             # ``kv_steps`` count every table column (``decode_kv_counts``
             # knows only the device kernel's work list): tests/perfbench
-            # holds the CPU twins to that (PERF.md section 7, PR 29)
+            # holds the CPU twins to that (PERF.md section 7, PR 29). Pools by
+            # head (four dimensions) are the tiled kernel's alone: its body, at a tile of 8
+            q_tile = 8 if k_flat.ndim == 4 else 1
             _note_choice(q.shape[0], tables_l.shape[0], tables_l.shape[1],
-                         {"kernel": "paged_attn_interpreted", "q_tile": 1, "blocks_per_step": 1,
+                         {"kernel": "paged_attn_interpreted", "q_tile": q_tile, "blocks_per_step": 1,
                           "rule": "interpret"})
             return _pallas_paged(q, k_flat, v_flat, tables_l, seq_idx.astype(jnp.int32),
                                  pos.astype(jnp.int32), block_size=cfg.block_size,
-                                 interpret=True, window=cfg.sliding_window,
+                                 interpret=True, window=cfg.sliding_window, q_tile=q_tile,
                                  alibi=tuple(np.asarray(al).tolist()) if al is not None else None,
                                  k_scale=k_scale, v_scale=v_scale, **latent)
         # paged_attention itself falls back (loudly) off-TPU / tiny heads

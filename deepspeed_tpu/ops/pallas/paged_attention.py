@@ -71,10 +71,19 @@ def _note_choice(T, S, max_blocks, choice):
 # a KV block is fetched and read out by kv head once per 128 query tokens (256
 # measured 2-5% faster at twice the VMEM: PERF.md, PR 25).
 _LONG_ROW_TILE = 128
+# ... unless the pools lie BY HEAD with ONE query head a kv head: the MXU's left
+# operand is then the tile's own rows and nothing else (128 rows where a group
+# of 4 gives it 512, under weights it loads anew for every head), so a long-row
+# step of such a call takes the largest tile whose working set
+# (:func:`_q_tiled_vmem_bytes`, and half again for Mosaic's own temporaries) the
+# kernel's scoped limit admits (PERF.md section 6, PR 40: 20 heads of 256 over
+# 14k and 32k tokens; over token-major pools, whose heads go through a scratch
+# by kv head, no tile beyond 128 has run on the chip, so they keep it)
+_Q_TILED_VMEM_LIMIT = 100 << 20
 
 
 def choose_kernel(T: int, S: int, max_blocks: int, nq: int, block_rows: int, d: int, itemsize: int,
-                  seq_idx=None, pos=None, parts: int = 2) -> dict:
+                  seq_idx=None, pos=None, parts: int = 2, kv_by_head: int = 0) -> dict:
     """Which kernel serves a batch of ``T`` tokens over ``S`` table rows of
     ``max_blocks`` columns, and with which tile: ``{"kernel", "q_tile",
     "blocks_per_step", "rule"}``. It follows from the program's static shapes
@@ -82,6 +91,7 @@ def choose_kernel(T: int, S: int, max_blocks: int, nq: int, block_rows: int, d: 
     changes it. ``block_rows`` is a KV block's rows, ``block_size * nkv``,
     ``itemsize`` the pool's and ``parts`` the pools a grid step fetches a block
     of (2: K and V; 1: a latent entry, read once for score and value);
+    ``kv_by_head`` the kv heads of pools BY HEAD (0: token-major pools);
     ``seq_idx``/``pos`` only let a caller with CONCRETE arrays have the tiled
     grid's layout contract checked. In order:
 
@@ -93,7 +103,12 @@ def choose_kernel(T: int, S: int, max_blocks: int, nq: int, block_rows: int, d: 
       one-token decode rows, so the tile is the power of two at or under
       ``2 T / S`` (and ``T``), between 8 and 128. A long-prompt step (2,048
       tokens over at most 8 rows) takes the large tile
-      (``heuristic:long_rows``). A step with many rows for its tokens (a
+      (``heuristic:long_rows``), and over pools by head with one query head a
+      kv head (``kv_by_head == nq``) the largest power of two up to ``2 T /
+      S`` whose working set ``_Q_TILED_VMEM_LIMIT`` admits
+      (``heuristic:long_rows_one_head``: the expanded form of latent attention
+      over its workspace of per-head keys and values, 512 at 20 heads of
+      256). A step with many rows for its tokens (a
       512-token SplitFuse ``put`` over 32 rows, a linear speculative verify of
       k+1 tokens a row) takes a smaller one (``heuristic:short_rows``),
       because a row beyond the chunks' own is a tile of its own and each
@@ -118,9 +133,15 @@ def choose_kernel(T: int, S: int, max_blocks: int, nq: int, block_rows: int, d: 
     per_row2 = min(T, 2 * T // max(S, 1))
     if T >= 64 and per_row2 >= 4:
         if _contiguity_ok(seq_idx, S, pos):
-            qt = max(8, min(_LONG_ROW_TILE, 1 << (per_row2.bit_length() - 1)))
-            return {"kernel": "paged_attn_q_tiled", "q_tile": qt, "blocks_per_step": 1,
-                    "rule": "heuristic:long_rows" if qt == _LONG_ROW_TILE else "heuristic:short_rows"}
+            fits = 1 << (per_row2.bit_length() - 1)
+            qt = max(8, min(_LONG_ROW_TILE, fits))
+            rule = "heuristic:long_rows" if qt == _LONG_ROW_TILE else "heuristic:short_rows"
+            if kv_by_head == nq and qt == _LONG_ROW_TILE:
+                while 2 * qt <= fits and _q_tiled_vmem_bytes(nq * 2 * qt, 2 * qt, d, block_rows // nq, nq, itemsize,
+                                                             itemsize) * 3 // 2 <= _Q_TILED_VMEM_LIMIT:
+                    qt *= 2
+                rule = "heuristic:long_rows_one_head"
+            return {"kernel": "paged_attn_q_tiled", "q_tile": qt, "blocks_per_step": 1, "rule": rule}
         rule = "contiguity_demoted"
     elif max_blocks < 8:
         rule = "heuristic:short_table"
@@ -150,19 +171,30 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: i
     and the value is its first ``value_dim`` lanes: the block is fetched ONCE a
     grid step for both. ``softmax_scale`` replaces ``1 / sqrt(d)`` (a latent
     entry's width is not the head's).
+    Pools of FOUR dimensions are pools BY HEAD, ``[nkv, blocks, block_size,
+    d]``: a head's blocks together, each the ``[block_size, d]`` of its tokens
+    (the workspace latent attention's expanded form makes per-head K and V in,
+    ``flat_model.ragged_forward``: laid out for the tiled kernel, which fetches
+    a table column's block of every head in a grid step and reads each where
+    it lies). No int8 scales there, and no decode kernel: the caller's rows
+    are long.
     The kernel and its tile are :func:`choose_kernel`'s, from the shapes.
     Returns [T, nq, d] (``[T, nq, value_dim]`` over a latent pool)."""
     T, nq, d = q.shape
-    nkv = k_pool.shape[1]
+    nkv = k_pool.shape[0 if k_pool.ndim == 4 else 1]
     S, max_blocks = block_tables.shape
+    if k_pool.ndim == 4 and (v_pool is None or k_scale is not None or k_pool.shape[2] != block_size):
+        raise ValueError(f"pools by head are K and V [nkv, blocks, {block_size}, d] without int8 scales")
     if window is not None:
         window = int(window)
     latent = v_pool is None
     if latent and (nkv != 1 or k_scale is not None or alibi is not None or not value_dim):
         raise ValueError("a latent pool is [pool_len, 1, d] with value_dim lanes of value, no int8 scales, no alibi")
     choice = choose_kernel(T, S, max_blocks, nq, block_size * nkv, d, k_pool.dtype.itemsize, seq_idx, pos,
-                           parts=1 if latent else 2)
+                           parts=1 if latent else 2, kv_by_head=nkv if k_pool.ndim == 4 else 0)
     _note_choice(T, S, max_blocks, choice)
+    if k_pool.ndim == 4 and choice["kernel"] == "paged_attn_kv_split":
+        raise NotImplementedError(f"pools by head under the decode kernel ({T} tokens over {S} rows: {choice['rule']})")
     if choice["kernel"] == "paged_attention_reference":
         if choice["rule"] == "unsupported_shape":
             # off-TPU the oracle is the design; ON TPU a shape miss silently
@@ -205,8 +237,11 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables, seq_idx, pos, blo
     [S, C] logical position of every context slot (tree nodes sit at flat
     slots but depth-based logical positions — alibi distances must use the
     logical ones). ``v_pool`` None, ``value_dim``, ``softmax_scale``: a latent
-    pool, as in ``paged_attention``."""
+    pool, and pools of four dimensions pools by head, as in
+    ``paged_attention``."""
     T, nq, d = q.shape
+    if k_pool.ndim == 4:  # [nkv, blocks, block, d] as the token-major pool the gather reads
+        k_pool, v_pool = (jnp.moveaxis(pool, 0, 2).reshape(-1, pool.shape[0], d) for pool in (k_pool, v_pool))
     nkv = k_pool.shape[1]
     g = nq // nkv
     S, max_blocks = block_tables.shape
@@ -273,8 +308,14 @@ def _pallas_paged(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: int
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    nkv = k_pool.shape[1]
     d = q.shape[2]
+    if k_pool.ndim == 4:  # pools by head, which only the tiled kernel reads
+        if q_tile <= 1 or k_scale is not None:
+            raise NotImplementedError("pools by head [nkv, blocks, block, d] are the tiled kernel's, without int8 scales")
+        return _paged_q_tiled(pl, pltpu, q, k_pool, v_pool, block_tables, seq_idx, pos, None, None,
+                              block_size=block_size, q_tile=q_tile, window=window, alibi=alibi, interpret=interpret,
+                              softmax_scale=softmax_scale)
+    nkv = k_pool.shape[1]
     # view the pool as whole blocks; drop any trailing scratch remainder
     n_pool_blocks = k_pool.shape[0] // block_size
     n_live = n_pool_blocks * block_size
@@ -397,6 +438,16 @@ def _lanes(x, n: int):
     return x if x.shape[1] == n else jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
 
+def _lane_copies(x, n: int):
+    """:func:`_lanes` for the tiled kernel's accumulator, where ``n`` is a
+    value's width: copies of the column side by side when ``n`` is several
+    whole ones (registers used again: values of 256 or 512 lanes, where
+    spreading the first lane over them was a third of a live step's time over
+    a latent pool: PERF.md section 6, PR 40)."""
+    reps, rest = divmod(n, x.shape[1])
+    return _lanes(x, n) if rest or reps == 1 else jnp.concatenate([x] * reps, axis=1)
+
+
 # what the scalar-prefetched work list of ``paged_attn_q_tiled`` may take of a
 # core's scalar memory. A v5e has 1 MiB and its compiler refuses the program
 # that passes it with whatever else it keeps there (compiled for a described
@@ -432,8 +483,10 @@ def _tile_runs(seq_idx, pos, q_tile: int, xp=jnp):
 
 def _tile_columns(tile_min, tile_max, tile_cnt, block_size: int, max_blocks: int, window, q_tile: int, xp=jnp):
     """``(lo, n, cols)``: the first table column a tile's rows can see, how
-    many LIVE columns it has from there (0 for an empty tile), and the most
-    the shapes allow a tile. The last live column is that of the tile's
+    many LIVE columns it has from there (0 for an empty tile, and for a tile
+    whose tokens all sit at a NEGATIVE position: they see no key, which is how
+    a caller takes a row out of a call without reshaping the batch), and the
+    most the shapes allow a tile. The last live column is that of the tile's
     largest position; under a sliding window the first is that of its
     smallest position's window edge, and since a run's positions ascend by
     at most one (the layout contract) a tile spans at most ``(window +
@@ -446,7 +499,7 @@ def _tile_columns(tile_min, tile_max, tile_cnt, block_size: int, max_blocks: int
     else:
         cols = min(max_blocks, (window + q_tile - 2) // block_size + 2)
         lo = xp.clip(xp.maximum(tile_min - (window - 1), 0) // block_size, hi - cols + 1, hi)
-    return lo, xp.where(tile_cnt > 0, hi - lo + 1, 0), cols
+    return lo, xp.where((tile_cnt > 0) & (tile_max >= 0), hi - lo + 1, 0), cols
 
 
 def _tiled_work_list(block_tables, seq_idx, pos, block_size: int, window, q_tile: int):
@@ -570,9 +623,15 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
     ``value_dim`` wide. Nothing is copied by head; the head loop walks the
     tile's rows in chunks of :func:`_latent_row_chunk` instead (each row has
     its own position, so a chunk need not hold whole tokens).
+
+    Pools BY HEAD (``paged_attention``), ``k3``/``v3`` ``[nkv, blocks,
+    block_size, d]``: a grid step fetches the table column's block of every
+    head, ``[nkv, 1, block_size, d]``, and the head loop reads a head's where
+    it lies: there is no scratch by kv head and nothing is copied.
     """
     T, nq, d = q.shape
-    nkv = k3.shape[1] // block_size
+    by_head = k3.ndim == 4
+    nkv = k3.shape[0] if by_head else k3.shape[1] // block_size
     g = nq // nkv
     qt = int(q_tile)
     quant = ks2 is not None
@@ -621,6 +680,8 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
     def kernel(tile_ref, col_ref, seq_ref, cnt_ref, bt_ref, q_ref, k_ref, *rest):
         if latent:
             pos_ref, o_ref, acc_ref, m_ref, l_ref = rest
+        elif by_head:
+            v_ref, pos_ref, o_ref, acc_ref, m_ref, l_ref = rest
         elif quant:
             v_ref, pos_ref, ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref, kh_ref, vh_ref = rest
         else:
@@ -643,7 +704,7 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
             p = jnp.exp(s - _lanes(m_new, block_size))
             alpha = jnp.exp(m_prev - m_new)
             l_ref[r, :] = l_ref[r, :] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc_ref[r, :] = acc_ref[r, :] * _lanes(alpha, dv) + jax.lax.dot(
+            acc_ref[r, :] = acc_ref[r, :] * _lane_copies(alpha, dv) + jax.lax.dot(
                 p.astype(cdt), values, preferred_element_type=jnp.float32)
             m_ref[r, :] = m_new
 
@@ -684,13 +745,17 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
                 rel = (kpos - my_pos).astype(jnp.float32)
             if quant:  # dequant at the VMEM tile — HBM only streamed int8
                 ks_t, vs_t = ks_ref[...].T, vs_ref[...].T   # [bs, nkv]
-            heads = zip(*(_block_heads(pl, pltpu, ref, nkv, block_size) for ref in (k_ref, v_ref)))
-            for n, (kh, vh) in enumerate(heads):  # the block's heads, once, for the head loop to index
-                if quant:
-                    kh = kh.astype(jnp.float32) * ks_t[:, n:n + 1]
-                    vh = vh.astype(jnp.float32) * vs_t[:, n:n + 1]
-                kh_ref[n] = kh.astype(cdt)
-                vh_ref[n] = vh.astype(cdt)
+            if by_head:  # the fetched block is by head already
+                k_of, v_of = (lambda n: k_ref[n, 0].astype(cdt)), (lambda n: v_ref[n, 0].astype(cdt))
+            else:
+                heads = zip(*(_block_heads(pl, pltpu, ref, nkv, block_size) for ref in (k_ref, v_ref)))
+                for n, (kh, vh) in enumerate(heads):  # the block's heads, once, for the head loop to index
+                    if quant:
+                        kh = kh.astype(jnp.float32) * ks_t[:, n:n + 1]
+                        vh = vh.astype(jnp.float32) * vs_t[:, n:n + 1]
+                    kh_ref[n] = kh.astype(cdt)
+                    vh_ref[n] = vh.astype(cdt)
+                k_of, v_of = (lambda n: kh_ref[n]), (lambda n: vh_ref[n])
 
             def head(n):
                 """One kv head's G rows: its working set is all that lives."""
@@ -698,11 +763,11 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
                 if not isinstance(n, int) and G % 8 == 0:
                     r0 = pl.multiple_of(r0, 8)
                 r = pl.ds(r0, rows)
-                s = jax.lax.dot_general(q_ref[0, r, :].astype(cdt), kh_ref[n], nt_dims,
+                s = jax.lax.dot_general(q_ref[0, r, :].astype(cdt), k_of(n), nt_dims,
                                         preferred_element_type=jnp.float32) * scale
                 if alibi is not None:
                     s = s + _slopes_tok_major(alibi[n * g:(n + 1) * g], rows) * rel
-                _update(r, jnp.where(vis, s, -1e30), vh_ref[n])
+                _update(r, jnp.where(vis, s, -1e30), v_of(n))
 
             if alibi is None:
                 # traced once and unrolled by the lowering: the same straight
@@ -723,9 +788,10 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
 
         @pl.when(tile_ref[i + 1] != tile)
         def _finalize():
-            o_ref[0] = (acc_ref[:] / _lanes(jnp.maximum(l_ref[:], 1e-30), dv)).astype(o_ref.dtype)
+            o_ref[0] = (acc_ref[:] / _lane_copies(jnp.maximum(l_ref[:], 1e-30), dv)).astype(o_ref.dtype)
 
-    kv_spec = pl.BlockSpec((1, block_size * nkv, d), kv_map)
+    kv_spec = pl.BlockSpec((nkv, 1, block_size, d), lambda i, *refs: (0, kv_map(i, *refs)[0], 0, 0)) if by_head \
+        else pl.BlockSpec((1, block_size * nkv, d), kv_map)
     in_specs = [pl.BlockSpec((1, R, d), q_map)] + [kv_spec] * (1 if latent else 2) + [
         pl.BlockSpec((1, G, _LANES), q_map)]
     operands = [q_t, k3] + ([] if latent else [v3]) + [pos_rows]
@@ -743,7 +809,7 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
             pltpu.VMEM((R, dv), jnp.float32),
             pltpu.VMEM((R, _LANES), jnp.float32),
             pltpu.VMEM((R, _LANES), jnp.float32),
-        ] + ([] if latent else [
+        ] + ([] if latent or by_head else [
             pltpu.VMEM((nkv, block_size, d), cdt),   # the block's K, V by kv head
             pltpu.VMEM((nkv, block_size, d), cdt),
         ]),
@@ -755,7 +821,7 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
         # half again for Mosaic's own temporaries
         need = _q_tiled_vmem_bytes(R, G, d, block_size, nkv, q.dtype.itemsize, k3.dtype.itemsize)
         kwargs["compiler_params"] = pltpu.CompilerParams(
-            vmem_limit_bytes=max(32 << 20, min(need * 3 // 2, 100 << 20)))
+            vmem_limit_bytes=max(32 << 20, min(need * 3 // 2, _Q_TILED_VMEM_LIMIT)))
     out_t = pl.pallas_call(kernel, grid_spec=grid_spec,
                            out_shape=jax.ShapeDtypeStruct((n_tiles, R, dv), q.dtype),
                            interpret=interpret, name="paged_attn_q_tiled", **kwargs)(

@@ -150,6 +150,134 @@ def test_the_tiled_kernels_body_attends_a_latent_pool(tiny):
         np.testing.assert_allclose(np.asarray(got)[:25], np.asarray(want)[:25], rtol=2e-5, atol=2e-5)
 
 
+# ---------------------------------------------------------------------------
+# the form of the attention follows the tokens a row is fed (PR 40)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def long_rows_of_8(monkeypatch):
+    """The tiny engine's rows count as long from 8 tokens on (the constant is
+    768: no row of a 64-token batch reaches it)."""
+    from deepspeed_tpu.inference.v2.model_implementations import flat_model
+
+    monkeypatch.setattr(flat_model, "_EXPAND_MIN_TOKENS", 8)
+    return flat_model
+
+
+# (tokens cached, tokens fed) a row of ONE put; the expanded form takes the FIRST TWO rows fed 8 or more
+_MIXED = {
+    "a_chunk_beside_decode_rows": [(20, 1), (16, 24), (9, 1)],
+    "two_long_rows": [(5, 16), (30, 1), (16, 24)],
+    "no_long_row": [(20, 1), (12, 7), (9, 1)],
+    "at_the_threshold_and_under_it": [(12, 8), (10, 7), (3, 1)],
+    "a_chunk_with_no_history": [(0, 24), (20, 1), (31, 1)],
+    "a_third_long_row_stays_absorbed": [(4, 12), (16, 16), (7, 9), (20, 1)],
+    "a_long_row_to_the_tables_last_block": [(100, 28), (20, 1)],
+}
+
+
+@pytest.mark.parametrize("attention", ["dense_blocked_attention", "paged_pallas_attention"])
+@pytest.mark.parametrize("case", sorted(_MIXED))
+def test_long_rows_attended_expanded_beside_the_rest_absorbed(tiny, long_rows_of_8, case, attention):
+    """One ``put`` whose rows are a mix: the logits of every row equal the
+    float32 reference's and those of an engine that attends absorbed alone,
+    and the rows the program attended expanded are the first two fed at least
+    the threshold, by the span's count and by the call it traced."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    cfg, params, _ = tiny
+    rows = _MIXED[case]
+    rng = np.random.default_rng(len(case))
+    seqs = [rng.integers(0, cfg.vocab_size, size=seen + new, dtype=np.int32) for seen, new in rows]
+    want = np.stack([_reference(cfg, params, ids, [len(ids) - 1])[0] for ids in seqs])
+
+    def served(engine):
+        for uid, (ids, (seen, _)) in enumerate(zip(seqs, rows)):
+            for c0 in range(0, seen, 64):  # the history, in chunks the engine's batch holds
+                engine.put([uid], [ids[c0:min(c0 + 64, seen)]], sample=None)
+        return np.asarray(engine.put(list(range(len(rows))), [ids[seen:] for ids, (seen, _) in zip(seqs, rows)],
+                                     sample=None), np.float32)
+
+    engine = _engine(cfg, params, attention)
+    got = served(engine)
+    assert _rel(got, want).max() < TOL
+    fed = sum(new for _, new in rows)
+    t_bucket = next(b for b in (8, 16, 32, 64) if b >= fed)
+    long_rows = [r for r in rows if r[1] >= 8][:2]
+    pairs = lambda seen, new: new * seen + new * (new + 1) // 2
+    said = engine._attn_span_args([s for s, _ in rows], [n for _, n in rows], t_bucket)
+    assert said["attn_pairs"] == cfg.num_layers * sum(pairs(*r) for r in rows)
+    assert said["attn_expanded_pairs"] == cfg.num_layers * sum(pairs(*r) for r in long_rows)
+    plan = long_rows_of_8.expanded_plan(cfg, t_bucket, 128 // BLOCK, BLOCK, 4)
+    assert plan == (min(2, t_bucket // 8), 128 // BLOCK)
+    if attention == "paged_pallas_attention":  # the module that records its calls: the second one was traced
+        assert pa.kernel_choice(t_bucket, 2 * plan[0] + 1, plan[1])["kernel"] == "paged_attn_interpreted"
+        assert engine._kernel_of(t_bucket, 4) == "paged_attn_interpreted:1:interpret+paged_attn_interpreted:8:interpret"
+    # absorbed alone: the same rows through an engine whose rows never count as long
+    long_rows_of_8._EXPAND_MIN_TOKENS = 768
+    alone = served(_engine(cfg, params, attention))
+    assert _rel(got, alone).max() < TOL
+
+
+@pytest.mark.parametrize("n_blocks", [0, 1, 2, 3, 4, 5])
+def test_the_expansion_walks_whole_segments_up_to_the_rows_length(long_rows_of_8, n_blocks, monkeypatch):
+    """``_expand_latents`` at segments of 2 blocks under a table of 5 columns
+    (not a whole number of segments): the blocks of ``ceil(n_blocks / 2)``
+    segments hold ``[ckv W_K_h | kr]`` and ``ckv W_V_h`` of the row's entries,
+    the last segment of a full table starting a block early; no block past
+    them is touched, in this slot or the other."""
+    fm = long_rows_of_8
+    monkeypatch.setattr(fm, "_EXPAND_SEGMENT_BLOCKS", 2)
+    cfg = glm_config("tiny", dtype=jnp.float32)
+    c, nope, rope, dv, nq, d = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim, \
+        cfg.num_heads, cfg.head_dim
+    rng = np.random.default_rng(n_blocks)
+    cols, bs, W = 5, 4, 40
+    entries = rng.normal(size=(9, bs, W)).astype(np.float32)
+    entries[..., c + rope:] = 0.0
+    row_blocks = np.asarray([7, 2, 8, 0, 5], np.int32)
+    w_k = rng.normal(size=(nq, c, nope)).astype(np.float32)
+    w_v = rng.normal(size=(nq, c, dv)).astype(np.float32)
+    ws = (jnp.full((nq, 2 * cols, bs, d), 7.0), jnp.full((nq, 2 * cols, bs, d), 7.0))
+    k, v = (np.asarray(a).reshape(nq, 2, cols, bs, d).swapaxes(0, 1) for a in jax.jit(
+        lambda n: fm._expand_latents(cfg, bs, jnp.asarray(entries), jnp.asarray(row_blocks), n, jnp.asarray(w_k),
+                                     jnp.asarray(w_v), ws, 1))(n_blocks))
+    made = {0: [], 1: [0, 1], 2: [0, 1], 3: [0, 1, 2, 3], 4: [0, 1, 2, 3], 5: [0, 1, 2, 3, 4]}[n_blocks]
+    e = entries[row_blocks]                                                    # [cols, bs, W]
+    want_k = np.concatenate([np.einsum("jtc,hcn->hjtn", e[..., :c], w_k),
+                             np.broadcast_to(e[None, ..., c:c + rope], (nq, cols, bs, rope))], axis=-1)
+    want_v = np.pad(np.einsum("jtc,hcv->hjtv", e[..., :c], w_v), ((0, 0), ) * 3 + ((0, d - dv), ))
+    np.testing.assert_allclose(k[1][:, made], want_k[:, made], rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(v[1][:, made], want_v[:, made], rtol=1e-5, atol=1e-5)
+    rest = [j for j in range(cols) if j not in made]
+    assert (k[0] == 7.0).all() and (v[0] == 7.0).all() and (k[1][:, rest] == 7.0).all() and (v[1][:, rest] == 7.0).all()
+
+
+def test_the_workspace_is_planned_from_static_shapes():
+    """``expanded_plan`` at the published widths: two rows of the whole table
+    for the cell's 2,048-token program (1.35e9 bytes of K and V), one for a
+    1,024-token one, none under the threshold, for a model without a latent
+    cache, or where values are wider than the scores' heads; a table so wide
+    that two rows would pass the workspace's bound is cut to what fits, and
+    ``expanded_slots`` leaves a row past the cut, or past the static count,
+    absorbed."""
+    from deepspeed_tpu.inference.v2.model_implementations import flat_model as fm
+
+    cfg = glm_config("4.7-flash", dtype=jnp.bfloat16)
+    assert fm.expanded_plan(cfg, 2048, 257, 128) == (2, 257)
+    assert fm.expanded_workspace_bytes(cfg, 2048, 257, 128) == 2 * 257 * 128 * 20 * 512 * 2 == 1347420160
+    assert fm.expanded_plan(cfg, 1024, 257, 128) == (1, 257) and fm.expanded_plan(cfg, 512, 257, 128) == (0, 0)
+    assert fm.expanded_workspace_bytes(cfg, 512, 257, 128) == 0
+    assert fm.expanded_plan(llama2_config("tiny"), 2048, 65, 128) == (0, 0)
+    assert fm.expanded_workspace_bytes(llama2_config("tiny"), 2048, 65, 128) == 0
+    wide = fm.expanded_plan(cfg, 2048, 1024, 128)                              # a table of 131,072 tokens
+    assert wide == (2, 409) and fm.expanded_workspace_bytes(cfg, 2048, 1024, 128) <= fm._EXPAND_WORKSPACE_BYTES
+    new, total = np.asarray([2040, 1, 900, 768, 767]), np.asarray([60000, 900, 30000, 768, 4000])
+    assert fm.expanded_slots(new, total, 2, 409, 128, xp=np).tolist() == [-1, -1, 0, 1, -1]    # 60,000 > 409 x 128
+    assert fm.expanded_slots(new, total, 1, 1024, 128, xp=np).tolist() == [0, -1, -1, -1, -1]
+    assert np.asarray(fm.expanded_slots(jnp.asarray(new), jnp.asarray(total), 2, 1024, 128)).tolist() == [0, -1, 1, -1, -1]
+
+
 @pytest.mark.parametrize("switch,value", [("rope_key", False), ("kv_norm", False), ("selection_bias", False),
                                           ("route_scale", 1.0), ("score_dim", 12)])
 def test_the_reference_without_one_mechanism_is_far_from_the_program(tiny, served, switch, value):

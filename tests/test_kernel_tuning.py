@@ -547,10 +547,16 @@ def on_tpu(monkeypatch):
 
 # heads and blocks of the two serving configurations: (nq, nkv, d, block, itemsize)
 _HEADS = {"mistral-7b": (32, 8, 128, 128, 2), "mellum2-12b-a2.5b": (32, 4, 128, 128, 2)}
+# ... and of the others that run the selector (PR 40): their grouped heads read the table of the two above
+# (Trinity's 8 kv heads Mistral's blocks a step, SDAR's 4 Mellum's), and GLM's ABSORBED call is a group of 20
+# over the one latent entry a token (``parts`` 1), which is not one query head a kv head either
+_MORE_HEADS = {"trinity-large-preview": (48, 8, 128, 128, 2, "mistral-7b"),
+               "sdar-30b-a3b-chat": (32, 4, 128, 128, 2, "mellum2-12b-a2.5b")}
 
 
 def _choose(T, S, max_blocks, config="mistral-7b", **kw):
-    nq, nkv, d, bs, itemsize = _HEADS[config]
+    """As ``paged_attention`` calls the selector over a token-major pool."""
+    nq, nkv, d, bs, itemsize = (_HEADS.get(config) or _MORE_HEADS[config])[:5]
     return choose_kernel(T, S, max_blocks, nq, bs * nkv, d, itemsize, **kw)
 
 
@@ -705,6 +711,125 @@ def test_choice_for_every_program_shape_of_the_cells(T, S, config, on_tpu):
     per_step = _PARENT_DECODE_BLOCKS_PER_STEP[config] if kernel == "paged_attn_kv_split" else 1
     assert _choose(T, S, -(-8320 // 128), config) == {
         "kernel": kernel, "q_tile": q_tile, "blocks_per_step": per_step, "rule": rule}
+
+
+@pytest.mark.parametrize("config", sorted(_MORE_HEADS))
+@pytest.mark.parametrize("T,S", sorted(_PARENT_CHOICES))
+def test_the_one_head_rule_moves_no_grouped_configuration(T, S, config, on_tpu):
+    """The rule added for pools by head with one query head a kv head (PR 40)
+    moves no token-major pool: Trinity's 48/8 and SDAR's 32/4 choose what the
+    table of the two configurations above says."""
+    kernel, q_tile, rule = _PARENT_CHOICES[(T, S)]
+    per_step = _PARENT_DECODE_BLOCKS_PER_STEP[_MORE_HEADS[config][5]] if kernel == "paged_attn_kv_split" else 1
+    assert _choose(T, S, 65, config) == {"kernel": kernel, "q_tile": q_tile, "blocks_per_step": per_step, "rule": rule}
+
+
+@pytest.mark.parametrize("T,S", [(T, 8) for T in (8, 16, 32, 64, 128, 256, 512, 1024, 2048)])
+def test_the_absorbed_call_over_a_latent_pool_keeps_its_choice(T, S, on_tpu):
+    """``glm-4.7-flash.longdoc``'s own programs (token buckets to 2,048 over 8
+    rows, a table of 257 columns, 20 heads over ONE latent entry of 640 lanes,
+    ``parts`` 1): the absorbed call is a group of 20 over a token-major pool,
+    and its tile stays at 128 at most."""
+    said = choose_kernel(T, S, 257, 20, 128, 640, 2, parts=1)
+    assert said["q_tile"] <= 128 and not said["rule"].endswith("one_head")
+
+
+@pytest.mark.parametrize("T,rows,want", [
+    (2048, 2, (512, "heuristic:long_rows_one_head")),     # the cell's chunk step: two workspace slots, 5 table rows
+    (1024, 1, (512, "heuristic:long_rows_one_head")),
+    (512, 1, (256, "heuristic:long_rows_one_head")),      # never a tile beyond 2 T / S
+    (256, 1, (128, "heuristic:long_rows_one_head")),
+    (128, 1, (64, "heuristic:short_rows")),
+])
+def test_one_query_head_a_kv_head_takes_the_largest_tile_its_vmem_admits(T, rows, want, on_tpu):
+    """The expanded form's call (``flat_model.expanded_batch``): 20 heads of
+    256 over pools by head, one query head a kv head, so the MXU's left operand
+    is the tile's own rows and the tile grows to the largest whose working set
+    and half again is under the limit the kernel asks the compiler for: 512,
+    where ``2 T / S`` allows it."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    nq = 20
+    choice = choose_kernel(T, 2 * rows + 1, 257, nq, 128 * nq, 256, 2, kv_by_head=nq)
+    assert (choice["kernel"], choice["q_tile"], choice["rule"]) == ("paged_attn_q_tiled", ) + want
+    need = lambda qt: pa._q_tiled_vmem_bytes(nq * qt, qt, 256, 128, nq, 2, 2) * 3 // 2
+    assert need(512) <= pa._Q_TILED_VMEM_LIMIT < need(1024)
+    assert choose_kernel(8192, 2, 257, nq, 128 * nq, 256, 2, kv_by_head=nq)["q_tile"] == 512     # the limit, not 2 T / S
+
+
+def test_one_head_rule_at_other_shapes(on_tpu):
+    """Pools by head of 32 heads of 128 take the tile the same limit gives
+    them; a served multi-head model (32/32 on the TOKEN-MAJOR pool, whose heads
+    go through the scratch by kv head: no tile beyond 128 has run there), or
+    pools by head under a group of several query heads, keep 128 as before,
+    and a short-row step keeps its small tile."""
+    assert choose_kernel(2048, 8, 65, 32, 128 * 32, 128, 2, kv_by_head=32) == {
+        "kernel": "paged_attn_q_tiled", "q_tile": 512, "blocks_per_step": 1, "rule": "heuristic:long_rows_one_head"}
+    assert choose_kernel(2048, 8, 65, 32, 128 * 32, 128, 2) == {
+        "kernel": "paged_attn_q_tiled", "q_tile": 128, "blocks_per_step": 1, "rule": "heuristic:long_rows"}
+    assert choose_kernel(2048, 8, 65, 32, 128 * 8, 128, 2, kv_by_head=8)["rule"] == "heuristic:long_rows"
+    assert choose_kernel(512, 32, 65, 32, 128 * 32, 128, 2, kv_by_head=32)["rule"] == "heuristic:short_rows"
+    assert choose_kernel(8, 8, 257, 20, 128 * 20, 256, 2, kv_by_head=20)["kernel"] == "paged_attn_kv_split"
+
+
+@pytest.mark.parametrize("q_tile", [8, 16])
+def test_pools_by_head_read_as_the_token_major_pool_does(q_tile):
+    """Pools of four dimensions ``[nkv, blocks, block, d]`` (latent
+    attention's workspace, PR 40) through the tiled kernel's body and through
+    the gather: the outputs of the same keys and values as a token-major pool,
+    with a softmax scale of the caller's; the decode kernel and int8 scales
+    refuse them by name."""
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_attention
+
+    rng, nq, kp, vp, tables, _ = _paged_setup(seed=9, nkv=4, g=1, n_seqs=3)
+    d, bs = 32, 16
+    by_head = lambda pool: jnp.moveaxis(pool.reshape(-1, bs, 4, d), 2, 0)
+    seq_idx = np.asarray([0] * 13 + [1] * 6 + [2], np.int32)          # as _mixed_batch lays its rows out
+    pos = np.asarray(list(range(20, 33)) + list(range(bs, bs + 6)) + [3 * bs + 5], np.int32)
+    q = jnp.asarray(rng.normal(size=(20, nq, d)), jnp.float32)
+    want = paged_attention_reference(q, kp, vp, tables, jnp.asarray(seq_idx), jnp.asarray(pos), bs, softmax_scale=0.3)
+    gathered = paged_attention_reference(q, by_head(kp), by_head(vp), tables, jnp.asarray(seq_idx), jnp.asarray(pos), bs,
+                                         softmax_scale=0.3)
+    np.testing.assert_array_equal(np.asarray(gathered), np.asarray(want))
+    got = _pallas_paged(q, by_head(kp), by_head(vp), tables, jnp.asarray(seq_idx), jnp.asarray(pos), block_size=bs,
+                        interpret=True, q_tile=q_tile, softmax_scale=0.3)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-5)
+    assert np.asarray(paged_attention(q, by_head(kp), by_head(vp), tables, jnp.asarray(seq_idx), jnp.asarray(pos), bs,
+                                      softmax_scale=0.3)).shape == (20, nq, d)                  # off the TPU: the gather
+    with pytest.raises(NotImplementedError, match="pools by head"):
+        _pallas_paged(q, by_head(kp), by_head(vp), tables, jnp.asarray(seq_idx), jnp.asarray(pos), block_size=bs, interpret=True)
+    with pytest.raises(ValueError, match="pools by head"):
+        paged_attention(q, by_head(kp), None, tables, jnp.asarray(seq_idx), jnp.asarray(pos), bs, value_dim=8)
+
+
+@pytest.mark.parametrize("q_tile", [8, 32])
+def test_a_run_at_a_negative_position_has_no_item_and_leaves_the_others_alone(q_tile):
+    """A caller takes a row out of a tiled call by handing its tokens the
+    position -1 (latent attention's two calls, PR 40): they see no key, so
+    their tiles have no item in the work list (the host's count agrees) and
+    the other rows' outputs are the oracle's."""
+    from deepspeed_tpu.ops.pallas.paged_attention import tiled_kv_counts
+
+    rng, nq, kp, vp, tables, _ = _paged_setup(seed=5, n_seqs=3)
+    d, bs = 32, 16
+    # row 0: one decode token; row 1: 40 tokens taken out; row 2: 20 tokens after 10; the pad run
+    seq_idx = np.asarray([0] + [1] * 40 + [2] * 20 + [0] * 3, np.int32)
+    pos = np.asarray([37] + [-1] * 40 + list(range(10, 30)) + [0] * 3, np.int32)
+    live = pos.copy()
+    live[1:41] = np.arange(5, 45)
+    q = jnp.asarray(rng.normal(size=(64, nq, d)), jnp.float32)
+    work = lambda p: [np.asarray(a) for a in _tiled_work_list(tables, jnp.asarray(seq_idx), jnp.asarray(p), bs, None, q_tile)]
+    *_, tile_seq, tile_cnt, w_tile, w_col, total = work(pos)
+    items = np.bincount(w_tile[:int(total)], minlength=tile_cnt.size)
+    assert (items[(tile_seq == 1) & (tile_cnt > 0)] == 0).all() and (items[(tile_seq != 1) & (tile_cnt > 0)] > 0).all()
+    assert int(total) < int(work(live)[-1])
+    max_blocks = tables.shape[1]
+    assert tiled_kv_counts(q_tile, seq_idx, pos, [(None, 1)], bs, max_blocks, 3)[1] == int(total)
+    ref = paged_attention_reference(q, kp, vp, tables, jnp.asarray(seq_idx), jnp.asarray(live), bs)
+    out = _pallas_paged(q, kp, vp, tables, jnp.asarray(seq_idx), jnp.asarray(pos), block_size=bs, interpret=True,
+                        q_tile=q_tile)
+    keep = np.r_[0, 41:61]
+    np.testing.assert_allclose(np.asarray(out)[keep], np.asarray(ref)[keep], rtol=2e-4, atol=2e-5)
 
 
 def test_no_file_or_environment_can_change_the_choice(tmp_path, monkeypatch, on_tpu):

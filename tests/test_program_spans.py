@@ -244,6 +244,104 @@ def test_spans_of_a_shape_the_tiled_kernel_took_count_its_work_list(window, monk
 PLANTED = {"kernel": "paged_attn_q_tiled", "q_tile": 8, "blocks_per_step": 1, "rule": "planted"}
 
 
+def _latent_engine(monkeypatch, min_tokens=8):
+    """The tiny GLM (latent attention: 4 heads, 5 layers) on the paged
+    kernel's module, its rows long from ``min_tokens`` tokens on (the constant
+    is 768: no row of a 64-token batch reaches it)."""
+    from deepspeed_tpu.inference.v2.model_implementations import flat_model
+    from deepspeed_tpu.models import glm_config
+
+    monkeypatch.setattr(flat_model, "_EXPAND_MIN_TOKENS", min_tokens)
+    cfg = glm_config("tiny", dtype=jnp.float32)
+    return cfg, _small_engine(TransformerLM(cfg), TransformerLM(cfg).init(jax.random.PRNGKey(3)), 4, 128)
+
+
+def test_a_latent_models_spans_say_the_pairs_attended_expanded_and_name_both_calls(monkeypatch):
+    """``serving/prefill`` of a model with latent attention carries
+    ``attn_expanded_pairs`` beside ``attn_pairs``, from the rows' lengths
+    alone (the pairs of the rows fed at least the threshold, times the
+    layers), its ``kernel`` names the absorbed call and the expanded one with
+    their tiles, and its ``tile_kv_live`` is both calls' work lists; a step of
+    decode rows and a decode horizon say 0. (Off the TPU no shape takes the
+    tiled kernel: both calls' choices are planted.)"""
+    cfg, engine = _latent_engine(monkeypatch)
+    rng = np.random.default_rng(4)
+    tokens = lambda n: rng.integers(0, cfg.vocab_size, size=n, dtype=np.int32)
+    one = [np.asarray([3], np.int32)]
+    engine.put([7], [tokens(30)]), engine.put([8], [tokens(9)])
+    engine.put([7, 8, 9], one * 2 + [tokens(20)]), engine.put([7, 8], one * 2), engine.decode([7, 8], one * 2, 3)  # trace first
+    max_blocks, layers = 128 // 16, cfg.num_layers
+    monkeypatch.setitem(pa.KERNEL_CHOICES, (32, 4, max_blocks), PLANTED)
+    monkeypatch.setitem(pa.KERNEL_CHOICES, (32, 2 * 2 + 1, max_blocks), {**PLANTED, "q_tile": 16})
+    engine._kernel_labels.clear()
+    batches = []
+    finalize = engine.batch.finalize
+    monkeypatch.setattr(engine.batch, "finalize", lambda: batches.append(finalize()) or batches[-1])
+    tracer = get_tracer().configure(enabled=True)
+    # 22 tokens in the bucket of 32 x 4: decode rows behind 35 and 14 tokens, one long row of 20
+    engine.put([7, 8, 10], one * 2 + [tokens(20)])
+    engine.put([7, 8], one * 2)
+    engine.decode([7, 8], one * 2, 3)
+    events = {e["name"]: e.get("args", {}) for e in tracer.drain() if e["ph"] == "X"}
+    mixed, step, burst = events["serving/prefill"], events["serving/decode_step"], events["serving/decode"]
+    assert mixed["kernel"] == "paged_attn_q_tiled:8:planted+paged_attn_q_tiled:16:planted"
+    assert mixed["attn_expanded_pairs"] == layers * (20 * 21 // 2) and mixed["attn_pairs"] == layers * (210 + 36 + 15)
+    assert step["attn_expanded_pairs"] == 0 == burst["attn_expanded_pairs"] and step["attn_pairs"] > 0 < burst["attn_pairs"]
+    # the two work lists on the same batch: the absorbed call without the long row, the expanded one over slot 0
+    rb = batches[0]
+    long_tok = (rb.token_seq_idx == 2) & rb.token_valid
+    totals = []
+    for tile, tables, seq_idx, pos in (
+            (8, rb.block_tables, rb.token_seq_idx, np.where(long_tok, -1, rb.token_pos)),
+            (16, np.zeros((5, max_blocks), np.int32), np.where(long_tok, 0, np.r_[[2] * 2, [0] * 20, [3] * 10]),
+             np.where(long_tok, rb.token_pos, -1))):
+        *_, total = pa._tiled_work_list(jnp.asarray(tables), jnp.asarray(seq_idx, jnp.int32), jnp.asarray(pos, jnp.int32),
+                                        16, None, tile)
+        totals.append(int(total))
+    assert totals[1] == 1 + 2                                  # positions 0-19: a tile of 16 over one block, one of 4 over two
+    assert mixed["tile_kv_live"] == layers * sum(totals)
+    assert mixed["tile_kv_bound"] == layers * ((32 // 8 + 4 + 1) + (32 // 16 + 5 + 1)) * max_blocks
+
+
+@pytest.mark.parametrize("latent", [False, True])
+def test_the_pool_is_sized_from_what_the_weights_and_the_workspace_leave(latent, monkeypatch):
+    """``_auto_kv_blocks`` on a device that reports its memory: a model with
+    latent attention whose largest program keeps a workspace of per-head K and
+    V counts it as used BEFORE the pool takes its fraction of the rest; every
+    other model's pool is what it was."""
+    from deepspeed_tpu.inference.v2 import DSStateManagerConfig, InferenceEngineV2, RaggedInferenceEngineConfig
+    from deepspeed_tpu.inference.v2.model_implementations import flat_model
+    from deepspeed_tpu.models import glm_config
+
+    monkeypatch.setattr(flat_model, "_EXPAND_MIN_TOKENS", 8)
+    cfg = glm_config("tiny", dtype=jnp.float32) if latent else TransformerConfig(
+        vocab_size=128, hidden_size=64, num_layers=2, num_heads=4, intermediate_size=128, max_seq_len=128,
+        dtype=jnp.float32, attention_impl="reference")
+    model = TransformerLM(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    param_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(params))
+    limit = param_bytes + (8 << 20)
+
+    class Device:
+        def memory_stats(self):
+            return {"bytes_limit": limit, "bytes_in_use": 0}
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [Device()])
+    sm = DSStateManagerConfig(max_tracked_sequences=1024, max_ragged_batch_size=64, max_ragged_sequence_count=4,
+                              max_context=128)
+    icfg = RaggedInferenceEngineConfig(kv_block_size=16, num_kv_blocks="auto", kv_dtype=jnp.float32, state_manager=sm,
+                                       kv_memory_fraction=0.5)
+    engine = InferenceEngineV2.__new__(InferenceEngineV2)
+    engine.params, engine._kv_entry = params, tuple(cfg.kv_entry)
+    per_block = cfg.num_layers * sum(h * w for h, w in cfg.kv_entry) * 16 * 4
+    workspace = flat_model.expanded_workspace_bytes(cfg, 64, 128 // 16, 16, 4)
+    assert workspace == (2 * 8 * 2 * cfg.num_heads * 16 * cfg.head_dim * 4 if latent else 0)
+    assert engine._auto_kv_blocks(cfg, icfg, 128) == int(((8 << 20) - workspace) * 0.5) // per_block
+    if latent:  # a program under the threshold keeps none
+        sm.max_ragged_batch_size = 4
+        assert engine._auto_kv_blocks(cfg, icfg, 128) == int((8 << 20) * 0.5) // per_block
+
+
 def _small_engine(model, params, rows, context):
     from deepspeed_tpu.inference.v2 import DSStateManagerConfig, InferenceEngineV2, RaggedInferenceEngineConfig
 

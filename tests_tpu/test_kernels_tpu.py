@@ -970,3 +970,55 @@ def test_paged_kernels_over_a_latent_pool_at_the_cells_shapes_on_chip(name, T, S
     got = np.asarray(out[:n], np.float32)
     np.testing.assert_allclose(got, ref, atol=5e-2, rtol=5e-2)
     assert np.linalg.norm(got - ref) / np.linalg.norm(ref) < 5e-3
+
+
+@pytest.mark.parametrize("q_tile", [128, 256, 512, None])
+@pytest.mark.parametrize("before", [12288, 30000])
+def test_the_expanded_call_over_pools_by_head_at_the_cells_shapes_on_chip(before, q_tile):
+    """Latent attention's EXPANDED call at ``glm-4.7-flash.longdoc``'s shapes
+    (PR 40): a 2,048-token chunk behind ``before`` tokens in workspace slot 1
+    of two, seven decode rows' tokens beside it at position -1 (they see
+    nothing and cost no grid step), 20 heads of 256 over pools BY HEAD
+    ``[20, 2 x 257, 128, 256]`` in bf16, ``paged_attn_q_tiled`` at each tile
+    the rule can give and, ``q_tile`` None, through ``paged_attention`` as the
+    model calls it: the rule names the largest, 512, and its working set and
+    half again is under the limit the kernel asks the compiler for. Against a
+    float32 reference; prints the microseconds a call."""
+    from deepspeed_tpu.inference.v2.model_implementations.flat_model import expanded_batch
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    nq, d, bs, cols, rows, T, chunk = 20, 256, 128, 257, 2, 2048, 2041
+    rng = np.random.default_rng(40)
+    n_live = -(-(before + chunk) // bs)
+    k_ws, v_ws = (jnp.asarray(rng.normal(size=(nq, rows * cols, bs, d)), jnp.bfloat16) for _ in range(2))
+    tables = jnp.pad(jnp.arange(rows * cols, dtype=jnp.int32).reshape(rows, cols), ((0, rows + 1), (0, 0)))
+    slot_of_tok = np.asarray([-1] * 7 + [1] * chunk, np.int32)
+    pos = np.concatenate([17000 + 2000 * np.arange(7), np.arange(before, before + chunk)]).astype(np.int32)
+    seq_idx, x_pos = (jnp.asarray(a) for a in expanded_batch(slot_of_tok, pos, rows, xp=np))
+    assert np.asarray(seq_idx).tolist() == [2] * 7 + [1] * chunk and (np.asarray(x_pos)[:7] == -1).all()
+    q = jnp.asarray(rng.normal(size=(T, nq, d)) / 4, jnp.bfloat16)
+    scale = 1.0 / 16.0
+    if q_tile is None:
+        pa.KERNEL_CHOICES.pop((T, 2 * rows + 1, cols), None)
+        fn = jax.jit(lambda q, k, v, seq_idx, pos: pa.paged_attention(q, k, v, tables, seq_idx, pos, bs, softmax_scale=scale))
+    else:
+        fn = jax.jit(lambda q, k, v, seq_idx, pos: pa._pallas_paged(q, k, v, tables, seq_idx, pos, block_size=bs,
+                                                                    q_tile=q_tile, softmax_scale=scale))
+    out = fn(q, k_ws, v_ws, seq_idx, x_pos)
+    if q_tile is None:
+        choice = pa.kernel_choice(T, 2 * rows + 1, cols)
+        assert choice == {"kernel": "paged_attn_q_tiled", "q_tile": 512, "blocks_per_step": 1,
+                          "rule": "heuristic:long_rows_one_head"}, choice
+        need = pa._q_tiled_vmem_bytes(nq * 512, 512, d, bs, nq, 2, 2)
+        assert need * 3 // 2 <= pa._Q_TILED_VMEM_LIMIT < pa._q_tiled_vmem_bytes(nq * 1024, 1024, d, bs, nq, 2, 2) * 3 // 2
+    us = _us_a_call(fn, q, k_ws, v_ws, seq_idx, x_pos, calls=10)
+    pairs = chunk * before + chunk * (chunk + 1) // 2
+    print(f"\nexpanded_call[before={before}, q_tile={q_tile or 'the rule: 512'}]: {us:.0f} us a call, "
+          f"{pairs * nq * 2 * 2 * d / us / 1e6:.1f} TFLOP/s")
+    # the float32 reference reads a token-major pool: slot 1's blocks of every head, a token a row
+    as_pool = lambda ws: jnp.moveaxis(ws[:, cols:cols + n_live], 0, 2).reshape(n_live * bs, nq, d)
+    ref = _paged_reference_by_run(q[7:] * (np.sqrt(d) * scale), as_pool(k_ws), as_pool(v_ws),
+                                  jnp.arange(n_live, dtype=jnp.int32)[None], [(before, chunk)], bs, None)
+    got, ref = np.asarray(out[7:7 + chunk], np.float32), np.asarray(ref, np.float32)
+    np.testing.assert_allclose(got, ref, atol=5e-2, rtol=5e-2)
+    assert np.linalg.norm(got - ref) / np.linalg.norm(ref) < 5e-3
