@@ -5,7 +5,7 @@ with ``state_manager: DSStateManagerConfig`` and tensor-parallel settings).
 """
 
 from dataclasses import dataclass, field
-from typing import Union
+from typing import Optional, Tuple, Union
 
 import jax.numpy as jnp
 
@@ -18,6 +18,10 @@ class DSStateManagerConfig:
     max_context: int = 2048  # per-sequence context ceiling (blocks * block_size)
     memory_config: str = "auto"  # 'auto' sizes the KV pool from free HBM
     offload: bool = False  # reference kv_cache.py:169 offload hooks — not yet
+    # the static shapes a step is padded up to, ascending, the last the limit above (None: 8, 16, 32, ... up
+    # to it). Fewer buckets are fewer programs to compile and warm; a step pads further.
+    token_buckets: Optional[Tuple[int, ...]] = None
+    seq_buckets: Optional[Tuple[int, ...]] = None
 
 
 @dataclass
@@ -197,6 +201,11 @@ class RaggedInferenceEngineConfig:
     kv_dtype: object = jnp.bfloat16
     # fraction of post-params free HBM given to the KV pool in auto mode
     kv_memory_fraction: float = 0.8
+    # a blocking step's tokens: False cuts them to the live rows on the device before the fetch, an eager slice
+    # that XLA compiles once per (bucket, rows), so a replica warms bucket x rows of them; True fetches the padded
+    # bucket (a token a row) and cuts it on the host, and no slice program exists. Logits are cut on the device
+    # either way: a row of them is the vocabulary wide.
+    cut_rows_on_host: bool = False
     state_manager: DSStateManagerConfig = field(default_factory=DSStateManagerConfig)
     # prefix-cache subsystem (refcounted COW block sharing + radix reuse)
     prefix_cache: PrefixCacheConfig = field(default_factory=PrefixCacheConfig)

@@ -27,13 +27,14 @@ from ...monitor.roofline import get_roofline
 from ...monitor.trace import (NULL_SPAN, get_tracer, pop_compile_source,
                               push_compile_source)
 from ...moe.grouped import merge_routing_stats
+from ...ops.pallas.kda import KERNEL_NAMES as KDA_KERNEL_NAMES, TILE as KDA_TILE
 from ...ops.pallas.paged_attention import decode_kv_counts, kernel_choice, tiled_kv_counts
 from ...utils.logging import log_dist
 from .config_v2 import RaggedInferenceEngineConfig
 from .model_implementations.flat_model import (expanded_batch, expanded_plan, expanded_slots, expanded_workspace_bytes,
                                                 ragged_forward)
 from .ragged.ragged_manager import DSStateManager
-from .ragged.ragged_wrapper import RaggedBatchWrapper, next_bucket
+from .ragged.ragged_wrapper import RaggedBatchWrapper, next_bucket, packed_len, unpack_state_slots
 from .scheduling_utils import SchedulingError, SchedulingResult
 
 
@@ -83,6 +84,17 @@ def _fetch(sp, out, watched):
         return np.asarray(out), []
     out, *seen = jax.device_get((out, *watched))
     return out, seen
+
+
+def _cut_and_fetch(sp, out, n: int, watched, block: bool, on_host: bool):
+    """``(out[:n], seen)``: a step's result cut to its live rows and, with
+    ``block``, brought to the host (``_fetch``). ``on_host`` fetches the
+    padded bucket and cuts there, so that no (bucket, n) slice program exists;
+    otherwise the cut is an eager slice on the device before the fetch."""
+    if block and on_host:
+        out, seen = _fetch(sp, out, watched)
+        return out[:n], seen
+    return _fetch(sp, out[:n], watched) if block else (out[:n], [])
 
 
 class InferenceEngineV2:
@@ -148,6 +160,18 @@ class InferenceEngineV2:
             raise NotImplementedError(
                 "speculative decoding of a model with latent attention: a verify step of k + 1 tokens a row and the "
                 "token-tree mask have not been shown equal to the reference over a latent pool")
+        # a model with state layers (linear attention): those layers cache no token and hold a fixed state a
+        # sequence instead; the K/V pool, the block tables and the attention counts are the other layers' alone
+        self._state_layers = tuple(getattr(mc, "state_layers", ()))
+        self._kv_layers = tuple(getattr(mc, "kv_layers", range(mc.num_layers)))
+        if self._state_layers:
+            if getattr(ic.speculative, "enabled", False):
+                raise NotImplementedError(
+                    "speculative decoding of a model with a recurrent state layer: a rejected draft is rewound, and "
+                    "the state has consumed the draft and keeps no snapshot to return to")
+            if np.dtype(ic.kv_dtype).itemsize == 1:
+                raise NotImplementedError("an int8 KV cache beside a recurrent state layer: the program threads "
+                                          "either the scales or the state pools, and the state is float32 as stated")
         max_context = ic.state_manager.max_context
         model_max = getattr(mc, "max_seq_len", None)
         if model_max is not None and max_context > model_max:
@@ -164,22 +188,23 @@ class InferenceEngineV2:
         else:
             self.num_kv_blocks = int(ic.num_kv_blocks)
         self.state_manager = DSStateManager(
-            mc.num_layers, mc.num_kv_heads, mc.head_dim,
+            len(self._kv_layers), mc.num_kv_heads, mc.head_dim,
             max_tracked_sequences=ic.state_manager.max_tracked_sequences,
             num_blocks=self.num_kv_blocks, block_size=bs, dtype=ic.kv_dtype,
-            prefix_cache_config=ic.prefix_cache, kv_entry=self._kv_entry)
+            prefix_cache_config=ic.prefix_cache, kv_entry=self._kv_entry,
+            state_entry=tuple(getattr(mc, "state_entry", ())), state_layers=len(self._state_layers))
         if self._block and self.state_manager.prefix_cache is not None:
             self.state_manager.prefix_cache.token_quantum = self._block
         self.batch = RaggedBatchWrapper(
             max_ragged_batch_size=ic.state_manager.max_ragged_batch_size,
             max_ragged_sequence_count=ic.state_manager.max_ragged_sequence_count,
-            max_blocks_per_seq=self._max_blocks_per_seq, block_size=bs)
+            max_blocks_per_seq=self._max_blocks_per_seq, block_size=bs,
+            token_buckets=ic.state_manager.token_buckets, seq_buckets=ic.state_manager.seq_buckets)
 
         self._compiled: Dict[Tuple[int, int, Optional[str]], object] = {}
-        self._kernel_labels: Dict[Tuple[int, int], str] = {}  # (tokens, rows) -> span label, see _kernel_of
+        self._kernel_labels: Dict[Tuple[int, int, bool], str] = {}  # (tokens, rows, horizon) -> span label, see _kernel_of
         # (window or None, layers that attend in it), for _kv_span_args and _tiled_kv_span_args
-        self._kv_windows = list(collections.Counter(
-            mc.layer_window(l) for l in range(mc.num_layers)).items())
+        self._kv_windows = list(collections.Counter(mc.layer_window(l) for l in self._kv_layers).items())
         # speculative-decoding lifetime totals (two int adds per verify
         # step; the gauge feeding off them only updates when metrics are on)
         self._spec_totals = {"drafted": 0, "accepted": 0}
@@ -281,7 +306,8 @@ class InferenceEngineV2:
 
         bs = ic.kv_block_size
         dt_bytes = _np.dtype(ic.kv_dtype).itemsize  # accepts "int8" and jnp dtypes alike
-        per_block = mc.num_layers * sum(h * w for h, w in self._kv_entry) * bs * dt_bytes
+        kv_layers = len(getattr(mc, "kv_layers", range(mc.num_layers)))  # the layers that cache a token's entry
+        per_block = kv_layers * sum(h * w for h, w in self._kv_entry) * bs * dt_bytes
         if dt_bytes == 1:  # int8 KV: absmax scales ride along, fp32 per (token, head)
             per_block += 2 * mc.num_layers * mc.num_kv_heads * bs * 4
         min_blocks = -(-max_context // bs) + 1
@@ -295,7 +321,8 @@ class InferenceEngineV2:
                 # ... and what the largest program keeps beside the pool while it runs: the per-head K and V of
                 # latent attention's long rows (zero for every other model), which the pool must not be given
                 used = max(stats.get("bytes_in_use", 0), param_bytes) + expanded_workspace_bytes(
-                    mc, ic.state_manager.max_ragged_batch_size, -(-max_context // bs), bs, dt_bytes)
+                    mc, ic.state_manager.max_ragged_batch_size, -(-max_context // bs), bs, dt_bytes) \
+                    + self._state_bytes_ahead(mc, ic, dt_bytes)
                 free = max(0, int(stats["bytes_limit"]) - used)
         except Exception:
             free = None
@@ -309,6 +336,21 @@ class InferenceEngineV2:
         log_dist(f"auto KV pool: {blocks} x {bs}-token blocks "
                  f"({blocks * per_block / 2**20:.0f}MiB of {free / 2**20:.0f}MiB free)", ranks=[0])
         return blocks
+
+    def _state_bytes_ahead(self, mc, ic, dt_bytes: int) -> int:
+        """What a model with state layers takes BEFORE the K/V pool gets its
+        share of what the weights leave: the state pools (one slot a tracked
+        sequence a state layer) and what the largest ``put`` program keeps of
+        a linear layer's tiles while it runs (``kda_chunks``: a dozen float32
+        arrays of ``[tiles x TILE, heads, width]``). 0 for every other model."""
+        state_layers = len(getattr(mc, "state_layers", ()))
+        if not state_layers:
+            return 0
+        (h, dk, dv), (taps, channels) = mc.state_entry
+        sm = ic.state_manager
+        slot = state_layers * (h * dk * dv * 4 + taps * channels * dt_bytes)
+        tile_tokens = sm.max_ragged_batch_size + KDA_TILE * sm.max_ragged_sequence_count
+        return sm.max_tracked_sequences * slot + 12 * tile_tokens * h * max(dk, dv) * 4
 
     def can_schedule(self, uids: Iterable[int], lengths: Iterable[int]) -> SchedulingResult:
         """Admission control (reference ``engine_v2.py:179``): sequence,
@@ -487,6 +529,7 @@ class InferenceEngineV2:
                 kernel=self._kernel_of(t_bucket, s_bucket), uids=[int(u) for u in batch_uids[:16]],
                 blocked=bool(block),
                 **self._attn_span_args([seq.seen_tokens for seq in descs], [t.size for t in batch_tokens], t_bucket),
+                **self._state_span_args(len(batch_uids), sum(int(t.size) for t in batch_tokens)),
                 **({} if had_prefill else
                    self._kv_span_args(t_bucket, s_bucket, [[seq.seen_tokens for seq in descs]])),
                 **self._tiled_kv_span_args(t_bucket, s_bucket, rb)))
@@ -494,12 +537,11 @@ class InferenceEngineV2:
                 for seq in descs:
                     seq.post_forward()
                     self.state_manager.publish_sequence(seq)  # completed full blocks → tree
-            seen = []
             with tr.span("serving/engine_fetch", tid="serving"):
-                out = out[:rb.n_seqs]  # slice ON DEVICE: the host fetch moves n_seqs rows, not the padded bucket
-                if block:
-                    # beside them, for a live span, the int32 the step counted of its experts
-                    out, seen = _fetch(sp, out, stats[:1])
+                # beside the rows, for a live span, the int32 the step counted of its experts. Logits are cut on
+                # the device whatever the option says: a row of them is the vocabulary wide
+                out, seen = _cut_and_fetch(sp, out, rb.n_seqs, stats[:1], block,
+                                           self.config.cut_rows_on_host and mode is not None)
             _observe(sp, lambda: self._moe_span_args(held["tokens"], t_bucket, 1, seen[0]) if seen else {}, held)
         if rf.enabled and block:
             # wall join through the blocking host fetch — the same window the
@@ -575,6 +617,21 @@ class InferenceEngineV2:
             args["attn_expanded_pairs"] = self.model_config.num_layers * int((row_pairs * expanded).sum())
         return args
 
+    def _state_span_args(self, rows: int, tokens: int) -> dict:
+        """What a step span says of the state layers of a model that has them
+        (nothing otherwise): ``state_rows``, the rows whose state the call
+        read and wrote (x the steps of a horizon); ``state_bytes``, those rows
+        x state layers x ``state_entry_bytes`` x 2, the least a correct form
+        moves; ``lin_tokens``, the tokens through linear layers, x layers;
+        ``state_slots_live`` of ``state_slots_total`` slots taken."""
+        if not self._state_layers:
+            return {}
+        kv = self.state_manager.kv_cache
+        entry, n = kv.state_entry_bytes(), len(self._state_layers)
+        return {"state_rows": rows, "state_bytes": rows * n * entry * 2, "state_entry_bytes": entry,
+                "lin_tokens": tokens * n, "state_slots_live": kv.state_slots - kv.free_state_slots,
+                "state_slots_total": kv.state_slots}
+
     def _expanded_plan(self, t_bucket: int):
         """``flat_model.expanded_plan`` of this engine's ``put`` program of
         ``t_bucket`` tokens: ``(0, 0)`` for a model without latent attention."""
@@ -632,7 +689,7 @@ class InferenceEngineV2:
             bound, live = bound + b, live + l
         return {"tile_kv_live": live, "tile_kv_bound": bound}
 
-    def _kernel_of(self, T: int, S: int) -> str:
+    def _kernel_of(self, T: int, S: int, horizon: bool = False) -> str:
         """``<kernel>:<n>:<rule>`` (``n``: the tiled kernel's ``q_tile``, or the
         KV blocks a grid step of the decode kernel takes) of the paged-attention
         call inside the compiled program of ``T`` tokens and ``S`` rows,
@@ -641,17 +698,24 @@ class InferenceEngineV2:
         with latent attention's expanded call names both calls, the absorbed
         one first, joined by ``+``. Empty
         while nothing recorded a choice for the shape (an attention module
-        that is not the paged kernel's)."""
-        label = self._kernel_labels.get((T, S))
+        that is not the paged kernel's). A model with state layers names the
+        delta rule's kernel behind them: the recurrent step of a decode
+        ``horizon``, the chunk scan of a ``put``."""
+        label = self._kernel_labels.get((T, S, horizon))
         if label is None:
             choices = [kernel_choice(T, S, self._max_blocks_per_seq)]
             rows, cols = self._expanded_plan(T)
             if rows:  # latent attention's second call, over the workspace of per-head K and V
                 choices.append(kernel_choice(T, 2 * rows + 1, cols))
+            parts = [] if None in choices else ["%s:%d:%s" % (c["kernel"], max(c["q_tile"], c["blocks_per_step"]), c["rule"])
+                                                for c in choices]
+            if self._state_layers:  # beside the paged kernel, the delta rule's form in this program
+                parts.append("%s:%d:%s" % ((KDA_KERNEL_NAMES[0], 1, "one-token-rows") if horizon else
+                                           (KDA_KERNEL_NAMES[1], KDA_TILE, "ragged")))
+            label = "+".join(parts)
             if None in choices:
-                return ""
-            label = self._kernel_labels[(T, S)] = "+".join("%s:%d:%s" % (
-                c["kernel"], max(c["q_tile"], c["blocks_per_step"]), c["rule"]) for c in choices)
+                return label
+            self._kernel_labels[(T, S, horizon)] = label
         return label
 
     # ------------------------------------------------------------------
@@ -799,16 +863,14 @@ class InferenceEngineV2:
             # flag discloses it
             held = _observe(sp, lambda: dict(
                 rows=S, tokens=S * int(n_steps), steps=int(n_steps), bucket_rows=int(s_bucket),
-                bucket_tokens=int(s_bucket), kernel=self._kernel_of(s_bucket, s_bucket),
+                bucket_tokens=int(s_bucket), kernel=self._kernel_of(s_bucket, s_bucket, horizon=True),
                 uids=[int(u) for u in uids[:16]], blocked=bool(block),
                 **self._attn_span_args([seq.seen_tokens for seq in seqs], [int(n_steps)] * S),
+                **self._state_span_args(S * int(n_steps), S * int(n_steps)),
                 **self._kv_span_args(s_bucket, s_bucket, np.asarray([seq.seen_tokens for seq in seqs])[None, :]
                                      + np.arange(int(n_steps))[:, None])))
-            seen = []
             with tr.span("serving/engine_fetch", tid="serving"):
-                toks = toks[:S]  # on-device slice before any host fetch
-                if block:
-                    toks, seen = _fetch(sp, toks, stats[:1])
+                toks, seen = _cut_and_fetch(sp, toks, S, stats[:1], block, self.config.cut_rows_on_host)
             pc = self.state_manager.prefix_cache
             with tr.span("serving/engine_commit", tid="serving"):
                 if block:
@@ -998,7 +1060,7 @@ class InferenceEngineV2:
         return self._compiled[key]
 
     def _ragged_step(self, params, packed, pools, t_bucket, s_bucket, gather_k: int = 0,
-                     tree_meta=None, moe_stats: bool = False, kv_only: bool = False):
+                     tree_meta=None, moe_stats: bool = False, kv_only: bool = False, one_token_rows: bool = False):
         """One ragged forward over the pool tuple (2 = bf16 pools, 4 = int8
         pools + scales, 1 = a latent pool). The SINGLE builder both compiled paths share —
         quant/non-quant variation lives in the tuple arity, not in four
@@ -1026,7 +1088,12 @@ class InferenceEngineV2:
         ``[experts_hit, expert_load_max, slots]`` of this forward.
 
         ``kv_only``: the commit of a diffusion block (``ragged_forward``): the
-        K/V of every layer and nothing else, logits None."""
+        K/V of every layer and nothing else, logits None.
+
+        A model with state layers: the pool tuple is ``(k, v, state, tails)``
+        and the rows' state slots ride behind the descriptors;
+        ``one_token_rows`` says that token ``i`` is row ``i`` (the decode
+        horizon's step), which picks the delta rule's recurrent form."""
         from .ragged.ragged_wrapper import unpack_descriptors
 
         token_ids, seq_idx, pos, valid, tables, last_idx = unpack_descriptors(
@@ -1080,11 +1147,18 @@ class InferenceEngineV2:
             # padding rows carry last_idx 0 — clamp their (negative) indices;
             # the caller slices the garbage rows off with [:n_seqs]
             last_idx = jnp.maximum(idx, 0).reshape(-1)
-        scales = {"k_scale": pools[2], "v_scale": pools[3]} if len(pools) == 4 else {}
+        if self._state_layers:  # (never beside int8: the last two pools are the state's)
+            if gather_k:
+                raise NotImplementedError("a speculative verify step (a tree of drafts among them) of a model with a "
+                                          "recurrent state layer: the rejected tokens cannot be rewound out of the state")
+            beside = {"state_pools": tuple(pools[2:]), "one_token_rows": one_token_rows,
+                      "state_slots": unpack_state_slots(packed, t_bucket, s_bucket, self._max_blocks_per_seq)}
+        else:
+            beside = {"k_scale": pools[2], "v_scale": pools[3]} if len(pools) == 4 else {}
         out = ragged_forward(self.model_config, self.config.kv_block_size, params,
                              token_ids, seq_idx, pos, valid, tables, last_idx,
                              pools[0], pools[1] if len(pools) > 1 else None, use_pallas=self._use_pallas,
-                             modules=self._modules, moe_stats=moe_stats, kv_only=kv_only, **scales, **extra)
+                             modules=self._modules, moe_stats=moe_stats, kv_only=kv_only, **beside, **extra)
         if moe_stats:
             return out[0], tuple(out[1:-1]), out[-1]
         return out[0], tuple(out[1:])  # logits, new pool tuple
@@ -1183,6 +1257,10 @@ class InferenceEngineV2:
         t_rf = time.perf_counter() if rf.enabled else 0.0
         uids = list(batch_uids)
         S = len(uids)
+        if self._state_layers:
+            raise NotImplementedError(
+                "speculate_decode (a linear draft or a token tree) of a model with a recurrent state layer: a "
+                "rejected draft is rewound, and the state has consumed the draft and keeps no snapshot to return to")
         with tr.span("serving/spec_verify", tid="serving") as sp:
             with tr.span("serving/engine_batch", tid="serving"):
                 firsts = [np.asarray(t, np.int32).reshape(-1) for t in first_tokens]
@@ -1505,7 +1583,7 @@ class InferenceEngineV2:
                         stepped = packed.at[0:s_bucket].set(toks) \
                                         .at[2 * s_bucket:3 * s_bucket].add(t)
                         logits, pl, *new = step_fn(params, stepped, pl, s_bucket, s_bucket,
-                                                   moe_stats=moe)
+                                                   moe_stats=moe, one_token_rows=True)
                         # draw keyed by the NEW token's absolute position —
                         # the same stream the sampled put path would produce
                         nxt = sample_tokens(logits, samp_f[:, 0], samp_f[:, 1], seeds,
@@ -1529,7 +1607,7 @@ class InferenceEngineV2:
                         stepped = packed.at[0:s_bucket].set(toks) \
                                         .at[2 * s_bucket:3 * s_bucket].add(t)
                         logits, pl, *new = step_fn(params, stepped, pl, s_bucket, s_bucket,
-                                                   moe_stats=moe)
+                                                   moe_stats=moe, one_token_rows=True)
                         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                         return (nxt, pl, merge(stats, new)), nxt
 
@@ -1600,7 +1678,7 @@ class InferenceEngineV2:
                     else self._get_compiled_decode(s_bucket, n_steps)
                 # packed layout [T ids][T idx][T pos][T valid][S*max_blocks][S last]
                 # with T == S on the decode path (S * B for a block-diffusion model)
-                packed = jnp.zeros(s_bucket * (4 * B + 1 + max_blocks), jnp.int32)
+                packed = jnp.zeros(packed_len(s_bucket * B, s_bucket, max_blocks, bool(self._state_layers)), jnp.int32)
                 t0 = time.perf_counter()
                 toks, pools = fn(self.params, packed, kv.pools())
                 jax.block_until_ready(toks)
@@ -1630,7 +1708,7 @@ class InferenceEngineV2:
                     fn = self._get_compiled(t_bucket, s_bucket, sample)
                     # put-path packed layout: [T ids][T idx][T pos][T valid]
                     # [S*max_blocks][S last]
-                    packed = jnp.zeros(4 * t_bucket + s_bucket * (max_blocks + 1), jnp.int32)
+                    packed = jnp.zeros(packed_len(t_bucket, s_bucket, max_blocks, bool(self._state_layers)), jnp.int32)
                     t0 = time.perf_counter()
                     out, pools = fn(self.params, packed, kv.pools())
                     jax.block_until_ready(out)
@@ -1824,6 +1902,10 @@ class InferenceEngineV2:
         exist yet, and partial blocks never travel (the tree only holds
         full blocks, same rule as ``publish``)."""
         sm = self.state_manager
+        if self._state_layers:
+            raise NotImplementedError(
+                "export_sequence_kv of a model with a recurrent state layer: the handoff ships K/V blocks, and the "
+                "receiving replica would resume with them and no state; the state would have to travel beside them")
         seq = sm.get_sequence(uid)
         tokens = np.asarray(tokens, np.int32).reshape(-1)
         bs = self.config.kv_block_size

@@ -37,6 +37,7 @@ import numpy as np
 
 from ....models.transformer import TransformerConfig, apply_rope, mlp_activation, rope_inv_freq, rope_table
 from ....moe.grouped import merge_routing_stats
+from ....ops.pallas.kda import kda_chunks, kda_step
 
 # Latent attention, the form a row a step (``ragged_forward``). Expanded, a
 # (query, key) pair a head costs ``2 (d + dv)`` operations where absorbed costs
@@ -161,7 +162,8 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
                    block_tables, last_idx, k_pool, v_pool, use_pallas: bool = False,
                    unroll: bool = True, modules: Dict[str, Any] = None,
                    k_scale=None, v_scale=None, pos_ids=None, attn_mask=None,
-                   ctx_pos_ids=None, moe_stats: bool = False, kv_only: bool = False):
+                   ctx_pos_ids=None, moe_stats: bool = False, kv_only: bool = False,
+                   state_pools=None, state_slots=None, one_token_rows: bool = False):
     """Returns (last-token logits [S_pad, V], k_pool, v_pool).
 
     token_ids/seq_idx/pos/valid: [T_pad]; block_tables: [S_pad, max_blocks];
@@ -241,6 +243,22 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
     wanted, so the last layer stops at its scatter, there is no head, and the
     logits returned are None.
 
+    A model with LINEAR-attention layers (``cfg.state_layers``, Kimi Delta
+    Attention: ``models/solar.py`` has the equations) takes ``state_pools``,
+    ``(state [Ls, slots, H, dk, dv] float32, tails [Ls, slots, taps - 1, 3 H
+    dk])`` stacked over the state layers, and ``state_slots`` ``[S_pad]``,
+    each row's slot; the pools ``k_pool``/``v_pool`` are stacked over the
+    layers that cache K and V alone (``cfg.kv_layers``), and so are the
+    attention weights. Such a layer caches no token: it runs q, k and v
+    through the causal convolution from each row's stored tail (zeros for a
+    row whose first token is at position 0, whatever the slot held), the delta
+    rule over each row's tokens from that row's state (``ops/pallas/kda.py``:
+    the chunkwise form over a ragged batch, or with ``one_token_rows``, the
+    decode horizon's step, where token ``i`` IS row ``i``, the recurrent
+    step), and writes state and tail back at the rows that were fed. A padded
+    token or row touches neither. The return gains the two pools after the
+    K/V pools.
+
     ``k_scale``/``v_scale``: int8-KV mode — [nkv, L*pool_len] fp32 absmax
     scales (lane-major over slots, the layout both the scatter and the
     Pallas kernel consume without a transpose). When given, the pools hold
@@ -295,12 +313,39 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
     # round-trip the whole cache through fresh stacked outputs every forward
     # — at serving scale that copy (~2x pool bytes of HBM traffic per decode
     # step) dominated the step budget.
+    quant = k_scale is not None
     NB = pool_len // block_size
-    L = k_pool.shape[0]
-    flat_len = L * pool_len
+    L = cfg.num_layers
+    flat_len = k_pool.shape[0] * pool_len
+    kv_index = {l: i for i, l in enumerate(cfg.kv_layers)}  # a layer's place in the K/V pools and attention weights
+    state_index = {l: i for i, l in enumerate(cfg.state_layers)}
+    if bool(state_index) != (state_pools is not None) or (state_index and (
+            state_slots is None or quant or attn_mask is not None or kv_only or latent)):
+        raise ValueError("a model with linear-attention layers takes state_pools and state_slots, and neither int8 "
+                         "scales, a token-tree mask nor kv_only; every other model takes none")
+    if state_index:
+        # what the rows are fed, once a program: tokens a row (rows come in order from flat token 0), a
+        # token's place in its row's run, and which rows start their sequence here
+        S = block_tables.shape[0]
+        n_slots, taps = state_pools[0].shape[1], cfg.kda_conv_size
+        ok = valid.astype(jnp.int32)
+        if one_token_rows:
+            n_tok, in_row, row_start = ok, jnp.zeros(T, jnp.int32), jnp.arange(T, dtype=jnp.int32)
+            first_pos = pos
+        else:
+            n_tok = jnp.zeros(S, jnp.int32).at[seq_idx].add(ok)
+            row_start = jnp.cumsum(n_tok) - n_tok
+            in_row = jnp.arange(T, dtype=jnp.int32) - row_start[seq_idx]
+            first_pos = pos[jnp.minimum(row_start, T - 1)]
+        fed = n_tok > 0
+        fresh = fed & (first_pos == 0)
+        n_live = jnp.sum(fed.astype(jnp.int32))
+        kda_pallas = use_pallas and jax.default_backend() == "tpu"
+        kda_interpret = bool(getattr(attention, "implementation_config", {}).get("interpret", False))
+        st_flat = state_pools[0].reshape((-1, ) + state_pools[0].shape[2:])
+        cv_flat = state_pools[1].reshape((-1, ) + state_pools[1].shape[2:])
     slot = block_tables[seq_idx, pos // block_size] * block_size + pos % block_size
 
-    quant = k_scale is not None
     # what the attention paths mask by (see the docstring)
     vis_pos = pos | (cfg.diffusion_block_size - 1) if cfg.diffusion_block_size > 1 else pos
     if cfg.diffusion_block_size > 1 and (attn_mask is not None or block_size % cfg.diffusion_block_size):
@@ -328,17 +373,64 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
     else:
         absorbed_pos, workspace = vis_pos, None
 
+    def linear_mixer(h1, blk, li, st_flat, cv_flat):
+        """A linear-attention layer's mixer on the normed input ``h1`` ``[T,
+        H]``, ``li`` its place among the state layers. Returns ``(out [T, H],
+        st_flat, cv_flat)``."""
+        nh, dk = cfg.kda_num_heads, cfg.kda_head_dim
+        f32 = jnp.float32
+        slot_li = li * n_slots + state_slots
+        x3 = jnp.concatenate([linear(h1, blk[f"kda_w{n}"], None) for n in "qkv"], axis=-1)            # [T, 3 nh dk]
+        w = jnp.concatenate([blk[f"kda_conv_{n}"] for n in "qkv"], axis=-1).astype(f32)               # [taps, 3 nh dk]
+        tails = jnp.where(fresh[:, None, None], 0, cv_flat[slot_li])                                  # [S, taps - 1, ..]
+        # the causal convolution: tap ``j`` back is the row's own token ``j`` earlier in this step, or,
+        # before the run's first token, what the row's tail kept of its earlier steps
+        y = w[taps - 1] * x3.astype(f32)
+        for j in range(1, taps):
+            earlier = jnp.concatenate([jnp.zeros_like(x3[:j]), x3[:T - j]], axis=0)
+            kept = tails[seq_idx, jnp.clip(taps - 1 + in_row - j, 0, taps - 2)]
+            y = y + w[taps - 1 - j] * jnp.where((in_row >= j)[:, None], earlier, kept).astype(f32)
+        # the tail after this step: the last ``taps - 1`` inputs of [old tail | the run]
+        at = n_tok[:, None] - (taps - 1) + jnp.arange(taps - 1, dtype=jnp.int32)[None, :]            # [S, taps - 1]
+        own = x3[jnp.clip(row_start[:, None] + at, 0, T - 1)]
+        old = jnp.take_along_axis(tails, jnp.clip(n_tok[:, None] + jnp.arange(taps - 1)[None, :], 0, taps - 2)[..., None],
+                                  axis=1)
+        new_tails = jnp.where((at >= 0)[..., None], own, old)
+        cv_flat = cv_flat.at[jnp.where(fed, slot_li, cv_flat.shape[0])].set(new_tails.astype(cv_flat.dtype), mode="drop")
+        q, k, v = (jax.nn.silu(part).reshape(T, nh, dk) for part in jnp.split(y, 3, axis=-1))
+        q = q * jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + 1e-6) / math.sqrt(dk)
+        k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + 1e-6)
+        decay_in = linear(linear(h1, blk["kda_wf1"], None), blk["kda_wf2"], None).astype(f32) + blk["kda_dt_bias"].astype(f32)
+        g = -jnp.exp(blk["kda_A_log"].astype(f32))[None, :, None] * jax.nn.softplus(decay_in).reshape(T, nh, dk)
+        beta = jax.nn.sigmoid(linear(h1, blk["kda_wb"], None).astype(f32)) * (2.0 if cfg.kda_neg_eigval else 1.0)
+        if one_token_rows:
+            o, st_flat = kda_step(q, k, v, g, beta, st_flat, slot_li, fresh, n_live, use_pallas=kda_pallas,
+                                  interpret=kda_interpret)
+        else:
+            o, st_flat = kda_chunks(q, k, v, g, beta, st_flat, slot_li, fresh, n_tok, use_pallas=kda_pallas,
+                                    interpret=kda_interpret)
+        # a norm over each head's values (one gain vector for all heads), then the output gate
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + cfg.norm_eps) * blk["kda_o_norm_scale"].astype(f32)
+        gate = linear(linear(h1, blk["kda_wg1"], None), blk["kda_wg2"], None).astype(f32)
+        o = (o.reshape(T, nh * dk) * jax.nn.sigmoid(gate)).astype(h1.dtype)
+        return linear(o, blk["kda_wo"], None), st_flat, cv_flat
+
     def layer(x, blk, l, k_flat, v_flat, ks_flat, vs_flat, stats=None, ws=None, kind=None, kv_alone=False):
         """``kind``: the layer's attention kind, static (None in a model of
         one kind, where ``l`` may be traced); ``stats``: the running MoE
-        counts; ``ws``: latent attention's workspace of per-head K and V;
-        ``kv_alone``: write this layer's K/V and stop."""
+        counts; ``ws``: latent attention's workspace of per-head K and V, or
+        a model with state layers' ``(state, tails)`` pools; ``kv_alone``:
+        write this layer's K/V and stop."""
         attend = modules["attention_full"] if kind == "full_attention" else attention
         h1 = pre_norm(x, blk["ln1_scale"], blk.get("ln1_bias"))
         bias = (lambda n: blk[n]) if cfg.use_bias else (lambda n: None)
-        slot_l = jnp.where(valid, l * pool_len + slot, flat_len)  # this layer's slots in the flat pool
-        tables_l = block_tables + l * NB  # layer l's blocks in the flat pool
-        if latent:
+        lk = kv_index.get(l, 0) if state_index else l  # (a traced ``l``: every layer caches K and V)
+        slot_l = jnp.where(valid, lk * pool_len + slot, flat_len)  # this layer's slots in the flat pool
+        tables_l = block_tables + lk * NB  # layer l's blocks in the flat pool
+        if kind == "linear_attention":
+            attn_out, *ws = linear_mixer(h1, blk, state_index[l], *ws)
+            ws = tuple(ws)
+        elif latent:
             c, nope, dv = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.v_head_dim
             W = k_flat.shape[-1]
             sin, cos = latent_rope
@@ -410,10 +502,11 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
             if attn_mask is not None:
                 scales = dict(scales, pos_ids=pid, mask=attn_mask, ctx_pos_ids=ctx_pos_ids)
             ctx = attend(q, k_flat, v_flat, tables_l, seq_idx, vis_pos, **scales).reshape(T, nq * d)
-        if cfg.attention_gate:
-            gate = linear(h1, blk["w_attn_gate"], None)
-            ctx = (ctx.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(ctx.dtype)
-        attn_out = linear(ctx, blk["wo"], bias("bo"))
+        if kind != "linear_attention":
+            if cfg.attention_gate:
+                gate = linear(h1, blk["w_attn_gate"], None)
+                ctx = (ctx.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(ctx.dtype)
+            attn_out = linear(ctx, blk["wo"], bias("bo"))
 
         def dense_mlp(h, w_up, w_gate, w_down, b_up=None, b_down=None):
             up = linear(h, w_up, b_up)
@@ -458,6 +551,9 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
     expert_keys = ("moe_wi", "moe_wg", "moe_wo")
     expert_layer_keys = ("gate_wg", "gate_bias", "shared_wi", "shared_wg", "shared_wo")
     dense_layer_keys = ("w_up", "w_gate", "w_down", "b_up", "b_down")
+    attention_keys = ("wq", "wk", "wv", "wo", "w_attn_gate", "q_norm_scale", "k_norm_scale")
+    if state_index:  # the state pools ride the layers where latent attention's workspace does
+        workspace = (st_flat, cv_flat)
     first_expert_layer = cfg.moe_num_dense_layers if moe is not None else 0
     mixed_mlp = first_expert_layer > 0
     experts = {k: v for k, v in params["blocks"].items() if k in expert_keys}
@@ -465,6 +561,10 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
 
     def index_of(name, l):
         """Layer ``l``'s index into the stacked array ``name``; None: it has none there."""
+        if state_index and name.startswith("kda_"):
+            return state_index.get(l)
+        if state_index and name in attention_keys:
+            return kv_index.get(l)
         if mixed_mlp and name in expert_layer_keys:
             return l - first_expert_layer if l >= first_expert_layer else None
         if mixed_mlp and name in dense_layer_keys:
@@ -483,8 +583,9 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
             raise NotImplementedError("kv_only under lax.scan: one scan body cannot stop its last layer at the "
                                       "scatter; the ragged forward unrolls up to 48 layers")
         if cfg.per_layer_attention or mixed_mlp:
-            raise NotImplementedError("layer_types or leading dense layers under lax.scan: one scan body has "
-                                      "one window, one rope and one MLP kind; the ragged forward unrolls up to 48 layers")
+            raise NotImplementedError("layer_types (windows, ropes, linear-attention layers) or leading dense layers "
+                                      "under lax.scan: one scan body has one mixer, one window, one rope and one MLP "
+                                      "kind; the ragged forward unrolls up to 48 layers")
 
         def scan_body(carry, inp):
             blk, l = inp
@@ -498,5 +599,7 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
     # logits_gather semantics: final norm + unembed only each sequence's
     # last token, through the pluggable unembed module
     logits = None if kv_only else unembed(params, x, last_idx)
+    if state_index:
+        pools += tuple(flat.reshape(pool.shape) for flat, pool in zip(workspace, state_pools))
     out = (logits, ) + pools + ((ks_flat, vs_flat) if quant else ())
     return out + (stats, ) if moe_stats else out

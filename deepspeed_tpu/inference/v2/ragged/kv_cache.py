@@ -22,6 +22,20 @@ flat pool. The pool shards over the ``model`` axis on the kv-head dim (TP).
 Every method below walks the parts that exist (:meth:`BlockedKVCache.pools`),
 so what rides them (copy-on-write, the host tier, rollback, export and
 import) carries a latent block as it carries a K/V block.
+
+A model with STATE layers (linear attention: ``TransformerConfig.state_entry``)
+has a second kind of cache beside the blocks: ``state_slots`` slots, one a
+tracked sequence, each a fixed ``[state_layers, ...]`` of float32 state and of
+the convolution's tail whatever the sequence's length,
+
+    state_pool [state_layers, state_slots, heads, dk, dv]   float32
+    tail_pool  [state_layers, state_slots, taps - 1, channels]
+
+handed out by an allocator of their own (:meth:`reserve_state` /
+:meth:`free_state`) and threaded through the compiled forwards after the K/V
+pools. ``num_layers`` is then the layers that cache K and V alone. A slot is
+not a block: nothing shares it, copies it or hashes it, and what moves blocks
+about (the prefix cache, the host tier, export) does not know it.
 """
 
 from typing import Optional, Tuple
@@ -44,10 +58,13 @@ class BlockedKVCache:
     add 1/(2·head_dim) back)."""
 
     def __init__(self, num_layers: int, num_kv_heads: int, head_dim: int, num_blocks: int, block_size: int = 64,
-                 dtype=jnp.bfloat16, sharding=None, entry=None):
+                 dtype=jnp.bfloat16, sharding=None, entry=None, state_entry=(), state_layers: int = 0,
+                 state_slots: int = 0):
         """``entry``: the model's ``kv_entry``; None = per-head K and V of
         ``num_kv_heads`` x ``head_dim``. ``num_kv_heads`` / ``head_dim`` are
-        then the first part's heads and width."""
+        then the first part's heads and width. ``state_entry``: the model's
+        ``state_entry`` (``()``: none), held by ``state_layers`` layers in
+        ``state_slots`` slots; ``num_layers`` counts the K/V layers alone."""
         if entry is None:
             entry = ((num_kv_heads, head_dim), ) * 2
         self.entry = tuple((int(h), int(w)) for h, w in entry)
@@ -69,6 +86,15 @@ class BlockedKVCache:
         self.k_pool = jnp.zeros(shapes[0], dtype)
         self.v_pool = jnp.zeros(shapes[1], dtype) if len(shapes) == 2 else None
         self.k_scale = self.v_scale = None
+        self.state_pool = self.tail_pool = self._state_allocator = None
+        if state_entry:
+            if self.quantized or sharding is not None:
+                raise NotImplementedError("a recurrent state beside an int8 or a sharded KV cache: the state is "
+                                          "float32 by the model's statement and lives whole on one device")
+            state_shape, tail_shape = state_entry
+            self.state_pool = jnp.zeros((state_layers, state_slots) + tuple(state_shape), jnp.float32)
+            self.tail_pool = jnp.zeros((state_layers, state_slots) + tuple(tail_shape), dtype)
+            self._state_allocator = BlockedAllocator(state_slots)
         if self.quantized:
             # [nkv, L * NB * bs] — kv-heads on sublanes, slots on lanes: the
             # layout the forward's scatter and the Pallas kernel's scale
@@ -100,6 +126,33 @@ class BlockedKVCache:
     @property
     def free_blocks(self) -> int:
         return self._allocator.free_blocks
+
+    # -- the state slots of a model with state layers ------------------------
+    @property
+    def has_state(self) -> bool:
+        return self.state_pool is not None
+
+    @property
+    def state_slots(self) -> int:
+        return self._state_allocator.total_blocks if self.has_state else 0
+
+    @property
+    def free_state_slots(self) -> int:
+        return self._state_allocator.free_blocks if self.has_state else 0
+
+    def reserve_state(self) -> int:
+        """One slot for a sequence that enters. What the slot held stays in
+        it: the forward starts a sequence's first token from zero."""
+        return int(self._state_allocator.allocate(1)[0])
+
+    def free_state(self, slot: int) -> None:
+        self._state_allocator.free([int(slot)])
+
+    def state_entry_bytes(self) -> int:
+        """Bytes ONE sequence holds in ONE state layer (state and tail)."""
+        if not self.has_state:
+            return 0
+        return sum(p[0, 0].size * p.dtype.itemsize for p in (self.state_pool, self.tail_pool))
 
     @property
     def total_blocks(self) -> int:
@@ -220,21 +273,26 @@ class BlockedKVCache:
                 sc = sc.at[:, :, dst].set(sc[:, :, src])
                 setattr(self, name, sc.reshape(nkv, -1))
 
+    def _pool_names(self):
+        names = tuple(self._parts())
+        if self.quantized:
+            names += ("k_scale", "v_scale")
+        if self.has_state:
+            names += ("state_pool", "tail_pool")
+        return names
+
     def pools(self):
         """The donated pool tuple the compiled forwards thread through:
         (k, v) full-precision, (k, v, k_scale, v_scale) quantized, (latent, )
-        for a latent entry."""
-        pools = tuple(getattr(self, name) for name in self._parts())
-        if self.quantized:
-            return pools + (self.k_scale, self.v_scale)
-        return pools
+        for a latent entry, (k, v, state, tails) for a model with state
+        layers."""
+        return tuple(getattr(self, name) for name in self._pool_names())
 
-    def update(self, k_pool, v_pool=None, k_scale=None, v_scale=None) -> None:
+    def update(self, *pools) -> None:
         """Install the pools returned by the jitted forward (donated in/out),
         in ``pools()`` order."""
-        self.k_pool, self.v_pool = k_pool, v_pool
-        if k_scale is not None:
-            self.k_scale, self.v_scale = k_scale, v_scale
+        for name, pool in zip(self._pool_names(), pools, strict=True):
+            setattr(self, name, pool)
 
     def memory_bytes(self) -> int:
         n = sum(p.size * p.dtype.itemsize for p in self.pools()[:len(self.entry)])

@@ -105,6 +105,11 @@ class PrefixKVCache:
             raise ValueError(f"unknown eviction policy {eviction!r}: 'lru'")
         if min_hit_blocks < 1:
             raise ValueError(f"min_hit_blocks must be >= 1, got {min_hit_blocks}")
+        if getattr(kv_cache, "has_state", False):
+            raise NotImplementedError(
+                "PrefixKVCache for a model with a recurrent state layer: a shared prefix gives a new sequence K/V "
+                "blocks and no state; the state at a block boundary would have to be snapshot into the tree beside "
+                "the block, which is not built")
         self.kv_cache = kv_cache
         self.block_size = kv_cache.block_size
         self.min_hit_blocks = int(min_hit_blocks)

@@ -27,13 +27,20 @@ class DSStateManager:
 
     def __init__(self, num_layers: int, num_kv_heads: int, head_dim: int, *, max_tracked_sequences: int = 128,
                  num_blocks: int = 256, block_size: int = 64, dtype=jnp.bfloat16, kv_sharding=None,
-                 prefix_cache_config=None, kv_entry=None):
+                 prefix_cache_config=None, kv_entry=None, state_entry=(), state_layers: int = 0):
         """``kv_entry``: the model's ``TransformerConfig.kv_entry`` (None =
-        per-head K and V of ``num_kv_heads`` x ``head_dim``)."""
+        per-head K and V of ``num_kv_heads`` x ``head_dim``); ``num_layers``:
+        the layers that cache it. ``state_entry``: what a sequence holds in
+        each of ``state_layers`` state layers (``TransformerConfig.state_entry``;
+        ``()``: a model without): one slot a tracked sequence, taken when the
+        sequence is created and freed when it is flushed. What takes a
+        sequence's state to be its blocks refuses such a model by name:
+        ``PrefixKVCache``, ``TieredBlockStore``, a rewind in :meth:`rollback_to`."""
         self.max_tracked_sequences = max_tracked_sequences
         self.block_size = block_size
         self.kv_cache = BlockedKVCache(num_layers, num_kv_heads, head_dim, num_blocks, block_size, dtype=dtype,
-                                       sharding=kv_sharding, entry=kv_entry)
+                                       sharding=kv_sharding, entry=kv_entry, state_entry=state_entry,
+                                       state_layers=state_layers, state_slots=max_tracked_sequences)
         self.prefix_cache: Optional[PrefixKVCache] = None
         # host/disk capacity tier under the radix tree (tiered_store.py);
         # None whenever ragged.prefix_cache.host_tier is absent/disabled —
@@ -165,6 +172,8 @@ class DSStateManager:
             raise RuntimeError(f"already tracking {self.max_tracked_sequences} sequences")
         seq = DSSequenceDescriptor(uid=uid, block_size=self.block_size)
         seq.tenant = tenant
+        if self.kv_cache.has_state:
+            seq.state_slot = self.kv_cache.reserve_state()
         n_cached = 0
         if self.prefix_cache is not None and prompt_tokens is not None:
             prompt_tokens = np.asarray(prompt_tokens).reshape(-1)
@@ -241,6 +250,11 @@ class DSStateManager:
         about to be flushed: finish/cancel paths), so a shared partial tail
         is harmless and a dry pool cannot fail a terminal rewind."""
         n_tokens = int(n_tokens)
+        if self.kv_cache.has_state and not final and n_tokens != seq.seen_tokens:
+            raise NotImplementedError(
+                f"rollback_to({n_tokens}) of sequence {seq.uid} at {seq.seen_tokens} tokens: a model with a "
+                "recurrent state layer has consumed the tokens to be rewound and keeps no snapshot to return to "
+                "(a terminal rewind, final=True, is allowed: the state goes with the sequence)")
         if not 0 <= n_tokens <= seq.seen_tokens:
             raise ValueError(f"rollback_to({n_tokens}): sequence {seq.uid} has "
                              f"{seq.seen_tokens} materialized tokens")
@@ -329,3 +343,6 @@ class DSStateManager:
         self.publish_sequence(seq)
         if seq.kv_blocks:
             self.kv_cache.release(seq.kv_blocks)
+        if seq.state_slot >= 0:
+            self.kv_cache.free_state(seq.state_slot)
+            seq.state_slot = -1

@@ -35,6 +35,7 @@ class RaggedBatch:
     last_token_idx: np.ndarray  # [S_pad] int32 — flat index of each seq's last token
     n_tokens: int
     n_seqs: int
+    state_slots: np.ndarray = None  # [S_pad] int32, a model with state layers alone: each row's state slot
 
     @property
     def max_context_bucket(self) -> int:
@@ -44,13 +45,14 @@ class RaggedBatch:
         """All descriptor arrays as ONE int32 vector — a single host→device
         transfer per forward (the analog of the reference's single pinned-
         buffer upload, ``ragged_wrapper.py finalize()``).
-        Layout: [T ids][T seq_idx][T pos][T valid][S*max_blocks tables][S last_idx].
+        Layout: [T ids][T seq_idx][T pos][T valid][S*max_blocks tables][S last_idx],
+        then [S state slots] for a model with state layers.
         """
         return np.concatenate([
             self.token_ids, self.token_seq_idx, self.token_pos,
             self.token_valid.astype(np.int32), self.block_tables.reshape(-1),
             self.last_token_idx,
-        ]).astype(np.int32)
+        ] + ([] if self.state_slots is None else [self.state_slots])).astype(np.int32)
 
 
 def unpack_descriptors(packed, t_bucket: int, s_bucket: int, max_blocks: int):
@@ -66,6 +68,18 @@ def unpack_descriptors(packed, t_bucket: int, s_bucket: int, max_blocks: int):
     return token_ids, seq_idx, pos, valid, tables, last_idx
 
 
+def packed_len(t_bucket: int, s_bucket: int, max_blocks: int, state: bool = False) -> int:
+    """Length of ``RaggedBatch.packed()`` for a bucket (``state``: with the
+    rows' state slots behind it)."""
+    return 4 * t_bucket + s_bucket * (max_blocks + 1 + bool(state))
+
+
+def unpack_state_slots(packed, t_bucket: int, s_bucket: int, max_blocks: int):
+    """The rows' state slots, which a model with state layers packs last."""
+    at = packed_len(t_bucket, s_bucket, max_blocks)
+    return packed[at:at + s_bucket]
+
+
 class RaggedBatchWrapper:
 
     def __init__(self, max_ragged_batch_size: int = 768, max_ragged_sequence_count: int = 128,
@@ -75,8 +89,8 @@ class RaggedBatchWrapper:
         self.max_seqs = max_ragged_sequence_count
         self.max_blocks_per_seq = max_blocks_per_seq
         self.block_size = block_size
-        self.token_buckets = token_buckets or _pow2_buckets(max_ragged_batch_size)
-        self.seq_buckets = seq_buckets or _pow2_buckets(max_ragged_sequence_count)
+        self.token_buckets = _buckets(token_buckets, max_ragged_batch_size)
+        self.seq_buckets = _buckets(seq_buckets, max_ragged_sequence_count)
         self.clear()
 
     def clear(self):
@@ -120,6 +134,8 @@ class RaggedBatchWrapper:
         start_len = np.zeros(S, np.int32)
         total_len = np.zeros(S, np.int32)
         last_idx = np.zeros(S, np.int32)
+        with_state = any(desc.state_slot >= 0 for desc in self._descs)
+        state_slots = np.zeros(S, np.int32) if with_state else None
 
         cur = 0
         for i, (desc, toks) in enumerate(zip(self._descs, self._tokens)):
@@ -132,11 +148,24 @@ class RaggedBatchWrapper:
             start_len[i] = desc.seen_tokens
             total_len[i] = desc.seen_tokens + n
             last_idx[i] = cur + n - 1
+            if with_state:
+                state_slots[i] = desc.state_slot
             cur += n
 
         return RaggedBatch(token_ids=token_ids, token_seq_idx=seq_idx, token_pos=pos, token_valid=valid,
                            block_tables=tables, seq_start_len=start_len, seq_total_len=total_len,
-                           last_token_idx=last_idx, n_tokens=n_tokens, n_seqs=n_seqs)
+                           last_token_idx=last_idx, n_tokens=n_tokens, n_seqs=n_seqs, state_slots=state_slots)
+
+
+def _buckets(given, max_n: int):
+    """``given`` ascending, or the powers of two from 8 up to ``max_n``; the
+    largest bucket is the limit itself, so that a full batch has one."""
+    if not given:
+        return _pow2_buckets(max_n)
+    out = sorted(int(b) for b in given)
+    if out[-1] != max_n:
+        raise ValueError(f"buckets {out} must end at the limit {max_n}")
+    return out
 
 
 def _pow2_buckets(max_n: int):

@@ -36,6 +36,9 @@ class DSSequenceDescriptor:
     # request plane knows a tenant; rides into published radix-tree nodes
     # so hits and eviction pressure are attributable. None = untenanted.
     tenant: str = None
+    # a model with state layers: the sequence's slot in the state pools, taken
+    # at admission and freed at flush; -1 for every other model
+    state_slot: int = -1
     # a block-diffusion model (``diffusion_block_size`` B): ``seen_tokens`` is
     # the COMMITTED length, a multiple of B, whose K/V is final. The slots of
     # the block after it are written by every denoise forward of a ``decode``

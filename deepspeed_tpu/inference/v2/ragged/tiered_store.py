@@ -156,6 +156,10 @@ class TieredBlockStore:
     instance, no worker thread and no per-node residency state exist."""
 
     def __init__(self, kv_cache, config, telemetry=None, clock=time.monotonic):
+        if getattr(kv_cache, "has_state", False):
+            raise NotImplementedError(
+                "TieredBlockStore for a model with a recurrent state layer: the host tier moves K/V blocks and a "
+                "sequence's state is no block; it would have to travel beside them, which is not built")
         self.kv_cache = kv_cache
         self.config = config
         n = int(getattr(config, "host_blocks", 0) or 0)

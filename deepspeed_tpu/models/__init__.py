@@ -13,3 +13,4 @@ from .mellum import mellum, mellum_config
 from .trinity import trinity, trinity_config
 from .sdar import sdar, sdar_config
 from .glm import glm, glm_config
+from .solar import solar, solar_config

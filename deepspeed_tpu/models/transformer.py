@@ -168,6 +168,21 @@ class TransformerConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # linear attention (Kimi Delta Attention, the gated delta rule with a decay
+    # per key channel): the layers ``layer_types`` calls "linear_attention"
+    # cache NOTHING per token and carry, per sequence, a float32 state of
+    # ``kda_num_heads`` x ``kda_head_dim`` x ``kda_head_dim`` and the last
+    # ``kda_conv_size - 1`` inputs of the short causal convolution over q, k
+    # and v (``state_entry``). The decay and the output gate come through two
+    # matrices of rank ``kda_gate_rank`` each; ``kda_neg_eigval`` doubles the
+    # step size (the transition's eigenvalue along k lies in (-1, 1)). Served
+    # by the ragged path alone (``ops/pallas/kda.py``). 0 heads = none, every
+    # other family as it was
+    kda_num_heads: int = 0
+    kda_head_dim: int = 128
+    kda_conv_size: int = 4
+    kda_gate_rank: int = 128
+    kda_neg_eigval: bool = True
     # ZeRO++ qwZ (reference partition_parameters.py:1139 quantized all-gather
     # handles): when set (by the engine, from zero_quantized_weights), the
     # per-layer stage-3 weight gathers inside the scan body travel as int8
@@ -225,10 +240,22 @@ class TransformerConfig:
                                           "positions, a q/k norm, a gate, biases or block diffusion")
         if self.layer_types is not None:
             self.layer_types = tuple(self.layer_types)
-            unknown = set(self.layer_types) - {"sliding_attention", "full_attention"}
+            unknown = set(self.layer_types) - {"sliding_attention", "full_attention", "linear_attention"}
             if unknown or len(self.layer_types) != self.num_layers:
-                raise ValueError(f"layer_types needs {self.num_layers} entries of 'sliding_attention' or "
-                                 f"'full_attention', got {len(self.layer_types)} with {sorted(unknown)}")
+                raise ValueError(f"layer_types needs {self.num_layers} entries of 'sliding_attention', "
+                                 f"'full_attention' or 'linear_attention', got {len(self.layer_types)} with "
+                                 f"{sorted(unknown)}")
+        if bool(self.state_layers) != (self.kda_num_heads > 0):
+            raise ValueError(f"kda_num_heads={self.kda_num_heads} with {len(self.state_layers)} 'linear_attention' "
+                             "layers in layer_types: the one names the other")
+        if self.state_layers:
+            if not self.kv_layers:
+                raise NotImplementedError("every layer 'linear_attention': the paged cache and its block tables "
+                                          "are built for at least one layer that caches K and V")
+            if self.use_bias or self.qkv_bias_enabled or self.diffusion_block_size or self.parallel_residual \
+                    or self.positions in ("alibi", "learned") or self.kda_conv_size < 2:
+                raise NotImplementedError("linear attention beside biases, block diffusion, a parallel residual, "
+                                          "alibi or learned positions, or without its short convolution")
         if self.intermediate_size is None:
             if self.mlp == "swiglu":
                 self.intermediate_size = int(8 * self.hidden_size / 3 / 128 + 1) * 128
@@ -275,6 +302,30 @@ class TransformerConfig:
             return ((1, -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128), )
         return ((self.num_kv_heads, self.head_dim), ) * 2
 
+    @property
+    def state_layers(self) -> Tuple[int, ...]:
+        """The layers that carry a recurrent state per sequence and cache
+        nothing per token (``layer_types`` says 'linear_attention')."""
+        return tuple(l for l, kind in enumerate(self.layer_types or ()) if kind == "linear_attention")
+
+    @property
+    def kv_layers(self) -> Tuple[int, ...]:
+        """The layers that cache ``kv_entry`` per token: all but the state
+        layers. The paged pool is stacked over these alone."""
+        skip = set(self.state_layers)
+        return tuple(l for l in range(self.num_layers) if l not in skip)
+
+    @property
+    def state_entry(self) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
+        """What ONE sequence holds in ONE state layer, whatever its length:
+        the float32 state ``(heads, key width, value width)`` and the
+        convolution's tail ``(taps - 1, channels of q, k and v)`` in the
+        compute type. ``()`` for a model without state layers."""
+        if not self.state_layers:
+            return ()
+        h, d = self.kda_num_heads, self.kda_head_dim
+        return ((h, d, d), (self.kda_conv_size - 1, 3 * h * d))
+
     def layer_kind(self, l: int) -> Optional[str]:
         """Attention kind of layer ``l``; None where the model has one kind."""
         return None if self.layer_types is None else self.layer_types[l]
@@ -303,6 +354,9 @@ class TransformerConfig:
         why = []
         if self.layer_types is not None:
             why.append(f"layer_types gives each layer its own window and rope ({sorted(set(self.layer_types))})")
+        if self.state_layers:
+            why.append(f"{len(self.state_layers)} linear-attention layer(s) (a delta-rule state per sequence, which "
+                       "no whole-sequence forward builds)")
         if self.moe_num_experts > 0 and self.moe_num_dense_layers > 0:
             why.append(f"{self.moe_num_dense_layers} leading dense layer(s) before the expert layers: two MLP kinds")
         if self.experts_held != self.moe_num_experts:
@@ -348,14 +402,36 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
             return jnp.ones(shape, jnp.float32)
         return 1.0 + 0.1 * jax.random.normal(key, shape, jnp.float32)
 
+    La = len(cfg.kv_layers)  # layers with softmax attention: its arrays are stacked over these alone
     blocks = {
         "ln1_scale": gain(extra(12), (L, H)),
-        "wq": dense_init(k[0], (L, H, nq * d), H),
-        "wk": dense_init(k[1], (L, H, nkv * d), H),
-        "wv": dense_init(k[2], (L, H, nkv * d), H),
-        "wo": dense_init(k[3], (L, nq * d, H), nq * d) / math.sqrt(2 * L),
+        "wq": dense_init(k[0], (La, H, nq * d), H),
+        "wk": dense_init(k[1], (La, H, nkv * d), H),
+        "wv": dense_init(k[2], (La, H, nkv * d), H),
+        "wo": dense_init(k[3], (La, nq * d, H), nq * d) / math.sqrt(2 * L),
         "ln2_scale": gain(extra(13), (L, H)),
     }
+    if cfg.state_layers:
+        # linear attention (KDA), stacked over the state layers alone: q, k, v
+        # with their depthwise convolutions ``[taps, channels]``, the decay's
+        # and the output gate's two low-rank matrices, ``A_log`` a head and
+        # ``dt_bias`` a channel (float32 whatever the compute type), the step
+        # size, the per-head output norm's one gain vector, and ``W_o``
+        Ll, nh, dk, r, taps = len(cfg.state_layers), cfg.kda_num_heads, cfg.kda_head_dim, cfg.kda_gate_rank, \
+            cfg.kda_conv_size
+        C = nh * dk
+        for i, name in enumerate(("q", "k", "v")):
+            blocks[f"kda_w{name}"] = dense_init(extra(40 + i), (Ll, H, C), H)
+            blocks[f"kda_conv_{name}"] = dense_init(extra(43 + i), (Ll, taps, C), taps)
+        blocks.update(
+            kda_wf1=dense_init(extra(46), (Ll, H, r), H), kda_wf2=dense_init(extra(47), (Ll, r, C), r),
+            kda_wg1=dense_init(extra(48), (Ll, H, r), H), kda_wg2=dense_init(extra(49), (Ll, r, C), r),
+            kda_wb=dense_init(extra(50), (Ll, H, nh), H),
+            # decays spread over (0, 1): exp(A_log) in [1, 16) and softplus(dt_bias) about 0.01 to 0.3
+            kda_A_log=jnp.log(jax.random.uniform(extra(51), (Ll, nh), jnp.float32, 1.0, 16.0)),
+            kda_dt_bias=jax.random.uniform(extra(52), (Ll, C), jnp.float32, -4.5, -1.0),
+            kda_o_norm_scale=1.0 + 0.1 * jax.random.normal(extra(53), (Ll, dk), jnp.float32),
+            kda_wo=dense_init(extra(54), (Ll, C, H), C) / math.sqrt(2 * L))
     if cfg.latent_attention:
         # the two low-rank projections with their norms, and ``W_kvb`` as the
         # two parts the absorbed form multiplies by, a head at a time: keys
@@ -372,10 +448,10 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
             wkv_b_k=dense_init(extra(31), (L, nq, c, nope), c), wkv_b_v=dense_init(extra(32), (L, nq, c, dv), c),
             wo=dense_init(k[3], (L, nq * dv, H), nq * dv) / math.sqrt(2 * L))
     if cfg.attention_gate:
-        blocks["w_attn_gate"] = dense_init(extra(11), (L, H, nq * d), H)
+        blocks["w_attn_gate"] = dense_init(extra(11), (La, H, nq * d), H)
     if cfg.qk_norm:
-        blocks["q_norm_scale"] = gain(extra(14), (L, d))
-        blocks["k_norm_scale"] = gain(extra(15), (L, d))
+        blocks["q_norm_scale"] = gain(extra(14), (La, d))
+        blocks["k_norm_scale"] = gain(extra(15), (La, d))
     if cfg.post_norms:
         blocks["ln1_post_scale"] = gain(extra(16), (L, H))
         blocks["ln2_post_scale"] = gain(extra(17), (L, H))

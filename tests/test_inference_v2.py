@@ -837,3 +837,39 @@ def test_v2_engine_int4_weights_close_to_fp():
     # meaningful invariant: int8 must be tighter than int4 on the same model
     assert np.abs(l4 - lf).max() / scale < 0.5
     assert np.abs(l8 - lf).max() <= np.abs(l4 - lf).max()
+
+
+def test_the_wrapper_pads_to_the_buckets_it_is_given():
+    """``DSStateManagerConfig.seq_buckets`` / ``token_buckets``: a replica
+    that warms two buckets of each and no more; the last is the limit, so
+    that a full batch has a bucket."""
+    from deepspeed_tpu.inference.v2.config_v2 import DSStateManagerConfig
+    from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import RaggedBatchWrapper, next_bucket
+
+    assert DSStateManagerConfig().seq_buckets is None and DSStateManagerConfig().token_buckets is None
+    given = RaggedBatchWrapper(512, 128, seq_buckets=(32, 128), token_buckets=(128, 512))
+    assert (given.seq_buckets, given.token_buckets) == ([32, 128], [128, 512])
+    assert [next_bucket(n, given.seq_buckets) for n in (1, 32, 33, 128)] == [32, 32, 128, 128]
+    assert RaggedBatchWrapper(512, 128).seq_buckets == [8, 16, 32, 64, 128]
+    with pytest.raises(ValueError, match="must end at the limit 128"):
+        RaggedBatchWrapper(512, 128, seq_buckets=(32, 64))
+
+
+def test_rows_cut_on_the_host_are_the_rows_cut_on_the_device():
+    """``cut_rows_on_host``: the same tokens for ``put`` and ``decode`` of 3
+    rows in a bucket of 8, from an engine that pads to the buckets its
+    configuration names; logits are cut on the device either way."""
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(0, 128, size=n).astype(np.int32) for n in (5, 9, 3)]
+    got = []
+    for on_host in (False, True):
+        eng = _tiny_engine(max_ragged_sequence_count=8, seq_buckets=(8, ), token_buckets=(32, 64))
+        eng.config.cut_rows_on_host = on_host
+        assert (eng.batch.seq_buckets, eng.batch.token_buckets) == ([8], [32, 64])
+        first = eng.put([1, 2, 3], prompts, sample="greedy")
+        toks = eng.decode([1, 2, 3], [np.asarray([t], np.int32) for t in first], 4)
+        logits = eng.put([1, 2, 3], [np.asarray([t], np.int32) for t in toks[:, -1]])
+        assert first.shape == (3, ) and toks.shape == (3, 4) and logits.shape == (3, 128)
+        got.append((np.asarray(first), np.asarray(toks), np.asarray(logits)))
+    for a, b in zip(*got):
+        np.testing.assert_array_equal(a, b)

@@ -1022,3 +1022,55 @@ def test_the_expanded_call_over_pools_by_head_at_the_cells_shapes_on_chip(before
     got, ref = np.asarray(out[7:7 + chunk], np.float32), np.asarray(ref, np.float32)
     np.testing.assert_allclose(got, ref, atol=5e-2, rtol=5e-2)
     assert np.linalg.norm(got - ref) / np.linalg.norm(ref) < 5e-3
+
+
+@pytest.mark.parametrize("form", ["recurrent_step", "chunk_scan"])
+def test_the_delta_rules_kernels_at_the_cells_shapes_on_chip(form):
+    """``ops/pallas/kda.py`` at ``solar-open2-250b.decode-heavy-128``'s widths (64
+    heads of 128 x 128, float32 state): the recurrent step of 128 one-token rows
+    and the chunk scan of a 401-token chunk beside 100 one-token rows in a
+    512-token program, each against the rule token by token from the same pool,
+    with microseconds a call and the state's bytes over them."""
+    from deepspeed_tpu.ops.pallas import kda
+
+    H, d, slots = 64, 128, 160
+    rng = np.random.default_rng(0)
+    T, n_tok = (128, np.ones(128, np.int64)) if form == "recurrent_step" else (
+        512, np.asarray([401] + [1] * 100 + [0] * 27))
+    q, k = rng.normal(size=(2, T, H, d))
+    q = q / np.linalg.norm(q, axis=-1, keepdims=True) / np.sqrt(d)
+    k = k / np.linalg.norm(k, axis=-1, keepdims=True)
+    g = -np.exp(rng.uniform(-4, 1.5, size=(T, H, d)))
+    b = 2 / (1 + np.exp(-rng.normal(size=(T, H))))
+    x = [jnp.asarray(a, jnp.float32) for a in (q, k, rng.normal(size=(T, H, d)), g, b)]
+    pool = jnp.asarray(rng.normal(size=(slots, H, d, d)), jnp.float32)
+    slot = jnp.asarray(rng.permutation(slots)[:128], jnp.int32)
+    fresh = jnp.asarray(rng.integers(0, 2, size=128), jnp.int32)
+    if form == "recurrent_step":
+        fn = lambda q, k, v, g, b, pool: kda.kda_step(q, k, v, g, b, pool, slot, fresh, 128, use_pallas=True)
+    else:
+        fn = lambda q, k, v, g, b, pool: kda.kda_chunks(q, k, v, g, b, pool, slot, fresh, jnp.asarray(n_tok),
+                                                        use_pallas=True)
+    o, new = jax.jit(fn)(*x, pool)
+    in_place = jax.jit(fn, donate_argnums=5)  # as the engine calls it: the pool donated and advanced where it lies
+    _, carried = in_place(*x, pool + 0.0)
+    jax.block_until_ready(carried)
+    t0 = time.perf_counter()
+    for _ in range(10):
+        _, carried = in_place(*x, carried)
+    jax.block_until_ready(carried)
+    us = (time.perf_counter() - t0) / 10 * 1e6
+    rows = int((n_tok > 0).sum())
+    print(f"\nkda[{form}]: {us:.0f} us a call, {rows} rows' state read and written at "
+          f"{rows * 2 * H * d * d * 4 / us / 1e3:.0f} GB/s")
+    t0, untouched = 0, np.ones(slots, bool)
+    for r, n in enumerate(n_tok):
+        if n:
+            S0 = jnp.zeros((H, d, d)) if int(fresh[r]) else pool[slot[r]]
+            if r < 3 or r % 37 == 0:  # the long row and a few of the others, token by token
+                oo, S = kda.recurrence_reference(*[a[t0:t0 + n] for a in x], S0)
+                assert float(jnp.abs(oo - o[t0:t0 + n]).max()) < 1e-4
+                assert float(jnp.abs(S - new[slot[r]]).max()) < 1e-4
+            untouched[int(slot[r])] = False
+            t0 += n
+    assert np.array_equal(np.asarray(new)[untouched], np.asarray(pool)[untouched])
