@@ -67,11 +67,31 @@ def _engine(cfg, params, attention="dense_blocked_attention", prefix_cache=None,
 
 
 @pytest.fixture(scope="module")
-def served(tiny):
+def engine_of(tiny):
+    """One engine of the tiny model an attention module for the module's
+    tests, and one more for each threshold of long rows a test sets: the
+    threshold is read while a program is traced, so an engine is asked for and
+    fed under the threshold it is to run. Who feeds one flushes what it fed."""
+    from deepspeed_tpu.inference.v2.model_implementations import flat_model
+
+    cfg, params, _ = tiny
+    built = {}
+
+    def engine_of(attention="dense_blocked_attention"):
+        key = (attention, flat_model._EXPAND_MIN_TOKENS)
+        if key not in built:
+            built[key] = _engine(cfg, params, attention)
+        return built[key]
+
+    return engine_of
+
+
+@pytest.fixture(scope="module")
+def served(tiny, engine_of):
     """The program's logits for a 40-token prompt fed in chunks of 24 and 32
     positions decoded through the cache."""
-    cfg, params, ids = tiny
-    return _chunks_then_decode(_engine(cfg, params), ids, 40, 24)
+    _, _, ids = tiny
+    return _chunks_then_decode(engine_of(), ids, 40, 24)
 
 
 def _published(cfg) -> dict:
@@ -114,7 +134,7 @@ TOL = 2e-5  # float32 on both sides, the same routing: the order of float32 sums
     ("paged_pallas_attention", 40, 24),    # the decode kernel's body over the latent pool (the interpreter)
     ("paged_pallas_attention", 64, 64),    # one chunk; 8 positions decoded across a block's edge
 ])
-def test_chunked_prefill_and_decode_through_the_latent_cache_match_the_expanded_reference(tiny, attention,
+def test_chunked_prefill_and_decode_through_the_latent_cache_match_the_expanded_reference(tiny, engine_of, attention,
                                                                                          n_prompt, chunk):
     """The program attends in the absorbed form over cached latents; the
     reference expands per-head keys and values and caches nothing. Equal
@@ -122,7 +142,7 @@ def test_chunked_prefill_and_decode_through_the_latent_cache_match_the_expanded_
     norm and the rope), the rope on the one shared key part, the score scale
     and the router."""
     cfg, params, ids = tiny
-    got = _chunks_then_decode(_engine(cfg, params, attention), ids, n_prompt, chunk)
+    got = _chunks_then_decode(engine_of(attention), ids, n_prompt, chunk)
     rel = _rel(got, _reference(cfg, params, ids, list(range(n_prompt - 1, len(ids)))))
     assert rel.max() < TOL, rel
 
@@ -178,7 +198,7 @@ _MIXED = {
 
 @pytest.mark.parametrize("attention", ["dense_blocked_attention", "paged_pallas_attention"])
 @pytest.mark.parametrize("case", sorted(_MIXED))
-def test_long_rows_attended_expanded_beside_the_rest_absorbed(tiny, long_rows_of_8, case, attention):
+def test_long_rows_attended_expanded_beside_the_rest_absorbed(tiny, engine_of, long_rows_of_8, case, attention):
     """One ``put`` whose rows are a mix: the logits of every row equal the
     float32 reference's and those of an engine that attends absorbed alone,
     and the rows the program attended expanded are the first two fed at least
@@ -195,10 +215,13 @@ def test_long_rows_attended_expanded_beside_the_rest_absorbed(tiny, long_rows_of
         for uid, (ids, (seen, _)) in enumerate(zip(seqs, rows)):
             for c0 in range(0, seen, 64):  # the history, in chunks the engine's batch holds
                 engine.put([uid], [ids[c0:min(c0 + 64, seen)]], sample=None)
-        return np.asarray(engine.put(list(range(len(rows))), [ids[seen:] for ids, (seen, _) in zip(seqs, rows)],
-                                     sample=None), np.float32)
+        out = np.asarray(engine.put(list(range(len(rows))), [ids[seen:] for ids, (seen, _) in zip(seqs, rows)],
+                                    sample=None), np.float32)
+        for uid in range(len(rows)):
+            engine.flush(uid)
+        return out
 
-    engine = _engine(cfg, params, attention)
+    engine = engine_of(attention)
     got = served(engine)
     assert _rel(got, want).max() < TOL
     fed = sum(new for _, new in rows)
@@ -215,7 +238,7 @@ def test_long_rows_attended_expanded_beside_the_rest_absorbed(tiny, long_rows_of
         assert engine._kernel_of(t_bucket, 4) == "paged_attn_interpreted:1:interpret+paged_attn_interpreted:8:interpret"
     # absorbed alone: the same rows through an engine whose rows never count as long
     long_rows_of_8._EXPAND_MIN_TOKENS = 768
-    alone = served(_engine(cfg, params, attention))
+    alone = served(engine_of(attention))
     assert _rel(got, alone).max() < TOL
 
 

@@ -178,12 +178,17 @@ def _profiled(tmp_path, fn):
     return program_spans.read(str(path))
 
 
-def test_expert_span_counts_match_a_batch_counted_by_hand(tmp_path):
+def test_expert_span_counts_match_a_batch_counted_by_hand(tmp_path, monkeypatch):
     """(f) With the router's weights zero every expert's probability is 1/8
     and ``top_k`` takes the first two, so each live token puts one slot on
     expert 0 and one on expert 1 in each of the 4 layers. A put of 10 + 5
     tokens and a 3-step decode of both rows, read back from the profiler's file
     with the benchmark's own reader."""
+    from deepspeed_tpu.ops.pallas import paged_attention
+
+    # the table of recorded choices is the process's, keyed by shapes alone: without this, another
+    # file's paged engine of these shapes (a worker runs several files) names ITS kernel on this one's spans
+    monkeypatch.setattr(paged_attention, "KERNEL_CHOICES", {})
     cfg = mellum_config("tiny", dtype=jnp.float32)
     params = TransformerLM(cfg).init(jax.random.PRNGKey(2))
     params["blocks"]["gate_wg"] = jnp.zeros_like(params["blocks"]["gate_wg"])

@@ -89,7 +89,8 @@ def test_tracer_disabled_is_allocation_free():
     assert tr.drain() == []
 
 
-def test_compile_events_captured():
+def test_compile_events_captured(cold_compile):
+    # cold_compile: a program the harness's cache hands back gives no compile event (only the seconds it saved)
     tr = get_tracer().configure(enabled=True)  # buffer-only: no path needed
     jax.jit(lambda x: x * 2 + 1)(jnp.ones((3, 5)))  # fresh shape -> real compile
     events = tr.drain()

@@ -508,7 +508,9 @@ def test_error_status_maps_replica_death_to_503():
 # ----------------------------------------------------------------------
 # the drills (the acceptance bar, smoke-sized)
 # ----------------------------------------------------------------------
-def test_training_drill_smoke(tmp_path):
+def test_training_drill_smoke(tmp_path, cold_compile):
+    # cold_compile: the drill's restarts build the programs of the run they follow, and the ledger this test
+    # holds to the wall clock books every "compile" duration: a cache hit reports the seconds it SAVED as one
     from tools.chaos_drill import training_drill
 
     out = training_drill(seed=7, steps=6, workdir=str(tmp_path))

@@ -48,6 +48,14 @@ def _engine(model, params, kv_blocks=48, prefix=False, **diffusion):
     return InferenceEngineV2(model, icfg, params=params)
 
 
+@pytest.fixture(scope="module")
+def plain(weights):
+    """The module's ONE engine of the default configuration, for the tests that
+    change nothing of it: each flushes what it fed (``_run`` does, a scheduler
+    that ran to its end has), so the next finds the pool whole."""
+    return _engine(*weights)
+
+
 def _prompt(n, seed=0):
     return np.random.default_rng(seed).integers(0, 500, n).astype(np.int32)
 
@@ -82,14 +90,16 @@ def _forward_errors(hp, params, prompt, calls):
 
 def _run(engine, prompt, chunks, calls=(8, 4), uid=7):
     known = _prefill(engine, uid, prompt, chunks)
-    return [engine.decode([uid], [known] if c == 0 else None, n, probe=(0, )) for c, n in enumerate(calls)]
+    out = [engine.decode([uid], [known] if c == 0 else None, n, probe=(0, )) for c, n in enumerate(calls)]
+    engine.flush(uid)
+    return out
 
 
 @pytest.mark.parametrize("r", [0, 1, 2, 3])
-def test_every_denoise_forward_through_chunked_prefill_and_the_cache_agrees_with_the_reference(weights, r):
+def test_every_denoise_forward_through_chunked_prefill_and_the_cache_agrees_with_the_reference(weights, plain, r):
     model, params = weights
     prompt = _prompt(40 + r, seed=r)
-    calls = _run(_engine(model, params), prompt, (8, 20, 12))
+    calls = _run(plain, prompt, (8, 20, 12))
     errors = _forward_errors(_hp(), params, prompt, calls)
     # an open block of r tokens takes 4 - r forwards, every other block 4
     assert len(errors) == (B - r) + 2 * 4 and max(errors) < TOL, errors
@@ -132,7 +142,7 @@ def test_a_control_fails_the_comparison(weights, control):
     assert max(errors) > 100 * TOL, (control, errors)
 
 
-def test_one_pass_over_the_clean_sequence_and_every_noisy_block_equals_a_pass_a_forward(weights):
+def test_one_pass_over_the_clean_sequence_and_every_noisy_block_equals_a_pass_a_forward(weights, plain):
     """What the benchmark's check does on the chip: ALL denoise forwards in ONE
     reference pass, the final sequence followed by each forward's noisy block
     under an explicit ``visible``."""
@@ -140,7 +150,7 @@ def test_one_pass_over_the_clean_sequence_and_every_noisy_block_equals_a_pass_a_
 
     model, params = weights
     hp, prompt = _hp(), _prompt(43)
-    calls = _run(_engine(model, params), prompt, (8, 20, 12))
+    calls = _run(plain, prompt, (8, 20, 12))
     probe = serve_diffusion.probe_of_row([c[1] for c in calls], 0)
     tokens = np.concatenate([c[0][0] for c in calls])
     one = serve_diffusion.reference_logits(ref, hp, params, prompt, tokens, probe, B)
@@ -254,17 +264,16 @@ def test_a_block_calls_span_counts_the_tiled_grid_forward_by_forward(weights, mo
     assert not {"kv_live", "kv_steps"} & set(span)
 
 
-def _scheduler(model, params, **kw):
-    engine = _engine(model, params, **kw)
+def _scheduler(engine):
     return engine, DynamicSplitFuseScheduler(engine, token_budget=32)
 
 
-def test_requests_through_the_scheduler_with_stops_inside_blocks(weights):
+def test_requests_through_the_scheduler_with_stops_inside_blocks(weights, plain):
     """Prompts that end inside a block and before one, answers that end inside
     one: every request gets exactly its tokens, the reference's, and the pool
     is whole again."""
     model, params = weights
-    engine, sched = _scheduler(model, params)
+    engine, sched = _scheduler(plain)
     seen = []
     sched.step_observer = lambda uids, sizes, t0, dur, kind: seen.append((kind, list(uids), list(sizes)))
     requests = {1: (_prompt(13, 1), 10), 2: (_prompt(3, 2), 7), 3: (_prompt(40, 3), 9), 4: (_prompt(16, 4), 8)}
@@ -285,14 +294,14 @@ def test_requests_through_the_scheduler_with_stops_inside_blocks(weights):
     assert all(size % B == 0 for kind, _, sizes in seen if kind == "put" for size in sizes), "chunks end on blocks"
 
 
-def test_an_eos_inside_a_block_drops_the_blocks_tail(weights):
+def test_an_eos_inside_a_block_drops_the_blocks_tail(weights, plain):
     model, params = weights
     prompt = _prompt(18, 9)
     want, _ = ref.generate(_hp(), params, prompt, 4, "low_confidence_static")
     new = want[2:].tolist()
     eos = new[5]  # inside the second generated block
     cut = new.index(eos) + 1
-    engine, sched = _scheduler(model, params)
+    engine, sched = _scheduler(plain)
     sched.submit(1, prompt, max_new_tokens=14, eos_token_id=eos)
     assert sched.run()[1] == new[:cut]
     assert engine.free_blocks == 48
@@ -304,7 +313,7 @@ def test_a_cancel_in_mid_block_leaves_the_committed_length_the_pool_and_the_pref
     a block: the committed length is whole blocks, every block comes back, and
     the prefix cache holds whole committed KV blocks alone."""
     model, params = weights
-    engine, sched = _scheduler(model, params, prefix=True)
+    engine, sched = _scheduler(_engine(model, params, prefix=True))
     prompt = _prompt(39, 11)  # 36 whole + 3 open; KV blocks of 16
     sched.submit(1, prompt, max_new_tokens=30)
     sched.step()
@@ -329,7 +338,7 @@ def test_a_cancel_in_mid_block_leaves_the_committed_length_the_pool_and_the_pref
     assert pc.n_cached_blocks == 2
 
 
-def test_what_the_family_refuses_it_refuses_by_name(weights):
+def test_what_the_family_refuses_it_refuses_by_name(weights, plain):
     model, params = weights
     ids = jnp.zeros((1, 8), jnp.int32)
     from deepspeed_tpu.models.transformer import forward_hidden, forward_with_cache
@@ -338,7 +347,7 @@ def test_what_the_family_refuses_it_refuses_by_name(weights):
                            lambda: forward_with_cache(model.config, params, ids, None)):
         with pytest.raises(NotImplementedError, match="block-causal mask"):
             whole_sequence()
-    engine = _engine(model, params)
+    engine = plain
     engine.put([1], [_prompt(8)], sample="greedy")
     with pytest.raises(NotImplementedError, match="diffusion_block_size=4"):
         engine.speculate_decode([1], [np.zeros(1, np.int32)], [np.zeros(2, np.int32)], 2)
@@ -350,6 +359,8 @@ def test_what_the_family_refuses_it_refuses_by_name(weights):
         engine.decode([1], [_prompt(4)], 4)
     with pytest.raises(ValueError, match="whole blocks"):
         engine.put([1], [_prompt(6)], sample="greedy")
+    engine.flush(1)
+    assert engine.free_blocks == 48 and engine.state_manager.n_tracked_sequences == 0, "a refusal kept nothing"
     with pytest.raises(NotImplementedError, match="speculative decoding"):
         DynamicSplitFuseScheduler(engine, speculative=SpeculativeConfig(mode="ngram"))
     with pytest.raises(NotImplementedError, match="temperature sampling"):

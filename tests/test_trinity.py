@@ -54,6 +54,25 @@ def _engine(cfg, params, attention="dense_blocked_attention"):
     return InferenceEngineV2(TransformerLM(cfg), icfg, params=params)
 
 
+@pytest.fixture(scope="module")
+def engine_of():
+    """One engine a (configuration, attention) for the module. The weights are
+    an argument of every step program (``engine.params``, no module here
+    transforms them), so a test hands its own to the engine it shares, and
+    ``_prefill_then_decode`` flushes what it fed: what a case compares is what
+    an engine of its own would give, without tracing every program again."""
+    built = {}
+
+    def engine_of(cfg, params, attention="dense_blocked_attention"):
+        key = (repr(cfg), attention)
+        if key not in built:
+            built[key] = _engine(cfg, params, attention)
+        built[key].params = params
+        return built[key]
+
+    return engine_of
+
+
 def _published(cfg) -> dict:
     """The configuration-file keys the reference reads, from a program config."""
     return {"num_attention_heads": cfg.num_heads, "num_key_value_heads": cfg.num_kv_heads,
@@ -91,7 +110,7 @@ def _rel(got, ref):
                                                   ("paged_pallas_attention", None, 0),
                                                   ("dense_blocked_attention", 4, 4),
                                                   ("paged_pallas_attention", 4, 12)])
-def test_engine_prefill_and_decode_match_the_plain_reference(attention, held, first):
+def test_engine_prefill_and_decode_match_the_plain_reference(attention, held, first, engine_of):
     """A 40-token prefill (2.5 windows) and 8 positions decoded through the
     paged cache against the reference's full forward pass, with every expert
     held and with a share of 4 of the 16 (the router still scores all 16, an
@@ -105,7 +124,7 @@ def test_engine_prefill_and_decode_match_the_plain_reference(attention, held, fi
     assert params["blocks"]["moe_wi"].shape == (4, held or 16, 64, 48), "expert layers x held experts only"
     assert params["blocks"]["w_up"].shape == (1, 64, 128) and params["blocks"]["gate_wg"].shape == (4, 64, 16)
     ids = _ids(cfg)
-    got = _prefill_then_decode(_engine(cfg, params, attention), ids, 40)
+    got = _prefill_then_decode(engine_of(cfg, params, attention), ids, 40)
     rel = _rel(got, _reference_logits(cfg, params, ids, list(range(39, 48))))
     assert rel.max() < 2e-5, rel
 
@@ -113,7 +132,7 @@ def test_engine_prefill_and_decode_match_the_plain_reference(attention, held, fi
 @pytest.mark.parametrize("switch,value", [("gate", False), ("qk_norm", False), ("rope_in_full_layers", True),
                                           ("selection_bias", False), ("post_norms", False),
                                           ("route_scale", 1.0), ("window", 10**6)])
-def test_the_reference_without_one_mechanism_is_far_from_the_program(switch, value):
+def test_the_reference_without_one_mechanism_is_far_from_the_program(switch, value, engine_of):
     """The controls the chip check runs, at the small size: the reference
     with one mechanism turned off is of order one away from the program, so
     each of them is in the program's logits (and the reference's switch does
@@ -121,7 +140,7 @@ def test_the_reference_without_one_mechanism_is_far_from_the_program(switch, val
     cfg = trinity_config("tiny", dtype=jnp.float32, moe_experts_held=8)
     params = TransformerLM(cfg).init(jax.random.PRNGKey(3))
     ids = _ids(cfg)
-    got = _prefill_then_decode(_engine(cfg, params), ids, 40)
+    got = _prefill_then_decode(engine_of(cfg, params), ids, 40)
     assert _rel(got, _reference_logits(cfg, params, ids, list(range(39, 48)), **{switch: value})).max() > 0.05
 
 
@@ -129,7 +148,7 @@ def test_the_reference_without_one_mechanism_is_far_from_the_program(switch, val
     ("w_attn_gate", lambda a: -a), ("q_norm_scale", jnp.ones_like), ("k_norm_scale", jnp.ones_like),
     ("ln1_scale", jnp.ones_like), ("ln1_post_scale", jnp.ones_like), ("ln2_scale", jnp.ones_like),
     ("ln2_post_scale", jnp.ones_like), ("gate_bias", jnp.zeros_like)])
-def test_the_gate_the_qk_norm_the_four_norms_and_the_bias_each_change_the_logits(name, change):
+def test_the_gate_the_qk_norm_the_four_norms_and_the_bias_each_change_the_logits(name, change, engine_of):
     """Each of the family's own parameters is read where the reference reads
     it: with that one array changed (a gain set to one, the gate's matrix
     negated, the selection bias zeroed) the program's logits move, and they
@@ -140,9 +159,9 @@ def test_the_gate_the_qk_norm_the_four_norms_and_the_bias_each_change_the_logits
     if name == "gate_bias":  # drawn of the order of the gap between the k-th and the next score: widen it
         params["blocks"]["gate_bias"] = params["blocks"]["gate_bias"] * 10
     ids = _ids(cfg, 24)
-    before = _prefill_then_decode(_engine(cfg, params), ids, 20)
+    before = _prefill_then_decode(engine_of(cfg, params), ids, 20)
     params["blocks"][name] = change(params["blocks"][name])
-    after = _prefill_then_decode(_engine(cfg, params), ids, 20)
+    after = _prefill_then_decode(engine_of(cfg, params), ids, 20)
     assert _rel(after, before).max() > 1e-3
     assert _rel(after, _reference_logits(cfg, params, ids, list(range(19, 24)))).max() < 2e-5
 
