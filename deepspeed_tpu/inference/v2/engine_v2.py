@@ -341,8 +341,17 @@ class InferenceEngineV2:
         """What a model with state layers takes BEFORE the K/V pool gets its
         share of what the weights leave: the state pools (one slot a tracked
         sequence a state layer) and what the largest ``put`` program keeps of
-        a linear layer's tiles while it runs (``kda_chunks``: a dozen float32
-        arrays of ``[tiles x TILE, heads, width]``). 0 for every other model."""
+        a linear layer's operands while it runs (``kda_chunks``), counted in
+        float32 arrays of ``[tokens, heads, width]``: the tiles' ``o`` at the
+        static bound of tiles (a token budget of tiles plus a partial tile a
+        row); eight of the token budget (the five operands a tile takes, flat,
+        ``o`` back in the flat order, and the blocks of tiles laid one at a
+        time, which no longer exist all at once: PR 44); and for the rows fed
+        one token, which take the recurrent step, 32 of the rows (a row's ``a,
+        k, q`` columns at 16 lanes a head are 16 of them, the rest what XLA
+        lays them from, ``v`` and ``b``, and the step's ``o``). 320 MB at 512
+        tokens and 128 rows of 64 heads of 128, where the compiled program
+        holds 68 (a v5e, PERF.md section 6, PR 44). 0 for every other model."""
         state_layers = len(getattr(mc, "state_layers", ()))
         if not state_layers:
             return 0
@@ -350,7 +359,8 @@ class InferenceEngineV2:
         sm = ic.state_manager
         slot = state_layers * (h * dk * dv * 4 + taps * channels * dt_bytes)
         tile_tokens = sm.max_ragged_batch_size + KDA_TILE * sm.max_ragged_sequence_count
-        return sm.max_tracked_sequences * slot + 12 * tile_tokens * h * max(dk, dv) * 4
+        tokens = tile_tokens + 8 * sm.max_ragged_batch_size + 32 * sm.max_ragged_sequence_count
+        return sm.max_tracked_sequences * slot + tokens * h * max(dk, dv) * 4
 
     def can_schedule(self, uids: Iterable[int], lengths: Iterable[int]) -> SchedulingResult:
         """Admission control (reference ``engine_v2.py:179``): sequence,
@@ -529,7 +539,8 @@ class InferenceEngineV2:
                 kernel=self._kernel_of(t_bucket, s_bucket), uids=[int(u) for u in batch_uids[:16]],
                 blocked=bool(block),
                 **self._attn_span_args([seq.seen_tokens for seq in descs], [t.size for t in batch_tokens], t_bucket),
-                **self._state_span_args(len(batch_uids), sum(int(t.size) for t in batch_tokens)),
+                **self._state_span_args(len(batch_uids), sum(int(t.size) for t in batch_tokens),
+                                        sum(1 for t in batch_tokens if t.size == 1)),
                 **({} if had_prefill else
                    self._kv_span_args(t_bucket, s_bucket, [[seq.seen_tokens for seq in descs]])),
                 **self._tiled_kv_span_args(t_bucket, s_bucket, rb)))
@@ -617,18 +628,22 @@ class InferenceEngineV2:
             args["attn_expanded_pairs"] = self.model_config.num_layers * int((row_pairs * expanded).sum())
         return args
 
-    def _state_span_args(self, rows: int, tokens: int) -> dict:
+    def _state_span_args(self, rows: int, tokens: int, stepped: int) -> dict:
         """What a step span says of the state layers of a model that has them
         (nothing otherwise): ``state_rows``, the rows whose state the call
-        read and wrote (x the steps of a horizon); ``state_bytes``, those rows
-        x state layers x ``state_entry_bytes`` x 2, the least a correct form
-        moves; ``lin_tokens``, the tokens through linear layers, x layers;
+        read and wrote (x the steps of a horizon); ``state_rows_stepped``,
+        those of them whose state went through the recurrent step (a ``put``'s
+        rows fed ONE token; every row x step of a horizon), the rest through
+        the chunk scan; ``state_bytes``, the rows x state layers x
+        ``state_entry_bytes`` x 2, the least a correct form moves;
+        ``lin_tokens``, the tokens through linear layers, x layers;
         ``state_slots_live`` of ``state_slots_total`` slots taken."""
         if not self._state_layers:
             return {}
         kv = self.state_manager.kv_cache
         entry, n = kv.state_entry_bytes(), len(self._state_layers)
-        return {"state_rows": rows, "state_bytes": rows * n * entry * 2, "state_entry_bytes": entry,
+        return {"state_rows": rows, "state_rows_stepped": stepped, "state_bytes": rows * n * entry * 2,
+                "state_entry_bytes": entry,
                 "lin_tokens": tokens * n, "state_slots_live": kv.state_slots - kv.free_state_slots,
                 "state_slots_total": kv.state_slots}
 
@@ -699,8 +714,9 @@ class InferenceEngineV2:
         one first, joined by ``+``. Empty
         while nothing recorded a choice for the shape (an attention module
         that is not the paged kernel's). A model with state layers names the
-        delta rule's kernel behind them: the recurrent step of a decode
-        ``horizon``, the chunk scan of a ``put``."""
+        delta rule's kernels behind them: the recurrent step of a decode
+        ``horizon``; of a ``put`` the chunk scan and the recurrent step, which
+        takes the rows fed one token (``kda_chunks``)."""
         label = self._kernel_labels.get((T, S, horizon))
         if label is None:
             choices = [kernel_choice(T, S, self._max_blocks_per_seq)]
@@ -709,9 +725,9 @@ class InferenceEngineV2:
                 choices.append(kernel_choice(T, 2 * rows + 1, cols))
             parts = [] if None in choices else ["%s:%d:%s" % (c["kernel"], max(c["q_tile"], c["blocks_per_step"]), c["rule"])
                                                 for c in choices]
-            if self._state_layers:  # beside the paged kernel, the delta rule's form in this program
-                parts.append("%s:%d:%s" % ((KDA_KERNEL_NAMES[0], 1, "one-token-rows") if horizon else
-                                           (KDA_KERNEL_NAMES[1], KDA_TILE, "ragged")))
+            if self._state_layers:  # beside the paged kernel, the delta rule's forms in this program
+                parts += [] if horizon else ["%s:%d:ragged" % (KDA_KERNEL_NAMES[1], KDA_TILE)]
+                parts.append("%s:1:one-token-rows" % KDA_KERNEL_NAMES[0])
             label = "+".join(parts)
             if None in choices:
                 return label
@@ -866,7 +882,7 @@ class InferenceEngineV2:
                 bucket_tokens=int(s_bucket), kernel=self._kernel_of(s_bucket, s_bucket, horizon=True),
                 uids=[int(u) for u in uids[:16]], blocked=bool(block),
                 **self._attn_span_args([seq.seen_tokens for seq in seqs], [int(n_steps)] * S),
-                **self._state_span_args(S * int(n_steps), S * int(n_steps)),
+                **self._state_span_args(S * int(n_steps), S * int(n_steps), S * int(n_steps)),
                 **self._kv_span_args(s_bucket, s_bucket, np.asarray([seq.seen_tokens for seq in seqs])[None, :]
                                      + np.arange(int(n_steps))[:, None])))
             with tr.span("serving/engine_fetch", tid="serving"):

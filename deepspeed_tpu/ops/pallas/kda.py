@@ -6,14 +6,30 @@ The rule, a token (``a = exp(g)`` per key channel, ``b`` the step size):
 
     S' = diag(a_t) S_{t-1};  S_t = S' + b_t k_t (v_t - S'^T k_t)^T;  o_t = S_t^T q_t
 
-Two forms of it, one a kind of program:
+Two forms of it, chosen by what a row is fed:
 
-* :func:`kda_step` (kernel ``kda_recurrent_step``): ONE token a row, the
-  decode horizon's step. A read-modify-write of the row's whole state, bound
-  by memory bandwidth: ``2 x heads x dk x dv x 4`` bytes a row a layer.
-* :func:`kda_chunks` (kernel ``kda_chunk_scan``): a ragged batch of rows fed
-  ANY number of tokens each (SplitFuse chunks beside one-token rows), in the
-  chunkwise-parallel form. A row's tokens are laid into tiles of ``TILE``
+* :func:`kda_step` (kernel ``kda_recurrent_step``): ONE token a row. A
+  read-modify-write of the row's whole state, bound by memory bandwidth: ``2
+  x heads x dk x dv x 4`` bytes a row a layer and nothing else. The decode
+  horizon's step, where every row is such a row, calls it directly.
+* :func:`kda_chunks`: a ragged batch of rows fed ANY number of tokens each
+  (SplitFuse chunks beside the one-token rows of the sequences that decode).
+  It splits the batch by ``n_tok``, which is all it needs to see. A row fed
+  exactly one token goes through the recurrent step, wherever it stands in
+  the batch: compacted to the front on the device (:func:`step_rows`), its
+  token, slot and ``fresh`` gathered, its ``o`` taken back to its flat token.
+  A row fed two or more goes through the chunkwise-parallel form (kernel
+  ``kda_chunk_scan``). Why: the chunk scan pays the same for every live tile
+  whatever it holds (4.0 us a tile a block of 8 heads at 128 x 128 on a v5e,
+  32 us a row a layer for a tile that holds one token and seven dead places,
+  and its operands laid besides) and the step 13 us for the same row; both
+  read and write the row's state once, the step does nothing else (PERF.md
+  section 6, PR 44). With
+  120 rows decoding beside one prompt's chunk, nine tiles of ten were such
+  rows. The two sets of rows are disjoint and both kernels advance the one
+  aliased pool, so the order of the two calls does not matter.
+
+  The chunkwise form: a row's tokens are laid into tiles of ``TILE``
   tokens that start at the row's own first token, so no tile holds two rows
   and a chunk boundary falls wherever the scheduler put it: the last tile of
   a row is padded with tokens of decay one and step size zero, which leave
@@ -28,7 +44,13 @@ Two forms of it, one a kind of program:
   K_end^T U``. All of it is the kernel's, a tile's operands one register each
   (a batch of 8 x 8 matrices made by XLA for every tile was 6 MB of code a
   layer a program); it carries the state through a row's tiles in VMEM and
-  touches the pool once a row.
+  touches the pool once a row. The tiles' operands are laid by XLA (one
+  gather a block) and scanned a BLOCK of ``_TILE_BLOCK`` tiles at a time,
+  under a loop that runs as many blocks as hold a live tile: the plan's
+  static bound is ``T // TILE + R`` tiles, of which a chunk of 200 tokens
+  beside 120 one-token rows fills 25, and laying all of them cost 2.3 ms a
+  layer a call with not one token fed. A row that crosses a block goes on
+  from the pool, where the block before left its state.
 
 Both kernels take the pool flattened over layers, ``[layers * slots, heads,
 dk, dv]``, aliased to their output, and the rows' slots as prefetched
@@ -49,6 +71,9 @@ _HI = lax.Precision.HIGHEST
 # heads a grid step of either kernel holds: a block of the state is
 # ``_HEAD_BLOCK x dk x dv x 4`` bytes (512 KiB at 128 x 128), in and out, double-buffered
 _HEAD_BLOCK = 8
+# tiles a call of the chunk scan takes: ``kda_chunks`` lays a block of tiles and scans it, as many times as the
+# chunk rows' tiles fill blocks, so that what XLA moves to lay tiles follows the tokens and not the static bound
+_TILE_BLOCK = 16
 
 KERNEL_NAMES = ("kda_recurrent_step", "kda_chunk_scan")
 
@@ -187,18 +212,40 @@ def kda_step(q, k, v, g, beta, pool, slot, fresh, n_live, use_pallas: bool = Fal
 # any number of tokens a row
 # ---------------------------------------------------------------------------
 
+def _row_tiles(n_tok, xp=jnp):
+    """The tiles each row takes in the chunk scan: none for a row fed one
+    token (the recurrent step's) or none."""
+    return xp.where(n_tok > 1, -(-n_tok // TILE), 0)
+
+
+def step_rows(n_tok, xp=jnp):
+    """The rows of a ragged batch fed exactly ONE token, compacted in order
+    (no sort: a cumulative sum of the flag, compared with all, as
+    :func:`tile_plan` finds a tile's row). Returns ``(rows, place, n_live)``:
+    ``rows`` ``[R]``, the first ``n_live`` of them the one-token rows (the
+    rest name the last row and are dead); ``place`` ``[R]``, where row ``r``
+    stands among them (if it is one)."""
+    R = n_tok.shape[0]
+    one = (n_tok == 1).astype(xp.int32)
+    upto = xp.cumsum(one)
+    rows = xp.minimum(xp.sum((upto[None, :] <= xp.arange(R, dtype=xp.int32)[:, None]).astype(xp.int32), axis=1), R - 1)
+    return rows.astype(xp.int32), (upto - one).astype(xp.int32), upto[-1].astype(xp.int32)
+
+
 def tile_plan(n_tok, T: int, xp=jnp):
-    """The tiles of a ragged batch whose row ``r`` is fed ``n_tok[r]``
-    contiguous tokens, rows in order from flat token 0: ``NT = T // TILE + R``
-    tile slots (static; every row may end in a partial tile), of which the
-    first ``n_tiles`` are live. Returns ``(row, tok0, cnt, first, n_tiles)``,
-    each ``[NT]`` but the last: a tile's row, its first flat token, its live
-    tokens, whether it opens its row. A dead tile names the last live tile's
-    row and holds no token."""
+    """The chunk scan's tiles of a ragged batch whose row ``r`` is fed
+    ``n_tok[r]`` contiguous tokens, rows in order from flat token 0: a row of
+    two or more tokens takes ``ceil(n_tok / TILE)`` tiles, a row of one token
+    none (:func:`step_rows` has it), though its token keeps its place in the
+    flat order. ``NT = T // TILE + R`` tile slots (static; every row may end
+    in a partial tile), of which the first ``n_tiles`` are live. Returns
+    ``(row, tok0, cnt, first, n_tiles)``, each ``[NT]`` but the last: a tile's
+    row, its first flat token, its live tokens, whether it opens its row. A
+    dead tile names the last live tile's row and holds no token."""
     R = n_tok.shape[0]
     NT = T // TILE + R
     n_tok = n_tok.astype(xp.int32)
-    tiles = -(-n_tok // TILE)
+    tiles = _row_tiles(n_tok, xp)
     ends = xp.cumsum(tiles)
     n_tiles = ends[-1]
     t = xp.arange(NT, dtype=xp.int32)
@@ -314,43 +361,79 @@ def kda_chunks(q, k, v, g, beta, pool, slot, fresh, n_tok, use_pallas: bool = Fa
     (traced, ``[R]``; 0 for a padded row) in a run, rows in order from token 0,
     whatever is past the last row's run ignored; ``pool``, ``slot``, ``fresh``
     as :func:`kda_step` takes them. Returns ``(o [T, H, dv] float32, pool)``
-    with the states of the rows that were fed advanced and no other touched."""
+    with the states of the rows that were fed advanced and no other touched.
+
+    The rows fed exactly ONE token, wherever they stand, go through
+    :func:`kda_step` (13 us a row a layer at 64 heads of 128 x 128 on a v5e,
+    where a tile of the chunk scan that holds one token costs 32); the rows
+    fed two or more through the chunk scan, whose plan gives the others no
+    tile, a block of ``_TILE_BLOCK`` tiles laid and scanned at a time for as
+    many blocks as hold a live tile (a chunk of 200 tokens beside 120
+    one-token rows: 3,173 us a layer where the parent took 6,925, PERF.md
+    section 6, PR 44). The plain path, ``interpret`` and the chip make the
+    same split. A batch with no one-token row and one with nothing else both
+    run: the step with no live row hands the one block it maps back as it
+    came, and the chunk scan with no live tile is not called at all."""
     T, H, dk = q.shape
     dv = v.shape[-1]
-    row, tok0, cnt, first, n_tiles = tile_plan(n_tok, T)
-    NT = row.shape[0]
-    c = jnp.arange(TILE, dtype=jnp.int32)
-    at = jnp.where(c[None, :] < cnt[:, None], tok0[:, None] + c[None, :], T).reshape(-1)   # a dead token reads the fill
+    n_tok, slot, fresh = n_tok.astype(jnp.int32), slot.astype(jnp.int32), fresh.astype(jnp.int32)
     f32 = lambda x: x.astype(jnp.float32)
+    starts = jnp.cumsum(n_tok) - n_tok
+    # the one-token rows, live rows first as the step takes them, each with its token, slot and ``fresh``
+    rows1, place1, n_one = step_rows(n_tok)
+    tok1 = jnp.minimum(starts[rows1], T - 1)
+    o1, pool = kda_step(q[tok1], k[tok1], v[tok1], g[tok1], beta[tok1], pool, slot[rows1], fresh[rows1], n_one,
+                        use_pallas=use_pallas, interpret=interpret)
     b = f32(beta)[..., None]
-    # the five operands into tiles [NT, H, C, d] by ONE gather (zeros where no token is): q, k, b . k, b . v, g
+    # what a tile takes, one run of values a token a head, so that ONE gather lays a block: q, k, b . k, b . v, g
     flat = jnp.concatenate([f32(q), f32(k), b * f32(k), b * f32(v), f32(g)], axis=-1)
-    tiled = jnp.swapaxes(jnp.take(flat, at, axis=0, mode="fill", fill_value=0.0).reshape(NT, TILE, H, -1), 1, 2)
-    operands = tuple(tiled[..., lo:hi] for lo, hi in ((0, dk), (dk, 2 * dk), (2 * dk, 3 * dk), (3 * dk, 3 * dk + dv),
-                                                     (3 * dk + dv, 4 * dk + dv)))
-    of_tile = jnp.stack([slot.astype(jnp.int32), fresh.astype(jnp.int32)], axis=1)[row]
-    tile_slot, tile_fresh = of_tile[:, 0], of_tile[:, 1]
-    if use_pallas or interpret:
-        o, pool = _kda_chunks_pallas(operands, pool, tile_slot, first.astype(jnp.int32), tile_fresh, n_tiles.reshape(1),
-                                     _head_block(H), interpret)
-    else:
-        def step(carry, x):
-            pool, S = carry
-            *tile, s, is_first, is_fresh, live = x
-            S0 = jnp.where(is_first, jnp.where(is_fresh > 0, 0.0, pool[s]), S)
-            o, S = jax.vmap(_tile_math)(S0, *tile)
-            S = jnp.where(live, S, S0)
-            return (pool.at[jnp.where(live, s, pool.shape[0])].set(S, mode="drop"), S), o
+    row, tok0, cnt, first, n_tiles = tile_plan(n_tok, T)
+    NB = min(_TILE_BLOCK, row.shape[0])
+    pad = -row.shape[0] % NB   # whole blocks: a padding tile is one more dead one
+    row = jnp.pad(row, (0, pad), mode="edge")
+    tok0, cnt, first = (jnp.pad(a, (0, pad)) for a in (tok0, cnt, first))
+    NT = row.shape[0]
+    of_tile = jnp.stack([slot, fresh], axis=1)[row]
+    c = jnp.arange(TILE, dtype=jnp.int32)
+    hb = _head_block(H)
 
-        live = jnp.arange(NT) < n_tiles
-        (pool, _), o = lax.scan(step, (pool, jnp.zeros((H, dk, dv), jnp.float32)),
-                                (*operands, tile_slot, first, tile_fresh, live))
-    # back to the flat order: token t of row r lies at tile (row r's first tile + i // C), place i % C
-    o = jnp.swapaxes(o, 1, 2).reshape(NT * TILE, H, dv)
-    n_tok = n_tok.astype(jnp.int32)
-    ends = jnp.cumsum(n_tok)
-    tiles_of = -(-n_tok // TILE)
+    def block(i, carry):
+        """Tiles ``[i NB, (i + 1) NB)``: their operands laid by XLA, ``[NB, H, C, d]`` each (zeros where no token
+        is), through the kernel, their ``o`` into its place among the tiles'. A row that began in an earlier
+        block goes on from what that block left in the pool."""
+        pool, o_tiles = carry
+        cut = lambda a: lax.dynamic_slice_in_dim(a, i * NB, NB)
+        at = jnp.where(c[None, :] < cut(cnt)[:, None], cut(tok0)[:, None] + c[None, :], T).reshape(-1)   # a dead token reads the fill
+        tiled = jnp.swapaxes(jnp.take(flat, at, axis=0, mode="fill", fill_value=0.0).reshape(NB, TILE, H, -1), 1, 2)
+        operands = jnp.split(tiled, (dk, 2 * dk, 3 * dk, 3 * dk + dv), axis=-1)
+        opens = cut(first)
+        from_pool = opens | (jnp.arange(NB) == 0)
+        tile_slot, tile_fresh = cut(of_tile[:, 0]), jnp.where(opens, cut(of_tile[:, 1]), 0)
+        live = jnp.clip(n_tiles - i * NB, 0, NB)
+        if use_pallas or interpret:
+            o, pool = _kda_chunks_pallas(operands, pool, tile_slot, from_pool.astype(jnp.int32), tile_fresh, live.reshape(1),
+                                         hb, interpret)
+        else:
+            def step(carry, x):
+                pool, S = carry
+                *tile, s, reads, is_fresh, is_live = x
+                S0 = jnp.where(reads, jnp.where(is_fresh > 0, 0.0, pool[s]), S)
+                o, S = jax.vmap(_tile_math)(S0, *tile)
+                S = jnp.where(is_live, S, S0)
+                return (pool.at[jnp.where(is_live, s, pool.shape[0])].set(S, mode="drop"), S), o
+
+            (pool, _), o = lax.scan(step, (pool, jnp.zeros((H, dk, dv), jnp.float32)),
+                                    (*operands, tile_slot, from_pool, tile_fresh, jnp.arange(NB) < live))
+        o = jnp.swapaxes(o, 1, 2).reshape(NB * TILE, H, dv)
+        return pool, lax.dynamic_update_slice_in_dim(o_tiles, o, i * NB * TILE, axis=0)
+
+    # as many blocks as hold a live tile, and no more: what laying tiles costs follows the chunk rows' tokens
+    pool, o = lax.fori_loop(0, -(-n_tiles // NB), block, (pool, jnp.zeros((NT * TILE, H, dv), jnp.float32)))
+    # back to the flat order: token t of a chunk row r lies at tile (row r's first tile + i // C), place i % C;
+    # the token of a one-token row where the step left its row
+    tiles_of = _row_tiles(n_tok)
     t = jnp.arange(T, dtype=jnp.int32)
-    r = jnp.minimum(jnp.sum((ends[None, :] <= t[:, None]).astype(jnp.int32), axis=1), n_tok.shape[0] - 1)
-    of_tok = jnp.stack([ends - n_tok, jnp.cumsum(tiles_of) - tiles_of], axis=1)[r]   # the row's first token and first tile
-    return o[jnp.clip(of_tok[:, 1] * TILE + t - of_tok[:, 0], 0, NT * TILE - 1)], pool
+    r = jnp.minimum(jnp.sum(((starts + n_tok)[None, :] <= t[:, None]).astype(jnp.int32), axis=1), n_tok.shape[0] - 1)
+    of_tok = jnp.stack([starts, jnp.cumsum(tiles_of) - tiles_of, place1, n_tok], axis=1)[r]   # ONE gather a token
+    o = o[jnp.clip(of_tok[:, 1] * TILE + t - of_tok[:, 0], 0, NT * TILE - 1)]
+    return jnp.where((of_tok[:, 3] == 1)[:, None, None], o1[of_tok[:, 2]], o), pool

@@ -201,24 +201,33 @@ def _rule_inputs(n, H=4, d=16, seed=0):
     return [jnp.asarray(x, jnp.float32) for x in (q, k, rng.normal(size=(n, H, d)), g, b)]
 
 
+def _chunks_against_the_rule(n_tok, slots, fresh, interpret, T=64, seed=0):
+    """``kda_chunks`` over one ragged batch of ``T`` flat tokens from a pool of
+    12 random slots: every fed row's outputs and final state against the rule
+    token by token (a fresh row from zero, the others from what their slots
+    held), and every slot no fed row names bit-equal."""
+    x = _rule_inputs(T, seed=seed)
+    pool = jnp.asarray(np.random.default_rng(1).normal(size=(12, 4, 16, 16)), jnp.float32)
+    o, new = jax.jit(lambda *a: kda.kda_chunks(*a, interpret=interpret))(*x, pool, jnp.asarray(slots), jnp.asarray(fresh),
+                                                                         jnp.asarray(n_tok))
+    new, untouched, t0 = np.asarray(new), np.ones(12, bool), 0
+    for r, n in enumerate(n_tok):
+        if n:
+            oo, S = kda.recurrence_reference(*[a[t0:t0 + n] for a in x], jnp.zeros((4, 16, 16)) if fresh[r] else pool[slots[r]])
+            assert float(jnp.abs(oo - o[t0:t0 + n]).max()) < 2e-6, (r, n)
+            assert float(np.abs(np.asarray(S) - new[slots[r]]).max()) < 5e-6, (r, n)
+            untouched[slots[r]] = False
+            t0 += n
+    assert np.array_equal(new[untouched], np.asarray(pool)[untouched])
+
+
 @pytest.mark.parametrize("interpret", [False, True], ids=["jnp", "kernel-body"])
 def test_the_chunkwise_form_matches_the_recurrence_at_boundaries_that_do_not_divide(interpret):
     """Rows of 13, 1, 0, 21, 1 and 8 tokens in one ragged batch of 64 (tile 8):
     outputs and final states against the rule token by token, a fresh row
     from zero, the others from what their slots held; no other slot moves."""
-    n_tok, slots, fresh = np.array([13, 1, 0, 21, 1, 8, 0, 0]), np.array([3, 0, 5, 7, 2, 9, 1, 1]), np.array([0, 1, 0, 0, 0, 1, 0, 0])
-    x = _rule_inputs(64)
-    pool = jnp.asarray(np.random.default_rng(1).normal(size=(12, 4, 16, 16)), jnp.float32)
-    o, new = jax.jit(lambda *a: kda.kda_chunks(*a, interpret=interpret))(*x, pool, jnp.asarray(slots), jnp.asarray(fresh),
-                                                                         jnp.asarray(n_tok))
-    want, t0 = np.asarray(pool).copy(), 0
-    for r, n in enumerate(n_tok):
-        if n:
-            oo, S = kda.recurrence_reference(*[a[t0:t0 + n] for a in x], jnp.zeros((4, 16, 16)) if fresh[r] else pool[slots[r]])
-            assert float(jnp.abs(oo - o[t0:t0 + n]).max()) < 2e-6
-            want[slots[r]] = np.asarray(S)
-            t0 += n
-    assert float(np.abs(want - np.asarray(new)).max()) < 5e-6
+    _chunks_against_the_rule(np.array([13, 1, 0, 21, 1, 8, 0, 0]), np.array([3, 0, 5, 7, 2, 9, 1, 1]),
+                             np.array([0, 1, 0, 0, 0, 1, 0, 0]), interpret)
 
 
 @pytest.mark.parametrize("interpret", [False, True], ids=["jnp", "kernel-body"])
@@ -238,11 +247,67 @@ def test_the_recurrent_step_advances_the_live_rows_alone(interpret, n_live):
 
 
 def test_a_tile_plan_gives_every_row_its_own_tiles():
+    """A row of two or more tokens takes its own tiles, a row of one token
+    none (the recurrent step's), and every row keeps its place in the flat
+    order: the row of 21 still starts at token 14."""
     row, tok0, cnt, first, n_tiles = kda.tile_plan(np.array([13, 1, 0, 21]), 64, xp=np)
-    assert int(n_tiles) == 2 + 1 + 0 + 3 and len(row) == 64 // 8 + 4
-    assert list(row[:6]) == [0, 0, 1, 3, 3, 3] and list(tok0[:6]) == [0, 8, 13, 14, 22, 30]
-    assert list(cnt[:6]) == [8, 5, 1, 8, 8, 5] and list(first[:6]) == [True, False, True, True, False, False]
-    assert not cnt[6:].any() and set(row[6:]) == {3}  # a dead tile names the last live one's row and holds nothing
+    assert int(n_tiles) == 2 + 0 + 0 + 3 and len(row) == 64 // 8 + 4
+    assert list(row[:5]) == [0, 0, 3, 3, 3] and list(tok0[:5]) == [0, 8, 14, 22, 30]
+    assert list(cnt[:5]) == [8, 5, 8, 8, 5] and list(first[:5]) == [True, False, True, False, False]
+    assert not cnt[5:].any() and set(row[5:]) == {3}  # a dead tile names the last live one's row and holds nothing
+
+
+# what a ragged batch may look like: (tokens a row, rows that open their sequence); the flat batch is 64 tokens
+_SPLITS = {
+    "ones-before-a-chunk": ([1, 1, 1, 13, 0, 0, 0, 0], [0, 0, 1, 0, 0, 0, 0, 0]),
+    "ones-between-chunks": ([13, 1, 1, 21, 0, 0, 0, 0], [1, 0, 0, 0, 0, 0, 0, 0]),
+    "ones-after-chunks": ([21, 10, 1, 1, 1, 0, 0, 0], [0, 1, 0, 0, 0, 0, 0, 0]),
+    "a-fresh-one-token-row": ([1, 9, 1, 0, 0, 0, 0, 0], [1, 0, 0, 0, 0, 0, 0, 0]),
+    "one-token-rows-alone": ([1, 1, 1, 1, 1, 1, 1, 1], [0, 1, 0, 0, 0, 0, 1, 0]),
+    "no-one-token-row": ([2, 13, 8, 17, 0, 0, 0, 0], [0, 1, 0, 0, 0, 0, 0, 0]),
+    "padded-rows-among-them": ([1, 0, 11, 0, 1, 0, 2, 1], [0, 0, 1, 0, 0, 1, 0, 1]),
+    "every-place-of-a-tile": ([1, 7, 1, 9, 1, 15, 1, 3], [0, 0, 0, 1, 0, 0, 0, 0]),
+    "nothing-fed": ([0, 0, 0, 0, 0, 0, 0, 0], [0, 0, 0, 0, 0, 0, 0, 0]),
+}
+
+
+@pytest.mark.parametrize("interpret", [False, True], ids=["jnp", "kernel-body"])
+@pytest.mark.parametrize("batch", list(_SPLITS))
+def test_a_ragged_batch_is_split_by_what_each_row_is_fed(batch, interpret):
+    """``kda_chunks`` sends a row fed ONE token through the recurrent step
+    and a row fed more through the chunk scan, wherever each stands: outputs
+    and states against the rule token by token, a fresh row from zero; the
+    state of a row not fed (and of every slot no row names) bit-equal."""
+    n_tok, fresh = (np.asarray(a) for a in _SPLITS[batch])
+    _chunks_against_the_rule(n_tok, np.array([3, 0, 5, 7, 2, 9, 1, 10]), fresh, interpret, seed=3)
+
+
+@pytest.mark.parametrize("interpret", [False, True], ids=["jnp", "kernel-body"])
+@pytest.mark.parametrize("block", [1, 3])
+def test_a_chunk_row_goes_on_where_the_block_of_tiles_before_it_left_its_state(monkeypatch, block, interpret):
+    """The chunk scan is called a block of tiles at a time, as many times as
+    live tiles fill blocks: with blocks of 1 and of 3 tiles, rows of 21 (fresh),
+    1, 13, 1, 0 and 17 tokens (3 + 2 + 3 tiles) cross block boundaries in the
+    middle of a row, and outputs and states are what the rule gives."""
+    monkeypatch.setattr(kda, "_TILE_BLOCK", block)
+    _chunks_against_the_rule(np.array([21, 1, 13, 1, 0, 17]), np.array([3, 0, 5, 7, 2, 9]), np.array([1, 0, 0, 1, 0, 0]),
+                             interpret, T=56, seed=4)
+
+
+@pytest.mark.parametrize("batch", list(_SPLITS))
+def test_no_tile_holds_a_one_token_row_and_the_step_takes_them_all(batch):
+    """The chunk scan's live tiles count the rows of two or more tokens
+    alone; the step's live rows are the one-token rows, in order, each at the
+    place the plan says."""
+    n_tok = np.asarray(_SPLITS[batch][0])
+    row, tok0, cnt, first, n_tiles = kda.tile_plan(n_tok, 64, xp=np)
+    rows, place, n_live = kda.step_rows(n_tok, xp=np)
+    ones = np.flatnonzero(n_tok == 1)
+    assert int(n_live) == len(ones) and list(rows[:n_live]) == list(ones) and list(place[ones]) == list(range(len(ones)))
+    assert int(n_tiles) == sum(-(-n // 8) for n in n_tok if n > 1)
+    assert not set(row[:n_tiles]) & set(ones) and int(cnt.sum()) == sum(n for n in n_tok if n > 1)
+    starts = np.cumsum(n_tok) - n_tok   # every tile's first token lies in its own row's run of the flat order
+    assert all(starts[r] <= t < starts[r] + n_tok[r] for r, t in zip(row[:n_tiles], tok0[:n_tiles]))
 
 
 def test_the_shares_add_up(tiny):
@@ -347,10 +412,11 @@ def test_what_takes_state_to_be_blocks_is_refused_by_name(tiny, engine, call):
 
 
 def test_a_step_span_says_what_the_state_layers_and_the_one_kv_layer_had_to_do(tiny, engine, tmp_path):
-    """``state_rows``, ``state_bytes``, ``lin_tokens`` and the slots on the step
-    spans, and ``attn_pairs`` / ``attn_ctx_tokens`` counted over the ONE layer
-    that caches K and V, by hand: a 13-token chunk after 32 cached tokens
-    beside a one-token row at 5, then a decode horizon of 4."""
+    """``state_rows``, ``state_rows_stepped`` (of them, the rows whose state
+    went through the recurrent step), ``state_bytes``, ``lin_tokens`` and the
+    slots on the step spans, and ``attn_pairs`` / ``attn_ctx_tokens`` counted
+    over the ONE layer that caches K and V, by hand: a 13-token chunk after 32
+    cached tokens beside a one-token row at 5, then a decode horizon of 4."""
     from benchmark.lib import program_spans
 
     cfg, params, ids = tiny
@@ -377,10 +443,15 @@ def test_a_step_span_says_what_the_state_layers_and_the_one_kv_layer_had_to_do(t
     assert (prefill["attn_pairs"], prefill["attn_ctx_tokens"]) == (sum(32 + i + 1 for i in range(13)) + 6, 45 + 6)
     assert prefill["kv_entry_bytes"] == 2 * 2 * 16 * 4
     assert (prefill["state_rows"], prefill["lin_tokens"], prefill["state_entry_bytes"]) == (2, 3 * 14, entry)
+    assert prefill["state_rows_stepped"] == 1   # the mixed put: the one-token row is the step's, the chunk the scan's
     assert prefill["state_bytes"] == 2 * 3 * entry * 2
     assert (prefill["state_slots_live"], prefill["state_slots_total"]) == (2, 4)
-    assert prefill["kernel"].endswith("kda_chunk_scan:8:ragged")
+    assert prefill["kernel"].endswith("kda_chunk_scan:8:ragged+kda_recurrent_step:1:one-token-rows")
+    # the two puts before it fed a chunk each and no one-token row
+    assert [s.args["state_rows_stepped"] for s in program_spans.spans_named(trace, "serving/prefill")] == [0, 0, 1]
     (decode, ) = program_spans.spans_named(trace, "serving/decode")
     assert decode.args["attn_pairs"] == sum(45 + j + 1 for j in range(4)) + sum(6 + j + 1 for j in range(4))
     assert (decode.args["state_rows"], decode.args["lin_tokens"], decode.args["state_bytes"]) == (8, 24, 8 * 3 * entry * 2)
+    assert decode.args["state_rows_stepped"] == 8   # a horizon: every row x step
     assert decode.args["kernel"].endswith("kda_recurrent_step:1:one-token-rows")
+    assert "kda_chunk_scan" not in decode.args["kernel"]

@@ -1024,19 +1024,28 @@ def test_the_expanded_call_over_pools_by_head_at_the_cells_shapes_on_chip(before
     assert np.linalg.norm(got - ref) / np.linalg.norm(ref) < 5e-3
 
 
-@pytest.mark.parametrize("form", ["recurrent_step", "chunk_scan"])
+# tokens a row of the ragged forms, 128 rows in a 512-token program
+_DELTA_RULE_BATCHES = {"chunk_scan": [401] + [0] * 127, "mixed-401+100x1": [401] + [1] * 100 + [0] * 27,
+                       "mixed-127x1+385": [1] * 127 + [385]}
+
+
+@pytest.mark.parametrize("form", ["recurrent_step", *_DELTA_RULE_BATCHES])
 def test_the_delta_rules_kernels_at_the_cells_shapes_on_chip(form):
     """``ops/pallas/kda.py`` at ``solar-open2-250b.decode-heavy-128``'s widths (64
-    heads of 128 x 128, float32 state): the recurrent step of 128 one-token rows
-    and the chunk scan of a 401-token chunk beside 100 one-token rows in a
-    512-token program, each against the rule token by token from the same pool,
-    with microseconds a call and the state's bytes over them."""
+    heads of 128 x 128, float32 state): the recurrent step of 128 one-token
+    rows (``kda_step``), and ``kda_chunks`` over a 401-token chunk alone (the
+    chunk scan's 51 tiles and no step row), over that chunk beside 100
+    one-token rows (the shape PR 41 timed at 7,123 us a call, when each of
+    those rows was a tile of the chunk scan; they go through the recurrent
+    step since PR 44) and over 127 one-token rows BEFORE a 385-token chunk (a
+    full house as the window has it), in a 512-token program. Each against the
+    rule token by token from the same pool, with microseconds a call and the
+    state's bytes over them."""
     from deepspeed_tpu.ops.pallas import kda
 
     H, d, slots = 64, 128, 160
     rng = np.random.default_rng(0)
-    T, n_tok = (128, np.ones(128, np.int64)) if form == "recurrent_step" else (
-        512, np.asarray([401] + [1] * 100 + [0] * 27))
+    T, n_tok = (128, np.ones(128, np.int64)) if form == "recurrent_step" else (512, np.asarray(_DELTA_RULE_BATCHES[form]))
     q, k = rng.normal(size=(2, T, H, d))
     q = q / np.linalg.norm(q, axis=-1, keepdims=True) / np.sqrt(d)
     k = k / np.linalg.norm(k, axis=-1, keepdims=True)
@@ -1046,18 +1055,21 @@ def test_the_delta_rules_kernels_at_the_cells_shapes_on_chip(form):
     pool = jnp.asarray(rng.normal(size=(slots, H, d, d)), jnp.float32)
     slot = jnp.asarray(rng.permutation(slots)[:128], jnp.int32)
     fresh = jnp.asarray(rng.integers(0, 2, size=128), jnp.int32)
+    # the batch as ARGUMENTS, as the engine hands it over: closed over, XLA folds the plan into constants
     if form == "recurrent_step":
-        fn = lambda q, k, v, g, b, pool: kda.kda_step(q, k, v, g, b, pool, slot, fresh, 128, use_pallas=True)
+        fn = lambda q, k, v, g, b, pool, slot, fresh, n: kda.kda_step(q, k, v, g, b, pool, slot, fresh, jnp.sum(n),
+                                                                      use_pallas=True)
     else:
-        fn = lambda q, k, v, g, b, pool: kda.kda_chunks(q, k, v, g, b, pool, slot, fresh, jnp.asarray(n_tok),
-                                                        use_pallas=True)
-    o, new = jax.jit(fn)(*x, pool)
+        fn = lambda q, k, v, g, b, pool, slot, fresh, n: kda.kda_chunks(q, k, v, g, b, pool, slot, fresh, n,
+                                                                        use_pallas=True)
+    batch = (slot, fresh, jnp.asarray(n_tok, jnp.int32))
+    o, new = jax.jit(fn)(*x, pool, *batch)
     in_place = jax.jit(fn, donate_argnums=5)  # as the engine calls it: the pool donated and advanced where it lies
-    _, carried = in_place(*x, pool + 0.0)
+    _, carried = in_place(*x, pool + 0.0, *batch)
     jax.block_until_ready(carried)
     t0 = time.perf_counter()
     for _ in range(10):
-        _, carried = in_place(*x, carried)
+        _, carried = in_place(*x, carried, *batch)
     jax.block_until_ready(carried)
     us = (time.perf_counter() - t0) / 10 * 1e6
     rows = int((n_tok > 0).sum())
@@ -1067,7 +1079,7 @@ def test_the_delta_rules_kernels_at_the_cells_shapes_on_chip(form):
     for r, n in enumerate(n_tok):
         if n:
             S0 = jnp.zeros((H, d, d)) if int(fresh[r]) else pool[slot[r]]
-            if r < 3 or r % 37 == 0:  # the long row and a few of the others, token by token
+            if r < 3 or r % 37 == 0 or n > 1:  # the long row and a few of the others, token by token
                 oo, S = kda.recurrence_reference(*[a[t0:t0 + n] for a in x], S0)
                 assert float(jnp.abs(oo - o[t0:t0 + n]).max()) < 1e-4
                 assert float(jnp.abs(S - new[slot[r]]).max()) < 1e-4
