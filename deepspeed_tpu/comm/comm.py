@@ -276,12 +276,12 @@ def _eagerize(func):
     the call is wrapped in a one-off ``shard_map`` over the current mesh
     (operands replicated), jitted, executed and cached by shape — the
     torch.distributed ergonomics, and what lets ``timed_op`` wall-time a real
-    device collective (``bench.py --trace``'s comm spans). Inside jit, or
+    device collective (the tracer's ``comm/*`` spans). Inside jit, or
     with no mesh initialized, the call passes through untouched.
 
     Caveat: the FIRST eager call per (op, shape, dtype, group) includes the
     jit compile in its wall time — discard or warm past that sample when
-    deriving steady-state bandwidth (bench.py does)."""
+    deriving steady-state bandwidth."""
     name = func.__name__
     sig = inspect.signature(func)
     cache = {}

@@ -29,7 +29,7 @@ items sizeable, in two halves:
     the hit rate improve if the pool were 4x bigger" from a dashboard
     instead of a guess. Validated against an exact LRU stack-distance
     simulation in ``tests/test_cache_telemetry.py`` and against the live
-    measured hit rate by ``tools/serving_load.py cache_pressure``.
+    measured hit rate by ``tools/serving_load.py``'s ``cache_pressure_bench``.
 
 Zero overhead when the ``ragged.prefix_cache.telemetry`` block is absent:
 no CacheTelemetry object exists anywhere, every hook site is a single
@@ -230,7 +230,7 @@ class CacheTelemetry:
                                              buckets=AGE_BUCKETS_S)
         # tier migration latency distributions: promote is the admission-
         # side wait a request actually eats (headline p50/p99 in the
-        # serving_load host_tier A/B); demote is worker-side queue+copy time
+        # ``tools/serving_load.py``'s ``host_tier_ab``); demote is worker-side queue+copy time
         self.promote_latency_s = Histogram("cache/promote_latency_s",
                                            buckets=AGE_BUCKETS_S)
         self.demote_latency_s = Histogram("cache/demote_latency_s",
@@ -454,8 +454,8 @@ class CacheTelemetry:
         return rows
 
     def snapshot(self) -> dict:
-        """One JSON-able dict: the bench/tool surface (``bench.py``'s
-        ``cache{...}`` block and ``serving_load.py cache_pressure``)."""
+        """One JSON-able dict: the tool surface (``tools/serving_load.py``'s
+        ``cache_pressure_bench`` returns it as ``telemetry``)."""
         return {
             "counters": dict(self.counters),
             "classes": self.refcount_classes(),

@@ -23,8 +23,8 @@ and three export paths, all existing PR 1/5 surfaces: the health exporter
 renders :meth:`MemoryAttribution.gauge_rows` as labelled
 ``memory/hbm_bytes{section=...}`` gauges on ``/metrics``, every forensic
 stall dump gains a ``memory`` section (registered by
-``HealthPlane.configure``), and ``bench.py`` prints the report as the final
-JSON's ``memory{...}`` block.
+``HealthPlane.configure``), and :func:`hbm_report` returns the same report
+as one dict.
 
 Import-light (stdlib only at module level; jax imported lazily per report).
 """
@@ -164,5 +164,5 @@ def get_memory() -> MemoryAttribution:
 
 def hbm_report() -> dict:
     """Module-level convenience: the current process-wide HBM attribution
-    (what ``bench.py`` prints and every forensic dump carries)."""
+    (what every forensic dump carries)."""
     return _memory.report()

@@ -203,8 +203,8 @@ def _abstract_signature(args):
 def cost_analysis_dict(compiled):
     """``compiled.cost_analysis()`` normalized to ONE flat dict — older jax
     wraps the result in a single-element list. The shared extraction used
-    here, by ``profiling/flops_profiler.py`` and ``tools/decode_profile.py``,
-    so every cost consumer in the repo reads the same keys."""
+    here and by ``profiling/flops_profiler.py``, so every cost consumer in
+    the repo reads the same keys."""
     cost = compiled.cost_analysis()
     if isinstance(cost, list):
         cost = cost[0] if cost else {}
@@ -438,8 +438,8 @@ class RooflinePlane:
         self._registry.note_wall(bucket, seconds)
 
     def register_fn(self, bucket, fn, *example_args, mesh=None):
-        """Tools entry (``tools/decode_profile.py``): register ``bucket``'s
-        cost from a jit-wrapped callable + example (or abstract) args."""
+        """Tools entry: register ``bucket``'s cost from a jit-wrapped
+        callable + example (or abstract) args."""
         if not self.enabled or self._registry is None:
             return
         self._registry.register_lazy(bucket, fn,

@@ -13,9 +13,9 @@ cause.
 
 Pure functions over plain dicts, import-light (no jax, no serving
 imports): the serving-side :class:`~deepspeed_tpu.serving.timeline.
-TimelineCollector` feeds it live requests; ``tools/trace_explain.py``
-feeds it two captured populations and diffs them. Everything here is
-unit-testable without a gateway.
+TimelineCollector` feeds it live requests; :func:`explain_delta` takes two
+captured populations and diffs them. Everything here is unit-testable
+without a gateway.
 
 Segment model
 -------------
@@ -233,7 +233,7 @@ def assemble_timeline(stamps, record=None, stalls=(), recompiles=(),
 
 
 # ---------------------------------------------------------------------------
-# population diff: the differential-explain model (tools/trace_explain.py)
+# population diff: the differential-explain model
 # ---------------------------------------------------------------------------
 def stage_totals(timeline) -> Dict[str, float]:
     """Per-stage milliseconds of ONE timeline (segments with the same name

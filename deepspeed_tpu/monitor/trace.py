@@ -24,7 +24,7 @@ the flops profiler and the torch profiler hooks, unified here into one bus:
     ``jax_compile`` duration events on the ``compile`` stream.
 
 Output is JSONL: one Chrome-trace event object per line, each independently
-``json.loads``-able (the acceptance format for ``bench.py --trace``). The
+``json.loads``-able. The
 ``trace_viewer`` JSON-array form for chrome://tracing or Perfetto is one
 ``to_chrome_trace`` call away.
 

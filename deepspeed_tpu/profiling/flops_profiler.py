@@ -17,7 +17,7 @@ def training_flops_per_token(n_params, num_layers=None, hidden_size=None, seq_le
     """Model training FLOPs per token, PaLM convention: 6 FLOPs per parameter
     (fwd 2 + bwd 4) plus the attention score/context term when the
     architecture is known. The numerator of every MFU this repo reports
-    (``monitor/metrics.py::compute_mfu``, engine step telemetry, bench.py)."""
+    (``monitor/metrics.py::compute_mfu``, engine step telemetry)."""
     flops = 6.0 * float(n_params)
     if num_layers and hidden_size and seq_len:
         flops += 12.0 * num_layers * hidden_size * seq_len

@@ -207,7 +207,7 @@ class TPUConfig(DeepSpeedConfigModel):
     # with shardings — nothing materializes), so pod-scale configs (7B/70B on
     # a 128-device mesh) can be AOT-lowered/compiled on hosts that could
     # never hold the weights. train_batch() is unusable in this mode; use
-    # aot_lower_train_step() (tools/pod_validate.py)
+    # aot_lower_train_step()
     abstract_init: bool = False
     # device trace capture (the TPU analog of the reference's torch-profiler
     # hooks): captures a perfetto/XPlane trace of global steps

@@ -9,8 +9,8 @@ forensic surface the repo already has:
     ``RequestLog`` chassis — the log can never grow unbounded);
   * an in-memory ring (``GET /v1/control`` + the health plane's stall
     dump provider read it without touching the file);
-  * ``control/*`` Prometheus counters (``tools/perf_sentinel.py``
-    audits the controller through these);
+  * ``control/*`` Prometheus counters (the controller is audited
+    through these);
   * a ``control/decision`` tracer instant + flight-recorder breadcrumb
     (the decision lands in the same timeline as the requests it
     affected).

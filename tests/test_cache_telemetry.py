@@ -426,7 +426,7 @@ def test_cache_pressure_mrc_accuracy():
     real eviction pressure."""
     from tools.serving_load import cache_pressure_bench
 
-    out = cache_pressure_bench(False, n_requests=96, seed=0)
+    out = cache_pressure_bench(n_requests=96, seed=0)
     assert out["evictions"] > 0, "the pressure workload must actually evict"
     assert out["measured_hit_rate"] is not None and out["mrc_predicted_1x"] is not None
     assert out["mrc_abs_err_1x"] <= 0.05, \

@@ -9,9 +9,8 @@ replica drain/undrain skipped by the router and the disagg decode picker,
 copy-on-write speculative K updates); the decision pass itself, driven
 tick-by-tick with synthetic sensor deltas so hysteresis, sustain,
 cooldown, and the global flap budget are asserted deterministically; the
-bounded JSONL decision log; the ``tools/check_control_actuators.py`` AST
-gate (clean on the live tree AND catches seeded drift); and the
-perf_sentinel direction table for the new ``control/*`` leaves.
+bounded JSONL decision log; and the ``tools/check_control_actuators.py`` AST
+gate (clean on the live tree AND catches seeded drift).
 """
 
 import json
@@ -36,7 +35,7 @@ from tools.serving_load import build_engine, build_gateway
 
 @pytest.fixture(scope="module")
 def direct_engine():
-    return build_engine(on_tpu=False)
+    return build_engine()
 
 
 def _get(port, path):
@@ -327,7 +326,7 @@ def test_ewma_smooths_bursty_idle_band_walk(direct_engine):
     band and the drain fires. The snapshot keeps BOTH values (idle_frac
     vs idle_frac_raw) so decision records stay auditable, and the applied
     record carries the satellite-3 inflight_rids roster."""
-    eng2 = build_engine(on_tpu=False)
+    eng2 = build_engine()
     try:
         applied_by_alpha = {}
         drains = None
@@ -480,17 +479,8 @@ def test_control_actuator_gate_catches_drift(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# the sentinel learned the new leaves; metric namespace admits control/*
+# the metric namespace admits control/*
 # ---------------------------------------------------------------------------
-def test_perf_sentinel_directions_for_control_leaves():
-    from tools.perf_sentinel import metric_direction
-
-    assert metric_direction("control.slo_miss_rate") == "lower"
-    assert metric_direction("control.fg_on_miss_rate") == "lower"
-    assert metric_direction("control.actuations") is None
-    assert metric_direction("control.deferred") is None
-
-
 def test_metric_namespace_admits_control_prefix():
     from tools.check_metric_names import APPROVED_PREFIXES, check
 

@@ -266,7 +266,7 @@ def test_load_module_state_dict_resets_offload_masters(eight_devices, tmp_path):
 
 
 def test_abstract_init_aot_lower(eight_devices):
-    """Compile-only validation path (tools/pod_validate.py): with
+    """Compile-only validation path: with
     tpu.abstract_init nothing materializes — the state is ShapeDtypeStructs
     with real shardings — and aot_lower_train_step builds the full fused
     train step abstractly. The compiled result must run GSPMD partitioning

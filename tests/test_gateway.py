@@ -30,12 +30,12 @@ from deepspeed_tpu.monitor.metrics import get_metrics
 from deepspeed_tpu.serving import (GatewayConfig, ServingGateway, SLOClassConfig,
                                    TokenStream, parse_sse, sse_frame)
 from tools.serving_load import (build_engine, build_gateway, make_workload,
-                                router_prefix_ab)
+                                router_prefix_ab, run_http_load)
 
 
 @pytest.fixture(scope="module")
 def direct_engine():
-    return build_engine(on_tpu=False)
+    return build_engine()
 
 
 @pytest.fixture(scope="module")
@@ -231,6 +231,45 @@ def test_backpressure_sheds_429_at_depth(direct_engine):
     assert not leaked, [t.name for t in leaked]
 
 
+@pytest.mark.parametrize("stream", [True, False], ids=["sse", "blocking"])
+def test_load_client_counts_every_terminal_once(direct_engine, stream):
+    """The client the drills and the tests trust (``run_http_load``): against a
+    class queue bounded at two and a replica that pulls nothing until ten are
+    shed, two requests wait and complete, ten are shed at once, and each of
+    the twelve is one record of exactly one kind in the aggregate."""
+    cfg = GatewayConfig(enabled=True, slo_classes={"interactive": SLOClassConfig(max_queue_depth=2)})
+    g = ServingGateway([direct_engine], cfg).start()
+
+    def resume_when_ten_are_shed():
+        deadline = time.time() + 60
+        while g.admission.stats["shed"] < 10 and time.time() < deadline:
+            time.sleep(0.005)
+        g.replicas[0].resume()
+
+    try:
+        g.replicas[0].pause()
+        resume = threading.Thread(target=resume_when_ten_are_shed)
+        resume.start()
+        wl = _wl(12, seed=4, uid_base=7000)
+        agg, recs = run_http_load(g.config.host, g.port, wl, concurrency=6, stream=stream)
+        resume.join()
+    finally:
+        g.stop()
+    assert len(recs) == agg["n_requests"] == 12
+    assert sorted(r["uid"] for r in recs) == [r["uid"] for r in wl]
+    done = [r for r in recs if r["status"] == 200]
+    shed = [r for r in recs if r["status"] == 429]
+    assert (agg["completed"], agg["shed"], agg["errors"]) == (len(done), len(shed), 0) == (2, 10, 0)
+    assert agg["shed_rate"] == round(10 / 12, 3)
+    want = {r["uid"]: r["max_new_tokens"] for r in wl}
+    for r in done:
+        assert r["error"] is None and len(r["tokens"]) == want[r["uid"]] and r["ttft_ms"] > 0
+    for r in shed:  # a miss with its reason and a hint, answered while the replica still slept: never a hang
+        assert r["error"] == "shed" and r["tokens"] == [] and r["ttft_ms"] is None
+        assert r["retry_after"] is not None and r["latency_ms"] < min(d["latency_ms"] for d in done)
+        assert r["request_id"] == f"load-{r['uid']}"
+
+
 # ---------------------------------------------------------------------------
 # abandonment: timeouts/disconnects release engine-side resources
 # ---------------------------------------------------------------------------
@@ -318,7 +357,7 @@ def test_slow_consumer_does_not_stall_decode(gw):
 # router: prefix affinity strictly beats random placement (ISSUE acceptance)
 # ---------------------------------------------------------------------------
 def test_router_prefix_affinity_beats_random(gw):
-    out = router_prefix_ab(on_tpu=False, n_requests=16, seed=3, gateway=gw)
+    out = router_prefix_ab(n_requests=16, seed=3, gateway=gw)
     assert out["token_parity"], "placement changed the generations"
     arms = out["arms"]
     assert arms["prefix"]["aggregate_hit_rate"] > arms["random"]["aggregate_hit_rate"], arms
@@ -496,7 +535,7 @@ def test_gateway_tree_spec_greedy_parity_with_direct_engine():
     prompts = [motif + rng.integers(0, 128, size=3).tolist() + motif + motif
                for _ in range(3)]
 
-    direct = _be(on_tpu=False)
+    direct = _be()
     want = []
     for i, p in enumerate(prompts):
         got = [int(np.asarray(direct.put([i + 1], [p], sample="greedy")).reshape(-1)[0])]
@@ -507,7 +546,7 @@ def test_gateway_tree_spec_greedy_parity_with_direct_engine():
         want.append(got)
 
     spec = SpeculativeConfig(mode="ngram", k=3, min_match=1, tree_width=3)
-    eng = _be(on_tpu=False, prefix_cache=True, speculative=spec)
+    eng = _be(prefix_cache=True, speculative=spec)
     g = ServingGateway([eng], GatewayConfig(enabled=True, port=0)).start()
     try:
         for p, w in zip(prompts, want):

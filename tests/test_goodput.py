@@ -17,13 +17,9 @@ The PR 14 acceptance bars, test-enforced:
   absent;
 * **taxonomy gate** — ``tools/check_goodput_taxonomy.py`` finds no
   unclassified tracer span in the engine/serving/resilience trees (and
-  does flag a planted one);
-* **trajectory reader** — ``tools/perf_sentinel.py`` aggregates synthetic
-  BENCH_r*.json rounds, flags regressions by metric direction, refuses
-  cross-backend pairs, and tolerates failed rounds.
+  does flag a planted one).
 """
 
-import json
 import os
 import sys
 import threading
@@ -557,77 +553,3 @@ def test_taxonomy_gate_flags_planted_violations(tmp_path):
     assert "not in goodput SPAN_TO_CATEGORY" in reasons  # unclassified span
     assert "dynamic span name" in reasons           # f-string emission
     assert why  # sanity: structured rows carry names/snippets
-
-
-# ---------------------------------------------------------------------------
-# perf_sentinel: the BENCH_r*.json trajectory reader
-# ---------------------------------------------------------------------------
-def _write_round(d, n, parsed, rc=0):
-    with open(os.path.join(d, f"BENCH_r{n:02d}.json"), "w") as f:
-        json.dump({"n": n, "cmd": "bench", "rc": rc, "tail": "", "parsed": parsed}, f)
-
-
-def test_perf_sentinel_trajectory_and_regression(tmp_path):
-    from tools.perf_sentinel import metric_direction, trajectory_verdicts
-
-    d = str(tmp_path)
-    _write_round(d, 1, {"metric": "m", "value": 100.0, "backend": "tpu",
-                        "chip": "v5e", "serving": {"ttft_p50_ms": 10.0}})
-    _write_round(d, 2, None, rc=1)  # failed round: a gap, not a crash
-    _write_round(d, 3, {"metric": "m", "value": 80.0, "backend": "tpu",
-                        "chip": "v5e", "serving": {"ttft_p50_ms": 25.0}})
-    rep = trajectory_verdicts(d, threshold=0.9)
-    assert [r["round"] for r in rep["rounds"]] == [1, 2, 3]
-    assert rep["rounds"][1]["parsed"] is False
-    assert rep["series"]["value"] == [(1, 100.0), (3, 80.0)]
-    verd = {v["metric"]: v for v in rep["verdicts"]}
-    assert verd["value"]["verdict"] == "regressed"          # higher-better fell
-    assert verd["serving.ttft_p50_ms"]["verdict"] == "regressed"  # latency rose
-    assert verd["value"]["prev_round"] == 1 and verd["value"]["cur_round"] == 3
-    assert rep["regressions"] == 2
-    assert metric_direction("a.b.decode_tok_s") == "higher"
-    assert metric_direction("x_ms") == "lower"
-    assert metric_direction("mystery") is None
-    # accounting fields are NEUTRAL: a longer run is not a regression
-    assert metric_direction("goodput.train.wall_s") is None
-    assert metric_direction("goodput.bench.fractions.idle") is None
-    assert metric_direction("unattributed_s") is None
-    assert metric_direction("chaos.recovery_badput_s") is None
-
-
-def test_perf_sentinel_refuses_cross_backend(tmp_path):
-    from tools.perf_sentinel import trajectory_verdicts
-
-    d = str(tmp_path)
-    _write_round(d, 1, {"metric": "m", "value": 100.0, "backend": "tpu", "chip": "v5e"})
-    _write_round(d, 2, {"metric": "m", "value": 5.0, "backend": "cpu"})
-    rep = trajectory_verdicts(d)
-    assert rep["regressions"] == 0 and rep["refused"] >= 1
-    assert all(v["verdict"] == "refused" and "cross-backend" in v["refused"]
-               for v in rep["verdicts"])
-
-
-def test_perf_sentinel_cli_strict_exit(tmp_path):
-    from tools.perf_sentinel import main as sentinel_main
-
-    d = str(tmp_path)
-    _write_round(d, 1, {"metric": "m", "value": 100.0, "backend": "cpu"})
-    _write_round(d, 2, {"metric": "m", "value": 50.0, "backend": "cpu"})
-    out = str(tmp_path / "v.json")
-    assert sentinel_main([d, "--strict", "--out", out]) == 1
-    with open(out) as f:
-        rep = json.load(f)
-    assert rep["regressions"] == 1
-    assert sentinel_main([d, "--threshold", "0.4"]) == 0  # tolerant threshold
-
-
-def test_bench_comparability_refusal_core():
-    from bench import comparability_refusal
-
-    tpu = {"backend": "tpu", "chip": "v5e"}
-    assert comparability_refusal(tpu, {"backend": "tpu", "chip": "v5e"}) is None
-    assert "cross-backend" in comparability_refusal(tpu, {"backend": "cpu"})
-    assert "cross-chip" in comparability_refusal(tpu, {"backend": "tpu", "chip": "v4"})
-    assert "no backend stamp" in comparability_refusal({}, tpu)
-    # pre-r06 on_tpu fallback still comparable
-    assert comparability_refusal({"on_tpu": True}, {"backend": "tpu"}) is None

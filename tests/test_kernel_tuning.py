@@ -999,43 +999,3 @@ def test_overlap_flag_cleared_for_reused_model():
     }
     deepspeed_tpu.initialize(model=model, config=config)
     assert model.config.overlap_gather is False
-
-
-# ---------------------------------------------------------------------------
-# bench backend stamp + cross-backend refusal
-# ---------------------------------------------------------------------------
-
-def test_bench_backend_stamp_and_cross_backend_refusal(tmp_path):
-    """The BENCH_r04/r05 caveat made machine-checkable: the final JSON is
-    backend+chip stamped, and compare_to_baseline REFUSES ratios across
-    backends (and across chips), including legacy baselines judged by
-    on_tpu, while a stampless pre-r06 baseline is refused outright."""
-    import bench
-
-    line = {"metric": "train_tokens_per_sec_per_chip", "value": 100.0,
-            **bench.backend_stamp(False)}
-    assert line["backend"] == "cpu" and line["chip"] == "cpu"
-
-    p = tmp_path / "b.json"
-    p.write_text(json.dumps({"value": 200.0, "backend": "tpu", "chip": "TPU v5 lite"}))
-    res = bench.compare_to_baseline(line, str(p))
-    assert "cross-backend" in res.get("refused", "")
-
-    p.write_text(json.dumps({"value": 50.0, "backend": "cpu", "chip": "cpu"}))
-    assert bench.compare_to_baseline(line, str(p))["ratio"] == 2.0
-
-    # the driver's BENCH_rXX wrapper with only the on_tpu disclosure (r04/r05)
-    p.write_text(json.dumps({"parsed": {"value": 100.0, "on_tpu": False}}))
-    assert bench.compare_to_baseline(line, str(p))["ratio"] == 1.0
-    p.write_text(json.dumps({"parsed": {"value": 100.0, "on_tpu": True}}))
-    assert "cross-backend" in bench.compare_to_baseline(line, str(p)).get("refused", "")
-
-    # stampless ancient line: refuse rather than guess
-    p.write_text(json.dumps({"parsed": {"value": 100.0}}))
-    assert "refused" in bench.compare_to_baseline(line, str(p))
-    # unreadable baseline: refuse, never raise
-    assert "refused" in bench.compare_to_baseline(line, str(tmp_path / "missing.json"))
-    # truthy but non-numeric value: refuse, never raise (the headline-safety
-    # invariant — a crash here would eat the whole run's final JSON)
-    p.write_text(json.dumps({"value": "12.3 tok/s", "backend": "cpu", "chip": "cpu"}))
-    assert "refused" in bench.compare_to_baseline(line, str(p))

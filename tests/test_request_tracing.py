@@ -127,7 +127,7 @@ def test_x_request_id_on_every_response_path():
     cfg = GatewayConfig(
         enabled=True,
         slo_classes={"interactive": SLOClassConfig(max_queue_depth=2)})
-    g = ServingGateway([build_engine(on_tpu=False)], cfg).start()
+    g = ServingGateway([build_engine()], cfg).start()
     try:
         port = g.port
         # 200 blocking: client id echoed
@@ -279,7 +279,7 @@ def test_tail_sampling_retains_all_misses_at_rate_zero(tmp_path):
                                              max_queue_depth=2),
                      "loose": SLOClassConfig(ttft_target_ms=1e9)},
         tracing=RequestTraceConfig(enabled=True, log_path=log, sample_rate=0.0))
-    g = ServingGateway([build_engine(on_tpu=False)], cfg).start()
+    g = ServingGateway([build_engine()], cfg).start()
     try:
         # SLO miss (any real TTFT > 0.001ms): retained despite rate 0
         st, _, _ = _post(g.port, {"prompt": [1, 2, 3, 4], "max_new_tokens": 3,
@@ -319,7 +319,7 @@ def test_zero_overhead_when_tracing_absent():
     fr = get_flight_recorder()
     tr = get_tracer()
     ring_before = fr.total_recorded
-    g = ServingGateway([build_engine(on_tpu=False)], GatewayConfig(enabled=True))
+    g = ServingGateway([build_engine()], GatewayConfig(enabled=True))
     assert g.reqtrace is None  # no plane object at all
     threads_before = {t.name for t in threading.enumerate()}
     g.start()

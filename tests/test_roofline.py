@@ -19,8 +19,7 @@ The PR 16 acceptance bars, test-enforced:
   an atomically-renamed XPlane artifact (no ``.tmp-*`` ever visible as a
   result), 409 while a capture is in flight, 404 with the block absent;
 * **tooling drift-catch** — ``check_metric_names`` accepts the
-  ``profile/`` prefix; ``perf_sentinel`` reads mfu/mbu as higher-better
-  and ``roofline.`` accounting as neutral.
+  ``profile/`` prefix.
 """
 
 import http.client
@@ -205,7 +204,7 @@ def test_zero_overhead_when_block_absent():
     threads_before = set(threading.enumerate())
     plane = get_roofline()
     assert not plane.enabled and plane._registry is None
-    engine = build_engine(False)
+    engine = build_engine()
     _tiny_serving_run(engine)
     assert plane._registry is None  # traffic armed nothing
     # the compiled cache holds the RAW jitted callables, not wrappers
@@ -223,7 +222,7 @@ def test_cost_join_reconciles_with_goodput_within_5pct():
 
     configure_goodput(enabled=True)
     plane = configure_roofline(enabled=True)
-    engine = build_engine(False)
+    engine = build_engine()
     engine.goodput_ledger = get_goodput().serving_ledger("rf-test")
     _tiny_serving_run(engine, horizons=(4, 4, 4, 4))
     # every compiled program is wrapped and every bucket has wall samples
@@ -262,7 +261,7 @@ def test_speculative_verify_bucket_joins():
     from tools.serving_load import build_engine
 
     plane = configure_roofline(enabled=True)
-    engine = build_engine(False)
+    engine = build_engine()
     rng = np.random.default_rng(1)
     prompts = [rng.integers(0, 128, size=10, dtype=np.int32) for _ in range(2)]
     uids = [0, 1]
@@ -386,7 +385,7 @@ def test_profiling_config_presence_enables_and_validates():
 
 
 # ---------------------------------------------------------------------------
-# tooling drift-catch (satellite: check_metric_names + perf_sentinel)
+# tooling drift-catch (satellite: check_metric_names)
 # ---------------------------------------------------------------------------
 def test_check_metric_names_accepts_profile_prefix():
     from tools.check_metric_names import APPROVED_PREFIXES, _FULL_NAME
@@ -404,23 +403,3 @@ def test_check_metric_names_accepts_profile_prefix():
     for name, labels, value in rows:
         assert _FULL_NAME.match(name), name
         assert set(labels) == {"bucket"} and 0 <= value
-
-
-def test_perf_sentinel_roofline_directions():
-    from tools.perf_sentinel import metric_direction
-
-    # utilizations are higher-better wherever they appear
-    assert metric_direction("roofline.buckets.decode/s8/n4.mfu") == "higher"
-    assert metric_direction("roofline.buckets.train_step.mbu") == "higher"
-    assert metric_direction("serving.mbu") == "higher"
-    assert metric_direction("some_mbu") == "higher"
-    # roofline accounting stays neutral: longer walls / bigger costs in a
-    # longer bench round are not regressions
-    assert metric_direction("roofline.buckets.train_step.wall_s") is None
-    assert metric_direction("roofline.buckets.train_step.flops") is None
-    assert metric_direction("roofline.peak_flops") is None
-    assert metric_direction("roofline.buckets.put/t16/s8/greedy.gap_to_roof") is None
-    # and the pre-existing directions did not drift
-    assert metric_direction("serving.decode_tok_s") == "higher"
-    assert metric_direction("train.step_ms") == "lower"
-    assert metric_direction("goodput.train.wall_s") is None

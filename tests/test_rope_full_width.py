@@ -124,6 +124,33 @@ def test_an_infinity_in_a_pass_through_lane_stays_an_infinity(d, r, value):
     assert np.isfinite(np.asarray(y[:, :, 3], np.float32)).all()
 
 
+@pytest.mark.parametrize("d,r", [(128, 128), (64, 16)], ids=["the_whole_head_rotates", "part_of_it"])
+def test_an_infinity_in_a_rotated_lane_as_accepted(d, r):
+    """What an infinity in a ROTATED lane does, pinned as accepted (ROADMAP D16, decided PR 46: the product is not
+    guarded, since no cell and no model feeds one). The
+    split form (``r == d``) loses that lane and its partner; the full-width form (``r < d``) loses every rotated
+    lane of that head, NaN but for the partner (``0 x inf`` in ``x @ P``), and keeps the pass-through lanes. No other
+    head is touched in either form."""
+    cfg = config(d, r)
+    x, _ = draw(d, jnp.float32, seed=3)
+    lane, partner = 5, 5 + r // 2
+    tables = T.rope_table(cfg, jnp.arange(S))
+    clean = np.asarray(T.apply_rope(x, *tables, cfg.rotary_dim))
+    y = np.asarray(T.apply_rope(x.at[0, 3, 1, lane].set(np.inf), *tables, cfg.rotary_dim))
+    head, rest = y[0, 3, 1], np.ones(y.shape[:3], bool)
+    rest[0, 3, 1] = False
+    assert np.array_equal(y[rest], clean[rest])
+    lost = np.flatnonzero(~np.isfinite(head))
+    if r == d:
+        assert lost.tolist() == [lane, partner] and np.isinf(head[lost]).all()
+        kept = np.delete(np.arange(d), lost)
+        assert np.array_equal(head[kept], clean[0, 3, 1][kept])
+    else:
+        assert lost.tolist() == list(range(r))
+        assert np.isinf(head[partner]) and np.isnan(np.delete(head[:r], partner)).all()
+        assert np.array_equal(head[r:], np.asarray(x)[0, 3, 1, r:])
+
+
 @pytest.mark.parametrize("d,r", [w for w in WIDTHS if w[1] < w[0]])
 def test_the_tables_of_partial_rotary_are_as_wide_as_the_head(d, r):
     cfg = config(d, r)

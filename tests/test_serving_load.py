@@ -7,31 +7,69 @@ runs, never WHAT it computes — generations must match token-for-token."""
 import numpy as np
 import pytest
 
-from tools.serving_load import (build_engine, make_shared_prefix_workload, make_workload,
-                                run_splitfuse, run_static)
+from tools.serving_load import (build_engine, make_multi_tenant_workload, make_shared_prefix_workload,
+                                make_workload, run_splitfuse, run_static)
 
 
 @pytest.fixture(scope="module")
 def engine():
-    return build_engine(on_tpu=False)
+    return build_engine()
 
 
 @pytest.fixture(scope="module")
 def cache_engine():
-    return build_engine(on_tpu=False, prefix_cache=True)
+    return build_engine(prefix_cache=True)
 
 
-def test_workload_shapes_and_arrivals():
-    wl = make_workload(8, prompt_lo=4, prompt_hi=10, new_lo=2, new_hi=5,
-                       rate_rps=100.0, seed=3)
-    assert len(wl) == 8
+def _uniform(n, **kw):
+    return make_workload(n, prompt_lo=4, prompt_hi=10, new_lo=2, new_hi=5, **kw)
+
+
+def _shared_prefix(n, **kw):
+    return make_shared_prefix_workload(n, n_prefixes=3, prefix_len=6, suffix_lo=1, suffix_hi=4, new_lo=2, new_hi=5, **kw)
+
+
+def _multi_tenant(n, **kw):
+    return make_multi_tenant_workload(n, n_tenants=3, prefix_len=6, suffix_lo=1, suffix_hi=4, new_lo=2, new_hi=5,
+                                      hot_new_mult=1, **kw)
+
+
+@pytest.mark.parametrize("make", [_uniform, _shared_prefix, _multi_tenant], ids=lambda f: f.__name__.strip("_"))
+def test_workload_shapes_and_arrivals(make):
+    wl = make(8, rate_rps=100.0, seed=3, uid_base=40)
+    assert [r["uid"] for r in wl] == list(range(40, 48))
     arr = [r["arrival"] for r in wl]
     assert arr == sorted(arr) and arr[0] > 0
-    assert all(4 <= r["prompt"].size <= 10 for r in wl)
+    assert all(4 <= r["prompt"].size <= 10 and r["prompt"].dtype == np.int32 for r in wl)
     assert all(2 <= r["max_new_tokens"] <= 5 for r in wl)
+    # the same seed draws the same workload
+    again = make(8, rate_rps=100.0, seed=3, uid_base=40)
+    assert all(np.array_equal(a["prompt"], b["prompt"]) and a["arrival"] == b["arrival"] for a, b in zip(wl, again))
     # saturated mode: everything offered at t=0
-    sat = make_workload(4, prompt_lo=4, prompt_hi=8, new_lo=2, new_hi=4, rate_rps=None)
-    assert all(r["arrival"] == 0.0 for r in sat)
+    assert all(r["arrival"] == 0.0 for r in make(4, rate_rps=None))
+
+
+def test_shared_prefix_workload_shares_inside_a_group_only():
+    """A pooled prefix is shared by the requests that drew it; ``unique=True`` gives every request its own."""
+    wl = _shared_prefix(40, seed=5)
+    prefixes = {r["prompt"][:6].tobytes() for r in wl}
+    assert 1 < len(prefixes) <= 3
+    assert len({r["prompt"].tobytes() for r in wl}) > len(prefixes)  # the suffixes differ
+    assert len({r["prompt"][:6].tobytes() for r in _shared_prefix(40, seed=5, unique=True)}) == 40
+
+
+def test_multi_tenant_workload_names_a_tenant_a_row_and_the_hot_one_takes_its_share():
+    wl = make_multi_tenant_workload(400, n_tenants=3, hot_share=0.4, hot_new_mult=2, new_lo=3, new_hi=8, seed=1)
+    assert {r["tenant"] for r in wl} == {"t0", "t1", "t2", "hot"}
+    hot = [r for r in wl if r["tenant"] == "hot"]
+    assert 0.3 < len(hot) / len(wl) < 0.5
+    # the hot tenant generates hot_new_mult times as long, the rest inside their bounds
+    assert all(6 <= r["max_new_tokens"] <= 16 and r["max_new_tokens"] % 2 == 0 for r in hot)
+    assert all(3 <= r["max_new_tokens"] <= 8 for r in wl if r["tenant"] != "hot")
+    # a tenant's prompts open with one of ITS prefixes (two a tenant), and no other tenant's
+    opens = {t: {r["prompt"][:24].tobytes() for r in wl if r["tenant"] == t} for t in ("t0", "t1", "t2", "hot")}
+    assert all(1 <= len(v) <= 2 for v in opens.values())
+    assert len(set.union(*opens.values())) == sum(len(v) for v in opens.values())
 
 
 def test_splitfuse_and_static_generate_identical_tokens(engine):
@@ -124,20 +162,3 @@ def test_scheduler_finished_property(engine):
     assert sched.finished == frozenset()
     sched.run()
     assert sched.finished == frozenset({900, 901})
-
-
-@pytest.mark.slow
-def test_speculative_sweep_grid_shape_and_parity():
-    """The K × tree-width sweep: every cell replays the identical request
-    stream (token parity vs the shared spec-off baseline) and reports a
-    per-cell accept rate; the per-mode best-accept summary covers every
-    swept mode."""
-    from tools.serving_load import speculative_sweep
-
-    out = speculative_sweep(False, ks=(2, ), widths=(1, 2), n_requests=5)
-    assert len(out["grid"]) == 2
-    assert out["all_parity"], "a sweep cell broke greedy token parity"
-    for cell in out["grid"]:
-        assert {"mode", "k", "tree_width", "accept_rate", "decode_tok_s",
-                "speedup", "token_parity"} <= set(cell)
-    assert set(out["best_accept_rate_by_mode"]) == {"ngram"}

@@ -20,9 +20,7 @@ what these pin, layer by layer —
     ``max_tracked_tenants`` into ``other``;
   * zero overhead with the block absent: no meter, no engine views, no
     stamp arrays, no scheduler observer, no threads (the PR 5 bar);
-  * the ``tools/check_tenant_labels.py`` AST gate (tier-1) + drift catch;
-  * ``perf_sentinel`` neutrality: per-tenant counters are accounting
-    fields, the fairness index is higher-better.
+  * the ``tools/check_tenant_labels.py`` AST gate (tier-1) + drift catch.
 """
 
 import http.client
@@ -210,7 +208,7 @@ def test_cross_tenant_hit_attribution(metered_gw):
 def test_eviction_pressure_attributed_to_publisher():
     from deepspeed_tpu.inference.v2 import DynamicSplitFuseScheduler
 
-    engine = build_engine(False, prefix_cache=True)
+    engine = build_engine(prefix_cache=True)
     meter = TenantMeter(MeteringConfig(enabled=True))
     engine.set_tenant_meter(meter)
     sched = DynamicSplitFuseScheduler(engine)
@@ -481,7 +479,7 @@ def test_metering_conservation_under_multi_tenant_load():
 def test_zero_overhead_when_metering_absent():
     fr = get_flight_recorder()
     ring_before = fr.total_recorded
-    engine = build_engine(on_tpu=False, prefix_cache=True)
+    engine = build_engine(prefix_cache=True)
     g = ServingGateway([engine], GatewayConfig(enabled=True))
     assert g.meter is None                     # no plane object at all
     threads_before = {t.name for t in threading.enumerate()}
@@ -506,21 +504,6 @@ def test_zero_overhead_when_metering_absent():
         assert st == 404
     finally:
         g.stop()
-
-
-# ---------------------------------------------------------------------------
-# perf_sentinel neutrality: tenants block is accounting, fairness directed
-# ---------------------------------------------------------------------------
-def test_perf_sentinel_tenant_directions():
-    from tools.perf_sentinel import metric_direction
-
-    assert metric_direction("tenants.fairness_index") == "higher"
-    assert metric_direction("tenants.per_tenant.hot.compute_s") is None
-    assert metric_direction("tenants.per_tenant.t0.kv_block_s") is None
-    assert metric_direction("tenants.achieved_rps") is None  # accounting block
-    # the generic rules still hold elsewhere
-    assert metric_direction("serving.value") == "higher"
-    assert metric_direction("serving.ttft_p50_ms") == "lower"
 
 
 # ---------------------------------------------------------------------------

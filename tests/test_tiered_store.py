@@ -539,7 +539,7 @@ def test_evict_starved_counter_and_breadcrumb():
 
 
 # ---------------------------------------------------------------------------
-# structural gates + perf_sentinel directions for the new bench leaves
+# structural gates
 # ---------------------------------------------------------------------------
 
 def test_check_kv_blocks_covers_host_pool_mutators(tmp_path):
@@ -556,22 +556,6 @@ def test_check_kv_blocks_covers_host_pool_mutators(tmp_path):
     assert [(rel, line) for rel, line, _ in bad] == [("rogue.py", 2), ("rogue.py", 3)]
 
 
-def test_perf_sentinel_directions_for_tier_leaves():
-    """Drift catch: the sentinel must trend the tier's bench leaves in the
-    right direction — a lower hierarchy hit rate or a higher promotion
-    latency is a regression, migration VOLUME is neutral attribution."""
-    from tools.perf_sentinel import metric_direction
-
-    assert metric_direction("cache.host_tier.hierarchy_hit_rate") == "higher"
-    assert metric_direction("cache.host_tier.hbm_hit_rate") == "higher"
-    assert metric_direction("cache.host_tier.promote_p50_ms") == "lower"
-    assert metric_direction("cache.host_tier.promote_p99_ms") == "lower"
-    assert metric_direction("cache.host_tier.ttft_promoted_hit_p50_ms") == "lower"
-    assert metric_direction("cache.host_tier.ttft_miss_p50_ms") == "lower"
-    assert metric_direction("cache.host_tier.demotions") is None
-    assert metric_direction("cache.host_tier.promotions") is None
-
-
 # ---------------------------------------------------------------------------
 # the A/B instrument itself (slow: two engines + compiles)
 # ---------------------------------------------------------------------------
@@ -580,7 +564,7 @@ def test_perf_sentinel_directions_for_tier_leaves():
 def test_host_tier_ab_hierarchy_beats_hbm_with_parity():
     from tools.serving_load import host_tier_ab
 
-    out = host_tier_ab(on_tpu=False, n_requests=48)
+    out = host_tier_ab(n_requests=48)
     assert out["token_parity"] is True
     on, off = out["host_tier"], out["hbm_only"]
     assert on["hierarchy_hit_rate"] > off["hbm_hit_rate"], out

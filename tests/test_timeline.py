@@ -18,9 +18,7 @@ cross-replica timeline whose segments sum to client e2e within tolerance
 and the satellite handoff fields on its summary record and final SSE
 frame; a closed-loop HTTP run with disagg AND control armed where every
 terminal request — completed and shed alike — has an addressable
-timeline; ``tools/trace_explain.py`` attributing a seeded stage delta and
-refusing cross-backend diffs through the shared ``bench`` refusal core;
-and the ``tools/check_timeline_joins.py`` AST gate (clean on the live
+timeline; and the ``tools/check_timeline_joins.py`` AST gate (clean on the live
 tree AND catching a violation planted in a temp file).
 """
 
@@ -231,7 +229,7 @@ def test_timeline_config_validation():
 # zero overhead absent: no objects, no observers, no threads, 404
 # ---------------------------------------------------------------------------
 def test_timeline_absent_costs_nothing():
-    eng = build_engine(on_tpu=False)
+    eng = build_engine()
     try:
         threads_before = set(threading.enumerate())
         observers_before = dict(chaos._observers)
@@ -436,45 +434,6 @@ def test_every_terminal_request_has_a_timeline():
 
 
 # ---------------------------------------------------------------------------
-# trace_explain: seeded-stage attribution + cross-backend refusal
-# ---------------------------------------------------------------------------
-def test_trace_explain_attributes_and_refuses(tmp_path):
-    from tools.trace_explain import explain, load_round, main
-
-    base = {"meta": {"backend": "cpu"},
-            "timelines": [_plain_tl(100.0, 50.0) for _ in range(6)]}
-    cur = {"meta": {"backend": "cpu"},
-           "timelines": [_plain_tl(260.0, 50.0) for _ in range(6)]}
-    p_base = tmp_path / "base.json"
-    p_cur = tmp_path / "cur.json"
-    p_base.write_text(json.dumps(base))
-    p_cur.write_text(json.dumps(cur))
-    rep = explain(load_round(str(p_base)), load_round(str(p_cur)))
-    assert rep["refused"] is None
-    assert rep["dominant_stage"] == "prefill"
-    assert rep["by_stage"]["prefill"]["delta_ms"] == pytest.approx(160.0,
-                                                                   abs=1e-3)
-    assert main([str(p_base), str(p_cur)]) == 0
-    # cross-backend: the shared bench refusal core fires, exit code 2
-    p_tpu = tmp_path / "tpu.json"
-    p_tpu.write_text(json.dumps({"meta": {"backend": "tpu", "chip": "v4"},
-                                 "timelines": cur["timelines"]}))
-    rep = explain(load_round(str(p_base)), load_round(str(p_tpu)))
-    assert "cross-backend" in rep["refused"]
-    assert main([str(p_base), str(p_tpu)]) == 2
-    # bad input: wrong shape / missing file / wrong arity all exit 1
-    p_bad = tmp_path / "bad.json"
-    p_bad.write_text(json.dumps({"nope": 1}))
-    assert main([str(p_base), str(p_bad)]) == 1
-    assert main([str(p_base), str(tmp_path / "missing.json")]) == 1
-    assert main([str(p_base)]) == 1
-    # a bare timeline list is accepted (meta-less)
-    p_bare = tmp_path / "bare.json"
-    p_bare.write_text(json.dumps(base["timelines"]))
-    assert load_round(str(p_bare))["meta"] == {}
-
-
-# ---------------------------------------------------------------------------
 # the join gate: clean on the live tree, catches planted drift
 # ---------------------------------------------------------------------------
 def test_timeline_joins_gate_clean_on_live_tree():
@@ -510,17 +469,9 @@ def test_timeline_joins_gate_catches_drift(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# sentinel + namespace discipline for the new plane
+# namespace discipline for the new plane
 # ---------------------------------------------------------------------------
 def test_timeline_metrics_neutral_and_namespaced():
     from tools.check_metric_names import APPROVED_PREFIXES
-    from tools.perf_sentinel import metric_direction
 
     assert "timeline" in APPROVED_PREFIXES
-    # timeline rounds are attribution captures, not perf verdicts: every
-    # leaf under the bench block stays direction-neutral
-    assert metric_direction("timeline.n_timelines") is None
-    assert metric_direction("timeline.delta_e2e_ms") is None
-    assert metric_direction("timeline.chaos_stalls") is None
-    # neutrality is scoped: serving latencies keep their directions
-    assert metric_direction("serving.ttft_p99_ms") == "lower"
