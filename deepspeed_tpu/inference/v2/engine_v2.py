@@ -28,9 +28,11 @@ from ...monitor.trace import (NULL_SPAN, get_tracer, pop_compile_source,
                               push_compile_source)
 from ...moe.grouped import merge_routing_stats
 from ...ops.pallas.kda import KERNEL_NAMES as KDA_KERNEL_NAMES, TILE as KDA_TILE
+from ...ops.pallas.lightning import KERNEL_NAMES as LIGHTNING_KERNEL_NAMES, TILE as LIGHTNING_TILE
 from ...ops.pallas.paged_attention import decode_kv_counts, kernel_choice, tiled_kv_counts
 from ...utils.logging import log_dist
 from .config_v2 import RaggedInferenceEngineConfig
+from .model_implementations.sparse_index import SCOPE as INDEX_SCOPE, index_tile
 from .model_implementations.flat_model import (expanded_batch, expanded_plan, expanded_slots, expanded_workspace_bytes,
                                                 ragged_forward)
 from .ragged.ragged_manager import DSStateManager
@@ -119,6 +121,9 @@ class InferenceEngineV2:
 
         self._modules = build_modules(mc, ic, use_pallas=self._use_pallas)
         self._moe = self._modules.get("moe")  # None: a dense model
+        # whether a step's result carries an int32 of counts made in the program: the routing's, or what a
+        # learned block selection's work lists fetched
+        self._counts = self._moe is not None or int(getattr(mc, "sparse_topk", 0) or 0) > 0
         # what every serving program of this engine is jitted with. A model
         # with experts on the TPU compiles with XLA's scoped VMEM raised to
         # 64 MiB: with the default 16 MiB, a put program of 64 tokens x 8 rows
@@ -172,6 +177,23 @@ class InferenceEngineV2:
             if np.dtype(ic.kv_dtype).itemsize == 1:
                 raise NotImplementedError("an int8 KV cache beside a recurrent state layer: the program threads "
                                           "either the scales or the state pools, and the state is float32 as stated")
+        # a model with a learned block-sparse selection: its softmax layers cache pooled keys beside K and V
+        # and both paged kernels read by what the queries select; the program counts what was fetched
+        self._index_entry = tuple(getattr(mc, "index_entry", ()))
+        self._sparse = bool(self._index_entry)
+        self._lightning = int(getattr(mc, "lightning_num_heads", 0) or 0) > 0
+        if self._sparse:
+            if getattr(ic.speculative, "enabled", False):
+                raise NotImplementedError(
+                    "speculative decoding of a model with pooled keys (a learned block selection): a rejected "
+                    "draft's keys are pooled into entries that the kept tokens share, and nothing rewinds them")
+            if np.dtype(ic.kv_dtype).itemsize == 1:
+                raise NotImplementedError("an int8 KV cache beside pooled keys (a learned block selection): the "
+                                          "indexer scores pooled keys of the compute type, and the selected lists "
+                                          "carry no scales")
+            if bs != mc.sparse_block_size:
+                raise ValueError(f"kv_block_size {bs} of a model that selects blocks of {mc.sparse_block_size} "
+                                 "tokens: the KV block is the selection's block")
         max_context = ic.state_manager.max_context
         model_max = getattr(mc, "max_seq_len", None)
         if model_max is not None and max_context > model_max:
@@ -192,7 +214,8 @@ class InferenceEngineV2:
             max_tracked_sequences=ic.state_manager.max_tracked_sequences,
             num_blocks=self.num_kv_blocks, block_size=bs, dtype=ic.kv_dtype,
             prefix_cache_config=ic.prefix_cache, kv_entry=self._kv_entry,
-            state_entry=tuple(getattr(mc, "state_entry", ())), state_layers=len(self._state_layers))
+            state_entry=tuple(getattr(mc, "state_entry", ())), state_layers=len(self._state_layers),
+            index_entry=self._index_entry)
         if self._block and self.state_manager.prefix_cache is not None:
             self.state_manager.prefix_cache.token_quantum = self._block
         self.batch = RaggedBatchWrapper(
@@ -202,6 +225,7 @@ class InferenceEngineV2:
             token_buckets=ic.state_manager.token_buckets, seq_buckets=ic.state_manager.seq_buckets)
 
         self._compiled: Dict[Tuple[int, int, Optional[str]], object] = {}
+        self._ahead: Dict[tuple, object] = {}  # program key -> the future of compile_ahead() that fills _compiled[key]
         self._kernel_labels: Dict[Tuple[int, int, bool], str] = {}  # (tokens, rows, horizon) -> span label, see _kernel_of
         # (window or None, layers that attend in it), for _kv_span_args and _tiled_kv_span_args
         self._kv_windows = list(collections.Counter(mc.layer_window(l) for l in self._kv_layers).items())
@@ -308,6 +332,9 @@ class InferenceEngineV2:
         dt_bytes = _np.dtype(ic.kv_dtype).itemsize  # accepts "int8" and jnp dtypes alike
         kv_layers = len(getattr(mc, "kv_layers", range(mc.num_layers)))  # the layers that cache a token's entry
         per_block = kv_layers * sum(h * w for h, w in self._kv_entry) * bs * dt_bytes
+        if getattr(mc, "index_entry", ()):  # a block's pooled keys: one of (heads, width) every stride tokens
+            stride, heads, width = mc.index_entry
+            per_block += kv_layers * heads * width * (bs // stride) * dt_bytes
         if dt_bytes == 1:  # int8 KV: absmax scales ride along, fp32 per (token, head)
             per_block += 2 * mc.num_layers * mc.num_kv_heads * bs * 4
         min_blocks = -(-max_context // bs) + 1
@@ -322,7 +349,7 @@ class InferenceEngineV2:
                 # latent attention's long rows (zero for every other model), which the pool must not be given
                 used = max(stats.get("bytes_in_use", 0), param_bytes) + expanded_workspace_bytes(
                     mc, ic.state_manager.max_ragged_batch_size, -(-max_context // bs), bs, dt_bytes) \
-                    + self._state_bytes_ahead(mc, ic, dt_bytes)
+                    + self._state_bytes_ahead(mc, ic, dt_bytes) + self._index_bytes_ahead(mc, ic, max_context)
                 free = max(0, int(stats["bytes_limit"]) - used)
         except Exception:
             free = None
@@ -355,12 +382,38 @@ class InferenceEngineV2:
         state_layers = len(getattr(mc, "state_layers", ()))
         if not state_layers:
             return 0
-        (h, dk, dv), (taps, channels) = mc.state_entry
         sm = ic.state_manager
+        if len(mc.state_entry) == 1:  # lightning layers: the state alone, no convolution tail
+            # the slots, and the chunk scan's tiles of q, k, v and o in float32 (``lightning_chunks``: a token
+            # budget of tiles and a partial tile a row) beside their flat forms
+            (h, dk, dv), = mc.state_entry
+            tokens = 2 * (sm.max_ragged_batch_size + LIGHTNING_TILE * sm.max_ragged_sequence_count)
+            return sm.max_tracked_sequences * state_layers * h * dk * dv * 4 + tokens * h * (3 * dk + dv) * 4
+        (h, dk, dv), (taps, channels) = mc.state_entry
         slot = state_layers * (h * dk * dv * 4 + taps * channels * dt_bytes)
         tile_tokens = sm.max_ragged_batch_size + KDA_TILE * sm.max_ragged_sequence_count
         tokens = tile_tokens + 8 * sm.max_ragged_batch_size + 32 * sm.max_ragged_sequence_count
         return sm.max_tracked_sequences * slot + tokens * h * max(dk, dv) * 4
+
+    def _index_bytes_ahead(self, mc, ic, max_context: int) -> int:
+        """What a model with a learned block selection keeps beside the pools
+        while its largest ``put`` program runs: the indexer's scores of one
+        pass (``sparse_index._SCORE_BYTES``, twice: the softmax beside them),
+        the selection a token a kv head a block with the mask the tiled kernel
+        takes with every (tile, block) pair (bfloat16, 16 sublanes), and the
+        pooled keys gathered a tile. 0 for every other model."""
+        if not getattr(mc, "index_entry", ()):
+            return 0
+        from .model_implementations.sparse_index import _SCORE_BYTES
+
+        sm = ic.state_manager
+        T, S = sm.max_ragged_batch_size, sm.max_ragged_sequence_count
+        blocks = -(-max_context // ic.kv_block_size)
+        stride, heads, width = mc.index_entry
+        tiles = -(-T // 128) + S + 1
+        mask = tiles * blocks * 16 * 128 * 2
+        pooled = tiles * blocks * (ic.kv_block_size // stride) * heads * width * 2
+        return 2 * _SCORE_BYTES + 2 * mask + 3 * T * heads * blocks + pooled
 
     def can_schedule(self, uids: Iterable[int], lengths: Iterable[int]) -> SchedulingResult:
         """Admission control (reference ``engine_v2.py:179``): sequence,
@@ -420,7 +473,12 @@ class InferenceEngineV2:
         tokens are drawn from the tempered/top-p distribution ON DEVICE
         (``sampling.sample_tokens``), keyed by (seed, token position) so a
         fixed seed replays the same stream; all-greedy lists keep the
-        byte-identical argmax program."""
+        byte-identical argmax program.
+
+        ``sample='probe'`` (a model with a learned block selection; a check's
+        reading, not a serving mode): ``(logits, (positions, selection,
+        attention output))`` of the live rows, the three as
+        ``ragged_forward(probe=True)`` lays them."""
         hb = self._health
         # normalize ONCE, before any breadcrumb math: both arguments may be
         # single-pass iterables, and _put's re-asarray of the converted rows
@@ -540,7 +598,7 @@ class InferenceEngineV2:
                 blocked=bool(block),
                 **self._attn_span_args([seq.seen_tokens for seq in descs], [t.size for t in batch_tokens], t_bucket),
                 **self._state_span_args(len(batch_uids), sum(int(t.size) for t in batch_tokens),
-                                        sum(1 for t in batch_tokens if t.size == 1)),
+                                        0 if self._lightning else sum(1 for t in batch_tokens if t.size == 1)),
                 **({} if had_prefill else
                    self._kv_span_args(t_bucket, s_bucket, [[seq.seen_tokens for seq in descs]])),
                 **self._tiled_kv_span_args(t_bucket, s_bucket, rb)))
@@ -553,6 +611,8 @@ class InferenceEngineV2:
                 # the device whatever the option says: a row of them is the vocabulary wide
                 out, seen = _cut_and_fetch(sp, out, rb.n_seqs, stats[:1], block,
                                            self.config.cut_rows_on_host and mode is not None)
+                if mode == "probe":  # (logits, (positions, selection, attention output)) of the live rows
+                    out = (out, tuple(np.asarray(a)[:rb.n_seqs] for a in stats[1]))
             _observe(sp, lambda: self._moe_span_args(held["tokens"], t_bucket, 1, seen[0]) if seen else {}, held)
         if rf.enabled and block:
             # wall join through the blocking host fetch — the same window the
@@ -584,9 +644,13 @@ class InferenceEngineV2:
         ``experts_total`` held experts with at least one slot, summed over
         expert layers and steps; ``expert_load_max``, the most slots one
         expert of one layer held; ``experts_held`` of ``experts_published``,
-        the experts here of those the router scores. ``stats`` is the
+        the experts here of those the router scores. A model with a learned
+        block selection says ``attn_blocks_read`` instead, the one count its
+        program makes (:meth:`_sparse_span_args`). ``stats`` is the
         program's ``[experts_hit, expert_load_max, slots]``."""
         mc = self.model_config
+        if self._sparse:
+            return {"attn_blocks_read": int(stats[0])}
         layer_forwards = mc.num_expert_layers * forwards - kv_only_forwards
         return {"moe_slots": int(stats[2]),
                 "moe_slots_routed": tokens * mc.moe_top_k * layer_forwards,
@@ -611,6 +675,8 @@ class InferenceEngineV2:
         in the expanded form (``flat_model.expanded_slots`` on the same
         lengths; 0 for a decode horizon, which has no such program)."""
         seen, new = np.asarray(seen, np.int64), np.asarray(new, np.int64)
+        if self._sparse:
+            return self._sparse_span_args(seen, new)
         pairs = ctx = 0
         for window, layers in self._kv_windows:
             if window is None:
@@ -628,13 +694,48 @@ class InferenceEngineV2:
             args["attn_expanded_pairs"] = self.model_config.num_layers * int((row_pairs * expanded).sum())
         return args
 
+    def _sparse_span_args(self, seen, new) -> dict:
+        """:meth:`_attn_span_args` of a model with a learned block selection,
+        which counts what was READ, from the rows' lengths alone (a token with
+        more than ``dense_len`` tokens of context selects exactly ``topk``
+        blocks, its own among them, and every other token every visible
+        block). In blocks of the selection, summed over sparse layers, kv heads
+        and query tokens: ``attn_blocks_visible``, those at or before the
+        token's own, and ``attn_blocks_selected``, those its selection chose
+        (``attn_blocks_read``, what the work lists fetched, a tile's union, is
+        counted in the program and joins the span with the step's result: equal
+        to both for a row under ``dense_len``). ``sparse_rows`` / ``dense_rows``:
+        the rows with and without a token past ``dense_len``; ``index_keys``:
+        the pooled keys such tokens scored (x kv heads x layers);
+        ``index_entry_bytes``: one pooled key's bytes in one layer.
+        ``attn_pairs``: the (query, selected context token) pairs a layer;
+        ``attn_ctx_tokens``: the context tokens the rows' queries selected, at
+        most (a chunk's tokens may share blocks)."""
+        mc, kv = self.model_config, self.state_manager.kv_cache
+        bs, layers, nkv = kv.block_size, len(self._kv_layers), mc.num_kv_heads
+        p = np.concatenate([np.arange(s, s + n) for s, n in zip(seen, new)] or [np.zeros(0, np.int64)])
+        dense = p + 1 <= mc.sparse_dense_len
+        visible = p // bs + 1
+        selected = np.where(dense, visible, mc.sparse_topk)
+        pairs = np.where(dense, p + 1, (mc.sparse_topk - 1) * bs + p % bs + 1)
+        keys = np.where(dense, 0, (p - (mc.sparse_kernel_size - 1)) // mc.sparse_kernel_stride + 1)
+        past = seen + new > mc.sparse_dense_len
+        row_of = np.repeat(np.arange(len(new)), new)
+        ctx = np.minimum(seen + new, np.bincount(row_of, weights=pairs, minlength=len(new)).astype(np.int64))
+        return {"attn_pairs": layers * int(pairs.sum()), "attn_ctx_tokens": layers * int(ctx.sum()),
+                "kv_entry_bytes": sum(h * w for h, w in self._kv_entry) * kv.k_pool.dtype.itemsize,
+                "attn_blocks_visible": layers * nkv * int(visible.sum()),
+                "attn_blocks_selected": layers * nkv * int(selected.sum()),
+                "sparse_rows": int(past.sum()), "dense_rows": int((~past).sum()),
+                "index_keys": layers * nkv * int(keys.sum()), "index_entry_bytes": kv.index_entry_bytes()}
+
     def _state_span_args(self, rows: int, tokens: int, stepped: int) -> dict:
         """What a step span says of the state layers of a model that has them
         (nothing otherwise): ``state_rows``, the rows whose state the call
         read and wrote (x the steps of a horizon); ``state_rows_stepped``,
         those of them whose state went through the recurrent step (a ``put``'s
-        rows fed ONE token; every row x step of a horizon), the rest through
-        the chunk scan; ``state_bytes``, the rows x state layers x
+        rows fed ONE token, of the delta rule: a lightning ``put`` scans every
+        row; every row x step of a horizon), the rest through the chunk scan; ``state_bytes``, the rows x state layers x
         ``state_entry_bytes`` x 2, the least a correct form moves;
         ``lin_tokens``, the tokens through linear layers, x layers;
         ``state_slots_live`` of ``state_slots_total`` slots taken."""
@@ -664,8 +765,8 @@ class InferenceEngineV2:
         traced yet or one the tiled grid took, which says the same of itself
         under names of its own (:meth:`_tiled_kv_span_args`)."""
         choice = kernel_choice(T, S, self._max_blocks_per_seq)
-        if choice is None or choice["kernel"] == "paged_attn_q_tiled":
-            return {}
+        if choice is None or choice["kernel"] == "paged_attn_q_tiled" or self._sparse:
+            return {}  # (under a selection the grid follows the data: ``attn_blocks_read`` says what it ran)
         steps, live = decode_kv_counts(choice, pos, self._kv_windows, self.config.kv_block_size,
                                        self._max_blocks_per_seq, T)
         return {"kv_steps": steps, "kv_live": live}
@@ -681,7 +782,7 @@ class InferenceEngineV2:
         forwards at the batch's positions plus ``offset``, ``kv_only`` of
         which stop before the last layer's attention."""
         choice = kernel_choice(T, S, self._max_blocks_per_seq)
-        if choice is None or choice["kernel"] != "paged_attn_q_tiled":
+        if choice is None or choice["kernel"] != "paged_attn_q_tiled" or self._sparse:
             return {}
         last = self.model_config.layer_window(self.model_config.num_layers - 1)
         bs = self.config.kv_block_size
@@ -725,7 +826,12 @@ class InferenceEngineV2:
                 choices.append(kernel_choice(T, 2 * rows + 1, cols))
             parts = [] if None in choices else ["%s:%d:%s" % (c["kernel"], max(c["q_tile"], c["blocks_per_step"]), c["rule"])
                                                 for c in choices]
-            if self._state_layers:  # beside the paged kernel, the delta rule's forms in this program
+            if self._sparse:  # the indexer that made the selection the paged kernel read by
+                parts.append("%s:%d:top%d" % (INDEX_SCOPE, index_tile(T), self.model_config.sparse_topk))
+            if self._lightning:  # one form a program: the chunk scan of a put, the recurrent step of a horizon
+                parts.append("%s:1:one-token-rows" % LIGHTNING_KERNEL_NAMES[0] if horizon else
+                             "%s:%d:ragged" % (LIGHTNING_KERNEL_NAMES[1], LIGHTNING_TILE))
+            elif self._state_layers:  # beside the paged kernel, the delta rule's forms in this program
                 parts += [] if horizon else ["%s:%d:ragged" % (KDA_KERNEL_NAMES[1], KDA_TILE)]
                 parts.append("%s:1:one-token-rows" % KDA_KERNEL_NAMES[0])
             label = "+".join(parts)
@@ -1076,7 +1182,8 @@ class InferenceEngineV2:
         return self._compiled[key]
 
     def _ragged_step(self, params, packed, pools, t_bucket, s_bucket, gather_k: int = 0,
-                     tree_meta=None, moe_stats: bool = False, kv_only: bool = False, one_token_rows: bool = False):
+                     tree_meta=None, moe_stats: bool = False, kv_only: bool = False, one_token_rows: bool = False,
+                     probe: bool = False):
         """One ragged forward over the pool tuple (2 = bf16 pools, 4 = int8
         pools + scales, 1 = a latent pool). The SINGLE builder both compiled paths share —
         quant/non-quant variation lives in the tuple arity, not in four
@@ -1109,7 +1216,13 @@ class InferenceEngineV2:
         A model with state layers: the pool tuple is ``(k, v, state, tails)``
         and the rows' state slots ride behind the descriptors;
         ``one_token_rows`` says that token ``i`` is row ``i`` (the decode
-        horizon's step), which picks the delta rule's recurrent form."""
+        horizon's step), which picks the delta rule's recurrent form. A model
+        with a learned block selection: the pooled keys' pool is the third of
+        the tuple, ``moe_stats`` carries the blocks its work lists served, and
+        ``probe`` adds a last result of its own, ``ragged_forward``'s: the
+        positions, the selection and the attention output of a few tokens a
+        row in every sparse layer (``put(sample="probe")``: a check's way to
+        read back what the indexer chose and what the paged kernel made of it)."""
         from .ragged.ragged_wrapper import unpack_descriptors
 
         token_ids, seq_idx, pos, valid, tables, last_idx = unpack_descriptors(
@@ -1167,17 +1280,25 @@ class InferenceEngineV2:
             if gather_k:
                 raise NotImplementedError("a speculative verify step (a tree of drafts among them) of a model with a "
                                           "recurrent state layer: the rejected tokens cannot be rewound out of the state")
-            beside = {"state_pools": tuple(pools[2:]), "one_token_rows": one_token_rows,
+            beside = {"state_pools": tuple(pools[3 if self._sparse else 2:]), "one_token_rows": one_token_rows,
                       "state_slots": unpack_state_slots(packed, t_bucket, s_bucket, self._max_blocks_per_seq)}
         else:
             beside = {"k_scale": pools[2], "v_scale": pools[3]} if len(pools) == 4 else {}
+        if self._sparse:  # (never beside int8 either: the third pool is the pooled keys')
+            if gather_k:
+                raise NotImplementedError("a speculative verify step of a model with pooled keys (a learned block "
+                                          "selection): the rejected tokens' keys cannot be taken out of the pooled ones")
+            beside.update(index_pool=pools[2], probe=probe)
         out = ragged_forward(self.model_config, self.config.kv_block_size, params,
                              token_ids, seq_idx, pos, valid, tables, last_idx,
                              pools[0], pools[1] if len(pools) > 1 else None, use_pallas=self._use_pallas,
                              modules=self._modules, moe_stats=moe_stats, kv_only=kv_only, **beside, **extra)
+        tail = ()
+        if probe:
+            out, tail = out[:-1], out[-1:]
         if moe_stats:
-            return out[0], tuple(out[1:-1]), out[-1]
-        return out[0], tuple(out[1:])  # logits, new pool tuple
+            return (out[0], tuple(out[1:-1]), out[-1]) + tail
+        return (out[0], tuple(out[1:])) + tail  # logits, new pool tuple
 
     # ------------------------------------------------------------------
     def speculate_decode(self, batch_uids: List[int], first_tokens, draft_tokens,
@@ -1273,10 +1394,11 @@ class InferenceEngineV2:
         t_rf = time.perf_counter() if rf.enabled else 0.0
         uids = list(batch_uids)
         S = len(uids)
-        if self._state_layers:
+        if self._state_layers or self._sparse:
             raise NotImplementedError(
-                "speculate_decode (a linear draft or a token tree) of a model with a recurrent state layer: a "
-                "rejected draft is rewound, and the state has consumed the draft and keeps no snapshot to return to")
+                "speculate_decode (a linear draft or a token tree) of a model with a recurrent state layer or with "
+                "pooled keys (a learned block selection): a rejected draft is rewound, and the state has consumed "
+                "the draft and keeps no snapshot to return to, as the pooled keys have pooled the draft's keys")
         with tr.span("serving/spec_verify", tid="serving") as sp:
             with tr.span("serving/engine_batch", tid="serving"):
                 firsts = [np.asarray(t, np.int32).reshape(-1) for t in first_tokens]
@@ -1573,6 +1695,7 @@ class InferenceEngineV2:
 
     def _get_compiled_decode(self, s_bucket: int, n_steps: int, sampled: bool = False):
         key = ("decode", s_bucket, n_steps, bool(sampled))
+        self._await_ahead(key)
         if key not in self._compiled:
             bucket = f"decode/s{s_bucket}/n{n_steps}{'/sampled' if sampled else ''}"
             self._note_compile(bucket)
@@ -1580,7 +1703,7 @@ class InferenceEngineV2:
 
             max_blocks = self._max_blocks_per_seq
             step_fn = self._ragged_step
-            moe = self._moe is not None
+            moe = self._counts
             stats0 = (jnp.zeros(3, jnp.int32), ) if moe else ()
 
             def merge(stats, new):
@@ -1687,7 +1810,7 @@ class InferenceEngineV2:
                 n_steps = int(n_steps)
                 B = self._block or 1  # a block-diffusion model: n_steps // B blocks of B tokens a row
                 key = ("diffuse", s_bucket, n_steps // B, ()) if self._block else ("decode", s_bucket, n_steps, False)
-                if key in self._compiled:
+                if key in self._compiled and key not in self._ahead:  # one compiled ahead that no call has run runs below
                     results.append({"seqs": s_bucket, "steps": n_steps, "seconds": 0.0, "cached": True})
                     continue
                 fn = self._get_compiled_blocks(s_bucket, n_steps // B) if self._block \
@@ -1717,7 +1840,7 @@ class InferenceEngineV2:
                     continue  # a prefill batch never has more rows than tokens
                 for sample in put_samples:
                     key = (t_bucket, s_bucket, sample)
-                    if key in self._compiled:
+                    if key in self._compiled and key not in self._ahead:
                         results.append({"seqs": s_bucket, "tokens": t_bucket,
                                         "sample": sample, "seconds": 0.0, "cached": True})
                         continue
@@ -1744,6 +1867,88 @@ class InferenceEngineV2:
         if declare_warmed:
             self.declare_gp_warmed()
         return results
+
+    # ------------------------------------------------------------------
+    def compile_ahead(self, programs):
+        """Start compiling ``programs`` on two threads of their own and return
+        at once. Compiling keeps the HOST busy and the device idle (tracing,
+        lowering, XLA, or reading an executable back from the persistent
+        cache: 8 to 40 s a program of a 16-layer model), so a start-up that
+        has work for the DEVICE meanwhile (loading, a self-check) need not
+        take both in turn. Two threads, because tracing holds the
+        interpreter's lock and XLA and the cache's reads do not: a third gains
+        little. ``programs``, in the order wanted: ``("decode", seqs,
+        steps)``, the greedy multi-step decode program, and ``("put", tokens,
+        seqs, sample)`` with ``sample`` ``None``, ``"greedy"`` or ``"probe"``,
+        each rounded up to its bucket as :meth:`warmup` rounds. Nothing is
+        executed and no pool is touched: a program is lowered from shapes and
+        compiled ahead of time, and the executable takes the jitted
+        function's place in the engine's table. A call that needs a program
+        still in the making waits for it (and raises what its compile
+        raised). Returns the futures; ``warmup`` afterwards runs once, on its
+        zero descriptor, each of them that no call has run yet (a program's
+        first run is part of warming it), reports the others ``"cached"`` and
+        declares the boundary."""
+        from concurrent.futures import ThreadPoolExecutor
+
+        if self._block:
+            raise NotImplementedError("compile_ahead: a block-diffusion model's decode programs are warmup()'s")
+        shapes = lambda tree: jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=getattr(a, "sharding", None)), tree)
+        params, pools = shapes(self.params), shapes(self.state_manager.kv_cache.pools())
+        jobs = []
+        for kind, *spec in programs:
+            if kind not in ("decode", "put"):
+                raise ValueError(f"compile_ahead: unknown program kind {kind!r}: 'decode' | 'put'")
+            s_bucket = next_bucket(int(spec[-2 if kind == "put" else 0]), self.batch.seq_buckets)
+            t_bucket = next_bucket(int(spec[0]), self.batch.token_buckets) if kind == "put" else s_bucket
+            key = (t_bucket, s_bucket, spec[2]) if kind == "put" else ("decode", s_bucket, int(spec[1]), False)
+            if key in self._compiled:
+                continue
+            # the jitted function is made HERE, so that a later call finds the key and waits for its future
+            fn = self._get_compiled(*key) if kind == "put" else self._get_compiled_decode(s_bucket, int(spec[1]))
+            if not hasattr(fn, "lower"):
+                continue  # wrapped by the roofline's capture: compiled at its first call, as ever
+            packed = jax.ShapeDtypeStruct(
+                (packed_len(t_bucket, s_bucket, self._max_blocks_per_seq, bool(self._state_layers)), ), jnp.int32)
+            jobs.append((key, fn, packed))
+
+        def compile_one(key, fn, packed):
+            prev = push_compile_source("serving")
+            try:
+                self._compiled[key] = fn.lower(params, packed, pools).compile()
+            finally:
+                pop_compile_source(prev)
+
+        pool = ThreadPoolExecutor(2, thread_name_prefix="compile-ahead")
+        for job in jobs:
+            self._ahead[job[0]] = pool.submit(compile_one, *job)
+        pool.shutdown(wait=False)
+        return [self._ahead[key] for key, _, _ in jobs]
+
+    def compiled_horizon(self, rows: int, wanted: int, sampled: bool = False) -> int:
+        """The multi-step decode horizon a scheduler that wants ``wanted``
+        steps for ``rows`` rows should ask for. Before the warm-up boundary is
+        declared (:meth:`warmup`, :meth:`declare_gp_warmed`) that is
+        ``wanted``: the program compiles at its first call, as ever. After it
+        a compile is a stall in steady state, so it is the longest horizon of
+        at most ``wanted`` steps that the engine HAS for these rows, and
+        ``wanted`` only where it has none: a replica may warm fewer horizons
+        than the scheduler's six (each is a program of the whole model,
+        seconds of its start-up) and is then served by those."""
+        if not self._gp_warmed:
+            return int(wanted)
+        s_bucket = next_bucket(int(rows), self.batch.seq_buckets)
+        have = [k[2] for k in list(self._compiled)  # a snapshot: another thread may add a program meanwhile
+                if len(k) == 4 and k[0] == "decode" and k[1] == s_bucket and k[3] == bool(sampled) and k[2] <= wanted]
+        return max(have, default=int(wanted))
+
+    def _await_ahead(self, key):
+        """Wait for ``key``'s program if :meth:`compile_ahead` is making it."""
+        if self._ahead:
+            future = self._ahead.pop(key, None)
+            if future is not None:
+                future.result()
 
     # ------------------------------------------------------------------
     def query(self, uid: Optional[int] = None):
@@ -1918,10 +2123,11 @@ class InferenceEngineV2:
         exist yet, and partial blocks never travel (the tree only holds
         full blocks, same rule as ``publish``)."""
         sm = self.state_manager
-        if self._state_layers:
+        if self._state_layers or self._sparse:
             raise NotImplementedError(
-                "export_sequence_kv of a model with a recurrent state layer: the handoff ships K/V blocks, and the "
-                "receiving replica would resume with them and no state; the state would have to travel beside them")
+                "export_sequence_kv of a model with a recurrent state layer or with pooled keys (a learned block "
+                "selection): the handoff ships K/V blocks, and the receiving replica would resume with them and no "
+                "state and no pooled keys; both would have to travel beside them")
         seq = sm.get_sequence(uid)
         tokens = np.asarray(tokens, np.int32).reshape(-1)
         bs = self.config.kv_block_size
@@ -1982,14 +2188,17 @@ class InferenceEngineV2:
     # ------------------------------------------------------------------
     def _get_compiled(self, t_bucket: int, s_bucket: int, sample: Optional[str] = None):
         key = (t_bucket, s_bucket, sample)
+        self._await_ahead(key)
         if key not in self._compiled:
             bucket = f"put/t{t_bucket}/s{s_bucket}/{sample or 'logits'}"
             self._note_compile(bucket)
-            if sample not in (None, "greedy", "sample"):
-                raise ValueError(f"unsupported sample mode {sample!r}: None | 'greedy' | 'sample'")
+            if sample not in (None, "greedy", "sample") and not (sample == "probe" and self._sparse):
+                raise ValueError(f"unsupported sample mode {sample!r}: None | 'greedy' | 'sample' (and 'probe', logits "
+                                 "and beside them what a few tokens a row selected and attended, of a model that "
+                                 "selects blocks)")
             step_fn = self._ragged_step
             mb = self._max_blocks_per_seq
-            moe = self._moe is not None  # the step's result then carries the routing counts
+            moe = self._counts  # the step's result then carries the routing counts (or a selection's blocks read)
 
             if sample == "sample":
                 from .sampling import sample_tokens
@@ -2010,7 +2219,7 @@ class InferenceEngineV2:
             else:
                 def fwd(params, packed, pools):
                     logits, pools, *stats = step_fn(params, packed, pools, t_bucket, s_bucket,
-                                                    moe_stats=moe)
+                                                    moe_stats=moe, probe=sample == "probe")
                     out = jnp.argmax(logits, axis=-1).astype(jnp.int32) if sample == "greedy" else logits
                     return (out, *stats), pools
 

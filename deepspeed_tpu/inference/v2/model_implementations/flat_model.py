@@ -38,6 +38,11 @@ import numpy as np
 from ....models.transformer import TransformerConfig, apply_rope, mlp_activation, rope_inv_freq, rope_table
 from ....moe.grouped import merge_routing_stats
 from ....ops.pallas.kda import kda_chunks, kda_step
+from ....ops.pallas.lightning import lightning_chunks, lightning_step
+from .sparse_index import select_blocks, update_pooled_keys
+
+# tokens a row whose selection and attention output ``ragged_forward(probe=True)`` hands back
+PROBES = 4
 
 # Latent attention, the form a row a step (``ragged_forward``). Expanded, a
 # (query, key) pair a head costs ``2 (d + dv)`` operations where absorbed costs
@@ -163,7 +168,8 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
                    unroll: bool = True, modules: Dict[str, Any] = None,
                    k_scale=None, v_scale=None, pos_ids=None, attn_mask=None,
                    ctx_pos_ids=None, moe_stats: bool = False, kv_only: bool = False,
-                   state_pools=None, state_slots=None, one_token_rows: bool = False):
+                   state_pools=None, state_slots=None, one_token_rows: bool = False, index_pool=None,
+                   probe: bool = False):
     """Returns (last-token logits [S_pad, V], k_pool, v_pool).
 
     token_ids/seq_idx/pos/valid: [T_pad]; block_tables: [S_pad, max_blocks];
@@ -257,7 +263,36 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
     decode horizon's step, where token ``i`` IS row ``i``, the recurrent
     step), and writes state and tail back at the rows that were fed. A padded
     token or row touches neither. The return gains the two pools after the
-    K/V pools.
+    K/V pools. LIGHTNING layers (``cfg.lightning_num_heads``, scalar-decay
+    linear attention: ``models/minicpm.py``) are state layers too, with the
+    state pool alone (``state_pools`` of one): q and k are normed a head and
+    ROPED, there is no convolution, and the recurrence's two forms are
+    ``ops/pallas/lightning.py``'s: the chunkwise one for every ``put``, the
+    recurrent step under ``one_token_rows``.
+
+    A model with a learned block-sparse SELECTION (``cfg.sparse_topk``) takes
+    ``index_pool`` ``[La, NB * block / stride, nkv, d]``, the pooled keys its
+    sparse layers cache beside K and V on the same block table, and returns it
+    after the K/V pools. Such a layer, after its K/V scatter, writes the
+    pooled keys this step's tokens complete, scores the blocks of every
+    query's context with them (``sparse_index.py``) and hands the selection to
+    the attention module, whose kernels lay grid steps for selected blocks
+    alone. The KV block is the selection's block. ``moe_stats`` of such a model
+    (it has no experts) appends int32 ``[blocks_read, 0, 0]``: the (token, kv
+    head, block) triples the work lists served, as the lists themselves count
+    their items (``paged_attention``'s second result under a selection),
+    summed over the layers; what was visible and what was selected follow from
+    the rows' lengths and are the engine's to count. ``probe`` appends, last of
+    all, what a check reads back of ``PROBES`` tokens a row (its run's first
+    and last and two between: the middle of a chunk's tiles and their ends)
+    in every sparse layer: ``(positions [S_pad, PROBES] int32, selection
+    [S_pad, PROBES, La, nkv, max_blocks] bool, attention output [S_pad, PROBES,
+    La, nq * d] float32)``, the selection the indexer made and what the paged
+    kernel gave back under it, before the gate and ``W_o``.
+
+    ``cfg.residual_scale`` multiplies each branch's output before it is added
+    and ``cfg.logit_scale`` the logits (the head has no bias, so that is the
+    final normed hidden state scaled).
 
     ``k_scale``/``v_scale``: int8-KV mode — [nkv, L*pool_len] fp32 absmax
     scales (lane-major over slots, the layout both the scatter and the
@@ -279,8 +314,14 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
     if cfg.moe_num_experts > 0 and moe is None:
         raise ValueError("a model with experts needs the module set's 'moe' slot "
                          "(modules/heuristics.build_modules fills it)")
-    if moe_stats and moe is None:
-        raise ValueError("moe_stats asked of a model without experts")
+    sparse = cfg.sparse_topk > 0
+    if moe_stats and moe is None and not sparse:
+        raise ValueError("moe_stats asked of a model without experts or a block selection")
+    if sparse != (index_pool is not None) or (sparse and (block_size != cfg.sparse_block_size or k_scale is not None
+                                                          or attn_mask is not None or kv_only)):
+        raise ValueError(f"a model with a block selection takes index_pool, KV blocks of its sparse_block_size "
+                         f"({cfg.sparse_block_size}, got {block_size}), and neither int8 scales, a token-tree mask "
+                         "nor kv_only; every other model takes no index_pool")
     if getattr(cfg, "sparse_attention", None) is not None:
         # same policy as forward_with_cache: dense paged decode would
         # silently mismatch a sparse-trained model's attention distribution
@@ -323,6 +364,7 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
             state_slots is None or quant or attn_mask is not None or kv_only or latent)):
         raise ValueError("a model with linear-attention layers takes state_pools and state_slots, and neither int8 "
                          "scales, a token-tree mask nor kv_only; every other model takes none")
+    lightning = cfg.lightning_num_heads > 0
     if state_index:
         # what the rows are fed, once a program: tokens a row (rows come in order from flat token 0), a
         # token's place in its row's run, and which rows start their sequence here
@@ -343,7 +385,9 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
         kda_pallas = use_pallas and jax.default_backend() == "tpu"
         kda_interpret = bool(getattr(attention, "implementation_config", {}).get("interpret", False))
         st_flat = state_pools[0].reshape((-1, ) + state_pools[0].shape[2:])
-        cv_flat = state_pools[1].reshape((-1, ) + state_pools[1].shape[2:])
+        cv_flat = None if lightning else state_pools[1].reshape((-1, ) + state_pools[1].shape[2:])
+    if sparse:
+        idx_flat = index_pool.reshape((-1, ) + index_pool.shape[2:])
     slot = block_tables[seq_idx, pos // block_size] * block_size + pos % block_size
 
     # what the attention paths mask by (see the docstring)
@@ -415,6 +459,29 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
         o = (o.reshape(T, nh * dk) * jax.nn.sigmoid(gate)).astype(h1.dtype)
         return linear(o, blk["kda_wo"], None), st_flat, cv_flat
 
+    def lightning_mixer(h1, blk, li, st_flat, rope):
+        """A lightning layer's mixer on the normed input ``h1`` ``[T, H]``,
+        ``li`` its place among the state layers, ``rope`` its ``(sin, cos)``.
+        Returns ``(out [T, H], st_flat)``."""
+        nh, dk = cfg.lightning_num_heads, cfg.lightning_head_dim
+        f32 = jnp.float32
+        q, k, v = (linear(h1, blk[f"la_w{n}"], None).reshape(T, nh, dk) for n in "qkv")
+        q, k = pre_norm(q, blk["la_q_norm_scale"]), pre_norm(k, blk["la_k_norm_scale"])
+        q = apply_rope(q[None], *rope, cfg.rotary_dim)[0].astype(f32) / math.sqrt(dk)
+        k = apply_rope(k[None], *rope, cfg.rotary_dim)[0].astype(f32)
+        slot_li = li * n_slots + state_slots
+        if one_token_rows:
+            o, st_flat = lightning_step(q, k, v, blk["la_slope"], st_flat, slot_li, fresh, n_live, use_pallas=kda_pallas,
+                                        interpret=kda_interpret)
+        else:
+            o, st_flat = lightning_chunks(q, k, v, blk["la_slope"], st_flat, slot_li, fresh, n_tok, use_pallas=kda_pallas,
+                                          interpret=kda_interpret)
+        # a norm over all heads' values with one gain vector, then the output gate
+        o = o.reshape(T, nh * dk)
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + cfg.norm_eps) * blk["la_o_norm_scale"].astype(f32)
+        o = (o * jax.nn.sigmoid(linear(h1, blk["la_wg"], None).astype(f32))).astype(h1.dtype)
+        return linear(o, blk["la_wo"], None), st_flat
+
     def layer(x, blk, l, k_flat, v_flat, ks_flat, vs_flat, stats=None, ws=None, kind=None, kv_alone=False):
         """``kind``: the layer's attention kind, static (None in a model of
         one kind, where ``l`` may be traced); ``stats``: the running MoE
@@ -430,6 +497,9 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
         if kind == "linear_attention":
             attn_out, *ws = linear_mixer(h1, blk, state_index[l], *ws)
             ws = tuple(ws)
+        elif kind == "lightning_attention":
+            attn_out, st = lightning_mixer(h1, blk, state_index[l], ws[0], ropes[kind])
+            ws = (st, ) + tuple(ws[1:])
         elif latent:
             c, nope, dv = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.v_head_dim
             W = k_flat.shape[-1]
@@ -501,8 +571,20 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
             scales = {"k_scale": ks_flat, "v_scale": vs_flat} if quant else {}
             if attn_mask is not None:
                 scales = dict(scales, pos_ids=pid, mask=attn_mask, ctx_pos_ids=ctx_pos_ids)
-            ctx = attend(q, k_flat, v_flat, tables_l, seq_idx, vis_pos, **scales).reshape(T, nq * d)
-        if kind != "linear_attention":
+            if kind == "sparse_attention":
+                # the pooled keys this step completes, then each query's blocks: the work lists follow the data
+                p_flat = update_pooled_keys(cfg, block_size, k_flat, ws[-1], tables_l, seq_idx, pos, valid)
+                picked = select_blocks(cfg, block_size, q, p_flat, tables_l, seq_idx, pos, valid)
+                ws = tuple(ws[:-1]) + (p_flat, )
+                ctx, read = attend(q, k_flat, v_flat, tables_l, seq_idx, vis_pos, selection=picked, **scales)
+                if stats is not None:
+                    stats = stats.at[0].add(nkv * read)
+                if probe:
+                    probes.append((picked[probe_tok], ctx.reshape(T, nq * d)[probe_tok].astype(jnp.float32)))
+            else:
+                ctx = attend(q, k_flat, v_flat, tables_l, seq_idx, vis_pos, **scales)
+            ctx = ctx.reshape(T, nq * d)
+        if kind not in ("linear_attention", "lightning_attention"):
             if cfg.attention_gate:
                 gate = linear(h1, blk["w_attn_gate"], None)
                 ctx = (ctx.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(ctx.dtype)
@@ -530,8 +612,9 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
                 out = out + dense_mlp(h, blk["shared_wi"], blk.get("shared_wg"), blk["shared_wo"])
             return out
 
-        def post(y, name):  # the sandwich norm on a branch's output
-            return pre_norm(y, blk[name]) if cfg.post_norms else y
+        def post(y, name):  # the sandwich norm on a branch's output, and MiniCPM's factor on the branch
+            y = pre_norm(y, blk[name]) if cfg.post_norms else y
+            return y if cfg.residual_scale == 1.0 else (y.astype(jnp.float32) * cfg.residual_scale).astype(y.dtype)
 
         if cfg.parallel_residual:  # GPT-J / NeoX / Falcon
             h2 = h1 if cfg.shared_ln else pre_norm(x, blk["ln2_scale"], blk.get("ln2_bias"))
@@ -553,7 +636,17 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
     dense_layer_keys = ("w_up", "w_gate", "w_down", "b_up", "b_down")
     attention_keys = ("wq", "wk", "wv", "wo", "w_attn_gate", "q_norm_scale", "k_norm_scale")
     if state_index:  # the state pools ride the layers where latent attention's workspace does
-        workspace = (st_flat, cv_flat)
+        workspace = (st_flat, ) if lightning else (st_flat, cv_flat)
+    if sparse:  # ... and the pooled keys behind them
+        workspace = (workspace or ()) + (idx_flat, )
+    probes = []
+    if probe:
+        if not sparse:
+            raise ValueError("probe asked of a model without a block selection")
+        # a row's run this step: its tokens are consecutive and end at last_idx
+        count = jnp.zeros((last_idx.shape[0], ), jnp.int32).at[seq_idx].add(valid.astype(jnp.int32))
+        along = (jnp.maximum(count - 1, 0)[:, None] * jnp.arange(PROBES, dtype=jnp.int32)[None, :]) // (PROBES - 1)
+        probe_tok = jnp.maximum(last_idx - jnp.maximum(count - 1, 0), 0)[:, None] + along
     first_expert_layer = cfg.moe_num_dense_layers if moe is not None else 0
     mixed_mlp = first_expert_layer > 0
     experts = {k: v for k, v in params["blocks"].items() if k in expert_keys}
@@ -561,7 +654,7 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
 
     def index_of(name, l):
         """Layer ``l``'s index into the stacked array ``name``; None: it has none there."""
-        if state_index and name.startswith("kda_"):
+        if state_index and name.startswith(("kda_", "la_")):
             return state_index.get(l)
         if state_index and name in attention_keys:
             return kv_index.get(l)
@@ -599,7 +692,14 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
     # logits_gather semantics: final norm + unembed only each sequence's
     # last token, through the pluggable unembed module
     logits = None if kv_only else unembed(params, x, last_idx)
+    if logits is not None and cfg.logit_scale != 1.0:
+        logits = logits * cfg.logit_scale
+    if sparse:
+        pools += (workspace[-1].reshape(index_pool.shape), )
     if state_index:
         pools += tuple(flat.reshape(pool.shape) for flat, pool in zip(workspace, state_pools))
     out = (logits, ) + pools + ((ks_flat, vs_flat) if quant else ())
-    return out + (stats, ) if moe_stats else out
+    out = out + (stats, ) if moe_stats else out
+    if probe:
+        out += ((pos[probe_tok], jnp.stack([p for p, _ in probes], axis=2), jnp.stack([c for _, c in probes], axis=2)), )
+    return out

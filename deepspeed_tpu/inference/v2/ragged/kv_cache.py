@@ -35,7 +35,19 @@ handed out by an allocator of their own (:meth:`reserve_state` /
 :meth:`free_state`) and threaded through the compiled forwards after the K/V
 pools. ``num_layers`` is then the layers that cache K and V alone. A slot is
 not a block: nothing shares it, copies it or hashes it, and what moves blocks
-about (the prefix cache, the host tier, export) does not know it.
+about (the prefix cache, the host tier, export) does not know it. A lightning
+layer's state is the state pool alone (``state_entry`` of one part: no tail).
+
+A model with a learned block-sparse SELECTION (``TransformerConfig.index_entry``)
+has a third kind: one mean-pooled key every ``stride`` tokens a KV head,
+
+    index_pool [num_layers, num_blocks * block_size / stride, heads, width]
+
+ON the K/V blocks' table: a block's pooled keys are the ``block_size / stride``
+entries under its id, so they are allocated, freed and tracked with the block
+and by nothing else. Like the state it rides the compiled forwards (after the
+K/V pools, before the state's) and is unknown to what copies, shares or moves
+blocks, which refuse such a model by name.
 """
 
 from typing import Optional, Tuple
@@ -59,12 +71,14 @@ class BlockedKVCache:
 
     def __init__(self, num_layers: int, num_kv_heads: int, head_dim: int, num_blocks: int, block_size: int = 64,
                  dtype=jnp.bfloat16, sharding=None, entry=None, state_entry=(), state_layers: int = 0,
-                 state_slots: int = 0):
+                 state_slots: int = 0, index_entry=()):
         """``entry``: the model's ``kv_entry``; None = per-head K and V of
         ``num_kv_heads`` x ``head_dim``. ``num_kv_heads`` / ``head_dim`` are
         then the first part's heads and width. ``state_entry``: the model's
         ``state_entry`` (``()``: none), held by ``state_layers`` layers in
-        ``state_slots`` slots; ``num_layers`` counts the K/V layers alone."""
+        ``state_slots`` slots; ``num_layers`` counts the K/V layers alone.
+        ``index_entry``: the model's ``index_entry`` ``(stride, heads, width)``
+        (``()``: no pooled keys)."""
         if entry is None:
             entry = ((num_kv_heads, head_dim), ) * 2
         self.entry = tuple((int(h), int(w)) for h, w in entry)
@@ -86,14 +100,22 @@ class BlockedKVCache:
         self.k_pool = jnp.zeros(shapes[0], dtype)
         self.v_pool = jnp.zeros(shapes[1], dtype) if len(shapes) == 2 else None
         self.k_scale = self.v_scale = None
-        self.state_pool = self.tail_pool = self._state_allocator = None
+        self.state_pool = self.tail_pool = self._state_allocator = self.index_pool = None
+        if index_entry:
+            stride, heads, width = (int(x) for x in index_entry)
+            if self.quantized or sharding is not None or len(self.entry) != 2 or self.block_size % stride:
+                raise NotImplementedError("pooled keys (a learned block selection) beside an int8, a sharded or a "
+                                          f"latent KV cache, or KV blocks ({self.block_size}) that hold no whole "
+                                          f"number of pooling strides ({stride})")
+            self.index_pool = jnp.zeros((num_layers, self.num_blocks * self.block_size // stride, heads, width), dtype)
         if state_entry:
             if self.quantized or sharding is not None:
                 raise NotImplementedError("a recurrent state beside an int8 or a sharded KV cache: the state is "
                                           "float32 by the model's statement and lives whole on one device")
-            state_shape, tail_shape = state_entry
+            state_shape, *tail_shape = state_entry
             self.state_pool = jnp.zeros((state_layers, state_slots) + tuple(state_shape), jnp.float32)
-            self.tail_pool = jnp.zeros((state_layers, state_slots) + tuple(tail_shape), dtype)
+            if tail_shape:  # the delta rule's convolution tail; a lightning layer has none
+                self.tail_pool = jnp.zeros((state_layers, state_slots) + tuple(tail_shape[0]), dtype)
             self._state_allocator = BlockedAllocator(state_slots)
         if self.quantized:
             # [nkv, L * NB * bs] — kv-heads on sublanes, slots on lanes: the
@@ -152,7 +174,16 @@ class BlockedKVCache:
         """Bytes ONE sequence holds in ONE state layer (state and tail)."""
         if not self.has_state:
             return 0
-        return sum(p[0, 0].size * p.dtype.itemsize for p in (self.state_pool, self.tail_pool))
+        return sum(p[0, 0].size * p.dtype.itemsize for p in (self.state_pool, self.tail_pool) if p is not None)
+
+    # -- the pooled keys of a model with a learned block selection -----------
+    @property
+    def has_index(self) -> bool:
+        return self.index_pool is not None
+
+    def index_entry_bytes(self) -> int:
+        """Bytes ONE pooled key holds in ONE layer (every kv head's)."""
+        return self.index_pool[0, 0].size * self.index_pool.dtype.itemsize if self.has_index else 0
 
     @property
     def total_blocks(self) -> int:
@@ -277,15 +308,19 @@ class BlockedKVCache:
         names = tuple(self._parts())
         if self.quantized:
             names += ("k_scale", "v_scale")
+        if self.has_index:
+            names += ("index_pool", )
         if self.has_state:
-            names += ("state_pool", "tail_pool")
+            names += ("state_pool", ) if self.tail_pool is None else ("state_pool", "tail_pool")
         return names
 
     def pools(self):
         """The donated pool tuple the compiled forwards thread through:
         (k, v) full-precision, (k, v, k_scale, v_scale) quantized, (latent, )
         for a latent entry, (k, v, state, tails) for a model with state
-        layers."""
+        layers (the delta rule's; (k, v, state) for lightning layers), the
+        pooled keys of a model with a block selection before the state's: (k,
+        v, index, state)."""
         return tuple(getattr(self, name) for name in self._pool_names())
 
     def update(self, *pools) -> None:
@@ -298,6 +333,8 @@ class BlockedKVCache:
         n = sum(p.size * p.dtype.itemsize for p in self.pools()[:len(self.entry)])
         if self.quantized:
             n += 2 * self.k_scale.size * 4
+        if self.has_index:  # a block's pooled keys are part of what the block holds
+            n += self.index_pool.size * self.index_pool.dtype.itemsize
         return n
 
     def block_bytes(self) -> int:

@@ -110,6 +110,11 @@ class PrefixKVCache:
                 "PrefixKVCache for a model with a recurrent state layer: a shared prefix gives a new sequence K/V "
                 "blocks and no state; the state at a block boundary would have to be snapshot into the tree beside "
                 "the block, which is not built")
+        if getattr(kv_cache, "has_index", False):
+            raise NotImplementedError(
+                "PrefixKVCache for a model with pooled keys (a learned block selection): a pooling kernel straddles "
+                "block boundaries, so a shared block's pooled keys depend on the block after it; copy-on-write and "
+                "the tree's hashes would have to carry the pooled keys beside the block, which is not built")
         self.kv_cache = kv_cache
         self.block_size = kv_cache.block_size
         self.min_hit_blocks = int(min_hit_blocks)

@@ -27,7 +27,7 @@ class DSStateManager:
 
     def __init__(self, num_layers: int, num_kv_heads: int, head_dim: int, *, max_tracked_sequences: int = 128,
                  num_blocks: int = 256, block_size: int = 64, dtype=jnp.bfloat16, kv_sharding=None,
-                 prefix_cache_config=None, kv_entry=None, state_entry=(), state_layers: int = 0):
+                 prefix_cache_config=None, kv_entry=None, state_entry=(), state_layers: int = 0, index_entry=()):
         """``kv_entry``: the model's ``TransformerConfig.kv_entry`` (None =
         per-head K and V of ``num_kv_heads`` x ``head_dim``); ``num_layers``:
         the layers that cache it. ``state_entry``: what a sequence holds in
@@ -35,12 +35,16 @@ class DSStateManager:
         ``()``: a model without): one slot a tracked sequence, taken when the
         sequence is created and freed when it is flushed. What takes a
         sequence's state to be its blocks refuses such a model by name:
-        ``PrefixKVCache``, ``TieredBlockStore``, a rewind in :meth:`rollback_to`."""
+        ``PrefixKVCache``, ``TieredBlockStore``, a rewind in :meth:`rollback_to`.
+        ``index_entry``: the pooled keys a model with a learned block selection
+        caches beside K and V (``TransformerConfig.index_entry``), on the
+        blocks' own table; the same three refuse it."""
         self.max_tracked_sequences = max_tracked_sequences
         self.block_size = block_size
         self.kv_cache = BlockedKVCache(num_layers, num_kv_heads, head_dim, num_blocks, block_size, dtype=dtype,
                                        sharding=kv_sharding, entry=kv_entry, state_entry=state_entry,
-                                       state_layers=state_layers, state_slots=max_tracked_sequences)
+                                       state_layers=state_layers, state_slots=max_tracked_sequences,
+                                       index_entry=index_entry)
         self.prefix_cache: Optional[PrefixKVCache] = None
         # host/disk capacity tier under the radix tree (tiered_store.py);
         # None whenever ragged.prefix_cache.host_tier is absent/disabled —
@@ -250,11 +254,12 @@ class DSStateManager:
         about to be flushed: finish/cancel paths), so a shared partial tail
         is harmless and a dry pool cannot fail a terminal rewind."""
         n_tokens = int(n_tokens)
-        if self.kv_cache.has_state and not final and n_tokens != seq.seen_tokens:
+        if (self.kv_cache.has_state or self.kv_cache.has_index) and not final and n_tokens != seq.seen_tokens:
             raise NotImplementedError(
                 f"rollback_to({n_tokens}) of sequence {seq.uid} at {seq.seen_tokens} tokens: a model with a "
-                "recurrent state layer has consumed the tokens to be rewound and keeps no snapshot to return to "
-                "(a terminal rewind, final=True, is allowed: the state goes with the sequence)")
+                "recurrent state layer has consumed the tokens to be rewound and keeps no snapshot to return to, and "
+                "one with pooled keys (a learned block selection) has pooled the rewound tokens' keys into entries "
+                "that earlier tokens share (a terminal rewind, final=True, is allowed: both go with the sequence)")
         if not 0 <= n_tokens <= seq.seen_tokens:
             raise ValueError(f"rollback_to({n_tokens}): sequence {seq.uid} has "
                              f"{seq.seen_tokens} materialized tokens")

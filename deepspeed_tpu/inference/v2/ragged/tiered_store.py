@@ -160,6 +160,10 @@ class TieredBlockStore:
             raise NotImplementedError(
                 "TieredBlockStore for a model with a recurrent state layer: the host tier moves K/V blocks and a "
                 "sequence's state is no block; it would have to travel beside them, which is not built")
+        if getattr(kv_cache, "has_index", False):
+            raise NotImplementedError(
+                "TieredBlockStore for a model with pooled keys (a learned block selection): the host tier moves K/V "
+                "blocks, and a block's pooled keys would have to travel beside it, which is not built")
         self.kv_cache = kv_cache
         self.config = config
         n = int(getattr(config, "host_blocks", 0) or 0)

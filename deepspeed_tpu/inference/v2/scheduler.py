@@ -397,6 +397,9 @@ class DynamicSplitFuseScheduler:
         # sampling rides down only when some row actually samples — an
         # all-greedy burst keeps the original argmax scan program
         samp = [r.sampling for r in decoding] if any(r.sampled for r in decoding) else None
+        # a replica that warmed FEWER horizons than the six (each is a program of the whole model: seconds of its
+        # start-up) is served by the longest one it has; with all six warmed this is the horizon above
+        horizon = self.engine.compiled_horizon(len(decoding), horizon, sampled=samp is not None)
         t0 = time.perf_counter()
         toks = np.asarray(self.engine.decode(uids, first, horizon, eos_token_ids=eos,
                                              sampling=samp))  # [S, horizon]

@@ -14,3 +14,4 @@ from .trinity import trinity, trinity_config
 from .sdar import sdar, sdar_config
 from .glm import glm, glm_config
 from .solar import solar, solar_config
+from .minicpm import minicpm, minicpm_config

@@ -37,6 +37,11 @@ from ..parallel.mesh import BATCH_AXES, DATA_AXIS, MODEL_AXIS, PIPE_AXIS, SEQ_AX
 from ..runtime.zero.partition import PartitionRules
 
 
+# what ``layer_types`` may call a layer; those of ``STATE_KINDS`` cache no token and hold a state a sequence
+LAYER_KINDS = ("sliding_attention", "full_attention", "sparse_attention", "linear_attention", "lightning_attention")
+STATE_KINDS = ("linear_attention", "lightning_attention")
+
+
 @dataclass
 class TransformerConfig:
     vocab_size: int = 32000
@@ -183,6 +188,41 @@ class TransformerConfig:
     kda_conv_size: int = 4
     kda_gate_rank: int = 128
     kda_neg_eigval: bool = True
+    # Lightning attention (scalar-decay linear attention, MiniCPM-SALA): the
+    # layers ``layer_types`` calls "lightning_attention" cache nothing per
+    # token either and carry a float32 state of ``lightning_num_heads`` x
+    # ``lightning_head_dim`` x ``lightning_head_dim`` a sequence and nothing
+    # else: ``S_t = lambda_h S_{t-1} + k_t v_t^T``, ``o_t = S_t^T q_t``, with
+    # ``lambda_h = exp(-s_h)`` a constant of the head and the layer (``s_h =
+    # 2^(-8 (h + 1) / heads) (1 - l / (L - 1) + 1e-5)`` at the PUBLISHED layer
+    # index ``l = lightning_layer_offset + layer`` of ``lightning_layers_published``
+    # layers), q and k normed a head and roped, no convolution, no delta rule.
+    # Served by the ragged path alone (``ops/pallas/lightning.py``). 0 heads = none
+    lightning_num_heads: int = 0
+    lightning_head_dim: int = 128
+    lightning_layer_offset: int = 0
+    lightning_layers_published: Optional[int] = None
+    # learned block-sparse selection (InfLLM v2): the layers ``layer_types``
+    # calls "sparse_attention" cache, beside K and V, one mean-pooled key every
+    # ``sparse_kernel_stride`` tokens over ``sparse_kernel_size`` tokens a KV
+    # head (``index_entry``), score the blocks of ``sparse_block_size`` tokens
+    # with them and attend, past ``sparse_dense_len`` tokens of context, the
+    # ``sparse_topk`` highest-scoring blocks alone, the first
+    # ``sparse_init_blocks`` and those of the last ``sparse_window_size``
+    # tokens always among them (``models/minicpm.py`` has the equations).
+    # 0 = every softmax layer attends every visible token
+    sparse_topk: int = 0
+    sparse_kernel_size: int = 32
+    sparse_kernel_stride: int = 16
+    sparse_block_size: int = 64
+    sparse_init_blocks: int = 1
+    sparse_window_size: int = 2048
+    sparse_dense_len: int = 8192
+    # MiniCPM's scaled residual path: each branch's output times
+    # ``residual_scale`` before it is added, the final normed hidden state times
+    # ``logit_scale`` before the head (``embed_scale`` is the third of them)
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0
     # ZeRO++ qwZ (reference partition_parameters.py:1139 quantized all-gather
     # handles): when set (by the engine, from zero_quantized_weights), the
     # per-layer stage-3 weight gathers inside the scan body travel as int8
@@ -240,20 +280,43 @@ class TransformerConfig:
                                           "positions, a q/k norm, a gate, biases or block diffusion")
         if self.layer_types is not None:
             self.layer_types = tuple(self.layer_types)
-            unknown = set(self.layer_types) - {"sliding_attention", "full_attention", "linear_attention"}
+            unknown = set(self.layer_types) - set(LAYER_KINDS)
             if unknown or len(self.layer_types) != self.num_layers:
-                raise ValueError(f"layer_types needs {self.num_layers} entries of 'sliding_attention', "
-                                 f"'full_attention' or 'linear_attention', got {len(self.layer_types)} with "
-                                 f"{sorted(unknown)}")
-        if bool(self.state_layers) != (self.kda_num_heads > 0):
-            raise ValueError(f"kda_num_heads={self.kda_num_heads} with {len(self.state_layers)} 'linear_attention' "
-                             "layers in layer_types: the one names the other")
+                raise ValueError(f"layer_types needs {self.num_layers} entries of {', '.join(LAYER_KINDS)}, got "
+                                 f"{len(self.layer_types)} with {sorted(unknown)}")
+        # ``layer_types`` says which kind a layer is; the widths of a kind are stated if and only if it occurs
+        for kind, heads, name in (("linear_attention", self.kda_num_heads, "kda_num_heads"),
+                                  ("lightning_attention", self.lightning_num_heads, "lightning_num_heads"),
+                                  ("sparse_attention", self.sparse_topk, "sparse_topk")):
+            n = (self.layer_types or ()).count(kind)
+            if bool(n) != (heads > 0):
+                raise ValueError(f"{name}={heads} with {n} {kind!r} layers in layer_types: the one names the other")
+        if self.kda_num_heads > 0 and self.lightning_num_heads > 0:
+            raise NotImplementedError("'linear_attention' (the delta rule) and 'lightning_attention' (scalar decay) "
+                                      "layers in one model: a sequence's state slot holds one kind")
+        if self.lightning_num_heads > 0 and (self.lightning_head_dim != self.head_dim or self.lightning_head_dim % 2):
+            raise NotImplementedError(f"lightning_head_dim={self.lightning_head_dim} beside heads of {self.head_dim}: "
+                                      "the lightning layers rotate q and k with the softmax layers' one rope table")
+        if self.sparse_topk > 0:
+            kinds = {self.layer_types[l] for l in self.kv_layers}
+            sc = (self.sparse_kernel_size, self.sparse_kernel_stride, self.sparse_block_size)
+            if kinds != {"sparse_attention"} or self.sliding_window is not None or self.latent_attention \
+                    or self.diffusion_block_size or self.positions == "alibi":
+                raise NotImplementedError("a learned selection beside softmax layers without one, a sliding window, "
+                                          "latent attention, block diffusion or alibi: one pool of pooled keys is "
+                                          "stacked over every layer that caches K and V")
+            if sc[0] != 2 * sc[1] or sc[2] % sc[1] or self.sparse_window_size < sc[0] + sc[2] \
+                    or self.sparse_topk < self.sparse_init_blocks + self.sparse_window_size // sc[2] + 2 \
+                    or self.sparse_dense_len < self.sparse_topk * sc[2]:
+                raise ValueError(f"sparse selection: kernel {sc[0]} = 2 x stride {sc[1]}, block {sc[2]} a multiple of "
+                                 f"the stride, window {self.sparse_window_size} >= kernel + block, topk "
+                                 f"{self.sparse_topk} over the forced blocks, dense_len {self.sparse_dense_len} >= topk blocks")
         if self.state_layers:
             if not self.kv_layers:
                 raise NotImplementedError("every layer 'linear_attention': the paged cache and its block tables "
                                           "are built for at least one layer that caches K and V")
             if self.use_bias or self.qkv_bias_enabled or self.diffusion_block_size or self.parallel_residual \
-                    or self.positions in ("alibi", "learned") or self.kda_conv_size < 2:
+                    or self.positions in ("alibi", "learned") or (self.kda_num_heads > 0 and self.kda_conv_size < 2):
                 raise NotImplementedError("linear attention beside biases, block diffusion, a parallel residual, "
                                           "alibi or learned positions, or without its short convolution")
         if self.intermediate_size is None:
@@ -305,8 +368,9 @@ class TransformerConfig:
     @property
     def state_layers(self) -> Tuple[int, ...]:
         """The layers that carry a recurrent state per sequence and cache
-        nothing per token (``layer_types`` says 'linear_attention')."""
-        return tuple(l for l, kind in enumerate(self.layer_types or ()) if kind == "linear_attention")
+        nothing per token (``layer_types`` says 'linear_attention', the delta
+        rule, or 'lightning_attention', scalar decay)."""
+        return tuple(l for l, kind in enumerate(self.layer_types or ()) if kind in STATE_KINDS)
 
     @property
     def kv_layers(self) -> Tuple[int, ...]:
@@ -320,11 +384,23 @@ class TransformerConfig:
         """What ONE sequence holds in ONE state layer, whatever its length:
         the float32 state ``(heads, key width, value width)`` and the
         convolution's tail ``(taps - 1, channels of q, k and v)`` in the
-        compute type. ``()`` for a model without state layers."""
+        compute type (the delta rule's layers; a lightning layer holds the
+        state alone). ``()`` for a model without state layers."""
         if not self.state_layers:
             return ()
+        if self.lightning_num_heads > 0:
+            return ((self.lightning_num_heads, self.lightning_head_dim, self.lightning_head_dim), )
         h, d = self.kda_num_heads, self.kda_head_dim
         return ((h, d, d), (self.kda_conv_size - 1, 3 * h * d))
+
+    @property
+    def index_entry(self) -> Tuple[int, ...]:
+        """What a sparse layer caches beside K and V: ``(stride, heads,
+        width)``, one pooled key of ``(heads, width)`` every ``stride`` tokens,
+        on the K/V blocks' own table. ``()`` for a model without selection."""
+        if self.sparse_topk <= 0:
+            return ()
+        return (self.sparse_kernel_stride, self.num_kv_heads, self.head_dim)
 
     def layer_kind(self, l: int) -> Optional[str]:
         """Attention kind of layer ``l``; None where the model has one kind."""
@@ -355,8 +431,12 @@ class TransformerConfig:
         if self.layer_types is not None:
             why.append(f"layer_types gives each layer its own window and rope ({sorted(set(self.layer_types))})")
         if self.state_layers:
-            why.append(f"{len(self.state_layers)} linear-attention layer(s) (a delta-rule state per sequence, which "
+            rule = "scalar-decay (lightning)" if self.lightning_num_heads > 0 else "delta-rule"
+            why.append(f"{len(self.state_layers)} linear-attention layer(s) (a {rule} state per sequence, which "
                        "no whole-sequence forward builds)")
+        if self.sparse_topk > 0:
+            why.append(f"a learned block-sparse selection (top {self.sparse_topk} blocks of {self.sparse_block_size} "
+                       "over pooled keys cached beside K and V, which no whole-sequence forward builds)")
         if self.moe_num_experts > 0 and self.moe_num_dense_layers > 0:
             why.append(f"{self.moe_num_dense_layers} leading dense layer(s) before the expert layers: two MLP kinds")
         if self.experts_held != self.moe_num_experts:
@@ -368,6 +448,7 @@ class TransformerConfig:
                            (self.post_norms, "norms after attention and MLP"),
                            (self.rope_layer_types is not None, "rope in some layer kinds only"),
                            (self.embed_scale != 1.0, "a scaled embedding"),
+                           (self.residual_scale != 1.0 or self.logit_scale != 1.0, "scaled residual branches and head input"),
                            (self.latent_attention,
                             f"latent attention (a cached latent of {self.kv_lora_rank} + {self.qk_rope_head_dim}, "
                             "attended in the absorbed form)"),
@@ -377,6 +458,19 @@ class TransformerConfig:
             if flag:
                 why.append(what)
         return tuple(why)
+
+
+def lightning_slopes(cfg: TransformerConfig) -> np.ndarray:
+    """``s_h`` of every lightning layer's heads, ``[layers, heads]`` float32:
+    ``2^(-8 (h + 1) / heads) (1 - l / (L - 1) + 1e-5)`` at the published layer
+    index ``l`` of ``L`` published layers (Lightning Attention-2,
+    arXiv:2401.04658); a head's decay a token is ``exp(-s_h)``."""
+    nh = cfg.lightning_num_heads
+    L = cfg.lightning_layers_published or cfg.num_layers
+    head = 2.0 ** (-8.0 * (np.arange(nh, dtype=np.float64) + 1.0) / nh)
+    layer = np.asarray([1.0 - (cfg.lightning_layer_offset + l) / max(L - 1, 1) + 1e-5
+                        for l in cfg.state_layers], np.float64)
+    return (layer[:, None] * head[None, :]).astype(np.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -398,7 +492,7 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
     def gain(key, shape):
         """A norm's gain: one, unless the family's extra norms are on, whose
         gains are drawn about one so that leaving a norm out shows."""
-        if not (cfg.post_norms or cfg.qk_norm or cfg.latent_attention):
+        if not (cfg.post_norms or cfg.qk_norm or cfg.latent_attention or cfg.lightning_num_heads):
             return jnp.ones(shape, jnp.float32)
         return 1.0 + 0.1 * jax.random.normal(key, shape, jnp.float32)
 
@@ -411,7 +505,7 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
         "wo": dense_init(k[3], (La, nq * d, H), nq * d) / math.sqrt(2 * L),
         "ln2_scale": gain(extra(13), (L, H)),
     }
-    if cfg.state_layers:
+    if cfg.kda_num_heads > 0:
         # linear attention (KDA), stacked over the state layers alone: q, k, v
         # with their depthwise convolutions ``[taps, channels]``, the decay's
         # and the output gate's two low-rank matrices, ``A_log`` a head and
@@ -432,6 +526,20 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
             kda_dt_bias=jax.random.uniform(extra(52), (Ll, C), jnp.float32, -4.5, -1.0),
             kda_o_norm_scale=1.0 + 0.1 * jax.random.normal(extra(53), (Ll, dk), jnp.float32),
             kda_wo=dense_init(extra(54), (Ll, C, H), C) / math.sqrt(2 * L))
+    if cfg.lightning_num_heads > 0:
+        # lightning attention, stacked over its layers alone: q, k, v, the output gate and ``W_o``, the two
+        # per-head norms' gain vectors, the output norm's one gain vector over all heads' values, and the
+        # decay's exponent a head (float32, a constant of the head and the PUBLISHED layer, not a weight)
+        Ll, nh, dk = len(cfg.state_layers), cfg.lightning_num_heads, cfg.lightning_head_dim
+        C = nh * dk
+        for i, name in enumerate(("q", "k", "v", "g")):
+            blocks[f"la_w{name}"] = dense_init(extra(60 + i), (Ll, H, C), H)
+        blocks.update(
+            la_wo=dense_init(extra(64), (Ll, C, H), C) / math.sqrt(2 * L),
+            la_q_norm_scale=1.0 + 0.1 * jax.random.normal(extra(65), (Ll, dk), jnp.float32),
+            la_k_norm_scale=1.0 + 0.1 * jax.random.normal(extra(66), (Ll, dk), jnp.float32),
+            la_o_norm_scale=1.0 + 0.1 * jax.random.normal(extra(67), (Ll, C), jnp.float32),
+            la_slope=jnp.asarray(lightning_slopes(cfg), jnp.float32))
     if cfg.latent_attention:
         # the two low-rank projections with their norms, and ``W_kvb`` as the
         # two parts the absorbed form multiplies by, a head at a time: keys

@@ -154,7 +154,7 @@ def choose_kernel(T: int, S: int, max_blocks: int, nq: int, block_rows: int, d: 
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: int, window=None,
-                    alibi=None, k_scale=None, v_scale=None, value_dim=None, softmax_scale=None):
+                    alibi=None, k_scale=None, v_scale=None, value_dim=None, softmax_scale=None, selection=None):
     """q: [T, nq, d]; k_pool/v_pool: [pool_len, nkv, d] (one layer,
     pool_len = num_blocks*block_size, may include one trailing scratch slot);
     block_tables: [S, max_blocks]; seq_idx/pos: [T].
@@ -178,8 +178,22 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: i
     a table column's block of every head in a grid step and reads each where
     it lies). No int8 scales there, and no decode kernel: the caller's rows
     are long.
+    ``selection`` ``[T, nkv, max_blocks]`` bool: a learned block-sparse
+    selection, computed in the program from the queries themselves: token
+    ``t``'s heads of kv head ``n`` attend, of the keys at or before its
+    position, those of the table columns ``j`` with ``selection[t, n, j]``
+    alone. Both kernels' work lists then lay grid steps for selected blocks
+    alone: a tile of several tokens (and a block of several kv heads) reads
+    the UNION of its tokens' and heads' blocks and masks per token and head
+    inside (:func:`_tiled_work_list`, :func:`_decode_work_list`). No window,
+    alibi, int8 scales, latent pool or pools by head beside it. None: every
+    visible block, the lists and bodies as they were.
     The kernel and its tile are :func:`choose_kernel`'s, from the shapes.
-    Returns [T, nq, d] (``[T, nq, value_dim]`` over a latent pool)."""
+    Returns [T, nq, d] (``[T, nq, value_dim]`` over a latent pool); under a
+    ``selection`` ``(out, read)``, ``read`` the int32 count of (query token,
+    table column) pairs the work list that ran served: for every item it laid,
+    the tokens of the item's tile at or after the column (a tile's union is
+    what the kernel fetches; the gather counts a token's own columns)."""
     T, nq, d = q.shape
     nkv = k_pool.shape[0 if k_pool.ndim == 4 else 1]
     S, max_blocks = block_tables.shape
@@ -190,6 +204,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: i
     latent = v_pool is None
     if latent and (nkv != 1 or k_scale is not None or alibi is not None or not value_dim):
         raise ValueError("a latent pool is [pool_len, 1, d] with value_dim lanes of value, no int8 scales, no alibi")
+    _check_selection(selection, T, nkv, max_blocks, window, alibi, k_scale, latent or k_pool.ndim == 4)
     choice = choose_kernel(T, S, max_blocks, nq, block_size * nkv, d, k_pool.dtype.itemsize, seq_idx, pos,
                            parts=1 if latent else 2, kv_by_head=nkv if k_pool.ndim == 4 else 0)
     _note_choice(T, S, max_blocks, choice)
@@ -205,7 +220,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: i
                          "nq>=8, d%128==0) — serving through the DENSE gather fallback")
         return paged_attention_reference(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size,
                                          window=window, alibi=alibi, k_scale=k_scale, v_scale=v_scale,
-                                         value_dim=value_dim, softmax_scale=softmax_scale)
+                                         value_dim=value_dim, softmax_scale=softmax_scale, selection=selection)
     if k_scale is not None and block_size % 128 != 0:
         # the scale block (nkv, block_size) must be lane-aligned: the TPU
         # lowering rejects it otherwise, with a message that names neither
@@ -221,12 +236,33 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: i
     return _pallas_paged(q, k_pool, v_pool, block_tables, seq_idx.astype(jnp.int32),
                          pos.astype(jnp.int32), block_size=block_size, window=window,
                          alibi=alibi_t, k_scale=k_scale, v_scale=v_scale, q_tile=choice["q_tile"],
-                         value_dim=value_dim, softmax_scale=softmax_scale)
+                         value_dim=value_dim, softmax_scale=softmax_scale, selection=selection)
+
+
+# kv heads a selection's mask holds a (tile, KV block) pair at most (whole bf16 registers of 16 sublanes)
+_SELECTION_HEADS = 32
+
+
+def _mask_heads(nkv: int) -> int:
+    """Sublanes of a (tile, KV block) pair's mask: the kv heads in whole bf16 registers."""
+    return -(-nkv // 16) * 16
+
+
+def _check_selection(selection, T: int, nkv: int, max_blocks: int, window, alibi, k_scale, other_pool: bool):
+    if selection is None:
+        return
+    if tuple(selection.shape) != (T, nkv, max_blocks) or nkv > _SELECTION_HEADS:
+        raise ValueError(f"a selection is [tokens, kv heads (at most {_SELECTION_HEADS}), table columns] = "
+                         f"{(T, nkv, max_blocks)}, got {tuple(selection.shape)}")
+    if window is not None or alibi is not None or k_scale is not None or other_pool:
+        raise NotImplementedError("a block selection beside a sliding window, alibi, int8 KV, a latent pool or pools "
+                                  "by head: the selected lists and masks are built for plain K and V pools")
 
 
 def paged_attention_reference(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: int,
                               window=None, alibi=None, k_scale=None, v_scale=None,
-                              pos_ids=None, mask=None, ctx_pos_ids=None, value_dim=None, softmax_scale=None):
+                              pos_ids=None, mask=None, ctx_pos_ids=None, value_dim=None, softmax_scale=None,
+                              selection=None):
     """Gather-based oracle: materializes each sequence's context. ``alibi``:
     per-head slopes [nq] (Bloom). ``k_scale``/``v_scale``: int8-KV
     dequantization factors [nkv, pool_len] (see ``paged_attention``).
@@ -267,10 +303,17 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables, seq_idx, pos, blo
         causal = jnp.arange(C, dtype=jnp.int32)[None, :] <= pos[:, None]
         if window is not None:
             causal = causal & (pos[:, None] - jnp.arange(C, dtype=jnp.int32)[None, :] < window)
-    s = jnp.where(causal[:, None, None, :], s, -1e30)
+    causal = causal[:, None, None, :]
+    if selection is not None:  # [T, nkv, max_blocks] over the blocks' tokens, for every head of a kv head's group
+        causal = causal & jnp.repeat(selection, block_size, axis=2)[:, :, None, :]
+    s = jnp.where(causal, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("tngc,tcnd->tngd", p, ctxv[seq_idx])
-    return out.reshape(T, nq, ctxv.shape[-1]).astype(q.dtype)
+    out = out.reshape(T, nq, ctxv.shape[-1]).astype(q.dtype)
+    if selection is None:
+        return out
+    own = jnp.arange(selection.shape[2], dtype=jnp.int32)[None, :] <= (pos // block_size)[:, None]
+    return out, jnp.sum(jnp.any(selection, axis=1) & own & (pos >= 0)[:, None], dtype=jnp.int32)
 
 
 def _slopes_rows(alibi, reps):
@@ -298,7 +341,7 @@ def _slopes_tok_major(alibi_g, rows):
                                              "softmax_scale"))
 def _pallas_paged(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: int, interpret: bool = False,
                   window=None, alibi=None, k_scale=None, v_scale=None, q_tile: int = 1, value_dim=None,
-                  softmax_scale=None):
+                  softmax_scale=None, selection=None):
     """The kernel the caller names, with no choice of its own: ``q_tile``
     above 1 runs ``paged_attn_q_tiled`` at that tile, 1 (one query token a
     grid row) the decode kernel ``paged_attn_kv_split``. ``paged_attention``
@@ -309,6 +352,8 @@ def _pallas_paged(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: int
     from jax.experimental.pallas import tpu as pltpu
 
     d = q.shape[2]
+    _check_selection(selection, q.shape[0], k_pool.shape[0 if k_pool.ndim == 4 else 1], block_tables.shape[1], window,
+                     alibi, k_scale, v_pool is None or k_pool.ndim == 4)
     if k_pool.ndim == 4:  # pools by head, which only the tiled kernel reads
         if q_tile <= 1 or k_scale is not None:
             raise NotImplementedError("pools by head [nkv, blocks, block, d] are the tiled kernel's, without int8 scales")
@@ -330,13 +375,14 @@ def _pallas_paged(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: int
     if q_tile > 1:
         return _paged_q_tiled(pl, pltpu, q, as_rows(k_pool), as_rows(v_pool), block_tables, seq_idx, pos,
                               ks2, vs2, block_size=block_size, q_tile=q_tile, window=window,
-                              alibi=alibi, interpret=interpret, value_dim=value_dim, softmax_scale=softmax_scale)
+                              alibi=alibi, interpret=interpret, value_dim=value_dim, softmax_scale=softmax_scale,
+                              selection=selection)
     # the decode kernel takes the int8 scales [nkv, cols] laid out to match the rows
     by_col = lambda sc: jnp.transpose(sc).reshape(n_pool_blocks, 1, block_size * nkv)
     return _paged_kv_split(pl, pltpu, q, as_rows(k_pool), as_rows(v_pool), block_tables, seq_idx,
                            pos, by_col(ks2) if quant else None, by_col(vs2) if quant else None,
                            block_size=block_size, window=window, alibi=alibi, interpret=interpret,
-                           value_dim=value_dim, softmax_scale=softmax_scale)
+                           value_dim=value_dim, softmax_scale=softmax_scale, selection=selection)
 
 
 # tokens of a tile's rows that the short pass of ``_paged_q_tiled`` covers: a
@@ -502,7 +548,7 @@ def _tile_columns(tile_min, tile_max, tile_cnt, block_size: int, max_blocks: int
     return lo, xp.where((tile_cnt > 0) & (tile_max >= 0), hi - lo + 1, 0), cols
 
 
-def _tiled_work_list(block_tables, seq_idx, pos, block_size: int, window, q_tile: int):
+def _tiled_work_list(block_tables, seq_idx, pos, block_size: int, window, q_tile: int, selection=None):
     """The tiles of a ragged batch and the LIVE (tile, KV block) pairs of
     their grid, as the int32 arrays ``paged_attn_q_tiled`` prefetches.
 
@@ -531,7 +577,18 @@ def _tiled_work_list(block_tables, seq_idx, pos, block_size: int, window, q_tile
     tile's items by a scatter of ``n_tiles`` steps and a running sum: a
     gather an item (the first form of this list: four of 6,306 elements)
     cost a call of 64 rows 0.24 ms of XLA time beside a kernel of 1.6 ms (my
-    chip run, PR 34)."""
+    chip run, PR 34).
+
+    Under a ``selection`` ``[T, nkv, max_blocks]`` (``paged_attention``) a
+    tile's items are the columns, among those above, that ANY of its tokens
+    selects for ANY kv head, still consecutive and ascending, found by one
+    compaction of the ``[n_tiles, max_blocks]`` table of such pairs (so a tile
+    reads the union of its tokens' blocks, and a row whose tokens select every
+    visible block has the items it had). A tenth result is then the mask the
+    kernel takes with each item, ``[n_tiles, max_blocks, _mask_heads(nkv),
+    q_tile]`` bfloat16: 1 where slot ``t`` of the tile selects the column for
+    kv head ``n``; an eleventh the (token, column) pairs the items serve, each
+    item counted for its tile's tokens at or after the column."""
     T = pos.shape[0]
     S, max_blocks = block_tables.shape
     qt = int(q_tile)
@@ -546,6 +603,18 @@ def _tiled_work_list(block_tables, seq_idx, pos, block_size: int, window, q_tile
                                 jnp.max(jnp.where(valid, tile_pos, -1), axis=1), tile_cnt,
                                 block_size, max_blocks, window, qt)
     bound = n_tiles * cols
+    if selection is not None:
+        nkv = selection.shape[1]
+        picked = selection[tile_tok] & valid[:, :, None, None]                       # [n_tiles, qt, nkv, max_blocks]
+        col = jnp.arange(max_blocks, dtype=jnp.int32)[None, :]
+        live = jnp.any(picked, axis=(1, 2)) & (col >= lo[:, None]) & (col < (lo + n)[:, None])
+        item = jnp.nonzero(live.reshape(-1), size=bound + 1, fill_value=n_tiles * max_blocks)[0].astype(jnp.int32)
+        mask = jnp.pad(jnp.transpose(picked, (0, 3, 2, 1)).astype(jnp.bfloat16),
+                       ((0, 0), (0, 0), (0, _mask_heads(nkv) - nkv), (0, 0)))
+        served = jnp.sum(valid[:, :, None] & ((tile_pos // block_size)[:, :, None] >= col[None]), axis=1, dtype=jnp.int32)
+        return (tile_id, slot, tile_tok, valid, tile_seq, tile_cnt, item // max_blocks,
+                jnp.minimum(item % max_blocks, max_blocks - 1), jnp.sum(live, dtype=jnp.int32), mask,
+                jnp.sum(jnp.where(live, served, 0), dtype=jnp.int32))
     start = jnp.cumsum(n) - n                     # the items before a tile's own
     total = start[-1] + n[-1]
     # a tile's number, and its first column less its first item: each steps at the tile's first item
@@ -560,7 +629,7 @@ def _tiled_work_list(block_tables, seq_idx, pos, block_size: int, window, q_tile
 
 def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
                    block_size: int, q_tile: int, window, alibi, interpret: bool, value_dim=None,
-                   softmax_scale=None):
+                   softmax_scale=None, selection=None):
     """Q-tiled kernel: grid steps for the LIVE (tile, KV block) pairs only.
 
     Each tile packs up to ``q_tile`` CONTIGUOUS same-sequence tokens, so
@@ -628,6 +697,14 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
     block_size, d]``: a grid step fetches the table column's block of every
     head, ``[nkv, 1, block_size, d]``, and the head loop reads a head's where
     it lies: there is no scratch by kv head and nothing is copied.
+
+    Under a ``selection`` the work list holds the union of the tile's selected
+    columns and each item brings its mask ``[_mask_heads(nkv), q_tile]`` (a kv
+    head a sublane, a slot a lane: 4 KB beside the block's K and V). A row's
+    bit is wanted down the sublanes, so one small product with a constant 0/1
+    matrix ``[G, q_tile]`` (row ``t * g + h`` picks slot ``t``) lays every kv
+    head's bits as columns ``[rows, heads]``, and a head's column joins the
+    position mask.
     """
     T, nq, d = q.shape
     by_head = k3.ndim == 4
@@ -646,8 +723,9 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
     cdt = jnp.float32 if quant else jnp.promote_types(q.dtype, k3.dtype)
 
     # --- segmented tiles and their live KV blocks (contiguity contract: see paged_attention) ---
-    tile_id, slot, tile_tok, valid, tile_seq, tile_cnt, w_tile, w_col, total = _tiled_work_list(
-        block_tables, seq_idx, pos, block_size, window, qt)
+    selected = selection is not None
+    tile_id, slot, tile_tok, valid, tile_seq, tile_cnt, w_tile, w_col, total, *sel_mask = _tiled_work_list(
+        block_tables, seq_idx, pos, block_size, window, qt, selection)
     n_tiles = tile_cnt.shape[0]
     cols = (w_tile.shape[0] - 1) // n_tiles
     smem = _tiled_smem_bytes(n_tiles, cols, *block_tables.shape)
@@ -684,6 +762,8 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
             v_ref, pos_ref, o_ref, acc_ref, m_ref, l_ref = rest
         elif quant:
             v_ref, pos_ref, ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref, kh_ref, vh_ref = rest
+        elif selected:
+            v_ref, pos_ref, sel_ref, spread_ref, o_ref, acc_ref, m_ref, l_ref, kh_ref, vh_ref = rest
         else:
             v_ref, pos_ref, o_ref, acc_ref, m_ref, l_ref, kh_ref, vh_ref = rest
         i = pl.program_id(0)
@@ -743,6 +823,9 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
                 vis = jnp.logical_and(vis, my_pos - kpos < window)
             if alibi is not None:
                 rel = (kpos - my_pos).astype(jnp.float32)
+            if selected:  # every kv head's bit of each row, as columns [rows, heads]
+                picked = jax.lax.dot_general(spread_ref[:rows, :], sel_ref[0, 0], nt_dims,
+                                             preferred_element_type=jnp.float32)
             if quant:  # dequant at the VMEM tile — HBM only streamed int8
                 ks_t, vs_t = ks_ref[...].T, vs_ref[...].T   # [bs, nkv]
             if by_head:  # the fetched block is by head already
@@ -767,15 +850,16 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
                                         preferred_element_type=jnp.float32) * scale
                 if alibi is not None:
                     s = s + _slopes_tok_major(alibi[n * g:(n + 1) * g], rows) * rel
-                _update(r, jnp.where(vis, s, -1e30), v_of(n))
+                seen = jnp.logical_and(vis, picked[:, n:n + 1] > 0.5) if selected else vis
+                _update(r, jnp.where(seen, s, -1e30), v_of(n))
 
-            if alibi is None:
+            if alibi is None and not selected:
                 # traced once and unrolled by the lowering: the same straight
                 # line of eight heads as a Python loop gives Mosaic, at an
                 # eighth of the tracing (a rolled loop ran 1.6x slower; eight
                 # traced copies cost every program 0.3-1.0 s of set-up)
                 jax.lax.fori_loop(0, nkv, lambda n, c: (head(n), c)[1], 0, unroll=True)
-            else:  # the slopes are constants of the head
+            else:  # the slopes, and a selection's column, are constants of the head
                 for n in range(nkv):
                     head(n)
 
@@ -799,6 +883,11 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
         in_specs += [pl.BlockSpec((nkv, block_size), scale_map),
                      pl.BlockSpec((nkv, block_size), scale_map)]
         operands += [ks2, vs2]
+    if selected:
+        spread = (np.arange(G)[:, None] // g == np.arange(qt)[None, :])
+        in_specs += [pl.BlockSpec((1, 1, _mask_heads(nkv), qt), lambda i, tile_ref, col_ref, *refs: (tile_ref[i], col_ref[i], 0, 0)),
+                     pl.BlockSpec((G, qt), lambda i, *refs: (0, 0))]
+        operands += [sel_mask[0], jnp.asarray(spread, jnp.bfloat16)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
@@ -828,7 +917,7 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
                                w_tile, w_col, tile_seq, tile_cnt, block_tables, *operands)
     # scatter tiles back to token order: only slots that tokens fill are read
     flat = out_t.reshape(n_tiles, nkv, qt, g, dv).transpose(0, 2, 1, 3, 4).reshape(n_tiles * qt, nq, dv)
-    return flat[tile_id * qt + slot]
+    return (flat[tile_id * qt + slot], sel_mask[1]) if selected else flat[tile_id * qt + slot]
 
 
 # measured on a v5e (PERF.md section 6, PR 28): 512 KiB blocks (8 kv heads of
@@ -900,7 +989,7 @@ def tiled_kv_counts(q_tile: int, seq_idx, pos, windows, block_size: int, max_blo
     return bound, live
 
 
-def _decode_work_list(block_tables, seq_idx, pos, block_size: int, window, per_step: int = 1):
+def _decode_work_list(block_tables, seq_idx, pos, block_size: int, window, per_step: int = 1, selection=None):
     """The live (row, block) pairs of a decode batch, row after row and
     ``per_step`` consecutive table columns an item, as the int32 arrays the
     decode kernel prefetches, and how many items there are.
@@ -920,10 +1009,44 @@ def _decode_work_list(block_tables, seq_idx, pos, block_size: int, window, per_s
     window can span) / per_step)``: prefix-shared blocks count once per row
     that reads them, so the pool's size bounds nothing. ``w_row`` has one
     more entry and reads ``T`` from ``total`` on, so ``w_row[i + 1] !=
-    w_row[i]`` marks the last item of every row."""
+    w_row[i]`` marks the last item of every row.
+
+    Under a ``selection`` ``[T, nkv, max_blocks]`` (``paged_attention``) a
+    row's pairs are the columns up to ``hi`` that ANY kv head selects, in
+    ascending order, ``per_step`` of them an item, which need not be
+    neighbours: ``w_col`` is then ``[per_step * bound]``, slot ``b`` of item
+    ``i`` at ``b * bound + i`` as in ``w_blk`` (a dead slot names a column no
+    position reaches, so the position mask hides it), a fifth result
+    ``w_heads`` ``[per_step * bound]`` holds, bit ``n``, whether kv head ``n``
+    selected the slot's column, and a sixth counts the live pairs."""
     T = pos.shape[0]
     max_blocks = block_tables.shape[1]
     hi = jnp.clip(pos // block_size, 0, max_blocks - 1)
+    if selection is not None:
+        nkv = selection.shape[1]
+        col = jnp.arange(max_blocks, dtype=jnp.int32)[None, :]
+        live = jnp.any(selection, axis=1) & (col <= hi[:, None]) & (pos >= 0)[:, None]      # [T, max_blocks]
+        rank = jnp.cumsum(live, axis=1, dtype=jnp.int32) - 1
+        n = (rank[:, -1] + per_step) // per_step
+        bound = T * (-(-max_blocks // per_step))
+        total = jnp.sum(n)
+        start = jnp.cumsum(n) - n
+        i = jnp.arange(bound + 1, dtype=jnp.int32)
+        w_row = jnp.repeat(jnp.arange(T, dtype=jnp.int32), n, total_repeat_length=bound + 1)
+        # a live pair's place: slot ``rank % per_step`` of item ``start + rank // per_step``
+        place = jnp.where(live, (rank % per_step) * bound + start[:, None] + rank // per_step, per_step * bound)
+        heads = jnp.sum(selection.astype(jnp.int32) << jnp.arange(nkv, dtype=jnp.int32)[None, :, None], axis=1)
+        no_col = 2**20  # a dead slot's column: past every position
+        w_col = jnp.full((per_step * bound, ), no_col, jnp.int32).at[place.reshape(-1)].set(
+            jnp.broadcast_to(col, live.shape).reshape(-1), mode="drop")
+        w_heads = jnp.zeros((per_step * bound, ), jnp.int32).at[place.reshape(-1)].set(heads.reshape(-1), mode="drop")
+        item_row = jnp.tile(w_row[:bound], per_step)
+        dead = w_col == no_col
+        blk = block_tables[seq_idx[item_row], jnp.where(dead, 0, w_col)]
+        # a dead slot repeats the block the slot last held, so nothing is fetched for it
+        k = jnp.arange(per_step * bound, dtype=jnp.int32)
+        blk = blk[jax.lax.cummax(jnp.where(dead, 0, k))]
+        return jnp.where(i < total, w_row, T), w_col, blk, total.astype(jnp.int32), w_heads, jnp.sum(live, dtype=jnp.int32)
     if window is None:
         lo = jnp.zeros_like(hi)
         cols = max_blocks
@@ -949,7 +1072,8 @@ def _decode_work_list(block_tables, seq_idx, pos, block_size: int, window, per_s
 
 
 def _paged_kv_split(pl, pltpu, q, k2, v2, block_tables, seq_idx, pos, ks2, vs2,
-                    block_size: int, window, alibi, interpret: bool, value_dim=None, softmax_scale=None):
+                    block_size: int, window, alibi, interpret: bool, value_dim=None, softmax_scale=None,
+                    selection=None):
     """The decode kernel: grid steps for the LIVE (row, KV block) pairs only.
 
     A decode batch is one query token a row against contexts of very
@@ -991,7 +1115,13 @@ def _paged_kv_split(pl, pltpu, q, k2, v2, block_tables, seq_idx, pos, ks2, vs2,
     matrix ``[block_size, d]`` of its tokens' entries, fetched once a step; the
     scores take all of it, the value product its first ``value_dim`` lanes,
     and ``acc`` and the output are ``value_dim`` wide. The query heads are
-    padded to whole 16-row tiles of the MXU's left operand."""
+    padded to whole 16-row tiles of the MXU's left operand.
+
+    Under a ``selection`` an item's slots are the row's selected columns
+    (each slot its own column, :func:`_decode_work_list`), fetched for every kv
+    head that any head chose them for; a sixth prefetched array says which kv
+    heads did, and a query head sees a slot's keys only if its kv head's bit is
+    set."""
     T, nq, d = q.shape
     latent = v2 is None
     if latent and nq % 16:
@@ -999,6 +1129,7 @@ def _paged_kv_split(pl, pltpu, q, k2, v2, block_tables, seq_idx, pos, ks2, vs2,
                               seq_idx, pos, ks2, vs2, block_size, window, alibi, interpret, value_dim,
                               softmax_scale)
         return out[:, :nq]
+    selected = selection is not None
     M = k2.shape[1]                # rows of a block: block_size * nkv
     nkv = M // block_size
     g = nq // nkv
@@ -1009,18 +1140,22 @@ def _paged_kv_split(pl, pltpu, q, k2, v2, block_tables, seq_idx, pos, ks2, vs2,
     # quantised (int8 is exact in float32, and the scales are float32)
     cdt = jnp.float32 if quant else jnp.promote_types(q.dtype, k2.dtype)
     B = _decode_blocks_per_step(M, d, k2.dtype.itemsize, 1 if latent else 2)
-    w_row, w_col, w_blk, total = _decode_work_list(block_tables, seq_idx, pos, block_size, window, B)
-    bound = w_col.shape[0]
+    w_row, w_col, w_blk, total, *w_heads = _decode_work_list(block_tables, seq_idx, pos, block_size, window, B, selection)
+    read = w_heads.pop() if selected else None
+    bound = w_blk.shape[0] // B
 
-    def q_map(i, row_ref, col_ref, blk_ref, pos_ref):
+    def q_map(i, row_ref, *refs):
         return (row_ref[i], 0, 0)
 
     def kv_map(b):
-        return lambda i, row_ref, col_ref, blk_ref, pos_ref: (blk_ref[b * bound + i], 0, 0)
+        return lambda i, row_ref, col_ref, blk_ref, *refs: (blk_ref[b * bound + i], 0, 0)
 
     nt_dims = (((1, ), (1, )), ((), ()))  # [nq, d] x [M, d] -> [nq, M]
 
-    def kernel(row_ref, col_ref, blk_ref, pos_ref, q_ref, tok_ref, *rest):
+    def kernel(row_ref, col_ref, blk_ref, pos_ref, *rest):
+        if selected:
+            heads_ref, *rest = rest
+        q_ref, tok_ref, *rest = rest
         if latent:  # the value is the entry's first lanes: the same block, read where it lies
             k_refs, rest = rest[:B], rest[B:]
             value = lambda b: k_refs[b][0, :, :dv]
@@ -1053,8 +1188,11 @@ def _paged_kv_split(pl, pltpu, q, k2, v2, block_tables, seq_idx, pos, ks2, vs2,
             if quant:
                 sc = sc * ks_refs[b][0]
             sc = sc * scale
-            rel = my_pos - (col_ref[i] + b) * block_size
+            rel = my_pos - (col_ref[b * bound + i] if selected else col_ref[i] + b) * block_size
             vis = tok <= rel
+            if selected:  # the slot's column, for the kv heads that chose it alone
+                kv_head = jax.lax.broadcasted_iota(jnp.int32, (nq, M), 0) // g
+                vis = jnp.logical_and(vis, ((heads_ref[b * bound + i] >> kv_head) & 1) == 1)
             if window is not None:
                 vis = jnp.logical_and(vis, tok > rel - window)
             if alibi is not None:
@@ -1094,7 +1232,7 @@ def _paged_kv_split(pl, pltpu, q, k2, v2, block_tables, seq_idx, pos, ks2, vs2,
         operands += [ks2] * B + [vs2] * B
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=5 if selected else 4,
         grid=(total, ),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, nq, dv), q_map),
@@ -1104,7 +1242,8 @@ def _paged_kv_split(pl, pltpu, q, k2, v2, block_tables, seq_idx, pos, ks2, vs2,
             pltpu.VMEM((nq, _LANES), jnp.float32),
         ],
     )
-    return pl.pallas_call(kernel, grid_spec=grid_spec,
-                          out_shape=jax.ShapeDtypeStruct((T, nq, dv), q.dtype),
-                          interpret=interpret, name="paged_attn_kv_split")(
-                              w_row, w_col, w_blk, pos, *operands)
+    out = pl.pallas_call(kernel, grid_spec=grid_spec,
+                         out_shape=jax.ShapeDtypeStruct((T, nq, dv), q.dtype),
+                         interpret=interpret, name="paged_attn_kv_split")(
+                             w_row, w_col, w_blk, pos, *w_heads, *operands)
+    return (out, read) if selected else out

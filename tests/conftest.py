@@ -71,6 +71,7 @@ _FIRST_OUT = (
     "test_glm.py",
     "test_engine_zero.py",
     "test_trinity.py",
+    "test_minicpm.py",
     "test_sdar.py",
     "perfbench/test_bench_sdar.py",
     "test_checkpoint_tools.py",

@@ -57,7 +57,7 @@ def tiny():
 
 def _engine(cfg, params, attention="dense_blocked_attention", prefix_cache=None, kv_dtype=jnp.float32, **kwargs):
     sm = DSStateManagerConfig(max_tracked_sequences=4, max_ragged_batch_size=64,
-                              max_ragged_sequence_count=4, max_context=128)
+                              max_ragged_sequence_count=4, max_context=128, token_buckets=(64, ), seq_buckets=(4, ))
     icfg = RaggedInferenceEngineConfig(kv_block_size=BLOCK, num_kv_blocks=48, kv_dtype=kv_dtype,
                                        state_manager=sm, **kwargs)
     if prefix_cache is not None:
@@ -184,6 +184,14 @@ def long_rows_of_8(monkeypatch):
     return flat_model
 
 
+@pytest.mark.parametrize("t_bucket", [8, 16, 32, 64])
+def test_a_buckets_plan_is_a_slot_a_threshold_of_tokens_up_to_two(tiny, long_rows_of_8, t_bucket):
+    """The module's engines pad every ``put`` to the 64-token bucket (one
+    program a module); what a smaller bucket's program would be given is
+    still ``expanded_plan``'s to say."""
+    assert long_rows_of_8.expanded_plan(tiny[0], t_bucket, 128 // BLOCK, BLOCK, 4) == (min(2, t_bucket // 8), 128 // BLOCK)
+
+
 # (tokens cached, tokens fed) a row of ONE put; the expanded form takes the FIRST TWO rows fed 8 or more
 _MIXED = {
     "a_chunk_beside_decode_rows": [(20, 1), (16, 24), (9, 1)],
@@ -225,7 +233,7 @@ def test_long_rows_attended_expanded_beside_the_rest_absorbed(tiny, engine_of, l
     got = served(engine)
     assert _rel(got, want).max() < TOL
     fed = sum(new for _, new in rows)
-    t_bucket = next(b for b in (8, 16, 32, 64) if b >= fed)
+    t_bucket = next(b for b in engine.batch.token_buckets if b >= fed)   # (the module's engines pad to ONE bucket)
     long_rows = [r for r in rows if r[1] >= 8][:2]
     pairs = lambda seen, new: new * seen + new * (new + 1) // 2
     said = engine._attn_span_args([s for s, _ in rows], [n for _, n in rows], t_bucket)

@@ -83,6 +83,9 @@ def _tiny_engine(model=None, **sm_over):
                             attention_impl="reference")
     sm = dict(max_tracked_sequences=8, max_ragged_batch_size=64, max_ragged_sequence_count=4, max_context=64)
     sm.update(sm_over)
+    # ONE put program an engine, the same for every test's engine of these limits
+    sm.setdefault("token_buckets", (sm["max_ragged_batch_size"], ))
+    sm.setdefault("seq_buckets", (sm["max_ragged_sequence_count"], ))
     cfg = RaggedInferenceEngineConfig(kv_block_size=8, num_kv_blocks=32, kv_dtype=jnp.float32,
                                       state_manager=DSStateManagerConfig(**sm), use_pallas_kernels="never")
     return InferenceEngineV2(model, cfg)

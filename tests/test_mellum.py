@@ -43,7 +43,7 @@ def _engine(cfg, params, attention="dense_blocked_attention"):
                                             RaggedInferenceEngineConfig)
 
     sm = DSStateManagerConfig(max_tracked_sequences=4, max_ragged_batch_size=64,
-                              max_ragged_sequence_count=4, max_context=128)
+                              max_ragged_sequence_count=4, max_context=128, token_buckets=(64, ), seq_buckets=(4, ))
     icfg = RaggedInferenceEngineConfig(kv_block_size=16, num_kv_blocks=32, kv_dtype=jnp.float32,
                                        state_manager=sm)
     icfg.modules.attention = {"name": attention, "implementation_config": {"interpret": True}}
@@ -208,8 +208,8 @@ def test_expert_span_counts_match_a_batch_counted_by_hand(tmp_path, monkeypatch)
     (dec, ) = program_spans.spans_named(trace, "serving/decode")
     L, E, k = 4, 8, 2
     moe = engine._modules["moe"]
-    assert put.args["bucket_tokens"] == 16 and dec.args["bucket_rows"] == 4
-    want_put = {"moe_slots": 15 * k * L, "moe_rows": moe.padded_rows(16) * L, "experts_hit": 2 * L,
+    assert put.args["bucket_tokens"] == 64 and dec.args["bucket_rows"] == 4   # the engine's one token bucket
+    want_put = {"moe_slots": 15 * k * L, "moe_rows": moe.padded_rows(64) * L, "experts_hit": 2 * L,
                 "experts_total": E * L, "expert_load_max": 15}
     want_dec = {"moe_slots": 2 * 3 * k * L, "moe_rows": moe.padded_rows(4) * L * 3, "experts_hit": 2 * L * 3,
                 "experts_total": E * L * 3, "expert_load_max": 2}

@@ -41,7 +41,7 @@ def weights():
 
 def _engine(model, params, kv_blocks=48, prefix=False, **diffusion):
     sm = DSStateManagerConfig(max_tracked_sequences=8, max_ragged_batch_size=64, max_ragged_sequence_count=8,
-                              max_context=192)
+                              max_context=192, token_buckets=(64, ), seq_buckets=(8, ))
     icfg = RaggedInferenceEngineConfig(kv_block_size=16, num_kv_blocks=kv_blocks, kv_dtype=jnp.float32,
                                        state_manager=sm, diffusion=DiffusionConfig(**diffusion),
                                        prefix_cache=PrefixCacheConfig(enabled=prefix))

@@ -68,7 +68,7 @@ def tiny():
 
 def _engine(cfg, params, **kwargs):
     sm = DSStateManagerConfig(max_tracked_sequences=4, max_ragged_batch_size=32, max_ragged_sequence_count=4,
-                              max_context=128)
+                              max_context=128, token_buckets=(32, ), seq_buckets=(4, ))
     icfg = RaggedInferenceEngineConfig(kv_block_size=BLOCK, num_kv_blocks=32, kv_dtype=kwargs.pop("kv_dtype", jnp.float32),
                                        state_manager=sm, **kwargs)
     return InferenceEngineV2(TransformerLM(cfg), icfg, params=params)

@@ -154,7 +154,7 @@ def test_build_drafter_modes():
 
 def _engine(model, params, cache_on=False, spec=None, num_kv_blocks=64, max_context=64):
     sm = DSStateManagerConfig(max_tracked_sequences=8, max_ragged_batch_size=64,
-                              max_ragged_sequence_count=8, max_context=max_context)
+                              max_ragged_sequence_count=8, max_context=max_context, token_buckets=(64, ), seq_buckets=(8, ))
     icfg = RaggedInferenceEngineConfig(
         kv_block_size=8, num_kv_blocks=num_kv_blocks, kv_dtype=jnp.float32,
         state_manager=sm, use_pallas_kernels="never",

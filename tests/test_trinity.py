@@ -47,7 +47,7 @@ def _engine(cfg, params, attention="dense_blocked_attention"):
                                             RaggedInferenceEngineConfig)
 
     sm = DSStateManagerConfig(max_tracked_sequences=4, max_ragged_batch_size=64,
-                              max_ragged_sequence_count=4, max_context=128)
+                              max_ragged_sequence_count=4, max_context=128, token_buckets=(64, ), seq_buckets=(4, ))
     icfg = RaggedInferenceEngineConfig(kv_block_size=16, num_kv_blocks=32, kv_dtype=jnp.float32,
                                        state_manager=sm)
     icfg.modules.attention = {"name": attention, "implementation_config": {"interpret": True}}
@@ -71,6 +71,21 @@ def engine_of():
         return built[key]
 
     return engine_of
+
+
+@pytest.fixture(scope="module")
+def served_once():
+    """What several cases of one parametrised test would each compute the
+    same: ``served_once(key, make)`` makes it at the first ask and hands it to
+    the others, so that every case still counts and the engine runs once."""
+    kept = {}
+
+    def once(key, make):
+        if key not in kept:
+            kept[key] = make()
+        return kept[key]
+
+    return once
 
 
 def _published(cfg) -> dict:
@@ -132,15 +147,18 @@ def test_engine_prefill_and_decode_match_the_plain_reference(attention, held, fi
 @pytest.mark.parametrize("switch,value", [("gate", False), ("qk_norm", False), ("rope_in_full_layers", True),
                                           ("selection_bias", False), ("post_norms", False),
                                           ("route_scale", 1.0), ("window", 10**6)])
-def test_the_reference_without_one_mechanism_is_far_from_the_program(switch, value, engine_of):
+def test_the_reference_without_one_mechanism_is_far_from_the_program(switch, value, engine_of, served_once):
     """The controls the chip check runs, at the small size: the reference
     with one mechanism turned off is of order one away from the program, so
     each of them is in the program's logits (and the reference's switch does
-    what it says)."""
-    cfg = trinity_config("tiny", dtype=jnp.float32, moe_experts_held=8)
-    params = TransformerLM(cfg).init(jax.random.PRNGKey(3))
-    ids = _ids(cfg)
-    got = _prefill_then_decode(engine_of(cfg, params), ids, 40)
+    what it says). The program's side is the same for every switch and is run once."""
+    def serve():
+        cfg = trinity_config("tiny", dtype=jnp.float32, moe_experts_held=8)
+        params = TransformerLM(cfg).init(jax.random.PRNGKey(3))
+        ids = _ids(cfg)
+        return cfg, params, ids, _prefill_then_decode(engine_of(cfg, params), ids, 40)
+
+    cfg, params, ids, got = served_once("share_of_8", serve)
     assert _rel(got, _reference_logits(cfg, params, ids, list(range(39, 48)), **{switch: value})).max() > 0.05
 
 
@@ -148,7 +166,7 @@ def test_the_reference_without_one_mechanism_is_far_from_the_program(switch, val
     ("w_attn_gate", lambda a: -a), ("q_norm_scale", jnp.ones_like), ("k_norm_scale", jnp.ones_like),
     ("ln1_scale", jnp.ones_like), ("ln1_post_scale", jnp.ones_like), ("ln2_scale", jnp.ones_like),
     ("ln2_post_scale", jnp.ones_like), ("gate_bias", jnp.zeros_like)])
-def test_the_gate_the_qk_norm_the_four_norms_and_the_bias_each_change_the_logits(name, change, engine_of):
+def test_the_gate_the_qk_norm_the_four_norms_and_the_bias_each_change_the_logits(name, change, engine_of, served_once):
     """Each of the family's own parameters is read where the reference reads
     it: with that one array changed (a gain set to one, the gate's matrix
     negated, the selection bias zeroed) the program's logits move, and they
@@ -159,7 +177,8 @@ def test_the_gate_the_qk_norm_the_four_norms_and_the_bias_each_change_the_logits
     if name == "gate_bias":  # drawn of the order of the gap between the k-th and the next score: widen it
         params["blocks"]["gate_bias"] = params["blocks"]["gate_bias"] * 10
     ids = _ids(cfg, 24)
-    before = _prefill_then_decode(engine_of(cfg, params), ids, 20)
+    # the unchanged weights' logits are the same for every array but the widened bias: run once for each
+    before = served_once(("before", name == "gate_bias"), lambda: _prefill_then_decode(engine_of(cfg, params), ids, 20))
     params["blocks"][name] = change(params["blocks"][name])
     after = _prefill_then_decode(engine_of(cfg, params), ids, 20)
     assert _rel(after, before).max() > 1e-3
@@ -336,7 +355,7 @@ def test_step_spans_count_the_slots_that_landed_here(family, tmp_path):
     (dec, ) = program_spans.spans_named(trace, "serving/decode")
     moe = engine._modules["moe"]
     want_put = {"moe_slots": 15 * here * layers, "moe_slots_routed": 15 * 2 * layers,
-                "moe_rows": moe.padded_rows(16) * layers, "experts_hit": here * layers,
+                "moe_rows": moe.padded_rows(64) * layers, "experts_hit": here * layers,   # the engine's one token bucket
                 "experts_total": held * layers, "expert_load_max": 15, "experts_held": held, "experts_published": total}
     want_dec = {"moe_slots": 2 * 3 * here * layers, "moe_slots_routed": 2 * 3 * 2 * layers,
                 "moe_rows": moe.padded_rows(4) * layers * 3, "experts_hit": here * layers * 3,

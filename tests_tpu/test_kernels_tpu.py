@@ -1086,3 +1086,100 @@ def test_the_delta_rules_kernels_at_the_cells_shapes_on_chip(form):
             untouched[int(slot[r])] = False
             t0 += n
     assert np.array_equal(np.asarray(new)[untouched], np.asarray(pool)[untouched])
+
+
+@pytest.mark.parametrize("name,T,S,rows,kernel", [
+    # minicpm-sala.longctx: a 2,048-token chunk at 30k of history behind seven riding decode rows
+    ("chunk_mixed", 2048, 8, [(34000 + 4000 * i, 1) for i in range(7)] + [(30000, 2041)], "paged_attn_q_tiled"),
+    # 8 decode rows at 34k-62k
+    ("decode_8_rows", 8, 8, [(34219 + 4000 * i, 1) for i in range(8)], "paged_attn_kv_split"),
+])
+@pytest.mark.parametrize("picked", ["selection", "all_true"])
+def test_paged_kernels_under_a_selection_at_the_cells_shapes_on_chip(name, T, S, rows, kernel, picked):
+    """Both paged kernels under a learned block selection at
+    ``minicpm-sala.longctx``'s shapes (32 query / 2 KV heads of 128, 64-token
+    blocks, tables 1,034 wide, bf16): 64 blocks a token a KV head (block 0, the
+    last 33 and 30 drawn at random, each token its own draw) through
+    ``paged_attention`` as the engine calls it, against the gather reference
+    on the same selection; under an all-true selection bit-equal to the call
+    without one. Prints the microseconds a call."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    nq, nkv, d, bs, mb, n_blocks = 32, 2, 128, 64, 1034, 8300
+    rng = np.random.default_rng(47)
+    k_pool, v_pool = (jnp.asarray(rng.normal(size=(n_blocks * bs, nkv, d)), jnp.bfloat16) for _ in range(2))
+    tables = jnp.asarray(np.stack([rng.permutation(n_blocks)[:mb] for _ in range(S)]), jnp.int32)
+    seq_idx = np.concatenate([np.full(new, r) for r, (_, new) in enumerate(rows)])
+    pos = np.concatenate([np.arange(before, before + new) for before, new in rows])
+    n = seq_idx.size
+    own = pos // bs
+    sel = np.zeros((T, nkv, mb), bool)
+    if picked == "all_true":
+        sel[:] = True
+    else:
+        for t in range(n):
+            for h in range(nkv):
+                sel[t, h, rng.choice(max(own[t] - 33, 1), size=30, replace=False)] = True
+            sel[t, :, max(own[t] - 32, 0):own[t] + 1] = True
+        sel[:, :, 0] = True
+    seq_idx = jnp.asarray(np.pad(seq_idx, (0, T - n)), jnp.int32)
+    pos = jnp.asarray(np.pad(pos, (0, T - n)), jnp.int32)
+    sel = jnp.asarray(sel)
+    q = jnp.asarray(rng.normal(size=(T, nq, d)), jnp.bfloat16)
+    pa.KERNEL_CHOICES.pop((T, S, mb), None)
+    fn = jax.jit(lambda q, tables, seq_idx, pos, sel: pa.paged_attention(q, k_pool, v_pool, tables, seq_idx, pos, bs,
+                                                                         selection=sel))
+    out, read = fn(q, tables, seq_idx, pos, sel)
+    choice = pa.kernel_choice(T, S, mb)
+    assert choice["kernel"] == kernel, choice
+    us = _us_a_call(fn, q, tables, seq_idx, pos, sel, calls=10)
+    visible = int(np.sum(np.asarray(pos) // bs + 1))
+    print(f"\nselection[{name}, {picked}]: {us:.0f} us a call, {choice}, served {int(read)} of {visible} (token, column) pairs")
+    if picked == "all_true":
+        assert int(read) == visible
+        plain = jax.jit(lambda q, tables, seq_idx, pos: pa.paged_attention(q, k_pool, v_pool, tables, seq_idx, pos, bs))
+        assert (np.asarray(out[:n]) == np.asarray(plain(q, tables, seq_idx, pos)[:n])).all()
+        print(f"  without a selection: {_us_a_call(plain, q, tables, seq_idx, pos, calls=10):.0f} us a call")
+        return
+    # the gather reference a token block at a time: [tokens, heads, 66k keys] float32 does not fit at once
+    ref = jax.jit(lambda q, seq_idx, pos, sel: pa.paged_attention_reference(q, k_pool, v_pool, tables, seq_idx, pos, bs,
+                                                                            selection=sel))
+    step = 32
+    for t0 in list(range(0, min(n, 8), step)) + [max(n - step, 0)]:
+        cut = slice(t0, min(t0 + step, n))
+        want = np.asarray(ref(q[cut], seq_idx[cut], pos[cut], sel[cut])[0], np.float32)
+        got = np.asarray(out[cut], np.float32)
+        assert np.linalg.norm(got - want) / np.linalg.norm(want) < 1e-2
+
+
+@pytest.mark.parametrize("form", ["recurrent_step", "chunk_mixed"])
+def test_the_lightning_kernels_at_the_cells_shapes_on_chip(form):
+    """``lightning_recurrent_step`` over 8 rows and ``lightning_chunk_scan``
+    over a 2,041-token chunk behind seven one-token rows, 32 heads of 128 x
+    128, against the recurrence token by token. Prints the microseconds a call."""
+    from deepspeed_tpu.ops.pallas import lightning
+
+    H, d, slots = 32, 128, 96
+    rng = np.random.default_rng(47)
+    n_tok = np.ones(8, np.int32) if form == "recurrent_step" else np.asarray([1] * 7 + [2041], np.int32)
+    T = 8 if form == "recurrent_step" else 2048
+    q, k, v = (jnp.asarray(rng.normal(size=(T, H, d)) / 4, jnp.float32) for _ in range(3))
+    slope = jnp.asarray(2.0 ** (-8.0 * (np.arange(H) + 1) / H) * 0.68, jnp.float32)
+    pool = jnp.asarray(rng.normal(size=(slots, H, d, d)), jnp.float32)
+    slot = jnp.asarray(rng.permutation(slots)[:8], jnp.int32)
+    fresh = jnp.asarray([0, 1, 0, 0, 0, 0, 0, 1], jnp.int32)
+    if form == "recurrent_step":
+        fn = jax.jit(lambda q, k, v, pool: lightning.lightning_step(q, k, v, slope, pool, slot, fresh, 8, use_pallas=True))
+    else:
+        fn = jax.jit(lambda q, k, v, pool: lightning.lightning_chunks(q, k, v, slope, pool, slot, fresh, jnp.asarray(n_tok),
+                                                                     use_pallas=True))
+    o, new_pool = fn(q, k, v, pool)
+    print(f"\nlightning[{form}]: {_us_a_call(fn, q, k, v, pool, calls=10):.0f} us a call")
+    start = 0
+    with jax.default_matmul_precision("highest"):
+        for r, n in enumerate(n_tok):
+            S0 = jnp.zeros((H, d, d)) if int(fresh[r]) else pool[slot[r]]
+            want_o, want_S = lightning.recurrence_reference(q[start:start + n], k[start:start + n], v[start:start + n], slope, S0)
+            assert float(jnp.linalg.norm(o[start:start + n] - want_o) / jnp.linalg.norm(want_o)) < 1e-3
+            assert float(jnp.linalg.norm(new_pool[slot[r]] - want_S) / jnp.linalg.norm(want_S)) < 1e-3
+            start += int(n)
