@@ -646,11 +646,17 @@ class InferenceEngineV2:
         expert of one layer held; ``experts_held`` of ``experts_published``,
         the experts here of those the router scores. A model with a learned
         block selection says ``attn_blocks_read`` instead, the one count its
-        program makes (:meth:`_sparse_span_args`). ``stats`` is the
-        program's ``[experts_hit, expert_load_max, slots]``."""
+        program makes (:meth:`_sparse_span_args`), and with it
+        ``attn_items_live``, the (tile, column) pairs the tiled kernel's work
+        lists laid, and ``attn_grid_steps``, the grid steps it ran for them:
+        their ratio is how full a step's block slots were (0 and 0 where the
+        decode kernel served every call: a horizon, a ``put`` of one-token
+        rows). ``stats`` is the program's ``[experts_hit, expert_load_max,
+        slots]`` (``[blocks_read, items_live, grid_steps]`` of such a model)."""
         mc = self.model_config
         if self._sparse:
-            return {"attn_blocks_read": int(stats[0])}
+            return {"attn_blocks_read": int(stats[0]), "attn_items_live": int(stats[1]),
+                    "attn_grid_steps": int(stats[2])}
         layer_forwards = mc.num_expert_layers * forwards - kv_only_forwards
         return {"moe_slots": int(stats[2]),
                 "moe_slots_routed": tokens * mc.moe_top_k * layer_forwards,
@@ -774,8 +780,9 @@ class InferenceEngineV2:
     def _tiled_kv_span_args(self, T: int, S: int, rb, forwards=((0, 1, 0), )) -> dict:
         """What a step span says of ``paged_attn_q_tiled``'s grid, for a shape
         that kernel took (nothing otherwise): ``tile_kv_live``, the live
-        (tile, KV block) pairs, which are the grid steps it ran, and
-        ``tile_kv_bound``, the tiles x table columns the shapes allow, both
+        (tile, KV block) pairs, ``tile_kv_steps``, the grid steps it ran for
+        them (the choice's ``blocks_per_step`` pairs of a tile a step), and
+        ``tile_kv_bound``, the tiles x table columns the shapes allow, each
         summed over layers and forwards (``paged_attention.tiled_kv_counts``
         on the batch's own arrays, as masked by: a block-diffusion model's
         ``pos | (B - 1)``). ``forwards``: ``(offset, n, kv_only)``, ``n``
@@ -787,7 +794,7 @@ class InferenceEngineV2:
         last = self.model_config.layer_window(self.model_config.num_layers - 1)
         bs = self.config.kv_block_size
         rows, cols = self._expanded_plan(T)
-        bound = live = 0
+        counts = np.zeros(3, np.int64)
         for offset, n, kv_only in forwards:
             # attention calls a window: its layers in every forward, less the last layer's in a commit
             calls = [(w, layers * n - (kv_only if w == last else 0)) for w, layers in self._kv_windows]
@@ -799,11 +806,12 @@ class InferenceEngineV2:
                 slot_of_tok = np.where(rb.token_valid, slots[rb.token_seq_idx], -1)
                 x_seq, x_pos = expanded_batch(slot_of_tok, pos, rows, xp=np)
                 x_choice = kernel_choice(T, 2 * rows + 1, cols)
-                b, l = tiled_kv_counts(x_choice["q_tile"], x_seq, x_pos, calls, bs, cols, 2 * rows + 1)
-                bound, live, pos = bound + b, live + l, np.where(slot_of_tok >= 0, -1, pos)
-            b, l = tiled_kv_counts(choice["q_tile"], rb.token_seq_idx, pos, calls, bs, self._max_blocks_per_seq, S)
-            bound, live = bound + b, live + l
-        return {"tile_kv_live": live, "tile_kv_bound": bound}
+                counts += tiled_kv_counts(x_choice["q_tile"], x_seq, x_pos, calls, bs, cols, 2 * rows + 1,
+                                          x_choice["blocks_per_step"])
+                pos = np.where(slot_of_tok >= 0, -1, pos)
+            counts += tiled_kv_counts(choice["q_tile"], rb.token_seq_idx, pos, calls, bs, self._max_blocks_per_seq, S,
+                                      choice["blocks_per_step"])
+        return {"tile_kv_bound": int(counts[0]), "tile_kv_live": int(counts[1]), "tile_kv_steps": int(counts[2])}
 
     def _kernel_of(self, T: int, S: int, horizon: bool = False) -> str:
         """``<kernel>:<n>:<rule>`` (``n``: the tiled kernel's ``q_tile``, or the

@@ -278,10 +278,12 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
     query's context with them (``sparse_index.py``) and hands the selection to
     the attention module, whose kernels lay grid steps for selected blocks
     alone. The KV block is the selection's block. ``moe_stats`` of such a model
-    (it has no experts) appends int32 ``[blocks_read, 0, 0]``: the (token, kv
-    head, block) triples the work lists served, as the lists themselves count
-    their items (``paged_attention``'s second result under a selection),
-    summed over the layers; what was visible and what was selected follow from
+    (it has no experts) appends int32 ``[blocks_read, items_live, grid_steps]``:
+    the (token, kv head, block) triples the work lists served, as the lists
+    themselves count them, then the (tile, column) pairs the tiled kernel's
+    lists laid and the grid steps it ran for them, several pairs a step where
+    a block is narrower than a lane tile (``paged_attention``'s second result
+    under a selection), each summed over the layers; what was visible and what was selected follow from
     the rows' lengths and are the engine's to count. ``probe`` appends, last of
     all, what a check reads back of ``PROBES`` tokens a row (its run's first
     and last and two between: the middle of a chunk's tiles and their ends)
@@ -577,8 +579,8 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
                 picked = select_blocks(cfg, block_size, q, p_flat, tables_l, seq_idx, pos, valid)
                 ws = tuple(ws[:-1]) + (p_flat, )
                 ctx, read = attend(q, k_flat, v_flat, tables_l, seq_idx, vis_pos, selection=picked, **scales)
-                if stats is not None:
-                    stats = stats.at[0].add(nkv * read)
+                if stats is not None:  # blocks read a kv head, then the tiled list's pairs and its grid steps
+                    stats = stats + read * jnp.asarray([nkv, 1, 1], jnp.int32)
                 if probe:
                     probes.append((picked[probe_tok], ctx.reshape(T, nq * d)[probe_tok].astype(jnp.float32)))
             else:

@@ -82,8 +82,20 @@ _LONG_ROW_TILE = 128
 _Q_TILED_VMEM_LIMIT = 100 << 20
 
 
+def _tiled_blocks_per_step(block_size: int, token_major: bool) -> int:
+    """KV blocks one grid step of ``paged_attn_q_tiled`` takes (1, 2 or 4):
+    as many as fill a 128-lane tile when a block is narrower than that, so a
+    kv head's scores, masks and softmax run in whole registers and what a step
+    pays whatever its keys (the rescale of ``m``/``l``/``acc``) is paid a tile
+    of lanes. ``token_major``: bf16 or float32 K and V pools ``[pool_len,
+    nkv, d]``, whose heads go through the scratch by kv head, where the
+    step's blocks lie side by side; a latent pool, pools by head (read where
+    the block lies) and int8 pools (a scale block a KV block) keep one."""
+    return max(1, min(4, _LANES // block_size)) if token_major else 1
+
+
 def choose_kernel(T: int, S: int, max_blocks: int, nq: int, block_rows: int, d: int, itemsize: int,
-                  seq_idx=None, pos=None, parts: int = 2, kv_by_head: int = 0) -> dict:
+                  seq_idx=None, pos=None, parts: int = 2, kv_by_head: int = 0, block_size: int = 128) -> dict:
     """Which kernel serves a batch of ``T`` tokens over ``S`` table rows of
     ``max_blocks`` columns, and with which tile: ``{"kernel", "q_tile",
     "blocks_per_step", "rule"}``. It follows from the program's static shapes
@@ -92,8 +104,9 @@ def choose_kernel(T: int, S: int, max_blocks: int, nq: int, block_rows: int, d: 
     ``itemsize`` the pool's and ``parts`` the pools a grid step fetches a block
     of (2: K and V; 1: a latent entry, read once for score and value);
     ``kv_by_head`` the kv heads of pools BY HEAD (0: token-major pools);
-    ``seq_idx``/``pos`` only let a caller with CONCRETE arrays have the tiled
-    grid's layout contract checked. In order:
+    ``block_size`` a KV block's tokens; ``seq_idx``/``pos`` only let a caller
+    with CONCRETE arrays have the tiled grid's layout contract checked. In
+    order:
 
     - off the TPU (``off_tpu``), or heads the kernels do not tile (``nq < 8``,
       ``d % 128``: ``unsupported_shape``): the gather reference;
@@ -114,7 +127,10 @@ def choose_kernel(T: int, S: int, max_blocks: int, nq: int, block_rows: int, d: 
       because a row beyond the chunks' own is a tile of its own and each
       costs a q and an output tile of DMA and a grid step a live KV block
       (the grid runs the live (tile, block) pairs alone:
-      :func:`_tiled_work_list`);
+      :func:`_tiled_work_list`). ``blocks_per_step`` is
+      :func:`_tiled_blocks_per_step`'s: one block of 128 tokens or more a
+      grid step, two of 64, four of 32 or fewer, over token-major pools that
+      are not int8 (``itemsize`` above 1);
     - such a batch whose concrete ``seq_idx`` breaks the layout contract
       (:func:`_contiguity_ok`: the tiled grid would overflow its static tile
       bound and scatter tokens into the wrong tiles): ``contiguity_demoted``
@@ -141,7 +157,8 @@ def choose_kernel(T: int, S: int, max_blocks: int, nq: int, block_rows: int, d: 
                                                              itemsize) * 3 // 2 <= _Q_TILED_VMEM_LIMIT:
                     qt *= 2
                 rule = "heuristic:long_rows_one_head"
-            return {"kernel": "paged_attn_q_tiled", "q_tile": qt, "blocks_per_step": 1, "rule": rule}
+            per_step = _tiled_blocks_per_step(block_size, parts == 2 and not kv_by_head and itemsize > 1)
+            return {"kernel": "paged_attn_q_tiled", "q_tile": qt, "blocks_per_step": per_step, "rule": rule}
         rule = "contiguity_demoted"
     elif max_blocks < 8:
         rule = "heuristic:short_table"
@@ -190,10 +207,13 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: i
     visible block, the lists and bodies as they were.
     The kernel and its tile are :func:`choose_kernel`'s, from the shapes.
     Returns [T, nq, d] (``[T, nq, value_dim]`` over a latent pool); under a
-    ``selection`` ``(out, read)``, ``read`` the int32 count of (query token,
-    table column) pairs the work list that ran served: for every item it laid,
-    the tokens of the item's tile at or after the column (a tile's union is
-    what the kernel fetches; the gather counts a token's own columns)."""
+    ``selection`` ``(out, read)``, ``read`` int32 ``[3]``: the (query token,
+    table column) pairs the work list that ran served (for every live column
+    it laid, the tokens of the column's tile at or after it: a tile's union is
+    what the kernel fetches; the gather counts a token's own columns), then
+    the (tile, column) pairs the TILED list laid and the grid steps it ran for
+    them, :func:`_tiled_blocks_per_step` columns a step (0 and 0 from the
+    decode kernel and the gather, which lay no tiles)."""
     T, nq, d = q.shape
     nkv = k_pool.shape[0 if k_pool.ndim == 4 else 1]
     S, max_blocks = block_tables.shape
@@ -206,7 +226,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: i
         raise ValueError("a latent pool is [pool_len, 1, d] with value_dim lanes of value, no int8 scales, no alibi")
     _check_selection(selection, T, nkv, max_blocks, window, alibi, k_scale, latent or k_pool.ndim == 4)
     choice = choose_kernel(T, S, max_blocks, nq, block_size * nkv, d, k_pool.dtype.itemsize, seq_idx, pos,
-                           parts=1 if latent else 2, kv_by_head=nkv if k_pool.ndim == 4 else 0)
+                           parts=1 if latent else 2, kv_by_head=nkv if k_pool.ndim == 4 else 0, block_size=block_size)
     _note_choice(T, S, max_blocks, choice)
     if k_pool.ndim == 4 and choice["kernel"] == "paged_attn_kv_split":
         raise NotImplementedError(f"pools by head under the decode kernel ({T} tokens over {S} rows: {choice['rule']})")
@@ -313,7 +333,12 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables, seq_idx, pos, blo
     if selection is None:
         return out
     own = jnp.arange(selection.shape[2], dtype=jnp.int32)[None, :] <= (pos // block_size)[:, None]
-    return out, jnp.sum(jnp.any(selection, axis=1) & own & (pos >= 0)[:, None], dtype=jnp.int32)
+    return out, _read_counts(jnp.sum(jnp.any(selection, axis=1) & own & (pos >= 0)[:, None], dtype=jnp.int32))
+
+
+def _read_counts(pairs, items=0, steps=0):
+    """``paged_attention``'s second result under a selection, int32 ``[3]``."""
+    return jnp.stack([jnp.asarray(c, jnp.int32) for c in (pairs, items, steps)])
 
 
 def _slopes_rows(alibi, reps):
@@ -338,16 +363,20 @@ def _slopes_tok_major(alibi_g, rows):
 
 
 @functools.partial(jax.jit, static_argnames=("block_size", "interpret", "window", "alibi", "q_tile", "value_dim",
-                                             "softmax_scale"))
+                                             "softmax_scale", "blocks_per_step"))
 def _pallas_paged(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: int, interpret: bool = False,
                   window=None, alibi=None, k_scale=None, v_scale=None, q_tile: int = 1, value_dim=None,
-                  softmax_scale=None, selection=None):
+                  softmax_scale=None, selection=None, blocks_per_step=None):
     """The kernel the caller names, with no choice of its own: ``q_tile``
     above 1 runs ``paged_attn_q_tiled`` at that tile, 1 (one query token a
     grid row) the decode kernel ``paged_attn_kv_split``. ``paged_attention``
     passes :func:`choose_kernel`'s tile; the interpret-mode parity tests
     name each body themselves. ``v_pool`` None: a latent pool
-    (``paged_attention``), given to either kernel as its K operand alone."""
+    (``paged_attention``), given to either kernel as its K operand alone.
+    ``blocks_per_step`` is the tests': the KV blocks a grid step of the tiled
+    kernel takes over token-major pools in place of
+    :func:`_tiled_blocks_per_step`'s (the chip's before and after from one
+    tree); nothing of the program passes it."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -376,7 +405,7 @@ def _pallas_paged(q, k_pool, v_pool, block_tables, seq_idx, pos, block_size: int
         return _paged_q_tiled(pl, pltpu, q, as_rows(k_pool), as_rows(v_pool), block_tables, seq_idx, pos,
                               ks2, vs2, block_size=block_size, q_tile=q_tile, window=window,
                               alibi=alibi, interpret=interpret, value_dim=value_dim, softmax_scale=softmax_scale,
-                              selection=selection)
+                              selection=selection, blocks_per_step=blocks_per_step)
     # the decode kernel takes the int8 scales [nkv, cols] laid out to match the rows
     by_col = lambda sc: jnp.transpose(sc).reshape(n_pool_blocks, 1, block_size * nkv)
     return _paged_kv_split(pl, pltpu, q, as_rows(k_pool), as_rows(v_pool), block_tables, seq_idx,
@@ -405,20 +434,21 @@ def _latent_row_chunk(G: int) -> int:
 
 
 def _q_tiled_vmem_bytes(R: int, G: int, d: int, block_size: int, nkv: int, q_itemsize: int,
-                        kv_itemsize: int) -> int:
-    """VMEM working set of one ``paged_attn_q_tiled`` grid step: the q and
-    output tiles and the K and V blocks ``[block_size * nkv, d]``
-    double-buffered by the pipeline (and the block once more, by kv head, with
-    the words of one load and their unpacked heads in float32), the float32
-    ``acc``, the lane-replicated ``m``/``l`` and positions, and one kv head's
-    float32 scores and probabilities."""
-    tiles = 2 * 2 * R * d * q_itemsize                        # q in, o out
-    kv = 2 * 2 * block_size * nkv * d * kv_itemsize           # K, V: the pool's rows, no padding
-    kv += 2 * nkv * block_size * d * 4                        # by head, at most float32
-    kv += 2 * (1 + 4 // kv_itemsize) * block_size * d * 4     # one load's words and its heads
-    state = R * d * 4 + 2 * R * _LANES * 4                    # acc, m, l
+                        kv_itemsize: int, per_step: int = 1) -> int:
+    """VMEM working set of one ``paged_attn_q_tiled`` grid step of
+    ``per_step`` KV blocks: the q and output tiles and each block's K and V
+    ``[block_size * nkv, d]`` double-buffered by the pipeline (and the step's
+    blocks once more, side by side by kv head, with the words of one load and
+    their unpacked heads in float32), the float32 ``acc``, the lane-replicated
+    ``m``/``l`` and positions, and one kv head's float32 scores and
+    probabilities over the step's keys."""
+    tiles = 2 * 2 * R * d * q_itemsize                                   # q in, o out
+    kv = per_step * 2 * 2 * block_size * nkv * d * kv_itemsize           # K, V: the pool's rows, no padding
+    kv += per_step * 2 * nkv * block_size * d * 4                        # by head, at most float32
+    kv += 2 * (1 + 4 // kv_itemsize) * block_size * d * 4                # one load's words and its heads
+    state = R * d * 4 + 2 * R * _LANES * 4                               # acc, m, l
     pos = 2 * G * _LANES * 4
-    head = 4 * G * max(block_size, _LANES) * 4                # s, p and their temporaries
+    head = 4 * G * max(per_step * block_size, _LANES) * 4                # s, p and their temporaries
     return tiles + kv + state + pos + head
 
 
@@ -502,11 +532,15 @@ def _lane_copies(x, n: int):
 _TILED_SMEM_BYTES = 512 << 10
 
 
-def _tiled_smem_bytes(n_tiles: int, cols: int, S: int, max_blocks: int) -> int:
+def _tiled_smem_bytes(n_tiles: int, cols: int, S: int, max_blocks: int, per_step: int = 1) -> int:
     """Scalar memory ``paged_attn_q_tiled`` prefetches for a shape: the two
-    arrays of the work list, a tile's table row and token count, and the
-    block table, whose rows are padded to whole 128-lane words."""
-    return 4 * (2 * (n_tiles * cols + 1) + 2 * n_tiles + S * -(-max_blocks // _LANES) * _LANES)
+    arrays of the work list (an item's tile, and the column of each of its
+    ``per_step`` slots: ``n_tiles x ceil(cols / per_step)`` items at most), a
+    tile's table row and token count, and the block table, whose rows are
+    padded to whole 128-lane words. Several blocks a step take no more than
+    one does once a tile can have three columns."""
+    items = n_tiles * -(-cols // per_step)
+    return 4 * ((1 + per_step) * items + 2 + 2 * n_tiles + S * -(-max_blocks // _LANES) * _LANES)
 
 
 def _tile_runs(seq_idx, pos, q_tile: int, xp=jnp):
@@ -548,9 +582,15 @@ def _tile_columns(tile_min, tile_max, tile_cnt, block_size: int, max_blocks: int
     return lo, xp.where((tile_cnt > 0) & (tile_max >= 0), hi - lo + 1, 0), cols
 
 
-def _tiled_work_list(block_tables, seq_idx, pos, block_size: int, window, q_tile: int, selection=None):
+# a dead slot's column inside the tiled kernel: past every position (times a block's tokens it stays an int32)
+_NO_COLUMN = 1 << 20
+
+
+def _tiled_work_list(block_tables, seq_idx, pos, block_size: int, window, q_tile: int, selection=None,
+                     per_step: int = 1):
     """The tiles of a ragged batch and the LIVE (tile, KV block) pairs of
-    their grid, as the int32 arrays ``paged_attn_q_tiled`` prefetches.
+    their grid, ``per_step`` of a tile's pairs an item, as the int32 arrays
+    ``paged_attn_q_tiled`` prefetches.
 
     Tiles (:func:`_tile_runs`): ``n_tiles`` is the static bound ``ceil(T /
     q_tile) + S + 1`` (interior splits, one ragged tail tile a sequence run,
@@ -561,17 +601,18 @@ def _tiled_work_list(block_tables, seq_idx, pos, block_size: int, window, q_tile
     whether it holds one, ``tile_seq`` a tile's table row and ``tile_cnt``
     its valid slots (a prefix).
 
-    The work list (:func:`_tile_columns`): tile ``i`` with tokens sees table
-    columns ``lo_i .. hi_i`` and has ``hi_i - lo_i + 1 >= 1`` items, an empty
-    tile none. Item ``k < total`` is tile ``w_tile[k]`` against table column
-    ``w_col[k]``, whose pool block is ``block_tables[tile_seq[w_tile[k]],
-    w_col[k]]``; items go tile after tile and ascend by column, so a tile's
-    items are consecutive. No item lies past a tile's last position or wholly
-    under its window. The arrays' length is one more than the most items the
-    SHAPES allow, ``n_tiles x min(max_blocks, columns a window can span)``
-    (prefix-shared blocks count once per tile that reads them, so the pool's
-    size bounds nothing); ``w_tile`` reads ``n_tiles`` from ``total`` on, so
-    ``w_tile[k + 1] != w_tile[k]`` marks the last item of every tile.
+    The work list (:func:`_tile_columns`), one block an item: tile ``i`` with
+    tokens sees table columns ``lo_i .. hi_i`` and has ``hi_i - lo_i + 1 >= 1``
+    items, an empty tile none. Item ``k < total`` is tile ``w_tile[k]`` against
+    table column ``w_col[k]``, whose pool block is
+    ``block_tables[tile_seq[w_tile[k]], w_col[k]]``; items go tile after tile
+    and ascend by column, so a tile's items are consecutive. No item lies past
+    a tile's last position or wholly under its window. The arrays' length is
+    one more than the most items the SHAPES allow, ``n_tiles x
+    min(max_blocks, columns a window can span)`` (prefix-shared blocks count
+    once per tile that reads them, so the pool's size bounds nothing);
+    ``w_tile`` reads ``n_tiles`` from ``total`` on, so ``w_tile[k + 1] !=
+    w_tile[k]`` marks the last item of every tile.
 
     Both arrays are what an item inherits from its tile, spread over the
     tile's items by a scatter of ``n_tiles`` steps and a running sum: a
@@ -580,18 +621,34 @@ def _tiled_work_list(block_tables, seq_idx, pos, block_size: int, window, q_tile
     chip run, PR 34).
 
     Under a ``selection`` ``[T, nkv, max_blocks]`` (``paged_attention``) a
-    tile's items are the columns, among those above, that ANY of its tokens
+    tile's pairs are the columns, among those above, that ANY of its tokens
     selects for ANY kv head, still consecutive and ascending, found by one
     compaction of the ``[n_tiles, max_blocks]`` table of such pairs (so a tile
     reads the union of its tokens' blocks, and a row whose tokens select every
     visible block has the items it had). A tenth result is then the mask the
-    kernel takes with each item, ``[n_tiles, max_blocks, _mask_heads(nkv),
+    kernel takes with each pair, ``[n_tiles, max_blocks, _mask_heads(nkv),
     q_tile]`` bfloat16: 1 where slot ``t`` of the tile selects the column for
-    kv head ``n``; an eleventh the (token, column) pairs the items serve, each
-    item counted for its tile's tokens at or after the column."""
+    kv head ``n``; an eleventh the (token, column) pairs the list serves, each
+    live pair counted for its tile's tokens at or after the column (blocks,
+    whatever the items hold), and a twelfth the live pairs themselves.
+
+    ``per_step`` ``B > 1`` (:func:`_tiled_blocks_per_step`): an item is a tile
+    against ``B`` of its live columns, taken in ascending order from the
+    tile's first on: consecutive columns without a selection, the selected
+    ones (which need not be neighbours) under one, so a tile of ``n`` pairs
+    has ``ceil(n / B)`` items, ``total`` their sum and the arrays' bound
+    ``n_tiles x ceil(columns / B)``. ``w_col`` is then ``[B * bound]``, slot
+    ``b`` of item ``k`` at ``b * bound + k``; only a tile's last item can have
+    dead slots, and a dead slot reads ``~c`` (negative), ``c`` the column that
+    slot held in the item before: the pipeline finds the block it has and
+    fetches nothing, and the kernel gives the slot :data:`_NO_COLUMN`, which
+    no position reaches. (A tile's ONLY item looks its ``c`` up in its own
+    table row: some block of its own sequence, fetched and masked.) The pairs
+    and their order are those of one block an item, so an all-true selection
+    lays what no selection lays."""
     T = pos.shape[0]
     S, max_blocks = block_tables.shape
-    qt = int(q_tile)
+    qt, B = int(q_tile), int(per_step)
     n_tiles = -(-T // qt) + S + 1
     tile_id, slot = _tile_runs(seq_idx, pos, qt)
     tile_tok = jnp.zeros((n_tiles, qt), jnp.int32).at[tile_id, slot].set(jnp.arange(T, dtype=jnp.int32))
@@ -602,19 +659,39 @@ def _tiled_work_list(block_tables, seq_idx, pos, block_size: int, window, q_tile
     lo, n, cols = _tile_columns(jnp.min(jnp.where(valid, tile_pos, jnp.int32(2**30)), axis=1),
                                 jnp.max(jnp.where(valid, tile_pos, -1), axis=1), tile_cnt,
                                 block_size, max_blocks, window, qt)
-    bound = n_tiles * cols
+    tiles = (tile_id, slot, tile_tok, valid, tile_seq, tile_cnt)
+    bound = n_tiles * -(-cols // B)
+    col = jnp.arange(max_blocks, dtype=jnp.int32)[None, :]
+    selected = ()
+    if selection is not None or B > 1:  # the table of live (tile, column) pairs
+        live = (col >= lo[:, None]) & (col < (lo + n)[:, None])
     if selection is not None:
         nkv = selection.shape[1]
         picked = selection[tile_tok] & valid[:, :, None, None]                       # [n_tiles, qt, nkv, max_blocks]
-        col = jnp.arange(max_blocks, dtype=jnp.int32)[None, :]
-        live = jnp.any(picked, axis=(1, 2)) & (col >= lo[:, None]) & (col < (lo + n)[:, None])
-        item = jnp.nonzero(live.reshape(-1), size=bound + 1, fill_value=n_tiles * max_blocks)[0].astype(jnp.int32)
+        live = live & jnp.any(picked, axis=(1, 2))
         mask = jnp.pad(jnp.transpose(picked, (0, 3, 2, 1)).astype(jnp.bfloat16),
                        ((0, 0), (0, 0), (0, _mask_heads(nkv) - nkv), (0, 0)))
         served = jnp.sum(valid[:, :, None] & ((tile_pos // block_size)[:, :, None] >= col[None]), axis=1, dtype=jnp.int32)
-        return (tile_id, slot, tile_tok, valid, tile_seq, tile_cnt, item // max_blocks,
-                jnp.minimum(item % max_blocks, max_blocks - 1), jnp.sum(live, dtype=jnp.int32), mask,
-                jnp.sum(jnp.where(live, served, 0), dtype=jnp.int32))
+        selected = (mask, jnp.sum(jnp.where(live, served, 0), dtype=jnp.int32), jnp.sum(live, dtype=jnp.int32))
+    if B > 1:
+        # a live pair's place: slot ``rank % B`` of its tile's item ``rank // B``, as ``_decode_work_list`` lays a row's
+        rank = jnp.cumsum(live, axis=1, dtype=jnp.int32) - 1
+        n = (rank[:, -1] + B) // B
+        start = jnp.cumsum(n) - n
+        total = start[-1] + n[-1]
+        place = jnp.where(live, (rank % B) * bound + start[:, None] + rank // B, B * bound)
+        w_col = jnp.full((B * bound, ), -1, jnp.int32).at[place.reshape(-1)].set(
+            jnp.broadcast_to(col, live.shape).reshape(-1), mode="drop")
+        k = jnp.arange(B * bound, dtype=jnp.int32)
+        dead = w_col < 0
+        w_col = jnp.where(dead, ~w_col[jax.lax.cummax(jnp.where(dead, 0, k))], w_col)
+        # an item's tile steps at each tile's first item (an empty tile's step falls on the next tile's)
+        steps = jnp.diff(jnp.arange(n_tiles, dtype=jnp.int32), prepend=0)
+        w_tile = jnp.cumsum(jnp.zeros((bound + 1, ), jnp.int32).at[start].add(steps))
+        return (*tiles, jnp.where(k[:bound + 1] < total, w_tile, n_tiles), w_col, total.astype(jnp.int32), *selected)
+    if selection is not None:  # one pair an item: the compaction of the table is the list, its pairs the items
+        item = jnp.nonzero(live.reshape(-1), size=bound + 1, fill_value=n_tiles * max_blocks)[0].astype(jnp.int32)
+        return (*tiles, item // max_blocks, jnp.minimum(item % max_blocks, max_blocks - 1), selected[2], *selected)
     start = jnp.cumsum(n) - n                     # the items before a tile's own
     total = start[-1] + n[-1]
     # a tile's number, and its first column less its first item: each steps at the tile's first item
@@ -623,14 +700,15 @@ def _tiled_work_list(block_tables, seq_idx, pos, block_size: int, window, q_tile
     steps = jnp.diff(of_tile, axis=1, prepend=0)
     spread = jnp.cumsum(jnp.zeros((2, bound + 1), jnp.int32).at[:, start].add(steps), axis=1)
     k = jnp.arange(bound + 1, dtype=jnp.int32)
-    return (tile_id, slot, tile_tok, valid, tile_seq, tile_cnt, jnp.where(k < total, spread[0], n_tiles),
-            jnp.clip(k + spread[1], 0, max_blocks - 1), total.astype(jnp.int32))
+    return (*tiles, jnp.where(k < total, spread[0], n_tiles), jnp.clip(k + spread[1], 0, max_blocks - 1),
+            total.astype(jnp.int32))
 
 
 def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
                    block_size: int, q_tile: int, window, alibi, interpret: bool, value_dim=None,
-                   softmax_scale=None, selection=None):
-    """Q-tiled kernel: grid steps for the LIVE (tile, KV block) pairs only.
+                   softmax_scale=None, selection=None, blocks_per_step=None):
+    """Q-tiled kernel: grid steps for the LIVE (tile, KV block) pairs only,
+    as many pairs a step as fill a 128-lane tile.
 
     Each tile packs up to ``q_tile`` CONTIGUOUS same-sequence tokens, so
     every KV block streams from HBM (and is read out by kv head) once per
@@ -664,6 +742,21 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
     rectangle's did (97 tiles x 65 columns and 64 table rows: 50 + 34 KB);
     a shape whose list would pass ``_TILED_SMEM_BYTES`` raises here, at trace
     time.
+
+    Blocks narrower than a 128-lane tile (:func:`_tiled_blocks_per_step`: two
+    of 64 tokens, four of 32 or fewer, over token-major pools that are not
+    int8): an item is a tile against ``B`` of its live columns (the work
+    list's ``per_step``), the pools are given ``B`` times with a K and a V
+    in_spec a slot, and each slot's heads are read out into rows ``b *
+    block_size ..`` of the scratch by kv head, so a kv head's keys are ONE
+    ``[B * block_size, d]`` matrix: one ``q k^T`` of ``B * block_size``
+    columns, one position mask whose key positions take each slot's own
+    column (a dead slot's is past every position), one online-softmax update
+    (one max, one ``exp``, one sum, one rescale of ``m``/``l``/``acc``) and
+    one ``p v``. At 64-token blocks every register of the scores is whole; one
+    block a step read half of each and paid the rescale every 64 keys
+    (PERF.md section 6, PR 48). At ``B == 1`` the list, the in_specs and the
+    body are what they were.
 
     The pool arrives as the decode kernel takes it, ``k3``/``v3`` ``[blocks,
     block_size * nkv, d]``, the pool's own bytes (row ``t * nkv + n`` is head
@@ -699,12 +792,20 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
     it lies: there is no scratch by kv head and nothing is copied.
 
     Under a ``selection`` the work list holds the union of the tile's selected
-    columns and each item brings its mask ``[_mask_heads(nkv), q_tile]`` (a kv
-    head a sublane, a slot a lane: 4 KB beside the block's K and V). A row's
-    bit is wanted down the sublanes, so one small product with a constant 0/1
-    matrix ``[G, q_tile]`` (row ``t * g + h`` picks slot ``t``) lays every kv
-    head's bits as columns ``[rows, heads]``, and a head's column joins the
-    position mask.
+    columns and each of an item's slots brings its mask ``[_mask_heads(nkv),
+    q_tile]`` (a kv head a sublane, a slot of the tile a lane: 4 KB beside the
+    block's K and V). A row's bit is wanted down the sublanes and across its
+    slot's lanes: a product with the identity turns each slot's mask
+    ``[q_tile, heads]``, a head's column of each slot is spread over that
+    slot's lanes, 16 registers ``[q_tile, keys]`` a kv head, and one product
+    with a constant 0/1 matrix ``[G, q_tile]`` (row ``t * g + h`` picks slot
+    ``t``) repeats them down each token's ``g`` rows to join the position
+    mask: the MXU has the room, and a lane broadcast a row and a slot (each
+    slot's bits as columns ``[rows, heads]``, this body's first form) took 22
+    of a 35 ms call where this takes 3 of 16 (PERF.md section 6, PR 48). The
+    second result is then
+    :func:`_read_counts`: the list's served pairs, its live pairs and the
+    grid's steps.
     """
     T, nq, d = q.shape
     by_head = k3.ndim == 4
@@ -713,6 +814,9 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
     qt = int(q_tile)
     quant = ks2 is not None
     latent = v3 is None
+    token_major = not (latent or by_head or quant)
+    B = int(blocks_per_step) if blocks_per_step and token_major else _tiled_blocks_per_step(block_size, token_major)
+    W = B * block_size             # keys of a grid step
     dv = int(value_dim) if latent else d   # width of a value, of acc and of the output
     scale = softmax_scale or 1.0 / math.sqrt(d)
     G = g * qt                     # rows of one kv head in a tile
@@ -725,10 +829,11 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
     # --- segmented tiles and their live KV blocks (contiguity contract: see paged_attention) ---
     selected = selection is not None
     tile_id, slot, tile_tok, valid, tile_seq, tile_cnt, w_tile, w_col, total, *sel_mask = _tiled_work_list(
-        block_tables, seq_idx, pos, block_size, window, qt, selection)
+        block_tables, seq_idx, pos, block_size, window, qt, selection, B)
     n_tiles = tile_cnt.shape[0]
-    cols = (w_tile.shape[0] - 1) // n_tiles
-    smem = _tiled_smem_bytes(n_tiles, cols, *block_tables.shape)
+    bound = w_tile.shape[0] - 1    # the most items the shapes allow
+    cols = bound // n_tiles * B
+    smem = _tiled_smem_bytes(n_tiles, cols, *block_tables.shape, B)
     if smem > _TILED_SMEM_BYTES:
         raise ValueError(f"paged_attn_q_tiled: the work list of {n_tiles} tiles x {cols} table columns takes {smem} "
                          f"bytes of scalar memory, over {_TILED_SMEM_BYTES}")
@@ -747,28 +852,37 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
     def q_map(i, tile_ref, col_ref, seq_ref, cnt_ref, bt_ref):
         return (tile_ref[i], 0, 0)
 
-    def kv_map(i, tile_ref, col_ref, seq_ref, cnt_ref, bt_ref):
-        return (bt_ref[seq_ref[tile_ref[i]], col_ref[i]], 0, 0)
+    def column(b, i, col_ref):
+        """The table column of slot ``b`` of item ``i``, and whether the slot is dead (which names, as ``~c``,
+        the column whose block the pipeline already holds for it)."""
+        if B == 1:
+            return col_ref[i], False
+        c = col_ref[b * bound + i]
+        return jnp.where(c < 0, ~c, c), c < 0
+
+    def kv_map(b):
+        return lambda i, tile_ref, col_ref, seq_ref, cnt_ref, bt_ref: (
+            bt_ref[seq_ref[tile_ref[i]], column(b, i, col_ref)[0]], 0, 0)
 
     def scale_map(i, *refs):
-        return (0, kv_map(i, *refs)[0])
+        return (0, kv_map(0)(i, *refs)[0])
 
     nt_dims = (((1, ), (1, )), ((), ()))  # [rows, d] x [block, d] -> [rows, block]
 
-    def kernel(tile_ref, col_ref, seq_ref, cnt_ref, bt_ref, q_ref, k_ref, *rest):
-        if latent:
-            pos_ref, o_ref, acc_ref, m_ref, l_ref = rest
-        elif by_head:
-            v_ref, pos_ref, o_ref, acc_ref, m_ref, l_ref = rest
-        elif quant:
-            v_ref, pos_ref, ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref, kh_ref, vh_ref = rest
-        elif selected:
-            v_ref, pos_ref, sel_ref, spread_ref, o_ref, acc_ref, m_ref, l_ref, kh_ref, vh_ref = rest
-        else:
-            v_ref, pos_ref, o_ref, acc_ref, m_ref, l_ref, kh_ref, vh_ref = rest
+    def kernel(tile_ref, col_ref, seq_ref, cnt_ref, bt_ref, q_ref, *rest):
+        k_refs, rest = rest[:B], rest[B:]
+        if not latent:
+            v_refs, rest = rest[:B], rest[B:]
+        pos_ref, *rest = rest
+        if quant:
+            ks_ref, vs_ref, *rest = rest
+        if selected:
+            sel_refs, spread_ref, rest = rest[:B], rest[B], rest[B + 1:]
+        o_ref, acc_ref, m_ref, l_ref, *by_kv_head = rest
+        k_ref = k_refs[0]
         i = pl.program_id(0)
         tile = tile_ref[i]
-        jb = col_ref[i]  # the table column this step covers
+        jb = col_ref[i]  # the table column this step covers (of several, its first slot's)
 
         @pl.when(jnp.logical_or(i == 0, tile_ref[jnp.maximum(i - 1, 0)] != tile))
         def _init():
@@ -776,12 +890,25 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
             m_ref[:] = jnp.full_like(m_ref, -1e30)
             l_ref[:] = jnp.zeros_like(l_ref)
 
+        def key_positions(rows):
+            """The positions of this step's keys: ``[rows, block]`` of the one column's, or one row ``[1, W]``,
+            each slot's lanes from its own column on."""
+            if B == 1:
+                return jb * block_size + jax.lax.broadcasted_iota(jnp.int32, (rows, block_size), 1)
+            lane = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
+            kpos = None
+            for b in reversed(range(B)):
+                c, dead = column(b, i, col_ref)
+                first = (jnp.where(dead, _NO_COLUMN, c) - b) * block_size
+                kpos = lane + first if kpos is None else jnp.where(lane < (b + 1) * block_size, lane + first, kpos)
+            return kpos
+
         def _update(r, s, values):
             """Rows ``r`` of the tile's online softmax take the masked scores
-            ``s`` of this block and its ``values`` ``[block, dv]``."""
+            ``s`` of this step's keys and their ``values`` ``[keys, dv]``."""
             m_prev = m_ref[r, :]               # [rows, 128], lanes equal
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - _lanes(m_new, block_size))
+            p = jnp.exp(s - _lanes(m_new, W))
             alpha = jnp.exp(m_prev - m_new)
             l_ref[r, :] = l_ref[r, :] * alpha + jnp.sum(p, axis=-1, keepdims=True)
             acc_ref[r, :] = acc_ref[r, :] * _lane_copies(alpha, dv) + jax.lax.dot(
@@ -799,7 +926,7 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
                     r0 = pl.multiple_of(r0, 8)
                 r = pl.ds(r0, step)
                 my_pos = _lanes(pos_ref[0, r, :], block_size)   # -1 on invalid slots
-                kpos = jb * block_size + jax.lax.broadcasted_iota(jnp.int32, (step, block_size), 1)
+                kpos = key_positions(step)
                 vis = kpos <= my_pos
                 if window is not None:
                     vis = jnp.logical_and(vis, my_pos - kpos < window)
@@ -813,32 +940,48 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
                 jax.lax.fori_loop(0, rows // step, lambda n, c: (chunk(n), c)[1], 0)
 
         def _compute(rows):
-            """One KV block against the first ``rows`` rows of every kv head."""
+            """This step's KV blocks against the first ``rows`` rows of every kv head."""
             if latent:
                 return _latent(rows)
-            my_pos = _lanes(pos_ref[0, :rows, :], block_size)   # -1 on invalid slots
-            kpos = jb * block_size + jax.lax.broadcasted_iota(jnp.int32, (rows, block_size), 1)
+            my_pos = _lanes(pos_ref[0, :rows, :], W)   # -1 on invalid slots
+            kpos = key_positions(rows)
             vis = kpos <= my_pos
             if window is not None:
                 vis = jnp.logical_and(vis, my_pos - kpos < window)
             if alibi is not None:
                 rel = (kpos - my_pos).astype(jnp.float32)
-            if selected:  # every kv head's bit of each row, as columns [rows, heads]
-                picked = jax.lax.dot_general(spread_ref[:rows, :], sel_ref[0, 0], nt_dims,
-                                             preferred_element_type=jnp.float32)
+            if selected:  # each slot's mask with a token a sublane, [q_tile, heads]: a transpose through the MXU
+                eye = (jax.lax.broadcasted_iota(jnp.int32, (qt, qt), 0)
+                       == jax.lax.broadcasted_iota(jnp.int32, (qt, qt), 1)).astype(jnp.bfloat16)
+                by_token = [jax.lax.dot_general(eye, ref[0, 0], nt_dims, preferred_element_type=jnp.float32)
+                            for ref in sel_refs]
+                lane = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
             if quant:  # dequant at the VMEM tile — HBM only streamed int8
                 ks_t, vs_t = ks_ref[...].T, vs_ref[...].T   # [bs, nkv]
             if by_head:  # the fetched block is by head already
-                k_of, v_of = (lambda n: k_ref[n, 0].astype(cdt)), (lambda n: v_ref[n, 0].astype(cdt))
+                k_of, v_of = (lambda n: k_ref[n, 0].astype(cdt)), (lambda n: v_refs[0][n, 0].astype(cdt))
             else:
-                heads = zip(*(_block_heads(pl, pltpu, ref, nkv, block_size) for ref in (k_ref, v_ref)))
-                for n, (kh, vh) in enumerate(heads):  # the block's heads, once, for the head loop to index
-                    if quant:
-                        kh = kh.astype(jnp.float32) * ks_t[:, n:n + 1]
-                        vh = vh.astype(jnp.float32) * vs_t[:, n:n + 1]
-                    kh_ref[n] = kh.astype(cdt)
-                    vh_ref[n] = vh.astype(cdt)
+                kh_ref, vh_ref = by_kv_head
+                for b in range(B):  # the step's blocks side by side, each block's heads read out once
+                    at = slice(None) if B == 1 else pl.ds(b * block_size, block_size)
+                    heads = zip(*(_block_heads(pl, pltpu, ref, nkv, block_size) for ref in (k_refs[b], v_refs[b])))
+                    for n, (kh, vh) in enumerate(heads):  # for the head loop to index
+                        if quant:
+                            kh = kh.astype(jnp.float32) * ks_t[:, n:n + 1]
+                            vh = vh.astype(jnp.float32) * vs_t[:, n:n + 1]
+                        kh_ref[n, at] = kh.astype(cdt)
+                        vh_ref[n, at] = vh.astype(cdt)
                 k_of, v_of = (lambda n: kh_ref[n]), (lambda n: vh_ref[n])
+
+            def chosen(n):
+                """Whether each row's token selected, for kv head ``n``, the column of each key's slot: the
+                bits by token ``[q_tile, keys]`` (a slot's column of ``by_token`` over the slot's lanes), which
+                one product with the 0/1 matrix ``[rows, q_tile]`` repeats down each token's rows."""
+                bits = jnp.broadcast_to(by_token[-1][:, n:n + 1], (qt, W))
+                for b in reversed(range(B - 1)):
+                    bits = jnp.where(lane < (b + 1) * block_size, by_token[b][:, n:n + 1], bits)
+                return jax.lax.dot(spread_ref[:rows, :], bits.astype(jnp.bfloat16),
+                                   preferred_element_type=jnp.float32) > 0.5
 
             def head(n):
                 """One kv head's G rows: its working set is all that lives."""
@@ -850,7 +993,7 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
                                         preferred_element_type=jnp.float32) * scale
                 if alibi is not None:
                     s = s + _slopes_tok_major(alibi[n * g:(n + 1) * g], rows) * rel
-                seen = jnp.logical_and(vis, picked[:, n:n + 1] > 0.5) if selected else vis
+                seen = jnp.logical_and(vis, chosen(n)) if selected else vis
                 _update(r, jnp.where(seen, s, -1e30), v_of(n))
 
             if alibi is None and not selected:
@@ -874,20 +1017,23 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
         def _finalize():
             o_ref[0] = (acc_ref[:] / _lane_copies(jnp.maximum(l_ref[:], 1e-30), dv)).astype(o_ref.dtype)
 
-    kv_spec = pl.BlockSpec((nkv, 1, block_size, d), lambda i, *refs: (0, kv_map(i, *refs)[0], 0, 0)) if by_head \
-        else pl.BlockSpec((1, block_size * nkv, d), kv_map)
-    in_specs = [pl.BlockSpec((1, R, d), q_map)] + [kv_spec] * (1 if latent else 2) + [
+    if by_head:
+        kv_specs = [pl.BlockSpec((nkv, 1, block_size, d), lambda i, *refs: (0, kv_map(0)(i, *refs)[0], 0, 0))]
+    else:
+        kv_specs = [pl.BlockSpec((1, block_size * nkv, d), kv_map(b)) for b in range(B)]
+    in_specs = [pl.BlockSpec((1, R, d), q_map)] + kv_specs * (1 if latent else 2) + [
         pl.BlockSpec((1, G, _LANES), q_map)]
-    operands = [q_t, k3] + ([] if latent else [v3]) + [pos_rows]
+    operands = [q_t] + [k3] * B + ([] if latent else [v3] * B) + [pos_rows]
     if quant:
         in_specs += [pl.BlockSpec((nkv, block_size), scale_map),
                      pl.BlockSpec((nkv, block_size), scale_map)]
         operands += [ks2, vs2]
     if selected:
         spread = (np.arange(G)[:, None] // g == np.arange(qt)[None, :])
-        in_specs += [pl.BlockSpec((1, 1, _mask_heads(nkv), qt), lambda i, tile_ref, col_ref, *refs: (tile_ref[i], col_ref[i], 0, 0)),
-                     pl.BlockSpec((G, qt), lambda i, *refs: (0, 0))]
-        operands += [sel_mask[0], jnp.asarray(spread, jnp.bfloat16)]
+        mask_map = lambda b: lambda i, tile_ref, col_ref, *refs: (tile_ref[i], column(b, i, col_ref)[0], 0, 0)
+        in_specs += [pl.BlockSpec((1, 1, _mask_heads(nkv), qt), mask_map(b)) for b in range(B)] + [
+            pl.BlockSpec((G, qt), lambda i, *refs: (0, 0))]
+        operands += [sel_mask[0]] * B + [jnp.asarray(spread, jnp.bfloat16)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
@@ -899,8 +1045,8 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
             pltpu.VMEM((R, _LANES), jnp.float32),
             pltpu.VMEM((R, _LANES), jnp.float32),
         ] + ([] if latent or by_head else [
-            pltpu.VMEM((nkv, block_size, d), cdt),   # the block's K, V by kv head
-            pltpu.VMEM((nkv, block_size, d), cdt),
+            pltpu.VMEM((nkv, W, d), cdt),   # the step's K, V by kv head
+            pltpu.VMEM((nkv, W, d), cdt),
         ]),
     )
     kwargs = {}
@@ -908,7 +1054,7 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
         # the default scoped limit (16 MiB on a v5e) is under the working set
         # of a 128-token tile of 32 heads; ask for what the step needs plus
         # half again for Mosaic's own temporaries
-        need = _q_tiled_vmem_bytes(R, G, d, block_size, nkv, q.dtype.itemsize, k3.dtype.itemsize)
+        need = _q_tiled_vmem_bytes(R, G, d, block_size, nkv, q.dtype.itemsize, k3.dtype.itemsize, B)
         kwargs["compiler_params"] = pltpu.CompilerParams(
             vmem_limit_bytes=max(32 << 20, min(need * 3 // 2, _Q_TILED_VMEM_LIMIT)))
     out_t = pl.pallas_call(kernel, grid_spec=grid_spec,
@@ -917,7 +1063,8 @@ def _paged_q_tiled(pl, pltpu, q, k3, v3, block_tables, seq_idx, pos, ks2, vs2,
                                w_tile, w_col, tile_seq, tile_cnt, block_tables, *operands)
     # scatter tiles back to token order: only slots that tokens fill are read
     flat = out_t.reshape(n_tiles, nkv, qt, g, dv).transpose(0, 2, 1, 3, 4).reshape(n_tiles * qt, nq, dv)
-    return (flat[tile_id * qt + slot], sel_mask[1]) if selected else flat[tile_id * qt + slot]
+    out = flat[tile_id * qt + slot]
+    return (out, _read_counts(sel_mask[1], sel_mask[2], total)) if selected else out
 
 
 # measured on a v5e (PERF.md section 6, PR 28): 512 KiB blocks (8 kv heads of
@@ -962,16 +1109,20 @@ def decode_kv_counts(choice, pos, windows, block_size: int, max_blocks: int, buc
     return steps, live
 
 
-def tiled_kv_counts(q_tile: int, seq_idx, pos, windows, block_size: int, max_blocks: int, bucket_rows: int):
-    """``(tile_kv_bound, tile_kv_live)`` of one forward's ``paged_attn_q_tiled``
-    calls, on the host: ``tile_kv_live`` the items of :func:`_tiled_work_list`
-    (the grid steps the kernel ran: the same run, tile and column rules,
-    applied in numpy) and ``tile_kv_bound`` the rectangle the shapes allow,
-    ``n_tiles x min(max_blocks, columns a window can span)``, which the grid
-    walked until PR 34. ``seq_idx``/``pos``: the batch's tokens as the kernel
-    is given them, the pad run included (``pos`` what it MASKS by: a
-    block-diffusion model's ``pos | (B - 1)``); ``windows`` as for
-    :func:`decode_kv_counts`, over whose layers both counts are summed."""
+def tiled_kv_counts(q_tile: int, seq_idx, pos, windows, block_size: int, max_blocks: int, bucket_rows: int,
+                    per_step: int = 1):
+    """``(tile_kv_bound, tile_kv_live, tile_kv_steps)`` of one forward's
+    ``paged_attn_q_tiled`` calls without a selection, on the host:
+    ``tile_kv_live`` the live (tile, KV block) pairs of
+    :func:`_tiled_work_list` (the same run, tile and column rules, applied in
+    numpy), ``tile_kv_steps`` its items, the grid steps the kernel ran for
+    them at ``per_step`` of a tile's pairs a step (the choice's
+    ``blocks_per_step``), and ``tile_kv_bound`` the rectangle the shapes
+    allow, ``n_tiles x min(max_blocks, columns a window can span)``, which
+    the grid walked until PR 34. ``seq_idx``/``pos``: the batch's tokens as
+    the kernel is given them, the pad run included (``pos`` what it MASKS by:
+    a block-diffusion model's ``pos | (B - 1)``); ``windows`` as for
+    :func:`decode_kv_counts`, over whose layers the counts are summed."""
     seq_idx, pos = np.asarray(seq_idx, np.int32), np.asarray(pos, np.int32)
     qt = int(q_tile)
     n_tiles = -(-pos.size // qt) + bucket_rows + 1
@@ -981,12 +1132,13 @@ def tiled_kv_counts(q_tile: int, seq_idx, pos, windows, block_size: int, max_blo
     np.minimum.at(tile_min, tile_id, pos)
     np.maximum.at(tile_max, tile_id, pos)
     tile_cnt = np.bincount(tile_id, minlength=n_tiles)
-    bound = live = 0
+    bound = live = steps = 0
     for window, layers in windows:
         _, n, cols = _tile_columns(tile_min, tile_max, tile_cnt, block_size, max_blocks, window, qt, xp=np)
         live += layers * int(n.sum())
+        steps += layers * int((-(-n // per_step)).sum())
         bound += layers * n_tiles * cols
-    return bound, live
+    return bound, live, steps
 
 
 def _decode_work_list(block_tables, seq_idx, pos, block_size: int, window, per_step: int = 1, selection=None):
@@ -1246,4 +1398,4 @@ def _paged_kv_split(pl, pltpu, q, k2, v2, block_tables, seq_idx, pos, ks2, vs2,
                          out_shape=jax.ShapeDtypeStruct((T, nq, dv), q.dtype),
                          interpret=interpret, name="paged_attn_kv_split")(
                              w_row, w_col, w_blk, pos, *w_heads, *operands)
-    return (out, read) if selected else out
+    return (out, _read_counts(read)) if selected else out

@@ -19,6 +19,18 @@ _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = _flags + " --xla_force_host_platform_device_count=8"
 os.environ["JAX_PLATFORMS"] = "cpu"
+# XLA:CPU compiles what these tests run at its lowest optimization level (jax's
+# own flag: LLVM at -O0 and none of its expensive passes). The programs are
+# tiny and run once or twice, so most of a test's CPU time is compiling, on as
+# many threads as the machine has cores, beside five other workers. The same
+# tree, back to back on one sandbox, took 1,608 s with the optimizations (the
+# driver cuts the run at 1,470 s) and 1,114 s without: 9,059 against 6,196 s
+# of tests (PERF.md section 2, "How a PR is checked"). Set in ``os.environ``
+# before jax is imported, so the rehearsals and drills that tests start as
+# processes of their own compile the same way: a quarter of the suite's time
+# is theirs. Nothing outside ``tests/`` sets it: no run of the benchmark and
+# no run on the chip sees it.
+os.environ.setdefault("JAX_DISABLE_MOST_OPTIMIZATIONS", "1")
 
 import jax  # noqa: E402
 
@@ -87,6 +99,7 @@ _FIRST_OUT = (
     "test_pipeline.py",
     "test_speculative.py",
     "test_mellum.py",
+    "test_tiled_blocks_per_step.py",
 )
 
 # A test's own limit, in seconds. One that nears it is repaired, not given more.

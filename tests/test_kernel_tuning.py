@@ -2,6 +2,7 @@
 the gather oracle), the one selector that picks between them from the
 shapes, explicit ZeRO-3 overlap bit-identical loss."""
 
+import functools
 import json
 import os
 import sys
@@ -501,10 +502,10 @@ def test_tiled_work_list_holds_exactly_the_live_pairs(q_tile, window, bound4):
         mine = [c for j, c in want if j == i]
         assert mine and mine[-1] == pos[toks].max() // bs and len(mine) <= cols
         assert window is None or mine[0] == max(pos[toks].min() - window + 1, 0) // bs
-    assert tiled_kv_counts(q_tile, seq_idx, pos, [(window, 1)], bs, mb, S) == (n_tiles * cols, total)
+    assert tiled_kv_counts(q_tile, seq_idx, pos, [(window, 1)], bs, mb, S) == (n_tiles * cols, total, total)
+    both = 3 * total + 2 * tiled_kv_counts(q_tile, seq_idx, pos, [(None, 1)], bs, mb, S)[1]
     assert tiled_kv_counts(q_tile, seq_idx, pos, [(window, 3), (None, 2)], bs, mb, S) == (
-        3 * n_tiles * cols + 2 * n_tiles * mb,
-        3 * total + 2 * tiled_kv_counts(q_tile, seq_idx, pos, [(None, 1)], bs, mb, S)[1])
+        3 * n_tiles * cols + 2 * n_tiles * mb, both, both)
 
 
 def test_a_work_list_past_the_scalar_memory_raises_at_trace_time():
@@ -514,10 +515,13 @@ def test_a_work_list_past_the_scalar_memory_raises_at_trace_time():
     sd = jax.ShapeDtypeStruct
     args = lambda mb: (sd((256, 4, 32), jnp.float32), sd((64, 2, 32), jnp.float32), sd((64, 2, 32), jnp.float32),
                        sd((64, mb), jnp.int32), sd((256, ), jnp.int32), sd((256, ), jnp.int32))
-    fn = lambda *a: _pallas_paged(*a, block_size=16, interpret=True, q_tile=8)
+    fn = lambda *a, **kw: _pallas_paged(*a, block_size=16, interpret=True, q_tile=8, **kw)
     assert pa_mod._tiled_smem_bytes(97, 65, 64, 65) == 83992          # the claimed cell's forward
     assert jax.eval_shape(fn, *args(65)).shape == (256, 4, 32)
     with pytest.raises(ValueError, match="97 tiles x 700 table columns takes 740592 bytes of scalar memory"):
+        jax.eval_shape(functools.partial(fn, blocks_per_step=1), *args(700))
+    # four 16-token blocks an item: a slot's column each and a quarter of the items, so five eighths of the list
+    with pytest.raises(ValueError, match="97 tiles x 700 table columns takes 536892 bytes of scalar memory"):
         jax.eval_shape(fn, *args(700))
 
 

@@ -361,8 +361,8 @@ _HAND_MADE = {"tiled": (6, 2, 16, 8, [50, 33, 9], [20, 1, 9], 8), "decode": (6, 
 def test_both_work_lists_under_a_hand_made_selection_match_the_gather(case):
     nq, nkv, d, bs, lens, news, q_tile = _HAND_MADE[case]
     n, args, sel = _batch(nq, nkv, d, bs, lens, news, jnp.float32)
-    want, own = pa.paged_attention_reference(*args, bs, selection=sel)
-    got, read = pa._pallas_paged(*args, block_size=bs, interpret=True, q_tile=q_tile, selection=sel)
+    want, (own, *_) = pa.paged_attention_reference(*args, bs, selection=sel)
+    got, (read, *_) = pa._pallas_paged(*args, block_size=bs, interpret=True, q_tile=q_tile, selection=sel)
     assert np.abs(np.asarray(got[:n] - want[:n])).max() < 2e-5
     # what the list says it served: a token's own columns a row a step, a tile's union for its tokens otherwise
     visible = int(np.sum(np.asarray(args[5]) // bs + 1))   # (the pad run is sequence 0's token 0 to a list)
@@ -389,7 +389,7 @@ def test_an_all_true_selection_is_bit_equal_to_no_selection(case):
     lists hold the items the plain lists hold, in their order."""
     nq, nkv, d, bs, lens, news, q_tile, dtype = _ALL_TRUE[case]
     n, args, sel = _batch(nq, nkv, d, bs, lens, news, dtype, all_true=True)
-    got, read = pa._pallas_paged(*args, block_size=bs, interpret=True, q_tile=q_tile, selection=sel)
+    got, (read, *_) = pa._pallas_paged(*args, block_size=bs, interpret=True, q_tile=q_tile, selection=sel)
     plain = pa._pallas_paged(*args, block_size=bs, interpret=True, q_tile=q_tile)
     assert (np.asarray(got[:n]) == np.asarray(plain[:n])).all()
     assert int(read) == int(np.sum(np.asarray(args[5]) // bs + 1))   # every visible block, a token
@@ -540,6 +540,41 @@ def test_what_takes_state_or_pooled_keys_to_be_blocks_is_refused_by_name(tiny, e
             TieredBlockStore(alone, HostTierConfig(enabled=True, host_blocks=4))
 
 
+def test_a_step_span_says_how_full_the_tiled_grids_steps_were(tiny, monkeypatch):
+    """With the tiled kernel serving the ``put`` programs (its body on the
+    interpreter, a tile of 8), ``serving/prefill`` says ``attn_items_live``,
+    the (tile, column) pairs the lists of the two sparse layers laid, and
+    ``attn_grid_steps``, the steps they ran in at four 8-token blocks a step,
+    beside an ``attn_blocks_read`` that is what the gather's program counts
+    for a row under ``dense_len`` (every visible block) and no less past it."""
+    from deepspeed_tpu.inference.v2.modules.implementations import attention
+    from deepspeed_tpu.monitor.trace import get_tracer
+
+    def tiled(q, k, v, tables, seq_idx, pos, bs, window=None, alibi=None, selection=None, **kw):
+        return pa._pallas_paged(q, k, v, tables, seq_idx.astype(jnp.int32), pos.astype(jnp.int32), block_size=bs,
+                                interpret=True, q_tile=8, selection=selection)
+
+    monkeypatch.setattr(attention, "paged_attention", tiled)
+    cfg, params, ids = tiny
+    eng = _engine(cfg, params, use_pallas_kernels="always")
+    get_tracer().reset()
+    tracer = get_tracer().configure(enabled=True)
+    try:
+        for c0 in range(0, 90, 30):
+            _logits(eng, [21], [ids[c0:c0 + 30]])
+        spans = [e["args"] for e in tracer.drain() if e["ph"] == "X" and e["name"] == "serving/prefill"]
+    finally:
+        get_tracer().reset()
+    first, _, last = spans
+    layers, nkv = 2, 2
+    # positions 0-29: tiles of 8 tokens see 1, 2, 3 and 4 columns, a step each (the pad run's tokens select nothing)
+    assert first["attn_items_live"] == layers * (1 + 2 + 3 + 4) and first["attn_grid_steps"] == layers * 4
+    assert first["attn_blocks_read"] == first["attn_blocks_visible"] == layers * nkv * sum(p // 8 + 1 for p in range(30))
+    # past dense_len a tile's union is at most its 8 tokens' 6 blocks and at least 6: two or more steps a tile
+    assert last["attn_items_live"] / 4 <= last["attn_grid_steps"] < last["attn_items_live"]
+    assert layers * 4 * 2 <= last["attn_grid_steps"] and last["attn_blocks_selected"] <= last["attn_blocks_read"]
+
+
 def test_a_step_span_says_what_was_visible_selected_and_read(tiny, engine, tmp_path):
     """The selection's counters and Solar's six state counters on the step
     spans, by hand: a 10-token chunk after 180 cached tokens beside a one-token
@@ -571,6 +606,8 @@ def test_a_step_span_says_what_was_visible_selected_and_read(tiny, engine, tmp_p
     assert prefill["attn_blocks_visible"] == layers * nkv * (sum(p // 8 + 1 for p in chunk) + 1)
     assert prefill["attn_blocks_selected"] == layers * nkv * (10 * 6 + 1)
     assert prefill["attn_blocks_selected"] <= prefill["attn_blocks_read"] <= prefill["attn_blocks_visible"]
+    # (the tiled lists' pairs and grid steps: off the chip the gather serves every call and lays no tiles)
+    assert prefill["attn_items_live"] == prefill["attn_grid_steps"] == 0
     assert (prefill["sparse_rows"], prefill["dense_rows"]) == (1, 1)
     assert prefill["index_keys"] == layers * nkv * sum((p - 3) // 2 + 1 for p in chunk)
     assert prefill["index_entry_bytes"] == 2 * 16 * 4 and prefill["kv_entry_bytes"] == 2 * 2 * 16 * 4

@@ -203,13 +203,19 @@ def test_decode_spans_count_the_kernels_grid_and_the_live_pairs(window):
     assert 0 < burst["kv_live"] <= burst["kv_steps"]
 
 
+# the tiled choice these tests plant: four 16-token blocks a grid step, the rule's over token-major float32 pools
+PLANTED = {"kernel": "paged_attn_q_tiled", "q_tile": 8, "blocks_per_step": 4, "rule": "planted"}
+
+
 @pytest.mark.parametrize("window", [None, 24])
 def test_spans_of_a_shape_the_tiled_kernel_took_count_its_work_list(window, monkeypatch):
     """``serving/prefill`` of a shape ``paged_attn_q_tiled`` took carries
-    ``tile_kv_live``, the work list's ``total`` on the same batch, and
-    ``tile_kv_bound``, the tiles x columns rectangle of the shapes, both times
+    ``tile_kv_live``, the work list's live (tile, column) pairs on the same
+    batch (its ``total`` at one block an item), ``tile_kv_steps``, its
+    ``total`` at the choice's four 16-token blocks an item, and
+    ``tile_kv_bound``, the tiles x columns rectangle of the shapes, each times
     the layers; not ``kv_live``/``kv_steps``, which stay the decode grid's;
-    a shape another kernel took carries neither of the two new counts. (Off
+    a shape another kernel took carries none of the three. (Off
     the TPU no shape takes the tiled kernel: the choice is planted.)"""
     engine = _engine(window)
     rng = np.random.default_rng(2)
@@ -219,8 +225,7 @@ def test_spans_of_a_shape_the_tiled_kernel_took_count_its_work_list(window, monk
     engine.put([7, 8, 9], one * 2 + [tokens(20)])              # trace the programs first
     engine.put([7, 8], one * 2)
     max_blocks, layers = 96 // 16, 2
-    monkeypatch.setitem(pa.KERNEL_CHOICES, (32, 4, max_blocks),
-                        {"kernel": "paged_attn_q_tiled", "q_tile": 8, "blocks_per_step": 1, "rule": "planted"})
+    monkeypatch.setitem(pa.KERNEL_CHOICES, (32, 4, max_blocks), PLANTED)
     batches = []
     finalize = engine.batch.finalize
     monkeypatch.setattr(engine.batch, "finalize", lambda: batches.append(finalize()) or batches[-1])
@@ -231,17 +236,15 @@ def test_spans_of_a_shape_the_tiled_kernel_took_count_its_work_list(window, monk
     mixed, step = events["serving/prefill"], events["serving/decode_step"]
     assert mixed["kernel"] == "paged_attn_q_tiled:8:planted" and (mixed["bucket_tokens"], mixed["bucket_rows"]) == (32, 4)
     rb = batches[0]
-    *_, total = pa._tiled_work_list(jnp.asarray(rb.block_tables), jnp.asarray(rb.token_seq_idx),
-                                    jnp.asarray(rb.token_pos), 16, window, 8)
+    pairs, steps = (int(pa._tiled_work_list(jnp.asarray(rb.block_tables), jnp.asarray(rb.token_seq_idx),
+                                            jnp.asarray(rb.token_pos), 16, window, 8, per_step=per)[8]) for per in (1, 4))
     n_tiles = 32 // 8 + 4 + 1
     cols = max_blocks if window is None else min(max_blocks, (window + 8 - 2) // 16 + 2)
-    assert mixed["tile_kv_live"] == layers * int(total) and mixed["tile_kv_bound"] == layers * n_tiles * cols
+    assert mixed["tile_kv_live"] == layers * pairs and mixed["tile_kv_bound"] == layers * n_tiles * cols
+    assert mixed["tile_kv_steps"] == layers * steps and 0 < steps < pairs
     assert 0 < mixed["tile_kv_live"] < mixed["tile_kv_bound"]
     assert not {"kv_live", "kv_steps"} & set(mixed)
-    assert {"kv_live", "kv_steps"} <= set(step) and not {"tile_kv_live", "tile_kv_bound"} & set(step)
-
-
-PLANTED = {"kernel": "paged_attn_q_tiled", "q_tile": 8, "blocks_per_step": 1, "rule": "planted"}
+    assert {"kv_live", "kv_steps"} <= set(step) and not {"tile_kv_live", "tile_kv_bound", "tile_kv_steps"} & set(step)
 
 
 def _latent_engine(monkeypatch, min_tokens=8):
@@ -261,9 +264,10 @@ def test_a_latent_models_spans_say_the_pairs_attended_expanded_and_name_both_cal
     ``attn_expanded_pairs`` beside ``attn_pairs``, from the rows' lengths
     alone (the pairs of the rows fed at least the threshold, times the
     layers), its ``kernel`` names the absorbed call and the expanded one with
-    their tiles, and its ``tile_kv_live`` is both calls' work lists; a step of
-    decode rows and a decode horizon say 0. (Off the TPU no shape takes the
-    tiled kernel: both calls' choices are planted.)"""
+    their tiles, and its ``tile_kv_live`` is both calls' work lists, as is its
+    ``tile_kv_steps`` (a latent pool and pools by head are read a block a grid
+    step); a step of decode rows and a decode horizon say 0. (Off the TPU no
+    shape takes the tiled kernel: both calls' choices are planted.)"""
     cfg, engine = _latent_engine(monkeypatch)
     rng = np.random.default_rng(4)
     tokens = lambda n: rng.integers(0, cfg.vocab_size, size=n, dtype=np.int32)
@@ -271,8 +275,8 @@ def test_a_latent_models_spans_say_the_pairs_attended_expanded_and_name_both_cal
     engine.put([7], [tokens(30)]), engine.put([8], [tokens(9)])
     engine.put([7, 8, 9], one * 2 + [tokens(20)]), engine.put([7, 8], one * 2), engine.decode([7, 8], one * 2, 3)  # trace first
     max_blocks, layers = 128 // 16, cfg.num_layers
-    monkeypatch.setitem(pa.KERNEL_CHOICES, (32, 4, max_blocks), PLANTED)
-    monkeypatch.setitem(pa.KERNEL_CHOICES, (32, 2 * 2 + 1, max_blocks), {**PLANTED, "q_tile": 16})
+    monkeypatch.setitem(pa.KERNEL_CHOICES, (32, 4, max_blocks), {**PLANTED, "blocks_per_step": 1})
+    monkeypatch.setitem(pa.KERNEL_CHOICES, (32, 2 * 2 + 1, max_blocks), {**PLANTED, "q_tile": 16, "blocks_per_step": 1})
     engine._kernel_labels.clear()
     batches = []
     finalize = engine.batch.finalize
@@ -299,7 +303,7 @@ def test_a_latent_models_spans_say_the_pairs_attended_expanded_and_name_both_cal
                                         16, None, tile)
         totals.append(int(total))
     assert totals[1] == 1 + 2                                  # positions 0-19: a tile of 16 over one block, one of 4 over two
-    assert mixed["tile_kv_live"] == layers * sum(totals)
+    assert mixed["tile_kv_live"] == mixed["tile_kv_steps"] == layers * sum(totals)
     assert mixed["tile_kv_bound"] == layers * ((32 // 8 + 4 + 1) + (32 // 16 + 5 + 1)) * max_blocks
 
 
@@ -414,11 +418,13 @@ def _step_case(name, plant):
 
 # what the step span of each case said at commit 3963d3a (the parent of the PR that moved the observation under
 # a span of its own), but ``seqs``, which repeated ``rows``, and ``block_ms``, a time; uids from 50; and, since PR 38,
-# what a causal step says of its attention work by hand: ``attn_pairs``, ``attn_ctx_tokens``, ``kv_entry_bytes``
+# what a causal step says of its attention work by hand: ``attn_pairs``, ``attn_ctx_tokens``, ``kv_entry_bytes``; and, since
+# PR 48, ``tile_kv_steps`` beside ``tile_kv_live`` (the live pairs, as before): the grid steps at the planted four blocks a
+# step (the mixed put's seven tiles have one or two live columns each: 7 steps a layer for 9 pairs)
 STEP_SPAN_ARGS = {
     "dense_put_with_a_prefill_chunk": {
         "blocked": True, "bucket_rows": 4, "bucket_tokens": 32, "kernel": "paged_attn_q_tiled:8:planted",
-        "rows": 3, "rows_decode": 2, "steps": 1, "tile_kv_bound": 108, "tile_kv_live": 18, "tokens": 22,
+        "rows": 3, "rows_decode": 2, "steps": 1, "tile_kv_bound": 108, "tile_kv_live": 18, "tile_kv_steps": 14, "tokens": 22,
         "uids": [50, 51, 52], "attn_pairs": 2 * (31 + 10 + 210), "attn_ctx_tokens": 2 * (31 + 10 + 20), "kv_entry_bytes": 256},
     "dense_put_of_decode_rows": {
         "blocked": True, "bucket_rows": 4, "bucket_tokens": 8, "kernel": "paged_attention_reference:1:off_tpu",
@@ -446,7 +452,7 @@ STEP_SPAN_ARGS = {
         "denoise_forwards": 8, "expert_load_max": 9, "experts_held": 8, "experts_hit": 193, "experts_published": 8,
         "experts_total": 224, "kernel": "paged_attn_q_tiled:8:planted", "masked_fed": 51, "moe_rows": 4928,
         "moe_slots": 672, "moe_slots_routed": 672, "open_tokens": 3, "rows": 3, "steps": 10, "tile_kv_bound": 4368,
-        "tile_kv_live": 266, "tokens": 24, "tokens_committed": 21, "tokens_dropped": 0, "tokens_fed": 120,
+        "tile_kv_live": 266, "tile_kv_steps": 168, "tokens": 24, "tokens_committed": 21, "tokens_dropped": 0, "tokens_fed": 120,
         "uids": [50, 51, 52]},
 }
 
@@ -677,10 +683,11 @@ def test_kernel_choice_names_the_rule_that_decided(monkeypatch, shape, env, want
 
 
 @pytest.mark.parametrize("T,S,max_blocks,want", [
+    # the tiled kernel: 16-token blocks are an eighth of a lane tile, so four a grid step (the most)
     (512, 32, 65, {"kernel": "paged_attn_q_tiled", "q_tile": 32, "rule": "heuristic:short_rows",
-                   "blocks_per_step": 1}),
+                   "blocks_per_step": 4}),
     (2048, 8, 65, {"kernel": "paged_attn_q_tiled", "q_tile": 128, "rule": "heuristic:long_rows",
-                   "blocks_per_step": 1}),
+                   "blocks_per_step": 4}),
     # the decode kernel: 16-token blocks of 8 kv heads of 128 in float32 are 128 KiB, so four a grid step
     (32, 32, 65, {"kernel": "paged_attn_kv_split", "q_tile": 1, "rule": "heuristic:long_table",
                   "blocks_per_step": 4}),

@@ -225,7 +225,9 @@ def test_the_engine_generates_what_the_reference_generates(weights, strategy, st
 
 def test_a_block_calls_span_counts_the_tiled_grid_forward_by_forward(weights, monkeypatch):
     """``serving/decode`` of a shape ``paged_attn_q_tiled`` took (planted: off
-    the TPU none does) carries ``tile_kv_live`` and ``tile_kv_bound`` summed
+    the TPU none does) carries ``tile_kv_live``, the live (tile, column) pairs,
+    ``tile_kv_steps``, the grid steps at the choice's four 16-token blocks a
+    step, and ``tile_kv_bound``, each summed
     over the call's forwards and layers: a block's denoise forwards run every
     layer's attention and its commit all but the last layer's, each at the
     block's own positions under the bound ``pos | 3``, the pad run's too; not
@@ -240,7 +242,7 @@ def test_a_block_calls_span_counts_the_tiled_grid_forward_by_forward(weights, mo
     engine.decode(uids, known, 8)                                    # trace the program first
     layers, max_blocks, S = model.config.num_layers, 192 // 16, 8
     monkeypatch.setitem(pa.KERNEL_CHOICES, (S * B, S, max_blocks),
-                        {"kernel": "paged_attn_q_tiled", "q_tile": 8, "blocks_per_step": 1, "rule": "planted"})
+                        {"kernel": "paged_attn_q_tiled", "q_tile": 8, "blocks_per_step": 4, "rule": "planted"})
     batches, finalize = [], engine._block_batch.finalize
     monkeypatch.setattr(engine._block_batch, "finalize", lambda: batches.append(finalize()) or batches[-1])
     get_tracer().reset()
@@ -254,12 +256,14 @@ def test_a_block_calls_span_counts_the_tiled_grid_forward_by_forward(weights, mo
     assert (span["denoise_forwards"], span["commit_forwards"]) == (8, 2)
     (rb, ) = batches                                                 # the call's descriptor: its first block
     tables, seq_idx = jnp.asarray(rb.block_tables), jnp.asarray(rb.token_seq_idx)
-    live = 0
+    live = steps = 0
     for b in range(2):
-        *_, total = pa._tiled_work_list(tables, seq_idx, jnp.asarray((rb.token_pos + b * B) | (B - 1)), 16, None, 8)
-        live += (4 * layers + (layers - 1)) * int(total)
+        pairs, items = (int(pa._tiled_work_list(tables, seq_idx, jnp.asarray((rb.token_pos + b * B) | (B - 1)), 16, None, 8,
+                                                per_step=per)[8]) for per in (1, 4))
+        live += (4 * layers + (layers - 1)) * pairs
+        steps += (4 * layers + (layers - 1)) * items
     n_tiles = S * B // 8 + S + 1
-    assert span["tile_kv_live"] == live > 0
+    assert span["tile_kv_live"] == live > 0 and span["tile_kv_steps"] == steps and live / 4 <= steps < live
     assert span["tile_kv_bound"] == 2 * (4 * layers + layers - 1) * n_tiles * max_blocks
     assert not {"kv_live", "kv_steps"} & set(span)
 

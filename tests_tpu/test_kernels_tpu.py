@@ -1102,7 +1102,8 @@ def test_paged_kernels_under_a_selection_at_the_cells_shapes_on_chip(name, T, S,
     last 33 and 30 drawn at random, each token its own draw) through
     ``paged_attention`` as the engine calls it, against the gather reference
     on the same selection; under an all-true selection bit-equal to the call
-    without one. Prints the microseconds a call."""
+    without one. Prints the microseconds a call, and for the tiled kernel the
+    same at ONE block a grid step beside the rule's two, a step and a key."""
     from deepspeed_tpu.ops.pallas import paged_attention as pa
 
     nq, nkv, d, bs, mb, n_blocks = 32, 2, 128, 64, 1034, 8300
@@ -1134,13 +1135,38 @@ def test_paged_kernels_under_a_selection_at_the_cells_shapes_on_chip(name, T, S,
     assert choice["kernel"] == kernel, choice
     us = _us_a_call(fn, q, tables, seq_idx, pos, sel, calls=10)
     visible = int(np.sum(np.asarray(pos) // bs + 1))
-    print(f"\nselection[{name}, {picked}]: {us:.0f} us a call, {choice}, served {int(read)} of {visible} (token, column) pairs")
+    pairs, items, steps = (int(c) for c in read)
+    print(f"\nselection[{name}, {picked}]: {us:.0f} us a call, {choice}, served {pairs} of {visible} (token, column) pairs")
+    one = None
+    if kernel == "paged_attn_q_tiled":
+        # the same call at ONE block a grid step, through the kernel's own test-only argument: the before and the
+        # after from one tree on one chip (the parent's tree read 52.4 ms under this selection, 56.5 under the
+        # all-true one and 45.6 without one: my chip runs, PR 47)
+        assert choice["blocks_per_step"] == 2 and 0 < steps <= items <= 2 * steps
+        tiled = lambda per, sel_too=True: jax.jit(lambda q, tables, seq_idx, pos, sel: pa._pallas_paged(
+            q, k_pool, v_pool, tables, seq_idx, pos, block_size=bs, q_tile=choice["q_tile"], blocks_per_step=per,
+            selection=sel if sel_too else None))
+        one_fn = tiled(1)
+        one, read_one = one_fn(q, tables, seq_idx, pos, sel)
+        assert [int(c) for c in read_one] == [pairs, items, items]
+        us_one = _us_a_call(one_fn, q, tables, seq_idx, pos, sel, calls=10)
+        print(f"  two blocks a grid step: {us:.0f} us a call, {steps} steps for {items} (tile, column) pairs "
+              f"({items / steps:.3f} a step), {us / steps:.2f} us a step, {us * 1e3 / (items * bs):.2f} ns a (tile, key)\n"
+              f"  one block a grid step: {us_one:.0f} us a call, {items} steps, {us_one / items:.2f} us a step, "
+              f"{us_one * 1e3 / (items * bs):.2f} ns a (tile, key): a two-block step costs {us / steps / (us_one / items):.2f} "
+              f"of a one-block step")
     if picked == "all_true":
-        assert int(read) == visible
+        assert pairs == visible
         plain = jax.jit(lambda q, tables, seq_idx, pos: pa.paged_attention(q, k_pool, v_pool, tables, seq_idx, pos, bs))
         assert (np.asarray(out[:n]) == np.asarray(plain(q, tables, seq_idx, pos)[:n])).all()
         print(f"  without a selection: {_us_a_call(plain, q, tables, seq_idx, pos, calls=10):.0f} us a call")
+        if one is not None:
+            us_plain_one = _us_a_call(tiled(1, False), q, tables, seq_idx, pos, sel, calls=10)
+            print(f"  without a selection, one block a grid step: {us_plain_one:.0f} us a call")
         return
+    if one is not None:  # the same keys under the same masks: rounding apart (one partial maximum a 128 keys, not a 64)
+        a, b = np.asarray(out[:n], np.float32), np.asarray(one[:n], np.float32)
+        assert np.linalg.norm(a - b) / np.linalg.norm(b) < 1e-2
     # the gather reference a token block at a time: [tokens, heads, 66k keys] float32 does not fit at once
     ref = jax.jit(lambda q, seq_idx, pos, sel: pa.paged_attention_reference(q, k_pool, v_pool, tables, seq_idx, pos, bs,
                                                                             selection=sel))
