@@ -163,7 +163,10 @@ class DiffusionConfig:
     denoising_steps`` forwards) masked positions of highest confidence;
     ``low_confidence_dynamic``: every masked position whose confidence is
     over ``confidence_threshold``, and at least the most confident one. The
-    last of the ``denoising_steps`` forwards unmasks whatever is left."""
+    last of the ``denoising_steps`` forwards unmasks whatever is left. The K/V
+    of a block's final ids are written by the next block's first denoise
+    forward, which is fed both blocks, or, for the last block of a ``decode``
+    call, by one forward more that writes K/V alone: nothing here chooses."""
 
     denoising_steps: int = 4
     remasking: str = "low_confidence_static"

@@ -613,7 +613,7 @@ class InferenceEngineV2:
                                            self.config.cut_rows_on_host and mode is not None)
                 if mode == "probe":  # (logits, (positions, selection, attention output)) of the live rows
                     out = (out, tuple(np.asarray(a)[:rb.n_seqs] for a in stats[1]))
-            _observe(sp, lambda: self._moe_span_args(held["tokens"], t_bucket, 1, seen[0]) if seen else {}, held)
+            _observe(sp, lambda: self._moe_span_args([(held["tokens"], t_bucket, 1, 0)], seen[0]) if seen else {}, held)
         if rf.enabled and block:
             # wall join through the blocking host fetch — the same window the
             # outer put() books as prefill/decode-active in the goodput ledger,
@@ -631,10 +631,13 @@ class InferenceEngineV2:
                 reg.histogram("serving/decode_step_ms").observe(dt_ms)
         return out
 
-    def _moe_span_args(self, tokens: int, t_bucket: int, forwards: int, stats, kv_only_forwards: int = 0) -> dict:
-        """What a step span says of the expert layers over a call of
-        ``forwards`` forwards of ``tokens`` live tokens each, ``kv_only_forwards``
-        of which stop before the last layer's experts. ``moe_slots_routed``:
+    def _moe_span_args(self, shapes, stats) -> dict:
+        """What a step span says of the expert layers over a call whose
+        forwards are ``shapes``: ``(tokens, t_bucket, forwards,
+        kv_only_forwards)``, ``forwards`` forwards of ``tokens`` live tokens
+        each in a program of ``t_bucket``, ``kv_only_forwards`` of which stop
+        before the last layer's experts (what the host reckons is reckoned a
+        forward shape and summed). ``moe_slots_routed``:
         live tokens x top-k x EXPERT layers (a leading dense layer routes
         nothing); ``moe_slots``: those of them that landed on experts held
         here and took a row (all, for a model that holds every expert; a
@@ -657,12 +660,13 @@ class InferenceEngineV2:
         if self._sparse:
             return {"attn_blocks_read": int(stats[0]), "attn_items_live": int(stats[1]),
                     "attn_grid_steps": int(stats[2])}
-        layer_forwards = mc.num_expert_layers * forwards - kv_only_forwards
+        layer_forwards = [(tokens, t_bucket, mc.num_expert_layers * forwards - kv_only)
+                          for tokens, t_bucket, forwards, kv_only in shapes]
         return {"moe_slots": int(stats[2]),
-                "moe_slots_routed": tokens * mc.moe_top_k * layer_forwards,
-                "moe_rows": self._moe.padded_rows(t_bucket) * layer_forwards,
+                "moe_slots_routed": sum(tokens * mc.moe_top_k * n for tokens, _, n in layer_forwards),
+                "moe_rows": sum(self._moe.padded_rows(t_bucket) * n for _, t_bucket, n in layer_forwards),
                 "experts_hit": int(stats[0]),
-                "experts_total": mc.experts_held * layer_forwards,
+                "experts_total": mc.experts_held * sum(n for _, _, n in layer_forwards),
                 "expert_load_max": int(stats[1]),
                 "experts_held": mc.experts_held, "experts_published": mc.moe_num_experts}
 
@@ -777,28 +781,40 @@ class InferenceEngineV2:
                                        self._max_blocks_per_seq, T)
         return {"kv_steps": steps, "kv_live": live}
 
-    def _tiled_kv_span_args(self, T: int, S: int, rb, forwards=((0, 1, 0), )) -> dict:
-        """What a step span says of ``paged_attn_q_tiled``'s grid, for a shape
-        that kernel took (nothing otherwise): ``tile_kv_live``, the live
-        (tile, KV block) pairs, ``tile_kv_steps``, the grid steps it ran for
-        them (the choice's ``blocks_per_step`` pairs of a tile a step), and
-        ``tile_kv_bound``, the tiles x table columns the shapes allow, each
-        summed over layers and forwards (``paged_attention.tiled_kv_counts``
-        on the batch's own arrays, as masked by: a block-diffusion model's
-        ``pos | (B - 1)``). ``forwards``: ``(offset, n, kv_only)``, ``n``
-        forwards at the batch's positions plus ``offset``, ``kv_only`` of
-        which stop before the last layer's attention."""
-        choice = kernel_choice(T, S, self._max_blocks_per_seq)
-        if choice is None or choice["kernel"] != "paged_attn_q_tiled" or self._sparse:
+    def _tiled_kv_span_args(self, T: int, S: int, rb, forwards=((0, 1, 0, False), )) -> dict:
+        """What a step span says of ``paged_attn_q_tiled``'s grid, for the
+        forwards of a shape that kernel took (nothing if it took none):
+        ``tile_kv_live``, the live (tile, KV block) pairs, ``tile_kv_steps``,
+        the grid steps it ran for them (the choice's ``blocks_per_step`` pairs
+        of a tile a step), and ``tile_kv_bound``, the tiles x table columns the
+        shapes allow, each summed over layers and forwards
+        (``paged_attention.tiled_kv_counts`` on the batch's own arrays, as
+        masked by: a block-diffusion model's ``pos | (B - 1)``). ``forwards``:
+        ``(offset, n, kv_only, fused)``, ``n`` forwards at the batch's
+        positions plus ``offset``, ``kv_only`` of which stop before the last
+        layer's attention; ``fused``: forwards of ``2 T`` tokens, a row's block
+        before (a diffusion block's commit) then the row's tokens, as
+        ``diffusion.build_block_program`` lays them."""
+        from .diffusion import rows_beside
+
+        if self._sparse:
             return {}
         last = self.model_config.layer_window(self.model_config.num_layers - 1)
-        bs = self.config.kv_block_size
-        rows, cols = self._expanded_plan(T)
-        counts = np.zeros(3, np.int64)
-        for offset, n, kv_only in forwards:
+        bs, B = self.config.kv_block_size, self._block
+        counts, tiled = np.zeros(3, np.int64), False
+        for offset, n, kv_only, fused in forwards:
+            T_f = 2 * T if fused else T
+            choice = kernel_choice(T_f, S, self._max_blocks_per_seq)
+            if choice is None or choice["kernel"] != "paged_attn_q_tiled":
+                continue
+            tiled = True
             # attention calls a window: its layers in every forward, less the last layer's in a commit
             calls = [(w, layers * n - (kv_only if w == last else 0)) for w, layers in self._kv_windows]
-            pos = (rb.token_pos + offset) | max(self._block - 1, 0)
+            seq_idx, pos = rb.token_seq_idx, rb.token_pos + offset
+            rows, cols = self._expanded_plan(T)
+            if fused:  # (never beside latent attention: the configuration refuses block diffusion there)
+                seq_idx, pos = rows_beside(seq_idx, seq_idx, B, np), rows_beside(np.maximum(pos - B, 0), pos, B, np)
+            pos = pos | max(B - 1, 0)
             if rows:
                 # latent attention's two calls: the absorbed one without the long rows' tiles, and the
                 # expanded one over the workspace's own table
@@ -809,8 +825,10 @@ class InferenceEngineV2:
                 counts += tiled_kv_counts(x_choice["q_tile"], x_seq, x_pos, calls, bs, cols, 2 * rows + 1,
                                           x_choice["blocks_per_step"])
                 pos = np.where(slot_of_tok >= 0, -1, pos)
-            counts += tiled_kv_counts(choice["q_tile"], rb.token_seq_idx, pos, calls, bs, self._max_blocks_per_seq, S,
+            counts += tiled_kv_counts(choice["q_tile"], seq_idx, pos, calls, bs, self._max_blocks_per_seq, S,
                                       choice["blocks_per_step"])
+        if not tiled:
+            return {}
         return {"tile_kv_bound": int(counts[0]), "tile_kv_live": int(counts[1]), "tile_kv_steps": int(counts[2])}
 
     def _kernel_of(self, T: int, S: int, horizon: bool = False) -> str:
@@ -1032,7 +1050,7 @@ class InferenceEngineV2:
                     for seq in seqs:
                         seq.post_forward()
                         self.state_manager.publish_sequence(seq)
-            _observe(sp, lambda: self._moe_span_args(S, s_bucket, int(n_steps), seen[0]) if seen else {}, held)
+            _observe(sp, lambda: self._moe_span_args([(S, s_bucket, int(n_steps), 0)], seen[0]) if seen else {}, held)
         if rf.enabled and block:
             rf.note_wall(rf_bucket, time.perf_counter() - t_rf)
         if reg.enabled and block:
@@ -1045,10 +1063,15 @@ class InferenceEngineV2:
     def _decode_blocks(self, batch_uids, first_tokens, n_steps, block, eos_token_ids=None, sampling=None,
                        max_new_tokens=None, probe=()):
         """``decode`` of a block-diffusion model: ``n_steps // B`` blocks a row
-        in one compiled program (``diffusion.build_block_program``). Under the
-        span's name and arguments of the causal burst, ``steps`` counting the
-        FORWARDS of the call (denoise and commit), with the counts a block at a
-        time beside them (PERF.md section 3)."""
+        in one compiled program (``diffusion.build_block_program``), in which
+        a block's commit rides in the next block's first denoise forward and
+        the call's last block alone is committed by a forward of its own. Under
+        the span's name and arguments of the causal burst, ``steps`` counting
+        the FORWARDS the call ran (``denoise_forwards`` and
+        ``commit_forwards``, the ``kv_only`` one), ``fused_commits`` the blocks
+        whose commit rode in a denoise forward and ``tokens_fed`` the live
+        tokens fed (a forward that carries a commit feeds two blocks a row),
+        with the counts a block at a time beside them (PERF.md section 3)."""
         from .sampling import all_greedy
 
         tr = get_tracer()
@@ -1110,20 +1133,24 @@ class InferenceEngineV2:
             with tr.span("serving/engine_dispatch", tid="serving") as sd:
                 n_programs = len(self._compiled)
                 fn = self._get_compiled_blocks(s_bucket, n_blocks, probe)
-                (toks, *counts), pools = fn(self.params, jnp.asarray(rb.packed()), kv.pools())
+                # the blocks to advance ride behind the descriptor: the program is built for up to its capacity
+                (toks, *counts), pools = fn(self.params, jnp.asarray(np.append(rb.packed(), np.int32(n_blocks))), kv.pools())
                 kv.update(*pools)
                 if sd is not NULL_SPAN:
                     sd.set_args(compiled=len(self._compiled) > n_programs)
+            # the attention call of the forwards of S x B tokens, then of those that carry a commit (S x 2B)
             held = _observe(sp, lambda: dict(
                 rows=S, tokens=S * n_steps, bucket_rows=int(s_bucket), bucket_tokens=int(s_bucket * B),
-                kernel=self._kernel_of(s_bucket * B, s_bucket), uids=[int(u) for u in uids[:16]], blocked=True,
-                blocks=n_blocks, block_size=B, commit_forwards=n_blocks, open_tokens=sum(opened)))
+                kernel="+".join(filter(None, (self._kernel_of(t * s_bucket * B, s_bucket)
+                                              for t in ((1, 2) if n_blocks > 1 else (1, ))))),
+                uids=[int(u) for u in uids[:16]], blocked=True, blocks=n_blocks, block_size=B, commit_forwards=1,
+                fused_commits=n_blocks - 1, open_tokens=sum(opened)))
             with tr.span("serving/engine_fetch", tid="serving"):
                 # the whole bucket comes to the host (a few KB of int32) and is cut there: an eager
                 # slice on the device would be one more tiny program a (bucket, rows) pair to warm.
                 # Beside it, for a live span: forwards a block, masked slots fed, the experts' counts
                 toks, seen = _fetch(sp, toks, counts[:len(counts) - 2 * bool(probe)])
-            toks = toks[:S]
+            toks = toks[:S, :n_steps]
             if eos_token_ids is None or isinstance(eos_token_ids, (int, np.integer)):
                 eos_token_ids = [eos_token_ids] * S
             assert len(eos_token_ids) == S, "eos_token_ids must match batch_uids"
@@ -1149,17 +1176,25 @@ class InferenceEngineV2:
             def counted():
                 t_done = time.perf_counter()  # the call's time a block, before what observing it takes
                 forwards, masked_fed, *stats = seen
-                n_denoise = int(forwards.sum())
-                n_fwd = n_denoise + n_blocks
+                forwards = forwards[:n_blocks]
+                n_denoise, n_fused = int(forwards.sum()), n_blocks - 1
+                # a block's first forward is of 2 x (S x B) tokens wherever a commit rides in the call (block 0's
+                # feeds the half before as padding), the others and the call's one commit of S x B
+                first = n_blocks if n_fused else 0  # forwards of the 2 x (S x B) shape
+                T, t_bucket = S * B, s_bucket * B
                 moe_args = {}
                 if stats:
-                    moe_args = self._moe_span_args(S * B, s_bucket * B, n_fwd, stats[0], kv_only_forwards=n_blocks)
-                return dict(steps=n_fwd, denoise_forwards=n_denoise, tokens_committed=sum(kept),
-                            tokens_fed=S * B * n_fwd, masked_fed=int(masked_fed),
+                    moe_args = self._moe_span_args(
+                        [(T, 2 * t_bucket, min(first, 1), 0), (2 * T, 2 * t_bucket, n_fused, 0),
+                         (T, t_bucket, n_denoise - first + 1, 1)], stats[0])
+                tiled = [(b * B, 1, 0, True) for b in range(first)] + \
+                    [(b * B, int(n) - bool(first), 0, False) for b, n in enumerate(forwards)] + \
+                    [(n_fused * B, 1, 1, False)]
+                return dict(steps=n_denoise + 1, denoise_forwards=n_denoise, tokens_committed=sum(kept),
+                            tokens_fed=T * (n_denoise + 1 + n_fused), masked_fed=int(masked_fed),
                             tokens_dropped=n_steps * S - held["open_tokens"] - sum(kept),
                             block_ms=round((t_done - t_call) * 1e3 / n_blocks, 3), **moe_args,
-                            **self._tiled_kv_span_args(s_bucket * B, s_bucket, rb,
-                                                       [(b * B, int(n) + 1, 1) for b, n in enumerate(forwards)]))
+                            **self._tiled_kv_span_args(t_bucket, s_bucket, rb, tiled))
 
             _observe(sp, counted, held)
         if reg.enabled:
@@ -1168,24 +1203,43 @@ class InferenceEngineV2:
             reg.gauge("serving/decode_tokens_per_sec").set(sum(kept) / max(dt, 1e-9))
         if probe:
             # forwards [blocks]; ids [blocks, steps, rows, B] and logits [..., V], the program's last two results
-            forwards, ids, logits = (np.asarray(a) for a in (counts[0], *counts[-2:]))
+            forwards, ids, logits = (np.asarray(a)[:n_blocks] for a in (counts[0], *counts[-2:]))
             return toks, {"rows": list(probe), "ids": ids, "forwards": forwards, "logits": logits}
         return toks
 
+    def _block_program_cap(self, n_blocks: int, probe_rows: tuple = ()) -> int:
+        """The blocks the program that serves a call of ``n_blocks`` is built
+        for. The blocks a call advances are an argument of the program, so a
+        row bucket needs TWO: one of one block, whose forwards are all of ``S
+        x B`` tokens, and one for every longer call, whose blocks' first
+        forwards carry the commit of the block before; that one is built for
+        the scheduler's burst (``DECODE_HORIZON`` tokens) or the call itself
+        if it asks for more. A program of three forwards of the whole model
+        is 4 s of a warm start and 30 s of a cold one: one a (row bucket,
+        blocks) pair was 16 of them in a replica's set-up where this is 8. A
+        probe's results are sized by the blocks, so its program is built for
+        the call's own."""
+        from .scheduler import DynamicSplitFuseScheduler
+
+        if probe_rows or n_blocks == 1:
+            return n_blocks
+        return max(n_blocks, DynamicSplitFuseScheduler.DECODE_HORIZON // self._block)
+
     def _get_compiled_blocks(self, s_bucket: int, n_blocks: int, probe_rows: tuple = ()):
-        key = ("diffuse", s_bucket, n_blocks, probe_rows)
+        cap = self._block_program_cap(n_blocks, probe_rows)
+        key = ("diffuse", s_bucket, cap, probe_rows)
         if key not in self._compiled:
             from .diffusion import build_block_program
 
-            self._note_compile(f"diffuse/s{s_bucket}/b{n_blocks}{'/probe' if probe_rows else ''}")
+            self._note_compile(f"diffuse/s{s_bucket}/b{cap}{'/probe' if probe_rows else ''}")
             mc, dc = self.model_config, self.config.diffusion
             fwd = build_block_program(
                 self._ragged_step, block_size=self._block, mask_id=mc.mask_token_id,
                 denoising_steps=dc.denoising_steps, remasking=dc.remasking, threshold=dc.confidence_threshold,
-                s_bucket=s_bucket, n_blocks=n_blocks, moe=self._moe is not None, vocab=mc.vocab_size,
+                s_bucket=s_bucket, cap=cap, moe=self._moe is not None, vocab=mc.vocab_size,
                 probe_rows=probe_rows)
             self._compiled[key] = jax.jit(fwd, donate_argnums=(2, ), **self._jit_options)
-            log_dist(f"compiled block-diffusion decode bucket seqs={s_bucket} blocks={n_blocks} "
+            log_dist(f"compiled block-diffusion decode bucket seqs={s_bucket} blocks<={cap} "
                      f"probe_rows={probe_rows}", ranks=[0])
         return self._compiled[key]
 
@@ -1218,8 +1272,8 @@ class InferenceEngineV2:
         ``moe_stats`` (a model with experts): a third result, int32
         ``[experts_hit, expert_load_max, slots]`` of this forward.
 
-        ``kv_only``: the commit of a diffusion block (``ragged_forward``): the
-        K/V of every layer and nothing else, logits None.
+        ``kv_only``: the commit of the last diffusion block of a call
+        (``ragged_forward``): the K/V of every layer and nothing else, logits None.
 
         A model with state layers: the pool tuple is ``(k, v, state, tails)``
         and the rows' state slots ride behind the descriptors;
@@ -1817,15 +1871,18 @@ class InferenceEngineV2:
             for n_steps in decode_steps:
                 n_steps = int(n_steps)
                 B = self._block or 1  # a block-diffusion model: n_steps // B blocks of B tokens a row
-                key = ("diffuse", s_bucket, n_steps // B, ()) if self._block else ("decode", s_bucket, n_steps, False)
+                key = ("diffuse", s_bucket, self._block_program_cap(n_steps // B), ()) if self._block \
+                    else ("decode", s_bucket, n_steps, False)
                 if key in self._compiled and key not in self._ahead:  # one compiled ahead that no call has run runs below
                     results.append({"seqs": s_bucket, "steps": n_steps, "seconds": 0.0, "cached": True})
                     continue
                 fn = self._get_compiled_blocks(s_bucket, n_steps // B) if self._block \
                     else self._get_compiled_decode(s_bucket, n_steps)
                 # packed layout [T ids][T idx][T pos][T valid][S*max_blocks][S last]
-                # with T == S on the decode path (S * B for a block-diffusion model)
-                packed = jnp.zeros(packed_len(s_bucket * B, s_bucket, max_blocks, bool(self._state_layers)), jnp.int32)
+                # with T == S on the decode path (S * B for a block-diffusion model, whose
+                # descriptor ends in the blocks to advance: none here)
+                packed = jnp.zeros(packed_len(s_bucket * B, s_bucket, max_blocks, bool(self._state_layers))
+                                   + bool(self._block), jnp.int32)
                 t0 = time.perf_counter()
                 toks, pools = fn(self.params, packed, kv.pools())
                 jax.block_until_ready(toks)

@@ -245,9 +245,11 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
     of the token's block, ``pos | (B - 1)``, here and nowhere else, while rope
     and the KV slot keep ``pos``. ``B`` divides the KV block, so the block
     columns a row walks are those of ``pos``. ``kv_only``: the forward that
-    commits a block: K/V of every layer are written and nothing else is
-    wanted, so the last layer stops at its scatter, there is no head, and the
-    logits returned are None.
+    commits the LAST block of a ``decode`` call (a block before it is
+    committed inside the next block's first denoise forward, an ordinary
+    ragged step of two blocks a row: ``diffusion.build_block_program``): K/V
+    of every layer are written and nothing else is wanted, so the last layer
+    stops at its scatter, there is no head, and the logits returned are None.
 
     A model with LINEAR-attention layers (``cfg.state_layers``, Kimi Delta
     Attention: ``models/solar.py`` has the equations) takes ``state_pools``,

@@ -447,12 +447,17 @@ STEP_SPAN_ARGS = {
         "kernel": "paged_attention_reference:1:off_tpu", "kv_live": 69, "kv_steps": 640, "moe_rows": 1280,
         "moe_slots": 80, "moe_slots_routed": 80, "rows": 2, "steps": 5, "tokens": 10, "uids": [50, 51],
         "attn_pairs": 3 * (80 + 74) + 215 + 75, "attn_ctx_tokens": 3 * (20 + 17) + 45 + 17, "kv_entry_bytes": 512},
+    # PR 50: the first block's commit rides in the second's first forward (``fused_commits``), so the call runs ONE
+    # commit forward and 9 forwards in all, two of them of 2 x 32 tokens (24 live in the one that carries the commit, 12
+    # in block 0's, whose half before is padding: 696 = 2 slots x 3 layers x (12 + 24) + 2 x 12 x (3 x 7 - 1)); the tiled
+    # choice is planted for the 32-token shape alone, so the grid's counts are those of its 3 x 7 - 1 attention calls
     "experts_decode_blocks": {
-        "block_size": 4, "blocked": True, "blocks": 2, "bucket_rows": 8, "bucket_tokens": 32, "commit_forwards": 2,
-        "denoise_forwards": 8, "expert_load_max": 9, "experts_held": 8, "experts_hit": 193, "experts_published": 8,
-        "experts_total": 224, "kernel": "paged_attn_q_tiled:8:planted", "masked_fed": 51, "moe_rows": 4928,
-        "moe_slots": 672, "moe_slots_routed": 672, "open_tokens": 3, "rows": 3, "steps": 10, "tile_kv_bound": 4368,
-        "tile_kv_live": 266, "tile_kv_steps": 168, "tokens": 24, "tokens_committed": 21, "tokens_dropped": 0, "tokens_fed": 120,
+        "block_size": 4, "blocked": True, "blocks": 2, "bucket_rows": 8, "bucket_tokens": 32, "commit_forwards": 1,
+        "denoise_forwards": 8, "expert_load_max": 15, "experts_held": 8, "experts_hit": 184, "experts_published": 8,
+        "experts_total": 208, "fused_commits": 1,
+        "kernel": "paged_attn_q_tiled:8:planted+paged_attention_reference:1:off_tpu", "masked_fed": 51, "moe_rows": 5632,
+        "moe_slots": 696, "moe_slots_routed": 696, "open_tokens": 3, "rows": 3, "steps": 9, "tile_kv_bound": 3120,
+        "tile_kv_live": 191, "tile_kv_steps": 120, "tokens": 24, "tokens_committed": 21, "tokens_dropped": 0, "tokens_fed": 120,
         "uids": [50, 51, 52]},
 }
 

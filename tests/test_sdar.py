@@ -223,15 +223,21 @@ def test_the_engine_generates_what_the_reference_generates(weights, strategy, st
         assert len(forwards) < 2 + 2 * 4, "over so low a threshold some forward unmasks several positions"
 
 
+def _beside(a, b):
+    """A row's block before, then its block: the rows of two ``[S x B]`` token arrays side by side."""
+    return np.concatenate([np.asarray(a).reshape(-1, B), np.asarray(b).reshape(-1, B)], axis=1).reshape(-1)
+
+
 def test_a_block_calls_span_counts_the_tiled_grid_forward_by_forward(weights, monkeypatch):
-    """``serving/decode`` of a shape ``paged_attn_q_tiled`` took (planted: off
+    """``serving/decode`` of shapes ``paged_attn_q_tiled`` took (planted: off
     the TPU none does) carries ``tile_kv_live``, the live (tile, column) pairs,
     ``tile_kv_steps``, the grid steps at the choice's four 16-token blocks a
-    step, and ``tile_kv_bound``, each summed
-    over the call's forwards and layers: a block's denoise forwards run every
-    layer's attention and its commit all but the last layer's, each at the
-    block's own positions under the bound ``pos | 3``, the pad run's too; not
-    ``kv_live``/``kv_steps``."""
+    step, and ``tile_kv_bound``, each summed over the call's forwards and
+    layers, a forward SHAPE at a time: a block's first forward is of 2B tokens
+    a row (the block before, whose commit it is, then the block, under the
+    bound ``pos | 3``, the pad run's too) at that shape's tile, its others of
+    B tokens a row, and the call's one commit runs all but the last layer's
+    attention; not ``kv_live``/``kv_steps``."""
     from deepspeed_tpu.monitor.trace import get_tracer
     from deepspeed_tpu.ops.pallas import paged_attention as pa
 
@@ -241,8 +247,9 @@ def test_a_block_calls_span_counts_the_tiled_grid_forward_by_forward(weights, mo
     known = [_prefill(engine, uid, p, (p.size // B * B, )) for uid, p in zip(uids, prompts)]
     engine.decode(uids, known, 8)                                    # trace the program first
     layers, max_blocks, S = model.config.num_layers, 192 // 16, 8
-    monkeypatch.setitem(pa.KERNEL_CHOICES, (S * B, S, max_blocks),
-                        {"kernel": "paged_attn_q_tiled", "q_tile": 8, "blocks_per_step": 4, "rule": "planted"})
+    for tokens, tile in ((S * B, 8), (S * 2 * B, 16)):
+        monkeypatch.setitem(pa.KERNEL_CHOICES, (tokens, S, max_blocks),
+                            {"kernel": "paged_attn_q_tiled", "q_tile": tile, "blocks_per_step": 4, "rule": "planted"})
     batches, finalize = [], engine._block_batch.finalize
     monkeypatch.setattr(engine._block_batch, "finalize", lambda: batches.append(finalize()) or batches[-1])
     get_tracer().reset()
@@ -252,20 +259,152 @@ def test_a_block_calls_span_counts_the_tiled_grid_forward_by_forward(weights, mo
         (span, ) = [e["args"] for e in tracer.drain() if e["ph"] == "X" and e["name"] == "serving/decode"]
     finally:
         get_tracer().reset()
-    assert span["kernel"] == "paged_attn_q_tiled:8:planted" and span["blocks"] == 2
-    assert (span["denoise_forwards"], span["commit_forwards"]) == (8, 2)
+    assert span["kernel"] == "paged_attn_q_tiled:8:planted+paged_attn_q_tiled:16:planted" and span["blocks"] == 2
+    assert (span["denoise_forwards"], span["commit_forwards"], span["fused_commits"], span["steps"]) == (8, 1, 1, 9)
+    assert span["tokens_fed"] == 3 * B * (9 + 1)
     (rb, ) = batches                                                 # the call's descriptor: its first block
-    tables, seq_idx = jnp.asarray(rb.block_tables), jnp.asarray(rb.token_seq_idx)
+    tables = jnp.asarray(rb.block_tables)
+
+    def grid(seq_idx, pos, tile):  # (live pairs, grid steps) of one attention call
+        return [int(pa._tiled_work_list(tables, jnp.asarray(seq_idx), jnp.asarray(pos | (B - 1)), 16, None, tile,
+                                        per_step=per)[8]) for per in (1, 4)]
+
     live = steps = 0
     for b in range(2):
-        pairs, items = (int(pa._tiled_work_list(tables, seq_idx, jnp.asarray((rb.token_pos + b * B) | (B - 1)), 16, None, 8,
-                                                per_step=per)[8]) for per in (1, 4))
-        live += (4 * layers + (layers - 1)) * pairs
-        steps += (4 * layers + (layers - 1)) * items
-    n_tiles = S * B // 8 + S + 1
+        pos = rb.token_pos + b * B
+        first = grid(_beside(rb.token_seq_idx, rb.token_seq_idx), _beside(np.maximum(pos - B, 0), pos), 16)
+        others = grid(rb.token_seq_idx, pos, 8)
+        calls = 3 * layers + (layers - 1 if b == 1 else 0)           # three more denoise forwards, the last commit
+        live += layers * first[0] + calls * others[0]
+        steps += layers * first[1] + calls * others[1]
     assert span["tile_kv_live"] == live > 0 and span["tile_kv_steps"] == steps and live / 4 <= steps < live
-    assert span["tile_kv_bound"] == 2 * (4 * layers + layers - 1) * n_tiles * max_blocks
+    assert span["tile_kv_bound"] == max_blocks * (2 * layers * (S * 2 * B // 16 + S + 1)
+                                                  + (7 * layers - 1) * (S * B // 8 + S + 1))
     assert not {"kv_live", "kv_steps"} & set(span)
+
+
+@pytest.fixture(scope="module")
+def written_out(weights):
+    """An engine a rule, with the two forwards a block took before a commit
+    rode anywhere, jitted from the engine's own ``_ragged_step``: a denoise
+    forward and a ``kv_only`` commit of ``S x B`` tokens."""
+    model, params = weights
+    made = {}
+
+    def of(rule):
+        if rule not in made:
+            engine = _engine(model, params, remasking=rule, confidence_threshold=0.02)
+            step = engine._ragged_step
+            made[rule] = (engine,
+                          jax.jit(lambda packed, pools: step(params, packed, pools, 8 * B, 8, gather_k=B - 1, moe_stats=True)),
+                          jax.jit(lambda packed, pools: step(params, packed, pools, 8 * B, 8, moe_stats=True, kv_only=True)))
+        return made[rule]
+
+    return of
+
+
+@pytest.mark.parametrize("probed", [False, True], ids=["timed", "probed"])
+@pytest.mark.parametrize("rule", ["low_confidence_static", "low_confidence_dynamic"])
+@pytest.mark.parametrize("n_blocks", [1, 2, 4])
+def test_a_call_whose_commits_ride_equals_the_sequence_written_out(written_out, monkeypatch, n_blocks, rule, probed):
+    """A call of ``n_blocks`` blocks against the same call written out forward
+    by forward in the order it had before a commit rode anywhere: a block's
+    denoise forwards while a mask is left, then a ``kv_only`` commit of its
+    final ids, each of ``S x B`` tokens through the engine's own
+    ``_ragged_step``, from the same pools and the same descriptor. Tokens,
+    forwards a block, every forward's ids and logits (of the probed row) and
+    the K/V pools after the call are the written-out sequence's; the call ran
+    ONE commit forward, ``n_blocks - 1`` rode. Rows: a prompt of whole blocks,
+    one shorter than a block (nothing committed: its block before would lie
+    before position 0) and one that opens its first block."""
+    from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import RaggedBatchWrapper
+    from deepspeed_tpu.inference.v2.sampling import diffusion_candidates, diffusion_quota, diffusion_unmask
+    from deepspeed_tpu.monitor.trace import get_tracer
+
+    engine, denoise, commit = written_out(rule)
+    uids, prompts = [3, 4, 5], [_prompt(40, 1), _prompt(3, 2), _prompt(23, 3)]
+    engine.state_manager.get_or_create_sequence(4)                   # admitted with nothing to prefill
+    known = [_prefill(engine, uid, p, (p.size // B * B, ) if p.size >= B else ()) for uid, p in zip(uids, prompts)]
+    kv, S, T = engine.state_manager.kv_cache, 8, 8 * B
+    before = [np.asarray(p) for p in kv.pools()]
+    batches, finalize = [], RaggedBatchWrapper.finalize
+    monkeypatch.setattr(RaggedBatchWrapper, "finalize", lambda self: batches.append(finalize(self)) or batches[-1])
+    get_tracer().reset()
+    tracer = get_tracer().configure(enabled=True)
+    try:
+        out = engine.decode(uids, known, n_blocks * B, probe=(2, ) if probed else ())
+        (span, ) = [e["args"] for e in tracer.drain() if e["ph"] == "X" and e["name"] == "serving/decode"]
+    finally:
+        get_tracer().reset()
+    toks, probe = out if probed else (out, None)
+    after = [np.asarray(p) for p in kv.pools()]
+    packed = batches[0].packed()                                     # the call's descriptor: its first block
+    for uid in uids:
+        engine.flush(uid)
+
+    pools, valid = tuple(jnp.asarray(p) for p in before), packed[3 * T:4 * T] > 0
+    want, forwards, fed = [], [], []
+    for b in range(n_blocks):
+        at = packed.copy()
+        at[2 * T:3 * T] += b * B
+        ids = packed[0:T].copy() if b == 0 else np.full(T, 511, np.int32)
+        i = 0
+        while i < 4 and (valid & (ids == 511)).any():
+            at[0:T] = ids
+            logits, pools, _ = denoise(jnp.asarray(at), pools)
+            fed.append((b, i, ids.reshape(S, B)[2].copy(), np.asarray(logits).reshape(S, B, -1)[2]))
+            tok, conf = diffusion_candidates(logits)
+            masked = valid & (ids == 511)
+            choose = np.asarray(diffusion_unmask(conf.reshape(S, B), jnp.asarray(masked.reshape(S, B)), rule,
+                                                 diffusion_quota(B, 4)[i], 0.02, i == 3)).reshape(T)
+            ids, i = np.where(choose, np.asarray(tok), ids), i + 1
+        at[0:T] = ids
+        _, pools, _ = commit(jnp.asarray(at), pools)
+        want.append(ids.reshape(S, B)[:3])
+        forwards.append(i)
+    assert np.asarray(toks).tolist() == np.concatenate(want, axis=1).tolist()
+    assert not (np.asarray(toks) == 511).any() and toks[1, :3].tolist() == prompts[1].tolist()
+    assert (span["denoise_forwards"], span["commit_forwards"], span["fused_commits"]) == (sum(forwards), 1, n_blocks - 1)
+    assert span["steps"] == span["denoise_forwards"] + 1 and span["tokens_fed"] == 3 * B * (span["steps"] + n_blocks - 1)
+    if rule == "low_confidence_static":
+        assert span["steps"] == 4 * n_blocks + 1
+    else:
+        assert span["steps"] < 4 * n_blocks + 1, "over so low a threshold some forward unmasks several positions"
+    for got, kept in zip(after, pools):
+        np.testing.assert_allclose(got, np.asarray(kept), rtol=1e-5, atol=1e-5)
+    assert any(np.abs(a - b).max() > 1e-3 for a, b in zip(after, before)), "the call wrote the cache"
+    if probed:
+        assert probe["forwards"].tolist() == forwards
+        for b, i, ids, logits in fed:
+            assert probe["ids"][b, i, 0].tolist() == ids.tolist(), (b, i)
+            np.testing.assert_allclose(probe["logits"][b, i, 0], logits, rtol=1e-4, atol=1e-4)
+
+
+def test_a_row_buckets_calls_of_two_blocks_and_more_are_one_program(weights):
+    """The blocks a call advances are an argument of the program: a row bucket
+    has one program of one block (its forwards all of ``S x B`` tokens: two
+    traces of the step) and one for every longer call up to the scheduler's
+    burst (three traces: the forward that carries a commit, the others, the
+    ``kv_only`` commit), so warming calls of 1, 2, 4 and 8 blocks builds two;
+    a call past the burst builds one of its own. Whatever program serves it, a
+    call generates what the reference generates."""
+    model, params = weights
+    engine = _engine(model, params, kv_blocks=64)
+    step, traced = engine._ragged_step, []
+    engine._ragged_step = lambda *a, **kw: traced.append((a[3], kw.get("kv_only", False))) or step(*a, **kw)
+    warmed = engine.warmup([8], [B, 2 * B, 4 * B, 8 * B])
+    assert [(w["steps"], w["cached"]) for w in warmed] == [(B, False), (2 * B, False), (4 * B, True), (8 * B, True)]
+    assert traced == [(8 * B, False), (8 * B, True), (16 * B, False), (8 * B, False), (8 * B, True)]
+    assert sorted(k[2] for k in engine._compiled if k[0] == "diffuse") == [1, 8]
+    prompt = _prompt(22, seed=3)
+    engine.put([7], [prompt[:20]], sample="greedy")
+    n_traced = len(traced)
+    out = np.concatenate([engine.decode([7], [prompt[20:]] if c == 0 else None, n) for c, n in enumerate((4, 8, 16, 32, 64))],
+                         axis=1)
+    engine.flush(7)
+    assert len(traced) == n_traced + 3 and sorted(k[2] for k in engine._compiled if k[0] == "diffuse") == [1, 8, 16]
+    want, _ = ref.generate(_hp(), params, prompt, 31, "low_confidence_static")
+    assert out[0].tolist() == want.tolist()
 
 
 def _scheduler(engine):

@@ -1209,3 +1209,21 @@ def test_the_lightning_kernels_at_the_cells_shapes_on_chip(form):
             assert float(jnp.linalg.norm(o[start:start + n] - want_o) / jnp.linalg.norm(want_o)) < 1e-3
             assert float(jnp.linalg.norm(new_pool[slot[r]] - want_S) / jnp.linalg.norm(want_S)) < 1e-3
             start += int(n)
+
+
+def test_a_block_diffusion_call_whose_commits_ride_at_the_cells_shapes_on_chip():
+    """``chip_smoke.diffusion_phase`` as a test (`-s` to read its times): at
+    ``sdar-30b-a3b-chat.block-diffusion-64``'s shapes (64 rows, blocks of 4, 8
+    layers of 128 experts, contexts of 1,024) a ``decode`` call of four blocks,
+    three of whose commits ride in the next block's first denoise forward,
+    gives the tokens of the same call written out as four denoise forwards and
+    a ``kv_only`` commit a block; a denoise forward, one that carries a commit
+    and the commit are timed alone (PERF.md section 6, PR 50, has the readings:
+    the call is worth its while as long as the second costs under 1.3 x the
+    first)."""
+    import chip_smoke
+
+    smoke = chip_smoke.Smoke(rehearsal=False)
+    chip_smoke.diffusion_phase(smoke)
+    ms = smoke.record["diffusion_ms"]
+    assert 0 < ms["commit"] < ms["denoise"] < ms["fused"] < 2 * ms["denoise"], ms
