@@ -29,6 +29,7 @@ from ...monitor.trace import (NULL_SPAN, get_tracer, pop_compile_source,
 from ...moe.grouped import merge_routing_stats
 from ...ops.pallas.kda import KERNEL_NAMES as KDA_KERNEL_NAMES, TILE as KDA_TILE
 from ...ops.pallas.lightning import KERNEL_NAMES as LIGHTNING_KERNEL_NAMES, TILE as LIGHTNING_TILE
+from ...ops.pallas.mamba2 import KERNEL_NAMES as MAMBA_KERNEL_NAMES, TILE as MAMBA_TILE, TILE_BLOCK as MAMBA_TILE_BLOCK
 from ...ops.pallas.paged_attention import decode_kv_counts, kernel_choice, tiled_kv_counts
 from ...utils.logging import log_dist
 from .config_v2 import RaggedInferenceEngineConfig
@@ -182,6 +183,7 @@ class InferenceEngineV2:
         self._index_entry = tuple(getattr(mc, "index_entry", ()))
         self._sparse = bool(self._index_entry)
         self._lightning = int(getattr(mc, "lightning_num_heads", 0) or 0) > 0
+        self._mamba = int(getattr(mc, "mamba_num_heads", 0) or 0) > 0
         if self._sparse:
             if getattr(ic.speculative, "enabled", False):
                 raise NotImplementedError(
@@ -391,6 +393,15 @@ class InferenceEngineV2:
             return sm.max_tracked_sequences * state_layers * h * dk * dv * 4 + tokens * h * (3 * dk + dv) * 4
         (h, dk, dv), (taps, channels) = mc.state_entry
         slot = state_layers * (h * dk * dv * 4 + taps * channels * dt_bytes)
+        if self._mamba:  # state-space layers: (heads, head width, state width)
+            # the slots; of the largest ``put`` the convolution's float32 output, what the tiles are laid from (dt x,
+            # dt A, B and C a token) and ``y`` back in the flat order, a block of tiles beside them
+            # (``mamba2_chunks``); of the rows fed one token the step's columns of ``dt x`` and of ``y`` (128
+            # lanes a group) and its rows of B, C and the decays
+            groups = mc.mamba_n_groups
+            tokens = 4 * sm.max_ragged_batch_size + 4 * MAMBA_TILE_BLOCK * MAMBA_TILE
+            rows = sm.max_ragged_sequence_count * groups * (2 * dk * 128 + 16 * dv) * 4
+            return sm.max_tracked_sequences * slot + tokens * (channels + h * dk) * 4 + rows
         tile_tokens = sm.max_ragged_batch_size + KDA_TILE * sm.max_ragged_sequence_count
         tokens = tile_tokens + 8 * sm.max_ragged_batch_size + 32 * sm.max_ragged_sequence_count
         return sm.max_tracked_sequences * slot + tokens * h * max(dk, dv) * 4
@@ -748,15 +759,22 @@ class InferenceEngineV2:
         row; every row x step of a horizon), the rest through the chunk scan; ``state_bytes``, the rows x state layers x
         ``state_entry_bytes`` x 2, the least a correct form moves;
         ``lin_tokens``, the tokens through linear layers, x layers;
-        ``state_slots_live`` of ``state_slots_total`` slots taken."""
+        ``state_slots_live`` of ``state_slots_total`` slots taken. A model with
+        state-space layers says besides what its roofline reads:
+        ``mamba_row_calls``, the (row, layer, call) triples whose state was
+        read and written (a horizon's step is a call), and ``mamba_tokens``,
+        the (token, layer) pairs through the scan."""
         if not self._state_layers:
             return {}
         kv = self.state_manager.kv_cache
         entry, n = kv.state_entry_bytes(), len(self._state_layers)
-        return {"state_rows": rows, "state_rows_stepped": stepped, "state_bytes": rows * n * entry * 2,
-                "state_entry_bytes": entry,
-                "lin_tokens": tokens * n, "state_slots_live": kv.state_slots - kv.free_state_slots,
-                "state_slots_total": kv.state_slots}
+        out = {"state_rows": rows, "state_rows_stepped": stepped, "state_bytes": rows * n * entry * 2,
+               "state_entry_bytes": entry,
+               "lin_tokens": tokens * n, "state_slots_live": kv.state_slots - kv.free_state_slots,
+               "state_slots_total": kv.state_slots}
+        if self._mamba:
+            out.update(mamba_row_calls=rows * n, mamba_tokens=tokens * n)
+        return out
 
     def _expanded_plan(self, t_bucket: int):
         """``flat_model.expanded_plan`` of this engine's ``put`` program of
@@ -857,9 +875,10 @@ class InferenceEngineV2:
             if self._lightning:  # one form a program: the chunk scan of a put, the recurrent step of a horizon
                 parts.append("%s:1:one-token-rows" % LIGHTNING_KERNEL_NAMES[0] if horizon else
                              "%s:%d:ragged" % (LIGHTNING_KERNEL_NAMES[1], LIGHTNING_TILE))
-            elif self._state_layers:  # beside the paged kernel, the delta rule's forms in this program
-                parts += [] if horizon else ["%s:%d:ragged" % (KDA_KERNEL_NAMES[1], KDA_TILE)]
-                parts.append("%s:1:one-token-rows" % KDA_KERNEL_NAMES[0])
+            elif self._state_layers:  # beside the paged kernel, the rule's two forms in this program
+                names, tile = (MAMBA_KERNEL_NAMES, MAMBA_TILE) if self._mamba else (KDA_KERNEL_NAMES, KDA_TILE)
+                parts += [] if horizon else ["%s:%d:ragged" % (names[1], tile)]
+                parts.append("%s:1:one-token-rows" % names[0])
             label = "+".join(parts)
             if None in choices:
                 return label

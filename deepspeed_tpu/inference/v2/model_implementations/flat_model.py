@@ -39,6 +39,7 @@ from ....models.transformer import TransformerConfig, apply_rope, mlp_activation
 from ....moe.grouped import merge_routing_stats
 from ....ops.pallas.kda import kda_chunks, kda_step
 from ....ops.pallas.lightning import lightning_chunks, lightning_step
+from ....ops.pallas.mamba2 import mamba2_chunks, mamba2_step
 from .sparse_index import select_blocks, update_pooled_keys
 
 # tokens a row whose selection and attention output ``ragged_forward(probe=True)`` hands back
@@ -270,7 +271,18 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
     state pool alone (``state_pools`` of one): q and k are normed a head and
     ROPED, there is no convolution, and the recurrence's two forms are
     ``ops/pallas/lightning.py``'s: the chunkwise one for every ``put``, the
-    recurrent step under ``one_token_rows``.
+    recurrent step under ``one_token_rows``. STATE-SPACE layers
+    (``cfg.mamba_num_heads``, Mamba-2's selective scan: ``models/nemotron.py``)
+    are state layers with both pools, the state ``[Ls, slots, H, P, N]`` and the
+    tail of the convolution over x, B and C; the scan's two forms are
+    ``ops/pallas/mamba2.py``'s, the ``D`` skip, the convolution and the gated
+    norm XLA's.
+
+    A model of ``cfg.single_branch_layers`` runs in each layer the ONE branch
+    the layer has under its one norm: the mixer ``cfg.layer_types`` names, or,
+    in an ``"mlp_only"`` layer, the MLP. Such a layer caches nothing, takes no
+    part in the K/V pools or the state pools, and its arrays are stacked over
+    the layers of its kind wherever they lie (``index_of``).
 
     A model with a learned block-sparse SELECTION (``cfg.sparse_topk``) takes
     ``index_pool`` ``[La, NB * block / stride, nkv, d]``, the pooled keys its
@@ -373,7 +385,7 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
         # what the rows are fed, once a program: tokens a row (rows come in order from flat token 0), a
         # token's place in its row's run, and which rows start their sequence here
         S = block_tables.shape[0]
-        n_slots, taps = state_pools[0].shape[1], cfg.kda_conv_size
+        n_slots, taps = state_pools[0].shape[1], (cfg.mamba_conv_size if cfg.mamba_num_heads > 0 else cfg.kda_conv_size)
         ok = valid.astype(jnp.int32)
         if one_token_rows:
             n_tok, in_row, row_start = ok, jnp.zeros(T, jnp.int32), jnp.arange(T, dtype=jnp.int32)
@@ -421,15 +433,13 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
     else:
         absorbed_pos, workspace = vis_pos, None
 
-    def linear_mixer(h1, blk, li, st_flat, cv_flat):
-        """A linear-attention layer's mixer on the normed input ``h1`` ``[T,
-        H]``, ``li`` its place among the state layers. Returns ``(out [T, H],
-        st_flat, cv_flat)``."""
-        nh, dk = cfg.kda_num_heads, cfg.kda_head_dim
+    def tailed_conv(x3, w, slot_li, cv_flat):
+        """The causal depthwise convolution of a state layer over this step's
+        tokens ``x3`` ``[T, channels]`` with filters ``w`` ``[taps, channels]``
+        (float32), each row going on from its stored tail. Returns ``(y [T,
+        channels] float32, cv_flat)``, the tails of the rows that were fed
+        written back."""
         f32 = jnp.float32
-        slot_li = li * n_slots + state_slots
-        x3 = jnp.concatenate([linear(h1, blk[f"kda_w{n}"], None) for n in "qkv"], axis=-1)            # [T, 3 nh dk]
-        w = jnp.concatenate([blk[f"kda_conv_{n}"] for n in "qkv"], axis=-1).astype(f32)               # [taps, 3 nh dk]
         tails = jnp.where(fresh[:, None, None], 0, cv_flat[slot_li])                                  # [S, taps - 1, ..]
         # the causal convolution: tap ``j`` back is the row's own token ``j`` earlier in this step, or,
         # before the run's first token, what the row's tail kept of its earlier steps
@@ -444,7 +454,18 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
         old = jnp.take_along_axis(tails, jnp.clip(n_tok[:, None] + jnp.arange(taps - 1)[None, :], 0, taps - 2)[..., None],
                                   axis=1)
         new_tails = jnp.where((at >= 0)[..., None], own, old)
-        cv_flat = cv_flat.at[jnp.where(fed, slot_li, cv_flat.shape[0])].set(new_tails.astype(cv_flat.dtype), mode="drop")
+        return y, cv_flat.at[jnp.where(fed, slot_li, cv_flat.shape[0])].set(new_tails.astype(cv_flat.dtype), mode="drop")
+
+    def linear_mixer(h1, blk, li, st_flat, cv_flat):
+        """A linear-attention layer's mixer on the normed input ``h1`` ``[T,
+        H]``, ``li`` its place among the state layers. Returns ``(out [T, H],
+        st_flat, cv_flat)``."""
+        nh, dk = cfg.kda_num_heads, cfg.kda_head_dim
+        f32 = jnp.float32
+        slot_li = li * n_slots + state_slots
+        x3 = jnp.concatenate([linear(h1, blk[f"kda_w{n}"], None) for n in "qkv"], axis=-1)            # [T, 3 nh dk]
+        w = jnp.concatenate([blk[f"kda_conv_{n}"] for n in "qkv"], axis=-1).astype(f32)               # [taps, 3 nh dk]
+        y, cv_flat = tailed_conv(x3, w, slot_li, cv_flat)
         q, k, v = (jax.nn.silu(part).reshape(T, nh, dk) for part in jnp.split(y, 3, axis=-1))
         q = q * jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + 1e-6) / math.sqrt(dk)
         k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + 1e-6)
@@ -486,25 +507,44 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
         o = (o * jax.nn.sigmoid(linear(h1, blk["la_wg"], None).astype(f32))).astype(h1.dtype)
         return linear(o, blk["la_wo"], None), st_flat
 
-    def layer(x, blk, l, k_flat, v_flat, ks_flat, vs_flat, stats=None, ws=None, kind=None, kv_alone=False):
-        """``kind``: the layer's attention kind, static (None in a model of
-        one kind, where ``l`` may be traced); ``stats``: the running MoE
-        counts; ``ws``: latent attention's workspace of per-head K and V, or
-        a model with state layers' ``(state, tails)`` pools; ``kv_alone``:
-        write this layer's K/V and stop."""
+    def state_space_mixer(h1, blk, li, st_flat, cv_flat):
+        """A state-space (Mamba-2) layer's mixer on the normed input ``h1``
+        ``[T, H]``, ``li`` its place among the state layers. Returns ``(out [T,
+        H], st_flat, cv_flat)``."""
+        nh, P, N, G = cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.mamba_state_size, cfg.mamba_n_groups
+        f32 = jnp.float32
+        inner = nh * P
+        slot_li = li * n_slots + state_slots
+        z, xbc, dt_in = jnp.split(linear(h1, blk["m2_w_in"], None), (inner, inner + cfg.mamba_conv_channels), axis=-1)
+        y, cv_flat = tailed_conv(xbc, blk["m2_conv_w"].astype(f32), slot_li, cv_flat)
+        xs, B, C = jnp.split(jax.nn.silu(y + blk["m2_conv_b"].astype(f32)), (inner, inner + G * N), axis=-1)
+        xs, B, C = xs.reshape(T, nh, P), B.reshape(T, G, N), C.reshape(T, G, N)
+        dt = jax.nn.softplus(dt_in.astype(f32) + blk["m2_dt_bias"].astype(f32))
+        A = -jnp.exp(blk["m2_A_log"].astype(f32))
+        if one_token_rows:
+            y, st_flat = mamba2_step(xs, B, C, dt, A, st_flat, slot_li, fresh, n_live, use_pallas=kda_pallas,
+                                     interpret=kda_interpret)
+        else:
+            y, st_flat = mamba2_chunks(xs, B, C, dt, A, st_flat, slot_li, fresh, n_tok, use_pallas=kda_pallas,
+                                       interpret=kda_interpret)
+        y = (y + blk["m2_D"].astype(f32)[None, :, None] * xs).reshape(T, inner) * jax.nn.silu(z.astype(f32))
+        # the gated norm: RMS within each group's channels, one gain vector over all
+        y = y.reshape(T, G, inner // G)
+        y = (y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + cfg.norm_eps)).reshape(T, inner)
+        y = (y * blk["m2_norm_scale"].astype(f32)).astype(h1.dtype)
+        return linear(y, blk["m2_w_out"], None), st_flat, cv_flat
+
+    def softmax_mixer(h1, blk, l, kind, k_flat, v_flat, ks_flat, vs_flat, stats, ws, kv_alone):
+        """Softmax attention over the paged pool (per-head K and V, or the
+        latent entry): what every layer kind that is no state layer's runs.
+        Returns ``(out [T, H], k_flat, v_flat, ks_flat, vs_flat, stats, ws)``,
+        ``out`` None where ``kv_alone`` stopped the layer at its scatter."""
         attend = modules["attention_full"] if kind == "full_attention" else attention
-        h1 = pre_norm(x, blk["ln1_scale"], blk.get("ln1_bias"))
         bias = (lambda n: blk[n]) if cfg.use_bias else (lambda n: None)
-        lk = kv_index.get(l, 0) if state_index else l  # (a traced ``l``: every layer caches K and V)
+        lk = l if cfg.layer_types is None else kv_index[l]  # (a traced ``l``: every layer caches K and V)
         slot_l = jnp.where(valid, lk * pool_len + slot, flat_len)  # this layer's slots in the flat pool
         tables_l = block_tables + lk * NB  # layer l's blocks in the flat pool
-        if kind == "linear_attention":
-            attn_out, *ws = linear_mixer(h1, blk, state_index[l], *ws)
-            ws = tuple(ws)
-        elif kind == "lightning_attention":
-            attn_out, st = lightning_mixer(h1, blk, state_index[l], ws[0], ropes[kind])
-            ws = (st, ) + tuple(ws[1:])
-        elif latent:
+        if latent:
             c, nope, dv = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.v_head_dim
             W = k_flat.shape[-1]
             sin, cos = latent_rope
@@ -517,7 +557,7 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
             entry = jnp.pad(entry, ((0, 0), (0, 0), (0, W - entry.shape[-1])))
             k_flat = k_flat.at[slot_l].set(entry.astype(k_flat.dtype), mode="drop")
             if kv_alone:
-                return x, k_flat, v_flat, ks_flat, vs_flat, stats, ws
+                return None, k_flat, v_flat, ks_flat, vs_flat, stats, ws
             q_rope = apply_rope(qh[None, ..., nope:], sin, cos)[0]
             # W_K_h^T folded into the query: [q_nope_h W_K_h^T | rope(q_rope_h) | 0] against the entries
             q_lat = jnp.einsum("thn,hcn->thc", qh[..., :nope], blk["wkv_b_k"],
@@ -567,7 +607,7 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
             k_flat = k_flat.at[slot_l].set(k.astype(k_flat.dtype), mode="drop")
             v_flat = v_flat.at[slot_l].set(v.astype(v_flat.dtype), mode="drop")
             if kv_alone:
-                return x, k_flat, v_flat, ks_flat, vs_flat, stats, ws
+                return None, k_flat, v_flat, ks_flat, vs_flat, stats, ws
 
             # scales/tree kwargs only passed when active, so full-precision
             # causal third-party attention implementations keep the original
@@ -588,11 +628,42 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
             else:
                 ctx = attend(q, k_flat, v_flat, tables_l, seq_idx, vis_pos, **scales)
             ctx = ctx.reshape(T, nq * d)
-        if kind not in ("linear_attention", "lightning_attention"):
-            if cfg.attention_gate:
-                gate = linear(h1, blk["w_attn_gate"], None)
-                ctx = (ctx.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(ctx.dtype)
-            attn_out = linear(ctx, blk["wo"], bias("bo"))
+        if cfg.attention_gate:
+            gate = linear(h1, blk["w_attn_gate"], None)
+            ctx = (ctx.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(ctx.dtype)
+        return linear(ctx, blk["wo"], bias("bo")), k_flat, v_flat, ks_flat, vs_flat, stats, ws
+
+    def state_mixer(run):
+        """A state layer's mixer as the table's entries are called: the K/V
+        pools pass through untouched and ``ws`` holds the state pools."""
+
+        def mixer(h1, blk, l, kind, k_flat, v_flat, ks_flat, vs_flat, stats, ws, kv_alone):
+            out, *pools = run(h1, blk, state_index[l], *ws[:n_state_pools], *((ropes[kind], ) if kind in ropes else ()))
+            return out, k_flat, v_flat, ks_flat, vs_flat, stats, tuple(pools) + tuple(ws[n_state_pools:])
+
+        return mixer
+
+    # the mixers by the layer's kind; every kind that is not named here (None, of a model of one kind, among
+    # them) is softmax attention over the paged pool, and an "mlp_only" layer has none
+    mixers = {"linear_attention": state_mixer(linear_mixer), "lightning_attention": state_mixer(lightning_mixer),
+              "state_space": state_mixer(state_space_mixer)}
+    n_state_pools = len(state_pools or ())
+
+    def layer(x, blk, l, k_flat, v_flat, ks_flat, vs_flat, stats=None, ws=None, kind=None, kv_alone=False):
+        """``kind``: the layer's kind, static (None in a model of one kind,
+        where ``l`` may be traced); ``stats``: the running MoE counts; ``ws``:
+        latent attention's workspace of per-head K and V, or a model with
+        state layers' ``(state, tails)`` pools; ``kv_alone``: write this
+        layer's K/V and stop. A layer runs the branches it HAS: a mixer and an
+        MLP under a norm each, or, in a model of ``single_branch_layers``, the
+        one of them its kind names under the layer's one norm."""
+        h1 = pre_norm(x, blk["ln1_scale"], blk.get("ln1_bias"))
+        bias = (lambda n: blk[n]) if cfg.use_bias else (lambda n: None)
+        if kind != "mlp_only":
+            attn_out, k_flat, v_flat, ks_flat, vs_flat, stats, ws = mixers.get(kind, softmax_mixer)(
+                h1, blk, l, kind, k_flat, v_flat, ks_flat, vs_flat, stats, ws, kv_alone)
+            if attn_out is None:  # ``kv_alone``: the layer stopped at its scatter
+                return x, k_flat, v_flat, ks_flat, vs_flat, stats, ws
 
         def dense_mlp(h, w_up, w_gate, w_down, b_up=None, b_down=None):
             up = linear(h, w_up, b_up)
@@ -608,7 +679,7 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
             # layer's out of them: ``blk`` holds no routed expert's weights
             out = moe(h, blk["gate_wg"], experts["moe_wi"], experts.get("moe_wg"),
                       experts["moe_wo"], valid=valid, with_stats=stats is not None,
-                      layer=l - first_expert_layer, gate_bias=blk.get("gate_bias"))
+                      layer=expert_index[l], gate_bias=blk.get("gate_bias"))
             if stats is not None:
                 out, layer_stats = out
                 stats = merge_routing_stats(stats, layer_stats)
@@ -620,6 +691,9 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
             y = pre_norm(y, blk[name]) if cfg.post_norms else y
             return y if cfg.residual_scale == 1.0 else (y.astype(jnp.float32) * cfg.residual_scale).astype(y.dtype)
 
+        if cfg.single_branch_layers:  # ONE branch under the layer's one norm
+            return x + post(mlp(h1) if kind == "mlp_only" else attn_out, "ln1_post_scale"), k_flat, v_flat, ks_flat, \
+                vs_flat, stats, ws
         if cfg.parallel_residual:  # GPT-J / NeoX / Falcon
             h2 = h1 if cfg.shared_ln else pre_norm(x, blk["ln2_scale"], blk.get("ln2_bias"))
             return x + attn_out + mlp(h2), k_flat, v_flat, ks_flat, vs_flat, stats, ws
@@ -651,21 +725,24 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
         count = jnp.zeros((last_idx.shape[0], ), jnp.int32).at[seq_idx].add(valid.astype(jnp.int32))
         along = (jnp.maximum(count - 1, 0)[:, None] * jnp.arange(PROBES, dtype=jnp.int32)[None, :]) // (PROBES - 1)
         probe_tok = jnp.maximum(last_idx - jnp.maximum(count - 1, 0), 0)[:, None] + along
-    first_expert_layer = cfg.moe_num_dense_layers if moe is not None else 0
-    mixed_mlp = first_expert_layer > 0
+    mixed_mlp = moe is not None and cfg.moe_num_dense_layers > 0
+    expert_index = {l: i for i, l in enumerate(cfg.expert_layers)}  # a layer's place in the expert layers' arrays
+    dense_index = {l: i for i, l in enumerate(cfg.dense_layers)}
     experts = {k: v for k, v in params["blocks"].items() if k in expert_keys}
     per_layer = {k: v for k, v in params["blocks"].items() if k not in expert_keys}
 
     def index_of(name, l):
-        """Layer ``l``'s index into the stacked array ``name``; None: it has none there."""
-        if state_index and name.startswith(("kda_", "la_")):
+        """Layer ``l``'s index into the stacked array ``name``, by the
+        layer's kind: an array is stacked over the layers that have it,
+        wherever they lie; None: layer ``l`` has none there."""
+        if name.startswith(("kda_", "la_", "m2_")):
             return state_index.get(l)
-        if state_index and name in attention_keys:
+        if name in attention_keys:
             return kv_index.get(l)
-        if mixed_mlp and name in expert_layer_keys:
-            return l - first_expert_layer if l >= first_expert_layer else None
-        if mixed_mlp and name in dense_layer_keys:
-            return l if l < first_expert_layer else None
+        if name in expert_layer_keys:
+            return expert_index.get(l)
+        if name in dense_layer_keys:
+            return dense_index.get(l)
         return l
 
     if unroll and L <= 48:
@@ -680,9 +757,10 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
             raise NotImplementedError("kv_only under lax.scan: one scan body cannot stop its last layer at the "
                                       "scatter; the ragged forward unrolls up to 48 layers")
         if cfg.per_layer_attention or mixed_mlp:
-            raise NotImplementedError("layer_types (windows, ropes, linear-attention layers) or leading dense layers "
-                                      "under lax.scan: one scan body has one mixer, one window, one rope and one MLP "
-                                      "kind; the ragged forward unrolls up to 48 layers")
+            raise NotImplementedError("layer_types (windows, ropes, linear-attention, lightning, state-space or "
+                                      "mlp-only layers) or leading dense layers under lax.scan: one scan body has one "
+                                      "mixer, one window, one rope and one MLP kind; the ragged forward unrolls up to "
+                                      "48 layers")
 
         def scan_body(carry, inp):
             blk, l = inp

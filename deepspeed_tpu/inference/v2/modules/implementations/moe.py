@@ -75,6 +75,8 @@ class GroupedGemmMoE(DSMoEBase):
         def act(up, gate):
             if cfg.activation == "swiglu":
                 return jax.nn.silu(gate) * up
+            if cfg.activation == "relu2":  # relu squared, no gate matrix
+                return jax.numpy.square(jax.nn.relu(up))
             return jax.nn.relu(up) if cfg.activation == "relu" else jax.nn.gelu(up)
 
         return grouped_moe_ffn(x.astype(cfg.dtype), top_idx, top_w.astype(cfg.dtype), expert_up, expert_down,

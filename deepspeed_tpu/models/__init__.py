@@ -15,3 +15,4 @@ from .sdar import sdar, sdar_config
 from .glm import glm, glm_config
 from .solar import solar, solar_config
 from .minicpm import minicpm, minicpm_config
+from .nemotron import nemotron, nemotron_config

@@ -37,9 +37,11 @@ from ..parallel.mesh import BATCH_AXES, DATA_AXIS, MODEL_AXIS, PIPE_AXIS, SEQ_AX
 from ..runtime.zero.partition import PartitionRules
 
 
-# what ``layer_types`` may call a layer; those of ``STATE_KINDS`` cache no token and hold a state a sequence
-LAYER_KINDS = ("sliding_attention", "full_attention", "sparse_attention", "linear_attention", "lightning_attention")
-STATE_KINDS = ("linear_attention", "lightning_attention")
+# what ``layer_types`` may call a layer; those of ``STATE_KINDS`` cache no token and hold a state a sequence;
+# an "mlp_only" layer has no mixer at all and caches nothing (a model of ``single_branch_layers``)
+LAYER_KINDS = ("sliding_attention", "full_attention", "sparse_attention", "linear_attention", "lightning_attention",
+               "state_space", "mlp_only")
+STATE_KINDS = ("linear_attention", "lightning_attention", "state_space")
 
 
 @dataclass
@@ -53,7 +55,7 @@ class TransformerConfig:
     max_seq_len: int = 2048
     norm: str = "rmsnorm"  # 'rmsnorm' | 'layernorm'
     positions: str = "rotary"  # 'rotary' | 'learned' | 'alibi'
-    mlp: str = "swiglu"  # 'swiglu' | 'gelu' | 'relu'
+    mlp: str = "swiglu"  # 'swiglu' | 'gelu' | 'relu' | 'relu2' (relu squared, no gate matrix)
     use_bias: bool = False
     # per-site override for the qkv projections only (Qwen2: biased qkv,
     # bias-free o/mlp). None = follow use_bias.
@@ -126,8 +128,10 @@ class TransformerConfig:
     # the dense MLP of width ``intermediate_size``, the rest the experts
     moe_num_dense_layers: int = 0
     # shared experts of width ``expert_size`` each, run on every token beside
-    # the routed ones (one SwiGLU of their summed width)
+    # the routed ones (one MLP of their summed width, or of
+    # ``moe_shared_expert_size`` where the family states a width of its own)
     moe_num_shared_experts: int = 0
+    moe_shared_expert_size: Optional[int] = None
     # the router's score over all ``moe_num_experts``: 'softmax', or 'sigmoid'
     # (each expert's own; with ``moe_route_bias`` the top-k is taken of score +
     # a per-expert float32 bias no gradient trains, and the weights are the
@@ -218,6 +222,24 @@ class TransformerConfig:
     sparse_init_blocks: int = 1
     sparse_window_size: int = 2048
     sparse_dense_len: int = 8192
+    # state-space layers (Mamba-2's selective scan, Nemotron-H): the layers
+    # ``layer_types`` calls "state_space" cache nothing per token and carry, per
+    # sequence, a float32 state of ``mamba_num_heads`` x ``mamba_head_dim`` x
+    # ``mamba_state_size`` (the state width last: 128 on the lanes) and the last
+    # ``mamba_conv_size - 1`` inputs of the causal convolution over x, B and C
+    # (``state_entry``). A head's decay is made from the input, ``exp(dt_t
+    # A_h)``; B and C are shared by the heads of one of ``mamba_n_groups``
+    # groups (``models/nemotron.py`` has the equations). Served by the ragged
+    # path alone (``ops/pallas/mamba2.py``). 0 heads = none
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 64
+    mamba_state_size: int = 128
+    mamba_n_groups: int = 8
+    mamba_conv_size: int = 4
+    # a layer is ONE pre-norm branch, ``x + branch(norm(x))``: a mixer where
+    # ``layer_types`` names one, the MLP where it says "mlp_only". False: every
+    # layer is a mixer, then an MLP, under two norms
+    single_branch_layers: bool = False
     # MiniCPM's scaled residual path: each branch's output times
     # ``residual_scale`` before it is added, the final normed hidden state times
     # ``logit_scale`` before the head (``embed_scale`` is the third of them)
@@ -247,8 +269,9 @@ class TransformerConfig:
             first, held = self.moe_first_expert, self.experts_held
             if not (1 <= held and 0 <= first and first + held <= self.moe_num_experts):
                 raise ValueError(f"experts [{first}, {first + held}) held of {self.moe_num_experts}")
-            if not 0 <= self.moe_num_dense_layers < self.num_layers:
-                raise ValueError(f"moe_num_dense_layers={self.moe_num_dense_layers} of {self.num_layers} layers")
+            if not 0 <= self.moe_num_dense_layers < len(self.mlp_layers):
+                raise ValueError(f"moe_num_dense_layers={self.moe_num_dense_layers} of {len(self.mlp_layers)} layers "
+                                 "with an MLP")
         if self.diffusion_block_size:
             B = self.diffusion_block_size
             if B < 1 or B & (B - 1):
@@ -287,13 +310,28 @@ class TransformerConfig:
         # ``layer_types`` says which kind a layer is; the widths of a kind are stated if and only if it occurs
         for kind, heads, name in (("linear_attention", self.kda_num_heads, "kda_num_heads"),
                                   ("lightning_attention", self.lightning_num_heads, "lightning_num_heads"),
+                                  ("state_space", self.mamba_num_heads, "mamba_num_heads"),
                                   ("sparse_attention", self.sparse_topk, "sparse_topk")):
             n = (self.layer_types or ()).count(kind)
             if bool(n) != (heads > 0):
                 raise ValueError(f"{name}={heads} with {n} {kind!r} layers in layer_types: the one names the other")
-        if self.kda_num_heads > 0 and self.lightning_num_heads > 0:
-            raise NotImplementedError("'linear_attention' (the delta rule) and 'lightning_attention' (scalar decay) "
-                                      "layers in one model: a sequence's state slot holds one kind")
+        if sum(n > 0 for n in (self.kda_num_heads, self.lightning_num_heads, self.mamba_num_heads)) > 1:
+            raise NotImplementedError("'linear_attention' (the delta rule), 'lightning_attention' (scalar decay) and "
+                                      "'state_space' (the selective scan) layers in one model: a sequence's state "
+                                      "slot holds one kind")
+        if self.mamba_num_heads > 0 and (self.mamba_num_heads % self.mamba_n_groups or self.mamba_conv_size < 2):
+            raise ValueError(f"mamba_num_heads={self.mamba_num_heads} in {self.mamba_n_groups} groups of whole heads, "
+                             f"a convolution of at least 2 taps (got {self.mamba_conv_size})")
+        if ("mlp_only" in (self.layer_types or ())) and not self.single_branch_layers:
+            raise ValueError("an 'mlp_only' layer belongs to a model of single_branch_layers: every other model's "
+                             "layer is a mixer, then an MLP")
+        if self.single_branch_layers:
+            if self.layer_types is None or "mlp_only" not in self.layer_types:
+                raise ValueError("single_branch_layers needs layer_types with at least one 'mlp_only' layer")
+            if self.parallel_residual or self.post_norms or self.moe_num_dense_layers or self.norm == "layernorm" \
+                    or self.use_bias or self.latent_attention or self.diffusion_block_size:
+                raise NotImplementedError("single_branch_layers beside a parallel residual, norms after a branch, leading "
+                                          "dense layers, layernorm, biases, latent attention or block diffusion")
         if self.lightning_num_heads > 0 and (self.lightning_head_dim != self.head_dim or self.lightning_head_dim % 2):
             raise NotImplementedError(f"lightning_head_dim={self.lightning_head_dim} beside heads of {self.head_dim}: "
                                       "the lightning layers rotate q and k with the softmax layers' one rope table")
@@ -313,11 +351,12 @@ class TransformerConfig:
                                  f"{self.sparse_topk} over the forced blocks, dense_len {self.sparse_dense_len} >= topk blocks")
         if self.state_layers:
             if not self.kv_layers:
-                raise NotImplementedError("every layer 'linear_attention': the paged cache and its block tables "
-                                          "are built for at least one layer that caches K and V")
+                raise NotImplementedError("no layer that caches K and V (every layer 'linear_attention', or of another "
+                                          "kind that caches no token): the paged cache and its block tables are built "
+                                          "for at least one")
             if self.use_bias or self.qkv_bias_enabled or self.diffusion_block_size or self.parallel_residual \
                     or self.positions in ("alibi", "learned") or (self.kda_num_heads > 0 and self.kda_conv_size < 2):
-                raise NotImplementedError("linear attention beside biases, block diffusion, a parallel residual, "
+                raise NotImplementedError("a recurrent state layer beside biases, block diffusion, a parallel residual, "
                                           "alibi or learned positions, or without its short convolution")
         if self.intermediate_size is None:
             if self.mlp == "swiglu":
@@ -350,6 +389,21 @@ class TransformerConfig:
         return self.moe_intermediate_size or self.intermediate_size
 
     @property
+    def expert_rows(self) -> int:
+        """The width a routed expert's matrices are STORED at: ``expert_size``,
+        or, where that is wider than a lane tile and no whole number of them
+        (1,856 = 14.5 x 128), the next multiple of 128, the padding zeros (an
+        expert's hidden units that read nothing and feed nothing: exact through
+        relu, relu squared and SwiGLU, and a fixed point of their gradients).
+        The chip's tiled memory holds a row of 1,856 as 1,920 whatever its
+        shape says, and given the choice lays such an array with its OTHER
+        matrix dimension last: the grouped kernel, which reads an expert's tile
+        in place, then got a transposed copy of every expert a program (3.08 GB
+        at 5 x 64 experts: the compiler's own report, PR 51)."""
+        f = self.expert_size
+        return f if f <= 128 or f % 128 == 0 else -(-f // 128) * 128
+
+    @property
     def latent_attention(self) -> bool:
         return self.kv_lora_rank > 0
 
@@ -369,27 +423,60 @@ class TransformerConfig:
     def state_layers(self) -> Tuple[int, ...]:
         """The layers that carry a recurrent state per sequence and cache
         nothing per token (``layer_types`` says 'linear_attention', the delta
-        rule, or 'lightning_attention', scalar decay)."""
+        rule, 'lightning_attention', scalar decay, or 'state_space', the
+        selective scan)."""
         return tuple(l for l, kind in enumerate(self.layer_types or ()) if kind in STATE_KINDS)
 
     @property
     def kv_layers(self) -> Tuple[int, ...]:
         """The layers that cache ``kv_entry`` per token: all but the state
-        layers. The paged pool is stacked over these alone."""
-        skip = set(self.state_layers)
-        return tuple(l for l in range(self.num_layers) if l not in skip)
+        layers and the layers that are an MLP alone. The paged pool is stacked
+        over these alone."""
+        return tuple(l for l in range(self.num_layers)
+                     if self.layer_types is None or self.layer_types[l] not in STATE_KINDS + ("mlp_only", ))
+
+    @property
+    def mlp_layers(self) -> Tuple[int, ...]:
+        """The layers that have an MLP: every layer, or, in a model of
+        ``single_branch_layers``, those ``layer_types`` calls 'mlp_only'."""
+        if not self.single_branch_layers:
+            return tuple(range(self.num_layers))
+        return tuple(l for l, kind in enumerate(self.layer_types) if kind == "mlp_only")
+
+    @property
+    def expert_layers(self) -> Tuple[int, ...]:
+        """The layers whose MLP is the routed experts: the MLP layers after
+        the leading ``moe_num_dense_layers`` of them, wherever they lie."""
+        return self.mlp_layers[self.moe_num_dense_layers:] if self.moe_num_experts > 0 else ()
+
+    @property
+    def dense_layers(self) -> Tuple[int, ...]:
+        """The layers whose MLP is the dense one."""
+        return self.mlp_layers[:self.moe_num_dense_layers] if self.moe_num_experts > 0 else self.mlp_layers
+
+    @property
+    def mamba_conv_channels(self) -> int:
+        """Channels the state-space layers' convolution runs over: x, B and C."""
+        return self.mamba_num_heads * self.mamba_head_dim + 2 * self.mamba_n_groups * self.mamba_state_size
 
     @property
     def state_entry(self) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
-        """What ONE sequence holds in ONE state layer, whatever its length:
-        the float32 state ``(heads, key width, value width)`` and the
-        convolution's tail ``(taps - 1, channels of q, k and v)`` in the
-        compute type (the delta rule's layers; a lightning layer holds the
-        state alone). ``()`` for a model without state layers."""
+        """What ONE sequence holds in ONE state layer, whatever its length, one
+        of three shapes: the delta rule's float32 state ``(heads, key width,
+        value width)`` and its convolution's tail ``(taps - 1, channels of q, k
+        and v)`` in the compute type; a lightning layer's state of the same
+        form alone; a state-space layer's float32 state ``(heads, head width,
+        state width)`` (the state width, 128, on the lanes: a head's 64 values
+        last would be padded to twice their bytes) and its convolution's tail
+        ``(taps - 1, channels of x, B and C)``. ``()`` for a model without
+        state layers."""
         if not self.state_layers:
             return ()
         if self.lightning_num_heads > 0:
             return ((self.lightning_num_heads, self.lightning_head_dim, self.lightning_head_dim), )
+        if self.mamba_num_heads > 0:
+            return ((self.mamba_num_heads, self.mamba_head_dim, self.mamba_state_size),
+                    (self.mamba_conv_size - 1, self.mamba_conv_channels))
         h, d = self.kda_num_heads, self.kda_head_dim
         return ((h, d, d), (self.kda_conv_size - 1, 3 * h * d))
 
@@ -417,7 +504,7 @@ class TransformerConfig:
 
     @property
     def num_expert_layers(self) -> int:
-        return self.num_layers - self.moe_num_dense_layers if self.moe_num_experts > 0 else 0
+        return len(self.expert_layers)
 
     @property
     def experts_held(self) -> int:
@@ -430,10 +517,16 @@ class TransformerConfig:
         why = []
         if self.layer_types is not None:
             why.append(f"layer_types gives each layer its own window and rope ({sorted(set(self.layer_types))})")
-        if self.state_layers:
+        if self.mamba_num_heads > 0:
+            why.append(f"{len(self.state_layers)} state-space layer(s) (a selective-scan state and a convolution's tail "
+                       "per sequence, which no whole-sequence forward builds)")
+        elif self.state_layers:
             rule = "scalar-decay (lightning)" if self.lightning_num_heads > 0 else "delta-rule"
             why.append(f"{len(self.state_layers)} linear-attention layer(s) (a {rule} state per sequence, which "
                        "no whole-sequence forward builds)")
+        if self.single_branch_layers:
+            why.append(f"layers of ONE branch each ({len(self.mlp_layers)} of {self.num_layers} an MLP alone, the others "
+                       "a mixer alone): the scanned block is a mixer, then an MLP")
         if self.sparse_topk > 0:
             why.append(f"a learned block-sparse selection (top {self.sparse_topk} blocks of {self.sparse_block_size} "
                        "over pooled keys cached beside K and V, which no whole-sequence forward builds)")
@@ -444,6 +537,7 @@ class TransformerConfig:
         for flag, what in ((self.moe_num_shared_experts > 0, "a shared expert"),
                            (self.moe_score_func != "softmax" or self.moe_route_bias or self.moe_route_scale != 1.0,
                             "sigmoid / biased / scaled routing"),
+                           (self.moe_num_experts > 0 and self.mlp == "relu2", "relu-squared experts without a gate matrix"),
                            (self.qk_norm, "a q/k norm"), (self.attention_gate, "gated attention"),
                            (self.post_norms, "norms after attention and MLP"),
                            (self.rope_layer_types is not None, "rope in some layer kinds only"),
@@ -483,8 +577,8 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
     nq, nkv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     k = jax.random.split(rng, 12)
     extra = partial(jax.random.fold_in, k[11])  # keys of what PR 31 added: the first eleven draw what they drew
-    Ld = cfg.moe_num_dense_layers if cfg.moe_num_experts > 0 else L  # layers with the dense MLP
-    Le = L - Ld  # layers with experts: their arrays are stacked over these alone
+    Ld = len(cfg.dense_layers)  # layers with the dense MLP
+    Le = len(cfg.expert_layers)  # layers with experts: their arrays are stacked over these alone, wherever they lie
 
     def dense_init(key, shape, fan_in):
         return (jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in))
@@ -492,7 +586,7 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
     def gain(key, shape):
         """A norm's gain: one, unless the family's extra norms are on, whose
         gains are drawn about one so that leaving a norm out shows."""
-        if not (cfg.post_norms or cfg.qk_norm or cfg.latent_attention or cfg.lightning_num_heads):
+        if not (cfg.post_norms or cfg.qk_norm or cfg.latent_attention or cfg.lightning_num_heads or cfg.mamba_num_heads):
             return jnp.ones(shape, jnp.float32)
         return 1.0 + 0.1 * jax.random.normal(key, shape, jnp.float32)
 
@@ -540,6 +634,23 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
             la_k_norm_scale=1.0 + 0.1 * jax.random.normal(extra(66), (Ll, dk), jnp.float32),
             la_o_norm_scale=1.0 + 0.1 * jax.random.normal(extra(67), (Ll, C), jnp.float32),
             la_slope=jnp.asarray(lightning_slopes(cfg), jnp.float32))
+    if cfg.mamba_num_heads > 0:
+        # state-space layers (Mamba-2), stacked over their layers alone: ``W_in`` to [z | x B C | dt], the depthwise
+        # convolution over x, B and C with its bias, ``A_log``, ``D`` and ``dt_bias`` a head (float32 whatever the
+        # compute type), the gated norm's gain and ``W_out``. ``dt = softplus(dt_in + dt_bias)`` log-uniform on
+        # [1e-3, 1e-1] at a zero input and ``exp(A_log)`` in [1, 16): a token's decays spread over (0.2, 1)
+        Ll, nh, P = len(cfg.state_layers), cfg.mamba_num_heads, cfg.mamba_head_dim
+        C, conv, taps = nh * P, cfg.mamba_conv_channels, cfg.mamba_conv_size
+        dt0 = jnp.exp(jax.random.uniform(extra(75), (Ll, nh), jnp.float32, math.log(1e-3), math.log(1e-1)))
+        blocks.update(
+            m2_w_in=dense_init(extra(70), (Ll, H, C + conv + nh), H),
+            m2_conv_w=dense_init(extra(71), (Ll, taps, conv), taps),
+            m2_conv_b=0.1 * jax.random.normal(extra(72), (Ll, conv), jnp.float32),
+            m2_A_log=jnp.log(jax.random.uniform(extra(73), (Ll, nh), jnp.float32, 1.0, 16.0)),
+            m2_D=1.0 + 0.1 * jax.random.normal(extra(74), (Ll, nh), jnp.float32),
+            m2_dt_bias=dt0 + jnp.log(-jnp.expm1(-dt0)),  # softplus^-1
+            m2_norm_scale=1.0 + 0.1 * jax.random.normal(extra(76), (Ll, C), jnp.float32),
+            m2_w_out=dense_init(extra(77), (Ll, C, H), C) / math.sqrt(2 * L))
     if cfg.latent_attention:
         # the two low-rank projections with their norms, and ``W_kvb`` as the
         # two parts the absorbed form multiplies by, a head at a time: keys
@@ -566,17 +677,21 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
     if Le > 0:
         # the router scores all E experts; the weights of those held here
         # alone are allocated, and none for a dense layer
-        E, Eh, Fe = cfg.moe_num_experts, cfg.experts_held, cfg.expert_size
+        E, Eh, Fe, Fr = cfg.moe_num_experts, cfg.experts_held, cfg.expert_size, cfg.expert_rows
         blocks["gate_wg"] = dense_init(k[4], (Le, H, E), H)
-        blocks["moe_wi"] = dense_init(k[5], (Le, Eh, H, Fe), H)
-        blocks["moe_wo"] = dense_init(k[6], (Le, Eh, Fe, H), Fe) / math.sqrt(2 * L)
+        blocks["moe_wi"] = dense_init(k[5], (Le, Eh, H, Fr), H)
+        blocks["moe_wo"] = dense_init(k[6], (Le, Eh, Fr, H), Fe) / math.sqrt(2 * L)
         if cfg.mlp == "swiglu":
-            blocks["moe_wg"] = dense_init(k[10], (Le, Eh, H, Fe), H)
+            blocks["moe_wg"] = dense_init(k[10], (Le, Eh, H, Fr), H)
+        if Fr != Fe:  # the width is stored padded (``expert_rows``): the hidden units past it are zeros
+            live = (jnp.arange(Fr) < Fe).astype(jnp.float32)
+            blocks.update({name: blocks[name] * (live[:, None] if name == "moe_wo" else live)
+                           for name in ("moe_wi", "moe_wo", "moe_wg") if name in blocks})
         if cfg.moe_route_bias:
             # of the order of the gap between the k-th and the next score
             blocks["gate_bias"] = 0.01 * jax.random.normal(extra(18), (Le, E), jnp.float32)
         if cfg.moe_num_shared_experts > 0:
-            Fs = cfg.moe_num_shared_experts * Fe
+            Fs = cfg.moe_shared_expert_size or cfg.moe_num_shared_experts * Fe
             blocks["shared_wi"] = dense_init(extra(19), (Le, H, Fs), H)
             blocks["shared_wo"] = dense_init(extra(20), (Le, Fs, H), Fs) / math.sqrt(2 * L)
             if cfg.mlp == "swiglu":
@@ -586,8 +701,8 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
         blocks["w_down"] = dense_init(k[5] if Le == 0 else extra(24), (Ld, F, H), F) / math.sqrt(2 * L)
         if cfg.mlp == "swiglu":
             blocks["w_gate"] = dense_init(k[6] if Le == 0 else extra(25), (Ld, H, F), H)
-    if cfg.parallel_residual and cfg.shared_ln:
-        del blocks["ln2_scale"]  # single pre-norm feeds both branches
+    if (cfg.parallel_residual and cfg.shared_ln) or cfg.single_branch_layers:
+        del blocks["ln2_scale"]  # single pre-norm feeds both branches, or a layer has one branch and its one norm
     if cfg.norm == "layernorm":
         blocks["ln1_bias"] = jnp.zeros((L, H), jnp.float32)
         if not (cfg.parallel_residual and cfg.shared_ln):
@@ -842,11 +957,14 @@ def reference_attention(q, k, v, causal=True, segment_ids=None, window=None, ali
 
 
 def mlp_activation(cfg: TransformerConfig, up, gate=None):
-    """Shared MLP nonlinearity (swiglu/gelu/relu — relu for OPT-era models)."""
+    """Shared MLP nonlinearity (swiglu/gelu/relu — relu for OPT-era models;
+    relu2, relu squared, for Nemotron-H's)."""
     if cfg.mlp == "swiglu":
         return jax.nn.silu(gate) * up
     if cfg.mlp == "relu":
         return jax.nn.relu(up)
+    if cfg.mlp == "relu2":
+        return jnp.square(jax.nn.relu(up))
     return jax.nn.gelu(up)
 
 

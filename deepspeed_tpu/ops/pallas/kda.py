@@ -212,10 +212,10 @@ def kda_step(q, k, v, g, beta, pool, slot, fresh, n_live, use_pallas: bool = Fal
 # any number of tokens a row
 # ---------------------------------------------------------------------------
 
-def _row_tiles(n_tok, xp=jnp):
+def _row_tiles(n_tok, xp=jnp, tile: int = TILE):
     """The tiles each row takes in the chunk scan: none for a row fed one
     token (the recurrent step's) or none."""
-    return xp.where(n_tok > 1, -(-n_tok // TILE), 0)
+    return xp.where(n_tok > 1, -(-n_tok // tile), 0)
 
 
 def step_rows(n_tok, xp=jnp):
@@ -232,20 +232,21 @@ def step_rows(n_tok, xp=jnp):
     return rows.astype(xp.int32), (upto - one).astype(xp.int32), upto[-1].astype(xp.int32)
 
 
-def tile_plan(n_tok, T: int, xp=jnp):
+def tile_plan(n_tok, T: int, xp=jnp, tile: int = TILE):
     """The chunk scan's tiles of a ragged batch whose row ``r`` is fed
     ``n_tok[r]`` contiguous tokens, rows in order from flat token 0: a row of
-    two or more tokens takes ``ceil(n_tok / TILE)`` tiles, a row of one token
+    two or more tokens takes ``ceil(n_tok / tile)`` tiles (``tile``: this
+    rule's ``TILE``, or the selective scan's 128), a row of one token
     none (:func:`step_rows` has it), though its token keeps its place in the
-    flat order. ``NT = T // TILE + R`` tile slots (static; every row may end
+    flat order. ``NT = T // tile + R`` tile slots (static; every row may end
     in a partial tile), of which the first ``n_tiles`` are live. Returns
     ``(row, tok0, cnt, first, n_tiles)``, each ``[NT]`` but the last: a tile's
     row, its first flat token, its live tokens, whether it opens its row. A
     dead tile names the last live tile's row and holds no token."""
     R = n_tok.shape[0]
-    NT = T // TILE + R
+    NT = T // tile + R
     n_tok = n_tok.astype(xp.int32)
-    tiles = _row_tiles(n_tok, xp)
+    tiles = _row_tiles(n_tok, xp, tile)
     ends = xp.cumsum(tiles)
     n_tiles = ends[-1]
     t = xp.arange(NT, dtype=xp.int32)
@@ -256,8 +257,8 @@ def tile_plan(n_tok, T: int, xp=jnp):
     of_row = xp.stack([n_tok, xp.cumsum(n_tok) - n_tok, ends - tiles], axis=1)[row]   # ONE gather: tokens, first token, first tile
     j = t - of_row[:, 2]
     live = t < n_tiles
-    cnt = xp.where(live, xp.clip(of_row[:, 0] - j * TILE, 0, TILE), 0).astype(xp.int32)
-    return row, (of_row[:, 1] + j * TILE).astype(xp.int32), cnt, live & (j == 0), n_tiles.astype(xp.int32)
+    cnt = xp.where(live, xp.clip(of_row[:, 0] - j * tile, 0, tile), 0).astype(xp.int32)
+    return row, (of_row[:, 1] + j * tile).astype(xp.int32), cnt, live & (j == 0), n_tiles.astype(xp.int32)
 
 
 def _tile_math(S0, q, k, kb, vb, g):

@@ -84,8 +84,10 @@ _FIRST_OUT = (
     "test_engine_zero.py",
     "test_trinity.py",
     "test_minicpm.py",
+    "test_nemotron.py",
     "test_sdar.py",
     "perfbench/test_bench_sdar.py",
+    "perfbench/test_bench_nemotron.py",
     "test_checkpoint_tools.py",
     "test_program_spans.py",
     "test_inference_v2.py",

@@ -1088,6 +1088,66 @@ def test_the_delta_rules_kernels_at_the_cells_shapes_on_chip(form):
     assert np.array_equal(np.asarray(new)[untouched], np.asarray(pool)[untouched])
 
 
+_SELECTIVE_SCAN_BATCHES = {
+    # a 770-token chunk in the middle of 254 one-token rows: a mixed put of the window, a full house
+    "chunk_among_254_rows": [1] * 127 + [770] + [1] * 127 + [0],
+    # three chunks that cross the scan's blocks of tiles, no one-token row
+    "three_chunks": [401, 130, 493] + [0] * 253,
+}
+
+
+@pytest.mark.parametrize("form", ["recurrent_step", *_SELECTIVE_SCAN_BATCHES])
+def test_the_selective_scans_kernels_at_the_cells_shapes_on_chip(form):
+    """``ops/pallas/mamba2.py`` at ``nemotron-3-nano-30b-a3b.decode-heavy-256``'s
+    widths (64 heads of 64 x 128 in 8 groups, float32 state): the recurrent
+    step of 256 one-token rows (``mamba2_step``), and ``mamba2_chunks`` over a
+    770-token chunk among 254 one-token rows and over three chunks alone, in a
+    1,024-token program. Each against the rule token by token from the same
+    pool, with microseconds a call and the state's bytes over them."""
+    from deepspeed_tpu.ops.pallas import mamba2
+
+    H, G, P, N, slots, R = 64, 8, 64, 128, 300, 256
+    rng = np.random.default_rng(0)
+    T, n_tok = (R, np.ones(R, np.int64)) if form == "recurrent_step" else (1024, np.asarray(_SELECTIVE_SCAN_BATCHES[form]))
+    x = [jnp.asarray(a, jnp.float32) for a in (rng.normal(size=(T, H, P)), rng.normal(size=(T, G, N)),
+                                               rng.normal(size=(T, G, N)), np.exp(rng.uniform(np.log(3e-4), np.log(0.3), (T, H))))]
+    A = -jnp.asarray(rng.uniform(1, 16, H), jnp.float32)
+    pool = jnp.asarray(rng.normal(size=(slots, H, P, N)), jnp.float32)
+    slot = jnp.asarray(rng.permutation(slots)[:R], jnp.int32)
+    fresh = jnp.asarray(rng.integers(0, 2, size=R), jnp.int32)
+    # the batch as ARGUMENTS, as the engine hands it over: closed over, XLA folds the plan into constants
+    if form == "recurrent_step":
+        fn = lambda x, B, C, dt, pool, slot, fresh, n: mamba2.mamba2_step(x, B, C, dt, A, pool, slot, fresh, jnp.sum(n),
+                                                                          use_pallas=True)
+    else:
+        fn = lambda x, B, C, dt, pool, slot, fresh, n: mamba2.mamba2_chunks(x, B, C, dt, A, pool, slot, fresh, n,
+                                                                            use_pallas=True)
+    batch = (slot, fresh, jnp.asarray(n_tok, jnp.int32))
+    y, new = jax.jit(fn)(*x, pool, *batch)
+    in_place = jax.jit(fn, donate_argnums=4)  # as the engine calls it: the pool donated and advanced where it lies
+    _, carried = in_place(*x, pool + 0.0, *batch)
+    jax.block_until_ready(carried)
+    t0 = time.perf_counter()
+    for _ in range(10):
+        _, carried = in_place(*x, carried, *batch)
+    jax.block_until_ready(carried)
+    us = (time.perf_counter() - t0) / 10 * 1e6
+    rows = int((n_tok > 0).sum())
+    print(f"\nmamba2[{form}]: {us:.0f} us a call, {rows} rows' state read and written at "
+          f"{rows * 2 * H * P * N * 4 / us / 1e3:.0f} GB/s")
+    t0, untouched = 0, np.ones(slots, bool)
+    for r, n in enumerate(n_tok):
+        if n:
+            S0 = jnp.zeros((H, P, N)) if int(fresh[r]) else pool[slot[r]]
+            if r < 3 or r % 37 == 0 or n > 1:  # the long rows and a few of the others, token by token
+                yy, S = mamba2.recurrence_reference(*[a[t0:t0 + n] for a in x], A, S0)
+                assert float(jnp.abs(yy - y[t0:t0 + n]).max()) < 2e-4
+                assert float(jnp.abs(S - new[slot[r]]).max()) < 2e-4
+            untouched[int(slot[r])] = False
+            t0 += n
+    assert np.array_equal(np.asarray(new)[untouched], np.asarray(pool)[untouched])
+
+
 @pytest.mark.parametrize("name,T,S,rows,kernel", [
     # minicpm-sala.longctx: a 2,048-token chunk at 30k of history behind seven riding decode rows
     ("chunk_mixed", 2048, 8, [(34000 + 4000 * i, 1) for i in range(7)] + [(30000, 2041)], "paged_attn_q_tiled"),
