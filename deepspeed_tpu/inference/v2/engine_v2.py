@@ -29,7 +29,8 @@ from ...monitor.trace import (NULL_SPAN, get_tracer, pop_compile_source,
 from ...moe.grouped import merge_routing_stats
 from ...ops.pallas.kda import KERNEL_NAMES as KDA_KERNEL_NAMES, TILE as KDA_TILE
 from ...ops.pallas.lightning import KERNEL_NAMES as LIGHTNING_KERNEL_NAMES, TILE as LIGHTNING_TILE
-from ...ops.pallas.mamba2 import KERNEL_NAMES as MAMBA_KERNEL_NAMES, TILE as MAMBA_TILE, TILE_BLOCK as MAMBA_TILE_BLOCK
+from ...ops.pallas.mamba2 import (KERNEL_NAMES as MAMBA_KERNEL_NAMES, TILE as MAMBA_TILE, TILE_BLOCK as MAMBA_TILE_BLOCK,
+                                  step_operand_bytes as mamba_step_operand_bytes)
 from ...ops.pallas.paged_attention import decode_kv_counts, kernel_choice, tiled_kv_counts
 from ...utils.logging import log_dist
 from .config_v2 import RaggedInferenceEngineConfig
@@ -396,11 +397,11 @@ class InferenceEngineV2:
         if self._mamba:  # state-space layers: (heads, head width, state width)
             # the slots; of the largest ``put`` the convolution's float32 output, what the tiles are laid from (dt x,
             # dt A, B and C a token) and ``y`` back in the flat order, a block of tiles beside them
-            # (``mamba2_chunks``); of the rows fed one token the step's columns of ``dt x`` and of ``y`` (128
-            # lanes a group) and its rows of B, C and the decays
-            groups = mc.mamba_n_groups
+            # (``mamba2_chunks``); of the rows fed one token what the recurrent step is laid a row: ONE tile of
+            # ``dt x`` and one of ``y`` a grid step (a head a lane; one step a row where a row's state fits VMEM, as
+            # at the published widths) and the step's rows of B, C and the decays
             tokens = 4 * sm.max_ragged_batch_size + 4 * MAMBA_TILE_BLOCK * MAMBA_TILE
-            rows = sm.max_ragged_sequence_count * groups * (2 * dk * 128 + 16 * dv) * 4
+            rows = sm.max_ragged_sequence_count * mamba_step_operand_bytes(h, mc.mamba_n_groups, dk, dv)
             return sm.max_tracked_sequences * slot + tokens * (channels + h * dk) * 4 + rows
         tile_tokens = sm.max_ragged_batch_size + KDA_TILE * sm.max_ragged_sequence_count
         tokens = tile_tokens + 8 * sm.max_ragged_batch_size + 32 * sm.max_ragged_sequence_count

@@ -24,11 +24,21 @@ Two forms, split by what a row is fed as the delta rule's are:
 * :func:`mamba2_step` (kernel ``mamba2_recurrent_step``): ONE token a row, a
   read-modify-write of the row's whole state, bound by memory bandwidth: ``2 x
   heads x P x N x 4`` bytes a row a layer. The decode horizon's step, where
-  token ``i`` is row ``i``. A grid step holds one GROUP's heads, so ``B`` and
-  ``C`` come once a block as rows over the lanes; ``dt x`` comes as columns
-  (the head's width on sublanes, lane ``h`` the block's head ``h``) and ``y``
-  goes back the same way, laid by XLA, so that no vector is turned in the
-  kernel.
+  token ``i`` is row ``i``. A grid step holds as many of a row's GROUPS as
+  VMEM takes (``_groups_per_step``: all eight at the published widths, where
+  a row's layer state is ONE contiguous 2 MiB block each way and a layer's
+  grid 256 steps for 256 rows; a step of one group's 256 KiB ran at 55% of
+  the memory's stream, PERF.md section 6, PR 52). ``dt x`` comes as ONE tile
+  a step (the head's width on sublanes, lane ``j`` the step's head ``j``) and
+  ``y`` goes back the same way, one transposition a row each laid by XLA, so
+  that no vector is turned in the kernel; ``B``, ``C`` and the decays come as
+  rows over the lanes, a group's ``B`` and ``C`` taken once for its heads.
+  The body takes the step's heads one at a time, whole, every product on the
+  vector unit in float32, and leaves a head's ``y`` on the head's lane by a
+  tree of merges (``_LaneSums``: a select and one turn of the cross-lane unit
+  a head, where a reduction a head costs seven and was what the copies did
+  not hide); the call is jitted so that a program's layers share one trace
+  of it.
 * :func:`mamba2_chunks`: a ragged batch of rows fed any number of tokens. A
   row fed exactly one token goes through the recurrent step wherever it
   stands (``kda.step_rows``); a row fed two or more through the chunk scan
@@ -57,6 +67,8 @@ was. Off the TPU both run as ``jax.numpy`` over the same tile quantities
 Products are float32 at ``HIGHEST``.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -70,6 +82,11 @@ _HI = lax.Precision.HIGHEST
 TILE_BLOCK = 4
 
 KERNEL_NAMES = ("mamba2_recurrent_step", "mamba2_chunk_scan")
+
+_LANES = 128
+# what the recurrent step's buffers may take of VMEM (``_groups_per_step``): at the published widths a row's whole
+# layer state is 2 MiB, read and written in two buffers each
+_STEP_VMEM_BYTES = 24 << 20
 
 
 def recurrence_reference(x, B, C, dt, A, state):
@@ -94,56 +111,152 @@ def recurrence_reference(x, B, C, dt, A, state):
 # one token a row
 # ---------------------------------------------------------------------------
 
-def _mamba2_step_pallas(cols, bc, pool, slot, fresh, n_live, hb: int, interpret: bool):
-    """``cols`` ``[R, G, P, 128]``: lane ``h`` of a group's block holds ``dt x``
-    of its head ``h``, the head's width on sublanes; ``bc`` ``[R, G, rows, N]``:
-    row 0 the group's ``B``, row 1 its ``C``, row ``2 + h`` head ``h``'s decay
-    over every lane."""
+def _tile_lanes(heads: int) -> int:
+    return -(-heads // _LANES) * _LANES
+
+
+def _step_operand_bytes(gs: int, hb: int, P: int, N: int) -> int:
+    """A grid step's small operands: the ``dt x`` tile in, the ``y`` tile out
+    (a head a lane, whole tiles of lanes), the rows of B, C and the decays."""
+    return (2 * P * _tile_lanes(gs * hb) + (2 * gs + gs * hb) * N) * 4
+
+
+def _step_vmem_bytes(gs: int, hb: int, P: int, N: int) -> int:
+    """What a grid step of ``gs`` groups keeps in VMEM: the state's block read
+    and the one written and the step's small operands, two buffers each."""
+    return 2 * (2 * gs * hb * P * N * 4 + _step_operand_bytes(gs, hb, P, N))
+
+
+def _groups_per_step(G: int, hb: int, P: int, N: int) -> int:
+    """Groups of a row one grid step of ``mamba2_recurrent_step`` takes: the
+    largest divisor of ``G`` whose buffers fit ``_STEP_VMEM_BYTES`` (all eight
+    at the published widths, a row's whole layer state as ONE contiguous
+    block), at least one. It follows from the static shapes alone: no
+    argument, field or variable changes it."""
+    return max([gs for gs in range(1, G + 1) if G % gs == 0 and _step_vmem_bytes(gs, hb, P, N) <= _STEP_VMEM_BYTES],
+               default=1)
+
+
+def step_operand_bytes(H: int, G: int, P: int, N: int) -> int:
+    """What XLA lays a ROW for the recurrent step beside the pool, over the
+    row's grid steps (``engine_v2`` sizes the K/V pool by it)."""
+    gs = _groups_per_step(G, H // G, P, N)
+    return (G // gs) * _step_operand_bytes(gs, H // G, P, N)
+
+
+class _LaneSums:
+    """The sums over the lanes of up to ``W`` arrays ``[rows, W]`` (``W`` a
+    power of two), array ``j``'s on lane ``j`` of ONE array: a tree of merges,
+    each a select of two arrays' halves and one turn (level ``k`` folds lanes
+    ``2^k`` apart and leaves bit ``k`` of a lane to say which of its two
+    arrays the lane sums), so that an array costs one turn on the cross-lane
+    unit where a reduction of its own costs one a halving. Arrays are merged
+    as they come: no more than one a level waits."""
+
+    def __init__(self, lane, roll):
+        self.lane, self.roll, self.W = lane, roll, lane.shape[1]
+        self.waiting = []   # (level, array), levels falling
+        self.count = 0
+        self.firsts = {}    # a level's lanes whose bit is clear, made once
+
+    def _merge(self, a, b, level: int):
+        if level not in self.firsts:
+            self.firsts[level] = (self.lane & (1 << level)) == 0
+        first = self.firsts[level]
+        if b is None:   # no array came for the other half: its lanes sum nothing
+            return jnp.where(first, a, 0.0) + self.roll(jnp.where(first, 0.0, a), 1 << level, 1)
+        return jnp.where(first, a, b) + self.roll(jnp.where(first, b, a), 1 << level, 1)
+
+    def add(self, a):
+        level = 0
+        while self.waiting and self.waiting[-1][0] == level:
+            a, level = self._merge(self.waiting.pop()[1], a, level), level + 1
+        self.waiting.append((level, a))
+        self.count += 1
+
+    def result(self):
+        """``[rows, W]``: lane ``l`` the sum of array ``l`` mod the count rounded up to a power of two."""
+        level, a = self.waiting.pop()
+        while self.waiting or (1 << level) < self.count:
+            if self.waiting and self.waiting[-1][0] == level:
+                a = self._merge(self.waiting.pop()[1], a, level)
+            else:
+                a = self._merge(a, None, level)
+            level += 1
+        while (1 << level) < self.W:   # every lane of an array's class takes the whole sum
+            a, level = a + self.roll(a, 1 << level, 1), level + 1
+        return a
+
+
+@functools.partial(jax.jit, static_argnames=("hb", "interpret"))   # a program's layers share ONE trace of the body
+def _mamba2_step_pallas(xt, rows, pool, slot, n_live, hb: int, interpret: bool):
+    """``xt`` ``[R, steps, P, lanes]``: lane ``j`` of a step's tile holds ``dt
+    x`` of its head ``j``, the head's width on sublanes; ``rows`` ``[R, steps,
+    2 gs + gs hb, N]``: the step's ``gs`` groups' ``B``, then their ``C``, then
+    each of its heads' decay over every lane (0 for a row that starts from
+    zero). Returns ``y`` laid as ``xt``, and the pool."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    R, G, P, _ = cols.shape
+    R, steps, P, lanes = xt.shape
     N = pool.shape[-1]
+    gs = rows.shape[2] // (2 + hb)
+    nh = gs * hb
+    if N % _LANES and N & (N - 1):
+        raise ValueError(f"mamba2_recurrent_step: a state of {N} is neither whole tiles of {_LANES} lanes nor a power of two")
+    W = min(N, _LANES)             # the lanes a tree of sums runs over, and the heads it takes
 
     def live_row(r, n_ref):
         return jnp.maximum(jnp.minimum(r, n_ref[0] - 1), 0)
 
-    def pool_map(j, r, slot_ref, fresh_ref, n_ref):
+    def pool_map(j, r, slot_ref, n_ref):
         return slot_ref[live_row(r, n_ref)], j, 0, 0
 
-    def row_map(j, r, slot_ref, fresh_ref, n_ref):
+    def row_map(j, r, slot_ref, n_ref):
         return live_row(r, n_ref), j, 0, 0
 
-    def kernel(slot_ref, fresh_ref, n_ref, cols_ref, bc_ref, s_in, o_ref, s_out):
+    def kernel(slot_ref, n_ref, x_ref, rows_ref, s_in, o_ref, s_out):
         r = pl.program_id(1)
         n = n_ref[0]
 
         @pl.when(r < n)
         def _live():
-            keep = jnp.where(fresh_ref[r] > 0, 0.0, 1.0)
-            b_row, c_row = bc_ref[0, 0, 0:1, :], bc_ref[0, 0, 1:2, :]
-            for h in range(hb):
-                S = s_in[0, h] * (keep * bc_ref[0, 0, 2 + h:3 + h, :]) + cols_ref[0, 0, :, h:h + 1] * b_row
-                s_out[0, h] = S
-                o_ref[0, 0, :, h:h + 1] = jnp.sum(S * c_row, axis=1, keepdims=True)
+            # a head at a time, whole: the products stay on the vector unit in float32, and the heads' ``y`` leave
+            # as ONE tile, a head a lane (a pass over 8 sublanes of every head at a time under a loop read 1,883 us
+            # for 256 rows where this reads 1,736: a loop's passes do not overlap, PERF.md section 6, PR 52)
+            lane = lax.broadcasted_iota(jnp.int32, (P, W), 1)
+            for first in range(0, nh, W):
+                x = x_ref[0, 0, :, first:first + W]
+                sums = _LaneSums(lane, pltpu.roll)
+                for head in range(first, min(first + W, nh)):
+                    g = head // hb
+                    if head % hb == 0 or head == first:   # a group's B and C once for its heads
+                        b_row, c_row = rows_ref[0, 0, g:g + 1, :], rows_ref[0, 0, gs + g:gs + g + 1, :]
+                    S = s_in[0, head] * rows_ref[0, 0, 2 * gs + head:2 * gs + head + 1, :] \
+                        + x[:, head - first:head - first + 1] * b_row
+                    s_out[0, head] = S
+                    prod = S * c_row
+                    sums.add(functools.reduce(jnp.add, [prod[:, c:c + W] for c in range(0, N, W)]))   # a state of several tiles of lanes, folded
+                o_ref[0, 0, :, first:first + W] = sums.result()
 
         @pl.when((n == 0) & (r == 0))
         def _untouched():  # no live row at all: the one block this grid maps goes back as it came
             s_out[...] = s_in[...]
 
+    of_row = lambda a: pl.BlockSpec((1, 1) + a.shape[2:], row_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3, grid=(G, R),
-        in_specs=[pl.BlockSpec((1, 1, P, 128), row_map), pl.BlockSpec((1, 1, bc.shape[2], N), row_map),
-                  pl.BlockSpec((1, hb, P, N), pool_map)],
-        out_specs=[pl.BlockSpec((1, 1, P, 128), row_map), pl.BlockSpec((1, hb, P, N), pool_map)])
+        num_scalar_prefetch=2, grid=(steps, R),
+        in_specs=[of_row(xt), of_row(rows), pl.BlockSpec((1, nh, P, N), pool_map)],
+        out_specs=[of_row(xt), pl.BlockSpec((1, nh, P, N), pool_map)])
     kwargs = {}
     if not interpret:
-        kwargs["compiler_params"] = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
+        kwargs["compiler_params"] = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"),
+                                                         vmem_limit_bytes=_STEP_VMEM_BYTES + (8 << 20))   # and the body's own
     o, pool = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((R, G, P, 128), jnp.float32), jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
-        input_output_aliases={5: 1}, interpret=interpret, name=KERNEL_NAMES[0], **kwargs)(
-            slot, fresh, n_live, cols, bc, pool)
+        out_shape=[jax.ShapeDtypeStruct(xt.shape, jnp.float32), jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        input_output_aliases={4: 1}, interpret=interpret, name=KERNEL_NAMES[0], **kwargs)(
+            slot, n_live, xt, rows, pool)
     return o, pool
 
 
@@ -165,13 +278,15 @@ def mamba2_step(x, B, C, dt, A, pool, slot, fresh, n_live, use_pallas: bool = Fa
     dt = f32(dt)
     xdt, decay = f32(x) * dt[..., None], jnp.exp(dt * f32(A))
     if use_pallas or interpret:
-        # [R, G, hb, P] -> [R, G, P, hb] -> lanes padded to 128
-        cols = jnp.pad(jnp.swapaxes(xdt.reshape(R, G, hb, P), 2, 3), ((0, 0), (0, 0), (0, 0), (0, 128 - hb)))
-        bc = jnp.concatenate([f32(B)[:, :, None, :], f32(C)[:, :, None, :],
-                              jnp.broadcast_to(decay.reshape(R, G, hb, 1), (R, G, hb, N))], axis=2)
-        bc = jnp.pad(bc, ((0, 0), (0, 0), (0, -(2 + hb) % 8), (0, 0)))
-        o, pool = _mamba2_step_pallas(cols, bc, pool, slot, fresh, n_live, hb, interpret)
-        return jnp.swapaxes(o[..., :hb], 2, 3).reshape(R, H, P), pool
+        gs = _groups_per_step(G, hb, P, N)
+        steps, nh = G // gs, gs * hb
+        # [R, steps, nh, P] -> [R, steps, P, nh]: ONE transposition a row, the lanes padded to whole tiles
+        xt = jnp.pad(jnp.swapaxes(xdt.reshape(R, steps, nh, P), 2, 3), ((0, 0), ) * 3 + ((0, _tile_lanes(nh) - nh), ))
+        starts = jnp.where((fresh > 0)[:, None], 0.0, decay)   # a fresh row: whatever the slot held, times zero
+        rows = jnp.concatenate([f32(B).reshape(R, steps, gs, N), f32(C).reshape(R, steps, gs, N),
+                                jnp.broadcast_to(starts.reshape(R, steps, nh, 1), (R, steps, nh, N))], axis=2)
+        o, pool = _mamba2_step_pallas(xt, rows, pool, slot, n_live, hb, interpret)
+        return jnp.swapaxes(o[..., :nh], 2, 3).reshape(R, H, P), pool
     live = jnp.arange(R) < n_live[0]
     Bh, Ch = jnp.repeat(f32(B), hb, axis=1), jnp.repeat(f32(C), hb, axis=1)
     S = jnp.where((fresh > 0)[:, None, None, None], 0.0, pool[slot])

@@ -232,6 +232,68 @@ def test_the_recurrent_step_advances_the_live_rows_alone(interpret, n_live):
     assert np.abs(np.asarray(got) - want).max() < 2e-6   # (no live row: every slot exactly as it was)
 
 
+def _stepped_rows(x, B, C, dt, A, pool, slot, fresh, n_live):
+    """The first ``n_live`` rows one token each through the recurrence as written: ``(y a row, pool)``."""
+    want, ys = np.array(pool), []
+    for r in range(n_live):
+        S0 = jnp.zeros(pool.shape[1:]) if fresh[r] else pool[slot[r]]
+        yr, want[int(slot[r])] = mamba2.recurrence_reference(x[r:r + 1], B[r:r + 1], C[r:r + 1], dt[r:r + 1], A, S0)
+        ys.append(np.asarray(yr[0]))
+    return ys, want
+
+
+@pytest.mark.parametrize("n_live", [0, 1, 5])
+@pytest.mark.parametrize("groups_a_step", [1, 2])
+def test_the_recurrent_steps_body_with_fewer_groups_a_step_than_the_model_has(monkeypatch, groups_a_step, n_live):
+    """The kernel's own body on a grid of several steps a row (the VMEM budget
+    patched down to what one and what two of four groups take): live rows
+    with their slots out of order, a fresh one among them, dead rows behind
+    them, against the recurrence; no other slot moves."""
+    H, G, P, N = 8, 4, 8, 16
+    x, B, C, dt, A, pool = _scan_inputs(T=5, H=H, G=G, P=P, N=N, slots=9, seed=3)
+    monkeypatch.setattr(mamba2, "_STEP_VMEM_BYTES", mamba2._step_vmem_bytes(groups_a_step, H // G, P, N))
+    assert mamba2._groups_per_step(G, H // G, P, N) == groups_a_step
+    slot, fresh = jnp.asarray([7, 2, 8, 0, 4]), jnp.asarray([0, 0, 1, 0, 1])
+    y, got = mamba2.mamba2_step(x, B, C, dt, A, pool, slot, fresh, n_live, interpret=True)
+    ys, want = _stepped_rows(x, B, C, dt, A, pool, slot, fresh, n_live)
+    assert all(np.abs(np.asarray(y[r]) - yr).max() < 2e-6 for r, yr in enumerate(ys))
+    assert np.abs(np.asarray(got) - want).max() < 2e-6
+    untouched = [s for s in range(9) if s not in [int(a) for a in slot[:n_live]]]
+    assert np.array_equal(np.asarray(got)[untouched], np.asarray(pool)[untouched])
+
+
+@pytest.mark.parametrize("H,G,P,N", [(6, 2, 8, 16), (12, 2, 16, 256), (8, 4, 8, 128)],
+                         ids=["three_heads_a_group", "a_state_of_two_lane_tiles", "a_state_of_one_lane_tile"])
+def test_the_recurrent_steps_lane_sums_at_other_head_counts_and_state_widths(H, G, P, N):
+    """The kernel's body where the heads of a step are no power of two (the
+    tree of sums merges with nothing) and where a state row is two tiles of
+    lanes (folded before the tree) or exactly one, against the recurrence."""
+    x, B, C, dt, A, pool = _scan_inputs(T=3, H=H, G=G, P=P, N=N, slots=5, seed=5)
+    slot, fresh = jnp.asarray([4, 0, 2]), jnp.asarray([0, 1, 0])
+    y, got = mamba2.mamba2_step(x, B, C, dt, A, pool, slot, fresh, 3, interpret=True)
+    ys, want = _stepped_rows(x, B, C, dt, A, pool, slot, fresh, 3)
+    assert all(np.abs(np.asarray(y[r]) - yr).max() < 2e-5 * max(1.0, float(np.abs(yr).max())) for r, yr in enumerate(ys))
+    assert np.abs(np.asarray(got) - want).max() < 2e-6
+    with pytest.raises(ValueError, match="neither whole tiles"):
+        mamba2.mamba2_step(x, B[..., :12], C[..., :12], dt, A, pool[..., :12], slot, fresh, 3, interpret=True)
+
+
+@pytest.mark.parametrize("name,G,hb,P,N,want", [
+    ("published", 8, 8, 64, 128, 8),          # a row's whole layer state, 2 MiB, one block
+    ("tiny_twin", 2, 2, 8, 16, 2),
+    ("state_four_times_as_wide", 8, 8, 64, 512, 4),
+    ("head_four_times_as_wide", 8, 8, 256, 128, 4),
+    ("six_groups_sixteen_times", 6, 8, 256, 512, 1),
+])
+def test_the_groups_a_grid_step_follow_from_the_static_shapes_alone(name, G, hb, P, N, want):
+    gs = mamba2._groups_per_step(G, hb, P, N)
+    assert gs == want and G % gs == 0
+    assert gs == 1 or mamba2._step_vmem_bytes(gs, hb, P, N) <= mamba2._STEP_VMEM_BYTES
+    # the next divisor up would not fit
+    larger = [d for d in range(gs + 1, G + 1) if G % d == 0]
+    assert not larger or mamba2._step_vmem_bytes(larger[0], hb, P, N) > mamba2._STEP_VMEM_BYTES
+
+
 def test_a_tile_plan_gives_chunk_rows_their_own_tiles_and_one_token_rows_none():
     n_tok = np.array([5, 1, 0, 37, 1, 2, 0, 0], np.int32)
     row, tok0, cnt, first, n_tiles = mamba2.tile_plan(n_tok, 64, 8, xp=np)

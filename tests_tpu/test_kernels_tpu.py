@@ -1096,19 +1096,25 @@ _SELECTIVE_SCAN_BATCHES = {
 }
 
 
-@pytest.mark.parametrize("form", ["recurrent_step", *_SELECTIVE_SCAN_BATCHES])
+# a decode horizon's full house, and a ``put``'s few one-token rows: fewer grid steps to hide a call's start under
+_RECURRENT_STEP_ROWS = {"recurrent_step": 256, "recurrent_step_64_rows": 64, "recurrent_step_17_rows": 17}
+
+
+@pytest.mark.parametrize("form", [*_RECURRENT_STEP_ROWS, *_SELECTIVE_SCAN_BATCHES])
 def test_the_selective_scans_kernels_at_the_cells_shapes_on_chip(form):
     """``ops/pallas/mamba2.py`` at ``nemotron-3-nano-30b-a3b.decode-heavy-256``'s
     widths (64 heads of 64 x 128 in 8 groups, float32 state): the recurrent
-    step of 256 one-token rows (``mamba2_step``), and ``mamba2_chunks`` over a
-    770-token chunk among 254 one-token rows and over three chunks alone, in a
-    1,024-token program. Each against the rule token by token from the same
-    pool, with microseconds a call and the state's bytes over them."""
+    step of 256, 64 and 17 one-token rows (``mamba2_step``), and
+    ``mamba2_chunks`` over a 770-token chunk among 254 one-token rows and over
+    three chunks alone, in a 1,024-token program. Each against the rule token
+    by token from the same pool, with microseconds a call and the state's
+    bytes over them."""
     from deepspeed_tpu.ops.pallas import mamba2
 
-    H, G, P, N, slots, R = 64, 8, 64, 128, 300, 256
+    H, G, P, N, slots = 64, 8, 64, 128, 300
+    R = _RECURRENT_STEP_ROWS.get(form, 256)
     rng = np.random.default_rng(0)
-    T, n_tok = (R, np.ones(R, np.int64)) if form == "recurrent_step" else (1024, np.asarray(_SELECTIVE_SCAN_BATCHES[form]))
+    T, n_tok = (R, np.ones(R, np.int64)) if form in _RECURRENT_STEP_ROWS else (1024, np.asarray(_SELECTIVE_SCAN_BATCHES[form]))
     x = [jnp.asarray(a, jnp.float32) for a in (rng.normal(size=(T, H, P)), rng.normal(size=(T, G, N)),
                                                rng.normal(size=(T, G, N)), np.exp(rng.uniform(np.log(3e-4), np.log(0.3), (T, H))))]
     A = -jnp.asarray(rng.uniform(1, 16, H), jnp.float32)
@@ -1116,7 +1122,7 @@ def test_the_selective_scans_kernels_at_the_cells_shapes_on_chip(form):
     slot = jnp.asarray(rng.permutation(slots)[:R], jnp.int32)
     fresh = jnp.asarray(rng.integers(0, 2, size=R), jnp.int32)
     # the batch as ARGUMENTS, as the engine hands it over: closed over, XLA folds the plan into constants
-    if form == "recurrent_step":
+    if form in _RECURRENT_STEP_ROWS:
         fn = lambda x, B, C, dt, pool, slot, fresh, n: mamba2.mamba2_step(x, B, C, dt, A, pool, slot, fresh, jnp.sum(n),
                                                                           use_pallas=True)
     else:
