@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from ...moe.grouped import merge_routing_stats
+from ...monitor import scopes
 from .sampling import diffusion_candidates, diffusion_quota, diffusion_unmask
 
 
@@ -80,34 +81,42 @@ def build_block_program(step_fn, *, block_size: int, mask_id: int, denoising_ste
     fused = cap > 1
 
     def merge(stats, new):
-        return tuple(merge_routing_stats(a, b) for a, b in zip(stats, new))
+        with jax.named_scope(scopes.MOE):
+            return tuple(merge_routing_stats(a, b) for a, b in zip(stats, new))
 
     def fwd(params, packed, pools):
-        packed, n_blocks = packed[:-1], packed[-1]
-        ids0, seq_idx, pos0 = packed[0:T], packed[T:2 * T], packed[2 * T:3 * T]
-        valid = packed[3 * T:4 * T] > 0
-        tables, last_idx = packed[4 * T:-S], packed[-S:]
+        with jax.named_scope(scopes.EMBED):  # the descriptors, and below every change made to them
+            packed, n_blocks = packed[:-1], packed[-1]
+            ids0, seq_idx, pos0 = packed[0:T], packed[T:2 * T], packed[2 * T:3 * T]
+            valid = packed[3 * T:4 * T] > 0
+            tables, last_idx = packed[4 * T:-S], packed[-S:]
         beside = lambda before, block: rows_beside(before, block, B)
 
         def unmask(i, b, ids, logits, masked_fed, probe):
             """What denoise forward ``i`` of block ``b`` makes of the ids it was fed."""
-            tok, conf = diffusion_candidates(logits)
-            masked = valid & (ids == mask_id)
-            choose = diffusion_unmask(conf.reshape(S, B), masked.reshape(S, B), remasking, quota[i],
-                                      threshold, i == steps - 1)
-            if probe:
-                probe = (probe[0].at[b, i].set(ids.reshape(S, B)[rows]),
-                         probe[1].at[b, i].set(logits.reshape(S, B, vocab)[rows].astype(jnp.float32)))
-            return (jnp.where(choose.reshape(T), tok, ids), masked_fed + jnp.sum(masked, dtype=jnp.int32), probe)
+            with jax.named_scope(scopes.SAMPLE):
+                tok, conf = diffusion_candidates(logits)
+                masked = valid & (ids == mask_id)
+                choose = diffusion_unmask(conf.reshape(S, B), masked.reshape(S, B), remasking, quota[i],
+                                          threshold, i == steps - 1)
+                if probe:
+                    probe = (probe[0].at[b, i].set(ids.reshape(S, B)[rows]),
+                             probe[1].at[b, i].set(logits.reshape(S, B, vocab)[rows].astype(jnp.float32)))
+                return (jnp.where(choose.reshape(T), tok, ids), masked_fed + jnp.sum(masked, dtype=jnp.int32), probe)
 
         def block(b, carry):
             pl, stats, masked_fed, probe, pending, out, forwards = carry
-            at_block = packed.at[2 * T:3 * T].add(b * B)  # every position a block further
-            feed = lambda ids: at_block.at[0:T].set(ids)
+            with jax.named_scope(scopes.EMBED):
+                at_block = packed.at[2 * T:3 * T].add(b * B)  # every position a block further
+
+            def feed(ids):
+                with jax.named_scope(scopes.EMBED):
+                    return at_block.at[0:T].set(ids)
 
             def masks_left(c):
                 i, ids = c[0], c[1]
-                return (i < steps) & jnp.any(valid & (ids == mask_id))
+                with jax.named_scope(scopes.SAMPLE):
+                    return (i < steps) & jnp.any(valid & (ids == mask_id))
 
             def denoise(c):
                 i, ids, pl, stats, masked_fed, probe = c
@@ -115,20 +124,23 @@ def build_block_program(step_fn, *, block_size: int, mask_id: int, denoising_ste
                 ids, masked_fed, probe = unmask(i, b, ids, logits, masked_fed, probe)
                 return i + 1, ids, pl, merge(stats, new), masked_fed, probe
 
-            i, ids = jnp.int32(0), jnp.where(b == 0, ids0, jnp.int32(mask_id))
+            with jax.named_scope(scopes.SAMPLE):
+                i, ids = jnp.int32(0), jnp.where(b == 0, ids0, jnp.int32(mask_id))
             if fused:
                 # the block before's commit and this block's first denoise forward in one: a row's 2B tokens
                 # end at 2 * (its last index) + 1, and the logits gathered are its last B tokens', the block's
-                pos = pos0 + b * B
-                both = jnp.concatenate([beside(pending, ids), beside(seq_idx, seq_idx),
-                                        beside(jnp.maximum(pos - B, 0), pos), beside(valid & (b > 0), valid),
-                                        tables, 2 * last_idx + 1]).astype(jnp.int32)
+                with jax.named_scope(scopes.EMBED):
+                    pos = pos0 + b * B
+                    both = jnp.concatenate([beside(pending, ids), beside(seq_idx, seq_idx),
+                                            beside(jnp.maximum(pos - B, 0), pos), beside(valid & (b > 0), valid),
+                                            tables, 2 * last_idx + 1]).astype(jnp.int32)
                 logits, pl, *new = step_fn(params, both, pl, 2 * T, S, gather_k=B - 1, moe_stats=moe)
                 ids, masked_fed, probe = unmask(i, b, ids, logits, masked_fed, probe)
                 i, stats = i + 1, merge(stats, new)
             n, ids, pl, stats, masked_fed, probe = jax.lax.while_loop(
                 masks_left, denoise, (i, ids, pl, stats, masked_fed, probe))
-            return pl, stats, masked_fed, probe, ids, out.at[b].set(ids.reshape(S, B)), forwards.at[b].set(n)
+            with jax.named_scope(scopes.SAMPLE):
+                return pl, stats, masked_fed, probe, ids, out.at[b].set(ids.reshape(S, B)), forwards.at[b].set(n)
 
         probe0 = ()
         if probe_rows:
@@ -138,9 +150,11 @@ def build_block_program(step_fn, *, block_size: int, mask_id: int, denoising_ste
             0, n_blocks, block, (pools, stats0, jnp.int32(0), probe0, ids0, jnp.zeros((cap, S, B), jnp.int32),
                                  jnp.zeros(cap, jnp.int32)))
         # the commit of the call's last block: the cache must hold the K/V of its FINAL ids
-        final = packed.at[2 * T:3 * T].add(jnp.maximum(n_blocks - 1, 0) * B).at[0:T].set(last)
+        with jax.named_scope(scopes.EMBED):
+            final = packed.at[2 * T:3 * T].add(jnp.maximum(n_blocks - 1, 0) * B).at[0:T].set(last)
         _, pools, *new = step_fn(params, final, pools, T, S, moe_stats=moe, kv_only=True)
-        tokens = out.transpose(1, 0, 2).reshape(S, cap * B)
+        with jax.named_scope(scopes.SAMPLE):
+            tokens = out.transpose(1, 0, 2).reshape(S, cap * B)
         return (tokens, forwards, masked_fed, *merge(stats, new), *probe), pools
 
     return fwd

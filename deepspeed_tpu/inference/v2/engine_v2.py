@@ -23,7 +23,7 @@ from ...monitor.goodput import get_goodput
 from ...monitor.health import get_health
 from ...monitor.memory import get_memory, tree_device_bytes
 from ...monitor.metrics import get_metrics
-from ...monitor.roofline import get_roofline
+from ...monitor import scopes
 from ...monitor.trace import (NULL_SPAN, get_tracer, pop_compile_source,
                               push_compile_source)
 from ...moe.grouped import merge_routing_stats
@@ -34,7 +34,7 @@ from ...ops.pallas.mamba2 import (KERNEL_NAMES as MAMBA_KERNEL_NAMES, TILE as MA
 from ...ops.pallas.paged_attention import decode_kv_counts, kernel_choice, tiled_kv_counts
 from ...utils.logging import log_dist
 from .config_v2 import RaggedInferenceEngineConfig
-from .model_implementations.sparse_index import SCOPE as INDEX_SCOPE, index_tile
+from .model_implementations.sparse_index import index_tile
 from .model_implementations.flat_model import (expanded_batch, expanded_plan, expanded_slots, expanded_workspace_bytes,
                                                 ragged_forward)
 from .ragged.ragged_manager import DSStateManager
@@ -524,8 +524,6 @@ class InferenceEngineV2:
         tr = get_tracer()
         reg = get_metrics()
         t0 = time.perf_counter() if reg.enabled else 0.0
-        rf = get_roofline()
-        t_rf = time.perf_counter() if rf.enabled else 0.0
         batch_tokens = [np.asarray(t, np.int32).reshape(-1) for t in batch_tokens]
         if any(t.size == 0 for t in batch_tokens):
             # an empty chunk would alias the PREVIOUS row's last_idx in the
@@ -597,8 +595,9 @@ class InferenceEngineV2:
                     # upload) instead of one host-to-device transfer per array
                     (out, *stats), pools = fn(self.params, jnp.asarray(rb.packed()), kv.pools())
                 kv.update(*pools)
-                if sd is not NULL_SPAN:
-                    sd.set_args(compiled=len(self._compiled) > n_programs)
+                if sd is not NULL_SPAN:  # the program by its ``_compiled`` key: the device's operations until the fetch are its
+                    sd.set_args(compiled=len(self._compiled) > n_programs,
+                                program="put:%d:%d:%s" % (t_bucket, s_bucket, mode or "logits"))
             # counts at the boundary, and the uids so that a request-scoped
             # trace can attribute every engine forward to the requests
             # composing it (capped: span args are payload, not a table)
@@ -626,12 +625,6 @@ class InferenceEngineV2:
                 if mode == "probe":  # (logits, (positions, selection, attention output)) of the live rows
                     out = (out, tuple(np.asarray(a)[:rb.n_seqs] for a in stats[1]))
             _observe(sp, lambda: self._moe_span_args([(held["tokens"], t_bucket, 1, 0)], seen[0]) if seen else {}, held)
-        if rf.enabled and block:
-            # wall join through the blocking host fetch — the same window the
-            # outer put() books as prefill/decode-active in the goodput ledger,
-            # so the roofline and goodput accountings reconcile
-            rf.note_wall(f"put/t{t_bucket}/s{s_bucket}/{mode or 'logits'}",
-                         time.perf_counter() - t_rf)
         if reg.enabled and block:
             # a STEP's latency, not a time to first token (the operator's TTFT
             # is gateway/ttft_ms_<class>, from admission); block=False measures
@@ -872,7 +865,7 @@ class InferenceEngineV2:
             parts = [] if None in choices else ["%s:%d:%s" % (c["kernel"], max(c["q_tile"], c["blocks_per_step"]), c["rule"])
                                                 for c in choices]
             if self._sparse:  # the indexer that made the selection the paged kernel read by
-                parts.append("%s:%d:top%d" % (INDEX_SCOPE, index_tile(T), self.model_config.sparse_topk))
+                parts.append("%s:%d:top%d" % (scopes.SPARSE_INDEX, index_tile(T), self.model_config.sparse_topk))
             if self._lightning:  # one form a program: the chunk scan of a put, the recurrent step of a horizon
                 parts.append("%s:1:one-token-rows" % LIGHTNING_KERNEL_NAMES[0] if horizon else
                              "%s:%d:ragged" % (LIGHTNING_KERNEL_NAMES[1], LIGHTNING_TILE))
@@ -959,8 +952,6 @@ class InferenceEngineV2:
         tr = get_tracer()
         reg = get_metrics()
         t0 = time.perf_counter() if reg.enabled else 0.0
-        rf = get_roofline()
-        t_rf = time.perf_counter() if rf.enabled else 0.0
         uids = list(batch_uids)
         S = len(uids)
         with tr.span("serving/decode", tid="serving") as sp:
@@ -1010,11 +1001,10 @@ class InferenceEngineV2:
 
             kv = self.state_manager.kv_cache
             s_bucket = rb.token_ids.shape[0]
-            rf_sampled = sampling is not None and not all_greedy(sampling)
-            rf_bucket = f"decode/s{s_bucket}/n{n_steps}{'/sampled' if rf_sampled else ''}"
+            sampled = sampling is not None and not all_greedy(sampling)
             with tr.span("serving/engine_dispatch", tid="serving") as sd:
                 n_programs = len(self._compiled)
-                if rf_sampled:
+                if sampled:
                     fn = self._get_compiled_decode(s_bucket, n_steps, sampled=True)
                     samp_f, seeds = pack_sampling(sampling, uids, s_bucket)
                     (toks, *stats), pools = fn(self.params, jnp.asarray(rb.packed()),
@@ -1026,7 +1016,8 @@ class InferenceEngineV2:
                     (toks, *stats), pools = fn(self.params, jnp.asarray(rb.packed()), kv.pools())
                 kv.update(*pools)
                 if sd is not NULL_SPAN:
-                    sd.set_args(compiled=len(self._compiled) > n_programs)
+                    sd.set_args(compiled=len(self._compiled) > n_programs,
+                                program="decode:%d:%d%s" % (s_bucket, n_steps, ":sampled" if sampled else ""))
             # without the host fetch the span is dispatch only: the blocked
             # flag discloses it
             held = _observe(sp, lambda: dict(
@@ -1071,8 +1062,6 @@ class InferenceEngineV2:
                         seq.post_forward()
                         self.state_manager.publish_sequence(seq)
             _observe(sp, lambda: self._moe_span_args([(S, s_bucket, int(n_steps), 0)], seen[0]) if seen else {}, held)
-        if rf.enabled and block:
-            rf.note_wall(rf_bucket, time.perf_counter() - t_rf)
         if reg.enabled and block:
             dt = time.perf_counter() - t0
             reg.histogram("serving/decode_ms").observe(dt * 1e3)
@@ -1157,7 +1146,8 @@ class InferenceEngineV2:
                 (toks, *counts), pools = fn(self.params, jnp.asarray(np.append(rb.packed(), np.int32(n_blocks))), kv.pools())
                 kv.update(*pools)
                 if sd is not NULL_SPAN:
-                    sd.set_args(compiled=len(self._compiled) > n_programs)
+                    sd.set_args(compiled=len(self._compiled) > n_programs,
+                                program="diffuse:%d:%d" % (s_bucket, self._block_program_cap(n_blocks, probe)))
             # the attention call of the forwards of S x B tokens, then of those that carry a commit (S x 2B)
             held = _observe(sp, lambda: dict(
                 rows=S, tokens=S * n_steps, bucket_rows=int(s_bucket), bucket_tokens=int(s_bucket * B),
@@ -1307,57 +1297,60 @@ class InferenceEngineV2:
         read back what the indexer chose and what the paged kernel made of it)."""
         from .ragged.ragged_wrapper import unpack_descriptors
 
-        token_ids, seq_idx, pos, valid, tables, last_idx = unpack_descriptors(
-            packed, t_bucket, s_bucket, self._max_blocks_per_seq)
+        with jax.named_scope(scopes.EMBED):
+            token_ids, seq_idx, pos, valid, tables, last_idx = unpack_descriptors(
+                packed, t_bucket, s_bucket, self._max_blocks_per_seq)
         extra = {}
         if tree_meta is not None:
             assert gather_k, "tree_meta requires the gather_k verify layout"
-            T = t_bucket
-            k1 = gather_k + 1
-            pos_ids = tree_meta[0:T]
-            branch = tree_meta[T:2 * T]
-            depth = tree_meta[2 * T:3 * T]
-            C = self._max_blocks_per_seq * self.config.kv_block_size
-            # chunk-local flat node index from the packed layout alone:
-            # every verify chunk is exactly k1 tokens ending at last_idx
-            node_idx = jnp.arange(T, dtype=jnp.int32) - (last_idx[seq_idx] - gather_k)
-            start = pos - node_idx                    # committed length, per token
-            ctx_p = jnp.arange(C, dtype=jnp.int32)[None, :]
-            j = ctx_p - start[:, None]                # ctx slot's flat node index
-            jj = jnp.clip(j, 0, gather_k)
-            # per-sequence node tables scattered from this batch's own rows
-            b_tbl = jnp.zeros((s_bucket, k1), jnp.int32).at[seq_idx, node_idx].set(
-                branch, mode="drop")
-            d_tbl = jnp.zeros((s_bucket, k1), jnp.int32).at[seq_idx, node_idx].set(
-                depth, mode="drop")
-            cb = jnp.take_along_axis(b_tbl[seq_idx], jj, axis=1)   # [T, C]
-            cd = jnp.take_along_axis(d_tbl[seq_idx], jj, axis=1)
-            in_tree = (j >= 0) & (j <= gather_k)
-            # ancestor visibility: committed prefix | root (depth 0) | an
-            # EARLIER node of my own branch — a sibling branch's KV sits at
-            # an earlier slot but must stay invisible
-            vis_tree = in_tree & (cd <= depth[:, None]) & ((cd == 0) | (cb == branch[:, None]))
-            mask = (ctx_p < start[:, None]) | vis_tree
-            if getattr(self.model_config, "per_layer_attention", False):
-                raise NotImplementedError("token-tree verification builds one visibility mask for all "
-                                          "layers; this model's layers differ in window (layer_types)")
-            window = getattr(self.model_config, "sliding_window", None)
-            if window:
-                ctx_pid_t = jnp.where(in_tree, start[:, None] + cd, ctx_p)
-                mask = mask & (pos_ids[:, None] - ctx_pid_t < int(window))
-            # ctx logical positions per sequence (alibi distances)
-            start_s = pos[jnp.maximum(last_idx, 0)] - gather_k     # [S]
-            js = ctx_p - start_s[:, None]
-            jjs = jnp.clip(js, 0, gather_k)
-            ds = jnp.take_along_axis(d_tbl, jjs, axis=1)
-            ctx_pid = jnp.where((js >= 0) & (js <= gather_k), start_s[:, None] + ds,
-                                jnp.broadcast_to(ctx_p, (s_bucket, C)))
-            extra = {"pos_ids": pos_ids, "attn_mask": mask, "ctx_pos_ids": ctx_pid}
+            with jax.named_scope(scopes.MIXER):
+                T = t_bucket
+                k1 = gather_k + 1
+                pos_ids = tree_meta[0:T]
+                branch = tree_meta[T:2 * T]
+                depth = tree_meta[2 * T:3 * T]
+                C = self._max_blocks_per_seq * self.config.kv_block_size
+                # chunk-local flat node index from the packed layout alone:
+                # every verify chunk is exactly k1 tokens ending at last_idx
+                node_idx = jnp.arange(T, dtype=jnp.int32) - (last_idx[seq_idx] - gather_k)
+                start = pos - node_idx                    # committed length, per token
+                ctx_p = jnp.arange(C, dtype=jnp.int32)[None, :]
+                j = ctx_p - start[:, None]                # ctx slot's flat node index
+                jj = jnp.clip(j, 0, gather_k)
+                # per-sequence node tables scattered from this batch's own rows
+                b_tbl = jnp.zeros((s_bucket, k1), jnp.int32).at[seq_idx, node_idx].set(
+                    branch, mode="drop")
+                d_tbl = jnp.zeros((s_bucket, k1), jnp.int32).at[seq_idx, node_idx].set(
+                    depth, mode="drop")
+                cb = jnp.take_along_axis(b_tbl[seq_idx], jj, axis=1)   # [T, C]
+                cd = jnp.take_along_axis(d_tbl[seq_idx], jj, axis=1)
+                in_tree = (j >= 0) & (j <= gather_k)
+                # ancestor visibility: committed prefix | root (depth 0) | an
+                # EARLIER node of my own branch — a sibling branch's KV sits at
+                # an earlier slot but must stay invisible
+                vis_tree = in_tree & (cd <= depth[:, None]) & ((cd == 0) | (cb == branch[:, None]))
+                mask = (ctx_p < start[:, None]) | vis_tree
+                if getattr(self.model_config, "per_layer_attention", False):
+                    raise NotImplementedError("token-tree verification builds one visibility mask for all "
+                                              "layers; this model's layers differ in window (layer_types)")
+                window = getattr(self.model_config, "sliding_window", None)
+                if window:
+                    ctx_pid_t = jnp.where(in_tree, start[:, None] + cd, ctx_p)
+                    mask = mask & (pos_ids[:, None] - ctx_pid_t < int(window))
+                # ctx logical positions per sequence (alibi distances)
+                start_s = pos[jnp.maximum(last_idx, 0)] - gather_k     # [S]
+                js = ctx_p - start_s[:, None]
+                jjs = jnp.clip(js, 0, gather_k)
+                ds = jnp.take_along_axis(d_tbl, jjs, axis=1)
+                ctx_pid = jnp.where((js >= 0) & (js <= gather_k), start_s[:, None] + ds,
+                                    jnp.broadcast_to(ctx_p, (s_bucket, C)))
+                extra = {"pos_ids": pos_ids, "attn_mask": mask, "ctx_pos_ids": ctx_pid}
         if gather_k:
-            idx = last_idx[:, None] - gather_k + jnp.arange(gather_k + 1, dtype=jnp.int32)
-            # padding rows carry last_idx 0 — clamp their (negative) indices;
-            # the caller slices the garbage rows off with [:n_seqs]
-            last_idx = jnp.maximum(idx, 0).reshape(-1)
+            with jax.named_scope(scopes.LM_HEAD):
+                idx = last_idx[:, None] - gather_k + jnp.arange(gather_k + 1, dtype=jnp.int32)
+                # padding rows carry last_idx 0 — clamp their (negative) indices;
+                # the caller slices the garbage rows off with [:n_seqs]
+                last_idx = jnp.maximum(idx, 0).reshape(-1)
         if self._state_layers:  # (never beside int8: the last two pools are the state's)
             if gather_k:
                 raise NotImplementedError("a speculative verify step (a tree of drafts among them) of a model with a "
@@ -1472,8 +1465,6 @@ class InferenceEngineV2:
         tr = get_tracer()
         reg = get_metrics()
         t0 = time.perf_counter() if reg.enabled else 0.0
-        rf = get_roofline()
-        t_rf = time.perf_counter() if rf.enabled else 0.0
         uids = list(batch_uids)
         S = len(uids)
         if self._state_layers or self._sparse:
@@ -1583,7 +1574,9 @@ class InferenceEngineV2:
                     out, pools = fn(self.params, jnp.asarray(rb.packed()), kv.pools())
                 kv.update(*pools)
                 if sd is not NULL_SPAN:
-                    sd.set_args(compiled=len(self._compiled) > n_programs)
+                    sd.set_args(compiled=len(self._compiled) > n_programs,
+                                program="verify:%d:%d:%d%s%s" % (t_bucket, s_bucket, n_new - 1, ":tree" if tree else "",
+                                                                 ":sampled" if sampled else ""))
 
             held = _observe(sp, lambda: dict(
                 rows=S, tokens=S * n_new, bucket_tokens=int(t_bucket), bucket_rows=int(s_bucket), steps=1, k=k,
@@ -1674,12 +1667,6 @@ class InferenceEngineV2:
             _observe(sp, lambda: dict(drafted=drafted, accepted=accepts[:16]), held)
         self._spec_totals["drafted"] += drafted
         self._spec_totals["accepted"] += accepted
-        if rf.enabled:
-            # speculate always fetches to host (the committed rows), so the
-            # verify wall join needs no block gate
-            rf.note_wall(f"verify/t{t_bucket}/s{s_bucket}/k{n_new - 1}"
-                         f"{'/tree' if tree else ''}{'/sampled' if sampled else ''}",
-                         time.perf_counter() - t_rf)
         if reg.enabled:
             reg.counter("serving/spec_drafted_tokens").inc(drafted)
             reg.counter("serving/spec_accepted_tokens").inc(accepted)
@@ -1738,39 +1725,37 @@ class InferenceEngineV2:
                 def fwd(params, packed, samp_f, seeds, pools):
                     logits, pools = step_fn(params, packed, pools, t_bucket, s_bucket,
                                             gather_k=k)
-                    lg = logits.reshape(s_bucket, k + 1, -1)
-                    last = packed[4 * t_bucket + s_bucket * mb:
-                                  4 * t_bucket + s_bucket * mb + s_bucket]
-                    idx = jnp.maximum(
-                        last[:, None] - k + jnp.arange(k + 1, dtype=jnp.int32), 0)
-                    chunk = packed[0:t_bucket][idx]                 # fed token rows
-                    starts = packed[2 * t_bucket:3 * t_bucket][jnp.maximum(last, 0)] - k
-                    accept, nxt = spec_verify_draws(lg, chunk, samp_f[:, 0], samp_f[:, 1],
-                                                    seeds, starts)
-                    return (accept.astype(jnp.int32), nxt), pools
+                    with jax.named_scope(scopes.SAMPLE):
+                        lg = logits.reshape(s_bucket, k + 1, -1)
+                        last = packed[4 * t_bucket + s_bucket * mb:
+                                      4 * t_bucket + s_bucket * mb + s_bucket]
+                        idx = jnp.maximum(
+                            last[:, None] - k + jnp.arange(k + 1, dtype=jnp.int32), 0)
+                        chunk = packed[0:t_bucket][idx]                 # fed token rows
+                        starts = packed[2 * t_bucket:3 * t_bucket][jnp.maximum(last, 0)] - k
+                        accept, nxt = spec_verify_draws(lg, chunk, samp_f[:, 0], samp_f[:, 1],
+                                                        seeds, starts)
+                        return (accept.astype(jnp.int32), nxt), pools
 
                 self._compiled[key] = jax.jit(fwd, donate_argnums=(4, ), **self._jit_options)
             elif tree:
                 def fwd(params, packed, tree_meta, pools):
                     logits, pools = step_fn(params, packed, pools, t_bucket, s_bucket,
                                             gather_k=k, tree_meta=tree_meta)
-                    toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                    return toks.reshape(s_bucket, k + 1), pools
+                    with jax.named_scope(scopes.SAMPLE):
+                        toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                        return toks.reshape(s_bucket, k + 1), pools
 
                 self._compiled[key] = jax.jit(fwd, donate_argnums=(3, ), **self._jit_options)
             else:
                 def fwd(params, packed, pools):
                     logits, pools = step_fn(params, packed, pools, t_bucket, s_bucket,
                                             gather_k=k)
-                    toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                    return toks.reshape(s_bucket, k + 1), pools
+                    with jax.named_scope(scopes.SAMPLE):
+                        toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                        return toks.reshape(s_bucket, k + 1), pools
 
                 self._compiled[key] = jax.jit(fwd, donate_argnums=(2, ), **self._jit_options)
-            rf = get_roofline()
-            if rf.enabled:
-                # roofline cost capture: the wrapper snapshots this program's
-                # abstract signature on its first real call (lazy cost_analysis)
-                self._compiled[key] = rf.capture_executable(bucket, self._compiled[key])
             log_dist(f"compiled speculative verify bucket tokens={t_bucket} "
                      f"seqs={s_bucket} k={k} tree={tree} sampled={sampled}", ranks=[0])
         return self._compiled[key]
@@ -1790,56 +1775,62 @@ class InferenceEngineV2:
 
             def merge(stats, new):
                 """The routing counts (none for a dense model) over the steps."""
-                return tuple(merge_routing_stats(a, b) for a, b in zip(stats, new))
+                with jax.named_scope(scopes.MOE):
+                    return tuple(merge_routing_stats(a, b) for a, b in zip(stats, new))
 
             if sampled:
                 from .sampling import sample_tokens
 
                 def fwd(params, packed, samp_f, seeds, pools):
-                    token_ids = unpack_descriptors(packed, s_bucket, s_bucket, max_blocks)[0]
-                    pos_row = packed[2 * s_bucket:3 * s_bucket]
+                    with jax.named_scope(scopes.EMBED):
+                        token_ids = unpack_descriptors(packed, s_bucket, s_bucket, max_blocks)[0]
+                        pos_row = packed[2 * s_bucket:3 * s_bucket]
 
                     def step(carry, t):
                         toks, pl, stats = carry
-                        stepped = packed.at[0:s_bucket].set(toks) \
-                                        .at[2 * s_bucket:3 * s_bucket].add(t)
+                        with jax.named_scope(scopes.EMBED):
+                            stepped = packed.at[0:s_bucket].set(toks) \
+                                            .at[2 * s_bucket:3 * s_bucket].add(t)
                         logits, pl, *new = step_fn(params, stepped, pl, s_bucket, s_bucket,
                                                    moe_stats=moe, one_token_rows=True)
                         # draw keyed by the NEW token's absolute position —
                         # the same stream the sampled put path would produce
-                        nxt = sample_tokens(logits, samp_f[:, 0], samp_f[:, 1], seeds,
-                                            pos_row + t + 1)
+                        with jax.named_scope(scopes.SAMPLE):
+                            nxt = sample_tokens(logits, samp_f[:, 0], samp_f[:, 1], seeds,
+                                                pos_row + t + 1)
                         return (nxt, pl, merge(stats, new)), nxt
 
                     (_, pools, stats), out = jax.lax.scan(
                         step, (token_ids, pools, stats0), jnp.arange(n_steps, dtype=jnp.int32))
-                    return (out.T, *stats), pools  # [S, n_steps]
+                    with jax.named_scope(scopes.SAMPLE):
+                        return (out.T, *stats), pools  # [S, n_steps]
 
                 self._compiled[key] = jax.jit(fwd, donate_argnums=(4, ), **self._jit_options)
             else:
                 def fwd(params, packed, pools):
-                    token_ids = unpack_descriptors(packed, s_bucket, s_bucket, max_blocks)[0]
+                    with jax.named_scope(scopes.EMBED):
+                        token_ids = unpack_descriptors(packed, s_bucket, s_bucket, max_blocks)[0]
 
                     def step(carry, t):
                         toks, pl, stats = carry
                         # feed the greedy tokens back into the packed descriptor
                         # and advance positions in-scan from the packed starts
                         # (packed layout: [T ids][T seq_idx][T pos]...)
-                        stepped = packed.at[0:s_bucket].set(toks) \
-                                        .at[2 * s_bucket:3 * s_bucket].add(t)
+                        with jax.named_scope(scopes.EMBED):
+                            stepped = packed.at[0:s_bucket].set(toks) \
+                                            .at[2 * s_bucket:3 * s_bucket].add(t)
                         logits, pl, *new = step_fn(params, stepped, pl, s_bucket, s_bucket,
                                                    moe_stats=moe, one_token_rows=True)
-                        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                        with jax.named_scope(scopes.SAMPLE):
+                            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                         return (nxt, pl, merge(stats, new)), nxt
 
                     (_, pools, stats), out = jax.lax.scan(
                         step, (token_ids, pools, stats0), jnp.arange(n_steps, dtype=jnp.int32))
-                    return (out.T, *stats), pools  # [S, n_steps]
+                    with jax.named_scope(scopes.SAMPLE):
+                        return (out.T, *stats), pools  # [S, n_steps]
 
                 self._compiled[key] = jax.jit(fwd, donate_argnums=(2, ), **self._jit_options)
-            rf = get_roofline()
-            if rf.enabled:
-                self._compiled[key] = rf.capture_executable(bucket, self._compiled[key])
             log_dist(f"compiled multi-step decode bucket seqs={s_bucket} steps={n_steps} "
                      f"sampled={sampled}", ranks=[0])
         return self._compiled[key]
@@ -1992,8 +1983,6 @@ class InferenceEngineV2:
                 continue
             # the jitted function is made HERE, so that a later call finds the key and waits for its future
             fn = self._get_compiled(*key) if kind == "put" else self._get_compiled_decode(s_bucket, int(spec[1]))
-            if not hasattr(fn, "lower"):
-                continue  # wrapped by the roofline's capture: compiled at its first call, as ever
             packed = jax.ShapeDtypeStruct(
                 (packed_len(t_bucket, s_bucket, self._max_blocks_per_seq, bool(self._state_layers)), ), jnp.int32)
             jobs.append((key, fn, packed))
@@ -2291,27 +2280,26 @@ class InferenceEngineV2:
                 def fwd(params, packed, samp_f, seeds, pools):
                     logits, pools, *stats = step_fn(params, packed, pools, t_bucket, s_bucket,
                                                     moe_stats=moe)
-                    last = packed[4 * t_bucket + s_bucket * mb:
-                                  4 * t_bucket + s_bucket * mb + s_bucket]
-                    # key each draw by the sampled token's OWN position:
-                    # replay-deterministic for a fixed (seed, prompt) and
-                    # independent of batch composition
-                    ctr = packed[2 * t_bucket:3 * t_bucket][jnp.maximum(last, 0)] + 1
-                    toks = sample_tokens(logits, samp_f[:, 0], samp_f[:, 1], seeds, ctr)
-                    return (toks, *stats), pools
+                    with jax.named_scope(scopes.SAMPLE):
+                        last = packed[4 * t_bucket + s_bucket * mb:
+                                      4 * t_bucket + s_bucket * mb + s_bucket]
+                        # key each draw by the sampled token's OWN position:
+                        # replay-deterministic for a fixed (seed, prompt) and
+                        # independent of batch composition
+                        ctr = packed[2 * t_bucket:3 * t_bucket][jnp.maximum(last, 0)] + 1
+                        toks = sample_tokens(logits, samp_f[:, 0], samp_f[:, 1], seeds, ctr)
+                        return (toks, *stats), pools
 
                 self._compiled[key] = jax.jit(fwd, donate_argnums=(4, ), **self._jit_options)
             else:
                 def fwd(params, packed, pools):
                     logits, pools, *stats = step_fn(params, packed, pools, t_bucket, s_bucket,
                                                     moe_stats=moe, probe=sample == "probe")
-                    out = jnp.argmax(logits, axis=-1).astype(jnp.int32) if sample == "greedy" else logits
-                    return (out, *stats), pools
+                    with jax.named_scope(scopes.SAMPLE):
+                        out = jnp.argmax(logits, axis=-1).astype(jnp.int32) if sample == "greedy" else logits
+                        return (out, *stats), pools
 
                 self._compiled[key] = jax.jit(fwd, donate_argnums=(2, ), **self._jit_options)
-            rf = get_roofline()
-            if rf.enabled:
-                self._compiled[key] = rf.capture_executable(bucket, self._compiled[key])
             log_dist(f"compiled ragged forward bucket tokens={t_bucket} seqs={s_bucket} "
                      f"sample={sample}", ranks=[0])
         return self._compiled[key]

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ....models.transformer import TransformerConfig, apply_rope, mlp_activation, rope_inv_freq, rope_table
+from ....monitor import scopes
 from ....moe.grouped import merge_routing_stats
 from ....ops.pallas.kda import kda_chunks, kda_step
 from ....ops.pallas.lightning import lightning_chunks, lightning_step
@@ -310,6 +311,12 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
     and ``cfg.logit_scale`` the logits (the head has no bias, so that is the
     final normed hidden state scaled).
 
+    Every part of the step is traced under its ``jax.named_scope`` of
+    ``monitor/scopes.py`` (``embed``, ``attn_proj``, ``mixer``, ``attn_out``,
+    ``mlp`` / ``moe``, ``lm_head``), the norm before a branch and the residual
+    add after it with the matmuls they are fused into: a device trace is read
+    back by those names, so new code here goes under the part it belongs to.
+
     ``k_scale``/``v_scale``: int8-KV mode — [nkv, L*pool_len] fp32 absmax
     scales (lane-major over slots, the layout both the scatter and the
     Pallas kernel consume without a transpose). When given, the pools hold
@@ -352,86 +359,89 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
                          "token-tree mask; every other model takes k_pool and v_pool")
 
     pid = pos if pos_ids is None else pos_ids
-    x = embedding(params, token_ids, pid)  # [T, H]
-    # one rope table an attention kind (a model of one kind: the key None), in
-    # the order the kinds first appear: a set's order changes with the process's
-    # string hash seed, and with it the traced program and its compile-cache key
-    ropes = {kind: rope_table(cfg, pid, kind) for kind in dict.fromkeys(cfg.layer_types or (None, ))
-             if cfg.rope_layer_types is None or kind in cfg.rope_layer_types} \
-        if cfg.positions == "rotary" and not latent else {}
-    if latent:  # the rotated part of a head alone: tables [T, qk_rope_head_dim / 2], halves rotated
-        angles = pid.astype(jnp.float32)[:, None] * jnp.asarray(rope_inv_freq(cfg)[0])[None, :]
-        latent_rope = jnp.sin(angles), jnp.cos(angles)
+    with jax.named_scope(scopes.EMBED):
+        x = embedding(params, token_ids, pid)  # [T, H]
+    # what the mixers share, once a program: rope tables, each token's slot, the rows' runs, the workspaces
+    with jax.named_scope(scopes.MIXER):
+        # one rope table an attention kind (a model of one kind: the key None), in
+        # the order the kinds first appear: a set's order changes with the process's
+        # string hash seed, and with it the traced program and its compile-cache key
+        ropes = {kind: rope_table(cfg, pid, kind) for kind in dict.fromkeys(cfg.layer_types or (None, ))
+                 if cfg.rope_layer_types is None or kind in cfg.rope_layer_types} \
+            if cfg.positions == "rotary" and not latent else {}
+        if latent:  # the rotated part of a head alone: tables [T, qk_rope_head_dim / 2], halves rotated
+            angles = pid.astype(jnp.float32)[:, None] * jnp.asarray(rope_inv_freq(cfg)[0])[None, :]
+            latent_rope = jnp.sin(angles), jnp.cos(angles)
 
-    # flat KV slot of each token; padding tokens dropped via OOB scatter.
-    # The pools ride the layer scan as CARRY over a layers-flattened view
-    # [(L*NB*bs), nkv, d]: scatter/gather address layer l via an l*pool_len
-    # (resp. l*NB block-table) offset. Pools as scan xs/ys would instead
-    # round-trip the whole cache through fresh stacked outputs every forward
-    # — at serving scale that copy (~2x pool bytes of HBM traffic per decode
-    # step) dominated the step budget.
-    quant = k_scale is not None
-    NB = pool_len // block_size
-    L = cfg.num_layers
-    flat_len = k_pool.shape[0] * pool_len
-    kv_index = {l: i for i, l in enumerate(cfg.kv_layers)}  # a layer's place in the K/V pools and attention weights
-    state_index = {l: i for i, l in enumerate(cfg.state_layers)}
-    if bool(state_index) != (state_pools is not None) or (state_index and (
-            state_slots is None or quant or attn_mask is not None or kv_only or latent)):
-        raise ValueError("a model with linear-attention layers takes state_pools and state_slots, and neither int8 "
-                         "scales, a token-tree mask nor kv_only; every other model takes none")
-    lightning = cfg.lightning_num_heads > 0
-    if state_index:
-        # what the rows are fed, once a program: tokens a row (rows come in order from flat token 0), a
-        # token's place in its row's run, and which rows start their sequence here
-        S = block_tables.shape[0]
-        n_slots, taps = state_pools[0].shape[1], (cfg.mamba_conv_size if cfg.mamba_num_heads > 0 else cfg.kda_conv_size)
-        ok = valid.astype(jnp.int32)
-        if one_token_rows:
-            n_tok, in_row, row_start = ok, jnp.zeros(T, jnp.int32), jnp.arange(T, dtype=jnp.int32)
-            first_pos = pos
+        # flat KV slot of each token; padding tokens dropped via OOB scatter.
+        # The pools ride the layer scan as CARRY over a layers-flattened view
+        # [(L*NB*bs), nkv, d]: scatter/gather address layer l via an l*pool_len
+        # (resp. l*NB block-table) offset. Pools as scan xs/ys would instead
+        # round-trip the whole cache through fresh stacked outputs every forward
+        # — at serving scale that copy (~2x pool bytes of HBM traffic per decode
+        # step) dominated the step budget.
+        quant = k_scale is not None
+        NB = pool_len // block_size
+        L = cfg.num_layers
+        flat_len = k_pool.shape[0] * pool_len
+        kv_index = {l: i for i, l in enumerate(cfg.kv_layers)}  # a layer's place in the K/V pools and attention weights
+        state_index = {l: i for i, l in enumerate(cfg.state_layers)}
+        if bool(state_index) != (state_pools is not None) or (state_index and (
+                state_slots is None or quant or attn_mask is not None or kv_only or latent)):
+            raise ValueError("a model with linear-attention layers takes state_pools and state_slots, and neither int8 "
+                             "scales, a token-tree mask nor kv_only; every other model takes none")
+        lightning = cfg.lightning_num_heads > 0
+        if state_index:
+            # what the rows are fed, once a program: tokens a row (rows come in order from flat token 0), a
+            # token's place in its row's run, and which rows start their sequence here
+            S = block_tables.shape[0]
+            n_slots, taps = state_pools[0].shape[1], (cfg.mamba_conv_size if cfg.mamba_num_heads > 0 else cfg.kda_conv_size)
+            ok = valid.astype(jnp.int32)
+            if one_token_rows:
+                n_tok, in_row, row_start = ok, jnp.zeros(T, jnp.int32), jnp.arange(T, dtype=jnp.int32)
+                first_pos = pos
+            else:
+                n_tok = jnp.zeros(S, jnp.int32).at[seq_idx].add(ok)
+                row_start = jnp.cumsum(n_tok) - n_tok
+                in_row = jnp.arange(T, dtype=jnp.int32) - row_start[seq_idx]
+                first_pos = pos[jnp.minimum(row_start, T - 1)]
+            fed = n_tok > 0
+            fresh = fed & (first_pos == 0)
+            n_live = jnp.sum(fed.astype(jnp.int32))
+            kda_pallas = use_pallas and jax.default_backend() == "tpu"
+            kda_interpret = bool(getattr(attention, "implementation_config", {}).get("interpret", False))
+            st_flat = state_pools[0].reshape((-1, ) + state_pools[0].shape[2:])
+            cv_flat = None if lightning else state_pools[1].reshape((-1, ) + state_pools[1].shape[2:])
+        if sparse:
+            idx_flat = index_pool.reshape((-1, ) + index_pool.shape[2:])
+        slot = block_tables[seq_idx, pos // block_size] * block_size + pos % block_size
+
+        # what the attention paths mask by (see the docstring)
+        vis_pos = pos | (cfg.diffusion_block_size - 1) if cfg.diffusion_block_size > 1 else pos
+        if cfg.diffusion_block_size > 1 and (attn_mask is not None or block_size % cfg.diffusion_block_size):
+            raise NotImplementedError(f"blocks of {cfg.diffusion_block_size} under a block-causal mask: no token-tree "
+                                      f"mask beside it, and a KV block ({block_size}) holds whole blocks")
+
+        # latent attention's long rows (see the docstring): which they are, their
+        # tokens as the expanded call takes them, and the workspace, once a program
+        x_rows, x_cols = expanded_plan(cfg, T, block_tables.shape[1], block_size, k_pool.dtype.itemsize)
+        if x_rows:
+            S = block_tables.shape[0]
+            fed = jnp.zeros(S, jnp.int32).at[seq_idx].add(valid.astype(jnp.int32))
+            length = jnp.zeros(S, jnp.int32).at[seq_idx].max(jnp.where(valid, pos + 1, 0))
+            slot_of_row = expanded_slots(fed, length, x_rows, x_cols, block_size)
+            slot_of_tok = jnp.where(valid, slot_of_row[seq_idx], -1)
+            x_seq, x_pos = expanded_batch(slot_of_tok, vis_pos, x_rows)
+            # a slot's table row and the KV blocks its context holds (none: no row took the slot)
+            x_row = [jnp.argmax(slot_of_row == i) for i in range(x_rows)]
+            x_blocks = [jnp.where(jnp.any(slot_of_row == i), -(-length[r] // block_size), 0) for i, r in enumerate(x_row)]
+            # the workspace's own table, a constant: column ``j`` of slot ``i`` lies where it was made (and the
+            # rows of the tokens of no slot name block 0: nothing is read through them)
+            x_tables = jnp.pad(jnp.arange(x_rows * x_cols, dtype=jnp.int32).reshape(x_rows, x_cols), ((0, x_rows + 1), (0, 0)))
+            absorbed_pos = jnp.where(slot_of_tok >= 0, -1, vis_pos)   # the absorbed call drops the long rows' tiles
+            workspace = (jnp.zeros((nq, x_rows * x_cols, block_size, d), k_pool.dtype), ) * 2
         else:
-            n_tok = jnp.zeros(S, jnp.int32).at[seq_idx].add(ok)
-            row_start = jnp.cumsum(n_tok) - n_tok
-            in_row = jnp.arange(T, dtype=jnp.int32) - row_start[seq_idx]
-            first_pos = pos[jnp.minimum(row_start, T - 1)]
-        fed = n_tok > 0
-        fresh = fed & (first_pos == 0)
-        n_live = jnp.sum(fed.astype(jnp.int32))
-        kda_pallas = use_pallas and jax.default_backend() == "tpu"
-        kda_interpret = bool(getattr(attention, "implementation_config", {}).get("interpret", False))
-        st_flat = state_pools[0].reshape((-1, ) + state_pools[0].shape[2:])
-        cv_flat = None if lightning else state_pools[1].reshape((-1, ) + state_pools[1].shape[2:])
-    if sparse:
-        idx_flat = index_pool.reshape((-1, ) + index_pool.shape[2:])
-    slot = block_tables[seq_idx, pos // block_size] * block_size + pos % block_size
-
-    # what the attention paths mask by (see the docstring)
-    vis_pos = pos | (cfg.diffusion_block_size - 1) if cfg.diffusion_block_size > 1 else pos
-    if cfg.diffusion_block_size > 1 and (attn_mask is not None or block_size % cfg.diffusion_block_size):
-        raise NotImplementedError(f"blocks of {cfg.diffusion_block_size} under a block-causal mask: no token-tree "
-                                  f"mask beside it, and a KV block ({block_size}) holds whole blocks")
-
-    # latent attention's long rows (see the docstring): which they are, their
-    # tokens as the expanded call takes them, and the workspace, once a program
-    x_rows, x_cols = expanded_plan(cfg, T, block_tables.shape[1], block_size, k_pool.dtype.itemsize)
-    if x_rows:
-        S = block_tables.shape[0]
-        fed = jnp.zeros(S, jnp.int32).at[seq_idx].add(valid.astype(jnp.int32))
-        length = jnp.zeros(S, jnp.int32).at[seq_idx].max(jnp.where(valid, pos + 1, 0))
-        slot_of_row = expanded_slots(fed, length, x_rows, x_cols, block_size)
-        slot_of_tok = jnp.where(valid, slot_of_row[seq_idx], -1)
-        x_seq, x_pos = expanded_batch(slot_of_tok, vis_pos, x_rows)
-        # a slot's table row and the KV blocks its context holds (none: no row took the slot)
-        x_row = [jnp.argmax(slot_of_row == i) for i in range(x_rows)]
-        x_blocks = [jnp.where(jnp.any(slot_of_row == i), -(-length[r] // block_size), 0) for i, r in enumerate(x_row)]
-        # the workspace's own table, a constant: column ``j`` of slot ``i`` lies where it was made (and the
-        # rows of the tokens of no slot name block 0: nothing is read through them)
-        x_tables = jnp.pad(jnp.arange(x_rows * x_cols, dtype=jnp.int32).reshape(x_rows, x_cols), ((0, x_rows + 1), (0, 0)))
-        absorbed_pos = jnp.where(slot_of_tok >= 0, -1, vis_pos)   # the absorbed call drops the long rows' tiles
-        workspace = (jnp.zeros((nq, x_rows * x_cols, block_size, d), k_pool.dtype), ) * 2
-    else:
-        absorbed_pos, workspace = vis_pos, None
+            absorbed_pos, workspace = vis_pos, None
 
     def tailed_conv(x3, w, slot_li, cv_flat):
         """The causal depthwise convolution of a state layer over this step's
@@ -463,15 +473,20 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
         nh, dk = cfg.kda_num_heads, cfg.kda_head_dim
         f32 = jnp.float32
         slot_li = li * n_slots + state_slots
-        x3 = jnp.concatenate([linear(h1, blk[f"kda_w{n}"], None) for n in "qkv"], axis=-1)            # [T, 3 nh dk]
+        with jax.named_scope(scopes.ATTN_PROJ):
+            x3 = jnp.concatenate([linear(h1, blk[f"kda_w{n}"], None) for n in "qkv"], axis=-1)        # [T, 3 nh dk]
         w = jnp.concatenate([blk[f"kda_conv_{n}"] for n in "qkv"], axis=-1).astype(f32)               # [taps, 3 nh dk]
         y, cv_flat = tailed_conv(x3, w, slot_li, cv_flat)
         q, k, v = (jax.nn.silu(part).reshape(T, nh, dk) for part in jnp.split(y, 3, axis=-1))
         q = q * jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + 1e-6) / math.sqrt(dk)
         k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + 1e-6)
-        decay_in = linear(linear(h1, blk["kda_wf1"], None), blk["kda_wf2"], None).astype(f32) + blk["kda_dt_bias"].astype(f32)
+        with jax.named_scope(scopes.ATTN_PROJ):
+            decay_in = linear(linear(h1, blk["kda_wf1"], None), blk["kda_wf2"], None)
+        decay_in = decay_in.astype(f32) + blk["kda_dt_bias"].astype(f32)
         g = -jnp.exp(blk["kda_A_log"].astype(f32))[None, :, None] * jax.nn.softplus(decay_in).reshape(T, nh, dk)
-        beta = jax.nn.sigmoid(linear(h1, blk["kda_wb"], None).astype(f32)) * (2.0 if cfg.kda_neg_eigval else 1.0)
+        with jax.named_scope(scopes.ATTN_PROJ):
+            beta_in = linear(h1, blk["kda_wb"], None)
+        beta = jax.nn.sigmoid(beta_in.astype(f32)) * (2.0 if cfg.kda_neg_eigval else 1.0)
         if one_token_rows:
             o, st_flat = kda_step(q, k, v, g, beta, st_flat, slot_li, fresh, n_live, use_pallas=kda_pallas,
                                   interpret=kda_interpret)
@@ -480,9 +495,11 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
                                     interpret=kda_interpret)
         # a norm over each head's values (one gain vector for all heads), then the output gate
         o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + cfg.norm_eps) * blk["kda_o_norm_scale"].astype(f32)
-        gate = linear(linear(h1, blk["kda_wg1"], None), blk["kda_wg2"], None).astype(f32)
-        o = (o.reshape(T, nh * dk) * jax.nn.sigmoid(gate)).astype(h1.dtype)
-        return linear(o, blk["kda_wo"], None), st_flat, cv_flat
+        with jax.named_scope(scopes.ATTN_PROJ):
+            gate = linear(linear(h1, blk["kda_wg1"], None), blk["kda_wg2"], None)
+        o = (o.reshape(T, nh * dk) * jax.nn.sigmoid(gate.astype(f32))).astype(h1.dtype)
+        with jax.named_scope(scopes.ATTN_OUT):
+            return linear(o, blk["kda_wo"], None), st_flat, cv_flat
 
     def lightning_mixer(h1, blk, li, st_flat, rope):
         """A lightning layer's mixer on the normed input ``h1`` ``[T, H]``,
@@ -490,7 +507,8 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
         Returns ``(out [T, H], st_flat)``."""
         nh, dk = cfg.lightning_num_heads, cfg.lightning_head_dim
         f32 = jnp.float32
-        q, k, v = (linear(h1, blk[f"la_w{n}"], None).reshape(T, nh, dk) for n in "qkv")
+        with jax.named_scope(scopes.ATTN_PROJ):
+            q, k, v = [linear(h1, blk[f"la_w{n}"], None).reshape(T, nh, dk) for n in "qkv"]
         q, k = pre_norm(q, blk["la_q_norm_scale"]), pre_norm(k, blk["la_k_norm_scale"])
         q = apply_rope(q[None], *rope, cfg.rotary_dim)[0].astype(f32) / math.sqrt(dk)
         k = apply_rope(k[None], *rope, cfg.rotary_dim)[0].astype(f32)
@@ -504,8 +522,11 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
         # a norm over all heads' values with one gain vector, then the output gate
         o = o.reshape(T, nh * dk)
         o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + cfg.norm_eps) * blk["la_o_norm_scale"].astype(f32)
-        o = (o * jax.nn.sigmoid(linear(h1, blk["la_wg"], None).astype(f32))).astype(h1.dtype)
-        return linear(o, blk["la_wo"], None), st_flat
+        with jax.named_scope(scopes.ATTN_PROJ):
+            gate = linear(h1, blk["la_wg"], None)
+        o = (o * jax.nn.sigmoid(gate.astype(f32))).astype(h1.dtype)
+        with jax.named_scope(scopes.ATTN_OUT):
+            return linear(o, blk["la_wo"], None), st_flat
 
     def state_space_mixer(h1, blk, li, st_flat, cv_flat):
         """A state-space (Mamba-2) layer's mixer on the normed input ``h1``
@@ -515,7 +536,8 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
         f32 = jnp.float32
         inner = nh * P
         slot_li = li * n_slots + state_slots
-        z, xbc, dt_in = jnp.split(linear(h1, blk["m2_w_in"], None), (inner, inner + cfg.mamba_conv_channels), axis=-1)
+        with jax.named_scope(scopes.ATTN_PROJ):
+            z, xbc, dt_in = jnp.split(linear(h1, blk["m2_w_in"], None), (inner, inner + cfg.mamba_conv_channels), axis=-1)
         y, cv_flat = tailed_conv(xbc, blk["m2_conv_w"].astype(f32), slot_li, cv_flat)
         xs, B, C = jnp.split(jax.nn.silu(y + blk["m2_conv_b"].astype(f32)), (inner, inner + G * N), axis=-1)
         xs, B, C = xs.reshape(T, nh, P), B.reshape(T, G, N), C.reshape(T, G, N)
@@ -532,7 +554,8 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
         y = y.reshape(T, G, inner // G)
         y = (y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + cfg.norm_eps)).reshape(T, inner)
         y = (y * blk["m2_norm_scale"].astype(f32)).astype(h1.dtype)
-        return linear(y, blk["m2_w_out"], None), st_flat, cv_flat
+        with jax.named_scope(scopes.ATTN_OUT):
+            return linear(y, blk["m2_w_out"], None), st_flat, cv_flat
 
     def softmax_mixer(h1, blk, l, kind, k_flat, v_flat, ks_flat, vs_flat, stats, ws, kv_alone):
         """Softmax attention over the paged pool (per-head K and V, or the
@@ -548,9 +571,10 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
             c, nope, dv = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.v_head_dim
             W = k_flat.shape[-1]
             sin, cos = latent_rope
-            cq = pre_norm(linear(h1, blk["wq_a"], None), blk["q_a_norm_scale"])
-            qh = linear(cq, blk["wq_b"], None).reshape(T, nq, d)
-            kv = linear(h1, blk["wkv_a"], None)
+            with jax.named_scope(scopes.ATTN_PROJ):
+                cq = pre_norm(linear(h1, blk["wq_a"], None), blk["q_a_norm_scale"])
+                qh = linear(cq, blk["wq_b"], None).reshape(T, nq, d)
+                kv = linear(h1, blk["wkv_a"], None)
             ckv = pre_norm(kv[:, :c], blk["kv_a_norm_scale"])
             kr = apply_rope(kv[None, :, None, c:], sin, cos)[0]          # [T, 1, rope]: ONE key part for all heads
             entry = jnp.concatenate([ckv[:, None, :], kr], axis=-1)
@@ -582,9 +606,10 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
             ctx = ctx.reshape(T, nq * dv)
         else:
             qkvb = (lambda n: blk[n]) if cfg.qkv_bias_enabled else (lambda n: None)
-            q = linear(h1, blk["wq"], qkvb("bq")).reshape(T, nq, d)
-            k = linear(h1, blk["wk"], qkvb("bk")).reshape(T, nkv, d)
-            v = linear(h1, blk["wv"], qkvb("bv")).reshape(T, nkv, d)
+            with jax.named_scope(scopes.ATTN_PROJ):
+                q = linear(h1, blk["wq"], qkvb("bq")).reshape(T, nq, d)
+                k = linear(h1, blk["wk"], qkvb("bk")).reshape(T, nkv, d)
+                v = linear(h1, blk["wv"], qkvb("bv")).reshape(T, nkv, d)
             if cfg.qk_norm:  # over each head's d, one gain vector for all heads
                 q = pre_norm(q, blk["q_norm_scale"])
                 k = pre_norm(k, blk["k_norm_scale"])
@@ -629,9 +654,11 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
                 ctx = attend(q, k_flat, v_flat, tables_l, seq_idx, vis_pos, **scales)
             ctx = ctx.reshape(T, nq * d)
         if cfg.attention_gate:
-            gate = linear(h1, blk["w_attn_gate"], None)
+            with jax.named_scope(scopes.ATTN_PROJ):
+                gate = linear(h1, blk["w_attn_gate"], None)
             ctx = (ctx.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(ctx.dtype)
-        return linear(ctx, blk["wo"], bias("bo")), k_flat, v_flat, ks_flat, vs_flat, stats, ws
+        with jax.named_scope(scopes.ATTN_OUT):
+            return linear(ctx, blk["wo"], bias("bo")), k_flat, v_flat, ks_flat, vs_flat, stats, ws
 
     def state_mixer(run):
         """A state layer's mixer as the table's entries are called: the K/V
@@ -657,11 +684,16 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
         layer's K/V and stop. A layer runs the branches it HAS: a mixer and an
         MLP under a norm each, or, in a model of ``single_branch_layers``, the
         one of them its kind names under the layer's one norm."""
-        h1 = pre_norm(x, blk["ln1_scale"], blk.get("ln1_bias"))
+        # the MLP's part: the routed experts', or the dense MLP's (the norm before and the residual add with it)
+        mlp_part = scopes.MLP if "gate_wg" not in blk else scopes.MOE
+        with jax.named_scope(mlp_part if kind == "mlp_only" else scopes.ATTN_PROJ):
+            h1 = pre_norm(x, blk["ln1_scale"], blk.get("ln1_bias"))
         bias = (lambda n: blk[n]) if cfg.use_bias else (lambda n: None)
         if kind != "mlp_only":
-            attn_out, k_flat, v_flat, ks_flat, vs_flat, stats, ws = mixers.get(kind, softmax_mixer)(
-                h1, blk, l, kind, k_flat, v_flat, ks_flat, vs_flat, stats, ws, kv_alone)
+            # a mixer names its input projections and its output projection inside: the rest of it is ``mixer``
+            with jax.named_scope(scopes.MIXER):
+                attn_out, k_flat, v_flat, ks_flat, vs_flat, stats, ws = mixers.get(kind, softmax_mixer)(
+                    h1, blk, l, kind, k_flat, v_flat, ks_flat, vs_flat, stats, ws, kv_alone)
             if attn_out is None:  # ``kv_alone``: the layer stopped at its scatter
                 return x, k_flat, v_flat, ks_flat, vs_flat, stats, ws
 
@@ -684,7 +716,8 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
                 out, layer_stats = out
                 stats = merge_routing_stats(stats, layer_stats)
             if "shared_wi" in blk:  # every token's, whole on every chip of the group
-                out = out + dense_mlp(h, blk["shared_wi"], blk.get("shared_wg"), blk["shared_wo"])
+                with jax.named_scope(scopes.MLP):
+                    out = out + dense_mlp(h, blk["shared_wi"], blk.get("shared_wg"), blk["shared_wo"])
             return out
 
         def post(y, name):  # the sandwich norm on a branch's output, and MiniCPM's factor on the branch
@@ -692,14 +725,18 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
             return y if cfg.residual_scale == 1.0 else (y.astype(jnp.float32) * cfg.residual_scale).astype(y.dtype)
 
         if cfg.single_branch_layers:  # ONE branch under the layer's one norm
-            return x + post(mlp(h1) if kind == "mlp_only" else attn_out, "ln1_post_scale"), k_flat, v_flat, ks_flat, \
-                vs_flat, stats, ws
+            with jax.named_scope(mlp_part if kind == "mlp_only" else scopes.ATTN_OUT):
+                return x + post(mlp(h1) if kind == "mlp_only" else attn_out, "ln1_post_scale"), k_flat, v_flat, \
+                    ks_flat, vs_flat, stats, ws
         if cfg.parallel_residual:  # GPT-J / NeoX / Falcon
-            h2 = h1 if cfg.shared_ln else pre_norm(x, blk["ln2_scale"], blk.get("ln2_bias"))
-            return x + attn_out + mlp(h2), k_flat, v_flat, ks_flat, vs_flat, stats, ws
-        x = x + post(attn_out, "ln1_post_scale")
-        h2 = pre_norm(x, blk["ln2_scale"], blk.get("ln2_bias"))
-        return x + post(mlp(h2), "ln2_post_scale"), k_flat, v_flat, ks_flat, vs_flat, stats, ws
+            with jax.named_scope(mlp_part):
+                h2 = h1 if cfg.shared_ln else pre_norm(x, blk["ln2_scale"], blk.get("ln2_bias"))
+                return x + attn_out + mlp(h2), k_flat, v_flat, ks_flat, vs_flat, stats, ws
+        with jax.named_scope(scopes.ATTN_OUT):
+            x = x + post(attn_out, "ln1_post_scale")
+        with jax.named_scope(mlp_part):
+            h2 = pre_norm(x, blk["ln2_scale"], blk.get("ln2_bias"))
+            return x + post(mlp(h2), "ln2_post_scale"), k_flat, v_flat, ks_flat, vs_flat, stats, ws
 
     # (a latent pool: one entry a token, [flat_len, 1, W], and no second pool)
     k_flat = k_pool.reshape((flat_len, ) + k_pool.shape[2:])
@@ -745,9 +782,23 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
             return dense_index.get(l)
         return l
 
+    def part_of(name):
+        """The part of a step that reads the stacked array ``name``: where a
+        layer's slice of it is traced (XLA copies some out of their stacks, a
+        step, a layer: 4.5% of a dense decode step's device time, PR 53)."""
+        if name in dense_layer_keys or name.startswith("ln2"):
+            return scopes.MLP
+        if name in expert_layer_keys:
+            return scopes.MOE
+        return scopes.ATTN_OUT if name.endswith("wo") else scopes.ATTN_PROJ
+
+    def layer_slice(name, stacked, i):
+        with jax.named_scope(part_of(name)):
+            return jax.tree_util.tree_map(lambda a: a[i], stacked)
+
     if unroll and L <= 48:
         for l in range(L):
-            blk_l = {name: jax.tree_util.tree_map(lambda a: a[i], stacked)
+            blk_l = {name: layer_slice(name, stacked, i)
                      for name, stacked in sorted(per_layer.items()) if (i := index_of(name, l)) is not None}
             x, k_flat, v_flat, ks_flat, vs_flat, stats, workspace = layer(
                 x, blk_l, l, k_flat, v_flat, ks_flat, vs_flat, stats, workspace, cfg.layer_kind(l),
@@ -773,9 +824,10 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
 
     # logits_gather semantics: final norm + unembed only each sequence's
     # last token, through the pluggable unembed module
-    logits = None if kv_only else unembed(params, x, last_idx)
-    if logits is not None and cfg.logit_scale != 1.0:
-        logits = logits * cfg.logit_scale
+    with jax.named_scope(scopes.LM_HEAD):
+        logits = None if kv_only else unembed(params, x, last_idx)
+        if logits is not None and cfg.logit_scale != 1.0:
+            logits = logits * cfg.logit_scale
     if sparse:
         pools += (workspace[-1].reshape(index_pool.shape), )
     if state_index:

@@ -31,9 +31,9 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ....monitor.scopes import SPARSE_INDEX
 from ....ops.pallas.paged_attention import _tile_runs
 
-SCOPE = "sparse_index"
 _SCORE_BYTES = 256 << 20
 
 
@@ -55,7 +55,7 @@ def update_pooled_keys(cfg, block_size: int, k_flat, p_flat, tables_l, seq_idx, 
     ksize, stride = cfg.sparse_kernel_size, cfg.sparse_kernel_stride
     per_block = block_size // stride
     T, S = pos.shape[0], tables_l.shape[0]
-    with jax.named_scope(SCOPE):
+    with jax.named_scope(SPARSE_INDEX):
         ends = valid & (pos >= ksize - 1) & ((pos - (ksize - 1)) % stride == 0)
         tok = jnp.nonzero(ends, size=pooled_capacity(T, S, stride), fill_value=T)[0]
         live = tok < T
@@ -120,7 +120,7 @@ def select_blocks(cfg, block_size: int, q, p_flat, tables_l, seq_idx, pos, valid
     nkv = p_flat.shape[1]
     qt = index_tile(T)
     n_tiles = -(-T // qt) + S + 1
-    with jax.named_scope(SCOPE):
+    with jax.named_scope(SPARSE_INDEX):
         tile_id, place = _tile_runs(seq_idx, pos, qt)
         tile_tok = jnp.zeros((n_tiles, qt), jnp.int32).at[tile_id, place].set(jnp.arange(T, dtype=jnp.int32))
         filled = jnp.zeros((n_tiles, qt), bool).at[tile_id, place].set(valid)

@@ -32,6 +32,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from ..monitor import scopes
 from ..ops.pallas.flash_attention import name_attn_out
 from ..parallel.mesh import BATCH_AXES, DATA_AXIS, MODEL_AXIS, PIPE_AXIS, SEQ_AXIS
 from ..runtime.zero.partition import PartitionRules
@@ -1114,13 +1115,14 @@ def _attn_branch(cfg: TransformerConfig, layer, h, sin, cos):
     dt = cfg.dtype
     B, S, H = h.shape
     nq, nkv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    q = jnp.einsum("bsh,hd->bsd", h, layer["wq"].astype(dt))
-    k = jnp.einsum("bsh,hd->bsd", h, layer["wk"].astype(dt))
-    v = jnp.einsum("bsh,hd->bsd", h, layer["wv"].astype(dt))
-    if cfg.qkv_bias_enabled:
-        q = q + layer["bq"].astype(dt)
-        k = k + layer["bk"].astype(dt)
-        v = v + layer["bv"].astype(dt)
+    with jax.named_scope(scopes.ATTN_PROJ):
+        q = jnp.einsum("bsh,hd->bsd", h, layer["wq"].astype(dt))
+        k = jnp.einsum("bsh,hd->bsd", h, layer["wk"].astype(dt))
+        v = jnp.einsum("bsh,hd->bsd", h, layer["wv"].astype(dt))
+        if cfg.qkv_bias_enabled:
+            q = q + layer["bq"].astype(dt)
+            k = k + layer["bk"].astype(dt)
+            v = v + layer["bv"].astype(dt)
     q = q.reshape(B, S, nq, d)
     k = k.reshape(B, S, nkv, d)
     v = v.reshape(B, S, nkv, d)
@@ -1168,9 +1170,10 @@ def _attn_branch(cfg: TransformerConfig, layer, h, sin, cos):
     # backward; the norm, the q/k/v projections, rope (and Ulysses'
     # all-to-alls), this reshape and the whole MLP branch are recomputed.
     ctx = ctx.reshape(B, S, nq * d)
-    attn_out = jnp.einsum("bsd,dh->bsh", ctx, layer["wo"].astype(dt))
-    if cfg.use_bias:
-        attn_out = attn_out + layer["bo"].astype(dt)
+    with jax.named_scope(scopes.ATTN_OUT):
+        attn_out = jnp.einsum("bsd,dh->bsh", ctx, layer["wo"].astype(dt))
+        if cfg.use_bias:
+            attn_out = attn_out + layer["bo"].astype(dt)
     return attn_out
 
 
@@ -1202,18 +1205,26 @@ def _block(cfg: TransformerConfig, x, layer, sin, cos, rng=None, constrain=True)
     the block input and add jointly; ``shared_ln`` reuses ln1 for the MLP."""
     if cfg.quantized_weights and constrain:
         layer = _qwz_layer_view(cfg, layer)
-    h1 = _norm(x, layer["ln1_scale"], layer.get("ln1_bias"), cfg.norm, cfg.norm_eps)
-    attn_out = _attn_branch(cfg, layer, h1, sin, cos)
+    # the parts of a step as a device trace is read by (monitor/scopes.py): each norm and residual add lies
+    # with the matmuls it is fused into, and the attention branch names its projections inside ``mixer``
+    mlp_part = scopes.MOE if cfg.moe_num_experts > 0 else scopes.MLP
+    with jax.named_scope(scopes.ATTN_PROJ):
+        h1 = _norm(x, layer["ln1_scale"], layer.get("ln1_bias"), cfg.norm, cfg.norm_eps)
+    with jax.named_scope(scopes.MIXER):
+        attn_out = _attn_branch(cfg, layer, h1, sin, cos)
     if cfg.parallel_residual:
-        h2 = h1 if cfg.shared_ln else _norm(x, layer["ln2_scale"], layer.get("ln2_bias"),
-                                            cfg.norm, cfg.norm_eps)
-        mlp_out, l_aux = _mlp_branch(cfg, layer, h2, rng, constrain=constrain)
-        x = x + attn_out + mlp_out
+        with jax.named_scope(mlp_part):
+            h2 = h1 if cfg.shared_ln else _norm(x, layer["ln2_scale"], layer.get("ln2_bias"),
+                                                cfg.norm, cfg.norm_eps)
+            mlp_out, l_aux = _mlp_branch(cfg, layer, h2, rng, constrain=constrain)
+            x = x + attn_out + mlp_out
         return _activation_constraint(cfg, x, enabled=constrain), l_aux
-    x = x + attn_out
-    h2 = _norm(x, layer["ln2_scale"], layer.get("ln2_bias"), cfg.norm, cfg.norm_eps)
-    mlp_out, l_aux = _mlp_branch(cfg, layer, h2, rng, constrain=constrain)
-    x = x + mlp_out
+    with jax.named_scope(scopes.ATTN_OUT):
+        x = x + attn_out
+    with jax.named_scope(mlp_part):
+        h2 = _norm(x, layer["ln2_scale"], layer.get("ln2_bias"), cfg.norm, cfg.norm_eps)
+        mlp_out, l_aux = _mlp_branch(cfg, layer, h2, rng, constrain=constrain)
+        x = x + mlp_out
     return _activation_constraint(cfg, x, enabled=constrain), l_aux
 
 
@@ -1328,16 +1339,18 @@ def forward_hidden(cfg: TransformerConfig, params: Dict[str, Any], input_ids: ja
     _refuse_mixed_layers(cfg, "forward_hidden")
     dt = cfg.dtype
     B, S = input_ids.shape
-    x = params["embed"]["embedding"].astype(dt)[input_ids]
-    if cfg.positions == "learned":
-        x = x + params["pos_embed"]["embedding"].astype(dt)[:S][None]
-    if cfg.embed_layernorm:
-        en = params["embed_norm"]
-        x = _norm(x, en["scale"], en.get("bias"), cfg.norm, cfg.norm_eps)
-    x = _activation_constraint(cfg, x)
+    with jax.named_scope(scopes.EMBED):
+        x = params["embed"]["embedding"].astype(dt)[input_ids]
+        if cfg.positions == "learned":
+            x = x + params["pos_embed"]["embedding"].astype(dt)[:S][None]
+        if cfg.embed_layernorm:
+            en = params["embed_norm"]
+            x = _norm(x, en["scale"], en.get("bias"), cfg.norm, cfg.norm_eps)
+        x = _activation_constraint(cfg, x)
 
     positions = jnp.arange(S)
-    sin, cos = rope_table(cfg, positions) if cfg.positions == "rotary" else (None, None)
+    with jax.named_scope(scopes.MIXER):
+        sin, cos = rope_table(cfg, positions) if cfg.positions == "rotary" else (None, None)
 
     block_fn = partial(_block, cfg)
     if cfg.remat:
@@ -1395,8 +1408,9 @@ def forward_hidden(cfg: TransformerConfig, params: Dict[str, Any], input_ids: ja
         idx = jnp.arange(L, dtype=jnp.int32)
         xs = (idx, layer_keys) if use_layer_keys else idx
         (x, _), l_auxs = lax.scan(overlap_body, (x, fetch(jnp.int32(0))), xs)
-        x = _norm(x, params["final_norm"]["scale"], params["final_norm"].get("bias"),
-                  cfg.norm, cfg.norm_eps)
+        with jax.named_scope(scopes.LM_HEAD):
+            x = _norm(x, params["final_norm"]["scale"], params["final_norm"].get("bias"),
+                      cfg.norm, cfg.norm_eps)
         return x, jnp.sum(l_auxs)
 
     xs_list = [params["blocks"]]
@@ -1423,20 +1437,22 @@ def forward_hidden(cfg: TransformerConfig, params: Dict[str, Any], input_ids: ja
         return lax.cond(keep, run, skip, carry)
 
     x, l_auxs = lax.scan(scan_body, x, tuple(xs_list) if len(xs_list) > 1 else xs_list[0])
-    x = _norm(x, params["final_norm"]["scale"], params["final_norm"].get("bias"), cfg.norm, cfg.norm_eps)
+    with jax.named_scope(scopes.LM_HEAD):
+        x = _norm(x, params["final_norm"]["scale"], params["final_norm"].get("bias"), cfg.norm, cfg.norm_eps)
     return x, jnp.sum(l_auxs)
 
 
 def _unembed(cfg: TransformerConfig, params, x):
     """Final hidden [..., H] → vocabulary logits [..., V] in fp32."""
     dt = cfg.dtype
-    if cfg.tie_embeddings:
-        logits = jnp.einsum("...h,vh->...v", x, params["embed"]["embedding"].astype(dt))
-    else:
-        logits = jnp.einsum("...h,hv->...v", x, params["lm_head"]["kernel"].astype(dt))
-        if "bias" in params["lm_head"]:  # GPT-J style biased unembedding
-            logits = logits + params["lm_head"]["bias"].astype(logits.dtype)
-    return logits.astype(jnp.float32)
+    with jax.named_scope(scopes.LM_HEAD):
+        if cfg.tie_embeddings:
+            logits = jnp.einsum("...h,vh->...v", x, params["embed"]["embedding"].astype(dt))
+        else:
+            logits = jnp.einsum("...h,hv->...v", x, params["lm_head"]["kernel"].astype(dt))
+            if "bias" in params["lm_head"]:  # GPT-J style biased unembedding
+                logits = logits + params["lm_head"]["bias"].astype(logits.dtype)
+        return logits.astype(jnp.float32)
 
 
 def forward_with_aux(cfg: TransformerConfig, params: Dict[str, Any], input_ids: jax.Array, rng=None,
@@ -1705,16 +1721,20 @@ def loss_fn(cfg: TransformerConfig, params, batch, rng=None):
     'loss_mask'. ``cfg.loss_chunk`` routes through the sequence-chunked CE
     (logits never fully materialized)."""
     input_ids = batch["input_ids"] if isinstance(batch, dict) else batch
-    aux_d = _ce_aux(batch, input_ids)
+    with jax.named_scope(scopes.LOSS):
+        aux_d = _ce_aux(batch, input_ids)
     pld_theta = batch.get("pld_theta") if isinstance(batch, dict) else None
     if cfg.loss_chunk and input_ids.shape[1] > cfg.loss_chunk:
         h, moe_aux = forward_hidden(cfg, params, input_ids, rng, pld_theta=pld_theta)
-        ce = _chunked_ce_loss(cfg, params, h, aux_d, int(cfg.loss_chunk))
+        with jax.named_scope(scopes.LOSS):  # (its chunks' unembedding stays ``lm_head``, inside)
+            ce = _chunked_ce_loss(cfg, params, h, aux_d, int(cfg.loss_chunk))
     else:
         logits, moe_aux = forward_with_aux(cfg, params, input_ids, rng, pld_theta=pld_theta)
-        ce = _ce_loss(logits, aux_d)
-    aux = cfg.moe_aux_loss_coef * moe_aux if cfg.moe_num_experts > 0 else 0.0
-    return ce + aux
+        with jax.named_scope(scopes.LOSS):
+            ce = _ce_loss(logits, aux_d)
+    with jax.named_scope(scopes.LOSS):
+        aux = cfg.moe_aux_loss_coef * moe_aux if cfg.moe_num_experts > 0 else 0.0
+        return ce + aux
 
 
 def pipeline_loss_fn(cfg: TransformerConfig, params, batches, rng=None, *, mesh, num_stages: int):

@@ -19,5 +19,4 @@ from .goodput import (  # noqa: F401
     get_goodput, configure_goodput, conservation_ok, GoodputLedger, GoodputPlane,
     RecompileSentinel, TRAIN_CATEGORIES, SERVING_CATEGORIES)
 from .roofline import (  # noqa: F401
-    get_roofline, configure_roofline, get_capture_manager, cost_analysis_dict,
-    CaptureBusyError, CaptureManager, RooflinePlane, ExecutableCostRegistry)
+    get_capture_manager, CaptureBusyError, CaptureManager)

@@ -11,11 +11,11 @@ from ..runtime.config_utils import DeepSpeedConfigModel
 def get_monitor_config(param_dict):
     monitor_dict = {key: param_dict.get(key, {})
                     for key in ("tensorboard", "wandb", "csv_monitor", "comet", "trace",
-                                "health", "goodput", "roofline")}
+                                "health", "goodput")}
     # presence-enables: an EMPTY {"trace": {}} / {"health": {}} block in the
     # config means "on with defaults" (the validator can only see set
     # fields, not presence)
-    for key in ("trace", "health", "goodput", "roofline"):
+    for key in ("trace", "health", "goodput"):
         if key in param_dict and not monitor_dict[key]:
             monitor_dict[key] = {"enabled": True}
     return DeepSpeedMonitorConfig(**monitor_dict)
@@ -138,37 +138,6 @@ class GoodputConfig(DeepSpeedConfigModel):
         return self
 
 
-class RooflineConfig(DeepSpeedConfigModel):
-    """``monitor.roofline`` block — the executable-cost registry + roofline
-    verdict plane and the on-demand XPlane capture manager
-    (``monitor/roofline.py``). Enabled by presence (the ``trace``/``health``/
-    ``goodput`` contract); off by default — compile sites and forward paths
-    then pay one ``enabled`` check each, with no registry, no per-compile
-    wrappers, and no threads (test-enforced)."""
-    enabled: bool = False
-    # measured wall past this multiple of the cost-model roof time verdicts
-    # `overhead_bound` instead of compute/bandwidth bound: the executable is
-    # not near either hardware roof, the gap is dispatch/host overhead
-    overhead_factor: float = Field(2.0, gt=1.0)
-    # peak overrides (FLOP/s, bytes/s per chip). None = the per-chip tables
-    # in monitor/metrics.py; on an unknown chip (CPU fallback) with no
-    # override, MFU/MBU report null and the verdict is `unknown` — the
-    # VERDICT r4 discipline (never a misleading utilization number)
-    peak_flops: Optional[float] = Field(None, gt=0)
-    peak_hbm_bw: Optional[float] = Field(None, gt=0)
-    # default artifact root for on-demand captures (the gateway's
-    # serving.gateway.profiling block carries its own)
-    capture_dir: str = "/tmp/dstpu_xplane"
-    # hard bound on any single on-demand capture
-    max_capture_s: float = Field(60.0, gt=0)
-
-    @model_validator(mode="after")
-    def enable_when_configured(self):
-        if self.model_fields_set and "enabled" not in self.model_fields_set:
-            self.enabled = True
-        return self
-
-
 class DeepSpeedMonitorConfig(DeepSpeedConfigModel):
     tensorboard: TensorBoardConfig = {}
     wandb: WandbConfig = {}
@@ -177,7 +146,6 @@ class DeepSpeedMonitorConfig(DeepSpeedConfigModel):
     trace: TraceConfig = {}
     health: HealthConfig = {}
     goodput: GoodputConfig = {}
-    roofline: RooflineConfig = {}
 
     @property
     def enabled(self):

@@ -24,13 +24,17 @@ def training_flops_per_token(n_params, num_layers=None, hidden_size=None, seq_le
     return flops
 
 
-def analyze_fn(fn, *example_args, **example_kwargs):
-    """Compile ``fn`` and return {'flops': float, 'bytes accessed': float, ...}.
-    Extraction is shared with the roofline plane (``monitor/roofline.py``) so
-    the point-wise profiler and the per-bucket verdicts can never read
-    different keys out of the same executable."""
-    from ..monitor.roofline import cost_analysis_dict
+def cost_analysis_dict(compiled):
+    """``compiled.cost_analysis()`` as ONE flat dict (a jax that wraps the
+    result in a single-element list is unwrapped)."""
+    cost = compiled.cost_analysis()
+    if isinstance(cost, list):
+        cost = cost[0] if cost else {}
+    return dict(cost or {})
 
+
+def analyze_fn(fn, *example_args, **example_kwargs):
+    """Compile ``fn`` and return {'flops': float, 'bytes accessed': float, ...}."""
     lowered = jax.jit(fn).lower(*example_args, **example_kwargs)
     return cost_analysis_dict(lowered.compile())
 

@@ -43,7 +43,8 @@ from ..monitor.trace import NULL_SPAN, configure_tracer, get_tracer
 from ..monitor.metrics import get_metrics, compute_mfu
 from ..monitor.health import get_health
 from ..monitor.goodput import configure_goodput, get_goodput
-from ..monitor.roofline import configure_roofline, get_capture_manager, get_roofline
+from ..monitor import scopes
+from ..monitor.roofline import get_capture_manager
 from ..parallel import groups
 from ..parallel.mesh import (BATCH_AXES, DATA_AXIS, DATA_REPL_AXIS, SEQ_AXIS, MeshConfig, build_mesh,
                              shard_map_compat)
@@ -462,11 +463,6 @@ class DeepSpeedEngine:
         _gp = get_goodput()
         if _gp.enabled:
             self._goodput = _gp.training
-        # roofline plane (monitor/roofline.py): executable-cost registry +
-        # per-bucket verdicts. Absent block: the singleton stays disabled and
-        # the compile site / step boundary pay one `enabled` check each.
-        if config.monitor_config.roofline.enabled:
-            configure_roofline(config=config.monitor_config.roofline)
         if config.flops_profiler_config.enabled:
             from ..profiling.flops_profiler import FlopsProfiler
 
@@ -720,7 +716,8 @@ class DeepSpeedEngine:
 
         def scaled_loss(p):
             loss, aux = self._loss_fn(p, batch, rng)
-            return loss * loss_scale, (loss, aux)
+            with jax.named_scope(scopes.LOSS):
+                return loss * loss_scale, (loss, aux)
 
         grads, (loss, _aux) = jax.grad(scaled_loss, has_aux=True)(params)
         grads = constrain(grads, self.zero_policy.grad_specs(params), self.mesh)
@@ -817,19 +814,22 @@ class DeepSpeedEngine:
             acc, rng = carry
             rng, sub = jax.random.split(rng)
             grads, loss = self._microbatch_grads(params, mb, sub, loss_scale)
-            acc = jax.tree_util.tree_map(jnp.add, acc, grads)
-            acc = constrain(acc, grad_specs, self.mesh)
+            with jax.named_scope(scopes.OPTIMIZER):
+                acc = jax.tree_util.tree_map(jnp.add, acc, grads)
+                acc = constrain(acc, grad_specs, self.mesh)
             return (acc, rng), loss
 
-        zeros = jax.tree_util.tree_map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-        zeros = constrain(zeros, grad_specs, self.mesh)
+        with jax.named_scope(scopes.OPTIMIZER):
+            zeros = jax.tree_util.tree_map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
+            zeros = constrain(zeros, grad_specs, self.mesh)
         if gas == 1:
             one = jax.tree_util.tree_map(lambda x: x[0], batches)
             (acc, _), losses = micro((zeros, rng), one)
             losses = losses[None]
         else:
             (acc, _), losses = jax.lax.scan(micro, (zeros, rng), batches)
-        acc = jax.tree_util.tree_map(lambda g: g / gas, acc)
+        with jax.named_scope(scopes.OPTIMIZER):
+            acc = jax.tree_util.tree_map(lambda g: g / gas, acc)
         return acc, losses
 
     def _accumulate_grads_fn(self, gas: int):
@@ -1200,14 +1200,15 @@ class DeepSpeedEngine:
 
     def _finalize_step(self, state, grads, mean_loss, unscaled=False):
         """Shared tail: apply update + build the step metrics dict."""
-        new_state, finite = self._apply_update(state, grads, jnp.array(True), unscaled=unscaled)
-        metrics = {
-            "loss": mean_loss,
-            "grad_norm": optax.global_norm(grads),
-            "overflow": jnp.logical_not(finite),
-            "lr": (self.lr_schedule_fn(state["step"]) if self.lr_schedule_fn is not None else
-                   jnp.asarray((self.config.optimizer_params or {}).get("lr", 0.0))),
-        }
+        with jax.named_scope(scopes.OPTIMIZER):
+            new_state, finite = self._apply_update(state, grads, jnp.array(True), unscaled=unscaled)
+            metrics = {
+                "loss": mean_loss,
+                "grad_norm": optax.global_norm(grads),
+                "overflow": jnp.logical_not(finite),
+                "lr": (self.lr_schedule_fn(state["step"]) if self.lr_schedule_fn is not None else
+                       jnp.asarray((self.config.optimizer_params or {}).get("lr", 0.0))),
+            }
         return new_state, metrics
 
     def _jit_step(self, fn):
@@ -1385,24 +1386,10 @@ class DeepSpeedEngine:
                         "train", bucket="train_step", warmed=self._gp_warm_declared,
                         step=self.global_steps)
                 self._compiled["train_step"] = self._build_train_step(gas)
-                _rf = get_roofline()
-                if _rf.enabled:
-                    # cost_analysis of the fused step needs the mesh for
-                    # lowering sharded args — captured with the wrapper
-                    self._compiled["train_step"] = _rf.capture_executable(
-                        "train_step", self._compiled["train_step"], mesh=self.mesh)
-            _rf = get_roofline()
-            t_rf = time.perf_counter() if _rf.enabled else 0.0
             # enqueue of the fused step (a first call traces and compiles here)
             with self._tracer.span("train/dispatch", tid="engine", step=self.global_steps,
                                    compiled=building), self.mesh:
                 self.state, metrics = self._compiled["train_step"](self.state, placed, step_rng)
-            if _rf.enabled:
-                # dispatch-side wall at the step boundary: async steps make a
-                # single sample an under-read, but steady-state backpressure
-                # converges it to the true step time (same caveat as
-                # _last_step_wall_ms)
-                _rf.note_wall("train_step", time.perf_counter() - t_rf)
         self.global_steps += 1
         self.micro_steps += gas
         self.global_samples += self.train_batch_size()

@@ -67,15 +67,19 @@ def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path):
     from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 
     before = jax.config.jax_compilation_cache_dir
+    keyed = jax.config.jax_compilation_cache_include_metadata_in_key
     try:
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         assert enable_compile_cache() == str(tmp_path)
         assert jax.config.jax_compilation_cache_dir == before  # nothing set in code
+        # an executable is cached under its metadata too: its op_names are what a trace is read by
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
         assert enable_compile_cache() == os.path.join(REPO, ".jax_cache")
         assert jax.config.jax_compilation_cache_dir == os.path.join(REPO, ".jax_cache")
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key", keyed)
 
 
 def test_requested_tpu_accelerator_raises_without_one(monkeypatch):

@@ -1,20 +1,10 @@
-"""Roofline attribution plane + on-demand profiling (``monitor/roofline.py``).
+"""On-demand profiling (``monitor/roofline.py``: the capture manager) and the
+per-chip peak tables. The cost-analysis roofline plane whose tests were here
+(PR 16) went with PR 53; ``tests/test_scopes.py`` and
+``tests/perfbench/test_bench_scopes.py`` hold what replaced it.
 
-The PR 16 acceptance bars, test-enforced:
-
-* **zero-overhead-off** — with the ``monitor.roofline`` block absent the
-  plane holds no registry, installs no per-compile wrappers (the engine's
-  compiled cache holds the raw jitted callables), and starts no threads
-  (the PR 5 contract the trace/health/goodput planes carry);
-* **cost-join reconciliation** — the registry's measured wall per bucket
-  sums to the goodput ledger's serving compute categories within 5% under
-  the CPU engine (both instruments watch the same windows, so they can
-  never tell different stories about where the time went);
-* **verdict math** — with both roofs priced the verdict is
-  compute_bound/bandwidth_bound by the binding roof, overhead_bound past
-  ``overhead_factor`` x roof, with gap-to-roof disclosed; any missing
-  input (CPU peaks, failed cost analysis) yields ``unknown`` + nulls,
-  never a guessed utilization;
+* **peak tables** — FLOP/s and HBM bytes/s keyed by the same device kinds,
+  and ``compute_mbu`` disclosing null where a peak is unknown;
 * **on-demand capture** — ``POST /v1/profile`` on a live gateway produces
   an atomically-renamed XPlane artifact (no ``.tmp-*`` ever visible as a
   result), 409 while a capture is in flight, 404 with the block absent;
@@ -28,18 +18,14 @@ import os
 import threading
 import time
 
-import numpy as np
 import pytest
 
-from deepspeed_tpu.monitor.goodput import configure_goodput, get_goodput
+from deepspeed_tpu.monitor.goodput import get_goodput
 from deepspeed_tpu.monitor.health import get_health
 from deepspeed_tpu.monitor.metrics import (CHIP_PEAK_FLOPS, CHIP_PEAK_HBM_BW,
                                            compute_mbu, compute_mfu, get_metrics,
                                            peak_flops_per_chip, peak_hbm_bw_per_chip)
-from deepspeed_tpu.monitor.roofline import (CaptureBusyError, CaptureManager,
-                                            ExecutableCostRegistry, _CapturedExecutable,
-                                            configure_roofline, get_capture_manager,
-                                            get_roofline)
+from deepspeed_tpu.monitor.roofline import CaptureBusyError, CaptureManager, get_capture_manager
 from deepspeed_tpu.monitor.trace import get_tracer
 
 
@@ -48,7 +34,6 @@ def _reset_planes():
     """Process-global planes: leave everything disarmed so engines in other
     test files never pay the observing path."""
     yield
-    get_roofline().shutdown()
     get_goodput().shutdown()
     get_metrics().disable()
     get_metrics().reset()
@@ -83,199 +68,6 @@ def test_compute_mbu_contract_mirrors_mfu():
     assert compute_mbu(1e9, 0.0, peak_bw=100e9) is None
     assert compute_mbu(1e9, 0.1, peak_bw=None) is None  # CPU: unknown chip
     assert compute_mfu(1e9, 0.1, peak_flops=None) is None
-
-
-# ---------------------------------------------------------------------------
-# config + lifecycle
-# ---------------------------------------------------------------------------
-def test_roofline_config_presence_enables():
-    from deepspeed_tpu.monitor.config import get_monitor_config
-
-    assert not get_monitor_config({}).roofline.enabled
-    assert get_monitor_config({"roofline": {}}).roofline.enabled
-    cfg = get_monitor_config({"roofline": {"overhead_factor": 3.0}}).roofline
-    assert cfg.enabled and cfg.overhead_factor == 3.0
-    assert not get_monitor_config(
-        {"roofline": {"enabled": False, "overhead_factor": 3.0}}).roofline.enabled
-
-
-def test_configure_arms_and_shutdown_disarms():
-    plane = configure_roofline(enabled=True, peak_flops=1e12, peak_hbm_bw=1e11)
-    assert plane.enabled and plane._registry is not None
-    assert plane.peaks() == (1e12, 1e11)
-    plane.note_wall("b", 0.5)
-    assert plane.report()["buckets"]["b"]["wall_s"] == 0.5
-    plane.shutdown()
-    assert not plane.enabled and plane._registry is None
-    # disabled hooks are no-ops, and capture_executable is identity
-    plane.note_wall("b", 0.5)
-    fn = lambda x: x  # noqa: E731
-    assert plane.capture_executable("b", fn) is fn
-    assert plane.report()["buckets"] == {}
-
-
-# ---------------------------------------------------------------------------
-# verdict math (peak overrides make the math unit-testable on CPU)
-# ---------------------------------------------------------------------------
-def test_verdict_math_with_both_roofs_priced():
-    plane = configure_roofline(enabled=True, peak_flops=1e12, peak_hbm_bw=1e11,
-                               overhead_factor=2.0)
-    # compute-bound: t_flops = 1e10/1e12 = 10ms binds over t_bytes = 1ms;
-    # measured 12ms is under 2x the 10ms roof
-    row = plane.verdict_row({"flops": 1e10, "bytes": 1e8}, wall_s=0.012, calls=1)
-    assert row["verdict"] == "compute_bound"
-    assert row["roof_s"] == pytest.approx(0.010)
-    assert row["gap_to_roof"] == pytest.approx(1.2)
-    assert row["mfu"] == pytest.approx(1e10 / 0.012 / 1e12, abs=1e-3)
-    # bandwidth-bound: t_bytes = 1e9/1e11 = 10ms binds over t_flops = 1ms
-    row = plane.verdict_row({"flops": 1e9, "bytes": 1e9}, wall_s=0.015, calls=1)
-    assert row["verdict"] == "bandwidth_bound"
-    assert row["mbu"] == pytest.approx(1e9 / 0.015 / 1e11, abs=1e-3)
-    # overhead-bound: measured 50ms >> 2 x 10ms roof
-    row = plane.verdict_row({"flops": 1e10, "bytes": 1e8}, wall_s=0.050, calls=1)
-    assert row["verdict"] == "overhead_bound"
-    assert row["gap_to_roof"] == pytest.approx(5.0)
-
-
-def test_verdict_unknown_when_any_input_missing():
-    # no peaks (the CPU default): utilization and verdict stay null even
-    # with a priced cost — never a misleading number
-    plane = configure_roofline(enabled=True)
-    if plane.peaks() != (None, None):  # pragma: no cover - TPU host
-        pytest.skip("real chip: peaks are knowable")
-    row = plane.verdict_row({"flops": 1e10, "bytes": 1e8}, wall_s=0.01, calls=1)
-    assert row["verdict"] == "unknown" and row["mfu"] is None and row["mbu"] is None
-    plane.shutdown()
-    # one-sided roof must NOT verdict (a missing bandwidth roof could call
-    # a bandwidth-bound kernel compute_bound)
-    plane = configure_roofline(enabled=True, peak_flops=1e12)
-    row = plane.verdict_row({"flops": 1e10, "bytes": 1e8}, wall_s=0.012, calls=1)
-    assert row["verdict"] == "unknown" and row["mfu"] is not None
-    # no wall samples -> unknown
-    plane.configure(peak_hbm_bw=1e11)
-    row = plane.verdict_row({"flops": 1e10, "bytes": 1e8}, wall_s=0.0, calls=0)
-    assert row["verdict"] == "unknown" and row["mean_wall_s"] is None
-
-
-def test_cost_fallback_discloses_null_never_crashes():
-    plane = configure_roofline(enabled=True, peak_flops=1e12, peak_hbm_bw=1e11)
-
-    class Boom:
-        def lower(self, *a):
-            raise RuntimeError("no backend")
-
-    plane._registry.register_lazy("bad", Boom(), ())
-    plane._registry.note_wall("bad", 0.01)
-    row = plane.report()["buckets"]["bad"]  # forcing the thunk must not raise
-    assert row["flops"] is None and row["bytes"] is None
-    assert row["verdict"] == "unknown"
-    assert "RuntimeError" in row["cost_error"]
-    # a cost dict with missing keys (some backends price only flops)
-    reg = ExecutableCostRegistry()
-    reg.register_cost("partial", {"flops": 1e9, "bytes": None})
-    reg.note_wall("partial", 0.01)
-    row = plane.verdict_row(reg.cost("partial"), 0.01, 1)
-    assert row["mfu"] is not None and row["mbu"] is None
-    assert row["verdict"] == "unknown"  # both roofs required
-
-
-# ---------------------------------------------------------------------------
-# engine integration: lazy capture + cost-join reconciliation
-# ---------------------------------------------------------------------------
-def _tiny_serving_run(engine, n_seqs=4, prompt_len=12, horizons=(4, 4, 4)):
-    rng = np.random.default_rng(0)
-    prompts = [rng.integers(0, 128, size=prompt_len, dtype=np.int32)
-               for _ in range(n_seqs)]
-    uids = list(range(n_seqs))
-    toks = []
-    for u in uids:
-        out = engine.put([u], [prompts[u]], sample="greedy")
-        toks.append(np.asarray([int(out[0])], np.int32))
-    for h in horizons:
-        engine.decode(uids, toks, h)
-    return uids
-
-
-def test_zero_overhead_when_block_absent():
-    """PR 5 contract: roofline machinery provably absent — no registry, no
-    wrappers in the compiled cache, no threads — when never configured."""
-    from tools.serving_load import build_engine
-
-    threads_before = set(threading.enumerate())
-    plane = get_roofline()
-    assert not plane.enabled and plane._registry is None
-    engine = build_engine()
-    _tiny_serving_run(engine)
-    assert plane._registry is None  # traffic armed nothing
-    # the compiled cache holds the RAW jitted callables, not wrappers
-    for key, fn in engine._compiled.items():
-        assert not isinstance(fn, _CapturedExecutable), key
-    new = [t for t in set(threading.enumerate()) - threads_before if t.is_alive()]
-    assert not [t.name for t in new if "roofline" in t.name.lower() or
-                "capture" in t.name.lower()]
-
-
-def test_cost_join_reconciles_with_goodput_within_5pct():
-    """The registry's wall and the goodput ledger's serving compute
-    categories watch the same windows: their totals must agree."""
-    from tools.serving_load import build_engine
-
-    configure_goodput(enabled=True)
-    plane = configure_roofline(enabled=True)
-    engine = build_engine()
-    engine.goodput_ledger = get_goodput().serving_ledger("rf-test")
-    _tiny_serving_run(engine, horizons=(4, 4, 4, 4))
-    # every compiled program is wrapped and every bucket has wall samples
-    assert all(isinstance(fn, _CapturedExecutable)
-               for fn in engine._compiled.values())
-    snap = plane._registry.snapshot()
-    assert snap, "no buckets registered"
-    put_w = sum(w for b, _, w, _ in snap if b.startswith("put/"))
-    dec_w = sum(w for b, _, w, _ in snap if b.startswith("decode/"))
-    assert put_w > 0 and dec_w > 0
-    cats = get_goodput().serving_ledger("rf-test").report()["categories"]
-    gp_total = cats.get("prefill_active", 0.0) + cats.get("decode_active", 0.0)
-    rf_total = put_w + dec_w
-    assert rf_total == pytest.approx(gp_total, rel=0.05), (rf_total, gp_total)
-    # the buckets carry the sentinel's label shapes and priced costs (CPU
-    # cost_analysis works on this jax; a backend without it would disclose)
-    rep = plane.report()
-    for bucket, row in rep["buckets"].items():
-        assert bucket.startswith(("put/", "decode/")), bucket
-        assert row["calls"] > 0
-    # verdicts honest on CPU: no peaks -> unknown + null MFU/MBU; with
-    # overrides the SAME rows verdict for real
-    if rep["peak_flops"] is None:
-        assert all(r["verdict"] == "unknown" for r in rep["buckets"].values())
-        assert plane.gauge_rows() == []
-        plane.configure(peak_flops=1e12, peak_hbm_bw=1e11)
-        rep = plane.report()
-        priced = [r for r in rep["buckets"].values() if r["flops"] is not None]
-        assert priced and all(r["verdict"] != "unknown" for r in priced)
-        names = {name for name, _, _ in plane.gauge_rows()}
-        assert names <= {"profile/roofline_mfu", "profile/roofline_mbu"}
-        assert names
-
-
-def test_speculative_verify_bucket_joins():
-    from tools.serving_load import build_engine
-
-    plane = configure_roofline(enabled=True)
-    engine = build_engine()
-    rng = np.random.default_rng(1)
-    prompts = [rng.integers(0, 128, size=10, dtype=np.int32) for _ in range(2)]
-    uids = [0, 1]
-    toks = []
-    for u in uids:
-        out = engine.put([u], [prompts[u]], sample="greedy")
-        toks.append(np.asarray([int(out[0])], np.int32))
-    drafts = [rng.integers(0, 128, size=3, dtype=np.int32) for _ in uids]
-    engine.speculate_decode(uids, toks, drafts, k=3)
-    verify = [b for b in plane._registry.buckets() if b.startswith("verify/")]
-    assert len(verify) == 1
-    _, _, wall, calls = [r for r in plane._registry.snapshot()
-                         if r[0] == verify[0]][0]
-    assert calls == 1 and wall > 0
 
 
 # ---------------------------------------------------------------------------
@@ -391,15 +183,5 @@ def test_check_metric_names_accepts_profile_prefix():
     from tools.check_metric_names import APPROVED_PREFIXES, _FULL_NAME
 
     assert "profile" in APPROVED_PREFIXES
-    assert _FULL_NAME.match("profile/roofline_mfu")
     assert _FULL_NAME.match("profile/captures_total")
-    assert not _FULL_NAME.match("rooflines/mfu")
-    # every gauge the plane exports passes the gate's full-name rule
-    plane = configure_roofline(enabled=True, peak_flops=1e12, peak_hbm_bw=1e11)
-    plane._registry.register_cost("b", {"flops": 1e9, "bytes": 1e8})
-    plane.note_wall("b", 0.01)
-    rows = plane.gauge_rows()
-    assert rows
-    for name, labels, value in rows:
-        assert _FULL_NAME.match(name), name
-        assert set(labels) == {"bucket"} and 0 <= value
+    assert not _FULL_NAME.match("profiles/captures_total")
