@@ -34,7 +34,7 @@ from ...ops.pallas.mamba2 import (KERNEL_NAMES as MAMBA_KERNEL_NAMES, TILE as MA
 from ...ops.pallas.paged_attention import decode_kv_counts, kernel_choice, tiled_kv_counts
 from ...utils.logging import log_dist
 from .config_v2 import RaggedInferenceEngineConfig
-from .model_implementations.sparse_index import index_tile
+from .model_implementations.sparse_index import index_tile, keys_scored, scores_by_kernel
 from .model_implementations.flat_model import (expanded_batch, expanded_plan, expanded_slots, expanded_workspace_bytes,
                                                 ragged_forward)
 from .ragged.ragged_manager import DSStateManager
@@ -186,6 +186,9 @@ class InferenceEngineV2:
         self._lightning = int(getattr(mc, "lightning_num_heads", 0) or 0) > 0
         self._mamba = int(getattr(mc, "mamba_num_heads", 0) or 0) > 0
         if self._sparse:
+            # whether the indexer's kernel runs, as ``ragged_forward`` decides it: on the chip, or its body interpreted
+            self._index_kernels = (self._use_pallas and jax.default_backend() == "tpu", bool(getattr(
+                self._modules["attention"], "implementation_config", {}).get("interpret", False)))
             if getattr(ic.speculative, "enabled", False):
                 raise NotImplementedError(
                     "speculative decoding of a model with pooled keys (a learned block selection): a rejected "
@@ -409,9 +412,11 @@ class InferenceEngineV2:
 
     def _index_bytes_ahead(self, mc, ic, max_context: int) -> int:
         """What a model with a learned block selection keeps beside the pools
-        while its largest ``put`` program runs: the indexer's scores of one
-        pass (``sparse_index._SCORE_BYTES``, twice: the softmax beside them),
-        the selection a token a kv head a block with the mask the tiled kernel
+        while its largest ``put`` program runs: the XLA form's scores of one
+        pass (``sparse_index._SCORE_BYTES``, twice: the softmax beside them;
+        the kernel's program holds less, two planes of block scores a tile
+        and the rows' pooled keys twice, and is given the same room), the
+        selection a token a kv head a block with the mask the tiled kernel
         takes with every (tile, block) pair (bfloat16, 16 sublanes), and the
         pooled keys gathered a tile. 0 for every other model."""
         if not getattr(mc, "index_entry", ()):
@@ -607,7 +612,8 @@ class InferenceEngineV2:
                 bucket_tokens=int(t_bucket), bucket_rows=int(s_bucket), steps=1,
                 kernel=self._kernel_of(t_bucket, s_bucket), uids=[int(u) for u in batch_uids[:16]],
                 blocked=bool(block),
-                **self._attn_span_args([seq.seen_tokens for seq in descs], [t.size for t in batch_tokens], t_bucket),
+                **self._attn_span_args([seq.seen_tokens for seq in descs], [t.size for t in batch_tokens], t_bucket,
+                                       s_bucket),
                 **self._state_span_args(len(batch_uids), sum(int(t.size) for t in batch_tokens),
                                         0 if self._lightning else sum(1 for t in batch_tokens if t.size == 1)),
                 **({} if had_prefill else
@@ -675,7 +681,7 @@ class InferenceEngineV2:
                 "expert_load_max": int(stats[1]),
                 "experts_held": mc.experts_held, "experts_published": mc.moe_num_experts}
 
-    def _attn_span_args(self, seen, new, t_bucket: int = 0) -> dict:
+    def _attn_span_args(self, seen, new, t_bucket: int = 0, s_bucket: int = 0) -> dict:
         """What a step span says of the attention work whatever kernel and
         form ran it, from the rows' lengths alone: ``attn_pairs``, the visible
         (query token, context token) pairs of a call that feeds row ``r`` the
@@ -688,10 +694,12 @@ class InferenceEngineV2:
         with latent attention says ``attn_expanded_pairs`` too: those of
         ``attn_pairs`` that the ``put`` program of ``t_bucket`` tokens attended
         in the expanded form (``flat_model.expanded_slots`` on the same
-        lengths; 0 for a decode horizon, which has no such program)."""
+        lengths; 0 for a decode horizon, which has no such program).
+        ``s_bucket``: the program's rows, which a model with a block selection
+        sizes its indexer's tiles by (:meth:`_sparse_span_args`)."""
         seen, new = np.asarray(seen, np.int64), np.asarray(new, np.int64)
         if self._sparse:
-            return self._sparse_span_args(seen, new)
+            return self._sparse_span_args(seen, new, t_bucket, s_bucket)
         pairs = ctx = 0
         for window, layers in self._kv_windows:
             if window is None:
@@ -709,7 +717,7 @@ class InferenceEngineV2:
             args["attn_expanded_pairs"] = self.model_config.num_layers * int((row_pairs * expanded).sum())
         return args
 
-    def _sparse_span_args(self, seen, new) -> dict:
+    def _sparse_span_args(self, seen, new, t_bucket: int = 0, s_bucket: int = 0) -> dict:
         """:meth:`_attn_span_args` of a model with a learned block selection,
         which counts what was READ, from the rows' lengths alone (a token with
         more than ``dense_len`` tokens of context selects exactly ``topk``
@@ -722,6 +730,12 @@ class InferenceEngineV2:
         to both for a row under ``dense_len``). ``sparse_rows`` / ``dense_rows``:
         the rows with and without a token past ``dense_len``; ``index_keys``:
         the pooled keys such tokens scored (x kv heads x layers);
+        ``index_keys_scored``: the (query token slot, kv head, pooled key)
+        triples the indexer's program scored for them (x layers): what the
+        kernel's work list covers (``ops/pallas/sparse_index.keys_scored``, the
+        program's own rule on the host), or every pooled key of the table for
+        every slot of every tile where the XLA form runs (``t_bucket`` 0: a
+        decode horizon, a program of ``s_bucket`` one-token rows a step);
         ``index_entry_bytes``: one pooled key's bytes in one layer.
         ``attn_pairs``: the (query, selected context token) pairs a layer;
         ``attn_ctx_tokens``: the context tokens the rows' queries selected, at
@@ -735,6 +749,13 @@ class InferenceEngineV2:
         pairs = np.where(dense, p + 1, (mc.sparse_topk - 1) * bs + p % bs + 1)
         keys = np.where(dense, 0, (p - (mc.sparse_kernel_size - 1)) // mc.sparse_kernel_stride + 1)
         past = seen + new > mc.sparse_dense_len
+        T = t_bucket or s_bucket
+        qt, rule = index_tile(T), (bs, mc.sparse_kernel_stride, mc.sparse_kernel_size, mc.sparse_dense_len)
+        steps = [(seen, new)] if t_bucket else [(seen + i, np.minimum(new - i, 1)) for i in range(int(new.max(initial=0)))]
+        if scores_by_kernel(T, *self._index_kernels):
+            scored = sum(keys_scored(s, n, self._max_blocks_per_seq, qt, *rule) for s, n in steps)
+        else:
+            scored = len(steps) * (-(-T // qt) + s_bucket + 1) * qt * self._max_blocks_per_seq * (bs // rule[1])
         row_of = np.repeat(np.arange(len(new)), new)
         ctx = np.minimum(seen + new, np.bincount(row_of, weights=pairs, minlength=len(new)).astype(np.int64))
         return {"attn_pairs": layers * int(pairs.sum()), "attn_ctx_tokens": layers * int(ctx.sum()),
@@ -742,7 +763,8 @@ class InferenceEngineV2:
                 "attn_blocks_visible": layers * nkv * int(visible.sum()),
                 "attn_blocks_selected": layers * nkv * int(selected.sum()),
                 "sparse_rows": int(past.sum()), "dense_rows": int((~past).sum()),
-                "index_keys": layers * nkv * int(keys.sum()), "index_entry_bytes": kv.index_entry_bytes()}
+                "index_keys": layers * nkv * int(keys.sum()), "index_keys_scored": layers * nkv * scored,
+                "index_entry_bytes": kv.index_entry_bytes()}
 
     def _state_span_args(self, rows: int, tokens: int, stepped: int) -> dict:
         """What a step span says of the state layers of a model that has them
@@ -1024,7 +1046,7 @@ class InferenceEngineV2:
                 rows=S, tokens=S * int(n_steps), steps=int(n_steps), bucket_rows=int(s_bucket),
                 bucket_tokens=int(s_bucket), kernel=self._kernel_of(s_bucket, s_bucket, horizon=True),
                 uids=[int(u) for u in uids[:16]], blocked=bool(block),
-                **self._attn_span_args([seq.seen_tokens for seq in seqs], [int(n_steps)] * S),
+                **self._attn_span_args([seq.seen_tokens for seq in seqs], [int(n_steps)] * S, s_bucket=s_bucket),
                 **self._state_span_args(S * int(n_steps), S * int(n_steps), S * int(n_steps)),
                 **self._kv_span_args(s_bucket, s_bucket, np.asarray([seq.seen_tokens for seq in seqs])[None, :]
                                      + np.arange(int(n_steps))[:, None])))

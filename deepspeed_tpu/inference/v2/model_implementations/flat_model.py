@@ -391,6 +391,9 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
             raise ValueError("a model with linear-attention layers takes state_pools and state_slots, and neither int8 "
                              "scales, a token-tree mask nor kv_only; every other model takes none")
         lightning = cfg.lightning_num_heads > 0
+        # the kernels of the state layers and of the selection's indexer: on the chip, or their bodies on the interpreter
+        kda_pallas = use_pallas and jax.default_backend() == "tpu"
+        kda_interpret = bool(getattr(attention, "implementation_config", {}).get("interpret", False))
         if state_index:
             # what the rows are fed, once a program: tokens a row (rows come in order from flat token 0), a
             # token's place in its row's run, and which rows start their sequence here
@@ -408,8 +411,6 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
             fed = n_tok > 0
             fresh = fed & (first_pos == 0)
             n_live = jnp.sum(fed.astype(jnp.int32))
-            kda_pallas = use_pallas and jax.default_backend() == "tpu"
-            kda_interpret = bool(getattr(attention, "implementation_config", {}).get("interpret", False))
             st_flat = state_pools[0].reshape((-1, ) + state_pools[0].shape[2:])
             cv_flat = None if lightning else state_pools[1].reshape((-1, ) + state_pools[1].shape[2:])
         if sparse:
@@ -643,7 +644,8 @@ def ragged_forward(cfg: TransformerConfig, block_size: int, params: Dict[str, An
             if kind == "sparse_attention":
                 # the pooled keys this step completes, then each query's blocks: the work lists follow the data
                 p_flat = update_pooled_keys(cfg, block_size, k_flat, ws[-1], tables_l, seq_idx, pos, valid)
-                picked = select_blocks(cfg, block_size, q, p_flat, tables_l, seq_idx, pos, valid)
+                picked = select_blocks(cfg, block_size, q, p_flat, tables_l, seq_idx, pos, valid, use_pallas=kda_pallas,
+                                       interpret=kda_interpret)
                 ws = tuple(ws[:-1]) + (p_flat, )
                 ctx, read = attend(q, k_flat, v_flat, tables_l, seq_idx, vis_pos, selection=picked, **scales)
                 if stats is not None:  # blocks read a kv head, then the tiled list's pairs and its grid steps

@@ -1244,6 +1244,63 @@ def test_paged_kernels_under_a_selection_at_the_cells_shapes_on_chip(name, T, S,
         assert np.linalg.norm(got - want) / np.linalg.norm(want) < 1e-2
 
 
+@pytest.mark.parametrize("context", [9000, 30000, 62757])
+def test_the_selection_indexers_kernel_at_the_cells_shapes_on_chip(context):
+    """``sparse_index_scores`` against the XLA form (``sparse_index.block_scores``,
+    three tiles a pass) at ``minicpm-sala.longctx``'s shapes: a 2,041-token
+    chunk that ends at ``context`` behind seven riding one-token rows, tiles of
+    128, 32 query / 2 KV heads of 128, 64-token blocks, pooled keys at stride
+    16 over a table 1,034 wide (4,136 a row), bf16. The relative L2 of the
+    block scores over the tiles that have an item, and the share of (token, kv
+    head, block) selections that are equal; prints both forms' microseconds a
+    call (gathers, work list and epilogue included; the selection is no part
+    of either)."""
+    import types
+
+    from deepspeed_tpu.inference.v2.model_implementations import sparse_index as si
+
+    cfg = types.SimpleNamespace(sparse_kernel_size=32, sparse_kernel_stride=16, sparse_topk=64, sparse_init_blocks=1,
+                                sparse_window_size=2048, sparse_dense_len=8192)
+    T, S, nq, nkv, d, bs, mb, n_blocks = 2048, 8, 32, 2, 128, 64, 1034, 8300
+    rows = [(34000 + 4000 * i, 1) for i in range(7)] + [(context - 2041, 2041)]
+    rng = np.random.default_rng(54)
+    p_flat = jnp.asarray(rng.normal(size=(n_blocks * bs // 16, nkv, d)), jnp.bfloat16)
+    tables = jnp.asarray(np.stack([rng.permutation(n_blocks)[:mb] for _ in range(S)]), jnp.int32)
+    seq_idx = np.concatenate([np.full(new, r) for r, (_, new) in enumerate(rows)])
+    pos = np.concatenate([np.arange(before, before + new) for before, new in rows])
+    n = seq_idx.size
+    valid = jnp.asarray(np.arange(T) < n)
+    seq_idx, pos = jnp.asarray(np.pad(seq_idx, (0, T - n)), jnp.int32), jnp.asarray(np.pad(pos, (0, T - n)), jnp.int32)
+    q = jnp.asarray(rng.normal(size=(T, nq, d)), jnp.bfloat16)
+
+    def form(use_pallas):
+        def scores(q, p_flat, tables, seq_idx, pos, valid):
+            r, (tile_id, place, _, tile_pos) = si.tile_scores(cfg, bs, q, p_flat, tables, seq_idx, pos, valid, use_pallas)
+            return r, tile_id, place, tile_pos
+        return jax.jit(scores)
+
+    args = (q, p_flat, tables, seq_idx, pos, valid)
+    (got, tile_id, place, tile_pos), (want, *_) = form(True)(*args), form(False)(*args)
+    us = {name: _us_a_call(form(use_pallas), *args, calls=10) for name, use_pallas in (("kernel", True), ("xla", False))}
+    scored = si.keys_scored([b for b, _ in rows], [m for _, m in rows], mb, 128, bs, 16, 32, cfg.sparse_dense_len)
+    asked = sum((p - 31) // 16 + 1 for p in np.asarray(pos[:n]) if p + 1 > cfg.sparse_dense_len)
+    print(f"\nsparse_index_scores[chunk to {context}]: kernel {us['kernel']:.0f} us a call, XLA form {us['xla']:.0f} us a call; "
+          f"{asked} (token, pooled key) pairs asked of {scored} scored a kv head, the rectangle {(T // 128 + S + 1) * 128 * mb * 4}")
+    got, want = np.asarray(got), np.asarray(want)
+    # the tiles with an item: every one that holds a token past dense_len (a dense tile reads 0 from the kernel)
+    has_item = np.asarray(tile_pos).max(axis=1) + 1 > cfg.sparse_dense_len
+    assert has_item.sum() >= 8 + (context - 2041 > cfg.sparse_dense_len) * 15
+    rel = np.linalg.norm(got[has_item] - want[has_item]) / np.linalg.norm(want[has_item])
+    pick = jax.jit(lambda r, at: jax.lax.map(lambda a: si.selection_of(cfg, bs, *a), (r, at), batch_size=3))
+    a, b = (np.asarray(pick(jnp.asarray(r), tile_pos)) for r in (got, want))
+    at = (np.asarray(tile_id) * 128 + np.asarray(place))[:n]
+    a, b = a.reshape(-1, nkv, mb)[at], b.reshape(-1, nkv, mb)[at]
+    same = float((a == b).mean())
+    print(f"  relative L2 of the block scores {rel:.2e}; equal selections {100 * same:.4f}% of {a.size}, "
+          f"{int((a != b).any(axis=(1, 2)).sum())} of {n} tokens differ somewhere")
+    assert rel < 1e-3 and same > 0.999
+
+
 @pytest.mark.parametrize("form", ["recurrent_step", "chunk_mixed"])
 def test_the_lightning_kernels_at_the_cells_shapes_on_chip(form):
     """``lightning_recurrent_step`` over 8 rows and ``lightning_chunk_scan``
